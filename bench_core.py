@@ -224,7 +224,7 @@ def main() -> list[dict]:
 def obs_ab_main() -> dict:
     """Core-plane observability A/B probe (``--obs-ab``): the
     task-submission + object-plane microbenchmarks most implicated in the
-    BENCH_r04 4-8x core collapse, run ONCE under whatever
+    round-4 4-8x core collapse, run ONCE under whatever
     ``RAY_TPU_EVENTS`` / ``RAY_TPU_METRICS_SERIES`` the caller exported.
     ``bench.py`` invokes this twice — obs ON and obs OFF — in separate
     subprocesses (both knobs are read at import) and emits both numbers

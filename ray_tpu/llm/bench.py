@@ -385,15 +385,12 @@ def run_multichip_bench(smoke: bool = False) -> dict:
     n_dev = len(jax.devices())
     tps = [t for t in (2, 4) if t <= n_dev]
     if not tps:
-        # single-device host (e.g. env without XLA_FLAGS): record the
-        # skip rather than fake a ratio
-        return {
-            "metric": "llm_multichip_tp_tokens_per_sec",
-            "value": 0.0,
-            "unit": "tok/s",
-            "vs_baseline": None,
-            "detail": {"skipped": f"needs >=2 devices, have {n_dev}"},
-        }
+        raise RuntimeError(
+            f"the multichip bench compares tp arms and needs >=2 devices; "
+            f"jax found {n_dev} (on a CPU host, set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=4 before jax "
+            "initializes)"
+        )
 
     cfg, params = _model()
     n_req = 3 if smoke else MULTICHIP_N

@@ -86,17 +86,13 @@ class KVBlockPool:
 
         self.cfg = cfg
         shape = (n_layers, cfg.num_blocks, n_heads, cfg.block_size, head_dim)
-        self.k = jnp.zeros(shape, jnp.dtype(dtype))
-        self.v = jnp.zeros(shape, jnp.dtype(dtype))
-        if sharding is not None:
-            # multichip: place the pool arrays head-sharded over the tp
-            # mesh at creation so the engine's jitted steps never move
-            # them; the host ledger below is unchanged — block ids are
-            # global, every device holds the same blocks' local heads
-            import jax
-
-            self.k = jax.device_put(self.k, sharding)
-            self.v = jax.device_put(self.v, sharding)
+        # multichip passes the head-sharded placement over the tp mesh:
+        # every device allocates only ITS shard (the whole pool never
+        # exists on one device) and the engine's jitted steps never move
+        # it; the host ledger below is unchanged — block ids are global,
+        # every device holds the same blocks' local heads
+        self.k = jnp.zeros(shape, jnp.dtype(dtype), device=sharding)
+        self.v = jnp.zeros(shape, jnp.dtype(dtype), device=sharding)
         self._lock = threading.Lock()
         # LIFO free list of physical block ids; 0 reserved (trash)
         self._free = list(range(cfg.num_blocks - 1, 0, -1))

@@ -585,8 +585,10 @@ class LLMEngine:
         object-plane pushes).
 
         The new pytree must match the current one's structure and leaf
-        shapes/dtypes — then the jitted step functions never retrace (they
-        cache on shape, and params are a traced argument, not a captured
+        shapes; leaves arrive in any float dtype (a learner pushes its
+        fp32 masters) and are cast to the resident leaf's dtype — then the
+        jitted step functions never retrace (they cache on shape and
+        dtype, and params are a traced argument, not a captured
         constant). Leaves are ``device_put`` once here so steady-state
         steps don't re-upload host arrays every call. In-flight requests
         simply continue under the new weights from their next step —
@@ -599,29 +601,32 @@ class LLMEngine:
         """
         import jax
 
+        resident = self.runner.params  # structure/shapes/dtypes never change
+        old_struct = jax.tree_util.tree_structure(resident)
+        new_struct = jax.tree_util.tree_structure(params)
+        if old_struct != new_struct:
+            raise ValueError(
+                "update_weights pytree structure mismatch: "
+                f"{new_struct} != {old_struct}"
+            )
+        for a, b in zip(
+            jax.tree_util.tree_leaves(resident), jax.tree_util.tree_leaves(params)
+        ):
+            if a.shape != b.shape:
+                raise ValueError(
+                    f"update_weights leaf mismatch: {b.shape} != {a.shape} "
+                    "(a retrace mid-traffic is never acceptable)"
+                )
+        params = jax.tree_util.tree_map(
+            lambda new, cur: new if new.dtype == cur.dtype else new.astype(cur.dtype),
+            params, resident,
+        )
         # prepare_params owns placement: plain device conversion single-
         # chip, sharded device_put (+ fused-qkv permutation) under tp>1 —
         # either way the swap lands with the compiled steps' exact layout
         new = self.runner.prepare_params(params)
         t0 = time.perf_counter()
         with self._lock:
-            old_struct = jax.tree_util.tree_structure(self.runner.params)
-            new_struct = jax.tree_util.tree_structure(new)
-            if old_struct != new_struct:
-                raise ValueError(
-                    "update_weights pytree structure mismatch: "
-                    f"{new_struct} != {old_struct}"
-                )
-            for a, b in zip(
-                jax.tree_util.tree_leaves(self.runner.params),
-                jax.tree_util.tree_leaves(new),
-            ):
-                if a.shape != b.shape or a.dtype != b.dtype:
-                    raise ValueError(
-                        f"update_weights leaf mismatch: {b.shape}/{b.dtype} "
-                        f"!= {a.shape}/{a.dtype} (a retrace mid-traffic is "
-                        "never acceptable)"
-                    )
             if version is None:
                 version = self._weights_version + 1
             if version < self._weights_version:
@@ -788,6 +793,31 @@ class LLMEngine:
                 )
                 s["spec_draft_seconds"] = self._spec_draft_s
             return s
+
+    def device_report(self) -> dict:
+        """``util.device_prof.device_report()`` for the engine's process
+        plus what the engine put on the device: the attention dispatch
+        rule's answer for this pool next to the names of the Mosaic
+        kernels actually inside each jitted step called so far, each
+        step's first-call (compile) seconds, the jit-cache sizes the
+        retrace detector reads, and the HBM ledger."""
+        from ray_tpu.ops.paged_attention import auto_impl
+        from ray_tpu.util.device_prof import device_report
+
+        rep = device_report()
+        with self._lock:
+            rep["first_call_s"] = dict(self.runner.first_call_s)
+            rep["jit_sites"] = self.runner.prof.stats()
+            rep["hbm"] = self.hbm_ledger()
+        rep["attention"] = {
+            "configured": self.cfg.attn_impl,
+            "auto_rule": auto_impl(
+                self.pool.cfg.block_size, self.model_cfg.head_dim
+            ),
+            # lowering takes seconds at full depth: outside the lock
+            "mosaic_kernels": self.runner.kernels_in_steps(),
+        }
+        return rep
 
     def run_loop(self, stop: threading.Event, idle_sleep_s: float = 0.002) -> None:
         """Drive ``step()`` until ``stop`` is set (serve replicas run this

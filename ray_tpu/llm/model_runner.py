@@ -47,8 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import events as _events
+from ray_tpu._private.compile_cache import ensure_compile_cache
 from ray_tpu.models.gpt import GPTConfig, _layernorm
-from ray_tpu.util.device_prof import JitProfiler
+from ray_tpu.util.device_prof import JitProfiler, mosaic_kernels
 from ray_tpu.models.gptj import GPTJConfig
 from ray_tpu.models.sampling import (
     sample_tokens_logprobs,
@@ -109,6 +110,16 @@ def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
     return jax.vmap(one)(logits, keys, temp, top_k, top_p)
 
 
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """An operand's shape, dtype and — where it was placed on purpose (a
+    sharded pool, a weight) — placement: enough to lower a step again,
+    holding no buffer."""
+    placed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(
+        jnp.shape(x), jnp.result_type(x), sharding=x.sharding if placed else None
+    )
+
+
 def _fork_impl(k_pool, v_pool, src, dst):
     """Copy-on-write block fork for the prefix cache: duplicate whole
     physical blocks across every layer — ``pool[:, dst[i]] = pool[:,
@@ -143,6 +154,7 @@ class PagedModelRunner:
             self.arch = "gpt"
         else:
             raise TypeError(f"unsupported model config {type(cfg).__name__}")
+        ensure_compile_cache()
         self.cfg = cfg
         self.params = params
         self.block_size = block_size
@@ -162,6 +174,13 @@ class PagedModelRunner:
         self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
         self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
         self._compiled: set = set()  # (fn, shape-key)s already traced
+        #: site -> (jitted fn, abstract operands, static kwargs) of its
+        #: first call: what kernels_in_steps lowers again, so the report
+        #: describes the step the engine runs and not a copy of its signature
+        self._first_operands: dict = {}
+        #: site -> wall seconds of its first call (trace + compile, or the
+        #: persistent-cache load) — set-up time, reported by device_report
+        self.first_call_s: dict = {}
         # device-step profiler: per-call wall time into device_step_seconds
         # {site=decode|prefill|verify|fork} + retrace detection against the
         # jit cache size — a site recompiling after its warmup baseline
@@ -178,9 +197,10 @@ class PagedModelRunner:
         if (fn, key) in self._compiled:
             return
         self._compiled.add((fn, key))
+        self.first_call_s[fn] = round(time.perf_counter() - t0, 3)
         _events.record(
             "llm.compile", fn=fn, shape=str(key), arch=self.arch,
-            first_call_s=round(time.perf_counter() - t0, 3),
+            first_call_s=self.first_call_s[fn],
         )
 
     def prepare_params(self, params: dict) -> dict:
@@ -191,6 +211,32 @@ class PagedModelRunner:
         ``LLMEngine.update_weights`` routes every hot-swap through here
         so swapped weights land exactly like the originals."""
         return jax.tree_util.tree_map(jnp.asarray, params)
+
+    def _call(self, site: str, fn, key: Any, *args, **static):
+        """Run one jitted step: remember its first call's operands
+        (abstractly), time it, and feed the compile marker and the
+        retrace detector."""
+        t0 = time.perf_counter()
+        if site not in self._first_operands:
+            self._first_operands[site] = (
+                fn, jax.tree_util.tree_map(_abstract, args), static
+            )
+        out = fn(*args, **static)
+        self._note_compile(site, key, t0)
+        self.prof.note(site, fn, time.perf_counter() - t0)
+        return out
+
+    def kernels_in_steps(self) -> dict:
+        """site -> names of the Mosaic kernels inside that jitted step's
+        lowered program, for every step called so far, at the operands it
+        was called with: what the attention dispatch rule actually put
+        into the step (none = plain XLA ops), read from the program
+        instead of trusted from the config.  Lowering only — nothing
+        compiles or runs."""
+        return {
+            site: mosaic_kernels(fn.lower(*args, **static))
+            for site, (fn, args, static) in self._first_operands.items()
+        }
 
     # -- shared layer math -------------------------------------------------
 
@@ -314,14 +360,11 @@ class PagedModelRunner:
 
     def decode_step(self, k_pool, v_pool, tokens, positions, tables,
                     temp, top_k, top_p, seeds, counters):
-        t0 = time.perf_counter()
-        out = self._decode(
+        return self._call(
+            "decode", self._decode, len(tokens),
             self.params, k_pool, v_pool, tokens, positions, tables,
             temp, top_k, top_p, seeds, counters,
         )
-        self._note_compile("decode", len(tokens), t0)
-        self.prof.note("decode", self._decode, time.perf_counter() - t0)
-        return out
 
     # -- speculative verification step -------------------------------------
 
@@ -400,14 +443,11 @@ class PagedModelRunner:
 
     def verify_step(self, k_pool, v_pool, tokens, base_pos, tables,
                     temp, top_k, top_p, seeds, counters):
-        t0 = time.perf_counter()
-        out = self._verify(
+        return self._call(
+            "verify", self._verify, tuple(jnp.shape(tokens)),
             self.params, k_pool, v_pool, tokens, base_pos, tables,
             temp, top_k, top_p, seeds, counters,
         )
-        self._note_compile("verify", tuple(jnp.shape(tokens)), t0)
-        self.prof.note("verify", self._verify, time.perf_counter() - t0)
-        return out
 
     # -- copy-on-write block fork (llm.prefix_cache) -----------------------
 
@@ -418,11 +458,7 @@ class PagedModelRunner:
         prompt diverges INSIDE a cached block: the copy makes the shared
         prefix positions of the fork valid, and prefill resumes at the
         divergence point."""
-        t0 = time.perf_counter()
-        out = self._fork(k_pool, v_pool, src, dst)
-        self._note_compile("fork", len(src), t0)
-        self.prof.note("fork", self._fork, time.perf_counter() - t0)
-        return out
+        return self._call("fork", self._fork, len(src), k_pool, v_pool, src, dst)
 
     # -- prefill chunk -----------------------------------------------------
 
@@ -481,11 +517,8 @@ class PagedModelRunner:
         return k_pool, v_pool, logits
 
     def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table):
-        t0 = time.perf_counter()
-        out = self._prefill(
+        return self._call(
+            "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
             jnp.int32(start), jnp.int32(n_valid), table, chunk=len(tokens),
         )
-        self._note_compile("prefill", len(tokens), t0)
-        self.prof.note("prefill", self._prefill, time.perf_counter() - t0)
-        return out

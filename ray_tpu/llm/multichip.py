@@ -15,13 +15,13 @@ Megatron column/row split, chosen so the PAGED layout shards for free:
 * **Attention** — per-head math never crosses heads: q/k/v projections
   are column-parallel (each device computes its own heads), the paged
   gather/scatter and softmax run on the local head group, and only the
-  output projection is row-parallel (one ``psum`` per layer).
+  output projection is row-parallel (one reduction per layer).
 * **MLP** — ``mlp_in`` column-parallel, ``mlp_out`` row-parallel,
-  second ``psum``.  GPT-J's parallel residual lets attention and MLP
+  second reduction.  GPT-J's parallel residual lets attention and MLP
   share a single fused reduction per layer.
 * **Everything else** (embedding, layernorms, lm_head, sampling) is
-  replicated: post-``psum`` activations are identical on all devices,
-  so every device samples the same token and the engine reads one
+  replicated: reduced activations are identical on all devices, so
+  every device samples the same token and the engine reads one
   replicated result.
 
 The three jitted entry points (decode / prefill / verify) and the CoW
@@ -30,27 +30,37 @@ speculative decoding, preemption-recompute, failover ``resume_tokens``
 and the prefix cache run UNCHANGED on top; ``EngineConfig(tp=N)`` is
 the only switch.  Off-TPU this runs on jax host-platform device-count
 meshes (``XLA_FLAGS=--xla_force_host_platform_device_count``), which is
-how tier-1 exercises tp=2/4 on CPU; Pallas kernels stay interpret-gated
-per ``ops.paged_attention.INTERPRET_ONLY``.
+how tier-1 exercises tp=2/4 on CPU; on a TPU the shard bodies' ``auto``
+attention is the Mosaic-compiled paged kernel over each device's local
+heads (``ops.paged_attention.auto_impl``).  Weights and pool are born
+sharded (``param_shardings`` as the seeded init's ``out_shardings``,
+``jnp.zeros(..., device=sharding)`` for the pool): no device ever holds
+a whole leaf of either.
 
 Numerics: splitting the two row-parallel contractions across devices
 changes the floating-point reduction order, so activations drift from
 the single-chip engine by ~1 ulp per layer.  Greedy argmax and
-fixed-seed sampling are robust to that (pinned by
+fixed-seed sampling are robust to that in float32 (pinned by
 ``tests/test_llm_multichip.py``'s tp=1 vs tp=2/4 identity matrix); the
-per-head attention path itself is bitwise identical per head.
+per-head attention path itself is bitwise identical per head.  WITHIN a
+tp engine a row's result must not depend on where the row sits in a
+step's batch — the prefix cache recomputes a prompt's tail at other
+rows of the prefill chunk and promises the same tokens — so the
+partials are summed in a fixed device order (``_tp_sum``), not by
+``psum``: a TPU all-reduce adds the devices' contributions in an order
+that depends on the element's place in the buffer, which in bf16 moved
+tokens between a cold prompt and its prefix hit on four v5e chips
+(PERF.md, PR 21).
 """
 
 from __future__ import annotations
 
-import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
 from ray_tpu.llm.model_runner import (
     PagedModelRunner,
@@ -66,6 +76,43 @@ from ray_tpu.ops.paged_attention import (
     paged_verify_attention,
 )
 from ray_tpu.parallel.mesh import make_tp_mesh
+
+
+def _tp_sum(x: jax.Array, axis: str) -> jax.Array:
+    """Sum of every ``axis`` device's ``x``, identical on all of them,
+    each element added in device order in float32 whatever its row:
+    gather (exact data movement), then add."""
+    parts = jax.lax.all_gather(x, axis).astype(jnp.float32)
+    total = parts[0]
+    for i in range(1, parts.shape[0]):
+        total = total + parts[i]
+    return total.astype(x.dtype)
+
+
+def _spec_for(path) -> P:
+    """Megatron split by param path: q/k/v + mlp_in column-parallel
+    (output dim sharded, biases ride along), attn_out + mlp_out
+    row-parallel (input dim sharded, replicated biases added after
+    the reduction), everything else replicated."""
+    names = [getattr(p, "key", None) for p in path]
+    if names and names[0] == "blocks" and len(names) >= 3:
+        mod, slot = names[1], names[-1]
+        if mod in ("q", "k", "v", "attn_qkv", "mlp_in"):
+            return P(None, None, "tp") if slot == "kernel" else P(None, "tp")
+        if mod in ("attn_out", "mlp_out") and slot == "kernel":
+            return P(None, "tp", None)
+    return P()
+
+
+def param_shardings(params, tp: int):
+    """The placement the tp runner's compiled steps expect, as a tree of
+    ``NamedSharding`` matching ``params`` (arrays or abstract shapes) —
+    ``serve.llm`` hands it to its jitted seeded init as ``out_shardings``
+    so each device generates only its own shard."""
+    mesh = make_tp_mesh(tp)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _leaf: NamedSharding(mesh, _spec_for(path)), params
+    )
 
 
 def _per_device_bytes(mesh, leaves) -> dict:
@@ -106,9 +153,15 @@ class ShardedKVBlockPool(KVBlockPool):
             )
         self.tp = tp
         self._mesh = make_tp_mesh(tp)
+        # spelled without trailing Nones, the way jax spells the jitted
+        # steps' OUTPUT shardings: the pool a step hands back must compare
+        # equal to the one it was given, or every jit site grows a second
+        # cache entry on its next call and the retrace detector
+        # (util.device_prof reads the jit cache size) reports a recompile
+        # that never happened
         super().__init__(
             cfg, n_layers, n_heads, head_dim, dtype,
-            sharding=NamedSharding(self._mesh, P(None, None, "tp", None, None)),
+            sharding=NamedSharding(self._mesh, P(None, None, "tp")),
         )
 
     def per_device_bytes(self) -> dict:
@@ -150,7 +203,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         # never traced); donation contract is the base class's — the
         # pool shards update in place
         self._decode = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._decode_shard,
                 mesh=self._mesh,
                 in_specs=(
@@ -169,7 +222,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             donate_argnums=(1, 2),
         )
         self._verify = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._verify_shard,
                 mesh=self._mesh,
                 in_specs=(
@@ -188,7 +241,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             donate_argnums=(1, 2),
         )
         self._prefill = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._prefill_shard,
                 mesh=self._mesh,
                 in_specs=(
@@ -209,7 +262,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         # CoW fork copies whole blocks along axis 1 — head-agnostic, so
         # the single-chip impl runs per-shard unchanged
         self._fork = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _fork_impl,
                 mesh=self._mesh,
                 in_specs=(
@@ -228,23 +281,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
     # -- parameter placement ----------------------------------------------
 
-    def _spec_for(self, path) -> P:
-        """Megatron split by param path: q/k/v + mlp_in column-parallel
-        (output dim sharded, biases ride along), attn_out + mlp_out
-        row-parallel (input dim sharded, replicated biases added after
-        the psum), everything else replicated."""
-        names = [getattr(p, "key", None) for p in path]
-        if names and names[0] == "blocks" and len(names) >= 3:
-            mod, slot = names[1], names[-1]
-            if mod in ("q", "k", "v", "attn_qkv", "mlp_in"):
-                return P(None, None, "tp") if slot == "kernel" else P(None, "tp")
-            if mod in ("attn_out", "mlp_out") and slot == "kernel":
-                return P(None, "tp", None)
-        return P()
-
     def _param_spec_tree(self):
         return jax.tree_util.tree_map_with_path(
-            lambda path, _leaf: self._spec_for(path), self.params
+            lambda path, _leaf: _spec_for(path), self.params
         )
 
     def _shuffle_qkv(self, x: jax.Array) -> jax.Array:
@@ -268,7 +307,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         ``update_weights`` hot-swap path and __init__ share it, so a
         swap lands with the exact placement the compiled steps expect
         (no silent retrace; RL024's runtime twin watches this)."""
-        new = jax.tree_util.tree_map(jnp.asarray, params)
+        # leaves go host -> their shards directly: staging a whole leaf on
+        # the default device first would not fit a model tp exists for
+        new = params
         if self.arch == "gpt":
             new = dict(new)
             blocks = dict(new["blocks"])
@@ -279,7 +320,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             new["blocks"] = blocks
         return jax.tree_util.tree_map_with_path(
             lambda path, leaf: jax.device_put(
-                leaf, NamedSharding(self._mesh, self._spec_for(path))
+                leaf, NamedSharding(self._mesh, _spec_for(path))
             ),
             new,
         )
@@ -297,8 +338,8 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         """One transformer layer on THIS device's head/ff shard.
         ``attend(q, k_l, v_l) -> (rows, local_d)`` supplies the step
         shape's paged attention over the local head group; the two
-        row-parallel projections produce partial sums reduced with
-        ``psum`` over "tp" (replicated biases added once, after)."""
+        row-parallel projections produce partial sums reduced over
+        "tp" by ``_tp_sum`` (replicated biases added once, after)."""
         dt = x.dtype
         if self.arch == "gptj":
             h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
@@ -316,7 +357,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             # sequential-residual arch below)
             out = (
                 x
-                + jax.lax.psum(att_p + mlp_p, "tp")
+                + _tp_sum(att_p + mlp_p, "tp")
                 + layer["mlp_out"]["bias"].astype(dt)
             )
         else:
@@ -327,7 +368,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             att_p = attend(q, k_l, v_l) @ layer["attn_out"]["kernel"].astype(dt)
             h = (
                 x
-                + jax.lax.psum(att_p, "tp")
+                + _tp_sum(att_p, "tp")
                 + layer["attn_out"]["bias"].astype(dt)
             )
             ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
@@ -337,14 +378,14 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             )
             out = (
                 h
-                + jax.lax.psum(mid @ layer["mlp_out"]["kernel"].astype(dt), "tp")
+                + _tp_sum(mid @ layer["mlp_out"]["kernel"].astype(dt), "tp")
                 + layer["mlp_out"]["bias"].astype(dt)
             )
         return out, k_l, v_l
 
     # -- shard bodies ------------------------------------------------------
     # Same control flow as the PagedModelRunner._*_impl bodies, with the
-    # pool/head math local and the reductions explicit.  Post-psum
+    # pool/head math local and the reductions explicit.  Reduced
     # activations are replicated, so lm_head + sampling run identically
     # on every device and the P() out_specs read one copy.
 
@@ -467,11 +508,8 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
     def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table):
         # base passes chunk= as a static kwarg; the shard body derives it
-        t0 = time.perf_counter()
-        out = self._prefill(
+        return self._call(
+            "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
             jnp.int32(start), jnp.int32(n_valid), table,
         )
-        self._note_compile("prefill", len(tokens), t0)
-        self.prof.note("prefill", self._prefill, time.perf_counter() - t0)
-        return out

@@ -217,9 +217,10 @@ def _block(cfg: GPTConfig, x, layer, mesh=None):
 
         att = ring_attention_sharded(heads(q), heads(k), heads(v), mesh)
     else:
-        from ray_tpu.ops.flash_attention import _interpret, flash_shardable
+        from ray_tpu.ops.attention import auto_impl
+        from ray_tpu.ops.flash_attention import flash_shardable
 
-        want_flash = impl == "flash" or (impl == "auto" and not _interpret())
+        want_flash = impl == "flash" or (impl == "auto" and auto_impl(s) == "flash")
         if (
             want_flash
             and mesh is not None

@@ -12,8 +12,8 @@ no attention op of its own — compute is user torch code; this is part of the
   (``ops/ring_attention.py``), selected by the model layer when the mesh
   shards sequence.
 
-``auto`` picks flash whenever the shape tiles cleanly (TPU: always for the
-model shapes here; other backends run the same kernels interpreted).
+``auto`` follows ONE rule, ``auto_impl``: flash on a TPU backend when the
+sequence tiles the kernel's 128-lane blocks, else XLA.
 """
 
 from __future__ import annotations
@@ -33,6 +33,18 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def auto_impl(seq: int) -> str:
+    """THE ``impl="auto"`` rule, by platform and shape (``models.gpt``
+    applies the same one before it picks the shard_map'd kernel):
+    ``"flash"`` on a TPU backend when ``seq`` tiles the kernel's
+    128-lane blocks, else ``"xla"``.  Off a TPU the Pallas kernel could
+    only run interpreted — orders of magnitude slower than compiled XLA
+    — so ``auto`` never picks it there."""
+    if jax.default_backend() == "tpu" and seq >= 128 and seq % 128 == 0:
+        return "flash"
+    return "xla"
+
+
 def causal_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto"
 ) -> jax.Array:
@@ -48,15 +60,8 @@ def causal_attention(
         )
     if impl == "xla":
         return _xla_attention(q, k, v)
-    seq = q.shape[2]
-    if impl == "auto":
-        from ray_tpu.ops.flash_attention import _interpret
-
-        if seq < 128 or seq % 128 or _interpret():
-            # ragged shapes can't tile the Pallas grid, and off-TPU the
-            # kernel would run interpreted (orders of magnitude slower than
-            # compiled XLA) — auto only picks flash where it wins
-            return _xla_attention(q, k, v)
+    if impl == "auto" and auto_impl(q.shape[2]) == "xla":
+        return _xla_attention(q, k, v)
     from ray_tpu.ops.flash_attention import flash_attention
 
     return flash_attention(q, k, v)

@@ -16,8 +16,8 @@ Causally-dead (q, kv) cells are skipped with ``pl.when``.
 TPU tiling notes: per-row stats (logsumexp, delta) live as ``(bh, 8, seq)``
 — value broadcast over 8 sublanes so the (sublane, lane) block shape
 ``(8, block_q)`` satisfies Mosaic's (8, 128) fp32 tile constraint. Sequence
-lengths must tile by 128 on the TPU path (the public entry falls back to the
-XLA implementation otherwise).
+lengths must tile by 128 on the TPU path (the public entry raises
+otherwise; ``ops.attention.auto_impl`` routes such shapes to XLA).
 
 This is the hot op behind ``ray_tpu.ops.attention.causal_attention`` — the
 reference has no attention kernel of its own (user torch code runs inside
@@ -347,8 +347,8 @@ def flash_attention(
     blockwise-recompute backward). Forward and backward block shapes tune
     independently (the dQ/dKV kernels have different reuse patterns than the
     forward); defaults are overridable via RAY_TPU_FLASH_{BQ,BK,BQB,BKB} for
-    sweeps. On TPU, seq must tile by 128 (Mosaic lane constraint) — falls
-    back to the XLA path otherwise; interpret mode (CPU CI) accepts any
+    sweeps. On TPU the blocks must tile by 128 (Mosaic lane constraint) —
+    anything else raises; interpret mode (CPU CI) accepts any
     power-of-two-friendly blocking.
     """
     b, h, s, d = q.shape
@@ -367,14 +367,14 @@ def flash_attention(
     )
     bq, bk = _pick_blocks(s, block_q, block_k)
     bqb, bkb = _pick_blocks(s, block_q_bwd, block_k_bwd)
-    # gate polarity matters to raylint RL022: `not _interpret() and ...`
-    # only skips the pallas path ON TPU with bad tiling — off-TPU CI still
-    # exercises the kernel interpreted, so no INTERPRET_ONLY entry is due
-    # here (contrast ops/paged_attention.py, which routes AWAY off-TPU)
     if not _interpret() and (bq % 128 or bk % 128 or bqb % 128 or bkb % 128):
-        from ray_tpu.ops.attention import _xla_attention
-
-        return _xla_attention(q, k, v)
+        # never a silent change of implementation: ``auto`` callers are
+        # routed by ``ops.attention.auto_impl`` before they get here
+        raise ValueError(
+            f"flash attention on TPU needs blocks that tile by 128 (Mosaic "
+            f"lane constraint); seq={s} picked fwd {bq}x{bk}, bwd {bqb}x{bkb} "
+            "— use impl='xla' for this shape"
+        )
     merge = lambda t: t.reshape(b * h, s, d)  # noqa: E731
     out = _flash_core(merge(q), merge(k), merge(v), bq, bk, bqb, bkb)
     return out.reshape(b, h, s, d)
@@ -409,9 +409,7 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, mesh) -> j
             f"tp={mesh.shape.get('tp', 1)}"
         )
     spec = P(("dp", "fsdp"), "tp", None, None)
-    from ray_tpu._private.jax_compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         flash_attention, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )

@@ -30,16 +30,20 @@ interchangeable paths behind one signature (same contract as
 * ``xla``    — gather the table's blocks into a dense (slots, heads,
   table*block, d) view, masked softmax.  The reference path; also what
   multi-chip pjit partitions cleanly.
-* ``pallas`` — a scalar-prefetch Pallas kernel: grid (slot, logical
-  block), the block table is prefetched so each step DMAs exactly its
-  physical KV block from HBM, online-softmax accumulation across the
-  minor (block) grid dimension.  No (slots, table*block) score matrix
-  and no gathered cache copy ever materializes.  Runs interpreted
-  off-TPU so CPU CI exercises the same code path (parity tests:
-  ``tests/test_llm_engine.py``, ``tests/test_llm_spec.py``).
+* ``pallas`` — ONE scalar-prefetch Pallas kernel (decode is the verify
+  kernel at window width 1): grid (slot, logical block), the block
+  table is prefetched so each step DMAs exactly its physical KV block
+  from HBM, online-softmax accumulation across the minor (block) grid
+  dimension.  No (slots, table*block) score matrix and no gathered
+  cache copy ever materializes.  Compiled by Mosaic on a TPU backend
+  (parity against the XLA path at the served shapes: ``chip_smoke.py``'s
+  kernel phase); interpreted everywhere else, which only tests that ask
+  for ``impl="pallas"`` reach (``tests/test_llm_engine.py``,
+  ``tests/test_llm_spec.py``).
 
-``auto`` picks the Pallas kernel on TPU when the shapes tile the MXU
-(block_size a multiple of 8, head_dim of 128), else XLA.
+``auto`` follows ONE rule, ``auto_impl``: the Pallas kernel on a TPU
+backend when the pool tiles (block_size a multiple of 8, head_dim of
+128), else XLA.
 
 Convention: table entries past a sequence's allocation MUST point at a
 valid physical block (the engine pads with block 0, its reserved trash
@@ -60,46 +64,24 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-#: RL022-verified registry of pallas wrappers whose COMPILED path is
-#: currently unexercised: the auto dispatcher routes to the XLA path
-#: wherever ``_interpret()`` is on — i.e. exactly where CI runs — so the
-#: kernels below have zero compiled-TPU validation coverage. Each entry
-#: is acknowledged validation debt (the ROADMAP's real-TPU tiling
-#: validation item); un-gating a kernel makes its entry stale and the
-#: lint forces it to be retired with the debt.
-INTERPRET_ONLY = (
-    "_paged_pallas: decode kernel's MXU tiling (block_size % 8, d % 128)"
-    " is unvalidated on real TPUs — auto dispatch falls back to XLA"
-    " off-TPU (ROADMAP real-TPU validation item)",
-    "_paged_verify_pallas: verify kernel rides the same gating; the"
-    " small window dim's tiling is unvalidated on real TPUs (ROADMAP"
-    " real-TPU validation item)",
-)
-
-# Tensor-parallel (llm.multichip) tiling notes for the real-TPU
-# follow-up.  Under ``EngineConfig(tp=N)`` these kernels run INSIDE a
-# shard_map body: the pool and query tensors they see carry
-# ``n_heads // tp`` LOCAL heads (the head axis is sharded
-# ``P(None, None, "tp", None, None)``), everything else — block_size,
-# head_dim, the block tables — is unchanged.  Consequences for the
-# compiled path when the gates above are retired:
-#   * the MXU constraints are per-head (block_size % 8, head_dim % 128),
-#     so head-sharding does not change any tile shape — a kernel that
-#     tiles at tp=1 tiles at any tp;
-#   * the head axis is the kernel grid's embarrassingly-parallel dim;
-#     shrinking it tp-fold shrinks the grid, so per-device occupancy
-#     drops for configs with few heads (e.g. 8 heads at tp=4 leaves a
-#     2-wide grid) — prefer fusing heads into the batch grid dim before
-#     validating small-head configs;
-#   * no collective runs inside the kernel: the tp psum happens in the
-#     caller (multichip._tp_layer) AFTER the attention output
-#     projection, so the Pallas body needs no REMOTE dma / barrier
-#     semantics and interpret-mode parity on host devices remains a
-#     faithful oracle for the sharded path.
+def auto_impl(block_size: int, head_dim: int) -> str:
+    """THE ``impl="auto"`` rule, by platform and shape, for decode and
+    verify alike: ``"pallas"`` on a TPU backend when the per-head KV
+    block ``(block_size, head_dim)`` tiles (sublanes by 8, lanes by
+    128), else ``"xla"``.  Off a TPU the kernel could only run
+    interpreted — orders of magnitude slower than compiled XLA — so
+    ``auto`` never picks it there.  The rule is per head: under
+    ``EngineConfig(tp=N)`` the kernel runs inside a shard_map body on
+    ``n_heads // tp`` local heads with every tile shape unchanged, and
+    the tp psum happens in the caller after the output projection, so
+    the kernel needs no collective."""
+    if _on_tpu() and block_size % 8 == 0 and head_dim % 128 == 0:
+        return "pallas"
+    return "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -200,107 +182,6 @@ def paged_verify_attention_xla(
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(
-    # scalar prefetch
-    tables_ref,   # (slots * tmax,) int32 — flattened block tables
-    lengths_ref,  # (slots,) int32
-    # blocked inputs
-    q_ref,        # (1, heads, d)
-    k_ref,        # (1, heads, block, d) — THE slot's j-th physical block
-    v_ref,
-    # blocked output
-    o_ref,        # (1, heads, d)
-    # scratch (carried across the minor grid dim)
-    acc_ref,      # (heads, d) f32
-    m_ref,        # (heads, 1) f32
-    l_ref,        # (heads, 1) f32
-    *,
-    block_size: int,
-    scale: float,
-):
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = lengths_ref[s]
-
-    @pl.when(j * block_size < length)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)            # (heads, d)
-        k = k_ref[0].astype(jnp.float32)            # (heads, block, d)
-        v = v_ref[0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k,
-            (((1,), (2,)), ((0,), (0,))),           # contract d, batch heads
-            preferred_element_type=jnp.float32,
-        ) * scale                                    # (heads, block)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1
-        )
-        scores = jnp.where(pos < length, scores, NEG_INF)
-
-        m_prev = m_ref[...]                          # (heads, 1)
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)              # (heads, 1)
-        p = jnp.exp(scores - m_new)                  # (heads, block)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            (((1,), (1,)), ((0,), (0,))),            # contract block, batch heads
-            preferred_element_type=jnp.float32,
-        )                                            # (heads, d)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-
-    @pl.when(j == n_blocks - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-
-
-def _paged_pallas(q, k_pool, v_pool, block_tables, lengths):
-    slots, heads, d = q.shape
-    _, _, block_size, _ = k_pool.shape
-    tmax = block_tables.shape[1]
-    scale = d**-0.5
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        # minor (block) dimension executes sequentially on TPU, so the
-        # online-softmax scratch carries across a slot's kv blocks
-        grid=(slots, tmax),
-        in_specs=[
-            pl.BlockSpec((1, heads, d), lambda s, j, tbl, lens: (s, 0, 0)),
-            pl.BlockSpec(
-                (1, heads, block_size, d),
-                lambda s, j, tbl, lens: (tbl[s * tmax + j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, heads, block_size, d),
-                lambda s, j, tbl, lens: (tbl[s * tmax + j], 0, 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, heads, d), lambda s, j, tbl, lens: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((heads, d), jnp.float32),
-            pltpu.VMEM((heads, 1), jnp.float32),
-            pltpu.VMEM((heads, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=block_size, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, heads, d), q.dtype),
-        interpret=_interpret(),
-    )(block_tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
-
-
 def _paged_verify_kernel(
     # scalar prefetch
     tables_ref,   # (slots * tmax,) int32 — flattened block tables
@@ -377,6 +258,8 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
     scale = d**-0.5
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
+        # minor (block) dimension executes sequentially on TPU, so the
+        # online-softmax scratch carries across a slot's kv blocks
         grid=(slots, tmax),
         in_specs=[
             pl.BlockSpec((1, w, heads, d), lambda s, j, tbl, pos: (s, 0, 0, 0)),
@@ -404,7 +287,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, w, heads, d), q.dtype),
-        interpret=_interpret(),
+        interpret=not _on_tpu(),
     )(block_tables.reshape(-1).astype(jnp.int32),
       positions.reshape(-1).astype(jnp.int32),
       q, k_pool, v_pool)
@@ -413,6 +296,18 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
 # ---------------------------------------------------------------------------
 # dispatcher
 # ---------------------------------------------------------------------------
+
+
+def _resolve_impl(impl: str, k_pool: jax.Array) -> str:
+    if impl not in ("auto", "xla", "pallas"):
+        raise ValueError(
+            f"unknown paged attention impl {impl!r}; expected 'auto', 'xla' "
+            "or 'pallas'"
+        )
+    if impl == "auto":
+        _, _, block_size, d = k_pool.shape
+        return auto_impl(block_size, d)
+    return impl
 
 
 def paged_attention(
@@ -429,20 +324,12 @@ def paged_attention(
     block_size, head_dim); block_tables: (slots, tmax) int32; lengths:
     (slots,) int32.  ``impl``: auto | xla | pallas.
     """
-    if impl not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"unknown paged attention impl {impl!r}; expected 'auto', 'xla' "
-            "or 'pallas'"
-        )
-    if impl == "xla":
+    if _resolve_impl(impl, k_pool) == "xla":
         return paged_attention_xla(q, k_pool, v_pool, block_tables, lengths)
-    if impl == "auto":
-        _, _, block_size, d = k_pool.shape
-        # off-TPU the kernel would run interpreted (orders of magnitude
-        # slower than compiled XLA); on TPU it needs MXU-friendly tiling
-        if _interpret() or block_size % 8 or d % 128:
-            return paged_attention_xla(q, k_pool, v_pool, block_tables, lengths)
-    return _paged_pallas(q, k_pool, v_pool, block_tables, lengths)
+    # decode is a verify window of width 1 whose query sits at length - 1
+    return _paged_verify_pallas(
+        q[:, None], k_pool, v_pool, block_tables, (lengths - 1)[:, None]
+    )[:, 0]
 
 
 def paged_verify_attention(
@@ -461,19 +348,6 @@ def paged_verify_attention(
     block_size, head_dim); block_tables: (slots, tmax) int32; positions:
     (slots, w) int32.  ``impl``: auto | xla | pallas.
     """
-    if impl not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"unknown paged attention impl {impl!r}; expected 'auto', 'xla' "
-            "or 'pallas'"
-        )
-    if impl == "xla":
+    if _resolve_impl(impl, k_pool) == "xla":
         return paged_verify_attention_xla(q, k_pool, v_pool, block_tables, positions)
-    if impl == "auto":
-        _, _, block_size, d = k_pool.shape
-        # same gating as paged_attention; real-TPU tiling of the small
-        # window dim rides the same validation item (ROADMAP)
-        if _interpret() or block_size % 8 or d % 128:
-            return paged_verify_attention_xla(
-                q, k_pool, v_pool, block_tables, positions
-            )
     return _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions)

@@ -54,9 +54,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str = "s
     axis and q,k,v are the LOCAL (batch, heads, seq/sp, head_dim) slices,
     sharded contiguously in sequence order.
     """
-    from ray_tpu._private.jax_compat import axis_size
-
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
     scale = 1.0 / (d**0.5)
@@ -100,9 +98,7 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, mesh) -> ja
     with batch over (dp,fsdp), heads over tp, seq over sp. Usable inside jit
     (e.g. from the GPT block under pjit)."""
     spec = P(("dp", "fsdp"), "tp", "sp", None)
-    from ray_tpu._private.jax_compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp"),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -109,9 +109,7 @@ def pipeline_apply(
         out = jax.lax.psum(out, axis_name)
         return out
 
-    from ray_tpu._private.jax_compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(param_spec, in_spec),

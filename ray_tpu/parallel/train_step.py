@@ -16,6 +16,7 @@ import jax
 import optax
 from jax.sharding import NamedSharding
 
+from ray_tpu._private.compile_cache import ensure_compile_cache
 from ray_tpu.parallel.sharding import batch_spec, param_sharding_rules
 
 
@@ -75,6 +76,7 @@ def make_step_fn(loss_fn, optimizer, mesh):
         params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
+    ensure_compile_cache()
     return jax.jit(
         step,
         in_shardings=(None, NamedSharding(mesh, batch_spec())),

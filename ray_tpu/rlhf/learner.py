@@ -47,8 +47,11 @@ from ray_tpu.rl.learner import LearnerGroup
 class GPTPolicyModule:
     """Adapter giving ``rl.learner.Learner`` the two hooks it needs.
     ``init`` delegates to ``gpt_init`` — the same function rollout
-    engines use (``serve.llm._build_model``), so a learner and a worker
-    seeded alike start bit-identical at version 0."""
+    engines use (``serve.llm._build_model``, which runs it under one jit
+    so a large model is born in its compute dtype and placement), so a
+    learner and a worker seeded alike start from the same weights at
+    version 0, to the compiler's last-place rounding of the init
+    arithmetic; the first weight push makes them bit-identical."""
 
     def __init__(self, cfg: GPTConfig):
         self.cfg = cfg

@@ -28,8 +28,17 @@ from ray_tpu.serve._private.common import (
 
 RECONCILE_PERIOD_S = 0.25
 #: A replica that has not finished __init__ (answered its first health
-#: check) within this window is declared failed and replaced.
-REPLICA_INIT_TIMEOUT_S = 120.0
+#: check) within this window is declared failed, killed and replaced;
+#: ``serve.run`` waits the same window for readiness.  Sized from the
+#: slowest init the repo has: an LLM replica generates its weights and
+#: compiles every engine step in __init__ (``chip_smoke.py`` prints the
+#: measured time; PERF.md records it), and a cold persistent compile
+#: cache must fit with room to spare.
+REPLICA_INIT_TIMEOUT_S = 600.0
+#: Replica constructors that raised in a row, with none succeeding in
+#: between, before ``serve.run`` gives up on the deployment and raises the
+#: last one's error: one failure may be transient, three are the code.
+MAX_INIT_FAILURES = 3
 
 
 def desired_replicas(
@@ -79,6 +88,12 @@ class _DeploymentState:
         # downscale victims draining in-flight requests: (ReplicaInfo,
         # kill-deadline) — out of the routing set, not yet killed
         self.draining: list[tuple[ReplicaInfo, float]] = []
+        #: replica __init__ failures in a row and the last one's repr —
+        #: reset when a replica initializes and on every (re)deploy; at
+        #: MAX_INIT_FAILURES ``serve.run`` raises instead of waiting out
+        #: the init window on a restart loop
+        self.init_failures = 0
+        self.init_error: Optional[str] = None
         # autoscaling bookkeeping
         self._scale_pressure_since: Optional[float] = None
         self._scale_direction = 0
@@ -131,6 +146,8 @@ class ServeController:
                 existing = self._deployments.get(spec.name)
                 if existing is not None:
                     existing.spec = spec
+                    # the new spec's replicas are judged on their own
+                    existing.init_failures, existing.init_error = 0, None
                     if spec.config.autoscaling_config is None:
                         existing.target_replicas = spec.config.num_replicas
                     for r in existing.replicas:  # push new user_config live
@@ -262,6 +279,10 @@ class ServeController:
                 "exists": True,
                 "target_replicas": state.target_replicas,
                 "running_replicas": len([r for r in state.replicas if r.healthy]),
+                "init_error": (
+                    state.init_error
+                    if state.init_failures >= MAX_INIT_FAILURES else None
+                ),
                 "replica_ids": [r.replica_id for r in state.replicas],
             }
 
@@ -416,6 +437,7 @@ class ServeController:
                             try:
                                 ray_tpu.get(r.init_ref, timeout=5.0)
                                 r.initialized = True
+                                state.init_failures, state.init_error = 0, None
                                 _events.record(
                                     "serve.replica_initialized",
                                     replica=r.replica_id,
@@ -424,6 +446,8 @@ class ServeController:
                                 self._bump_version_locked()  # routers may now use it
                             except Exception as e:
                                 r.healthy = False  # __init__ or first ping failed
+                                state.init_failures += 1
+                                state.init_error = repr(e)
                                 _events.record(
                                     "serve.replica_unhealthy",
                                     replica=r.replica_id,
@@ -451,6 +475,13 @@ class ServeController:
                 if dead:
                     state.replicas = [r for r in state.replicas if r.healthy]
                     self._bump_version_locked()
+                for r in dead:
+                    # its process may be wedged but alive, still holding
+                    # the accelerator the replacement is about to open
+                    try:
+                        ray_tpu.kill(r.actor)
+                    except Exception:  # raylint: disable=RL007
+                        pass  # best-effort teardown: it may already be dead
                 # start missing
                 missing = state.target_replicas - len(state.replicas)
                 for _ in range(max(0, missing)):

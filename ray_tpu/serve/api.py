@@ -218,8 +218,6 @@ def run(
     proxy ingress is up (``GET/POST /<name>`` with a JSON body);
     ``grpc=True`` the gRPC ingress (``ray.serve.GenericService/Predict``
     with ``application`` metadata — see _private/grpc_proxy.py)."""
-    import time
-
     controller = _get_or_start_controller()
     specs, ingress = _collect_specs(app, name)
     ray_tpu.get(controller.deploy_application.remote(name, specs), timeout=120)
@@ -232,12 +230,34 @@ def run(
             controller.ensure_grpc_proxy.remote(int(grpc_port or 0)), timeout=120
         )
     if _blocking:
-        deadline = time.time() + 120
-        while not ray_tpu.get(controller.ready.remote(), timeout=30):
-            if time.time() > deadline:
-                raise TimeoutError("Serve application failed to become ready")
-            time.sleep(0.1)
+        _wait_ready(controller, [spec.name for spec in specs])
     return DeploymentHandle(ingress)
+
+
+def _wait_ready(controller, deployment_names: list) -> None:
+    """Block until every deployment has its replicas initialized — for as
+    long as the controller itself gives a replica to initialize — or
+    raise what the replicas' ``__init__`` raised once it has failed
+    ``MAX_INIT_FAILURES`` times in a row: waiting out minutes of restart
+    loop tells the caller nothing."""
+    import time
+
+    from ray_tpu.serve._private.controller import REPLICA_INIT_TIMEOUT_S
+
+    deadline = time.time() + REPLICA_INIT_TIMEOUT_S
+    while not ray_tpu.get(controller.ready.remote(), timeout=30):
+        for name in deployment_names:
+            st = ray_tpu.get(
+                controller.get_deployment_status.remote(name), timeout=30
+            )
+            if st.get("init_error"):
+                raise RuntimeError(
+                    f"replicas of {name} keep failing to initialize: "
+                    f"{st['init_error']}"
+                )
+        if time.time() > deadline:
+            raise TimeoutError("Serve application failed to become ready")
+        time.sleep(0.1)
 
 
 def run_config(config: "dict | str", _blocking: bool = True) -> dict:
@@ -283,6 +303,7 @@ def run_config(config: "dict | str", _blocking: bool = True) -> dict:
         raise ValueError("serve config must be a mapping with an 'applications' list")
 
     handles: dict[str, str] = {}
+    deployed: list[str] = []
     http_port = (config.get("proxy") or {}).get("port")
     for app_cfg in config["applications"]:
         app_name = app_cfg.get("name", "default")
@@ -334,18 +355,12 @@ def run_config(config: "dict | str", _blocking: bool = True) -> dict:
             spec.config = cfg
         ray_tpu.get(controller.deploy_application.remote(app_name, specs), timeout=120)
         handles[app_name] = ingress
+        deployed.extend(spec.name for spec in specs)
     if http_port is not None:
         controller = _get_or_start_controller()
         ray_tpu.get(controller.ensure_proxy.remote(int(http_port)), timeout=120)
     if _blocking:
-        import time
-
-        controller = _get_or_start_controller()
-        deadline = time.time() + 120
-        while not ray_tpu.get(controller.ready.remote(), timeout=30):
-            if time.time() > deadline:
-                raise TimeoutError("Serve applications failed to become ready")
-            time.sleep(0.1)
+        _wait_ready(_get_or_start_controller(), deployed)
     return handles
 
 

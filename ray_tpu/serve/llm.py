@@ -35,30 +35,52 @@ from ray_tpu.llm.engine import EngineConfig, LLMEngine
 from ray_tpu.llm.scheduler import SamplingParams
 
 
-def _build_model(model: str, model_cfg, params, seed: int):
+def _seeded_params(init, cfg, seed: int, tp: int):
+    """Seeded random weights made by ONE jitted program, in the model's
+    compute dtype, straight into the placement the runner uses.  The fp32
+    masters ``init`` describes never materialize (GPT-J-6B's would be
+    24 GB on a 16 GB chip): XLA fuses each leaf's generation with its
+    cast, and under ``tp > 1`` the tp placement as ``out_shardings``
+    makes every device generate only its own shard."""
+    import jax
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(cfg.dtype)
+
+    def make():
+        params = init(jax.random.PRNGKey(seed), cfg)
+        return jax.tree_util.tree_map(lambda x: x.astype(dt), params)
+
+    shardings = None
+    if tp > 1:
+        from ray_tpu.llm.multichip import param_shardings
+
+        shardings = param_shardings(jax.eval_shape(make), tp)
+    return jax.jit(make, out_shardings=shardings)()
+
+
+def _build_model(model: str, model_cfg, params, seed: int, tp: int = 1):
     """Materialize (cfg, params) inside the replica — shipping a seed
     instead of a parameter pytree keeps deployment specs small and lets
-    each replica initialize straight onto its own device."""
-    import jax
-
+    each replica initialize straight onto its own device(s)."""
     if model == "gptj":
         from ray_tpu.models.gptj import GPTJ_6B, GPTJConfig, gptj_init
 
         cfg = model_cfg or GPTJ_6B
         if not isinstance(cfg, GPTJConfig):
             raise TypeError(f"model_cfg must be a GPTJConfig, got {type(cfg).__name__}")
-        if params is None:
-            params = gptj_init(jax.random.PRNGKey(seed), cfg)
+        init = gptj_init
     elif model == "gpt":
         from ray_tpu.models.gpt import GPTConfig, gpt_init
 
         cfg = model_cfg or GPTConfig()
         if not isinstance(cfg, GPTConfig):
             raise TypeError(f"model_cfg must be a GPTConfig, got {type(cfg).__name__}")
-        if params is None:
-            params = gpt_init(jax.random.PRNGKey(seed), cfg)
+        init = gpt_init
     else:
         raise ValueError(f"unknown model family {model!r}; expected 'gptj' or 'gpt'")
+    if params is None:
+        params = _seeded_params(init, cfg, seed, tp)
     return cfg, params
 
 
@@ -78,7 +100,8 @@ class LLMDeployment:
         draft_model_cfg=None,
         draft_params: Optional[dict] = None,
     ):
-        cfg, params = _build_model(model, model_cfg, params, seed)
+        tp = engine_config.tp if engine_config is not None else 1
+        cfg, params = _build_model(model, model_cfg, params, seed, tp)
         # speculative decoding with the small-model drafter
         # (engine_config.spec_drafter == "model"): the draft model's
         # config + params pass straight through to the engine; the
@@ -248,6 +271,21 @@ class LLMDeployment:
 
     def stats(self) -> dict:
         return self._engine.stats()
+
+    def audit(self) -> dict:
+        """The KV-pool ledger and prefix-tree audits the watchdog runs
+        every tick (``KVBlockPool.audit`` / ``PrefixCache.audit``), on
+        demand."""
+        cache = self._engine.prefix_cache
+        return {
+            "pool": self._engine.pool.audit(),
+            "prefix_cache": cache.audit() if cache is not None else None,
+        }
+
+    def device_report(self) -> dict:
+        """What this replica's engine runs on and what its compiled steps
+        contain (``LLMEngine.device_report``)."""
+        return self._engine.device_report()
 
     def check_health(self) -> None:
         if not self._loop.is_alive():

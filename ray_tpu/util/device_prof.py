@@ -156,3 +156,57 @@ class JitProfiler:
     @property
     def retraces(self) -> int:
         return sum(st[2] for st in self._sites.values())
+
+
+def mosaic_kernels(lowered) -> list:
+    """Names of the Mosaic (compiled Pallas) kernels inside a lowered jit
+    program: each is a ``tpu_custom_call`` carrying its kernel function's
+    name.  An INTERPRETED ``pallas_call`` lowers to plain ops and
+    contributes nothing — an empty list where a kernel was expected means
+    the step does not run the kernel on the device."""
+    import re
+
+    return re.findall(
+        r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"', lowered.as_text()
+    )
+
+
+def device_report() -> dict:
+    """What THIS process computes on, as jax reports it: platform,
+    device_kind, device count, the jax/jaxlib/libtpu versions, each
+    device's ``memory_stats()`` (where the backend reports one) and the
+    persistent compile cache's location and hit count.  Initializes the
+    backend — call it only in a process that computes (a serve replica,
+    a train worker), never in a driver that must leave the chip free."""
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    from ray_tpu._private import compile_cache
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    devices = jax.devices()
+    memory = {}
+    for d in devices:
+        stats = d.memory_stats() or {}
+        memory[str(d.id)] = {
+            k: stats[k]
+            for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                      "peak_bytes_reserved", "bytes_limit")
+            if k in stats
+        }
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "versions": {
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu,
+        },
+        "memory": memory,
+        "compile_cache": compile_cache.stats(),
+    }

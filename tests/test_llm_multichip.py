@@ -28,9 +28,8 @@ from ray_tpu.models.gptj import GPTJConfig, gptj_init
 
 
 def _multi_device_cpu() -> bool:
-    """Same capability probe as test_spmd_contracts: this jax build lacks
-    the ``jax_num_cpu_devices`` config, so devices exist only if the
-    conftest's XLA_FLAGS landed before jax initialized."""
+    """Same capability probe as test_spmd_contracts: the devices exist
+    only if the conftest's XLA_FLAGS landed before jax initialized."""
     import jax
 
     return len(jax.devices("cpu")) >= 4
@@ -198,6 +197,24 @@ def test_audit_and_watchdog_pass_sharded(tp):
     info = EngineWatchdog(eng, stall_deadline_s=30.0).check_once()
     assert info["audit"]["ok"]
     assert not info["stalled"]
+    # the pool a step hands back compares equal to the one it was given
+    # (ShardedKVBlockPool spells its placement the way jax spells step
+    # outputs), so no jit site ever grows a second cache entry
+    assert eng.runner.prof.retraces == 0
+    assert all(s["cache_size"] == 1 for s in eng.runner.prof.stats().values())
+    # the report lowers every step again from its first call's operands —
+    # sharded ones included — and that is not a retrace either
+    kernels = eng.device_report()["attention"]["mosaic_kernels"]
+    assert {"decode", "prefill"} <= set(kernels) and not any(kernels.values())
+    eng.generate([3, 1, 4, 1, 5], SamplingParams(max_tokens=4))
+    assert eng.runner.prof.retraces == 0
+    # the partials are gathered and added in device order, never psum'd:
+    # on TPU chips a bf16 all-reduce rounds a row by its place in the
+    # buffer and a prefix hit then decodes other tokens than the cold run
+    # (PERF.md, PR 21) — which no CPU run can show, so pin the program
+    fn, args, static = eng.runner._first_operands["prefill"]
+    text = fn.lower(*args, **static).as_text()
+    assert "all_gather" in text and "all_reduce" not in text
 
 
 @pytest.mark.parametrize("tp", [2, 4])

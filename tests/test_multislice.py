@@ -59,27 +59,6 @@ def test_single_slice_degenerates_to_plain_mesh():
     assert mesh.shape["dp"] == 2 and mesh.shape["tp"] == 2
 
 
-def _worker_can_size_cpu_devices() -> bool:
-    """Capability probe for the two-process DCN dryrun: each worker
-    subprocess sizes its local device count via
-    ``jax.config.update("jax_num_cpu_devices", n)``
-    (parallel/_multislice_worker.py). jax builds without that config
-    option (observed on 0.4.37 here — a documented pre-existing
-    environmental failure since PR 9) kill every worker at startup with
-    ``AttributeError: Unrecognized config option``, so the test cannot
-    exercise what it is about. The probe checks the option exists
-    without mutating anything."""
-    import jax
-
-    return hasattr(jax.config, "jax_num_cpu_devices")
-
-
-@pytest.mark.skipif(
-    not _worker_can_size_cpu_devices(),
-    reason="jax build lacks the jax_num_cpu_devices config option the "
-    "multislice worker needs (pre-existing environmental failure, "
-    "documented since PR 9)",
-)
 def test_two_process_dcn_dp():
     """REAL multi-process multislice: 2 subprocesses jax.distributed-join
     one 8-device mesh; dp gradient reduction crosses the process boundary

@@ -266,6 +266,31 @@ class TestEngineHotSwap:
         # default bumps
         assert eng.update_weights(tiny_params) == 4
 
+    def test_fp32_push_into_a_bf16_replica(self):
+        """A serve/rollout replica holds its seeded weights in the model's
+        compute dtype (``serve.llm._seeded_params``); the learner pushes
+        fp32 masters. The push is cast to the resident dtype — accepted,
+        no retrace — and decodes exactly like an engine born on the
+        cast weights."""
+        import dataclasses
+
+        from ray_tpu.serve.llm import _seeded_params
+
+        cfg = dataclasses.replace(TINY, dtype="bfloat16")
+        eng = LLMEngine(cfg, _seeded_params(gpt_init, cfg, 0, 1), ENG)
+        assert {x.dtype for x in jax.tree_util.tree_leaves(eng.runner.params)} == {
+            jnp.dtype("bfloat16")
+        }
+        sp = SamplingParams(max_tokens=8, temperature=1.0, seed=4)
+        eng.generate([2, 3, 4], sp)  # every step compiled before the push
+        masters = gpt_init(jax.random.PRNGKey(9), cfg)  # float32
+        assert eng.update_weights(jax.device_get(masters), 1) == 1
+        cast = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), masters)
+        assert eng.generate([2, 3, 4], sp) == LLMEngine(cfg, cast, ENG).generate(
+            [2, 3, 4], sp
+        )
+        assert eng.stats()["retraces"] == 0
+
 
 # ---------------------------------------------------------------------------
 # object-plane sync + rollout worker (cluster)

@@ -326,3 +326,49 @@ def test_deployment_options_and_user_config(serve_instance):
             break
         time.sleep(0.2)
     assert h.remote(3).result(timeout=30) == 21
+
+
+def test_run_raises_what_a_replica_constructor_raised(serve_instance):
+    """``serve.run`` waits as long as the controller gives a replica to
+    initialize (minutes: an LLM replica compiles in __init__) — but a
+    constructor that RAISED fails the call at once, with its error."""
+
+    @serve.deployment
+    class Broken:
+        def __init__(self):
+            raise ValueError("no weights here")
+
+        def __call__(self, x):
+            return x
+
+    t0 = time.time()
+    with pytest.raises(RuntimeError, match="no weights here"):
+        serve.run(Broken.bind(), name="broken")
+    assert time.time() - t0 < 60
+
+
+def test_fixed_redeploy_after_a_broken_one_is_judged_on_its_own(serve_instance):
+    """The constructor failures that failed one ``serve.run`` do not fail
+    the next one under the same deployment name: a redeploy resets the
+    count, and a replica that initializes clears it."""
+
+    @serve.deployment(name="phoenix")
+    class Broken:
+        def __init__(self):
+            raise ValueError("no weights here")
+
+        def __call__(self, x):
+            return x
+
+    @serve.deployment(name="phoenix")
+    class Fixed:
+        def __call__(self, x):
+            return x + 1
+
+    with pytest.raises(RuntimeError, match="no weights here"):
+        serve.run(Broken.bind(), name="phoenix")
+    h = serve.run(Fixed.bind(), name="phoenix")
+    assert h.remote(1).result(timeout=30) == 2
+    controller = ray_tpu.get_actor("SERVE_CONTROLLER")
+    st = ray_tpu.get(controller.get_deployment_status.remote(h.deployment_name), timeout=30)
+    assert st["init_error"] is None

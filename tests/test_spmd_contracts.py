@@ -17,14 +17,9 @@ import pytest
 
 def _multi_device_cpu() -> bool:
     """Capability probe: the twins need a >=2-device CPU mesh. The
-    suite's conftest forces 8 in-process CPU devices via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count`` before jax
-    initializes; it cannot use ``jax.config.update("jax_num_cpu_devices",
-    8)`` because this jax 0.4.37 build lacks that config option (the
-    documented pre-existing environmental failure since PR 9 — see
-    ``tests/test_multislice.py::_worker_can_size_cpu_devices``). The
-    probe checks the devices actually materialized, without mutating
-    anything."""
+    suite's conftest forces 8 in-process CPU devices before jax
+    initializes; the probe checks the devices actually materialized,
+    without mutating anything."""
     import jax
 
     return len(jax.devices("cpu")) >= 2
@@ -68,7 +63,6 @@ def test_rl020_bound_axis_traces_clean():
     checks, not the collective."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh(2)
@@ -76,7 +70,7 @@ def test_rl020_bound_axis_traces_clean():
     def body(x):
         return jax.lax.psum(x.sum(), "data")  # local sum, then cross-device
 
-    f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P())
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P())
     out = f(jnp.arange(4, dtype=jnp.float32))
     assert float(out) == pytest.approx(0.0 + 1.0 + 2.0 + 3.0)
 
