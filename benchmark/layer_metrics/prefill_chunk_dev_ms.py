@@ -1,0 +1,11 @@
+"""Median device time of one execution of the prefill program (one chunk
+of one sequence), from the trace's program line (first chip)."""
+
+from _common import median, trace_reduce
+
+PROGRAM = r"prefill"
+
+
+def read(run):
+    ds = trace_reduce.program_durations(run["reduced"], PROGRAM)
+    return 1e3 * median(ds) if ds else None
