@@ -286,7 +286,7 @@ def _serve(args) -> None:
         check(att["auto_rule"] == "pallas", f"auto rule says {att['auto_rule']}")
         for site in ("decode", "verify"):
             check(
-                att["mosaic_kernels"][site] == ["_paged_verify_kernel"],
+                att["mosaic_kernels"][site] == [f"paged_attention_{site}"],
                 f"{site} step holds kernels {att['mosaic_kernels'][site]}",
             )
     per_device = after["hbm"].get("per_device", {})
@@ -405,14 +405,14 @@ def phase_kernels(args) -> None:
             lambda q, k, v, t, n: pa.paged_attention(q, k, v, t, n, impl=impl),
             pa.paged_attention_xla,
             (rnd(4, (slots, heads, d)), kp, vp, tables, base + 1),
-            ["_paged_verify_kernel"],
+            ["paged_attention_decode"],
         )
         compare(
             f"paged_verify_{shape}",
             lambda q, k, v, t, p: pa.paged_verify_attention(q, k, v, t, p, impl=impl),
             pa.paged_verify_attention_xla,
             (rnd(5, (slots, w, heads, d)), kp, vp, tables, positions),
-            ["_paged_verify_kernel"],
+            ["paged_attention_verify"],
         )
 
     # flash forward + backward at the train shape, then at tp=4's heads
@@ -433,12 +433,12 @@ def phase_kernels(args) -> None:
         flash = lambda q, k, v: attention.causal_attention(q, k, v, impl=impl)  # noqa: E731
         compare(
             f"flash_fwd_b{b}_h{h}_s{s}_d{d}", flash, attention._xla_attention,
-            qkv, ["_fwd_kernel"],
+            qkv, ["flash_fwd"],
         )
         compare(
             f"flash_bwd_b{b}_h{h}_s{s}_d{d}", grads(flash),
             grads(attention._xla_attention), qkv,
-            ["_dkv_kernel", "_dq_kernel", "_fwd_kernel"],
+            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
         )
 
 
@@ -525,7 +525,7 @@ def _train(args) -> None:
     if not args.cpu_rehearsal:
         check(dev["platform"] == "tpu", f"train worker computes on {dev['platform']}")
         check(
-            m["mosaic_kernels"] == ["_dkv_kernel", "_dq_kernel", "_fwd_kernel"],
+            m["mosaic_kernels"] == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
             f"train step holds kernels {m['mosaic_kernels']}",
         )
     check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")

@@ -27,7 +27,13 @@ one scheduler.  ``step()`` is the whole design:
 Observability: every step is a ``util.tracing`` span; tokens/s, TTFT,
 inter-token latency, running/waiting counts, KV-block utilization and
 preemptions publish through ``util.metrics`` (the same surface the serve
-autoscaler and Grafana boards read).
+autoscaler and Grafana boards read).  The step also accounts for ITSELF:
+each host phase (``STEP_PHASES``) is timed once with ``perf_counter`` and
+that one timing feeds a ``jax.profiler.TraceAnnotation`` named
+``llm.step.<phase>`` (the device trace's clock: an idle gap of the chip
+gets the name of what the host was doing) and a cumulative counter in
+``stats()`` (``step_phase_s``, ``loop``, ``submit``, ``queue``: the whole
+window, not a traced slice).  OBSERVABILITY.md, "Engine step timeline".
 
 Threading: ``step()`` serializes on an internal lock — any number of
 submitter threads (serve replica handlers) can feed the engine while one
@@ -36,6 +42,7 @@ driver thread (or several, harmlessly) turns the crank.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import threading
 import time
@@ -93,6 +100,41 @@ METRIC_NAMES = (
     "llm_hbm_kv_free_bytes",
     "llm_hbm_drafter_bytes",
 )
+
+#: the host phases of one step, in the order a step runs them: the keys
+#: of ``stats()["step_phase_s"]``, each the name of its span on the
+#: profiler's clock behind ``llm.step.`` (a speculative step's
+#: ``verify_launch`` / ``verify_fetch`` spans add into the decode keys:
+#: the verify call IS that step's decode)
+STEP_PHASES = (
+    "admit", "prefill_build", "prefill_launch", "prefill_sample", "draft",
+    "decode_build", "decode_launch", "decode_fetch", "emit", "publish",
+)
+#: upper bounds (seconds) of ``stats()["loop"]["step_wall_hist"]``: a factor
+#: of √2 apart from 1 ms to 65.5 s, then one overflow bucket — a window's
+#: delta of the histogram says whether ANY step took seconds
+STEP_WALL_BOUNDS_S = tuple(0.001 * 2.0 ** (i / 2) for i in range(33))
+
+
+class _Phase:
+    """One host phase of a step: entered, it opens the phase's span on the
+    profiler's clock; left, it closes the span and adds the seconds to the
+    phase's counter.  One ``perf_counter`` pair, two readers."""
+
+    __slots__ = ("acc", "key", "ann", "t0")
+
+    def __init__(self, acc: dict, key: str, span: str):
+        self.acc, self.key = acc, key
+        self.ann = _tracing.annotate(span)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.acc[self.key] += time.perf_counter() - self.t0
+        return self.ann.__exit__(*exc)
+
 
 _METRICS = None
 _METRICS_LOCK = threading.Lock()
@@ -376,6 +418,18 @@ class LLMEngine:
         self._spec_draft_s = 0.0
         self._spec_skip = 0      # plain-decode steps left before re-probing
         self._spec_backoff = 0   # current backoff length (0 = speculating)
+        # the step's own account (stats(): step_phase_s / loop / submit):
+        # plain float adds under the lock the step or the submitter holds
+        # anyway; _loop_idle_s alone is added to outside it, by the one
+        # thread that runs run_loop
+        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self._step_wall_s = 0.0
+        self._step_wall_hist = [0] * (len(STEP_WALL_BOUNDS_S) + 1)
+        self._loop_lock_wait_s = 0.0
+        self._loop_idle_s = 0.0
+        self._submit_n = 0
+        self._submit_lock_wait_s = 0.0
+        self._submit_lock_wait_max_s = 0.0
         # liveness beat, read LOCK-FREE by the watchdog and stream_tokens'
         # stall diagnosis (a wedged step holds the engine lock, so the
         # observers must never need it): (monotonic t of the last completed
@@ -502,7 +556,23 @@ class LLMEngine:
             )
             req.stream.put(("done", done_reason))
             return req
-        with self._lock:
+        # step() holds this lock for a whole step and the loop takes it
+        # again at once, so a submitter can wait for many steps: the two
+        # stamps around the wait are the `lock` leg of the request's
+        # ledger and stats()["submit"] alike
+        t_wait = time.time()
+        with _tracing.annotate("llm.submit.lock_wait"):
+            self._lock.acquire()
+        try:
+            t_in = time.time()
+            waited = max(0.0, t_in - t_wait)
+            self._submit_n += 1
+            self._submit_lock_wait_s += waited
+            if waited > self._submit_lock_wait_max_s:
+                self._submit_lock_wait_max_s = waited
+            if req.phase_led is not None:
+                _phases.charge(req.phase_led, _phases.LOCK, t_in)
+            req.queued_t = t_in
             if self.cfg.shed and deadline_s is not None:
                 est = self._estimate_completion_s_locked(
                     params.max_tokens - len(req.out)
@@ -539,6 +609,8 @@ class LLMEngine:
                 time.monotonic() if prev_pending == 0 else t,
                 self.scheduler.num_running + self.scheduler.num_waiting,
             )
+        finally:
+            self._lock.release()
         return req
 
     def _estimate_completion_s_locked(self, new_tokens: int) -> Optional[float]:
@@ -780,6 +852,30 @@ class LLMEngine:
                 "preemptions": self._preemptions,
                 "service_rate_tokens_per_s": self._rate,
                 "weights_version": self._weights_version,
+                # wall time of THIS reading, taken under the lock: a caller
+                # can wait many steps for it, and a rate over two readings
+                # divides by the difference of these, not of the asking
+                "t_read": time.time(),
+                # the loop's wall time, split three ways (seconds, since
+                # start): inside steps that had work, waiting for the lock
+                # a submitter or a reader held, idle with nothing to do
+                "loop": {
+                    "step_wall_s": self._step_wall_s,
+                    "lock_wait_s": self._loop_lock_wait_s,
+                    "idle_s": self._loop_idle_s,
+                    "step_wall_hist": list(self._step_wall_hist),
+                    "step_wall_bounds_s": list(STEP_WALL_BOUNDS_S),
+                },
+                "step_phase_s": dict(self._phase_s),
+                "submit": {
+                    "n": self._submit_n,
+                    "lock_wait_s": self._submit_lock_wait_s,
+                    "lock_wait_max_s": self._submit_lock_wait_max_s,
+                },
+                "queue": {
+                    "admitted": self.scheduler.admit_count,
+                    "wait_s": self.scheduler.queue_wait_s,
+                },
             }
             if self.prefix_cache is not None:
                 s["prefix_cache"] = self.prefix_cache.stats()
@@ -824,19 +920,32 @@ class LLMEngine:
         in a daemon thread)."""
         while not stop.is_set():
             if not self.step():
-                stop.wait(idle_sleep_s)
+                t0 = time.perf_counter()
+                with _tracing.annotate("llm.loop.idle"):
+                    stop.wait(idle_sleep_s)
+                self._loop_idle_s += time.perf_counter() - t0
 
     # -- the step ----------------------------------------------------------
 
+    def _phase(self, key: str, span: Optional[str] = None) -> _Phase:
+        """``with self._phase("admit"):`` — the span ``llm.step.admit`` on
+        the profiler's clock and the seconds into ``step_phase_s["admit"]``
+        (``span`` where the two differ: the verify path)."""
+        return _Phase(self._phase_s, key, "llm.step." + (span or key))
+
     def step(self) -> bool:
         """One engine iteration; returns True when any work was done."""
-        from ray_tpu.util import tracing
-
-        with self._lock:
+        t_wait = time.perf_counter()
+        with _tracing.annotate("llm.loop.lock_wait"):
+            self._lock.acquire()
+        try:
+            t_in = time.perf_counter()
+            self._loop_lock_wait_s += t_in - t_wait
             sched = self.scheduler
             if not sched.has_work():
                 self._publish_gauges()
                 self._beat = (time.monotonic(), 0)
+                self._loop_idle_s += time.perf_counter() - t_in
                 return False
             self._step_n += 1
             m = _metrics()
@@ -849,32 +958,41 @@ class LLMEngine:
                 running=sched.num_running,
                 waiting=sched.num_waiting,
             )
-            if self._drafter is not None:
-                attrs["spec"] = spec_info
-            with tracing.span("llm_engine_step", **attrs):
-                self._reap()
-                sched.admit()
-                self._apply_cow()
-                did = self._prefill_one()
-                if self._drafter is not None and self._spec_skip == 0:
-                    did = self._spec_decode_all(spec_info) or did
-                else:
-                    did_decode = self._decode_all()
-                    if did_decode and self._spec_skip > 0:
-                        self._spec_skip -= 1  # backoff ticks on real decodes
-                    did = did_decode or did
-            # prune finished requests: the registry otherwise retains every
-            # Request (prompt, output, stream queue) for the replica's
-            # lifetime. Callers keep their own Request references; cancel()
-            # of a pruned id is a no-op, which is correct for finished work.
-            self._requests = {
-                k: r for k, r in self._requests.items() if not r.finished
-            }
-            self._publish_gauges()
+            with _tracing.annotate("llm.step", **attrs):
+                if self._drafter is not None:
+                    attrs["spec"] = spec_info
+                with _tracing.span("llm_engine_step", **attrs):
+                    with self._phase("admit"):
+                        self._reap()
+                        sched.admit()
+                        self._apply_cow()
+                    did = self._prefill_one()
+                    if self._drafter is not None and self._spec_skip == 0:
+                        did = self._spec_decode_all(spec_info) or did
+                    else:
+                        did_decode = self._decode_all()
+                        if did_decode and self._spec_skip > 0:
+                            self._spec_skip -= 1  # backoff ticks on real decodes
+                        did = did_decode or did
+                with self._phase("publish"):
+                    # prune finished requests: the registry otherwise retains
+                    # every Request (prompt, output, stream queue) for the
+                    # replica's lifetime. Callers keep their own Request
+                    # references; cancel() of a pruned id is a no-op, which
+                    # is correct for finished work.
+                    self._requests = {
+                        k: r for k, r in self._requests.items() if not r.finished
+                    }
+                    self._publish_gauges()
             self._beat = (
                 time.monotonic(), sched.num_running + sched.num_waiting
             )
+            wall = time.perf_counter() - t_in
+            self._step_wall_s += wall
+            self._step_wall_hist[bisect.bisect_left(STEP_WALL_BOUNDS_S, wall)] += 1
             return did or sched.has_work()
+        finally:
+            self._lock.release()
 
     # -- internals (all called under the lock) -----------------------------
 
@@ -920,63 +1038,75 @@ class LLMEngine:
 
     def _prefill_one(self) -> bool:
         """One chunk for the oldest admission still prefilling."""
-        pre = [r for r in self.scheduler.slots if r is not None and r.state == PREFILL]
-        if not pre:
-            return False
-        req = min(pre, key=lambda r: self.scheduler._admitted_at.get(r.id, 0))
-        chunk = self.cfg.prefill_chunk
-        # a preempted request replays prompt + already-generated tokens to
-        # rebuild its cache; a fresh one just prefills its prompt — and a
-        # prefix-cache hit starts past the matched prefix either way
-        full = req.prompt + req.out
-        piece = full[req.prefill_pos : req.prefill_pos + chunk]
-        n_valid = len(piece)
-        tokens = np.zeros(chunk, np.int32)
-        tokens[:n_valid] = piece
-        table = self.pool.table_row(req.id)
-        k, v, last_logits = self.runner.prefill_chunk(
-            self.pool.k, self.pool.v, tokens, req.prefill_pos, n_valid, table
-        )
-        self.pool.k, self.pool.v = k, v
-        req.prefill_pos += n_valid
-        self._prefill_tokens += n_valid
-        if req.phase_led is not None:
-            # a recompute's re-prefill is preemption cost, not prefill
-            _phases.charge(
-                req.phase_led,
-                _phases.PREEMPT if req.phase_recompute else _phases.PREFILL,
-                time.time(),
+        with self._phase("prefill_build"):
+            pre = [
+                r for r in self.scheduler.slots
+                if r is not None and r.state == PREFILL
+            ]
+            if not pre:
+                return False
+            req = min(pre, key=lambda r: self.scheduler._admitted_at.get(r.id, 0))
+            chunk = self.cfg.prefill_chunk
+            # a preempted request replays prompt + already-generated tokens
+            # to rebuild its cache; a fresh one just prefills its prompt —
+            # and a prefix-cache hit starts past the matched prefix either way
+            full = req.prompt + req.out
+            piece = full[req.prefill_pos : req.prefill_pos + chunk]
+            n_valid = len(piece)
+            tokens = np.zeros(chunk, np.int32)
+            tokens[:n_valid] = piece
+            table = self.pool.table_row(req.id)
+        with self._phase("prefill_launch"):
+            k, v, last_logits = self.runner.prefill_chunk(
+                self.pool.k, self.pool.v, tokens, req.prefill_pos, n_valid, table
             )
-        _metrics()["prefill_tokens"].inc(n_valid)
-        _events.record(
-            "llm.prefill_chunk", request_id=req.trace_id, engine_req=req.id,
-            pos=req.prefill_pos, of=len(full), n=n_valid,
-        )
-        if self.prefix_cache is not None:
-            # register the now-complete PROMPT blocks (generated tokens
-            # never enter the tree — only prompt content is matchable);
-            # the admission epoch keeps a request whose prefill straddled
-            # a weight-swap flush from re-inserting old-weight KV
-            self.prefix_cache.insert(
-                req.prompt,
-                self.pool.blocks_of(req.id),
-                limit=min(req.prefill_pos, len(req.prompt)),
-                epoch=req.cache_epoch,
+        # the chunk is in flight; what follows is the host's book-keeping
+        # for it, billed to prefill_build like the work before the launch
+        with self._phase("prefill_build"):
+            self.pool.k, self.pool.v = k, v
+            req.prefill_pos += n_valid
+            self._prefill_tokens += n_valid
+            if req.phase_led is not None:
+                # a recompute's re-prefill is preemption cost, not prefill
+                _phases.charge(
+                    req.phase_led,
+                    _phases.PREEMPT if req.phase_recompute else _phases.PREFILL,
+                    time.time(),
+                )
+            _metrics()["prefill_tokens"].inc(n_valid)
+            _events.record(
+                "llm.prefill_chunk", request_id=req.trace_id, engine_req=req.id,
+                pos=req.prefill_pos, of=len(full), n=n_valid,
             )
+            if self.prefix_cache is not None:
+                # register the now-complete PROMPT blocks (generated tokens
+                # never enter the tree — only prompt content is matchable);
+                # the admission epoch keeps a request whose prefill straddled
+                # a weight-swap flush from re-inserting old-weight KV
+                self.prefix_cache.insert(
+                    req.prompt,
+                    self.pool.blocks_of(req.id),
+                    limit=min(req.prefill_pos, len(req.prompt)),
+                    epoch=req.cache_epoch,
+                )
         if req.prefill_pos >= len(full):
             # final chunk: its last position's logits seed generation
-            p = req.params
-            tok, lp = self._sample1(
-                last_logits[None, :],
-                np.asarray([p.seed & 0xFFFFFFFF], np.uint32),
-                np.asarray([len(req.out)], np.int32),
-                np.asarray([p.temperature], np.float32),
-                np.asarray([p.top_k], np.int32),
-                np.asarray([p.top_p], np.float32),
-            )
-            req.state = RUNNING
-            req.phase_recompute = False  # recompute ends where decode resumes
-            self._emit(req, int(tok[0]), float(lp[0]))
+            with self._phase("prefill_sample"):
+                p = req.params
+                tok, lp = self._sample1(
+                    last_logits[None, :],
+                    np.asarray([p.seed & 0xFFFFFFFF], np.uint32),
+                    np.asarray([len(req.out)], np.int32),
+                    np.asarray([p.temperature], np.float32),
+                    np.asarray([p.top_k], np.int32),
+                    np.asarray([p.top_p], np.float32),
+                )
+                # the one host sync of a prefill: waits for the chunk
+                tok, lp = int(tok[0]), float(lp[0])
+            with self._phase("emit"):
+                req.state = RUNNING
+                req.phase_recompute = False  # recompute ends where decode resumes
+                self._emit(req, tok, lp)
         return True
 
     def _grow_all(self, extra: int = 0) -> None:
@@ -996,58 +1126,62 @@ class LLMEngine:
 
     def _decode_all(self) -> bool:
         """One batched decode step over every RUNNING slot."""
-        sched = self.scheduler
-        # memory first: every runner needs space for the token it is about
-        # to write; the youngest gets evicted when the pool is dry
-        self._grow_all()
-        active = [
-            (i, r)
-            for i, r in enumerate(sched.slots)
-            if r is not None and r.state == RUNNING
-        ]
-        if not active:
-            return False
-        S = self.cfg.max_slots
-        tokens = np.zeros(S, np.int32)
-        positions = np.zeros(S, np.int32)
-        tables = np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32)
-        temp = np.zeros(S, np.float32)
-        top_k = np.zeros(S, np.int32)
-        top_p = np.ones(S, np.float32)
-        seeds = np.zeros(S, np.uint32)
-        counters = np.zeros(S, np.int32)
-        for i, req in active:
-            tokens[i] = req.out[-1] if req.out else req.prompt[-1]
-            positions[i] = req.seq_len - 1  # the fed token's position
-            tables[i] = self.pool.table_row(req.id)
-            p = req.params
-            temp[i] = p.temperature
-            top_k[i] = p.top_k
-            top_p[i] = p.top_p
-            # mask, don't assign raw: a negative seed overflows a uint32
-            # cell on NumPy >= 2 and the OverflowError would kill the
-            # engine loop thread
-            seeds[i] = p.seed & 0xFFFFFFFF
-            counters[i] = len(req.out)
-        k, v, nxt, logp = self.runner.decode_step(
-            self.pool.k, self.pool.v, tokens, positions, tables,
-            temp, top_k, top_p, seeds, counters,
-        )
-        self.pool.k, self.pool.v = k, v
         import jax
 
-        nxt, logp = jax.device_get((nxt, logp))  # ONE host sync for the batch
-        now = time.time()
-        for i, req in active:
-            if req.phase_led is not None:
-                _phases.charge(req.phase_led, _phases.DECODE, now)
-        for i, req in active:
-            _events.record(
-                "llm.decode", request_id=req.trace_id, engine_req=req.id,
-                step=self._step_n, token=int(nxt[i]),
+        sched = self.scheduler
+        with self._phase("decode_build"):
+            # memory first: every runner needs space for the token it is
+            # about to write; the youngest gets evicted when the pool is dry
+            self._grow_all()
+            active = [
+                (i, r)
+                for i, r in enumerate(sched.slots)
+                if r is not None and r.state == RUNNING
+            ]
+            if not active:
+                return False
+            S = self.cfg.max_slots
+            tokens = np.zeros(S, np.int32)
+            positions = np.zeros(S, np.int32)
+            tables = np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32)
+            temp = np.zeros(S, np.float32)
+            top_k = np.zeros(S, np.int32)
+            top_p = np.ones(S, np.float32)
+            seeds = np.zeros(S, np.uint32)
+            counters = np.zeros(S, np.int32)
+            for i, req in active:
+                tokens[i] = req.out[-1] if req.out else req.prompt[-1]
+                positions[i] = req.seq_len - 1  # the fed token's position
+                tables[i] = self.pool.table_row(req.id)
+                p = req.params
+                temp[i] = p.temperature
+                top_k[i] = p.top_k
+                top_p[i] = p.top_p
+                # mask, don't assign raw: a negative seed overflows a uint32
+                # cell on NumPy >= 2 and the OverflowError would kill the
+                # engine loop thread
+                seeds[i] = p.seed & 0xFFFFFFFF
+                counters[i] = len(req.out)
+        with self._phase("decode_launch"):
+            k, v, nxt, logp = self.runner.decode_step(
+                self.pool.k, self.pool.v, tokens, positions, tables,
+                temp, top_k, top_p, seeds, counters,
             )
-            self._emit(req, int(nxt[i]), float(logp[i]))
-        _metrics()["tokens_per_step"].set(len(active))
+            self.pool.k, self.pool.v = k, v
+        with self._phase("decode_fetch"):
+            nxt, logp = jax.device_get((nxt, logp))  # ONE host sync for the batch
+        with self._phase("emit"):
+            now = time.time()
+            for i, req in active:
+                if req.phase_led is not None:
+                    _phases.charge(req.phase_led, _phases.DECODE, now)
+            for i, req in active:
+                _events.record(
+                    "llm.decode", request_id=req.trace_id, engine_req=req.id,
+                    step=self._step_n, token=int(nxt[i]),
+                )
+                self._emit(req, int(nxt[i]), float(logp[i]))
+            _metrics()["tokens_per_step"].set(len(active))
         return True
 
     def _spec_decode_all(self, spec_info: dict) -> bool:
@@ -1065,11 +1199,12 @@ class LLMEngine:
         ]
         if not active:
             return False
-        t0 = time.perf_counter()
-        draft = self._drafter.propose([r.prompt + r.out for _, r in active])
-        draft_s = time.perf_counter() - t0
-        self._spec_draft_s += draft_s
-        _metrics()["spec_draft_s"].inc(draft_s)
+        with self._phase("draft"):
+            t0 = time.perf_counter()
+            draft = self._drafter.propose([r.prompt + r.out for _, r in active])
+            draft_s = time.perf_counter() - t0
+            self._spec_draft_s += draft_s
+            _metrics()["spec_draft_s"].inc(draft_s)
         # drafter confidence gate: when NO slot's proposal is backed by a
         # real match (NGramDrafter.last_matched), the whole window would
         # be a doomed probe — plain-decode this step instead of paying a
@@ -1079,90 +1214,94 @@ class LLMEngine:
         matched = getattr(self._drafter, "last_matched", None)
         if matched is not None and not bool(matched.any()):
             return self._decode_all()
-        draft_by_id = {r.id: draft[row] for row, (_, r) in enumerate(active)}
-        # memory next: the window provisionally writes positions
-        # seq_len-1 .. seq_len-1+k; the youngest gets evicted when dry
-        self._grow_all(extra=kd)
-        active = [(i, r) for i, r in active if r.state == RUNNING]
-        if not active:
-            return False
-        S, W = self.cfg.max_slots, kd + 1
-        tokens = np.zeros((S, W), np.int32)
-        base_pos = np.zeros(S, np.int32)
-        tables = np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32)
-        temp = np.zeros(S, np.float32)
-        top_k = np.zeros(S, np.int32)
-        top_p = np.ones(S, np.float32)
-        seeds = np.zeros(S, np.uint32)
-        counters = np.zeros(S, np.int32)
-        for i, req in active:
-            tokens[i, 0] = req.out[-1] if req.out else req.prompt[-1]
-            tokens[i, 1:] = draft_by_id[req.id]
-            base_pos[i] = req.seq_len - 1  # the fed token's position
-            tables[i] = self.pool.table_row(req.id)
-            p = req.params
-            temp[i] = p.temperature
-            top_k[i] = p.top_k
-            top_p[i] = p.top_p
-            seeds[i] = p.seed & 0xFFFFFFFF
-            counters[i] = len(req.out)
-        k, v, n_acc, out, out_lp = self.runner.verify_step(
-            self.pool.k, self.pool.v, tokens, base_pos, tables,
-            temp, top_k, top_p, seeds, counters,
-        )
-        self.pool.k, self.pool.v = k, v
-        n_acc, out, out_lp = jax.device_get((n_acc, out, out_lp))  # ONE host sync
-        now = time.time()
-        for i, req in active:
-            if req.phase_led is not None:
-                _phases.charge(req.phase_led, _phases.SPEC_VERIFY, now)
-        emitted = 0
-        accepted = 0
-        for i, req in active:
-            n = int(n_acc[i])
-            accepted += n
-            _events.record(
-                "llm.verify", request_id=req.trace_id, engine_req=req.id,
-                step=self._step_n, proposed=kd, accepted=n,
+        with self._phase("decode_build"):
+            draft_by_id = {r.id: draft[row] for row, (_, r) in enumerate(active)}
+            # memory next: the window provisionally writes positions
+            # seq_len-1 .. seq_len-1+k; the youngest gets evicted when dry
+            self._grow_all(extra=kd)
+            active = [(i, r) for i, r in active if r.state == RUNNING]
+            if not active:
+                return False
+            S, W = self.cfg.max_slots, kd + 1
+            tokens = np.zeros((S, W), np.int32)
+            base_pos = np.zeros(S, np.int32)
+            tables = np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32)
+            temp = np.zeros(S, np.float32)
+            top_k = np.zeros(S, np.int32)
+            top_p = np.ones(S, np.float32)
+            seeds = np.zeros(S, np.uint32)
+            counters = np.zeros(S, np.int32)
+            for i, req in active:
+                tokens[i, 0] = req.out[-1] if req.out else req.prompt[-1]
+                tokens[i, 1:] = draft_by_id[req.id]
+                base_pos[i] = req.seq_len - 1  # the fed token's position
+                tables[i] = self.pool.table_row(req.id)
+                p = req.params
+                temp[i] = p.temperature
+                top_k[i] = p.top_k
+                top_p[i] = p.top_p
+                seeds[i] = p.seed & 0xFFFFFFFF
+                counters[i] = len(req.out)
+        with self._phase("decode_launch", "verify_launch"):
+            k, v, n_acc, out, out_lp = self.runner.verify_step(
+                self.pool.k, self.pool.v, tokens, base_pos, tables,
+                temp, top_k, top_p, seeds, counters,
             )
-            for j in range(n + 1):
-                self._emit(req, int(out[i, j]), float(out_lp[i, j]))
-                emitted += 1
-                if req.finished:
-                    # stop token / length cap hit inside the window: the
-                    # rest of the acceptance is after-the-end, discard it
-                    break
-            if not req.finished:
-                # ledger rollback: return the rejected tail's provisional
-                # blocks (device k/v needs none — see cache.shrink_to)
-                self.pool.shrink_to(req.id, req.seq_len)
-        proposed = kd * len(active)
-        self._spec_proposed += proposed
-        self._spec_accepted += accepted
-        step_rate = accepted / max(proposed, 1)
-        if step_rate < self.cfg.spec_min_accept:
-            # low acceptance: back off to plain decode, doubling the pause
-            # while probes keep failing (EngineConfig docstring)
-            self._spec_backoff = min(
-                max(self._spec_backoff * 2, 2), self.cfg.spec_backoff_max
+            self.pool.k, self.pool.v = k, v
+        with self._phase("decode_fetch", "verify_fetch"):
+            n_acc, out, out_lp = jax.device_get((n_acc, out, out_lp))  # ONE host sync
+        with self._phase("emit"):
+            now = time.time()
+            for i, req in active:
+                if req.phase_led is not None:
+                    _phases.charge(req.phase_led, _phases.SPEC_VERIFY, now)
+            emitted = 0
+            accepted = 0
+            for i, req in active:
+                n = int(n_acc[i])
+                accepted += n
+                _events.record(
+                    "llm.verify", request_id=req.trace_id, engine_req=req.id,
+                    step=self._step_n, proposed=kd, accepted=n,
+                )
+                for j in range(n + 1):
+                    self._emit(req, int(out[i, j]), float(out_lp[i, j]))
+                    emitted += 1
+                    if req.finished:
+                        # stop token / length cap hit inside the window: the
+                        # rest of the acceptance is after-the-end, discard it
+                        break
+                if not req.finished:
+                    # ledger rollback: return the rejected tail's provisional
+                    # blocks (device k/v needs none — see cache.shrink_to)
+                    self.pool.shrink_to(req.id, req.seq_len)
+            proposed = kd * len(active)
+            self._spec_proposed += proposed
+            self._spec_accepted += accepted
+            step_rate = accepted / max(proposed, 1)
+            if step_rate < self.cfg.spec_min_accept:
+                # low acceptance: back off to plain decode, doubling the
+                # pause while probes keep failing (EngineConfig docstring)
+                self._spec_backoff = min(
+                    max(self._spec_backoff * 2, 2), self.cfg.spec_backoff_max
+                )
+                self._spec_skip = self._spec_backoff
+            else:
+                self._spec_backoff = 0
+            m = _metrics()
+            m["spec_proposed"].inc(proposed)
+            m["spec_accepted"].inc(accepted)
+            m["spec_accept_rate"].set(step_rate)
+            m["tokens_per_step"].set(emitted)
+            spec_info.update(
+                k=kd,
+                slots=len(active),
+                proposed=proposed,
+                accepted=accepted,
+                emitted=emitted,
+                draft_s=round(draft_s, 6),
+                backoff=self._spec_backoff,
             )
-            self._spec_skip = self._spec_backoff
-        else:
-            self._spec_backoff = 0
-        m = _metrics()
-        m["spec_proposed"].inc(proposed)
-        m["spec_accepted"].inc(accepted)
-        m["spec_accept_rate"].set(step_rate)
-        m["tokens_per_step"].set(emitted)
-        spec_info.update(
-            k=kd,
-            slots=len(active),
-            proposed=proposed,
-            accepted=accepted,
-            emitted=emitted,
-            draft_s=round(draft_s, 6),
-            backoff=self._spec_backoff,
-        )
         return True
 
     def _emit(self, req: Request, tok: int, logp: float = float("nan")) -> None:

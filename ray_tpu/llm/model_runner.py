@@ -32,6 +32,12 @@ Three entry shapes, each jitted once per engine:
   the table's reach scatter to the trash block, so slots at the model-
   length cap stay safe (their surplus logits are discarded host-side).
 
+Names on the device: the shared layer math below runs under
+``jax.named_scope``s (``SCOPES``), the same names in decode, verify,
+prefill and fork, so a profiler trace or a lowered program says which
+device op is which after any recompile renumbers ``fusion.N``.  Scopes
+are metadata (``op_name``): they change no compiled code.
+
 Static shapes everywhere: slot count, chunk size, window width ``k+1``,
 table width, and pool geometry are compile-time constants — admission,
 preemption, completion, and per-step acceptance-length changes never
@@ -62,6 +68,14 @@ from ray_tpu.ops.paged_attention import (
 )
 
 
+#: the ``jax.named_scope``s of the jitted steps (``llm.multichip`` adds
+#: ``tp_sum``): a device op's ``op_name`` holds one of them as a path segment
+SCOPES = (
+    "embed", "qkv", "kv_write", "paged_attention", "attn_out", "mlp",
+    "lm_head", "sample", "kv_fork",
+)
+
+
 def _rotary_rows(x: jax.Array, positions: jax.Array, rotary_dim: int) -> jax.Array:
     """GPT-J interleaved rotary with PER-ROW positions. x: (n, heads, hd);
     positions: (n,) int32.  (models.gptj applies one shared position vector
@@ -86,9 +100,10 @@ def _scatter_kv(pool_l: jax.Array, vals: jax.Array, phys: jax.Array, off: jax.Ar
     """Write per-row k or v into physical blocks.  pool_l: (num_blocks,
     heads, block, d); vals: (n, heads, d); phys/off: (n,) int32."""
     n, heads, _ = vals.shape
-    return pool_l.at[
-        phys[:, None], jnp.arange(heads)[None, :], off[:, None], :
-    ].set(vals)
+    with jax.named_scope("kv_write"):
+        return pool_l.at[
+            phys[:, None], jnp.arange(heads)[None, :], off[:, None], :
+        ].set(vals)
 
 
 def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
@@ -97,9 +112,6 @@ def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
     no matter which slot or step it lands in.  Returns (tokens (n,),
     logprobs (n,)) — the chosen-token behavior logprob rides along free
     (``models.sampling`` module doc)."""
-    keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
-        seeds, counters
-    )
 
     def one(lg, k, t, kk, pp):
         tok, lp = sample_tokens_logprobs(
@@ -107,7 +119,11 @@ def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
         )
         return tok[0], lp[0]
 
-    return jax.vmap(one)(logits, keys, temp, top_k, top_p)
+    with jax.named_scope("sample"):
+        keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
+            seeds, counters
+        )
+        return jax.vmap(one)(logits, keys, temp, top_k, top_p)
 
 
 def _abstract(x) -> jax.ShapeDtypeStruct:
@@ -127,8 +143,9 @@ def _fork_impl(k_pool, v_pool, src, dst):
     through the model is L layer matmuls — the fork wins by orders of
     magnitude.  Unused lanes pad with (0, 0): trash copied onto trash,
     harmless and value-deterministic even with duplicate dst indices."""
-    k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-    v_pool = v_pool.at[:, dst].set(v_pool[:, src])
+    with jax.named_scope("kv_fork"):
+        k_pool = k_pool.at[:, dst].set(k_pool[:, src])
+        v_pool = v_pool.at[:, dst].set(v_pool[:, src])
     return k_pool, v_pool
 
 
@@ -137,9 +154,10 @@ def _verify_rows(logits, draft, seeds, counters, temp, top_k, top_p):
     ``_sample_rows``: window token i keys off (seed, counter + i)).
     logits: (S, W, V); draft: (S, W-1).  Returns (n_accepted (S,),
     out_tokens (S, W), out_logprobs (S, W))."""
-    return jax.vmap(speculative_verify_logprobs)(
-        logits, draft, seeds, counters, temp, top_k, top_p
-    )
+    with jax.named_scope("sample"):
+        return jax.vmap(speculative_verify_logprobs)(
+            logits, draft, seeds, counters, temp, top_k, top_p
+        )
 
 
 class PagedModelRunner:
@@ -247,36 +265,40 @@ class PagedModelRunner:
         dt = h.dtype
         n = h.shape[0]
         nh, hd = self.n_local_heads, cfg.head_dim
-        if self.arch == "gptj":
-            q = (h @ layer["q"]["kernel"].astype(dt)).reshape(n, nh, hd)
-            k = (h @ layer["k"]["kernel"].astype(dt)).reshape(n, nh, hd)
-            v = (h @ layer["v"]["kernel"].astype(dt)).reshape(n, nh, hd)
-            q = _rotary_rows(q, positions, cfg.rotary_dim)
-            k = _rotary_rows(k, positions, cfg.rotary_dim)
-        else:
-            qkv = h @ layer["attn_qkv"]["kernel"].astype(dt) + layer["attn_qkv"][
-                "bias"
-            ].astype(dt)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(n, nh, hd)
-            k = k.reshape(n, nh, hd)
-            v = v.reshape(n, nh, hd)
+        with jax.named_scope("qkv"):
+            if self.arch == "gptj":
+                q = (h @ layer["q"]["kernel"].astype(dt)).reshape(n, nh, hd)
+                k = (h @ layer["k"]["kernel"].astype(dt)).reshape(n, nh, hd)
+                v = (h @ layer["v"]["kernel"].astype(dt)).reshape(n, nh, hd)
+                q = _rotary_rows(q, positions, cfg.rotary_dim)
+                k = _rotary_rows(k, positions, cfg.rotary_dim)
+            else:
+                qkv = h @ layer["attn_qkv"]["kernel"].astype(dt) + layer["attn_qkv"][
+                    "bias"
+                ].astype(dt)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(n, nh, hd)
+                k = k.reshape(n, nh, hd)
+                v = v.reshape(n, nh, hd)
         return q, k, v
 
     def _mlp(self, layer, h):
         dt = h.dtype
-        mid = jax.nn.gelu(
-            h @ layer["mlp_in"]["kernel"].astype(dt) + layer["mlp_in"]["bias"].astype(dt)
-        )
-        return mid @ layer["mlp_out"]["kernel"].astype(dt) + layer["mlp_out"][
-            "bias"
-        ].astype(dt)
+        with jax.named_scope("mlp"):
+            mid = jax.nn.gelu(
+                h @ layer["mlp_in"]["kernel"].astype(dt)
+                + layer["mlp_in"]["bias"].astype(dt)
+            )
+            return mid @ layer["mlp_out"]["kernel"].astype(dt) + layer["mlp_out"][
+                "bias"
+            ].astype(dt)
 
     def _attn_out(self, layer, att_flat):
         dt = att_flat.dtype
-        out = att_flat @ layer["attn_out"]["kernel"].astype(dt)
-        if self.arch == "gpt":
-            out = out + layer["attn_out"]["bias"].astype(dt)
+        with jax.named_scope("attn_out"):
+            out = att_flat @ layer["attn_out"]["kernel"].astype(dt)
+            if self.arch == "gpt":
+                out = out + layer["attn_out"]["bias"].astype(dt)
         return out
 
     def _embed(self, params, tokens, positions):
@@ -287,18 +309,20 @@ class PagedModelRunner:
         # a swap would then silently update only the layer stack
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        x = params["embed"]["tokens"][tokens].astype(dt)
-        if self.arch == "gpt":
-            # clamp: padded prefill-tail positions may run past the table
-            pos = jnp.minimum(positions, cfg.seq_len - 1)
-            x = x + params["embed"]["pos"][pos].astype(dt)
+        with jax.named_scope("embed"):
+            x = params["embed"]["tokens"][tokens].astype(dt)
+            if self.arch == "gpt":
+                # clamp: padded prefill-tail positions may run past the table
+                pos = jnp.minimum(positions, cfg.seq_len - 1)
+                x = x + params["embed"]["pos"][pos].astype(dt)
         return x
 
     def _lm_head(self, params, h):
-        h = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-        logits = h.astype(jnp.float32) @ params["lm_head"]["kernel"]
-        if self.arch == "gptj":
-            logits = logits + params["lm_head"]["bias"]
+        with jax.named_scope("lm_head"):
+            h = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
+            logits = h.astype(jnp.float32) @ params["lm_head"]["kernel"]
+            if self.arch == "gptj":
+                logits = logits + params["lm_head"]["bias"]
         return logits
 
     # -- decode step -------------------------------------------------------

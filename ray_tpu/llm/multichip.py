@@ -82,11 +82,12 @@ def _tp_sum(x: jax.Array, axis: str) -> jax.Array:
     """Sum of every ``axis`` device's ``x``, identical on all of them,
     each element added in device order in float32 whatever its row:
     gather (exact data movement), then add."""
-    parts = jax.lax.all_gather(x, axis).astype(jnp.float32)
-    total = parts[0]
-    for i in range(1, parts.shape[0]):
-        total = total + parts[i]
-    return total.astype(x.dtype)
+    with jax.named_scope("tp_sum"):
+        parts = jax.lax.all_gather(x, axis).astype(jnp.float32)
+        total = parts[0]
+        for i in range(1, parts.shape[0]):
+            total = total + parts[i]
+        return total.astype(x.dtype)
 
 
 def _spec_for(path) -> P:
@@ -341,17 +342,27 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         row-parallel projections produce partial sums reduced over
         "tp" by ``_tp_sum`` (replicated biases added once, after)."""
         dt = x.dtype
+
+        def attn_partial(q, k_l, v_l):
+            att = attend(q, k_l, v_l)  # paged_attention names its own scope
+            with jax.named_scope("attn_out"):
+                return att @ layer["attn_out"]["kernel"].astype(dt)
+
+        def mlp_partial(h):
+            with jax.named_scope("mlp"):
+                mid = jax.nn.gelu(
+                    h @ layer["mlp_in"]["kernel"].astype(dt)
+                    + layer["mlp_in"]["bias"].astype(dt)
+                )
+                return mid @ layer["mlp_out"]["kernel"].astype(dt)
+
         if self.arch == "gptj":
             h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
             q, k, v = self._qkv_rows(layer, h, positions)
             k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
             v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-            att_p = attend(q, k_l, v_l) @ layer["attn_out"]["kernel"].astype(dt)
-            mid = jax.nn.gelu(
-                h @ layer["mlp_in"]["kernel"].astype(dt)
-                + layer["mlp_in"]["bias"].astype(dt)
-            )
-            mlp_p = mid @ layer["mlp_out"]["kernel"].astype(dt)
+            att_p = attn_partial(q, k_l, v_l)
+            mlp_p = mlp_partial(h)
             # parallel residual: attention + MLP partials share ONE
             # fused reduction per layer (half the collectives of the
             # sequential-residual arch below)
@@ -365,20 +376,16 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             q, k, v = self._qkv_rows(layer, ln1, positions)
             k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
             v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-            att_p = attend(q, k_l, v_l) @ layer["attn_out"]["kernel"].astype(dt)
+            att_p = attn_partial(q, k_l, v_l)
             h = (
                 x
                 + _tp_sum(att_p, "tp")
                 + layer["attn_out"]["bias"].astype(dt)
             )
             ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-            mid = jax.nn.gelu(
-                ln2 @ layer["mlp_in"]["kernel"].astype(dt)
-                + layer["mlp_in"]["bias"].astype(dt)
-            )
             out = (
                 h
-                + _tp_sum(mid @ layer["mlp_out"]["kernel"].astype(dt), "tp")
+                + _tp_sum(mlp_partial(ln2), "tp")
                 + layer["mlp_out"]["bias"].astype(dt)
             )
         return out, k_l, v_l

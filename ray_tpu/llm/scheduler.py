@@ -111,6 +111,10 @@ class Request:
         self.params = params
         self.deadline = deadline
         self.arrival_t = time.time()
+        # when it last joined the waiting queue: the engine re-stamps this
+        # once it holds its lock in submit (arrival_t is taken BEFORE that
+        # lock, so arrival → queued is the lock wait), preempt() at requeue
+        self.queued_t = self.arrival_t
         self.state = WAITING
         self.finish_reason: Optional[str] = None
         self.out: list[int] = [int(t) for t in resume_tokens]
@@ -175,6 +179,11 @@ class Scheduler:
         self._admitted_at: dict[str, int] = {}  # request id -> admission tick
         self.preempt_count = 0
         self.finish_count = 0  # lifetime finishes (engine rates this per step)
+        # stats()["queue"]: admissions and the seconds they had waited in
+        # the queue (queued_t → admit() picks the request; the stamp that
+        # closes the ledger's queue leg)
+        self.admit_count = 0
+        self.queue_wait_s = 0.0
         # copy-on-write forks queued by cache-aware admission:
         # (src_block, dst_block, request_id) — the engine drains these
         # right after admit() (same lock, same step), device-copying
@@ -219,6 +228,7 @@ class Scheduler:
             if not free:
                 break
             req = self.waiting[0]
+            picked_t = time.time()
             if req.phase_led is not None:
                 # close the queue leg HERE so the admission work that
                 # follows (prefix match, evict-to-fit, allocate, install)
@@ -227,7 +237,7 @@ class Scheduler:
                 _phases.charge(
                     req.phase_led,
                     _phases.PREEMPT if req.phase_recompute else _phases.QUEUE,
-                    time.time(),
+                    picked_t,
                 )
             # prompt (+ recomputed tokens after preempt) + one generation
             # block of headroom, capped at the table width for sequences
@@ -299,6 +309,8 @@ class Scheduler:
                     time.time(),
                 )
             admitted.append(req)
+            self.admit_count += 1
+            self.queue_wait_s += max(0.0, picked_t - req.queued_t)
             _events.record(
                 "llm.admit", request_id=req.trace_id, engine_req=req.id,
                 slot=slot, seq_len=req.seq_len,
@@ -356,6 +368,7 @@ class Scheduler:
             req.phase_recompute = True
         req.prefill_pos = 0
         req.state = WAITING
+        req.queued_t = time.time()
         self.waiting.appendleft(req)
         _events.record(
             "llm.preempt", request_id=req.trace_id, engine_req=req.id,

@@ -191,70 +191,72 @@ def _block(cfg: GPTConfig, x, layer, mesh=None):
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
 
-    ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-    qkv = ln1 @ layer["attn_qkv"]["kernel"].astype(dt) + layer["attn_qkv"]["bias"].astype(dt)
-    qkv = checkpoint_name(qkv, "qkv")  # saved only under remat_policy="attn_qkv"
-    # seq stays sharded over sp end-to-end (sequence parallelism); sp=1
-    # meshes make these the same constraints as before.
-    qkv = c(qkv, P(("dp", "fsdp"), "sp", "tp"))
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    with jax.named_scope("attn"):
+        ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+        qkv = ln1 @ layer["attn_qkv"]["kernel"].astype(dt) + layer["attn_qkv"]["bias"].astype(dt)
+        qkv = checkpoint_name(qkv, "qkv")  # saved only under remat_policy="attn_qkv"
+        # seq stays sharded over sp end-to-end (sequence parallelism); sp=1
+        # meshes make these the same constraints as before.
+        qkv = c(qkv, P(("dp", "fsdp"), "sp", "tp"))
+        q, k, v = jnp.split(qkv, 3, axis=-1)
 
-    def heads(t):
-        return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        def heads(t):
+            return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
-    impl = cfg.attn_impl
-    if impl == "ring" and mesh is None:
-        raise ValueError(
-            "attn_impl='ring' needs a device mesh with an 'sp' axis; pass "
-            "mesh= (or use attn_impl='auto', which picks ring only when the "
-            "mesh shards sequence)"
-        )
-    if impl == "ring" or (
-        impl == "auto" and mesh is not None and mesh.shape.get("sp", 1) > 1
-    ):
-        # sequence sharded over sp: ring attention rotates KV over ICI
-        from ray_tpu.ops.ring_attention import ring_attention_sharded
-
-        att = ring_attention_sharded(heads(q), heads(k), heads(v), mesh)
-    else:
-        from ray_tpu.ops.attention import auto_impl
-        from ray_tpu.ops.flash_attention import flash_shardable
-
-        want_flash = impl == "flash" or (impl == "auto" and auto_impl(s) == "flash")
-        if (
-            want_flash
-            and mesh is not None
-            and mesh.size > 1
-            and s >= 128
-            and s % 128 == 0
-            and flash_shardable(b, h, mesh)
+        impl = cfg.attn_impl
+        if impl == "ring" and mesh is None:
+            raise ValueError(
+                "attn_impl='ring' needs a device mesh with an 'sp' axis; pass "
+                "mesh= (or use attn_impl='auto', which picks ring only when the "
+                "mesh shards sequence)"
+            )
+        if impl == "ring" or (
+            impl == "auto" and mesh is not None and mesh.shape.get("sp", 1) > 1
         ):
-            # multi-device pjit: shard_map the Pallas kernel so it runs on
-            # each chip's dp/tp shard instead of being replicated (no GSPMD
-            # rule for a bare pallas_call)
-            from ray_tpu.ops.flash_attention import flash_attention_sharded
+            # sequence sharded over sp: ring attention rotates KV over ICI
+            from ray_tpu.ops.ring_attention import ring_attention_sharded
 
-            att = flash_attention_sharded(heads(q), heads(k), heads(v), mesh)
-        elif want_flash and mesh is not None and mesh.size > 1:
-            # multi-device but not shardable (batch/heads don't divide the
-            # mesh): a bare pallas_call would replicate on every chip — the
-            # XLA einsum partitions correctly instead
-            att = causal_attention(heads(q), heads(k), heads(v), impl="xla")
+            att = ring_attention_sharded(heads(q), heads(k), heads(v), mesh)
         else:
-            att = causal_attention(heads(q), heads(k), heads(v), impl=impl)
-    att = att.transpose(0, 2, 1, 3).reshape(b, s, d)
-    att = att @ layer["attn_out"]["kernel"].astype(dt) + layer["attn_out"]["bias"].astype(dt)
-    x = x + c(att, P(("dp", "fsdp"), "sp", None))
+            from ray_tpu.ops.attention import auto_impl
+            from ray_tpu.ops.flash_attention import flash_shardable
 
-    ln2 = _layernorm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
-    if cfg.n_experts > 0:
-        out, aux = _moe_mlp(cfg, ln2, layer, c)
-    else:
-        hmid = jax.nn.gelu(ln2 @ layer["mlp_in"]["kernel"].astype(dt) + layer["mlp_in"]["bias"].astype(dt))
-        hmid = checkpoint_name(hmid, "mlp_mid")
-        hmid = c(hmid, P(("dp", "fsdp"), "sp", "tp"))
-        out = hmid @ layer["mlp_out"]["kernel"].astype(dt) + layer["mlp_out"]["bias"].astype(dt)
-        aux = jnp.float32(0.0)
+            want_flash = impl == "flash" or (impl == "auto" and auto_impl(s) == "flash")
+            if (
+                want_flash
+                and mesh is not None
+                and mesh.size > 1
+                and s >= 128
+                and s % 128 == 0
+                and flash_shardable(b, h, mesh)
+            ):
+                # multi-device pjit: shard_map the Pallas kernel so it runs on
+                # each chip's dp/tp shard instead of being replicated (no GSPMD
+                # rule for a bare pallas_call)
+                from ray_tpu.ops.flash_attention import flash_attention_sharded
+
+                att = flash_attention_sharded(heads(q), heads(k), heads(v), mesh)
+            elif want_flash and mesh is not None and mesh.size > 1:
+                # multi-device but not shardable (batch/heads don't divide the
+                # mesh): a bare pallas_call would replicate on every chip — the
+                # XLA einsum partitions correctly instead
+                att = causal_attention(heads(q), heads(k), heads(v), impl="xla")
+            else:
+                att = causal_attention(heads(q), heads(k), heads(v), impl=impl)
+        att = att.transpose(0, 2, 1, 3).reshape(b, s, d)
+        att = att @ layer["attn_out"]["kernel"].astype(dt) + layer["attn_out"]["bias"].astype(dt)
+        x = x + c(att, P(("dp", "fsdp"), "sp", None))
+
+    with jax.named_scope("mlp"):
+        ln2 = _layernorm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
+        if cfg.n_experts > 0:
+            out, aux = _moe_mlp(cfg, ln2, layer, c)
+        else:
+            hmid = jax.nn.gelu(ln2 @ layer["mlp_in"]["kernel"].astype(dt) + layer["mlp_in"]["bias"].astype(dt))
+            hmid = checkpoint_name(hmid, "mlp_mid")
+            hmid = c(hmid, P(("dp", "fsdp"), "sp", "tp"))
+            out = hmid @ layer["mlp_out"]["kernel"].astype(dt) + layer["mlp_out"]["bias"].astype(dt)
+            aux = jnp.float32(0.0)
     return x + c(out, P(("dp", "fsdp"), "sp", None)), aux
 
 
@@ -338,18 +340,20 @@ def gpt_loss(cfg: GPTConfig, params: dict, tokens: jax.Array, mesh=None) -> jax.
         from ray_tpu.ops.fused_ce import fused_softmax_cross_entropy
 
         b, s, d = hidden.shape
-        losses = fused_softmax_cross_entropy(
-            hidden.reshape(b * s, d),
-            params["lm_head"]["kernel"],
-            targets.reshape(-1).astype(jnp.int32),
-            cfg.ce_chunks,
-        )
-        loss = losses.mean()
+        with jax.named_scope("ce"):
+            losses = fused_softmax_cross_entropy(
+                hidden.reshape(b * s, d),
+                params["lm_head"]["kernel"],
+                targets.reshape(-1).astype(jnp.int32),
+                cfg.ce_chunks,
+            )
+            loss = losses.mean()
     else:
-        logits = hidden.astype(jnp.float32) @ params["lm_head"]["kernel"]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        loss = -ll.mean()
+        with jax.named_scope("ce"):
+            logits = hidden.astype(jnp.float32) @ params["lm_head"]["kernel"]
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+            loss = -ll.mean()
     if cfg.n_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux
     return loss

@@ -125,6 +125,7 @@ def _flash_fwd(q, k, v, *, block_q, block_k):
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return out, lse8[:, :1, :]  # (bh, 1, seq)
 
@@ -245,6 +246,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -271,6 +273,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(k, v, q, do, lse, delta)
     return dq, dk, dv
 

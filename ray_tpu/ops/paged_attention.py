@@ -130,18 +130,19 @@ def paged_prefill_attention_xla(
     (chunk, heads, d)."""
     c, h, d = q.shape
     scale = d**-0.5
-    k = k_pool[block_table]  # (tmax, heads, block, d)
-    v = v_pool[block_table]
-    k = k.transpose(1, 0, 2, 3).reshape(h, -1, d)
-    v = v.transpose(1, 0, 2, 3).reshape(h, -1, d)
-    logits = jnp.einsum(
-        "chd,hkd->chk", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) * scale
-    mask = jnp.arange(k.shape[1])[None, None, :] <= positions[:, None, None]
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("chk,hkd->chd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    with jax.named_scope("paged_attention"):
+        k = k_pool[block_table]  # (tmax, heads, block, d)
+        v = v_pool[block_table]
+        k = k.transpose(1, 0, 2, 3).reshape(h, -1, d)
+        v = v.transpose(1, 0, 2, 3).reshape(h, -1, d)
+        logits = jnp.einsum(
+            "chd,hkd->chk", q.astype(jnp.float32), k.astype(jnp.float32)
+        ) * scale
+        mask = jnp.arange(k.shape[1])[None, None, :] <= positions[:, None, None]
+        logits = jnp.where(mask, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("chk,hkd->chd", probs, v.astype(jnp.float32))
+        return out.astype(q.dtype)
 
 
 def paged_verify_attention_xla(
@@ -288,6 +289,8 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, w, heads, d), q.dtype),
         interpret=not _on_tpu(),
+        # the name the kernel has in a lowered program and a device trace
+        name="paged_attention_decode" if w == 1 else "paged_attention_verify",
     )(block_tables.reshape(-1).astype(jnp.int32),
       positions.reshape(-1).astype(jnp.int32),
       q, k_pool, v_pool)
@@ -324,12 +327,13 @@ def paged_attention(
     block_size, head_dim); block_tables: (slots, tmax) int32; lengths:
     (slots,) int32.  ``impl``: auto | xla | pallas.
     """
-    if _resolve_impl(impl, k_pool) == "xla":
-        return paged_attention_xla(q, k_pool, v_pool, block_tables, lengths)
-    # decode is a verify window of width 1 whose query sits at length - 1
-    return _paged_verify_pallas(
-        q[:, None], k_pool, v_pool, block_tables, (lengths - 1)[:, None]
-    )[:, 0]
+    with jax.named_scope("paged_attention"):
+        if _resolve_impl(impl, k_pool) == "xla":
+            return paged_attention_xla(q, k_pool, v_pool, block_tables, lengths)
+        # decode is a verify window of width 1 whose query sits at length - 1
+        return _paged_verify_pallas(
+            q[:, None], k_pool, v_pool, block_tables, (lengths - 1)[:, None]
+        )[:, 0]
 
 
 def paged_verify_attention(
@@ -348,6 +352,9 @@ def paged_verify_attention(
     block_size, head_dim); block_tables: (slots, tmax) int32; positions:
     (slots, w) int32.  ``impl``: auto | xla | pallas.
     """
-    if _resolve_impl(impl, k_pool) == "xla":
-        return paged_verify_attention_xla(q, k_pool, v_pool, block_tables, positions)
-    return _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions)
+    with jax.named_scope("paged_attention"):
+        if _resolve_impl(impl, k_pool) == "xla":
+            return paged_verify_attention_xla(
+                q, k_pool, v_pool, block_tables, positions
+            )
+        return _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions)

@@ -72,8 +72,11 @@ def make_step_fn(loss_fn, optimizer, mesh):
 
     def step(state: TrainState, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
     ensure_compile_cache()

@@ -287,6 +287,28 @@ class LLMDeployment:
         contain (``LLMEngine.device_report``)."""
         return self._engine.device_report()
 
+    def start_trace(self, log_dir: str) -> str:
+        """Start a ``jax.profiler`` trace of THIS replica into ``log_dir``
+        (only the process that holds the chip can trace it).  Device ops
+        and the engine's ``llm.*`` annotations (OBSERVABILITY.md, "Engine
+        step timeline") land in one ``.xplane.pb`` on one clock.  The
+        Python tracer stays off: it slows the host loop, which is what an
+        idle share of the device measures."""
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        return log_dir
+
+    def stop_trace(self) -> None:
+        """Stop the trace and write it out.  This takes seconds of the
+        replica's time (4-65 s after a 3 s slice of GPT-J decode on a v5e,
+        PERF.md): read window counters BEFORE calling it."""
+        import jax
+
+        jax.profiler.stop_trace()
+
     def check_health(self) -> None:
         if not self._loop.is_alive():
             raise RuntimeError("LLM engine loop thread died")

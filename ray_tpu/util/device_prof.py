@@ -6,7 +6,9 @@ prefill / verify / fork in ``llm.model_runner``, the train step in
 run from cache forever — that is the static-shape discipline the whole
 engine is built on, and raylint RL014 (retrace-storm) enforces it
 statically.  This module is RL014's **runtime twin**: it measures the
-wall time of each call into a per-site histogram and watches the jit
+host wall time of each call into a per-site histogram (the ENQUEUE of an
+asynchronous dispatch, not the device's step: ``device_step_seconds`` is
+launch time plus any compile) and watches the jit
 cache size (``PjitFunction._cache_size``) so a site that RECOMPILES
 after its warmup baseline emits a ``<family>.retrace`` flight-recorder
 event and bumps the ``device_retraces`` counter — which the
@@ -69,8 +71,11 @@ def _metrics() -> dict:
         _METRICS = {
             "seconds": Histogram(
                 "device_step_seconds",
-                "wall time per jitted entry-point call (decode/prefill/"
-                "verify/fork/train_step), including any compile",
+                "HOST wall time of the call that ENQUEUES a jitted entry "
+                "point (decode/prefill/verify/fork/train_step): dispatch is "
+                "asynchronous, so this is launch time (plus any compile), "
+                "not the device's step time — for that read a trace "
+                "(decode_step_dev_ms) or the llm.step.*_launch/_fetch spans",
                 boundaries=_STEP_BOUNDARIES,
                 tag_keys=("site",),
             ),

@@ -2,7 +2,7 @@
 
 The task-plane waterfall (``util.waterfall``) breaks one *task hop* into
 phases; this module does the same for one *LLM request* across its whole
-life — proxy recv → router dispatch → engine queue → admission →
+life — proxy recv → router dispatch → engine lock → queue → admission →
 prefill → decode → stream delivery — so ``obs attribute`` can say which
 phase owns the p99 instead of "the engine took 2s".
 
@@ -82,7 +82,11 @@ PHASES = (
     ("dispatch", "engine",
      "proxy dispatch anchor → engine submit (cross-process; skipped for "
      "resumed submits)"),
-    ("queue", "engine", "engine submit → admission pops the request"),
+    ("lock", "engine",
+     "engine submit → the submitter holds the engine lock and enqueues "
+     "(the wait for a step to let go of it; the same two stamps feed "
+     "stats()['submit'])"),
+    ("queue", "engine", "enqueued under the lock → admission pops the request"),
     ("admit", "engine",
      "admission pop → slot installed (prefix match, evict-to-fit, shed "
      "check, CoW queue — matched-prefix time lands HERE, not prefill)"),
@@ -109,10 +113,10 @@ PHASES = (
 #: ENGINE_PHASES[i]; the integer constants below are what the engine's
 #: hot call sites pass to charge() (no per-stamp dict lookups)
 ENGINE_PHASES = (
-    "queue", "admit", "cow_fork", "prefill", "decode", "spec_verify",
+    "lock", "queue", "admit", "cow_fork", "prefill", "decode", "spec_verify",
     "preempt",
 )
-QUEUE, ADMIT, COW_FORK, PREFILL, DECODE, SPEC_VERIFY, PREEMPT = range(
+LOCK, QUEUE, ADMIT, COW_FORK, PREFILL, DECODE, SPEC_VERIFY, PREEMPT = range(
     1, len(ENGINE_PHASES) + 1
 )
 
@@ -165,7 +169,7 @@ def _metrics() -> dict:
             "phase": Histogram(
                 "llm_request_phase_s",
                 "per-request latency attributed by phase (proxy/dispatch/"
-                "queue/admit/cow_fork/prefill/decode/spec_verify/preempt/"
+                "lock/queue/admit/cow_fork/prefill/decode/spec_verify/preempt/"
                 "stream/total)",
                 boundaries=_PHASE_BOUNDARIES,
                 tag_keys=("phase",),
@@ -189,7 +193,7 @@ def new_ledger(t: float) -> list:
 
 def charge(led: list, idx: int, now: float) -> None:
     """Attribute the interval since the last stamp to engine phase
-    ``idx`` (one of the module's QUEUE..PREEMPT constants) and advance
+    ``idx`` (one of the module's LOCK..PREEMPT constants) and advance
     the cursor. Two float ops — the ≤2µs/stamp budget's whole cost."""
     led[idx] += now - led[0]
     led[0] = now

@@ -386,6 +386,29 @@ def span(name: str, **attributes: Any):
     return _Span(name, attributes)
 
 
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def annotate(name: str, **kw: Any):
+    """A span on the PROFILER's clock: ``jax.profiler.TraceAnnotation``,
+    nothing more.  While ``jax.profiler.start_trace`` is running in this
+    process the region lands in the same ``.xplane.pb`` as the device's
+    ops, on the same clock, so an idle gap of the device can be put down
+    to what the host was doing; ``kw`` become the event's arguments.  With
+    no trace running, entering and leaving costs well under a microsecond
+    and records nothing — so call sites enter it always, with no switch.
+
+    This is the clock new timing instruments (ROADMAP D5): ``span()``
+    above writes the Python ring that ``obs timeline`` reads, which no
+    device event shares a clock with."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **kw)
+
+
 def _jsonable(v: Any):
     try:
         json.dumps(v)

@@ -1,0 +1,255 @@
+"""The engine step accounts for itself (ISSUE 23): one ``perf_counter``
+timing per host phase feeds a ``TraceAnnotation`` on the profiler's clock
+and a counter in ``stats()``; the submit lock wait is a counter and the
+``lock`` leg of the request's phase ledger; jitted steps carry named
+scopes and the Pallas kernels names.  CPU, tiny model."""
+
+import glob
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm import EngineConfig, LLMEngine
+from ray_tpu.llm.engine import STEP_PHASES, STEP_WALL_BOUNDS_S
+from ray_tpu.llm.scheduler import SamplingParams
+from ray_tpu.models.gptj import GPTJConfig, gptj_init
+from ray_tpu.util import phases
+
+TINY = GPTJConfig(vocab_size=128, seq_len=64, d_model=64, n_layers=2, n_heads=4,
+                  rotary_dim=8, remat=False, attn_impl="xla", fused_loss=False,
+                  dtype="float32")
+#: steps long enough (milliseconds on a CPU) that the ~0.1 ms of Python
+#: between the phases of a step is under the 5% the account may miss
+WIDER = GPTJConfig(vocab_size=2048, seq_len=64, d_model=256, n_layers=4, n_heads=4,
+                   rotary_dim=8, remat=False, attn_impl="xla", fused_loss=False,
+                   dtype="float32")
+ENGINE = dict(max_slots=2, num_blocks=32, block_size=4, max_blocks_per_seq=12,
+              prefill_chunk=8)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return gptj_init(jax.random.PRNGKey(0), TINY)
+
+
+def _engine(params, cfg=TINY, **kw):
+    eng = LLMEngine(cfg, params, EngineConfig(**{**ENGINE, **kw}))
+    eng.warmup()
+    return eng
+
+
+def _delta(after, before):
+    if isinstance(after, dict):
+        return {k: _delta(after[k], before[k]) for k in after}
+    if isinstance(after, list):
+        return [a - b for a, b in zip(after, before)]
+    return after - before
+
+
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_stats_account_for_the_step(spec_k):
+    eng = _engine(gptj_init(jax.random.PRNGKey(0), WIDER), WIDER, spec_k=spec_k)
+    for seed in range(3):  # one more request than slots: a queue, prefills, decodes
+        eng.submit([5, 9, 7, 5, 9, 7, 5, 9, 3 + seed], SamplingParams(max_tokens=24))
+    before = eng.stats()
+    assert set(before["step_phase_s"]) == set(STEP_PHASES)
+    assert set(before["loop"]) == {"step_wall_s", "lock_wait_s", "idle_s",
+                                   "step_wall_hist", "step_wall_bounds_s"}
+    assert set(before["submit"]) == {"n", "lock_wait_s", "lock_wait_max_s"}
+    assert set(before["queue"]) == {"admitted", "wait_s"}
+    assert before["loop"]["step_wall_bounds_s"] == list(STEP_WALL_BOUNDS_S)
+    assert STEP_WALL_BOUNDS_S[0] == 0.001 and 64 < STEP_WALL_BOUNDS_S[-1] < 66
+    assert len(before["loop"]["step_wall_hist"]) == len(STEP_WALL_BOUNDS_S) + 1
+    for _ in range(20):
+        assert eng.step()
+    after = eng.stats()
+    d = _delta({k: after[k] for k in ("loop", "step_phase_s", "steps")},
+               {k: before[k] for k in ("loop", "step_phase_s", "steps")})
+    assert d["steps"] == 20 and sum(d["loop"]["step_wall_hist"]) == 20
+    wall, phase_sum = d["loop"]["step_wall_s"], sum(d["step_phase_s"].values())
+    assert wall > 0 and abs(phase_sum - wall) <= 0.05 * wall, (phase_sum, wall)
+    assert all(v >= 0 for v in d["step_phase_s"].values())
+    # every phase a plain step runs was charged; draft only when speculating
+    ran = {k for k, v in d["step_phase_s"].items() if v > 0}
+    assert ran >= set(STEP_PHASES) - {"draft"}, ran
+    assert ("draft" in ran) == (spec_k > 0)
+    assert after["t_read"] >= before["t_read"] > time.time() - 60
+    assert after["retraces"] == 0
+
+
+def test_submit_lock_wait_is_a_counter_and_the_ledger_lock_leg(params):
+    eng = _engine(params)
+    held = threading.Event()
+
+    def hold():
+        with eng._lock:
+            held.set()
+            time.sleep(0.2)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert held.wait(5)
+    req = eng.submit([1, 2, 3], SamplingParams(max_tokens=2))
+    t.join(5)
+    assert not t.is_alive()
+    s = eng.stats()["submit"]
+    assert s["n"] >= 1 and s["lock_wait_s"] >= 0.19 and s["lock_wait_max_s"] >= 0.19
+    assert req.phase_led[phases.LOCK] >= s["lock_wait_max_s"] - 1e-6
+    assert req.phase_led[phases.QUEUE] == 0.0  # the lock is no longer billed to the queue
+    while not req.finished:
+        eng.step()
+    # the ledger's identity still holds with the new leg in it
+    assert abs(sum(req.phase_led[1:]) - (req.phase_led[0] - req.arrival_t)) < 1e-6
+
+
+def test_queue_wait_counts_what_waits_for_a_slot(params):
+    eng = _engine(params, max_slots=1)
+    base = eng.stats()["queue"]
+    reqs = [eng.submit([1, 2, 3, 4 + i], SamplingParams(max_tokens=6)) for i in range(2)]
+    while not all(r.finished for r in reqs):
+        eng.step()
+    q = eng.stats()["queue"]
+    assert q["admitted"] - base["admitted"] == 2
+    # the second request sat in the queue while the first held the only slot
+    assert q["wait_s"] - base["wait_s"] > 0.0
+    led = reqs[1].phase_led
+    assert abs(led[phases.QUEUE] - (q["wait_s"] - base["wait_s"])) < 0.05
+
+
+def test_idle_loop_time_is_idle_not_step(params):
+    eng = _engine(params)
+    before = eng.stats()["loop"]
+    stop = threading.Event()
+    t = threading.Thread(target=eng.run_loop, args=(stop,))
+    t.start()
+    time.sleep(0.3)
+    stop.set()
+    t.join(5)
+    assert not t.is_alive()
+    after = eng.stats()["loop"]
+    assert after["idle_s"] - before["idle_s"] >= 0.25
+    assert after["step_wall_s"] == before["step_wall_s"]
+
+
+def test_profiler_trace_holds_every_phase_inside_the_step(params, tmp_path):
+    """Through the deployment's own hook: the replica is the process that
+    can trace the chip."""
+    from jax.profiler import ProfileData
+
+    from ray_tpu.serve.llm import LLMDeployment
+
+    dep = LLMDeployment(model="gptj", model_cfg=TINY, params=params,
+                        engine_config=EngineConfig(**ENGINE), warmup=True)
+    try:
+        assert dep.start_trace(str(tmp_path)) == str(tmp_path)
+        out = dep.generate([5, 9, 7, 5, 9, 7], max_tokens=5)
+        time.sleep(0.05)  # a few idle ticks of the loop inside the trace
+        dep.stop_trace()
+    finally:
+        dep._stop.set()
+        dep._loop.join(5)
+    assert len(out) == 5
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1]
+    spans = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events if e.name.startswith("llm.")
+    ]
+    steps = [(a, b) for n, a, b in spans if n == "llm.step"]
+    assert len(steps) >= 3
+    inner = {n for n, _a, _b in spans if n.startswith("llm.step.")}
+    assert inner >= {"llm.step." + k for k in STEP_PHASES if k != "draft"}, inner
+    for n, a, b in spans:
+        if n.startswith("llm.step."):
+            assert any(s <= a and b <= e for s, e in steps), n
+    names = {n for n, _a, _b in spans}
+    assert {"llm.loop.lock_wait", "llm.loop.idle", "llm.submit.lock_wait"} <= names
+
+
+def _scoped(text, scope):
+    """``scope`` as one segment of an op's name stack; autodiff wraps the
+    outermost segment (``jvp(ce)``, ``transpose(jvp(ce))``)."""
+    return re.search(r'[/("]' + re.escape(scope) + r'[/)"]', text) is not None
+
+
+@pytest.mark.parametrize("site,scopes", [
+    ("decode", ("embed", "qkv", "kv_write", "paged_attention", "attn_out", "mlp",
+                "lm_head", "sample")),
+    ("verify", ("embed", "qkv", "kv_write", "paged_attention", "attn_out", "mlp",
+                "lm_head", "sample")),
+    ("prefill", ("embed", "qkv", "kv_write", "paged_attention", "attn_out", "mlp",
+                 "lm_head")),
+    ("fork", ("kv_fork",)),
+])
+def test_lowered_steps_carry_the_scope_names(params, site, scopes):
+    eng = _engine(params, spec_k=2)
+    fn, args, static = eng.runner._first_operands[site]
+    text = fn.lower(*args, **static).as_text(debug_info=True)
+    missing = [s for s in scopes if not _scoped(text, s)]
+    assert not missing, missing
+    assert eng.stats()["retraces"] == 0
+
+
+def test_train_step_carries_the_scope_names():
+    import optax
+
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.train_step import build_train_step
+
+    cfg = GPTConfig(vocab_size=128, seq_len=32, d_model=32, n_layers=2, n_heads=2,
+                    attn_impl="xla")
+    mesh = make_mesh(devices=jax.devices()[:1])
+    init_fn, step_fn = build_train_step(
+        lambda p, b: gpt_loss(cfg, p, b), optax.adamw(1e-3), mesh)
+    state = init_fn(gpt_init(jax.random.PRNGKey(0), cfg))
+    text = step_fn.lower(state, jnp.zeros((2, 17), jnp.int32)).as_text(debug_info=True)
+    missing = [s for s in ("attn", "mlp", "ce", "optimizer") if not _scoped(text, s)]
+    assert not missing, missing
+
+
+def _tpu_kernels(fn, *args):
+    """Names of the Mosaic kernels in ``fn`` lowered FOR a TPU (no chip
+    and no libtpu needed: lowering only)."""
+    from ray_tpu.util.device_prof import mosaic_kernels
+
+    return sorted(mosaic_kernels(jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))))
+
+
+@pytest.mark.parametrize("w,name", [(1, "paged_attention_decode"),
+                                    (3, "paged_attention_verify")])
+def test_paged_kernel_is_named(monkeypatch, w, name):
+    from ray_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    q = jnp.zeros((2, w, 4, 128), jnp.bfloat16)
+    pool = jnp.zeros((8, 4, 8, 128), jnp.bfloat16)
+    tables = jnp.zeros((2, 4), jnp.int32)
+    pos = jnp.zeros((2, w), jnp.int32)
+    if w == 1:
+        got = _tpu_kernels(
+            lambda q, k, v, t, n: pa.paged_attention(q, k, v, t, n, impl="pallas"),
+            q[:, 0], pool, pool, tables, pos[:, 0] + 1)
+    else:
+        got = _tpu_kernels(
+            lambda q, k, v, t, p: pa.paged_verify_attention(q, k, v, t, p, impl="pallas"),
+            q, pool, pool, tables, pos)
+    assert got == [name]
+
+
+def test_flash_kernels_are_named(monkeypatch):
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert _tpu_kernels(lambda q: fa.flash_attention(q, q, q), q) == ["flash_fwd"]
+    assert _tpu_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
