@@ -387,15 +387,22 @@ def phase_kernels(args) -> None:
         )
 
     # paged decode + verify at the serve phase's shapes, then at the local
-    # head count of tp=4; the last row is the dispatch rule's boundary
+    # head count of tp=4; the last row is the dispatch rule's boundary.
+    # Both paths read the pool the way the jitted steps hand it over
+    # (model_runner._layer_loop): every layer's blocks in one (layers * nb,
+    # ...) view, the tables offset to a layer that is not the first
     slots, tmax, nb, w = (4, 40, 256, 4) if on_chip else (4, 6, 24, 4)
+    layers, layer = 3, 2
     paged_shapes = ((16, 16, 256), (4, 16, 256), (16, 8, 128))
     for heads, bs, d in paged_shapes if on_chip else ((2, 4, 16),):
         impl = "auto" if on_chip else "pallas"
         if on_chip:
             check(pa.auto_impl(bs, d) == "pallas", f"auto rule at {bs}x{d}")
-        kp, vp = rnd(1, (nb, heads, bs, d)), rnd(2, (nb, heads, bs, d))
-        tables = jax.random.randint(jax.random.PRNGKey(3), (slots, tmax), 1, nb)
+        kp = rnd(1, (layers * nb, heads, bs, d))
+        vp = rnd(2, (layers * nb, heads, bs, d))
+        tables = layer * nb + jax.random.randint(
+            jax.random.PRNGKey(3), (slots, tmax), 1, nb
+        )
         cap = tmax * bs
         base = jnp.array([0, bs + 1, cap // 2 + 3, cap - w - 1], jnp.int32)
         positions = base[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]

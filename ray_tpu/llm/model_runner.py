@@ -46,6 +46,7 @@ retrace.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any
 
@@ -96,14 +97,48 @@ def _rotary_rows(x: jax.Array, positions: jax.Array, rotary_dim: int) -> jax.Arr
     return jnp.concatenate([out, pas], axis=-1) if pas.shape[-1] else out
 
 
-def _scatter_kv(pool_l: jax.Array, vals: jax.Array, phys: jax.Array, off: jax.Array):
-    """Write per-row k or v into physical blocks.  pool_l: (num_blocks,
-    heads, block, d); vals: (n, heads, d); phys/off: (n,) int32."""
-    n, heads, _ = vals.shape
+def _scatter_kv(pool: jax.Array, vals: jax.Array, phys: jax.Array, off: jax.Array):
+    """Write per-row k or v into physical blocks of the WHOLE pool, seen as
+    ``_layer_loop`` carries it.  pool: (layers * num_blocks, heads, block,
+    d); vals: (n, heads, d); phys: (n,) int32 block ids IN THAT VIEW (the
+    layer's first block + the table's entry); off: (n,) int32."""
+    heads = vals.shape[1]
     with jax.named_scope("kv_write"):
-        return pool_l.at[
+        return pool.at[
             phys[:, None], jnp.arange(heads)[None, :], off[:, None], :
         ].set(vals)
+
+
+def _layer_loop(blocks, x, k_pool, v_pool, layer_fn):
+    """THE layer loop of every jitted step (decode, verify, prefill; the
+    three tensor-parallel shard bodies): scan over the stacked layer
+    weights with the pools as CARRY, whole, so each layer scatters its
+    rows into the buffer the caller donated and attention reads that same
+    buffer.  A pool that rides a scan as ``xs``/``ys`` is sliced per
+    layer and stacked again: six pool-sized copies a step on a v5e
+    (PERF.md, PR 24).
+
+    k_pool/v_pool: (L, NB, H, BS, D).  Inside the loop they are the free
+    (L * NB, H, BS, D) view, in which layer ``l``'s block ``b`` is block
+    ``l * NB + b``: ``layer_fn(x, layer, k, v, base) -> (x, k, v)`` gets
+    that view and ``base = l * NB``, and adds ``base`` to every block id
+    it writes (``_scatter_kv``) or reads (the block tables handed to
+    ``ops.paged_attention``).  Returns (x, k_pool, v_pool), pools in
+    their own shape."""
+    n_layers, nb = k_pool.shape[:2]
+    view = (n_layers * nb,) + k_pool.shape[2:]
+
+    def body(carry, inputs):
+        x, k, v = carry
+        layer, base = inputs
+        return layer_fn(x, layer, k, v, base), None
+
+    (x, k, v), _ = jax.lax.scan(
+        body,
+        (x, k_pool.reshape(view), v_pool.reshape(view)),
+        (blocks, jnp.arange(n_layers, dtype=jnp.int32) * nb),
+    )
+    return x, k.reshape(k_pool.shape), v.reshape(v_pool.shape)
 
 
 def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
@@ -181,10 +216,12 @@ class PagedModelRunner:
         # the tensor-parallel subclass (llm.multichip) narrows this to its
         # per-device head group and reuses _qkv_rows unchanged
         self.n_local_heads = cfg.n_heads
-        # donate the pool buffers: the scatter of each step's k/v updates
-        # in place instead of copying the whole pool every call (the pool
-        # is the biggest array in inference — a per-step copy would cost
-        # more than the step's math)
+        # donate the pool buffers: a step writes its rows into the buffers
+        # it was given and hands the same buffers back.  Donation alone did
+        # not make that so: it takes the pool as the layer loop's CARRY
+        # (_layer_loop); as the scan's xs/ys the pool was copied six times a
+        # step, more than the step's math.  tests/test_llm_pool_inplace.py
+        # holds every step to it through the compiled program's temp size
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
@@ -301,6 +338,33 @@ class PagedModelRunner:
                 out = out + layer["attn_out"]["bias"].astype(dt)
         return out
 
+    def _qkv_write(self, x, layer, k, v, base, positions, phys, off):
+        """The head of a layer, shared with the tensor-parallel runner:
+        ln1, the rows' q/k/v, and their k/v written into the whole pools
+        (``_layer_loop``'s view) at block ``base + phys`` — the ONE place
+        a write is offset to its layer.  Returns (ln1, q, k, v)."""
+        ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+        q, kr, vr = self._qkv_rows(layer, ln1, positions)
+        blocks = base + phys
+        k = _scatter_kv(k, kr.astype(k.dtype), blocks, off)
+        v = _scatter_kv(v, vr.astype(v.dtype), blocks, off)
+        return ln1, q, k, v
+
+    def _layer(self, x, layer, k, v, base, positions, phys, off, attend):
+        """One transformer layer over the whole pools (``_layer_loop``'s
+        view; ``base`` is this layer's first block there).
+        ``attend(q, k, v, base) -> (rows, d_model)`` supplies the step
+        shape's paged attention, its block tables offset by ``base``."""
+        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, phys, off)
+        att = self._attn_out(layer, attend(q, k, v, base))
+        if self.arch == "gptj":
+            out = x + att + self._mlp(layer, ln1)  # parallel residual
+        else:
+            h = x + att
+            ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
+            out = h + self._mlp(layer, ln2)
+        return out, k, v
+
     def _embed(self, params, tokens, positions):
         # params flows through the TRACED argument, never self.params: the
         # jitted executables cache across weight hot-swaps
@@ -347,36 +411,17 @@ class PagedModelRunner:
         phys = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
         off = positions % bs
         lengths = positions + 1
-        runner = self
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
-            if runner.arch == "gptj":
-                h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, h, positions)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_attention(
-                    q, k_l, v_l, tables, lengths, impl=runner.attn_impl
-                ).astype(x.dtype)
-                att = runner._attn_out(layer, att.reshape(x.shape[0], cfg.d_model))
-                out = x + att + runner._mlp(layer, h)  # parallel residual
-            else:
-                ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, ln1, positions)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_attention(
-                    q, k_l, v_l, tables, lengths, impl=runner.attn_impl
-                ).astype(x.dtype)
-                h = x + runner._attn_out(layer, att.reshape(x.shape[0], cfg.d_model))
-                ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-                out = h + runner._mlp(layer, ln2)
-            return out, (k_l, v_l)
+        def attend(q, k, v, base):
+            return paged_attention(
+                q, k, v, tables + base, lengths, impl=self.attn_impl
+            ).astype(x.dtype).reshape(x.shape[0], cfg.d_model)
 
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._layer, positions=positions, phys=phys, off=off, attend=attend
+            ),
         )
         logits = self._lm_head(params, x)  # (S, V)
         nxt, logp = _sample_rows(logits, seeds, counters, temp, top_k, top_p)
@@ -426,38 +471,18 @@ class PagedModelRunner:
             0,
         )
         off = pos_flat % bs
-        runner = self
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
-            if runner.arch == "gptj":
-                h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, h, pos_flat)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_verify_attention(
-                    q.reshape(S, W, cfg.n_heads, cfg.head_dim),
-                    k_l, v_l, tables, positions, impl=runner.attn_impl,
-                ).astype(x.dtype)
-                att = runner._attn_out(layer, att.reshape(S * W, cfg.d_model))
-                out = x + att + runner._mlp(layer, h)  # parallel residual
-            else:
-                ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, ln1, pos_flat)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_verify_attention(
-                    q.reshape(S, W, cfg.n_heads, cfg.head_dim),
-                    k_l, v_l, tables, positions, impl=runner.attn_impl,
-                ).astype(x.dtype)
-                h = x + runner._attn_out(layer, att.reshape(S * W, cfg.d_model))
-                ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-                out = h + runner._mlp(layer, ln2)
-            return out, (k_l, v_l)
+        def attend(q, k, v, base):
+            return paged_verify_attention(
+                q.reshape(S, W, cfg.n_heads, cfg.head_dim),
+                k, v, tables + base, positions, impl=self.attn_impl,
+            ).astype(x.dtype).reshape(S * W, cfg.d_model)
 
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._layer, positions=pos_flat, phys=phys, off=off, attend=attend
+            ),
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)  # (S, W, V)
         n_acc, out, logp = _verify_rows(
@@ -505,36 +530,17 @@ class PagedModelRunner:
         x = self._embed(params, tokens, positions)  # (chunk, d)
         phys = jnp.where(valid, table[positions // bs], 0)  # padded → trash
         off = positions % bs
-        runner = self
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
-            if runner.arch == "gptj":
-                h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, h, positions)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_prefill_attention_xla(
-                    q, k_l, v_l, table, positions
-                ).astype(x.dtype)
-                att = runner._attn_out(layer, att.reshape(chunk, cfg.d_model))
-                out = x + att + runner._mlp(layer, h)
-            else:
-                ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-                q, k, v = runner._qkv_rows(layer, ln1, positions)
-                k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-                v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-                att = paged_prefill_attention_xla(
-                    q, k_l, v_l, table, positions
-                ).astype(x.dtype)
-                h = x + runner._attn_out(layer, att.reshape(chunk, cfg.d_model))
-                ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-                out = h + runner._mlp(layer, ln2)
-            return out, (k_l, v_l)
+        def attend(q, k, v, base):
+            return paged_prefill_attention_xla(
+                q, k, v, table + base, positions
+            ).astype(x.dtype).reshape(chunk, cfg.d_model)
 
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._layer, positions=positions, phys=phys, off=off, attend=attend
+            ),
         )
         last = x[jnp.maximum(n_valid - 1, 0)]  # (d,)
         logits = self._lm_head(params, last[None, :])[0]  # (V,)

@@ -55,6 +55,7 @@ tokens between a cold prompt and its prefix hit on four v5e chips
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -65,9 +66,9 @@ from ray_tpu.llm.cache import CacheConfig, KVBlockPool
 from ray_tpu.llm.model_runner import (
     PagedModelRunner,
     _fork_impl,
+    _layer_loop,
     _layernorm,
     _sample_rows,
-    _scatter_kv,
     _verify_rows,
 )
 from ray_tpu.ops.paged_attention import (
@@ -335,16 +336,18 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
     # -- per-device layer math --------------------------------------------
 
-    def _tp_layer(self, x, layer, k_l, v_l, positions, phys, off, attend):
-        """One transformer layer on THIS device's head/ff shard.
-        ``attend(q, k_l, v_l) -> (rows, local_d)`` supplies the step
-        shape's paged attention over the local head group; the two
-        row-parallel projections produce partial sums reduced over
-        "tp" by ``_tp_sum`` (replicated biases added once, after)."""
+    def _tp_layer(self, x, layer, k, v, base, positions, phys, off, attend):
+        """One transformer layer on THIS device's head/ff shard, over the
+        whole local pools (``_layer_loop``'s view; ``base`` is this
+        layer's first block there).  ``attend(q, k, v, base) -> (rows,
+        local_d)`` supplies the step shape's paged attention over the
+        local head group; the two row-parallel projections produce
+        partial sums reduced over "tp" by ``_tp_sum`` (replicated biases
+        added once, after)."""
         dt = x.dtype
 
-        def attn_partial(q, k_l, v_l):
-            att = attend(q, k_l, v_l)  # paged_attention names its own scope
+        def attn_partial(q, k, v):
+            att = attend(q, k, v, base)  # paged_attention names its own scope
             with jax.named_scope("attn_out"):
                 return att @ layer["attn_out"]["kernel"].astype(dt)
 
@@ -356,27 +359,18 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 )
                 return mid @ layer["mlp_out"]["kernel"].astype(dt)
 
+        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, phys, off)
+        att_p = attn_partial(q, k, v)
         if self.arch == "gptj":
-            h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-            q, k, v = self._qkv_rows(layer, h, positions)
-            k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-            v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-            att_p = attn_partial(q, k_l, v_l)
-            mlp_p = mlp_partial(h)
             # parallel residual: attention + MLP partials share ONE
             # fused reduction per layer (half the collectives of the
             # sequential-residual arch below)
             out = (
                 x
-                + _tp_sum(att_p + mlp_p, "tp")
+                + _tp_sum(att_p + mlp_partial(ln1), "tp")
                 + layer["mlp_out"]["bias"].astype(dt)
             )
         else:
-            ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-            q, k, v = self._qkv_rows(layer, ln1, positions)
-            k_l = _scatter_kv(k_l, k.astype(k_l.dtype), phys, off)
-            v_l = _scatter_kv(v_l, v.astype(v_l.dtype), phys, off)
-            att_p = attn_partial(q, k_l, v_l)
             h = (
                 x
                 + _tp_sum(att_p, "tp")
@@ -388,7 +382,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 + _tp_sum(mlp_partial(ln2), "tp")
                 + layer["mlp_out"]["bias"].astype(dt)
             )
-        return out, k_l, v_l
+        return out, k, v
 
     # -- shard bodies ------------------------------------------------------
     # Same control flow as the PagedModelRunner._*_impl bodies, with the
@@ -406,24 +400,18 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         phys = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
         off = positions % bs
         lengths = positions + 1
-        runner = self
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
+        def attend(q, k, v, base):
+            return paged_attention(
+                q, k, v, tables + base, lengths, impl=self.attn_impl
+            ).astype(x.dtype).reshape(S, -1)
 
-            def attend(q, k_loc, v_loc):
-                return paged_attention(
-                    q, k_loc, v_loc, tables, lengths, impl=runner.attn_impl
-                ).astype(x.dtype).reshape(S, -1)
-
-            out, k_l, v_l = runner._tp_layer(
-                x, layer, k_l, v_l, positions, phys, off, attend
-            )
-            return out, (k_l, v_l)
-
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._tp_layer, positions=positions, phys=phys, off=off,
+                attend=attend,
+            ),
         )
         logits = self._lm_head(params, x)
         nxt, logp = _sample_rows(logits, seeds, counters, temp, top_k, top_p)
@@ -450,26 +438,20 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             0,
         )
         off = pos_flat % bs
-        runner = self
         nh, hd = self.n_local_heads, cfg.head_dim
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
+        def attend(q, k, v, base):
+            return paged_verify_attention(
+                q.reshape(S, W, nh, hd), k, v, tables + base, positions,
+                impl=self.attn_impl,
+            ).astype(x.dtype).reshape(S * W, -1)
 
-            def attend(q, k_loc, v_loc):
-                return paged_verify_attention(
-                    q.reshape(S, W, nh, hd), k_loc, v_loc, tables, positions,
-                    impl=runner.attn_impl,
-                ).astype(x.dtype).reshape(S * W, -1)
-
-            out, k_l, v_l = runner._tp_layer(
-                x, layer, k_l, v_l, pos_flat, phys, off, attend
-            )
-            return out, (k_l, v_l)
-
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._tp_layer, positions=pos_flat, phys=phys, off=off,
+                attend=attend,
+            ),
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)
         n_acc, out, logp = _verify_rows(
@@ -490,24 +472,18 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         x = self._embed(params, tokens, positions)
         phys = jnp.where(valid, table[positions // bs], 0)
         off = positions % bs
-        runner = self
 
-        def one_layer(carry, inputs):
-            x = carry
-            layer, k_l, v_l = inputs
+        def attend(q, k, v, base):
+            return paged_prefill_attention_xla(
+                q, k, v, table + base, positions
+            ).astype(x.dtype).reshape(chunk, -1)
 
-            def attend(q, k_loc, v_loc):
-                return paged_prefill_attention_xla(
-                    q, k_loc, v_loc, table, positions
-                ).astype(x.dtype).reshape(chunk, -1)
-
-            out, k_l, v_l = runner._tp_layer(
-                x, layer, k_l, v_l, positions, phys, off, attend
-            )
-            return out, (k_l, v_l)
-
-        x, (k_pool, v_pool) = jax.lax.scan(
-            one_layer, x, (params["blocks"], k_pool, v_pool)
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._tp_layer, positions=positions, phys=phys, off=off,
+                attend=attend,
+            ),
         )
         last = x[jnp.maximum(n_valid - 1, 0)]
         logits = self._lm_head(params, last[None, :])[0]

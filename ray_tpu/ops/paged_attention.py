@@ -7,10 +7,15 @@ the cluster-wide KV cache is a fixed pool of physical blocks
     k_pool, v_pool : (num_blocks, heads, block_size, head_dim)
 
 and each decode slot owns a *block table* mapping its logical block index
-to a physical block id.  Static shapes throughout — the pool size, block
-size, and table width are compile-time constants; only the table CONTENTS
-and per-slot lengths are data — so the engine jits one decode step and
-reuses it for every admission/eviction pattern.
+to a physical block id.  The functions here know nothing of layers: the
+jitted steps (``llm.model_runner._layer_loop``) hand every layer the
+WHOLE engine pool as its free ``(layers * blocks_per_layer, heads,
+block_size, head_dim)`` view, with block tables offset by ``layer *
+blocks_per_layer`` — a slice ``pool[layer]`` as the operand would be
+materialized, a pool-sized copy per layer.  Static shapes throughout —
+the pool size, block size, and table width are compile-time constants;
+only the table CONTENTS and per-slot lengths are data — so the engine
+jits one decode step and reuses it for every admission/eviction pattern.
 
 Three entry points:
 
@@ -47,9 +52,10 @@ backend when the pool tiles (block_size a multiple of 8, head_dim of
 
 Convention: table entries past a sequence's allocation MUST point at a
 valid physical block (the engine pads with block 0, its reserved trash
-block); masking by ``lengths``/``positions`` makes their values
-irrelevant.  Slots with ``length == 0`` produce finite garbage
-(big-negative masking, never NaN) — callers discard inactive slots.
+block — in the whole-pool view, the layer's own block 0); masking by
+``lengths``/``positions`` makes their values irrelevant.  Slots with
+``length == 0`` produce finite garbage (big-negative masking, never NaN)
+— callers discard inactive slots.
 """
 
 from __future__ import annotations

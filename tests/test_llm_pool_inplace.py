@@ -1,0 +1,246 @@
+"""The KV pool stays where it is: every jitted step writes and reads the
+WHOLE pool in place (``model_runner._layer_loop``, PR 24).
+
+Two properties per step (decode, prefill, verify) x arch (gpt, gptj), and
+the three tensor-parallel shard bodies at tp=2 on host devices:
+
+* **no pool-sized temporary** — the compiled step's
+  ``memory_analysis().temp_size_in_bytes`` stays under half of ONE pool's
+  bytes at a pool made large against the model.  With the pool riding
+  the layer scan as ``xs``/``ys`` (the parent of PR 24) XLA slices a layer
+  out, scatters, and stacks the layers into a second buffer: the
+  temporary is then the size of both pools, on this CPU backend as on
+  the v5e, so the temp size is what is asserted (not the HLO text).
+* **right rows, right layer, nothing else** — on a pool filled with
+  noise (every element distinct: a stray write shows, and so does a read
+  from another layer's blocks) a step changes the pool ONLY at ``(every
+  layer, phys, :, off, :)`` of the rows it was fed, and layer ``l``'s
+  rows hold layer ``l``'s k/v: the reference is a plain Python loop over
+  layers on per-layer pools with ``.at[].set`` and the unshifted block
+  tables, in float32.  That catches an ``l * NB`` offset applied to the
+  wrong table, or a write into the wrong layer.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from ray_tpu.llm.model_runner import (  # noqa: E402
+    PagedModelRunner,
+    _layernorm,
+    _sample_rows,
+    _verify_rows,
+)
+from ray_tpu.llm.multichip import TensorParallelPagedModelRunner  # noqa: E402
+from ray_tpu.models.gpt import GPTConfig, gpt_init  # noqa: E402
+from ray_tpu.models.gptj import GPTJConfig, gptj_init  # noqa: E402
+from ray_tpu.ops import paged_attention as pa  # noqa: E402
+from ray_tpu.parallel.mesh import make_tp_mesh  # noqa: E402
+
+# a pool large against the model: 3 layers x 512 blocks of 4 tokens, 4 heads
+# x 16 = 1.5 MB a pool in float32, against ~0.2 MB of temporaries for the rest
+L, NB, BS, TMAX, HEADS, HD = 3, 512, 4, 6, 4, 16
+SLOTS, CHUNK, W = 4, 8, 3
+ARCHS = {
+    "gpt": (
+        GPTConfig(
+            vocab_size=96, d_model=HEADS * HD, n_layers=L, n_heads=HEADS,
+            seq_len=64, dtype="float32",
+        ),
+        gpt_init,
+    ),
+    "gptj": (
+        GPTJConfig(
+            vocab_size=96, seq_len=64, d_model=HEADS * HD, n_layers=L,
+            n_heads=HEADS, rotary_dim=8, dtype="float32", remat=False,
+            attn_impl="xla", fused_loss=False,
+        ),
+        gptj_init,
+    ),
+}
+STEPS = ("decode", "prefill", "verify")
+CASES = [(step, arch, 1) for step in STEPS for arch in ARCHS] + [
+    pytest.param(
+        step, "gptj", 2,
+        marks=pytest.mark.skipif(
+            len(jax.devices("cpu")) < 2,
+            reason="needs 2 host devices (conftest's XLA_FLAGS)",
+        ),
+    )
+    for step in STEPS
+]
+
+
+@functools.lru_cache(maxsize=None)
+def _params(arch):
+    cfg, init = ARCHS[arch]
+    return init(jax.random.PRNGKey(0), cfg)
+
+
+def _noise_pools(tp):
+    shape = (L, NB, HEADS, BS, HD)
+    k = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+    if tp > 1:
+        sharding = NamedSharding(make_tp_mesh(tp), P(None, None, "tp"))
+        k, v = jax.device_put(k, sharding), jax.device_put(v, sharding)
+    return k, v
+
+
+def _operands(step):
+    """The step's operands after (params, k_pool, v_pool), and the rows it
+    feeds as (positions (n,), phys (n,), off (n,)) — the last an
+    independent statement of where a row's k/v belongs."""
+    rng = np.random.default_rng(7)
+    greedy = (
+        np.zeros(SLOTS, np.float32), np.zeros(SLOTS, np.int32),
+        np.ones(SLOTS, np.float32), np.zeros(SLOTS, np.uint32),
+        np.zeros(SLOTS, np.int32),
+    )
+    if step == "prefill":
+        table = rng.choice(np.arange(1, NB), TMAX, replace=False).astype(np.int32)
+        start, n_valid = 5, CHUNK - 2   # mid-block start; two padded rows
+        tokens = rng.integers(0, 96, CHUNK).astype(np.int32)
+        pos = start + np.arange(CHUNK)
+        phys = np.where(np.arange(CHUNK) < n_valid, table[pos // BS], 0)
+        ops = (tokens, jnp.int32(start), jnp.int32(n_valid), table)
+        return ops, (pos, phys, pos % BS)
+    tables = rng.choice(np.arange(1, NB), (SLOTS, TMAX), replace=False).astype(np.int32)
+    if step == "decode":
+        # slot 3 is inactive: position 0, an all-trash table
+        positions = np.array([0, 5, 11, 0], np.int32)
+        tables[3] = 0
+        tokens = rng.integers(0, 96, SLOTS).astype(np.int32)
+        phys = tables[np.arange(SLOTS), positions // BS]
+        return (tokens, positions, tables) + greedy, (positions, phys, positions % BS)
+    # verify: slot 2's window runs past the table's reach (-> trash)
+    base = np.array([0, 6, TMAX * BS - 2, 9], np.int32)
+    tokens = rng.integers(0, 96, (SLOTS, W)).astype(np.int32)
+    pos = (base[:, None] + np.arange(W)[None, :]).reshape(-1)
+    row_tables = np.repeat(tables, W, axis=0)
+    logical = np.minimum(pos // BS, TMAX - 1)
+    phys = np.where(
+        pos < TMAX * BS, row_tables[np.arange(SLOTS * W), logical], 0
+    )
+    return (tokens, base, tables) + greedy, (pos, phys, pos % BS)
+
+
+def _runner(arch, tp):
+    cfg, _ = ARCHS[arch]
+    if tp > 1:
+        return TensorParallelPagedModelRunner(cfg, _params(arch), BS, "xla", tp=tp)
+    return PagedModelRunner(cfg, _params(arch), BS, "xla")
+
+
+def _jitted(runner, step, ops):
+    """(jitted fn, static kwargs) of a step, as the runner's wrappers call it."""
+    if step == "prefill" and not hasattr(runner, "tp"):
+        return runner._prefill, {"chunk": len(ops[0])}
+    return getattr(runner, f"_{step}"), {}
+
+
+@pytest.mark.parametrize("step,arch,tp", CASES)
+def test_step_holds_no_pool_sized_temporary(step, arch, tp):
+    runner = _runner(arch, tp)
+    k, v = _noise_pools(tp)
+    ops, _rows = _operands(step)
+    fn, static = _jitted(runner, step, ops)
+    compiled = fn.lower(runner.params, k, v, *ops, **static).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    pool_bytes = k.nbytes // tp  # one pool's bytes on one device
+    assert temp < pool_bytes / 2, (
+        f"{step}/{arch}/tp{tp}: {temp} B of temporaries against a pool of "
+        f"{pool_bytes} B: the step copies the pool"
+    )
+
+
+def _reference(arch, step, ops, rows, k_pool, v_pool):
+    """The same step as a plain Python loop over layers: layer ``l``
+    scatters into and attends over ITS pool ``k_pool[l]`` with the
+    tables as given.  Single-chip float32; the layer math is the
+    runner's own helpers (not under test here), the loop, the writes and
+    the reads are not."""
+    cfg, _ = ARCHS[arch]
+    params = _params(arch)
+    ref = PagedModelRunner(cfg, params, BS, "xla")
+    pos, phys, off = (jnp.asarray(a, jnp.int32) for a in rows)
+    n = pos.shape[0]
+    tokens = jnp.asarray(ops[0]).reshape(-1)
+    x = ref._embed(params, tokens, pos)
+    for l in range(L):
+        layer = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
+        ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+        q, kr, vr = ref._qkv_rows(layer, ln1, pos)
+        heads = jnp.arange(HEADS)[None, :]
+        k_l = k_pool[l].at[phys[:, None], heads, off[:, None], :].set(kr)
+        v_l = v_pool[l].at[phys[:, None], heads, off[:, None], :].set(vr)
+        if step == "decode":
+            att = pa.paged_attention_xla(q, k_l, v_l, ops[2], pos + 1)
+        elif step == "prefill":
+            att = pa.paged_prefill_attention_xla(q, k_l, v_l, ops[3], pos)
+        else:
+            att = pa.paged_verify_attention_xla(
+                q.reshape(SLOTS, W, HEADS, HD), k_l, v_l, ops[2],
+                pos.reshape(SLOTS, W),
+            )
+        att = ref._attn_out(layer, att.reshape(n, HEADS * HD))
+        if arch == "gptj":
+            x = x + att + ref._mlp(layer, ln1)
+        else:
+            h = x + att
+            x = h + ref._mlp(
+                layer, _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
+            )
+        k_pool, v_pool = k_pool.at[l].set(k_l), v_pool.at[l].set(v_l)
+    if step == "prefill":
+        out = (ref._lm_head(params, x[int(ops[2]) - 1][None, :])[0],)
+    elif step == "decode":
+        out = _sample_rows(ref._lm_head(params, x), ops[6], ops[7], *ops[3:6])
+    else:
+        logits = ref._lm_head(params, x).reshape(SLOTS, W, -1)
+        out = _verify_rows(logits, ops[0][:, 1:], ops[6], ops[7], *ops[3:6])
+    return k_pool, v_pool, out
+
+
+@pytest.mark.parametrize("step,arch,tp", CASES)
+def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
+    runner = _runner(arch, tp)
+    k0, v0 = (np.asarray(a) for a in _noise_pools(1))
+    ops, rows = _operands(step)
+    ref_k, ref_v, ref_out = _reference(
+        arch, step, ops, rows, jnp.asarray(k0), jnp.asarray(v0)
+    )
+    fn, static = _jitted(runner, step, ops)
+    k, v = _noise_pools(tp)
+    k1, v1, *out = fn(runner.params, k, v, *ops, **static)
+
+    _pos, phys, off = rows
+    fed = np.zeros((L, NB, BS), bool)
+    fed[:, phys, off] = True
+    # splitting the row-parallel sums over devices moves activations by an
+    # ulp a layer (llm.multichip's module text); one device adds none
+    tol = 1e-4 if tp > 1 else 1e-5
+    for name, before, after, want in (("k", k0, k1, ref_k), ("v", v0, v1, ref_v)):
+        after, want = np.asarray(after), np.asarray(want)
+        changed = after != before                      # (L, NB, H, BS, D)
+        assert not changed.any(axis=(2, 4))[~fed].any(), (
+            f"{name}: written outside the rows fed, at (layer, block, offset) "
+            f"{np.argwhere(changed.any(axis=(2, 4)) & ~fed)[:4].tolist()}"
+        )
+        assert changed.all(axis=(2, 4))[fed].all(), f"{name}: a fed row kept its noise"
+        # block 0 is the trash block: several rows may land on one place
+        np.testing.assert_allclose(
+            after[:, 1:], want[:, 1:], rtol=tol, atol=tol,
+            err_msg=f"{name}: a layer's rows do not hold that layer's k/v",
+        )
+    for got, want in zip(out, ref_out):
+        got, want = np.asarray(got), np.asarray(want)
+        if np.issubdtype(got.dtype, np.integer):
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
