@@ -881,6 +881,9 @@ class LLMEngine:
                 s["prefix_cache"] = self.prefix_cache.stats()
             s["hbm"] = led
             s["retraces"] = self.runner.prof.retraces
+            s["tp"] = self.cfg.tp
+            if self.cfg.tp > 1:
+                s["tp_sum"] = self.runner.tp_sum_stats()
             if self._drafter is not None:
                 s["spec_proposed"] = self._spec_proposed
                 s["spec_accepted"] = self._spec_accepted

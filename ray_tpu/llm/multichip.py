@@ -51,6 +51,14 @@ partials are summed in a fixed device order (``_tp_sum``), not by
 that depends on the element's place in the buffer, which in bf16 moved
 tokens between a cold prompt and its prefix hit on four v5e chips
 (PERF.md, PR 21).
+
+On the device the shard bodies carry the one-chip bodies' scope names
+(``model_runner.SCOPES``) plus ``tp_sum`` with its halves nested
+(``tp_sum/gather``, ``tp_sum/add``).  ``_tp_sum`` also notes itself
+while a shard body is traced, so the runner's ledger of the reduction
+(``tp_sum_stats``: calls and received bytes of each program as it was
+traced) follows the code; ``LLMEngine.stats()`` reports it as
+``tp_sum`` beside ``tp``.  What it costs on the chip: PERF.md section 5.
 """
 
 from __future__ import annotations
@@ -79,16 +87,23 @@ from ray_tpu.ops.paged_attention import (
 from ray_tpu.parallel.mesh import make_tp_mesh
 
 
-def _tp_sum(x: jax.Array, axis: str) -> jax.Array:
+def _tp_sum(x: jax.Array, axis: str, noted: list) -> jax.Array:
     """Sum of every ``axis`` device's ``x``, identical on all of them,
     each element added in device order in float32 whatever its row:
-    gather (exact data movement), then add."""
+    gather (exact data movement), then add.  Both halves sit under the
+    ``tp_sum`` scope, each under a scope of its own, so a trace splits
+    the data movement from the summation.  Each call appends to
+    ``noted`` the bytes a device receives in it (``tp_sum_stats``)."""
     with jax.named_scope("tp_sum"):
-        parts = jax.lax.all_gather(x, axis).astype(jnp.float32)
-        total = parts[0]
-        for i in range(1, parts.shape[0]):
-            total = total + parts[i]
-        return total.astype(x.dtype)
+        with jax.named_scope("gather"):
+            parts = jax.lax.all_gather(x, axis)
+        noted.append((parts.size - x.size) * x.dtype.itemsize)
+        with jax.named_scope("add"):
+            parts = parts.astype(jnp.float32)
+            total = parts[0]
+            for i in range(1, parts.shape[0]):
+                total = total + parts[i]
+            return total.astype(x.dtype)
 
 
 def _spec_for(path) -> P:
@@ -195,6 +210,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         if cfg.d_ff % tp:
             raise ValueError(f"d_ff={cfg.d_ff} not divisible by tp={tp}")
         self.tp = tp
+        #: site -> {calls, bytes} of ``_tp_sum`` in one execution of that
+        #: step program, noted when it was traced (``_tp_layers``)
+        self._tp_sums: dict = {}
         self._mesh = make_tp_mesh(tp)
         # inherited _qkv_rows reshapes to this many heads — the ones
         # whose kernels' column shards live on this device
@@ -334,9 +352,27 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             self._mesh, jax.tree_util.tree_leaves(self.params)
         )
 
+    # -- the reduction's ledger -------------------------------------------
+
+    def tp_sum_stats(self) -> dict:
+        """Count and received bytes (per device) of the ``_tp_sum`` calls
+        in every step launched so far, and ``per_step`` of one execution
+        of each program, as its layer noted them when it was traced
+        (``_tp_layers``): a change of the reduction shows here, not only
+        in a trace."""
+        launched = self.prof.stats()
+        totals = {
+            k: sum(c[k] * launched.get(s, {"calls": 0})["calls"]
+                   for s, c in self._tp_sums.items())
+            for k in ("calls", "bytes")
+        }
+        return dict(totals, per_step=dict(self._tp_sums))
+
     # -- per-device layer math --------------------------------------------
 
-    def _tp_layer(self, x, layer, k, v, base, positions, phys, off, attend):
+    def _tp_layer(
+        self, x, layer, k, v, base, positions, phys, off, attend, noted
+    ):
         """One transformer layer on THIS device's head/ff shard, over the
         whole local pools (``_layer_loop``'s view; ``base`` is this
         layer's first block there).  ``attend(q, k, v, base) -> (rows,
@@ -359,30 +395,48 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 )
                 return mid @ layer["mlp_out"]["kernel"].astype(dt)
 
+        def biased(h, mod, scope):
+            # the replicated bias, added once after the reduction, under
+            # the scope in which the one-chip body adds it
+            with jax.named_scope(scope):
+                return h + layer[mod]["bias"].astype(dt)
+
         ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, phys, off)
         att_p = attn_partial(q, k, v)
         if self.arch == "gptj":
             # parallel residual: attention + MLP partials share ONE
             # fused reduction per layer (half the collectives of the
             # sequential-residual arch below)
-            out = (
-                x
-                + _tp_sum(att_p + mlp_partial(ln1), "tp")
-                + layer["mlp_out"]["bias"].astype(dt)
+            out = biased(
+                x + _tp_sum(att_p + mlp_partial(ln1), "tp", noted),
+                "mlp_out", "mlp",
             )
         else:
-            h = (
-                x
-                + _tp_sum(att_p, "tp")
-                + layer["attn_out"]["bias"].astype(dt)
+            h = biased(
+                x + _tp_sum(att_p, "tp", noted), "attn_out", "attn_out"
             )
             ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-            out = (
-                h
-                + _tp_sum(mlp_partial(ln2), "tp")
-                + layer["mlp_out"]["bias"].astype(dt)
+            out = biased(
+                h + _tp_sum(mlp_partial(ln2), "tp", noted), "mlp_out", "mlp"
             )
         return out, k, v
+
+    def _tp_layers(self, site, params, x, k_pool, v_pool, **rows):
+        """``_layer_loop`` over ``_tp_layer``.  The loop traces its layer
+        once for all of them: what that layer's ``_tp_sum`` calls noted,
+        times the layers, is program ``site``'s line in ``tp_sum_stats``."""
+        noted: list = []
+        out = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(self._tp_layer, noted=noted, **rows),
+        )
+        n_layers = k_pool.shape[0]
+        # written, never read, while tracing: the program's own account
+        # of itself, the one thing meant to be fixed at trace time
+        self._tp_sums[site] = {  # raylint: disable=RL009
+            "calls": n_layers * len(noted), "bytes": n_layers * sum(noted)
+        }
+        return out
 
     # -- shard bodies ------------------------------------------------------
     # Same control flow as the PagedModelRunner._*_impl bodies, with the
@@ -406,12 +460,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 q, k, v, tables + base, lengths, impl=self.attn_impl
             ).astype(x.dtype).reshape(S, -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._tp_layer, positions=positions, phys=phys, off=off,
-                attend=attend,
-            ),
+        x, k_pool, v_pool = self._tp_layers(
+            "decode", params, x, k_pool, v_pool,
+            positions=positions, phys=phys, off=off, attend=attend,
         )
         logits = self._lm_head(params, x)
         nxt, logp = _sample_rows(logits, seeds, counters, temp, top_k, top_p)
@@ -446,12 +497,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 impl=self.attn_impl,
             ).astype(x.dtype).reshape(S * W, -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._tp_layer, positions=pos_flat, phys=phys, off=off,
-                attend=attend,
-            ),
+        x, k_pool, v_pool = self._tp_layers(
+            "verify", params, x, k_pool, v_pool,
+            positions=pos_flat, phys=phys, off=off, attend=attend,
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)
         n_acc, out, logp = _verify_rows(
@@ -478,12 +526,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                 q, k, v, table + base, positions
             ).astype(x.dtype).reshape(chunk, -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._tp_layer, positions=positions, phys=phys, off=off,
-                attend=attend,
-            ),
+        x, k_pool, v_pool = self._tp_layers(
+            "prefill", params, x, k_pool, v_pool,
+            positions=positions, phys=phys, off=off, attend=attend,
         )
         last = x[jnp.maximum(n_valid - 1, 0)]
         logits = self._lm_head(params, last[None, :])[0]

@@ -217,6 +217,33 @@ def test_audit_and_watchdog_pass_sharded(tp):
     assert "all_gather" in text and "all_reduce" not in text
 
 
+@pytest.mark.parametrize("tp", [1, 2, 4])
+def test_stats_carry_tp_and_the_reductions_ledger(tp):
+    """``stats()`` names the mesh size and, under tp, what ``_tp_sum``
+    noted while each step program was traced (one reduction a GPT-J
+    layer, the other devices' float32 partials received), times the
+    launches: the ledger is read off the traced code, not computed."""
+    _, eng = _matrix(tp, 0, True)
+    stats = eng.stats()
+    assert stats["tp"] == tp
+    if tp == 1:
+        assert "tp_sum" not in stats
+        return
+    row = TINY.d_model * 4 * (tp - 1) * TINY.n_layers
+    led = stats["tp_sum"]
+    assert led["per_step"] == {
+        "prefill": {"calls": TINY.n_layers, "bytes": 8 * row},
+        "decode": {"calls": TINY.n_layers, "bytes": 3 * row},
+    }
+    launched = eng.runner.prof.stats()
+    assert led["calls"] == TINY.n_layers * (
+        launched["prefill"]["calls"] + launched["decode"]["calls"]
+    )
+    assert led["bytes"] == row * (
+        8 * launched["prefill"]["calls"] + 3 * launched["decode"]["calls"]
+    )
+
+
 @pytest.mark.parametrize("tp", [2, 4])
 def test_per_device_hbm_ledger(tp):
     """Per-device attribution: the pool splits exactly 1/tp per device,
