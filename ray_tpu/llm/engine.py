@@ -430,6 +430,9 @@ class LLMEngine:
         self._submit_n = 0
         self._submit_lock_wait_s = 0.0
         self._submit_lock_wait_max_s = 0.0
+        # decode / verify batches by the branch the sampler takes on the
+        # device (models.sampling._draw_rows): no row sampled, no sort
+        self._sampler_steps = {"greedy_steps": 0, "sorted_steps": 0}
         # liveness beat, read LOCK-FREE by the watchdog and stream_tokens'
         # stall diagnosis (a wedged step holds the engine lock, so the
         # observers must never need it): (monotonic t of the last completed
@@ -876,6 +879,7 @@ class LLMEngine:
                     "admitted": self.scheduler.admit_count,
                     "wait_s": self.scheduler.queue_wait_s,
                 },
+                "sampler": dict(self._sampler_steps),
             }
             if self.prefix_cache is not None:
                 s["prefix_cache"] = self.prefix_cache.stats()
@@ -1127,6 +1131,13 @@ class LLMEngine:
             self._preemptions += sched.preempt_count - before
             _metrics()["preempt"].inc(sched.preempt_count - before)
 
+    def _note_sampler(self, temp: np.ndarray) -> None:
+        """Count the batch about to be launched by the sampler's own
+        predicate (``any(temp > 0)``; empty slots carry 0): how often the
+        all-greedy skip engages, in ``stats()["sampler"]``."""
+        key = "sorted_steps" if (temp > 0.0).any() else "greedy_steps"
+        self._sampler_steps[key] += 1
+
     def _decode_all(self) -> bool:
         """One batched decode step over every RUNNING slot."""
         import jax
@@ -1165,6 +1176,7 @@ class LLMEngine:
                 # engine loop thread
                 seeds[i] = p.seed & 0xFFFFFFFF
                 counters[i] = len(req.out)
+            self._note_sampler(temp)
         with self._phase("decode_launch"):
             k, v, nxt, logp = self.runner.decode_step(
                 self.pool.k, self.pool.v, tokens, positions, tables,
@@ -1245,6 +1257,7 @@ class LLMEngine:
                 top_p[i] = p.top_p
                 seeds[i] = p.seed & 0xFFFFFFFF
                 counters[i] = len(req.out)
+            self._note_sampler(temp)
         with self._phase("decode_launch", "verify_launch"):
             k, v, n_acc, out, out_lp = self.runner.verify_step(
                 self.pool.k, self.pool.v, tokens, base_pos, tables,

@@ -58,10 +58,7 @@ from ray_tpu._private.compile_cache import ensure_compile_cache
 from ray_tpu.models.gpt import GPTConfig, _layernorm
 from ray_tpu.util.device_prof import JitProfiler, mosaic_kernels
 from ray_tpu.models.gptj import GPTJConfig
-from ray_tpu.models.sampling import (
-    sample_tokens_logprobs,
-    speculative_verify_logprobs,
-)
+from ray_tpu.models.sampling import sample_rows_logprobs, verify_rows_logprobs
 from ray_tpu.ops.paged_attention import (
     paged_attention,
     paged_prefill_attention_xla,
@@ -146,19 +143,10 @@ def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
     from (seeds[i], counters[i]) only, so a request draws the same tokens
     no matter which slot or step it lands in.  Returns (tokens (n,),
     logprobs (n,)) — the chosen-token behavior logprob rides along free
-    (``models.sampling`` module doc)."""
-
-    def one(lg, k, t, kk, pp):
-        tok, lp = sample_tokens_logprobs(
-            lg[None, :], k, t[None], kk[None], pp[None]
-        )
-        return tok[0], lp[0]
-
+    (``models.sampling`` module doc).  One batched call under the ``sample``
+    scope: a batch with no sampled row skips the sort."""
     with jax.named_scope("sample"):
-        keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
-            seeds, counters
-        )
-        return jax.vmap(one)(logits, keys, temp, top_k, top_p)
+        return sample_rows_logprobs(logits, seeds, counters, temp, top_k, top_p)
 
 
 def _abstract(x) -> jax.ShapeDtypeStruct:
@@ -190,7 +178,7 @@ def _verify_rows(logits, draft, seeds, counters, temp, top_k, top_p):
     logits: (S, W, V); draft: (S, W-1).  Returns (n_accepted (S,),
     out_tokens (S, W), out_logprobs (S, W))."""
     with jax.named_scope("sample"):
-        return jax.vmap(speculative_verify_logprobs)(
+        return verify_rows_logprobs(
             logits, draft, seeds, counters, temp, top_k, top_p
         )
 

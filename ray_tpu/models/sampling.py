@@ -23,10 +23,25 @@ Two helpers:
   sampled path (greedy falls out as the ``temperature <= 0`` argmax
   special case).
 
-Everything is jit-safe with static shapes: dynamic per-row ``k`` is
-implemented by ranking a full descending sort rather than ``lax.top_k``
-(whose k must be static), which also gives top-p its cumulative mass for
-free from the same sort.
+Everything is jit-safe with static shapes, and the sampler never leaves
+SORTED order (PR 27).  Per-row ``k`` and ``p`` need a rank and a cumulative
+mass, so a row is sorted once, descending and stable, by its
+temperature-scaled logits; the sort carries, beside that key, the
+vocabulary index and the Gumbel noise ``jax.random.categorical`` would
+have added to each token (``lax.sort`` with three operands, one key), so
+values, token ids and noise arrive aligned with no gather.  The top-k /
+top-p mask, the draw (the maximum of masked value + noise, the lowest
+token id among exact ties, as an argmax in vocabulary order breaks them)
+and the chosen token's logprob are all taken where the sort left the row:
+nothing is scattered back, since no caller needs filtered logits in
+vocabulary order.  The tokens did not change: a row's key, the noise
+drawn from it, the stable order and the mask are what they were, and
+``max(x[order] + g[order])`` picks the token ``max(x + g)`` picks.  A
+batch with no sampled row (``temperature <= 0`` everywhere, empty engine
+slots included) skips the sort: one ``lax.cond`` on ``any(temperature >
+0)``, inside the one compiled program, leaves the two greedy reductions.
+``token_logprobs`` alone keeps the filter in vocabulary order
+(``_filtered_logits``): it scores GIVEN ids and is differentiated.
 
 Convention: ``temperature <= 0`` means greedy (argmax) for that row —
 the PRNG key is still consumed uniformly so a batch mixing greedy and
@@ -69,31 +84,132 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
+#: the widest running sum XLA runs as one ``reduce_window``: a wider one it
+#: rewrites itself, into these same levels, under ops that carry no name
+_SCAN_WIDTH = 128
+
+
+def _running_sum(x):
+    """``jnp.cumsum(x, axis=1)`` of (b, n) float rows, in the levels XLA
+    itself breaks a long running sum into: tiles of 128 summed along the
+    tile (a ``reduce_window`` it runs as it stands), each tile then
+    raised by the sum of the tiles before it (the same, one level up).
+    Written out here because the ops of XLA's own rewrite carry no
+    ``op_name`` (and jax lowers ``cumsum`` out of line, which drops the
+    name stack): a trace would count them to no scope, not to the
+    sampler."""
+    b, n = x.shape
+    if n <= _SCAN_WIDTH:
+        return jax.lax.reduce_window(
+            x, 0.0, jax.lax.add, (1, n), (1, 1), ((0, 0), (n - 1, 0))
+        )
+    tiles = -(-n // _SCAN_WIDTH)
+    x = jnp.pad(x, ((0, 0), (0, tiles * _SCAN_WIDTH - n)))
+    within = jax.lax.reduce_window(
+        x.reshape(b, tiles, _SCAN_WIDTH), 0.0, jax.lax.add,
+        (1, 1, _SCAN_WIDTH), (1, 1, 1), ((0, 0), (0, 0), (_SCAN_WIDTH - 1, 0)),
+    )
+    # what stands before a tile: the running sum of the tiles' totals, shifted
+    totals = jax.lax.slice(
+        within, (0, 0, _SCAN_WIDTH - 1), (b, tiles - 1, _SCAN_WIDTH)
+    ).reshape(b, tiles - 1)
+    before = _running_sum(jnp.pad(totals, ((0, 0), (1, 0))))
+    return (within + before[:, :, None]).reshape(b, tiles * _SCAN_WIDTH)[:, :n]
+
+
+def _keep_sorted(sorted_scaled, kk, pp):
+    """THE top-k / top-p filter, as a mask over the RANKS of rows sorted
+    descending: rank < k for top-k (``k <= 0``: off), exclusive cumulative
+    mass < p for top-p (rank 0 always survives).  sorted_scaled: (b, v)
+    fp32; kk/pp: (b,).  One sort serves both truncations."""
+    ranks = jax.lax.broadcasted_iota(jnp.int32, sorted_scaled.shape, 1)
+    probs = jax.nn.softmax(sorted_scaled, axis=-1)
+    cum = _running_sum(probs)
+    keep = (kk[:, None] <= 0) | (ranks < kk[:, None])
+    return keep & ((cum - probs) < pp[:, None])
+
+
 def _filtered_logits(logits, temp, kk, pp):
     """Temperature-scaled logits with top-k/top-p support masked to
-    ``_NEG_INF``.  logits: (b, v) fp32; temp/kk/pp: (b,) arrays.  This IS
-    the distribution ``sample_tokens`` draws from — ``speculative_verify``
-    must score draft tokens under exactly the same filtering or the
-    accepted distribution would drift from the non-speculative path."""
-    b, v = logits.shape
-    safe_t = jnp.maximum(temp, 1e-6)[:, None]
-    scaled = logits / safe_t
-    # one descending sort serves both truncations: rank < k for top-k,
-    # exclusive cumulative mass < p for top-p (rank 0 always survives)
+    ``_NEG_INF``, in VOCABULARY order: the distribution the sampler draws
+    from, for ``token_logprobs`` alone (it scores given ids and is
+    differentiated; the sampler itself stays in sorted order and pays
+    neither this gather nor this scatter).  logits: (b, v) fp32;
+    temp/kk/pp: (b,) arrays."""
+    scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
     order = jnp.argsort(-scaled, axis=-1)
     sorted_scaled = jnp.take_along_axis(scaled, order, axis=-1)
-    ranks = jnp.arange(v)[None, :]
-    probs = jax.nn.softmax(sorted_scaled, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (kk[:, None] <= 0) | (ranks < kk[:, None])
-    keep &= (cum - probs) < pp[:, None]
-    masked_sorted = jnp.where(keep, sorted_scaled, _NEG_INF)
+    masked_sorted = jnp.where(
+        _keep_sorted(sorted_scaled, kk, pp), sorted_scaled, _NEG_INF
+    )
     # scatter the surviving logits back to vocab order
     return (
         jnp.full_like(scaled, _NEG_INF)
-        .at[jnp.arange(b)[:, None], order]
+        .at[jnp.arange(logits.shape[0])[:, None], order]
         .set(masked_sorted)
     )
+
+
+def _draw_rows(logits, keys, temp, kk, pp):
+    """The sampler: one token and its logprob a row.  logits: (b, v) fp32;
+    keys: (b, 2) — row i's is the key ``jax.random.categorical`` would be
+    handed for it; temp/kk/pp: (b,).  Returns (tokens (b,) int32, logprobs
+    (b,) fp32) under the module-doc conventions.
+
+    Greedy rows are two reductions over the raw logits.  Sampled rows are
+    drawn where ONE stable descending sort leaves them (module doc): it
+    carries each token's id and Gumbel noise beside the key, so neither a
+    vocabulary-wide gather nor a scatter follows it.  No row sampled, no
+    sort: the ``cond`` reads the operands on the device, so one compiled
+    program serves an all-greedy batch at the cost of its reductions."""
+    b, v = logits.shape
+    sampled_row = temp > 0.0
+    top = jnp.max(logits, axis=-1)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # log_softmax(logits) at the argmax, where the shifted logit is 0
+    greedy_lp = -jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), axis=-1))
+
+    def sorted_draw():
+        scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
+        # the noise categorical(key, row) adds to a (v,) row, token by token
+        noise = jax.vmap(lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (b, v), 1)
+        neg, order, noise = jax.lax.sort(
+            (-scaled, ids, noise), dimension=1, is_stable=True, num_keys=1
+        )
+        masked = jnp.where(_keep_sorted(-neg, kk, pp), -neg, _NEG_INF)
+        # Gumbel-max; among exact ties the lowest token id, as an argmax in
+        # vocabulary order would break them
+        z = masked + noise
+        tok = jnp.min(
+            jnp.where(z == jnp.max(z, axis=-1, keepdims=True), order, v), axis=-1
+        )
+        chosen = jnp.max(
+            jnp.where(order == tok[:, None], masked, -jnp.inf), axis=-1
+        )
+        # log_softmax(masked) at the token: rank 0 survives every filter,
+        # so masked[:, 0] is the row's maximum
+        norm = jnp.log(jnp.sum(jnp.exp(masked - masked[:, :1]), axis=-1))
+        return tok, (chosen - masked[:, 0]) - norm
+
+    tok, lp = jax.lax.cond(
+        jnp.any(sampled_row), sorted_draw, lambda: (greedy, greedy_lp)
+    )
+    return jnp.where(sampled_row, tok, greedy), jnp.where(sampled_row, lp, greedy_lp)
+
+
+def _request_keys(seeds, counters):
+    """The engine's row keys — the (seed, absolute output index) keying of
+    the module doc.  Output index ``counters[i]`` of the request seeded
+    ``seeds[i]`` draws with ``split(fold_in(PRNGKey(seed), index), 1)[0]``
+    (the ``split`` is ``sample_tokens_logprobs``'s per-row split at a batch
+    of one, which is how this key was first derived: kept, so that every
+    (seed, index) draws the token it always drew)."""
+    return jax.vmap(
+        lambda s, c: jax.random.split(
+            jax.random.fold_in(jax.random.PRNGKey(s), c), 1
+        )[0]
+    )(seeds, counters)
 
 
 def _broadcast_knobs(b, temperature, top_k, top_p):
@@ -129,15 +245,9 @@ def sample_tokens_logprobs(
     logprob the RLHF importance ratio needs, captured at zero extra
     model cost (the softmax already exists on device)."""
     logits = logits.astype(jnp.float32)
-    b, v = logits.shape
+    b = logits.shape[0]
     temp, kk, pp = _broadcast_knobs(b, temperature, top_k, top_p)
-
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    masked = _filtered_logits(logits, temp, kk, pp)
-    keys = jax.random.split(key, b)
-    sampled = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
-    tok = jnp.where(temp > 0.0, sampled, greedy)
-    return tok, _chosen_logprob(logits, masked, temp, tok)
+    return _draw_rows(logits, jax.random.split(key, b), temp, kk, pp)
 
 
 def sample_tokens(
@@ -156,6 +266,18 @@ def sample_tokens(
     per-row splits so the same (key, row) pair always reproduces.
     """
     return sample_tokens_logprobs(logits, key, temperature, top_k, top_p)[0]
+
+
+def sample_rows_logprobs(logits, seeds, counters, temperature, top_k, top_p):
+    """The engine's decode sampler: row i is output index ``counters[i]``
+    of the request seeded ``seeds[i]`` and draws from those two alone, so
+    a request gets the same tokens whatever slot, step or replica it lands
+    in.  logits: (n, vocab); every other operand (n,).  ONE batched call,
+    so the all-greedy skip of ``_draw_rows`` is a real branch.  Returns
+    (tokens (n,), logprobs (n,))."""
+    logits = logits.astype(jnp.float32)
+    temp, kk, pp = _broadcast_knobs(logits.shape[0], temperature, top_k, top_p)
+    return _draw_rows(logits, _request_keys(seeds, counters), temp, kk, pp)
 
 
 def token_logprobs(
@@ -188,6 +310,29 @@ def token_logprobs(
     return _chosen_logprob(logits, masked, temp, tokens.astype(jnp.int32))
 
 
+def verify_rows_logprobs(logits, draft, seeds, counters, temperature, top_k, top_p):
+    """Sample-then-match for a batch of verification windows (module doc;
+    ``speculative_verify`` has the contract).  logits: (S, W, vocab);
+    draft: (S, W-1); seeds/counters: (S,) — the request's seed and the
+    output index of its window's first token; knobs: (S,), one value a
+    window.  Window index i of slot s draws with ``sample_rows_logprobs``'s key for
+    (seeds[s], counters[s] + i), all S·W rows in one batched call.
+    Returns (n_accepted (S,), out (S, W), logprobs (S, W))."""
+    s, w, v = logits.shape
+    index = counters[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+    out, logp = sample_rows_logprobs(
+        logits.reshape(s * w, v),
+        jnp.repeat(seeds, w),
+        index.reshape(-1),
+        *(jnp.repeat(knob, w) for knob in (temperature, top_k, top_p)),
+    )
+    out, logp = out.reshape(s, w), logp.reshape(s, w)
+    accept = (draft == out[:, : w - 1]).astype(jnp.int32)
+    # an empty draft (w == 1) accepts nothing: the window is the bonus position
+    n_acc = jnp.sum(jnp.cumprod(accept, axis=1), axis=1).astype(jnp.int32)
+    return n_acc, out, logp
+
+
 def speculative_verify_logprobs(
     logits: jax.Array,
     draft: jax.Array,
@@ -206,30 +351,14 @@ def speculative_verify_logprobs(
     free). Validity mirrors ``out``: entries past ``n_accepted`` are
     conditioned on a rejected prefix and must be discarded with their
     tokens."""
-    logits = logits.astype(jnp.float32)
-    w, v = logits.shape
-    kd = w - 1
-    temp, kk, pp = _broadcast_knobs(w, temperature, top_k, top_p)
-
-    base = jax.random.PRNGKey(seed)
-    keys = jax.vmap(
-        lambda i: jax.random.fold_in(base, counter + i)
-    )(jnp.arange(w, dtype=jnp.int32))  # (w, 2)
-
-    def one(lg, key, t, k_, p_):
-        tok, lp = sample_tokens_logprobs(
-            lg[None, :], key, t[None], k_[None], p_[None]
-        )
-        return tok[0], lp[0]
-
-    out, logp = jax.vmap(one)(logits, keys, temp, kk, pp)  # (w,), (w,)
-
-    if kd:
-        accept = draft == out[:kd]
-        n_acc = jnp.sum(jnp.cumprod(accept.astype(jnp.int32))).astype(jnp.int32)
-    else:  # empty draft (w == 1): the window is just the bonus position
-        n_acc = jnp.int32(0)
-    return n_acc, out, logp
+    n_acc, out, logp = verify_rows_logprobs(
+        logits[None],
+        draft[None],
+        jnp.asarray(seed)[None],
+        jnp.asarray(counter, jnp.int32)[None],
+        *_broadcast_knobs(1, temperature, top_k, top_p),
+    )
+    return n_acc[0], out[0], logp[0]
 
 
 def speculative_verify(
