@@ -42,6 +42,39 @@ def check(cond, msg: str) -> None:
         raise BenchFailure(msg)
 
 
+#: the driver stops a run that is still going after this many seconds, and
+#: the slowest run of any cell (traced, from empty caches) aims under the
+#: target (README, "What a run costs")
+RUN_LIMIT_S = 360.0
+RUN_TARGET_S = 300.0
+
+
+class Budget:
+    """Where one run's wall time went: consecutive parts from the process's
+    start to the result line, so the parts sum to ``total_s``.  ``mark``
+    closes the part that began at the previous mark, at an instant given
+    or now (never before the previous mark, never in the future); a part
+    marked twice adds up.  No metric reads it: the ``run_budget`` progress
+    line is what the next cell's cost is sized from."""
+
+    def __init__(self, t_start: float, clock=time.time):
+        self.t_start = self._last = t_start
+        self._clock = clock
+        self.parts: dict = {}
+
+    def mark(self, name: str, t: float | None = None) -> float:
+        now = self._clock()
+        t = now if t is None else min(max(t, self._last), now)
+        self.parts[name] = self.parts.get(name, 0.0) + t - self._last
+        self._last = t
+        return t
+
+    def line(self, **extra) -> dict:
+        total = self.mark("other") - self.t_start
+        return {"parts_s": dict(self.parts), "total_s": total,
+                "limit_s": RUN_LIMIT_S, "target_s": RUN_TARGET_S, **extra}
+
+
 # ---------------------------------------------------------------------------
 # manifest and data files
 # ---------------------------------------------------------------------------
@@ -236,6 +269,7 @@ def become_subreaper() -> None:
 
 
 def _descendants() -> list:
+    """(pid, state) of every child and adopted orphan of this process."""
     me, out = os.getpid(), []
     for pid in filter(str.isdigit, os.listdir("/proc")):
         try:
@@ -244,8 +278,8 @@ def _descendants() -> list:
         except OSError:
             continue
         state, ppid = stat.rsplit(")", 1)[1].split()[:2]
-        if int(ppid) == me and state != "Z":
-            out.append(int(pid))
+        if int(ppid) == me:
+            out.append((int(pid), state))
     return out
 
 
@@ -266,7 +300,10 @@ def reap_descendants(grace_s: float = 10.0) -> list:
     kill what is left and wait for it — again and again until none is
     left: killing a parent hands ITS children to this process (the
     subreaper), and one that is still alive when this process exits goes
-    to init and is a leftover there.  Returns the pids that were killed."""
+    to init and is a leftover there.  A child that shows as a zombie but
+    cannot be collected yet is still ending (its first thread is gone, the
+    others are giving back what the process held) and is waited for like
+    any other.  Returns the pids that were killed."""
     deadline, killed = time.time() + grace_s, []
     while True:
         _collect_dead()
@@ -276,17 +313,64 @@ def reap_descendants(grace_s: float = 10.0) -> list:
         if time.time() < deadline:
             time.sleep(0.1)
             continue
-        for pid in left:
+        for pid, state in left:
+            if state == "Z":
+                continue
             try:
                 os.kill(pid, signal.SIGKILL)
+                killed.append(pid)
             except ProcessLookupError:
                 pass
-        for pid in left:
+        for pid, _state in left:
             try:
                 os.waitpid(pid, 0)
             except ChildProcessError:
                 pass
-        killed += left
+
+
+#: device nodes of a TPU chip: a process that has one open holds the chip
+CHIP_NODES = ("/dev/vfio/", "/dev/accel")
+
+
+def chip_holders() -> dict:
+    """pid -> command name of every process with a chip's device node
+    open, by any of its threads (a process whose first thread has ended
+    keeps its files until the last one has)."""
+    out = {}
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            tasks = os.listdir(f"/proc/{pid}/task")
+        except OSError:
+            continue
+        for tid in tasks:
+            fds = f"/proc/{pid}/task/{tid}/fd"
+            try:
+                held = any(os.readlink(os.path.join(fds, fd)).startswith(CHIP_NODES)
+                           for fd in os.listdir(fds))
+            except OSError:
+                continue
+            if held:
+                try:
+                    with open(f"/proc/{pid}/comm") as f:
+                        out[int(pid)] = f.read().strip()
+                except OSError:
+                    out[int(pid)] = "?"
+                break
+    return out
+
+
+def wait_for_free_chips(timeout_s: float = 60.0) -> tuple:
+    """Wait until no process holds a chip (a replica that has been told to
+    end gives back four chips one after the other, for seconds, and a
+    process that opens them meanwhile fails: "Device or resource busy").
+    Returns (seconds waited, the holders seen first)."""
+    t0, first = time.time(), None
+    while True:
+        holders = chip_holders()
+        first = holders if first is None else first
+        if not holders or time.time() - t0 > timeout_s:
+            return time.time() - t0, first
+        time.sleep(0.25)
 
 
 def run_dir(workload: str, seed: int) -> str:

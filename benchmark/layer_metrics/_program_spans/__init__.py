@@ -259,7 +259,10 @@ def _summary(by_scope: dict) -> dict:
 
 def load(run: dict):
     """The traced slice of this run as the readers need it, or None when
-    there is no trace or the program wrote no ``llm.*`` span into it.
+    there is no trace or the program wrote no ``llm.*`` span into it.  The
+    file is parsed ONCE a run: the summary keeps the parsed ``trace`` and
+    the decode programs' seconds by scope (``decode_by_scope``) for the
+    readers that need more than the summary.
     Emits one ``program_spans`` progress line: the idle seconds by span
     (PERF.md's gap table), the sampler's ops and each step program's device
     seconds by named scope."""
@@ -276,7 +279,8 @@ def load(run: dict):
             sample = decode.get("sample", {})
             decodes = sum(1 for m in trace["modules"] if DECODE_PROGRAM.search(m[2]))
             out = {"idle_s": idle, "idle_covered_s": covered,
-                   "sample_s": sum(sample.values()), "decodes": decodes}
+                   "sample_s": sum(sample.values()), "decodes": decodes,
+                   "trace": trace, "decode_by_scope": decode}
             H.emit("program_spans", device_clock_offset_ms=offset * 1e-6,
                    offset_bounds_ms=[None if b is None else b * 1e-6 for b in (lo, hi)],
                    spans=len(trace["spans"]), ops_with_op_name=len(trace["op_names"]),

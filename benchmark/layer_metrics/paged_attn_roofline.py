@@ -3,7 +3,14 @@ memory-bound: the least time is the live K and V bytes one chip must read
 (the family's ``paged_decode_kv_bytes`` at the mean live length over the traced
 slice, from the pool ledger's ``seq_bytes`` sampled at the slice's two
 ends) over the chip's HBM bandwidth; the time taken is the kernel's summed
-device time over the slice divided by the decode programs executed."""
+device time over the slice divided by the decode programs executed.
+
+The two ledger readings are taken just OUTSIDE the slice: ``trace_start``
+is asked for before the profiler starts and ``trace_stop`` before it is
+stopped, and each is answered when the engine's lock lets the reader in
+(``t_read``), up to seconds later, while the profiler starts or writes
+out.  Waiting for them inside the slice is what let it slide
+(``serving.take_slice``)."""
 
 from _common import family_piece, trace_reduce
 

@@ -80,46 +80,55 @@ def main() -> int:
             return 2
     H.prepare_environment(args.rehearsal)
     H.become_subreaper()
+    budget = H.Budget(T_START)
     ctx = {
         "args": args, "workload": workload, "config": config, "traffic": traffic,
         "t_start": T_START, "run_dir": H.run_dir(args.workload, args.seed),
+        "budget": budget,
     }
     H.emit("start", workload=args.workload, config=config["name"],
            traffic=traffic["name"], kind=traffic["kind"], seed=args.seed,
            seconds=args.seconds, trace=args.trace, rehearsal=args.rehearsal)
+    section = "per_layer" if args.trace else "end_to_end"
     try:
+        # a serving run comes back with its reference's child on the chip:
+        # the trace is reduced and the metrics are read meanwhile
         run = H.load_kind(traffic["kind"]).run(ctx)
+        run["manifest"], run["workload"] = man, workload
+        run["peaks"] = None if args.rehearsal else H.peaks_for(run["device"]["kind"])
+        result = {"attempted": run["attempted"], "failed": run["failed"]}
+        device = dict(run["device"])
+        budget.mark("run_end")
+        if args.trace:
+            from benchmark import trace_reduce
+
+            t0 = time.time()
+            run["reduced"] = trace_reduce.reduce_dir(run["trace_dir"])
+            H.check(run["reduced"]["busy_s"] > 0 or args.rehearsal,
+                    "no operation ran on the device inside the traced slice")
+            device["busy_s"] = run["reduced"]["busy_s"]
+            device["window_s"] = run["reduced"]["window_s"]
+            result["breakdown"] = trace_reduce.breakdown(run["reduced"])
+            H.emit("trace_reduced", seconds=time.time() - t0,
+                   window_s=device["window_s"], busy_s=device["busy_s"],
+                   host_spans=run["reduced"]["host_spans"],
+                   programs={
+                       dev: {n: [len(ds), H.median(ds)] for n, ds in d["programs"].items()}
+                       for dev, d in run["reduced"]["devices"].items()
+                   },
+                   lines=run["reduced"].get("lines"))
+            budget.mark("reduction")
+        result["metrics"] = H.read_metrics(man, section, args.workload, run)
+        budget.mark("metric_readers")
+        reference = run.get("reference")  # a training run's was part of set-up
+        if "finish" in run:
+            reference, run["correct"] = run.pop("finish")()
     finally:
         killed = H.reap_descendants(grace_s=5.0)
         if killed:
             H.note(f"killed leftover processes {killed}")
-    run["manifest"], run["workload"] = man, workload
-    run["peaks"] = None if args.rehearsal else H.peaks_for(run["device"]["kind"])
-
-    section = "per_layer" if args.trace else "end_to_end"
-    result = {"correct": run["correct"], "attempted": run["attempted"],
-              "failed": run["failed"]}
-    device = dict(run["device"])
-    if args.trace:
-        from benchmark import trace_reduce
-
-        t0 = time.time()
-        run["reduced"] = trace_reduce.reduce_dir(run["trace_dir"])
-        H.check(run["reduced"]["busy_s"] > 0 or args.rehearsal,
-                "no operation ran on the device inside the traced slice")
-        device["busy_s"] = run["reduced"]["busy_s"]
-        device["window_s"] = run["reduced"]["window_s"]
-        result["breakdown"] = trace_reduce.breakdown(run["reduced"])
-        H.emit("trace_reduced", seconds=time.time() - t0,
-               window_s=device["window_s"], busy_s=device["busy_s"],
-               host_spans=run["reduced"]["host_spans"],
-               programs={
-                   dev: {n: [len(ds), H.median(ds)] for n, ds in d["programs"].items()}
-                   for dev, d in run["reduced"]["devices"].items()
-               },
-               lines=run["reduced"].get("lines"))
-    result["metrics"] = H.read_metrics(man, section, args.workload, run)
-    result["device"] = device
+    result = {"correct": run["correct"], **result, "device": device}
+    H.emit("run_budget", **budget.line(reference=reference, trace=args.trace))
     if args.rehearsal:
         H.emit("rehearsal_result", **result)
         H.note("rehearsal finished; a rehearsal is not a result")

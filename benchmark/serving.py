@@ -19,9 +19,21 @@ import time
 
 from benchmark import harness as H
 
-#: what ``start_trace`` was seen to take on the chip, so that the traced
-#: slice still ends with the window
-TRACE_START_S = 4.0
+#: a gap this many times a decode alone carries a prefill chunk too
+CHUNK_GAP_FACTOR = 1.25
+#: engine steps that give every per-layer reader its samples: the traced
+#: slice holds no more of them, however fast the engine steps (PR 27's
+#: slices held 119-266 decodes), so a faster engine writes the same trace
+TRACE_STEPS = 150
+#: where in the window a traced run asks for the counters it takes the
+#: engine's steps a second from, and where its slice begins, as shares of
+#: the window: ``stop_trace`` then has the rest of the window and the drain
+RATE_READ_AT = 0.15
+TRACE_AT = 0.3
+#: the slice begins up to this long after ``TRACE_AT``, where the plan has
+#: most requests due in the ``TRACE_WAIT_S`` before it (``slice_begin``)
+TRACE_REACH_S = 5.0
+TRACE_WAIT_S = 3.0
 #: a cut stream has stalled when it was silent for this many median gaps
 STALL_GAPS = 20
 
@@ -39,8 +51,9 @@ def _engine_objects(config: dict, rehearsal: bool):
 
 def _build_app(config: dict, model_cfg, engine_cfg, traced: bool):
     """``--trace 0`` goes through ``build_llm_app`` untouched.  The traced
-    run binds the subclass that adds the trace hook, with the same
-    ``deployment(...)`` options ``build_llm_app`` uses."""
+    run binds the subclass whose ``stop_trace`` writes the ``.xplane.pb``
+    alone (``traced_deployment.py``), with the same ``deployment(...)``
+    options ``build_llm_app`` uses."""
     from ray_tpu.serve.llm import build_llm_app
 
     dep, model = config["deployment"], H.family_piece(config, "SERVE_MODEL")
@@ -119,39 +132,146 @@ def _identity_probes(port: int, probes: list, vocab: int) -> dict:
     }
 
 
-def _reference_verdict(config: dict, probes: list, outs: list, rehearsal: bool) -> dict:
+class ReferenceJob:
     """(c): teacher-forced check of the probe tokens against the plain
     reference, in a child that opens the chip AFTER the replica let go of
-    it.  The verdict is cached by configuration and tokens, so only the
-    first run of a cell in a checkout pays for it."""
-    import hashlib
+    it.  The child is started here and joined by ``verdict()``: it needs
+    the chip and the trace's reduction needs the host, so the two run side
+    by side.  The verdict is cached by configuration and tokens, so only
+    the first run of a cell in a checkout pays for it."""
 
-    sizes = H.sizes(config, rehearsal)
-    key = hashlib.sha256(json.dumps(
-        [{k: v for k, v in sizes.items() if k != "rehearsal"}, probes, outs],
-        sort_keys=True,
-    ).encode()).hexdigest()[:24]
-    path = os.path.join(H.CACHE_DIR, "verdicts", f"{config['name']}-{key}.json")
-    if os.path.exists(path):
-        verdict = H.load_json(path)
-        verdict["cached"] = True
-        return verdict
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    job = path + ".job"
-    with open(job, "w") as f:
-        json.dump({"config": config, "probes": probes, "outs": outs,
-                   "rehearsal": rehearsal}, f)
-    t0 = time.time()
-    proc = subprocess.run(
-        [sys.executable, os.path.join(H.BENCH_DIR, "reference_check.py"), job, path],
-        cwd=H.ROOT,
-    )
-    os.remove(job)
-    H.check(proc.returncode == 0, f"reference check exited {proc.returncode}")
-    verdict = H.load_json(path)
-    verdict["cached"] = False
-    verdict["seconds"] = round(time.time() - t0, 1)
-    return verdict
+    def __init__(self, config: dict, probes: list, outs: list, rehearsal: bool):
+        import hashlib
+
+        sizes = H.sizes(config, rehearsal)
+        key = hashlib.sha256(json.dumps(
+            [{k: v for k, v in sizes.items() if k != "rehearsal"}, probes, outs],
+            sort_keys=True,
+        ).encode()).hexdigest()[:24]
+        self.path = os.path.join(H.CACHE_DIR, "verdicts", f"{config['name']}-{key}.json")
+        self.proc, self.t0 = None, time.time()
+        if os.path.exists(self.path):
+            return
+        os.makedirs(os.path.dirname(self.path), exist_ok=True)
+        self.job = self.path + ".job"
+        with open(self.job, "w") as f:
+            json.dump({"config": config, "probes": probes, "outs": outs,
+                       "rehearsal": rehearsal}, f)
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(H.BENCH_DIR, "reference_check.py"),
+             self.job, self.path],
+            cwd=H.ROOT,
+        )
+
+    def verdict(self) -> dict:
+        if self.proc is None:
+            return dict(H.load_json(self.path), cached=True, seconds=0.0)
+        rc = self.proc.wait()
+        os.remove(self.job)
+        H.check(rc == 0, f"reference check exited {rc}")
+        return dict(H.load_json(self.path), cached=False,
+                    seconds=time.time() - self.t0)
+
+
+def slice_seconds(traffic: dict, seconds: float, steps_per_s) -> float:
+    """How long the traced slice is planned to be: what the traffic file
+    asks (``trace_s``, at most a quarter of the window), and no longer
+    than ``TRACE_STEPS`` engine steps take at the rate the window has
+    shown; without a rate, what the file asks."""
+    span = min(float(traffic.get("trace_s", 3.0)), seconds * 0.25)
+    if steps_per_s and steps_per_s > 0:
+        span = min(span, TRACE_STEPS / steps_per_s)
+    return span
+
+
+def chunk_step_share(counters: dict, prefill_chunk: int):
+    """At least this share (%) of the window's engine steps carried a
+    prefill chunk: the prompt tokens computed across the window over the
+    chunk's size (a prompt's last chunk is not full, so a little more did),
+    over the steps.  The engine's own count beside the clients'
+    ``chunk_gap_share``."""
+    steps = counters["close"]["steps"] - counters["open"]["steps"]
+    tokens = (counters["close"]["prefill_tokens_computed"]
+              - counters["open"]["prefill_tokens_computed"])
+    return 100.0 * tokens / prefill_chunk / steps if steps > 0 else None
+
+
+def slice_begin(dues: list, nominal: float, span_s: float) -> float:
+    """Where the traced slice begins, in the plan's seconds: within
+    ``TRACE_REACH_S`` after ``nominal``, the instant with most requests due
+    from ``TRACE_WAIT_S`` before it to a second before the slice ends.  A
+    request's prefill follows its due instant by the wait for the engine's
+    lock (seconds), and a slice that holds no prefill chunk gives the
+    chunk's reader nothing to read: at 0.8 requests/s three seconds in
+    twelve hold none.  The plan alone decides, so the same seed traces the
+    same slice on every tree; a plan without due instants (a closed loop
+    sends back to back) begins at ``nominal``."""
+    best, best_n = nominal, -1
+    for i in range(int(TRACE_REACH_S / 0.25) + 1):
+        b = nominal + 0.25 * i
+        n = sum(1 for d in dues if b - TRACE_WAIT_S <= d < b + span_s - 1.0)
+        if n > best_n:
+            best, best_n = b, n
+    return best
+
+
+def steps_per_second(first: dict, later: dict):
+    """Engine steps a second between two ``stats()`` readings, over the
+    instants the engine took them at (``t_read``); None where they lie
+    under a second apart (the first one waited for the lock that long)."""
+    dt = later.get("t_read", 0.0) - first.get("t_read", 0.0)
+    return (later["steps"] - first["steps"]) / dt if dt >= 1.0 else None
+
+
+def take_slice(handle, trace_dir: str, t_begin: float, span_s: float,
+               clock=time.time, sleep=time.sleep):
+    """The traced slice: start the profiler at ``t_begin`` and ask it to
+    stop ``span_s`` after it has started; nothing else happens in between.
+    ``stats()`` waits for the engine's lock, for seconds under load, and
+    ``stop_trace`` writes out every traced step at tens of times its
+    length: so the readings at the slice's two ends are ASKED for here,
+    ``trace_start`` just before ``start_trace`` and ``trace_stop`` just
+    before ``stop_trace`` (each outside the slice; ``t_read`` in each says
+    when the engine answered), and ``stop_trace`` is asked for and left to
+    run.  Returns ``join``: call it once the window and the drain are
+    over; it waits for the profiler and gives (the two readings, the
+    slice's timing)."""
+    import threading
+
+    sleep(max(0.0, t_begin - clock()))
+    before = handle.stats.remote()
+    t_a = clock()
+    handle.start_trace.remote(trace_dir).result()
+    t_b = clock()
+    sleep(max(0.0, t_b + span_s - clock()))
+    after = handle.stats.remote()
+    t_c = clock()
+    stop = handle.stop_trace.remote()
+    stopped = []
+
+    def wait_for_stop():
+        try:
+            stop.result()
+            stopped.append((clock(), None))
+        except BaseException as e:  # raised again by join()
+            stopped.append((clock(), e))
+
+    waiter = threading.Thread(target=wait_for_stop, daemon=True)
+    waiter.start()
+
+    def join() -> tuple:
+        t_j = clock()
+        waiter.join()
+        t_d, error = stopped[0]
+        if error is not None:
+            raise error
+        readings = {name: dict(ref.result(), _t=clock())
+                    for name, ref in (("trace_start", before), ("trace_stop", after))}
+        timing = {"planned_s": span_s, "start_call_s": t_b - t_a, "traced_s": t_c - t_b,
+                  "stop_call_s": t_d - t_c, "waited_for_stop_s": clock() - t_j}
+        return readings, timing
+
+    return join
 
 
 def _lateness_line(records: list) -> None:
@@ -188,6 +308,17 @@ def _client_summary(records: list, window: tuple, seconds: float) -> None:
     def pct(v, p):
         return 1e3 * H.percentile(v, p) if v else None
 
+    # a gap that carries a prefill chunk beside the decode stands clear of
+    # a decode alone, taken as the gaps' fastest tenth (the median itself
+    # carries a chunk once most gaps do; two lines that one read delivered
+    # are no gap and stay out of the tenth).  A chunk adds 70% to a decode
+    # on one chip and a third under tp=4, a long row a fifth at most: the
+    # line is drawn a quarter above.  Where this share nears 5% the 95th
+    # percentile flips between the two (README, "Re-rating a chat cell");
+    # the engine's own count stands on the ``engine_counters`` line
+    apart = [g for g in gaps if g >= 1e-3]
+    alone = H.percentile(apart, 10) if apart else None
+    long_gaps = sum(1 for g in gaps if g > CHUNK_GAP_FACTOR * alone) if apart else 0
     H.emit(
         "client_summary", completed_per_s=len(done) / seconds,
         out_tokens_per_s=toks / seconds, due_in_window=len(due),
@@ -195,7 +326,9 @@ def _client_summary(records: list, window: tuple, seconds: float) -> None:
         completed_in_window=len(done),
         prompt_tokens_completed_per_s=sum(r["prompt_len"] for r in done) / seconds,
         ttft_ms={p: pct(ttft, p) for p in (50, 90, 99)},
-        itl_ms={p: pct(gaps, p) for p in (50, 95, 99)}, gaps=len(gaps),
+        itl_ms={p: pct(gaps, p) for p in (50, 90, 93, 95, 97, 99)}, gaps=len(gaps),
+        chunk_gap_share=100.0 * long_gaps / len(gaps) if gaps else None,
+        decode_alone_gap_ms=1e3 * alone if apart else None,
     )
 
 
@@ -239,7 +372,10 @@ def _structural_failures(attempted: list, vocab: int, end: float,
 def run_cell(ctx: dict, make_plan) -> dict:
     """Run one serving cell.  ``make_plan(traffic, seed, vocab, seconds)``
     is the traffic kind's generator; it returns the client's plan without
-    ``t0``/``port`` plus ``lead_s`` and ``drain_s``."""
+    ``t0``/``port`` plus ``lead_s`` and ``drain_s``.  The run that comes
+    back is whole but for ``correct``: the reference's child is still on
+    the chip, and ``run["finish"]()`` joins it and gives the verdict, so
+    that the caller reduces the trace meanwhile."""
     import ray_tpu
     from ray_tpu import serve
 
@@ -247,17 +383,17 @@ def run_cell(ctx: dict, make_plan) -> dict:
     rehearsal, traced = args.rehearsal, bool(args.trace)
     model_cfg, engine_cfg = _engine_objects(config, rehearsal)
     vocab = model_cfg.vocab_size
-    rdir = ctx["run_dir"]
+    rdir, budget = ctx["run_dir"], ctx["budget"]
     marks = {"process_start": ctx["t_start"]}
 
     ray_tpu.init()
     try:
-        marks["ray_init"] = time.time()
+        marks["ray_init"] = budget.mark("ray_init")
         handle = serve.run(
             _build_app(config, model_cfg, engine_cfg, traced),
             name="llm", http=True, http_port=0,
         )
-        marks["replica_ready"] = time.time()
+        marks["replica_ready"] = budget.mark("serve_run")
         controller = ray_tpu.get_actor("SERVE_CONTROLLER")
         port = ray_tpu.get(controller.get_proxy_port.remote(), timeout=30)
         # device_report() lowers every step (seconds at this depth): it is
@@ -272,7 +408,7 @@ def run_cell(ctx: dict, make_plan) -> dict:
         # probes would wait behind them for tens of seconds of chip time
         probes = probe_prompts(config, vocab, rehearsal)
         ident = _identity_probes(port, probes, vocab)
-        marks["probes"] = time.time()
+        marks["probes"] = budget.mark("probes")
 
         plan = make_plan(traffic, args.seed, vocab, args.seconds)
         lead_s, drain_s = plan.pop("lead_s"), plan.pop("drain_s")
@@ -291,7 +427,7 @@ def run_cell(ctx: dict, make_plan) -> dict:
             [sys.executable, os.path.join(H.BENCH_DIR, "client.py"),
              plan_path, rec_path],
         )
-        marks["client_started"] = time.time()
+        marks["client_started"] = budget.mark("plan")
 
         def stats_at(t: float) -> dict:
             time.sleep(max(0.0, t - time.time()))
@@ -300,29 +436,47 @@ def run_cell(ctx: dict, make_plan) -> dict:
             return s
 
         counters = {"open": stats_at(t_open)}
-        trace_dir = None
+        budget.mark("lead_in", t_open)
+        trace_dir = join_slice = None
         if traced:
-            # the LAST seconds of the window: starting the profiler takes
-            # seconds and stopping it tens of seconds of the replica's
-            # time, and the counters are read across the whole window
-            span = min(float(traffic.get("trace_s", 3.0)), args.seconds * 0.25)
+            # a slice in the window's first half: what stop_trace writes out
+            # it then writes under the rest of the window and the drain.
+            # A traced run alone asks for one more reading before it, and
+            # takes the engine's steps a second from it, so that the slice
+            # is bounded in work as well as in seconds; that reading is
+            # never waited for past the instant the slice is planned at
+            time.sleep(max(0.0, t_open + RATE_READ_AT * args.seconds - time.time()))
+            rate_ref = handle.stats.remote()
+            span = slice_seconds(traffic, args.seconds, None)
+            dues = [r["due"] for r in plan.get("requests", [])]
+            t_begin = t0 + slice_begin(dues, lead_s + TRACE_AT * args.seconds, span)
+            try:
+                counters["rate_read"] = rate_ref.result(
+                    timeout=max(0.1, t_begin - 1.0 - time.time()))
+                rate = steps_per_second(counters["open"], counters["rate_read"])
+            except TimeoutError:
+                rate = None
+            span = slice_seconds(traffic, args.seconds, rate)
             trace_dir = os.path.join(rdir, "trace")
-            counters["trace_start"] = stats_at(t_close - span - TRACE_START_S)
-            t_a = time.time()
-            handle.start_trace.remote(trace_dir).result()
-            t_b = time.time()
+            join_slice = take_slice(handle, trace_dir, t_begin, span)
         counters["close"] = stats_at(t_close)
-        if traced:
-            counters["trace_stop"] = counters["close"]
-            t_c = time.time()
-            handle.stop_trace.remote().result()
-            H.emit("trace_taken", start_call_s=t_b - t_a, traced_s=t_c - t_b,
-                   stop_call_s=time.time() - t_c)
+        budget.mark("window", t_close)
+        budget.mark("close_read")
         rc = client.wait(timeout=drain_s + 120)
         H.check(rc == 0, f"the load generator exited {rc}")
+        budget.mark("drain_rest")
+        if traced:
+            readings, timing = join_slice()
+            counters.update(readings)
+            budget.mark("stop_trace_rest")
+            H.emit("trace_taken", steps_per_s=rate, **timing,
+                   steps=readings["trace_stop"]["steps"] - readings["trace_start"]["steps"],
+                   reads_apart_s=readings["trace_stop"]["t_read"]
+                   - readings["trace_start"]["t_read"])
         records = H.load_json(rec_path)["records"]
         _lateness_line(records)
         _client_summary(records, (lead_s, lead_s + args.seconds), args.seconds)
+        budget.mark("client_records")
 
         # -- after the drain: counters, audits, memory ---------------------
         counters["end"] = stats_at(time.time())
@@ -333,12 +487,14 @@ def run_cell(ctx: dict, make_plan) -> dict:
             "platform": after["platform"], "kind": after["device_kind"],
             "count": after["device_count"],
         }
+        budget.mark("after_drain_reads")
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
         killed = H.reap_descendants()
         if killed:
             H.note(f"killed leftover processes {killed}")
+        budget.mark("shutdown")
 
     import jax._src.xla_bridge as xb
 
@@ -349,6 +505,11 @@ def run_cell(ctx: dict, make_plan) -> dict:
         H.check(device["count"] >= ctx["workload"]["chips"],
                 f"the cell needs {ctx['workload']['chips']} chips, jax saw "
                 f"{device['count']}")
+    # once the chip is free the reference takes it, and this process goes on
+    waited_s, holders = H.wait_for_free_chips()
+    H.emit("chips_free", waited_s=waited_s, holders=holders)
+    budget.mark("chips_free")
+    reference = ReferenceJob(config, probes, ident["outs"], rehearsal)
 
     # the requests of the window: due before it closed, and not over before
     # it opened (the lead-in's requests still streaming or waiting count)
@@ -359,35 +520,14 @@ def run_cell(ctx: dict, make_plan) -> dict:
         and (r["done"] is None or r["done"] >= t_lo)
     ]
     bad = _structural_failures(attempted, vocab, t_hi + drain_s, queue_is_load)
-    verdict = _reference_verdict(config, probes, ident["outs"], rehearsal)
-    correctness = {
-        "structural_failures": len(bad),
-        "prefix_hit_identical": ident["prefix_hit_identical"],
-        "seeded_twice_identical": ident["seeded_twice_identical"],
-        "retraces": retraces,
-        "pool_audit_ok": bool(audits["pool"]["ok"]),
-        "prefix_audit_ok": audits["prefix_cache"] is None
-        or bool(audits["prefix_cache"]["ok"]),
-        "reference_ok": bool(verdict["ok"]),
-        "reference": {k: verdict.get(k) for k in
-                      ("max_deficit", "tolerance", "positions", "cached", "seconds")},
-        "jit_cache_sizes": {k: v["cache_size"] for k, v in after["jit_sites"].items()},
-    }
-    H.emit("correctness", **correctness, first_failures=bad[:5])
     H.emit("engine_counters", **{
-        at: {k: c[k] for k in ("_t", "running", "waiting", "kv_utilization", "free_blocks",
-                               "steps", "tokens_generated", "prefill_tokens_computed",
-                               "preemptions")}
+        at: {k: c.get(k) for k in ("_t", "t_read", "running", "waiting", "kv_utilization",
+                                   "free_blocks", "steps", "tokens_generated",
+                                   "prefill_tokens_computed", "preemptions", "sampler")}
         | {"hit_tokens": c.get("prefix_cache", {}).get("hit_tokens"),
            "evicted_blocks": c.get("prefix_cache", {}).get("evicted_blocks")}
         for at, c in counters.items()
-    })
-    correct = (
-        correctness["prefix_hit_identical"] and correctness["seeded_twice_identical"]
-        and retraces == 0 and correctness["pool_audit_ok"]
-        and correctness["prefix_audit_ok"] and correctness["reference_ok"]
-        and all(n == 1 for n in correctness["jit_cache_sizes"].values())
-    )
+    }, chunk_step_share=chunk_step_share(counters, engine_cfg.prefill_chunk))
     H.emit(
         "setup_breakdown",
         ray_init_s=marks["ray_init"] - marks["process_start"],
@@ -399,13 +539,43 @@ def run_cell(ctx: dict, make_plan) -> dict:
         compile_cache=after["compile_cache"], versions=after["versions"],
         hbm={k: v for k, v in after["hbm"].items() if k != "per_device"},
     )
+
+    def finish() -> tuple:
+        """Join the reference and decide ``correct`` (returned after what
+        the reference took and whether it was cached); every number compared
+        goes beside its limit on the ``correctness`` line, and on standard
+        error as the run's last lines."""
+        verdict = reference.verdict()
+        budget.mark("reference_join")
+        correctness = {
+            "structural_failures": len(bad),
+            "prefix_hit_identical": ident["prefix_hit_identical"],
+            "seeded_twice_identical": ident["seeded_twice_identical"],
+            "retraces": retraces,
+            "pool_audit_ok": bool(audits["pool"]["ok"]),
+            "prefix_audit_ok": audits["prefix_cache"] is None
+            or bool(audits["prefix_cache"]["ok"]),
+            "reference_ok": bool(verdict["ok"]),
+            "reference": {k: verdict.get(k) for k in
+                          ("max_deficit", "tolerance", "positions", "cached", "seconds")},
+            "jit_cache_sizes": {k: v["cache_size"] for k, v in after["jit_sites"].items()},
+        }
+        H.emit("correctness", **correctness, first_failures=bad[:5])
+        H.note("correctness " + json.dumps(dict(correctness, first_failures=bad[:5])))
+        return correctness["reference"], bool(
+            correctness["prefix_hit_identical"] and correctness["seeded_twice_identical"]
+            and retraces == 0 and correctness["pool_audit_ok"]
+            and correctness["prefix_audit_ok"] and correctness["reference_ok"]
+            and all(n == 1 for n in correctness["jit_cache_sizes"].values())
+        )
+
     peak = max(
         m.get("peak_bytes_in_use", 0) + m.get("peak_bytes_reserved", 0)
         for m in after["memory"].values()
     ) if after["memory"] else 0
     return {
         "kind": "serving",
-        "correct": bool(correct),
+        "finish": finish,
         "attempted": len(attempted),
         "failed": len(bad),
         "device": dict(device, memory_peak_bytes=int(peak)),

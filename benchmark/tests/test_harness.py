@@ -25,7 +25,7 @@ UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
 
 
 @pytest.mark.parametrize("name,kind", [
-    ("chat_r80_l14", open_loop), ("shared_prefix_c120", closed_sessions),
+    ("chat_r80_l14_v2", open_loop), ("shared_prefix_c120", closed_sessions),
     ("sessions48", closed_sessions),
 ])
 def test_same_seed_same_requests_other_seed_others(name, kind):
@@ -38,7 +38,7 @@ def test_same_seed_same_requests_other_seed_others(name, kind):
 
 
 def test_open_loop_sends_the_same_set_of_lengths_for_every_seed():
-    traffic = H.load_traffic("chat_r80_l14")
+    traffic = H.load_traffic("chat_r80_l14_v2")
     plans = [open_loop.make_plan(dict(traffic), s, 50400, 40.0) for s in (1, 2)]
 
     def lengths(plan, tag):
@@ -60,7 +60,7 @@ def test_open_loop_sends_the_same_set_of_lengths_for_every_seed():
 def test_the_seed_draws_the_schedule_and_the_set_of_lengths_stays():
     """No mix replays one schedule: ``--seed`` moves the due instants, the
     order and who gets which length; the stratified grids keep the SET."""
-    traffic = H.load_traffic("chat_r80_l14")
+    traffic = H.load_traffic("chat_r80_l14_v2")
     a, b = (open_loop.make_plan(dict(traffic), s, 50400, 50.0) for s in (1, 2))
     shape = lambda p: [(r["due"], len(r["payload"]["prompt"]), r["payload"]["max_tokens"],  # noqa: E731
                         "temperature" in r["payload"]) for r in p["requests"]]
@@ -95,7 +95,7 @@ def test_the_backlog_mix_primes_its_prefixes_and_keeps_the_queue_full():
     assert len(plan["clients"]) <= engine["deployment"]["max_ongoing_requests"] - 8
     assert all(len(s["turns"]) == 1 and s["turns"][0]["think_s"] == 0.0
                for c in plan["clients"] for s in c)
-    assert not open_loop.make_plan(H.load_traffic("chat_r80_l14"), 1, 50400, 5.0)["queue_is_load"]
+    assert not open_loop.make_plan(H.load_traffic("chat_r80_l14_v2"), 1, 50400, 5.0)["queue_is_load"]
 
 
 def test_sessions_stay_under_the_context_limit():
@@ -239,9 +239,8 @@ def test_every_name_in_the_manifest_resolves_and_holds_allowed_characters():
                 if "roofline" in m["name"]:
                     assert m["name"].endswith("_roofline") and m["unit"] == "%"
     assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(1, len(cells) // 4)
-    # every reader file is listed, except the one kept for the four-chip
-    # cell that PERF.md specifies as its first open item
-    listed = {m["name"] for m in man["per_layer"]} | {"_common", "allgather_dev_share"}
+    # every reader file is listed; ``_common`` is the readers' shared helper
+    listed = {m["name"] for m in man["per_layer"]} | {"_common"}
     files = {f[:-3] for f in os.listdir(os.path.join(H.BENCH_DIR, "layer_metrics"))
              if f.endswith(".py")}
     assert files == listed
@@ -513,7 +512,7 @@ def test_roofline_and_mfu_readers_take_their_counts_from_the_family():
 
 
 @pytest.mark.parametrize("cell,trace,extra", [
-    ("gptj_chat_r80", 0, []), ("gptj_chat_r80", 1, []),
+    ("gptj_chat_r80_v2", 0, []), ("gptj_chat_r80_v2", 1, []),
     ("gptj_shared_prefix_sat", 0, []), ("gptj_shared_prefix_sat", 1, []),
     ("gpt2m_train", 0, []), ("gpt2m_train", 1, []),
     # what no cell lists yet and PERF.md keeps for later: the four-chip

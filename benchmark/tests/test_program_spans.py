@@ -168,7 +168,7 @@ def test_the_recorded_v5e_xplane_gives_spans_scopes_and_the_kernels_name(tmp_pat
 
 def test_a_traced_rehearsal_reads_the_new_counters_and_no_less_than_before():
     proc = subprocess.run(
-        [sys.executable, os.path.join(H.BENCH_DIR, "run.py"), "--workload", "gptj_chat_r80",
+        [sys.executable, os.path.join(H.BENCH_DIR, "run.py"), "--workload", "gptj_chat_r80_v2",
          "--seed", "3", "--seconds", "4", "--trace", "1", "--rehearsal"],
         cwd=H.ROOT, capture_output=True, text=True, timeout=900,
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
@@ -180,7 +180,7 @@ def test_a_traced_rehearsal_reads_the_new_counters_and_no_less_than_before():
     # a CPU has no device plane: the trace readers, old and new, read nothing
     assert got == {"step_host_ms", "step_wall_max_ms", "submit_lock_wait_ms", "queue_wait_ms"}
     left_out = set(re.findall(r"per_layer metric (\w+): nothing to read", proc.stderr))
-    old = {m["name"] for m in H.manifest()["per_layer"][:15] if "gptj_chat_r80" in m["workloads"]}
+    old = {m["name"] for m in H.manifest()["per_layer"][:15] if "gptj_chat_r80_v2" in m["workloads"]}
     assert old == {"decode_step_dev_ms", "prefill_chunk_dev_ms", "paged_attn_roofline",
                    "device_idle_share"}
     # every old reader still ran to its end (it needs a chip's trace, as before)

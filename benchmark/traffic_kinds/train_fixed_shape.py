@@ -217,11 +217,13 @@ def run(ctx: dict) -> dict:
                 name="benchmark_train", storage_path=os.path.join(ctx["run_dir"], "train"),
             ),
         ).fit()
+        t_fit = time.time()
     finally:
         ray_tpu.shutdown()
         killed = H.reap_descendants()
         if killed:
             H.note(f"killed leftover processes {killed}")
+        t_down = time.time()
     if result.error is not None:
         raise result.error
 
@@ -250,6 +252,15 @@ def run(ctx: dict) -> dict:
     }
     H.emit("correctness", **correctness)
     marks = m["marks"]
+    # the worker's clock is this host's: its marks are this run's parts
+    for part, t in (
+        ("ray_init", t_init), ("worker_start", marks["loop_start"]),
+        ("params_init", marks["params"]), ("reference_probe", marks["reference"]),
+        ("state_init", marks["state"]), ("warmup_steps", marks["warm"]),
+        ("window_wait", m["t_open"]), ("window", m["t_close"]),
+        ("stop_trace_and_report", t_fit), ("shutdown", t_down),
+    ):
+        ctx["budget"].mark(part, t)
     H.emit(
         "setup_breakdown",
         ray_init_s=t_init - ctx["t_start"],
@@ -277,6 +288,8 @@ def run(ctx: dict) -> dict:
         "setup_s": m["t_open"] - ctx["t_start"],
         "seconds": m["t_close"] - m["t_open"],
         "train": m,
+        "reference": {"cached": m["reference_cached"],
+                      "seconds": marks["reference"] - marks["params"]},
         "trace_dir": trace_dir if args.trace else None,
         "device_report": rep,
         "model": m["model"],
