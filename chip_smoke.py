@@ -58,6 +58,8 @@ SERVE_DEPTH = 28
 #: bf16 keeps 8 significant bits: a kernel may differ from its reference
 #: by 4 units in the last place of the largest reference magnitude
 BF16_TOL = 2.0**-6
+#: back-to-back calls behind each ``kernel_ms`` of the kernel phase
+TIMED_CALLS = 20
 
 
 class SmokeFailure(Exception):
@@ -369,8 +371,14 @@ def phase_kernels(args) -> None:
         if on_chip:
             # an interpreted pallas_call lowers to plain ops: no kernel here
             check(kernels == sorted(want_kernels), f"{name}: holds {kernels}")
-        got = jax.tree_util.tree_leaves(jax.jit(kernel_fn)(*operands))
+        run = jax.jit(kernel_fn)
+        got = jax.tree_util.tree_leaves(run(*operands))
         ref = jax.tree_util.tree_leaves(jax.jit(ref_fn)(*operands))
+        t0 = time.perf_counter()
+        for _ in range(TIMED_CALLS):
+            out = run(*operands)
+        jax.block_until_ready(out)
+        kernel_ms = (time.perf_counter() - t0) / TIMED_CALLS * 1e3
         errs, bounds = [], []
         for g, r in zip(got, ref):
             g, r = g.astype(jnp.float32), r.astype(jnp.float32)
@@ -384,17 +392,27 @@ def phase_kernels(args) -> None:
         emit(
             "kernels", dev, kernel=name, mosaic_kernels=kernels,
             dtype=str(jnp.dtype(dt)), max_abs_err=errs, tolerance=bounds,
+            # the host's clock over back-to-back calls of the compiled
+            # program, dispatch included; off the chip no time is reported
+            kernel_ms=round(kernel_ms, 4) if on_chip else "not measured",
         )
 
     # paged decode + verify at the serve phase's shapes, then at the local
-    # head count of tp=4; the last row is the dispatch rule's boundary.
+    # head count of tp=4; the third row is the dispatch rule's boundary, the
+    # last a tp=4 decode batch as the engine sends it: 64 slots x table 128,
+    # most of them empty (an empty slot feeds position 0 and holds one block).
+    # Lengths are ragged in every row: one token, a block plus one, half a
+    # table, a table less the window.
     # Both paths read the pool the way the jitted steps hand it over
     # (model_runner._layer_loop): every layer's blocks in one (layers * nb,
     # ...) view, the tables offset to a layer that is not the first
-    slots, tmax, nb, w = (4, 40, 256, 4) if on_chip else (4, 6, 24, 4)
+    w = 4
     layers, layer = 3, 2
-    paged_shapes = ((16, 16, 256), (4, 16, 256), (16, 8, 128))
-    for heads, bs, d in paged_shapes if on_chip else ((2, 4, 16),):
+    paged_cases = (
+        (16, 16, 256, 4, 40, 256), (4, 16, 256, 4, 40, 256),
+        (16, 8, 128, 4, 40, 256), (4, 16, 256, 64, 128, 1024),
+    )
+    for heads, bs, d, slots, tmax, nb in paged_cases if on_chip else ((2, 4, 16, 4, 6, 24),):
         impl = "auto" if on_chip else "pallas"
         if on_chip:
             check(pa.auto_impl(bs, d) == "pallas", f"auto rule at {bs}x{d}")
@@ -404,9 +422,13 @@ def phase_kernels(args) -> None:
             jax.random.PRNGKey(3), (slots, tmax), 1, nb
         )
         cap = tmax * bs
-        base = jnp.array([0, bs + 1, cap // 2 + 3, cap - w - 1], jnp.int32)
+        ragged = [0, bs + 1, cap // 2 + 3, cap - w - 1]
+        # the four ragged rows spread over the slots, position 0 between them
+        base = jnp.zeros(slots, jnp.int32).at[
+            jnp.arange(4) * (slots // 4)
+        ].set(jnp.array(ragged, jnp.int32))
         positions = base[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-        shape = f"h{heads}_b{bs}_d{d}"
+        shape = f"h{heads}_b{bs}_d{d}_s{slots}_t{tmax}"
         compare(
             f"paged_decode_{shape}",
             lambda q, k, v, t, n: pa.paged_attention(q, k, v, t, n, impl=impl),

@@ -36,15 +36,27 @@ interchangeable paths behind one signature (same contract as
   table*block, d) view, masked softmax.  The reference path; also what
   multi-chip pjit partitions cleanly.
 * ``pallas`` — ONE scalar-prefetch Pallas kernel (decode is the verify
-  kernel at window width 1): grid (slot, logical block), the block
-  table is prefetched so each step DMAs exactly its physical KV block
-  from HBM, online-softmax accumulation across the minor (block) grid
-  dimension.  No (slots, table*block) score matrix and no gathered
-  cache copy ever materializes.  Compiled by Mosaic on a TPU backend
-  (parity against the XLA path at the served shapes: ``chip_smoke.py``'s
-  kernel phase); interpreted everywhere else, which only tests that ask
-  for ``impl="pallas"`` reach (``tests/test_llm_engine.py``,
-  ``tests/test_llm_spec.py``).
+  kernel at window width 1) whose work follows the blocks a row HOLDS:
+  grid ``(slots,)``, one step a slot; the pools stay in HBM and the
+  block tables and positions are prefetched scalars.  Inside a step the
+  kernel walks the row's own ``ceil(length / block_size)`` blocks — a
+  trip count read from the positions, never the table's width — in RUNS
+  of about 512 KB of each pool (``_run_blocks``: a function of the
+  block's shape): a run is one async copy a block, K and V, into one of
+  two VMEM buffers, started while the run before it is in the MXU; the
+  next live slot's first run is started before a slot's last one is
+  consumed, so the copies form one stream across slots.  No copy is
+  issued past a row's length; the last run's ragged tail is masked by
+  position; a slot of length 0 costs a grid step and no fetch.  All heads
+  of a run meet the queries in ONE matmul in the pool's dtype (a query
+  keeps its own head's columns), online-softmax state in float32.  No
+  (slots, table*block) score matrix and no gathered cache copy ever
+  materializes, and the walk's bound is data: one compiled program
+  whatever the batch.  Compiled by Mosaic on a TPU backend (parity
+  against the XLA path at the served shapes: ``chip_smoke.py``'s kernel
+  phase); interpreted everywhere else, which only tests that ask for
+  ``impl="pallas"`` reach (``tests/test_paged_kernel_walk.py``,
+  ``tests/test_llm_engine.py``, ``tests/test_llm_spec.py``).
 
 ``auto`` follows ONE rule, ``auto_impl``: the Pallas kernel on a TPU
 backend when the pool tiles (block_size a multiple of 8, head_dim of
@@ -53,9 +65,10 @@ backend when the pool tiles (block_size a multiple of 8, head_dim of
 Convention: table entries past a sequence's allocation MUST point at a
 valid physical block (the engine pads with block 0, its reserved trash
 block — in the whole-pool view, the layer's own block 0); masking by
-``lengths``/``positions`` makes their values irrelevant.  Slots with
-``length == 0`` produce finite garbage (big-negative masking, never NaN)
-— callers discard inactive slots.
+``lengths``/``positions`` makes their values irrelevant (the Pallas
+kernel never reads them).  Slots with ``length == 0`` produce finite
+garbage (XLA: big-negative masking; the kernel: zeros; never NaN) —
+callers discard inactive slots.
 """
 
 from __future__ import annotations
@@ -188,118 +201,217 @@ def paged_verify_attention_xla(
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+# what one fetch run holds of EACH pool; see ``_run_blocks``
+_RUN_BYTES = 512 * 1024
+
+
+def _run_blocks(heads: int, block_size: int, d: int, itemsize: int, tmax: int) -> int:
+    """Blocks in one fetch run: as many KV blocks as make about
+    ``_RUN_BYTES`` of one pool (4 at 16 heads x 16 x 256 in bf16, 16 at
+    the 4 local heads of ``tp=4``), never more than a table holds.  A
+    block alone (32-128 KB) is too short a copy to keep HBM busy; a run is
+    the unit the kernel double-buffers and feeds the MXU."""
+    return max(1, min(tmax, _RUN_BYTES // (heads * block_size * d * itemsize)))
+
 
 def _paged_verify_kernel(
     # scalar prefetch
     tables_ref,   # (slots * tmax,) int32 — flattened block tables
     pos_ref,      # (slots * w,) int32 — flattened query positions
-    # blocked inputs
-    q_ref,        # (1, w, heads, d)
-    k_ref,        # (1, heads, block, d) — THE slot's j-th physical block
-    v_ref,
-    # blocked output
-    o_ref,        # (1, w, heads, d)
-    # scratch (carried across the minor grid dim)
-    acc_ref,      # (heads, w, d) f32
-    m_ref,        # (heads, w, 1) f32
-    l_ref,        # (heads, w, 1) f32
+    # inputs
+    q_ref,        # (1, w * heads, d) — the slot's queries, row = (i, head)
+    k_hbm,        # (num_blocks, heads, block, d) — the WHOLE pool, in HBM
+    v_hbm,
+    # output
+    o_ref,        # (1, w * heads, d)
+    # scratch (carried across grid steps: the fetch stream spans slots)
+    kbuf,         # (2, run, heads, block, d) — two runs of K blocks
+    vbuf,
+    sems,         # DMA semaphores (2, 2): (k|v, buffer)
+    stream,       # SMEM (2,) int32: buffer of the next run; slot whose
+                  # first run is already in flight (or none)
     *,
+    heads: int,
     block_size: int,
     w: int,
+    run: int,
+    tmax: int,
     scale: float,
 ):
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
+    slots = pl.num_programs(0)
+    rows, d = q_ref.shape[1], q_ref.shape[2]
+    cols = run * heads * block_size
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def blocks_held(slot):
+        # the window is consecutive, so the LAST query's position bounds
+        # the valid cache
+        length = pos_ref[slot * w + (w - 1)] + 1
+        return jnp.minimum(jax.lax.div(length + (block_size - 1), block_size), tmax)
 
-    # per-query positions; the window is consecutive, so the LAST query's
-    # position bounds the valid cache
-    qpos = jnp.stack([pos_ref[s * w + i] for i in range(w)])  # (w,)
-    length = qpos[w - 1] + 1
+    def for_run(slot, r, buf, act):
+        """``act`` on the K and V copy of every block run ``r`` of ``slot``
+        holds — none past the row's length."""
+        n = jnp.minimum(run, blocks_held(slot) - r * run)
 
-    @pl.when(j * block_size < length)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (heads, w, d)
-        k = k_ref[0].astype(jnp.float32)                     # (heads, block, d)
-        v = v_ref[0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k,
-            (((2,), (2,)), ((0,), (0,))),    # contract d, batch heads
-            preferred_element_type=jnp.float32,
-        ) * scale                             # (heads, w, block)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1
-        )                                     # (1, block)
-        causal = pos[None, :, :] <= qpos[None, :, None]  # (1, w, block)
-        scores = jnp.where(causal, scores, NEG_INF)
+        def body(i, carry):
+            blk = tables_ref[slot * tmax + r * run + i]
+            act(pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[buf, i], sems.at[0, buf]))
+            act(pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[buf, i], sems.at[1, buf]))
+            return carry
 
-        m_prev = m_ref[...]                   # (heads, w, 1)
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)           # (heads, w, block)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            (((2,), (1,)), ((0,), (0,))),     # contract block, batch heads
-            preferred_element_type=jnp.float32,
-        )                                     # (heads, w, d)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
+        jax.lax.fori_loop(0, n, body, 0)
 
-    @pl.when(j == n_blocks - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).transpose(1, 0, 2).astype(o_ref.dtype)
+    def start_run(slot, r, buf):
+        for_run(slot, r, buf, lambda copy: copy.start())
+
+    @pl.when(s == 0)
+    def _open():
+        stream[0] = 0
+        stream[1] = -1
+        # the tail of a short run keeps what the buffer held before; its
+        # probabilities are 0, and 0 x (whatever VMEM held) must be 0
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    held = blocks_held(s)
+    n_runs = jax.lax.div(held + (run - 1), run)
+
+    @pl.when(held == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(held > 0)
+    def _live():
+        first = stream[0]
+
+        @pl.when(stream[1] != s)
+        def _cold():
+            start_run(s, 0, first)
+
+        q = q_ref[0].astype(kbuf.dtype)                        # (rows, d)
+        # column c of a run is (block c // (heads * block), head, token):
+        # all heads' scores come out of ONE matmul and a query keeps its
+        # own head's columns.  ``reach`` is how far past a column's place
+        # in its run the query's position lies, -1 off its head
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        col_head = jax.lax.rem(jax.lax.div(col, block_size), heads)
+        col_pos = (
+            jax.lax.div(col, heads * block_size) * block_size
+            + jax.lax.rem(col, block_size)
+        )
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        row_pos = jnp.zeros((rows, 1), jnp.int32)
+        for i in range(w):
+            row_pos = jnp.where(
+                jax.lax.div(row, heads) == i, pos_ref[s * w + i], row_pos
+            )
+        reach = jnp.where(
+            col_head == jax.lax.rem(row, heads), row_pos - col_pos, -1
+        )                                                       # (rows, cols)
+
+        def body(r, carry):
+            m_prev, l_prev, acc = carry
+            buf = jax.lax.rem(first + r, 2)
+
+            # the run after this one goes into the other buffer before
+            # this one is waited for: the row's next, or the next live
+            # row's first, so a short row pays no cold fetch
+            @pl.when(r + 1 < n_runs)
+            def _next_run():
+                start_run(s, r + 1, 1 - buf)
+
+            @pl.when(r + 1 == n_runs)
+            def _next_row():
+                nxt = jax.lax.while_loop(
+                    lambda j: (j < slots)
+                    & (blocks_held(jnp.minimum(j, slots - 1)) == 0),
+                    lambda j: j + 1,
+                    s + 1,
+                )
+                stream[1] = nxt
+
+                @pl.when(nxt < slots)
+                def _():
+                    start_run(nxt, 0, 1 - buf)
+
+            for_run(s, r, buf, lambda copy: copy.wait())
+            k = kbuf[buf].reshape(cols, d)
+            v = vbuf[buf].reshape(cols, d)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                           # (rows, cols)
+            seen = reach >= r * (run * block_size)
+            scores = jnp.where(seen, scores, NEG_INF)
+            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(seen, jnp.exp(scores - m_new), 0.0)
+            l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+            # p keeps float32's worth of bits on the MXU's bf16 path: the
+            # pool's dtype holds its leading bits, a second product the rest
+            p_hi = p.astype(v.dtype)
+            p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+
+            def times_v(part):
+                return jax.lax.dot_general(
+                    part, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )                                               # (rows, d)
+
+            pv = times_v(p_hi) + times_v(p_lo)
+            return m_new, l_new, acc * alpha + pv
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_runs, body,
+            (
+                jnp.full((rows, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, d), jnp.float32),
+            ),
+        )
+        stream[0] = jax.lax.rem(first + n_runs, 2)
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
     slots, w, heads, d = q.shape
     _, _, block_size, _ = k_pool.shape
     tmax = block_tables.shape[1]
-    scale = d**-0.5
+    run = _run_blocks(heads, block_size, d, k_pool.dtype.itemsize, tmax)
+    rows = w * heads
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        # minor (block) dimension executes sequentially on TPU, so the
-        # online-softmax scratch carries across a slot's kv blocks
-        grid=(slots, tmax),
+        # ONE step a slot, in order: the fetch stream and its two buffers
+        # carry from a slot to the next
+        grid=(slots,),
         in_specs=[
-            pl.BlockSpec((1, w, heads, d), lambda s, j, tbl, pos: (s, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, heads, block_size, d),
-                lambda s, j, tbl, pos: (tbl[s * tmax + j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, heads, block_size, d),
-                lambda s, j, tbl, pos: (tbl[s * tmax + j], 0, 0, 0),
-            ),
+            pl.BlockSpec((1, rows, d), lambda s, tbl, pos: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, w, heads, d), lambda s, j, tbl, pos: (s, 0, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, rows, d), lambda s, tbl, pos: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((heads, w, d), jnp.float32),
-            pltpu.VMEM((heads, w, 1), jnp.float32),
-            pltpu.VMEM((heads, w, 1), jnp.float32),
+            pltpu.VMEM((2, run, heads, block_size, d), k_pool.dtype),
+            pltpu.VMEM((2, run, heads, block_size, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _paged_verify_kernel, block_size=block_size, w=w, scale=scale
+            _paged_verify_kernel, heads=heads, block_size=block_size, w=w,
+            run=run, tmax=tmax, scale=d**-0.5,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, w, heads, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=not _on_tpu(),
         # the name the kernel has in a lowered program and a device trace
         name="paged_attention_decode" if w == 1 else "paged_attention_verify",
     )(block_tables.reshape(-1).astype(jnp.int32),
       positions.reshape(-1).astype(jnp.int32),
-      q, k_pool, v_pool)
+      q.reshape(slots, rows, d), k_pool, v_pool)
+    return out.reshape(slots, w, heads, d)
 
 
 # ---------------------------------------------------------------------------
