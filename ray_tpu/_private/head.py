@@ -863,7 +863,10 @@ class Head:
         # streaming-generator returns: task_id -> {"items": {index: obj_id},
         # "count": Optional[int] (set at completion), "next": next index a
         # consumer will ask for} (reference: task_manager.cc streaming
-        # generator bookkeeping, _raylet.pyx:1230)
+        # generator bookkeeping, _raylet.pyx:1230). A consumer blocked in
+        # ``stream_next`` waits on "cond", a condition of ITS stream (on
+        # self.lock), not on self.cv: an item wakes the stream it belongs
+        # to, not every blocked consumer of the cluster (``_wake_stream``)
         self.streams: dict[bytes, dict] = {}
         # disposed stream ids (bounded): late stream_items/task_done from a
         # producer that had not yet seen the cancel must NOT resurrect the
@@ -2543,7 +2546,15 @@ class Head:
             if ent is not None:
                 ent.refcount += 1  # held by the stream until handed out/disposed
             st["items"][payload["index"]] = payload["obj_id"]
-            self.cv.notify_all()
+            self._wake_stream(st)
+            self.cv.notify_all()  # the object's readiness, as every store
+
+    def _wake_stream(self, st: Optional[dict]) -> None:
+        """Lock held. Wake the consumers blocked in ``stream_next`` on this
+        stream: a new item, its end, its failure or its disposal."""
+        cond = st.get("cond") if st is not None else None
+        if cond is not None:
+            cond.notify_all()
 
     def rpc_stream_next(self, task_id, index, timeout=None):
         """Blocking: ('item', obj_id) when the index exists; ('end', count)
@@ -2575,7 +2586,15 @@ class Head:
                     raise rex.GetTimeoutError(f"stream_next timed out on {TaskID(task_id)}")
                 if self._shutdown:
                     raise rex.RayError("shutting down")
-                self.cv.wait(timeout=min(remaining, 1.0) if remaining else 1.0)
+                if st is None:  # asked for before the first item arrived
+                    st = self.streams.setdefault(
+                        task_id, {"items": {}, "count": None, "next": 0}
+                    )
+                cond = st.get("cond")
+                if cond is None:
+                    cond = st["cond"] = threading.Condition(self.lock)
+                # the timeout bounds what no wake-up reaches: shutdown
+                cond.wait(timeout=min(remaining, 1.0) if remaining else 1.0)
         if wh is not None and wh.alive:
             wh.send(("stream_ack", {"task_id": task_id, "consumed": index + 1}))
         return ("item", oid)
@@ -2587,6 +2606,7 @@ class Head:
         with self.lock:
             st = self.streams.pop(task_id, None)
             self._disposed_streams[task_id] = True
+            self._wake_stream(st)
             while len(self._disposed_streams) > 4096:
                 self._disposed_streams.pop(next(iter(self._disposed_streams)))
             running = task_id in self.tasks
@@ -2615,6 +2635,7 @@ class Head:
         if st["count"] is None:
             st["count"] = len(st["items"])
             st["completion"] = spec["return_ids"][0]
+        self._wake_stream(st)
 
     def _finish_stream_locked(self, task_id: bytes, payload: dict):
         """task_done of a streaming task: record the final item count and
@@ -2626,6 +2647,7 @@ class Head:
         results = payload.get("results") or []
         if results:
             st["completion"] = results[0][0]
+        self._wake_stream(st)
         self.cv.notify_all()
 
     def _on_task_done(self, wh: WorkerHandle, payload: dict):

@@ -16,7 +16,11 @@ one scheduler.  ``step()`` is the whole design:
    call, static slot count), sample per-slot tokens (per-request
    temperature/top-k/top-p/seed), stream them out, finish requests that
    hit ``max_tokens``/stop tokens, preempting the youngest when the
-   block pool runs dry.  With ``spec_k > 0`` the decode step is
+   block pool runs dry.  The decode is ONE STEP AHEAD of the host: what a
+   slot feeds lives on the device (``model_runner`` "Slot state"), so step
+   N+1 is launched before step N's tokens are read, and the device runs it
+   under the emit, the gauges, the lock hand-over and the next admit
+   ("The decode in flight" below).  With ``spec_k > 0`` the decode step is
    SPECULATIVE: a drafter (``llm.drafter``) proposes ``k`` tokens per
    slot, the target model verifies all ``k+1`` positions in one jitted
    call (``model_runner.verify_step``), and each slot emits its accepted
@@ -35,6 +39,21 @@ gets the name of what the host was doing) and a cumulative counter in
 ``stats()`` (``step_phase_s``, ``loop``, ``submit``, ``queue``: the whole
 window, not a traced slice).  OBSERVABILITY.md, "Engine step timeline".
 
+The decode in flight: ``_flight`` is the launched step whose tokens are
+still on the device.  A step launches the next decode first and reads
+``_flight`` after, and after that a final prefill chunk's first token,
+sampled inside the prefill program (``_first``).  A row is left out of a
+launch once its LAST token is sampled (``max_tokens`` / the model length,
+counted with the tokens in flight); a stop token shows one step late, so
+that row's extra token is dropped at the read (``discarded_tokens``; its
+blocks were freed when it finished, and the device runs its programs in
+launch order, so whoever holds them next writes after the stray write).
+What cannot run ahead reads the device dry first (``_drain``): a cancel or
+a blown deadline of a row in flight, a growth that has to preempt, a weight
+swap, a batch gone empty, the loop's stop.  A drafter needs every token on
+the host, so a speculating engine reads each decode at once (depth 0).
+``stats()["pipeline"]`` counts all of it.
+
 Threading: ``step()`` serializes on an internal lock — any number of
 submitter threads (serve replica handlers) can feed the engine while one
 driver thread (or several, harmlessly) turns the crank.
@@ -52,7 +71,12 @@ import numpy as np
 
 from ray_tpu._private import events as _events
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
-from ray_tpu.llm.model_runner import PagedModelRunner, _sample_rows
+from ray_tpu.llm.model_runner import (
+    PATCH_JOIN,
+    PATCH_SET,
+    PagedModelRunner,
+    pack_knobs,
+)
 from ray_tpu.util import phases as _phases
 from ray_tpu.util import tracing as _tracing
 from ray_tpu.llm.scheduler import (
@@ -105,15 +129,30 @@ METRIC_NAMES = (
 #: of ``stats()["step_phase_s"]``, each the name of its span on the
 #: profiler's clock behind ``llm.step.`` (a speculative step's
 #: ``verify_launch`` / ``verify_fetch`` spans add into the decode keys:
-#: the verify call IS that step's decode)
+#: the verify call IS that step's decode).  ``decode_fetch`` reads the
+#: decode launched a step EARLIER; ``prefill_sample`` is the wait for a
+#: final chunk's first token, after that decode's tokens are out;
+#: ``drain`` reads and emits what is in flight out of turn (``_drain``)
 STEP_PHASES = (
     "admit", "prefill_build", "prefill_launch", "prefill_sample", "draft",
-    "decode_build", "decode_launch", "decode_fetch", "emit", "publish",
+    "decode_build", "decode_launch", "decode_fetch", "emit", "publish", "drain",
 )
 #: upper bounds (seconds) of ``stats()["loop"]["step_wall_hist"]``: a factor
 #: of √2 apart from 1 ms to 65.5 s, then one overflow bucket — a window's
 #: delta of the histogram says whether ANY step took seconds
 STEP_WALL_BOUNDS_S = tuple(0.001 * 2.0 ** (i / 2) for i in range(33))
+
+
+class _Flight:
+    """A launched decode whose tokens are still on the device: the
+    ``(slot, request)`` rows it sampled for and the two arrays to read."""
+
+    __slots__ = ("rows", "ids", "nxt", "logp", "step")
+
+    def __init__(self, rows, nxt, logp, step):
+        self.rows = rows
+        self.ids = {r.id for _, r in rows}
+        self.nxt, self.logp, self.step = nxt, logp, step
 
 
 class _Phase:
@@ -454,9 +493,33 @@ class LLMEngine:
         self.max_model_len = cache_cfg.max_seq_len
         if self.runner.arch == "gpt":
             self.max_model_len = min(self.max_model_len, model_cfg.seq_len)
-        import jax
-
-        self._sample1 = jax.jit(_sample_rows)
+        # slot state on the device (model_runner "Slot state"): the carry
+        # every decode advances in place; the tables and knobs, each beside
+        # the NumPy mirror that decides when to send them again; the two
+        # operands of a step in which nothing joins and nothing changes
+        S = self.cfg.max_slots
+        place = self.runner.place
+        self._carry = place(np.zeros((3, S), np.int32))
+        self._no_patch = place(np.zeros((S, 4), np.int32))
+        self._no_first = place(np.zeros(1, np.int32))
+        tables = np.zeros((S, cache_cfg.max_blocks_per_seq), np.int32)
+        knobs = pack_knobs(
+            np.zeros(S), np.zeros(S), np.zeros(S), np.ones(S), np.zeros(S)
+        )
+        self._tables = (tables, place(tables))
+        self._knobs = (knobs, place(knobs))
+        # mirror of the carry: whose (position, counter) each slot feeds
+        # next; None = unknown (a verify step ran past it): set every slot
+        self._slot_ids: Optional[list] = [None] * S
+        self._slot_next = np.zeros((2, S), np.int32)
+        self._flight: Optional[_Flight] = None
+        # (request, token, logprob): a final chunk's first token, on the device
+        self._first: Optional[tuple] = None
+        self._pipe = {
+            "ahead_steps": 0, "serial_steps": 0, "drains": {},
+            "discarded_tokens": 0,
+            "uploads": {"full": 0, "none": 0, "partial": 0},
+        }
 
     # -- public API --------------------------------------------------------
 
@@ -645,8 +708,9 @@ class LLMEngine:
         return True
 
     def has_work(self) -> bool:
+        """Requests queued or running, or a decode still to be read."""
         with self._lock:
-            return self.scheduler.has_work()
+            return self.scheduler.has_work() or self._flight is not None
 
     @property
     def weights_version(self) -> int:
@@ -665,8 +729,10 @@ class LLMEngine:
         jitted step functions never retrace (they cache on shape and
         dtype, and params are a traced argument, not a captured
         constant). Leaves are ``device_put`` once here so steady-state
-        steps don't re-upload host arrays every call. In-flight requests
-        simply continue under the new weights from their next step —
+        steps don't re-upload host arrays every call. The decode in flight
+        is read and emitted first (a token sampled under the old weights
+        is on the host, in ``req.out``, before the version moves); in-flight
+        requests then continue under the new weights from their next step —
         exactly the semantics async RL wants (and their per-token behavior
         logprobs were captured at sample time, so off-policy correction
         stays exact across the swap).
@@ -709,6 +775,7 @@ class LLMEngine:
                     f"weights_version must not go backwards: "
                     f"{version} < {self._weights_version}"
                 )
+            self._drain("update_weights")
             self.runner.params = new
             self._weights_version = version
             # cached prefix KV was computed under the OLD weights: flush
@@ -880,6 +947,18 @@ class LLMEngine:
                     "wait_s": self.scheduler.queue_wait_s,
                 },
                 "sampler": dict(self._sampler_steps),
+                # the decode pipeline: launches made with the step before
+                # still unread / with nothing in flight; out-of-turn reads
+                # by reason; tokens dropped after a stop token; what each
+                # launch sent of (patch, tables, knobs); and the rows of
+                # the decode in flight NOW, whose tokens no counter above
+                # holds yet
+                "pipeline": dict(
+                    self._pipe,
+                    drains=dict(self._pipe["drains"]),
+                    uploads=dict(self._pipe["uploads"]),
+                    in_flight=len(self._flight.rows) if self._flight else 0,
+                ),
             }
             if self.prefix_cache is not None:
                 s["prefix_cache"] = self.prefix_cache.stats()
@@ -931,6 +1010,8 @@ class LLMEngine:
                 with _tracing.annotate("llm.loop.idle"):
                     stop.wait(idle_sleep_s)
                 self._loop_idle_s += time.perf_counter() - t0
+        with self._lock:
+            self._drain("stop")
 
     # -- the step ----------------------------------------------------------
 
@@ -949,7 +1030,7 @@ class LLMEngine:
             t_in = time.perf_counter()
             self._loop_lock_wait_s += t_in - t_wait
             sched = self.scheduler
-            if not sched.has_work():
+            if not sched.has_work() and self._flight is None:
                 self._publish_gauges()
                 self._beat = (time.monotonic(), 0)
                 self._loop_idle_s += time.perf_counter() - t_in
@@ -970,9 +1051,14 @@ class LLMEngine:
                     attrs["spec"] = spec_info
                 with _tracing.span("llm_engine_step", **attrs):
                     with self._phase("admit"):
-                        self._reap()
-                        sched.admit()
-                        self._apply_cow()
+                        doomed = self._doomed()
+                        reason = self._doomed_in_flight(doomed)
+                        if reason is None:
+                            self._admit(doomed)
+                    if reason is not None:
+                        self._drain(reason)
+                        with self._phase("admit"):
+                            self._admit(doomed)
                     did = self._prefill_one()
                     if self._drafter is not None and self._spec_skip == 0:
                         did = self._spec_decode_all(spec_info) or did
@@ -997,7 +1083,7 @@ class LLMEngine:
             wall = time.perf_counter() - t_in
             self._step_wall_s += wall
             self._step_wall_hist[bisect.bisect_left(STEP_WALL_BOUNDS_S, wall)] += 1
-            return did or sched.has_work()
+            return did or sched.has_work() or self._flight is not None
         finally:
             self._lock.release()
 
@@ -1007,16 +1093,45 @@ class LLMEngine:
         """Finish cancelled and deadline-blown requests (lock held). Also
         the watchdog's locked reap path — ONE copy of the doomed-request
         predicate. Returns how many were finished."""
+        doomed = self._doomed()
+        reason = self._doomed_in_flight(doomed)
+        if reason is not None:
+            self._drain(reason)
+        return self._finish_doomed(doomed)
+
+    def _doomed(self) -> list:
+        """(request, finish reason) of what the reap is about to finish."""
         now = time.time()
-        n = 0
+        doomed = []
         for req in list(self.scheduler.waiting) + self.scheduler.running:
             if req.cancelled.is_set():
-                self.scheduler.finish(req, FINISH_CANCELLED)
-                n += 1
+                doomed.append((req, FINISH_CANCELLED))
             elif req.deadline is not None and now >= req.deadline:
-                self.scheduler.finish(req, FINISH_DEADLINE)
+                doomed.append((req, FINISH_DEADLINE))
+        return doomed
+
+    def _doomed_in_flight(self, doomed: list) -> Optional[str]:
+        """The finish reason of a doomed row with a token in flight: the
+        device is read dry under it first, so that the token is in
+        ``req.out`` and never dropped in silence.  None: nothing to read."""
+        for req, reason in doomed:
+            if self._ahead(req):
+                return reason
+        return None
+
+    def _finish_doomed(self, doomed: list) -> int:
+        n = 0
+        for req, reason in doomed:
+            if not req.finished:  # the drained token may have ended it
+                self.scheduler.finish(req, reason)
                 n += 1
         return n
+
+    def _admit(self, doomed: list) -> None:
+        """The admit phase's work once nothing doomed is still in flight."""
+        self._finish_doomed(doomed)
+        self.scheduler.admit()
+        self._apply_cow()
 
     def _apply_cow(self) -> None:
         """Drain the scheduler's queued copy-on-write forks (cache-aware
@@ -1063,9 +1178,18 @@ class LLMEngine:
             tokens = np.zeros(chunk, np.int32)
             tokens[:n_valid] = piece
             table = self.pool.table_row(req.id)
+            # the program samples from every chunk's last logits; only the
+            # final chunk's token is kept, so only it pays for its knobs
+            final = req.prefill_pos + n_valid >= len(full)
+            p = req.params
+            sampling = pack_knobs(
+                len(req.out), p.temperature if final else 0.0, p.top_k, p.top_p,
+                p.seed,
+            )
         with self._phase("prefill_launch"):
-            k, v, last_logits = self.runner.prefill_chunk(
-                self.pool.k, self.pool.v, tokens, req.prefill_pos, n_valid, table
+            k, v, _logits, tok, logp = self.runner.prefill_chunk(
+                self.pool.k, self.pool.v, tokens, req.prefill_pos, n_valid, table,
+                sampling,
             )
         # the chunk is in flight; what follows is the host's book-keeping
         # for it, billed to prefill_build like the work before the launch
@@ -1096,33 +1220,24 @@ class LLMEngine:
                     limit=min(req.prefill_pos, len(req.prompt)),
                     epoch=req.cache_epoch,
                 )
-        if req.prefill_pos >= len(full):
-            # final chunk: its last position's logits seed generation
-            with self._phase("prefill_sample"):
-                p = req.params
-                tok, lp = self._sample1(
-                    last_logits[None, :],
-                    np.asarray([p.seed & 0xFFFFFFFF], np.uint32),
-                    np.asarray([len(req.out)], np.int32),
-                    np.asarray([p.temperature], np.float32),
-                    np.asarray([p.top_k], np.int32),
-                    np.asarray([p.top_p], np.float32),
-                )
-                # the one host sync of a prefill: waits for the chunk
-                tok, lp = int(tok[0]), float(lp[0])
-            with self._phase("emit"):
+            if final:
+                # the first generated token is on the device: the row can
+                # decode in THIS step (PATCH_JOIN) and the token is read
+                # once that decode is launched, not before
                 req.state = RUNNING
                 req.phase_recompute = False  # recompute ends where decode resumes
-                self._emit(req, tok, lp)
+                self._first = (req, tok, logp)
+        if final and self._drafter is not None:
+            self._read_first()  # a drafter reads the host's tokens: wait now
         return True
 
-    def _grow_all(self, extra: int = 0) -> None:
-        """Ensure every RUNNING slot has cache room for the position(s)
-        the next step writes (plus ``extra`` provisional speculative
-        ones), evicting the youngest when the pool is dry, with
-        preemption accounting."""
+    def _grow_all(self, rows: list) -> None:
+        """Ensure every ``(request, extra)`` has cache room for the
+        position the next step writes (``seq_len - 1`` plus ``extra``: its
+        tokens in flight, or a window's provisional positions), evicting
+        the youngest when the pool is dry, with preemption accounting."""
         sched = self.scheduler
-        for req in list(sched.running):
+        for req, extra in rows:
             if req.state != RUNNING:
                 continue
             before = sched.preempt_count
@@ -1138,65 +1253,219 @@ class LLMEngine:
         key = "sorted_steps" if (temp > 0.0).any() else "greedy_steps"
         self._sampler_steps[key] += 1
 
-    def _decode_all(self) -> bool:
-        """One batched decode step over every RUNNING slot."""
+    # -- the decode in flight ------------------------------------------------
+
+    def _ahead(self, req: Request) -> int:
+        """Tokens of ``req`` sampled on the device and not read yet."""
+        n = 1 if self._first is not None and self._first[0] is req else 0
+        if self._flight is not None and req.id in self._flight.ids:
+            n += 1
+        return n
+
+    def _take_flight(self) -> tuple:
+        """The decode in flight off the device: a step's ONE wait for a
+        decode.  Returns it with its tokens and logprobs on the host."""
         import jax
 
-        sched = self.scheduler
+        flight, self._flight = self._flight, None
+        # under the engine's lock whoever calls (the step; a weight swap or
+        # the loop's stop reading the device dry): the one unbounded daemon
+        # acquirer is the step loop, which IS this read; the watchdog takes
+        # the lock with a timeout and diagnoses from the lock-free beat
+        return flight, *jax.device_get((flight.nxt, flight.logp))  # raylint: disable=RL011
+
+    def _emit_flight(self, flight: _Flight, nxt, logp) -> None:
+        # a row that ended on a stop token a step ago was still in this
+        # launch: its extra token goes nowhere
+        rows = [(i, r) for i, r in flight.rows if not r.finished]
+        self._pipe["discarded_tokens"] += len(flight.rows) - len(rows)
+        now = time.time()
+        for i, req in rows:
+            if req.phase_led is not None:
+                _phases.charge(req.phase_led, _phases.DECODE, now)
+        for i, req in rows:
+            _events.record(
+                "llm.decode", request_id=req.trace_id, engine_req=req.id,
+                step=flight.step, token=int(nxt[i]),
+            )
+            self._emit(req, int(nxt[i]), float(logp[i]))
+        _metrics()["tokens_per_step"].set(len(rows))
+
+    def _take_first(self) -> tuple:
+        """A final chunk's first token off the device: waits for the chunk.
+        Returns (request, token, logprob)."""
+        import jax
+
+        (req, tok, logp), self._first = self._first, None
+        tok, logp = jax.device_get((tok, logp))  # raylint: disable=RL011 (as _take_flight)
+        return req, int(tok[0]), float(logp[0])
+
+    def _read_first(self) -> None:
+        """Wait for the first token and emit it.  Its own wait, AFTER the
+        decode's tokens are out: read in one go with them, every row's
+        token would wait for the chunk too, and a gap would hold the chunk
+        before the decode and the one after it."""
+        with self._phase("prefill_sample"):
+            got = self._take_first()
+        with self._phase("emit"):
+            self._emit(*got)
+
+    def _drain(self, reason: str) -> bool:
+        """Read and emit whatever is still on the device, out of turn:
+        before anything that must see every token on the host."""
+        if self._flight is None and self._first is None:
+            return False
+        with self._phase("drain"):
+            if self._flight is not None:
+                self._emit_flight(*self._take_flight())
+            if self._first is not None:
+                self._emit(*self._take_first())
+        drains = self._pipe["drains"]
+        drains[reason] = drains.get(reason, 0) + 1
+        return True
+
+    def _decode_rows(self) -> list:
+        """``(slot, request, tokens in flight)`` of the rows the next
+        decode samples for: RUNNING, and not already past their last token
+        with what is in flight."""
+        rows = []
+        for i, r in enumerate(self.scheduler.slots):
+            if r is None or r.state != RUNNING:
+                continue
+            ahead = self._ahead(r)
+            if (
+                len(r.out) + ahead < r.params.max_tokens
+                and r.seq_len + ahead < self.max_model_len
+            ):
+                rows.append((i, r, ahead))
+        return rows
+
+    def _would_preempt(self, rows: list) -> bool:
+        """Whether growing ``rows`` by a position each takes more blocks
+        than the free list and the prefix tree can give."""
+        pool = self.pool
+        need = sum(
+            max(
+                0,
+                pool.blocks_for(min(r.seq_len + ahead, pool.cfg.max_seq_len))
+                - len(pool.blocks_of(r.id)),
+            )
+            for _, r, ahead in rows
+        )
+        free = pool.num_free_blocks
+        return need > free and need > free + pool.num_evictable_blocks
+
+    def _launch_decode(self) -> Optional[_Flight]:
+        """Build and launch one batched decode over every row that has a
+        token to sample; None when there is none."""
         with self._phase("decode_build"):
-            # memory first: every runner needs space for the token it is
-            # about to write; the youngest gets evicted when the pool is dry
-            self._grow_all()
-            active = [
-                (i, r)
-                for i, r in enumerate(sched.slots)
-                if r is not None and r.state == RUNNING
-            ]
-            if not active:
-                return False
-            S = self.cfg.max_slots
-            tokens = np.zeros(S, np.int32)
-            positions = np.zeros(S, np.int32)
-            tables = np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32)
-            temp = np.zeros(S, np.float32)
-            top_k = np.zeros(S, np.int32)
-            top_p = np.ones(S, np.float32)
-            seeds = np.zeros(S, np.uint32)
-            counters = np.zeros(S, np.int32)
-            for i, req in active:
-                tokens[i] = req.out[-1] if req.out else req.prompt[-1]
-                positions[i] = req.seq_len - 1  # the fed token's position
-                tables[i] = self.pool.table_row(req.id)
-                p = req.params
-                temp[i] = p.temperature
-                top_k[i] = p.top_k
-                top_p[i] = p.top_p
-                # mask, don't assign raw: a negative seed overflows a uint32
-                # cell on NumPy >= 2 and the OverflowError would kill the
-                # engine loop thread
-                seeds[i] = p.seed & 0xFFFFFFFF
-                counters[i] = len(req.out)
-            self._note_sampler(temp)
+            rows = self._decode_rows()
+            # a row about to be preempted replays prompt + out: its token in
+            # flight has to be in out first
+            blocked = (
+                self._flight is not None or self._first is not None
+            ) and self._would_preempt(rows)
+            built = None if blocked else self._build_decode(rows)
+        if blocked:
+            self._drain("preempt")
+            with self._phase("decode_build"):
+                built = self._build_decode(self._decode_rows())
+        if built is None:
+            return None
+        rows, first_tok, patch = built
         with self._phase("decode_launch"):
-            k, v, nxt, logp = self.runner.decode_step(
-                self.pool.k, self.pool.v, tokens, positions, tables,
-                temp, top_k, top_p, seeds, counters,
+            k, v, self._carry, nxt, logp = self.runner.decode_step(
+                self.pool.k, self.pool.v, self._carry, first_tok, patch,
+                self._tables[1], self._knobs[1],
             )
             self.pool.k, self.pool.v = k, v
-        with self._phase("decode_fetch"):
-            nxt, logp = jax.device_get((nxt, logp))  # ONE host sync for the batch
-        with self._phase("emit"):
-            now = time.time()
-            for i, req in active:
-                if req.phase_led is not None:
-                    _phases.charge(req.phase_led, _phases.DECODE, now)
-            for i, req in active:
-                _events.record(
-                    "llm.decode", request_id=req.trace_id, engine_req=req.id,
-                    step=self._step_n, token=int(nxt[i]),
-                )
-                self._emit(req, int(nxt[i]), float(logp[i]))
-            _metrics()["tokens_per_step"].set(len(active))
+            return _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
+
+    def _build_decode(self, rows: list) -> Optional[tuple]:
+        """Grow ``rows`` and bring the device's slot state up to them: the
+        host sends only what its mirror of that state says has changed.
+        Returns (the rows that decode, the first-token operand, the patch
+        operand), or None when no row is left."""
+        # memory first: every runner needs space for the token it is
+        # about to write; the youngest gets evicted when the pool is dry
+        self._grow_all([(r, ahead) for _, r, ahead in rows])
+        rows = [row for row in rows if row[1].state == RUNNING]
+        if not rows:
+            return None
+        S = self.cfg.max_slots
+        feed = np.zeros((2, S), np.int32)  # positions, counters
+        tables = np.zeros_like(self._tables[0])
+        live = np.zeros(S, np.int32)
+        temp = np.zeros(S, np.float32)
+        top_k = np.zeros(S, np.int32)
+        top_p = np.ones(S, np.float32)
+        seeds = np.zeros(S, np.int64)
+        patch = np.zeros((S, 4), np.int32)
+        ids: list = [None] * S
+        known, first_tok = self._slot_ids, self._no_first
+        for i, req, ahead in rows:
+            pos = req.seq_len + ahead - 1  # the fed token's position
+            ctr = len(req.out) + ahead
+            feed[:, i] = pos, ctr
+            tables[i] = self.pool.table_row(req.id)
+            p = req.params
+            live[i] = 1
+            temp[i] = p.temperature
+            top_k[i] = p.top_k
+            top_p[i] = p.top_p
+            seeds[i] = p.seed & 0xFFFFFFFF
+            ids[i] = req.id
+            if (
+                known is None or known[i] != req.id
+                or self._slot_next[0, i] != pos or self._slot_next[1, i] != ctr
+            ):
+                if self._first is not None and self._first[0] is req:
+                    patch[i] = PATCH_JOIN, 0, pos, ctr
+                    first_tok = self._first[1]
+                else:
+                    assert ahead == 0, "a row set from the host has its token there"
+                    tok = req.out[-1] if req.out else req.prompt[-1]
+                    patch[i] = PATCH_SET, tok, pos, ctr
+        for i in range(S):
+            if ids[i] is None and (known is None or known[i] is not None):
+                patch[i, 0] = PATCH_SET  # emptied: back to (0, 0, 0)
+        place = self.runner.place
+        sent = 0
+        if patch.any():
+            patch, sent = place(patch), sent + 1
+        else:
+            patch = self._no_patch
+        if not np.array_equal(tables, self._tables[0]):
+            self._tables, sent = (tables, place(tables)), sent + 1
+        knobs = pack_knobs(live, temp, top_k, top_p, seeds)
+        if not np.array_equal(knobs, self._knobs[0]):
+            self._knobs, sent = (knobs, place(knobs)), sent + 1
+        self._pipe["uploads"][("none", "partial", "partial", "full")[sent]] += 1
+        self._slot_ids = ids
+        self._slot_next = feed + live
+        self._note_sampler(temp)
+        return rows, first_tok, patch
+
+    def _decode_all(self) -> bool:
+        """One batched decode step over every RUNNING slot: launch the
+        next one, THEN read the one in flight, so the device works under
+        the emit and everything up to the next launch.  A drafter needs
+        the tokens on the host: depth 0, each launch read at once."""
+        nxt = self._launch_decode()
+        if nxt is None:
+            return self._drain("empty")
+        self._pipe["ahead_steps" if self._flight is not None else "serial_steps"] += 1
+        if self._drafter is not None:
+            self._flight, nxt = nxt, None
+        if self._flight is not None:
+            with self._phase("decode_fetch"):
+                got = self._take_flight()
+            with self._phase("emit"):
+                self._emit_flight(*got)
+        if self._first is not None:
+            self._read_first()
+        if nxt is not None:
+            self._flight = nxt
         return True
 
     def _spec_decode_all(self, spec_info: dict) -> bool:
@@ -1233,7 +1502,7 @@ class LLMEngine:
             draft_by_id = {r.id: draft[row] for row, (_, r) in enumerate(active)}
             # memory next: the window provisionally writes positions
             # seq_len-1 .. seq_len-1+k; the youngest gets evicted when dry
-            self._grow_all(extra=kd)
+            self._grow_all([(r, kd) for _, r in active])
             active = [(i, r) for i, r in active if r.state == RUNNING]
             if not active:
                 return False
@@ -1258,6 +1527,7 @@ class LLMEngine:
                 seeds[i] = p.seed & 0xFFFFFFFF
                 counters[i] = len(req.out)
             self._note_sampler(temp)
+            self._slot_ids = None  # the window moves rows past the carry
         with self._phase("decode_launch", "verify_launch"):
             k, v, n_acc, out, out_lp = self.runner.verify_step(
                 self.pool.k, self.pool.v, tokens, base_pos, tables,

@@ -15,11 +15,14 @@ Three entry shapes, each jitted once per engine:
   their writes land in reserved block 0 and their sampled tokens are
   discarded host-side.  Every sampled token returns with its behavior
   logprob (``models.sampling`` logprob convention — the RLHF capture
-  path), as does every verified window position below.
+  path), as does every verified window position below.  What a slot
+  feeds LIVES ON THE DEVICE ("Slot state" below): the engine launches
+  step N+1 before it has read step N's tokens.
 * ``prefill_chunk`` — (chunk,) tokens of ONE sequence at positions
   ``start..start+chunk`` (tail-padded; padded positions scatter to the
-  trash block).  Returns the last valid position's logits so the final
-  chunk seeds the first generated token.
+  trash block).  Returns the last valid position's logits and the token
+  sampled from them by the request's own knobs (one row of
+  ``_sample_rows``): the final chunk's seeds generation, on the device.
 * ``verify_step`` — (slots, k+1) speculative-decode verification: each
   slot feeds its last emitted token plus ``k`` drafted tokens, their k/v
   scatter PROVISIONALLY into the pool, one multi-query paged attention
@@ -31,6 +34,16 @@ Three entry shapes, each jitted once per engine:
   rolls back host-side via ``cache.shrink_to``).  Window positions past
   the table's reach scatter to the trash block, so slots at the model-
   length cap stay safe (their surplus logits are discarded host-side).
+
+Slot state: the decode program carries ``(token, position, counter)`` of
+every slot from one step to the next in ONE donated ``(3, slots)`` int32
+array, which it returns advanced by the token it sampled, so the next step
+can be launched before this one's tokens are read.  The host says only what
+CHANGED, in ``patch`` (``PATCH_*``: a row set from host values, a row whose
+first token is the array a final prefill chunk left on the device, a slot
+emptied); block tables and the packed per-slot knobs (``pack_knobs``) are
+device arrays the engine replaces when its NumPy mirror of them changes.
+Small operands reach the device through ``place`` (replicated under ``tp``).
 
 Names on the device: the shared layer math below runs under
 ``jax.named_scope``s (``SCOPES``), the same names in decode, verify,
@@ -52,6 +65,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu._private import events as _events
 from ray_tpu._private.compile_cache import ensure_compile_cache
@@ -70,8 +84,46 @@ from ray_tpu.ops.paged_attention import (
 #: ``tp_sum``): a device op's ``op_name`` holds one of them as a path segment
 SCOPES = (
     "embed", "qkv", "kv_write", "paged_attention", "attn_out", "mlp",
-    "lm_head", "sample", "kv_fork",
+    "lm_head", "sample", "kv_fork", "slot_state",
 )
+
+#: ``patch[:, 0]`` of the decode program, per slot: feed what the carry
+#: holds; feed ``patch[:, 1:]`` = (token, position, counter); or feed
+#: that position and counter with the token a final prefill chunk sampled
+PATCH_KEEP, PATCH_SET, PATCH_JOIN = 0, 1, 2
+
+
+def _bits(x, dtype) -> np.ndarray:
+    """Host values as the int32 words of a packed operand."""
+    return np.asarray(x, dtype).view(np.int32)
+
+
+def pack_knobs(live, temp, top_k, top_p, seeds) -> np.ndarray:
+    """(slots, 5) int32: one upload for what a slot samples with.  ``live``
+    is 1 where the carry advances; an empty slot stays at position 0 (one
+    trash block for the kernel to walk).  From scalars, (5,): the prefill
+    program's one row, its first word the row's counter."""
+    return np.stack([
+        np.asarray(live, np.int32), _bits(temp, np.float32),
+        np.asarray(top_k, np.int32), _bits(top_p, np.float32),
+        # mask, don't cast raw: a negative seed overflows a uint32 cell on
+        # NumPy >= 2 and the OverflowError would kill the engine loop thread
+        _bits(np.asarray(seeds, np.int64) & 0xFFFFFFFF, np.uint32),
+    ], axis=-1)
+
+
+def host_batch(tokens, positions, tables, temp, top_k, top_p, seeds, counters):
+    """``decode_step``'s operands after the pools for a batch held wholly
+    on the host (a probe, a test: no carry to go on from): every slot is
+    set from these arrays."""
+    n = len(tokens)
+    patch = np.stack(
+        [np.full(n, PATCH_SET), tokens, positions, counters], axis=-1
+    ).astype(np.int32)
+    return (
+        np.zeros((3, n), np.int32), np.zeros(1, np.int32), patch,
+        np.asarray(tables, np.int32), pack_knobs(np.ones(n), temp, top_k, top_p, seeds),
+    )
 
 
 def _rotary_rows(x: jax.Array, positions: jax.Array, rotary_dim: int) -> jax.Array:
@@ -183,6 +235,57 @@ def _verify_rows(logits, draft, seeds, counters, temp, top_k, top_p):
         )
 
 
+def _f32(words):
+    return jax.lax.bitcast_convert_type(words, jnp.float32)
+
+
+def _u32(words):
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
+def _merge_slots(carry, first_tok, patch):
+    """What each slot feeds this step: the carry, or the host's patch."""
+    with jax.named_scope("slot_state"):
+        mode = patch[:, 0]
+        keep = mode == PATCH_KEEP
+        tokens = jnp.where(
+            keep, carry[0], jnp.where(mode == PATCH_JOIN, first_tok[0], patch[:, 1])
+        )
+        return (
+            tokens,
+            jnp.where(keep, carry[1], patch[:, 2]),
+            jnp.where(keep, carry[2], patch[:, 3]),
+        )
+
+
+def _advance_slots(live, nxt, positions, counters):
+    """The carry the next step feeds from: the sampled token, one position
+    and one counter on; an empty slot stays (0, 0, 0)."""
+    with jax.named_scope("slot_state"):
+        return jnp.stack([jnp.where(live > 0, nxt, 0), positions + live, counters + live])
+
+
+def _decode_sample(logits, knobs, counters):
+    """The decode batch's tokens by its packed knobs. Returns (live, tokens,
+    logprobs)."""
+    live = knobs[:, 0]
+    nxt, logp = _sample_rows(
+        logits, _u32(knobs[:, 4]), counters, _f32(knobs[:, 1]), knobs[:, 2],
+        _f32(knobs[:, 3]),
+    )
+    return live, nxt, logp
+
+
+def _prefill_sample(logits, sampling):
+    """One row of ``_sample_rows`` on a chunk's last logits (V,), by the
+    request's own ``pack_knobs(counter, ...)``. Returns ((1,) token, (1,)
+    logprob)."""
+    w = sampling[:, None]
+    return _sample_rows(
+        logits[None, :], _u32(w[4]), w[0], _f32(w[1]), w[2], _f32(w[3])
+    )
+
+
 class PagedModelRunner:
     """Owns the jitted step functions for one (config, params) pair."""
 
@@ -210,7 +313,7 @@ class PagedModelRunner:
         # (_layer_loop); as the scan's xs/ys the pool was copied six times a
         # step, more than the step's math.  tests/test_llm_pool_inplace.py
         # holds every step to it through the compiled program's temp size
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2, 3))
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
         )
@@ -384,17 +487,18 @@ class PagedModelRunner:
         params,
         k_pool,      # (L, NB, H, BS, D)
         v_pool,
-        tokens,      # (S,) int32 — the token being FED per slot
-        positions,   # (S,) int32 — its position (== cache length before it)
+        carry,       # (3, S) int32 — (token, position, counter) left by the
+                     # step before: donated, returned advanced
+        first_tok,   # (1,) int32 — a final prefill chunk's token (PATCH_JOIN)
+        patch,       # (S, 4) int32 — (PATCH_*, token, position, counter)
         tables,      # (S, T) int32
-        temp,        # (S,) f32
-        top_k,       # (S,) i32
-        top_p,       # (S,) f32
-        seeds,       # (S,) u32 — per-request sampling seed
-        counters,    # (S,) i32 — index of the token being sampled
+        knobs,       # (S, 5) int32 — pack_knobs
     ):
         cfg = self.cfg
         bs = self.block_size
+        # tokens: the token being FED per slot; positions: its position (==
+        # cache length before it); counters: index of the token being sampled
+        tokens, positions, counters = _merge_slots(carry, first_tok, patch)
         x = self._embed(params, tokens, positions)  # (S, d)
         phys = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
         off = positions % bs
@@ -412,15 +516,21 @@ class PagedModelRunner:
             ),
         )
         logits = self._lm_head(params, x)  # (S, V)
-        nxt, logp = _sample_rows(logits, seeds, counters, temp, top_k, top_p)
-        return k_pool, v_pool, nxt, logp
+        live, nxt, logp = _decode_sample(logits, knobs, counters)
+        return (
+            k_pool, v_pool, _advance_slots(live, nxt, positions, counters), nxt, logp
+        )
 
-    def decode_step(self, k_pool, v_pool, tokens, positions, tables,
-                    temp, top_k, top_p, seeds, counters):
+    def place(self, x):
+        """A small host operand onto the device the steps run on, to stay
+        there over many steps (replicated under ``tp``): uncommitted, like
+        the pool and the weights, so a step's cache key never changes."""
+        return jax.device_put(x)
+
+    def decode_step(self, k_pool, v_pool, carry, first_tok, patch, tables, knobs):
         return self._call(
-            "decode", self._decode, len(tokens),
-            self.params, k_pool, v_pool, tokens, positions, tables,
-            temp, top_k, top_p, seeds, counters,
+            "decode", self._decode, jnp.shape(tables)[0],
+            self.params, k_pool, v_pool, carry, first_tok, patch, tables, knobs,
         )
 
     # -- speculative verification step -------------------------------------
@@ -508,6 +618,7 @@ class PagedModelRunner:
         start,      # scalar int32 — position of tokens[0]
         n_valid,    # scalar int32 — valid tokens in this chunk
         table,      # (T,) int32 — THIS sequence's block table
+        sampling,   # (5,) int32 — pack_knobs(counter, ...) of this request
         *,
         chunk: int,
     ):
@@ -532,11 +643,14 @@ class PagedModelRunner:
         )
         last = x[jnp.maximum(n_valid - 1, 0)]  # (d,)
         logits = self._lm_head(params, last[None, :])[0]  # (V,)
-        return k_pool, v_pool, logits
+        tok, logp = _prefill_sample(logits, sampling)
+        return k_pool, v_pool, logits, tok, logp
 
-    def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table):
+    def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table, sampling):
+        # start / n_valid go in as host scalars: a jnp.int32() here is an
+        # eager program of its own in front of every chunk
         return self._call(
             "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
-            jnp.int32(start), jnp.int32(n_valid), table, chunk=len(tokens),
+            np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
         )

@@ -25,7 +25,8 @@ Megatron column/row split, chosen so the PAGED layout shards for free:
   replicated result.
 
 The three jitted entry points (decode / prefill / verify) and the CoW
-``fork_blocks`` keep their single-chip signatures — ``LLMEngine``,
+``fork_blocks`` keep their single-chip signatures (the decode's slot
+state and every small operand replicated, placed by ``place``) — ``LLMEngine``,
 speculative decoding, preemption-recompute, failover ``resume_tokens``
 and the prefix cache run UNCHANGED on top; ``EngineConfig(tp=N)`` is
 the only switch.  Off-TPU this runs on jax host-platform device-count
@@ -68,15 +69,19 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
 from ray_tpu.llm.model_runner import (
     PagedModelRunner,
+    _advance_slots,
+    _decode_sample,
     _fork_impl,
     _layer_loop,
     _layernorm,
-    _sample_rows,
+    _merge_slots,
+    _prefill_sample,
     _verify_rows,
 )
 from ray_tpu.ops.paged_attention import (
@@ -230,16 +235,16 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                     pspecs,
                     P(None, None, "tp", None, None),
                     P(None, None, "tp", None, None),
-                    P(), P(), P(), P(), P(), P(), P(), P(),
+                    P(), P(), P(), P(), P(),
                 ),
                 out_specs=(
                     P(None, None, "tp", None, None),
                     P(None, None, "tp", None, None),
-                    P(), P(),
+                    P(), P(), P(),
                 ),
                 check_vma=False,
             ),
-            donate_argnums=(1, 2),
+            donate_argnums=(1, 2, 3),
         )
         self._verify = jax.jit(
             jax.shard_map(
@@ -268,12 +273,12 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
                     pspecs,
                     P(None, None, "tp", None, None),
                     P(None, None, "tp", None, None),
-                    P(), P(), P(), P(),
+                    P(), P(), P(), P(), P(),
                 ),
                 out_specs=(
                     P(None, None, "tp", None, None),
                     P(None, None, "tp", None, None),
-                    P(),
+                    P(), P(), P(),
                 ),
                 check_vma=False,
             ),
@@ -300,6 +305,11 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         )
 
     # -- parameter placement ----------------------------------------------
+
+    def place(self, x):
+        """Replicated over the mesh, spelled as jax spells the steps' own
+        replicated outputs (the carry a decode hands back)."""
+        return jax.device_put(x, NamedSharding(self._mesh, P()))
 
     def _param_spec_tree(self):
         return jax.tree_util.tree_map_with_path(
@@ -445,10 +455,10 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
     # on every device and the P() out_specs read one copy.
 
     def _decode_shard(
-        self, params, k_pool, v_pool, tokens, positions, tables,
-        temp, top_k, top_p, seeds, counters,
+        self, params, k_pool, v_pool, carry, first_tok, patch, tables, knobs,
     ):
         bs = self.block_size
+        tokens, positions, counters = _merge_slots(carry, first_tok, patch)
         S = tokens.shape[0]
         x = self._embed(params, tokens, positions)
         phys = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
@@ -465,8 +475,10 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             positions=positions, phys=phys, off=off, attend=attend,
         )
         logits = self._lm_head(params, x)
-        nxt, logp = _sample_rows(logits, seeds, counters, temp, top_k, top_p)
-        return k_pool, v_pool, nxt, logp
+        live, nxt, logp = _decode_sample(logits, knobs, counters)
+        return (
+            k_pool, v_pool, _advance_slots(live, nxt, positions, counters), nxt, logp
+        )
 
     def _verify_shard(
         self, params, k_pool, v_pool, tokens, base_pos, tables,
@@ -508,7 +520,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         return k_pool, v_pool, n_acc, out, logp
 
     def _prefill_shard(
-        self, params, k_pool, v_pool, tokens, start, n_valid, table,
+        self, params, k_pool, v_pool, tokens, start, n_valid, table, sampling,
     ):
         # chunk is tokens.shape[0] — static under jit, but NOT a static
         # kwarg: shard_map takes positional specs only, and the engine
@@ -532,12 +544,13 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         )
         last = x[jnp.maximum(n_valid - 1, 0)]
         logits = self._lm_head(params, last[None, :])[0]
-        return k_pool, v_pool, logits
+        tok, logp = _prefill_sample(logits, sampling)
+        return k_pool, v_pool, logits, tok, logp
 
-    def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table):
+    def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table, sampling):
         # base passes chunk= as a static kwarg; the shard body derives it
         return self._call(
             "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
-            jnp.int32(start), jnp.int32(n_valid), table,
+            np.int32(start), np.int32(n_valid), table, sampling,
         )

@@ -30,7 +30,11 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool  # noqa: E402
-from ray_tpu.llm.model_runner import PagedModelRunner  # noqa: E402
+from ray_tpu.llm.model_runner import (  # noqa: E402
+    PagedModelRunner,
+    host_batch,
+    pack_knobs,
+)
 from ray_tpu.models.gpt import GPTConfig, gpt_init  # noqa: E402
 
 
@@ -92,8 +96,11 @@ def test_decode_step_invalidates_donated_pool_buffers():
     blows up with a deleted-buffer error."""
     runner, pool = _runner_and_pool()
     stale_k, stale_v = pool.k, pool.v
-    k, v, nxt, logp = runner.decode_step(pool.k, pool.v, *_decode_args(pool))
-    assert stale_k.is_deleted() and stale_v.is_deleted()
+    carry, *rest = host_batch(*_decode_args(pool))
+    carry = runner.place(carry)  # the slot state rides the same contract
+    k, v, new_carry, nxt, logp = runner.decode_step(pool.k, pool.v, carry, *rest)
+    assert stale_k.is_deleted() and stale_v.is_deleted() and carry.is_deleted()
+    assert np.asarray(new_carry)[0].tolist() == np.asarray(nxt).tolist()
     with pytest.raises(RuntimeError, match="deleted"):
         np.asarray(stale_k)  # the poisoned read RL013 flags statically
     # the reassign-immediately idiom restores a usable pool
@@ -110,9 +117,11 @@ def test_prefill_and_fork_paths_also_donate():
     table = pool.table_row(None)
     table[0] = 1
     old_k = pool.k
-    k, v, logits = runner.prefill_chunk(
-        pool.k, pool.v, np.array([1, 2, 3, 0], np.int32), 0, 3, table
+    k, v, logits, tok, _logp = runner.prefill_chunk(
+        pool.k, pool.v, np.array([1, 2, 3, 0], np.int32), 0, 3, table,
+        pack_knobs(0, 0.0, 0, 1.0, 0),
     )
+    assert int(tok[0]) == int(np.asarray(logits).argmax())
     assert old_k.is_deleted()
     pool.k, pool.v = k, v
     old_k = pool.k
@@ -137,9 +146,10 @@ def test_reassigned_pool_decodes_deterministically():
             )
             positions[:] = step
             counters[:] = step
-            k, v, nxt, logp = runner.decode_step(
-                pool.k, pool.v, tokens, positions, tables,
-                temp, tk, tp, seeds, counters,
+            k, v, _carry, nxt, logp = runner.decode_step(
+                pool.k, pool.v, *host_batch(
+                    tokens, positions, tables, temp, tk, tp, seeds, counters
+                ),
             )
             pool.k, pool.v = k, v
             out.append((np.asarray(nxt).copy(), np.asarray(logp).copy()))

@@ -35,6 +35,8 @@ from ray_tpu.llm.model_runner import (  # noqa: E402
     _layernorm,
     _sample_rows,
     _verify_rows,
+    host_batch,
+    pack_knobs,
 )
 from ray_tpu.llm.multichip import TensorParallelPagedModelRunner  # noqa: E402
 from ray_tpu.models.gpt import GPTConfig, gpt_init  # noqa: E402
@@ -108,7 +110,7 @@ def _operands(step):
         tokens = rng.integers(0, 96, CHUNK).astype(np.int32)
         pos = start + np.arange(CHUNK)
         phys = np.where(np.arange(CHUNK) < n_valid, table[pos // BS], 0)
-        ops = (tokens, jnp.int32(start), jnp.int32(n_valid), table)
+        ops = (tokens, np.int32(start), np.int32(n_valid), table)
         return ops, (pos, phys, pos % BS)
     tables = rng.choice(np.arange(1, NB), (SLOTS, TMAX), replace=False).astype(np.int32)
     if step == "decode":
@@ -138,10 +140,15 @@ def _runner(arch, tp):
 
 
 def _jitted(runner, step, ops):
-    """(jitted fn, static kwargs) of a step, as the runner's wrappers call it."""
-    if step == "prefill" and not hasattr(runner, "tp"):
-        return runner._prefill, {"chunk": len(ops[0])}
-    return getattr(runner, f"_{step}"), {}
+    """(jitted fn, its operands after the pools, static kwargs) of a step,
+    as the runner's wrappers call it: the decode takes its batch as slot
+    state and a patch (``host_batch``), the prefill a sampler row."""
+    if step == "decode":
+        return runner._decode, host_batch(*ops), {}
+    if step == "prefill":
+        static = {} if hasattr(runner, "tp") else {"chunk": len(ops[0])}
+        return runner._prefill, ops + (pack_knobs(0, 0.0, 0, 1.0, 0),), static
+    return runner._verify, ops, {}
 
 
 @pytest.mark.parametrize("step,arch,tp", CASES)
@@ -149,7 +156,7 @@ def test_step_holds_no_pool_sized_temporary(step, arch, tp):
     runner = _runner(arch, tp)
     k, v = _noise_pools(tp)
     ops, _rows = _operands(step)
-    fn, static = _jitted(runner, step, ops)
+    fn, ops, static = _jitted(runner, step, ops)
     compiled = fn.lower(runner.params, k, v, *ops, **static).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     pool_bytes = k.nbytes // tp  # one pool's bytes on one device
@@ -215,9 +222,16 @@ def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
     ref_k, ref_v, ref_out = _reference(
         arch, step, ops, rows, jnp.asarray(k0), jnp.asarray(v0)
     )
-    fn, static = _jitted(runner, step, ops)
+    fn, sent, static = _jitted(runner, step, ops)
     k, v = _noise_pools(tp)
-    k1, v1, *out = fn(runner.params, k, v, *ops, **static)
+    k1, v1, *out = fn(runner.params, k, v, *sent, **static)
+    if step == "decode":
+        # the carry the next step feeds from: the sampled token, one
+        # position and one counter on
+        carry, *out = out
+        np.testing.assert_array_equal(
+            np.asarray(carry), np.stack([np.asarray(out[0]), ops[1] + 1, ops[7] + 1])
+        )
 
     _pos, phys, off = rows
     fed = np.zeros((L, NB, BS), bool)

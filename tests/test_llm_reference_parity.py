@@ -40,7 +40,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import gptj as reference  # noqa: E402
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool  # noqa: E402
-from ray_tpu.llm.model_runner import PagedModelRunner  # noqa: E402
+from ray_tpu.llm.model_runner import (  # noqa: E402
+    PagedModelRunner,
+    host_batch,
+    pack_knobs,
+)
 from ray_tpu.llm.multichip import (  # noqa: E402
     ShardedKVBlockPool,
     TensorParallelPagedModelRunner,
@@ -108,7 +112,9 @@ def _served(tp: int, dtype: str = "float32"):
         piece = prompt[start:start + CHUNK]
         tokens = np.zeros(CHUNK, np.int32)
         tokens[:len(piece)] = piece
-        k, v, logits = runner.prefill_chunk(k, v, tokens, start, len(piece), table)
+        k, v, logits, tok, _ = runner.prefill_chunk(
+            k, v, tokens, start, len(piece), table, pack_knobs(0, 0.0, 0, 1.0, 0))
+        assert int(tok[0]) == int(np.asarray(logits).argmax())  # the in-program sampler's row
         chunk_logits.append(np.asarray(logits, np.float32))
     seq = prompt + [int(chunk_logits[-1].argmax())]
     tables = np.zeros((SLOTS, TABLE), np.int32)  # idle rows write the trash block
@@ -118,10 +124,12 @@ def _served(tp: int, dtype: str = "float32"):
     for _ in range(N_DECODE):
         tokens, positions = zeros.copy(), zeros.copy()
         tokens[SLOT], positions[SLOT] = seq[-1], len(seq) - 1
-        k, v, nxt, logp = runner.decode_step(
-            k, v, tokens, positions, tables,
-            np.zeros(SLOTS, np.float32), zeros, np.ones(SLOTS, np.float32),
-            np.zeros(SLOTS, np.uint32), zeros,
+        k, v, _carry, nxt, logp = runner.decode_step(
+            k, v, *host_batch(
+                tokens, positions, tables,
+                np.zeros(SLOTS, np.float32), zeros, np.ones(SLOTS, np.float32),
+                np.zeros(SLOTS, np.uint32), zeros,
+            ),
         )
         seq.append(int(nxt[SLOT]))
         logps.append(float(logp[SLOT]))

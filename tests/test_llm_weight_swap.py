@@ -20,7 +20,7 @@ import pytest
 import jax
 
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
-from ray_tpu.llm.model_runner import PagedModelRunner
+from ray_tpu.llm.model_runner import PagedModelRunner, host_batch, pack_knobs
 from ray_tpu.models.gptj import GPTJConfig, gptj_init
 
 CFG = GPTJConfig(
@@ -58,8 +58,9 @@ def _drive(runner):
     pool.allocate("s1", 12)
     table0 = pool.table_row("s0")
 
-    k, v, last_logits = runner.prefill_chunk(
-        pool.k, pool.v, prompt, 0, len(prompt), table0
+    k, v, last_logits, _tok, _lp = runner.prefill_chunk(
+        pool.k, pool.v, prompt, 0, len(prompt), table0,
+        pack_knobs(0, 0.0, 0, 1.0, 0),
     )
     pool.k, pool.v = k, v
 
@@ -71,9 +72,10 @@ def _drive(runner):
     top_p = np.ones(SLOTS, np.float32)
     seeds = np.zeros(SLOTS, np.uint32)
     counters = np.zeros(SLOTS, np.int32)
-    k, v, nxt, logp = runner.decode_step(
-        pool.k, pool.v, tokens, positions, tables,
-        greedy, top_k, top_p, seeds, counters,
+    k, v, _carry, nxt, logp = runner.decode_step(
+        pool.k, pool.v, *host_batch(
+            tokens, positions, tables, greedy, top_k, top_p, seeds, counters
+        ),
     )
     pool.k, pool.v = k, v
 

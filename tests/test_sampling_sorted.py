@@ -157,7 +157,8 @@ def test_decode_sampler_equals_full_sort_oracle(case, bf16):
 
 @pytest.mark.parametrize("case", ["greedy", "temperature_only", "chat_cell", "top_p_0.1"])
 def test_one_row_the_prefill_sampler_shape(case):
-    """``engine._sample1``: the sampler at one row, after a prompt's last chunk."""
+    """The sampler at one row: what the prefill program runs on a prompt's last chunk
+    (``model_runner._prefill_sample``)."""
     knobs = _knobs(*CASES[case], rows=1)
     for batch_seed in range(4):
         logits = _logits(batch_seed, rows=1, bf16=True)

@@ -187,3 +187,61 @@ def test_async_actor_method_streams(ray_start_regular):
     assert [ray_tpu.get(r, timeout=30) for r in g] == [0, 10, 20, 30]
     # loop stayed serviceable while the stream ran
     assert ray_tpu.get(c.ping.remote(), timeout=30) == "pong"
+
+
+def test_an_item_wakes_its_own_stream_only(ray_start_regular):
+    """A consumer blocked in ``stream_next`` waits on a condition of ITS
+    stream: the items of another stream do not wake it (with one condition
+    for all, a replica streaming 900 tokens a second to 120 consumers woke a
+    hundred thousand threads a second in the head: PERF.md, PR 35)."""
+    import threading
+
+    from ray_tpu._private.runtime import get_ctx
+
+    head = get_ctx().head
+
+    class Counting(threading.Condition):
+        notified = 0
+
+        def notify_all(self):
+            Counting.notified += 1
+            super().notify_all()
+
+    @ray_tpu.remote
+    class Gate:
+        def __init__(self):
+            self.open = False
+
+        def set(self):
+            self.open = True
+
+        def is_open(self):
+            return self.open
+
+    @ray_tpu.remote(num_returns="streaming")
+    def held(gate):
+        yield "first"
+        while not ray_tpu.get(gate.is_open.remote(), timeout=30):
+            time.sleep(0.05)
+        yield "second"
+
+    @ray_tpu.remote(num_returns="streaming")
+    def busy(n):
+        for i in range(n):
+            yield i
+
+    gate = Gate.remote()
+    slow = held.remote(gate)
+    assert ray_tpu.get(next(slow), timeout=30) == "first"
+    with head.lock:
+        head.streams[slow._task_id]["cond"] = Counting(head.lock)
+    got = []
+    waiter = threading.Thread(target=lambda: got.append(ray_tpu.get(next(slow), timeout=30)))
+    waiter.start()
+    time.sleep(0.3)  # the consumer is blocked on the held stream now
+    assert [ray_tpu.get(r, timeout=30) for r in busy.remote(40)] == list(range(40))
+    assert waiter.is_alive() and Counting.notified == 0
+    ray_tpu.get(gate.set.remote(), timeout=30)
+    waiter.join(30)
+    assert not waiter.is_alive() and got == ["second"] and Counting.notified >= 1
+    assert list(slow) == []
