@@ -444,6 +444,28 @@ def phase_kernels(args) -> None:
             ["paged_attention_verify"],
         )
 
+    # the retention decode kernel at Brumby's widths (8 key-value heads of
+    # 128, 5 query heads each) on a pool of 8 float32 states: six rows, four
+    # live, a dead row naming a live row's slot (it must move nothing)
+    from ray_tpu.ops import power_retention as pr
+
+    d, kv, group = (128, 8, 5) if on_chip else (16, 2, 2)
+    slots = jnp.asarray([5, 0, 0, 2, 7, 1], jnp.int32)
+    live = jnp.asarray([True, False, True, True, False, True])
+    log_g = -jax.random.uniform(jax.random.PRNGKey(6), (6, kv), jnp.float32, 1e-3, 1e-2)
+
+    def retention(impl):
+        return lambda s, q, k, v: pr.retention_decode(
+            s, q, k, v, log_g, slots, live, eps=1e-6, impl=impl)
+
+    compare(
+        f"retention_decode_h{kv}x{group}_d{d}",
+        retention("auto" if on_chip else "pallas"), retention("xla"),
+        (jax.random.normal(jax.random.PRNGKey(7), (8, kv) + pr.state_dims(d), jnp.float32),
+         rnd(8, (6, kv * group, d)), rnd(9, (6, kv, d)), rnd(10, (6, kv, d))),
+        ["retention_decode"],
+    )
+
     # flash forward + backward at the train shape, then at tp=4's heads
     flash_shapes = ((26, 16, 1024, 64), (26, 4, 1024, 64))
     for b, h, s, d in flash_shapes if on_chip else ((2, 2, 128, 16),):

@@ -1,4 +1,6 @@
-"""Paged KV-cache block pool — static-shape JAX storage, host-side ledger.
+"""What sequences hold on the device: the paged KV-cache block pool, and
+the recurrent-state pool of a model without keys and values (``StatePool``,
+at the end) — static-shape JAX storage, host-side ledger.
 
 vLLM-style paging on the TPU shape discipline: the device side is two
 fixed arrays per model
@@ -73,6 +75,10 @@ class KVBlockPool:
     updates, the pool object just holds the current version.
     """
 
+    #: sequences own blocks that grow, and blocks are shared: the prefix
+    #: cache, speculative windows and head-sharding are built on that
+    paged = True
+
     def __init__(
         self,
         cfg: CacheConfig,
@@ -103,6 +109,15 @@ class KVBlockPool:
         # sequences, so ownership alone no longer implies exclusivity)
         self._ref: dict[int, int] = {}
         self._cache_held: set[int] = set()
+
+    @property
+    def arrays(self) -> tuple:
+        """The device arrays a jitted step takes (donated) and hands back."""
+        return self.k, self.v
+
+    @arrays.setter
+    def arrays(self, new) -> None:
+        self.k, self.v = new
 
     # -- capacity ----------------------------------------------------------
 
@@ -409,4 +424,164 @@ class KVBlockPool:
                 if blocks is None:
                     raise KeyError(f"unknown sequence {seq_id!r}")
                 row[: len(blocks)] = blocks
+        return row
+
+
+@dataclasses.dataclass(frozen=True)
+class StateConfig:
+    """Geometry of a ``StatePool``, in the words the engine and the
+    scheduler use of a paged pool: a sequence's whole state is ONE block
+    that holds ``max_seq_len`` tokens, so nothing grows and the length
+    limit is the model's positions.  ``num_blocks`` is one more than the
+    slots, which keeps the callers' ``num_blocks - 1`` usable blocks true;
+    no state is reserved (a dead decode row writes nothing)."""
+
+    slots: int
+    max_seq_len: int
+
+    def __post_init__(self):
+        if self.slots < 1 or self.max_seq_len < 1:
+            raise ValueError("slots and max_seq_len must be >= 1")
+
+    @property
+    def block_size(self) -> int:
+        return self.max_seq_len
+
+    @property
+    def max_blocks_per_seq(self) -> int:
+        return 1
+
+    @property
+    def num_blocks(self) -> int:
+        return self.slots + 1
+
+
+class StatePool:
+    """Recurrent states of a model without keys or values: ONE device array
+    ``(layers, slots) + state_shape`` allocated at engine start, and the
+    ledger calls the scheduler and the engine make of ``KVBlockPool``.  A
+    sequence owns one slot from admission to its end: admission needs a
+    free slot, nothing grows, nothing is shared, nobody is preempted for
+    memory.  The jitted steps take ``state`` donated and hand it back; a
+    slot's state is overwritten by its next owner's first prefill chunk,
+    never cleared."""
+
+    paged = False
+
+    def __init__(self, cfg: StateConfig, n_layers: int, state_shape: tuple,
+                 dtype="float32"):
+        import jax.numpy as jnp
+
+        self.cfg = cfg
+        self.state = jnp.zeros((n_layers, cfg.slots) + tuple(state_shape), jnp.dtype(dtype))
+        self._lock = threading.Lock()
+        self._free = list(range(cfg.slots - 1, -1, -1))  # LIFO
+        self._owned: dict[str, int] = {}
+
+    @property
+    def arrays(self) -> tuple:
+        return (self.state,)
+
+    @arrays.setter
+    def arrays(self, new) -> None:
+        (self.state,) = new
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return -(-max(n_tokens, 1) // self.cfg.max_seq_len)
+
+    @property
+    def block_bytes(self) -> int:
+        """Device bytes of one slot's state across every layer."""
+        return self.state.nbytes // self.cfg.slots
+
+    @property
+    def device_bytes(self) -> int:
+        return self.state.nbytes
+
+    @property
+    def num_free_blocks(self) -> int:
+        with self._lock:
+            return len(self._free)
+
+    @property
+    def num_used_blocks(self) -> int:
+        with self._lock:
+            return len(self._owned)
+
+    #: nothing is shared, so nothing is held for a cache
+    num_evictable_blocks = 0
+
+    def ledger_counts(self) -> dict:
+        with self._lock:
+            return {"free": len(self._free), "seq_owned": len(self._owned),
+                    "cache_only": 0}
+
+    def utilization(self) -> float:
+        return self.num_used_blocks / self.cfg.slots
+
+    def can_allocate(self, n_tokens: int, shared: int = 0) -> bool:
+        if self.blocks_for(n_tokens) > 1:
+            return False
+        with self._lock:
+            return bool(self._free)
+
+    def allocate(self, seq_id: str, n_tokens: int, shared: Sequence[int] = ()) -> list[int]:
+        """Claim a slot; all-or-nothing like ``KVBlockPool.allocate``."""
+        with self._lock:
+            if seq_id in self._owned:
+                raise ValueError(f"sequence {seq_id!r} already owns a state")
+            if shared:
+                raise ValueError("a recurrent state is never shared")
+            if self.blocks_for(n_tokens) > 1:
+                raise ValueError(
+                    f"{n_tokens} tokens exceed the model's {self.cfg.max_seq_len} positions")
+            if not self._free:
+                raise MemoryError("state pool exhausted: no free slot")
+            slot = self._owned[seq_id] = self._free.pop()
+            return [slot]
+
+    def grow_to(self, seq_id: str, n_tokens: int) -> bool:
+        """A state holds any length up to the model's: nothing to grow."""
+        with self._lock:
+            if seq_id not in self._owned:
+                raise KeyError(f"unknown sequence {seq_id!r}")
+        return self.blocks_for(n_tokens) <= 1
+
+    def free(self, seq_id: str) -> int:
+        with self._lock:
+            slot = self._owned.pop(seq_id, None)
+            if slot is None:
+                return 0
+            self._free.append(slot)
+            return 1
+
+    def blocks_of(self, seq_id: str) -> list[int]:
+        with self._lock:
+            if seq_id not in self._owned:
+                raise KeyError(f"unknown sequence {seq_id!r}")
+            return [self._owned[seq_id]]
+
+    def audit(self) -> dict:
+        """Free and owned slots must partition the pool, each id in range
+        and held once; the keys the watchdog reads of ``KVBlockPool.audit``."""
+        with self._lock:
+            free, owned = list(self._free), dict(self._owned)
+        held = free + list(owned.values())
+        duplicates = len(held) != len(set(held))
+        out_of_range = sum(1 for s in held if not 0 <= s < self.cfg.slots)
+        missing = self.cfg.slots - len(held)
+        return {
+            "ok": not duplicates and not out_of_range and missing == 0,
+            "free": len(free), "owned": len(owned), "owners": list(owned),
+            "shared": 0, "cached": 0, "cached_only": 0, "ref_errors": 0,
+            "missing": missing, "duplicates": duplicates,
+            "out_of_range": out_of_range,
+        }
+
+    def table_row(self, seq_id: Optional[str]) -> np.ndarray:
+        """(1,) int32: the sequence's slot; ``None`` (an empty decode row)
+        is 0, and a dead row touches no state whatever it names."""
+        row = np.zeros(1, np.int32)
+        if seq_id is not None:
+            row[0] = self.blocks_of(seq_id)[0]
         return row

@@ -1,7 +1,11 @@
 """LLMEngine: the continuous-batching step loop.
 
 One engine owns one model (GPT or GPT-J params), one paged KV pool, and
-one scheduler.  ``step()`` is the whole design:
+one scheduler.  A model without keys and values (``cache_kind == "state"``:
+``models.brumby``) gets ``cache.StatePool`` and ``state_runner`` behind the
+same calls: a sequence owns one fixed-size recurrent state, so admission is
+a free slot, nothing grows or is preempted, and the prefix cache,
+speculation and ``tp > 1`` are refused.  ``step()`` is the whole design:
 
 1. reap cancellations and blown deadlines;
 2. admit waiting requests into free decode slots (FIFO, memory-gated,
@@ -70,7 +74,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from ray_tpu._private import events as _events
-from ray_tpu.llm.cache import CacheConfig, KVBlockPool
+from ray_tpu.llm.cache import CacheConfig, KVBlockPool, StateConfig, StatePool
 from ray_tpu.llm.model_runner import (
     PATCH_JOIN,
     PATCH_SET,
@@ -376,7 +380,20 @@ class LLMEngine:
             block_size=self.cfg.block_size,
             max_blocks_per_seq=self.cfg.max_blocks_per_seq,
         )
-        if self.cfg.tp > 1:
+        if getattr(model_cfg, "cache_kind", "kv") == "state":
+            # a model without keys and values: a sequence owns one
+            # fixed-size recurrent state (cache.StatePool), admission is a
+            # free slot and the length limit is the model's positions
+            self._refuse_for_state_model()
+            from ray_tpu.llm.state_runner import StateModelRunner
+
+            self.runner = StateModelRunner(model_cfg, params)
+            cache_cfg = StateConfig(self.cfg.max_slots, model_cfg.seq_len)
+            self.pool = StatePool(
+                cache_cfg, model_cfg.n_layers, self.runner.body.state_shape,
+                dtype=self.runner.body.state_dtype,
+            )
+        elif self.cfg.tp > 1:
             # tensor-parallel substrate (llm.multichip): sharded runner +
             # head-sharded pool over the same tp mesh; everything below
             # (scheduler, prefix cache, drafter, watchdog) is mesh-blind
@@ -520,6 +537,38 @@ class LLMEngine:
             "discarded_tokens": 0,
             "uploads": {"full": 0, "none": 0, "partial": 0},
         }
+        # a state pool's own account (stats()["state_pool"]): first chunks
+        # that overwrote a slot's state, decodes launched and the live rows
+        # they were sent (each row's state is read and written once a layer)
+        self._state_n = {"overwrites": 0, "decodes": 0, "decode_rows": 0}
+
+    def _refuse_for_state_model(self) -> None:
+        """What is built on K/V blocks means nothing for a recurrent state,
+        and snapshots of states, which would stand in for it, are later
+        work: say so instead of serving wrong tokens."""
+        what = type(self.model_cfg).__name__
+        if self.cfg.prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True with {what}: the radix prefix cache shares "
+                "K/V blocks, and this model keeps no keys or values, only one "
+                "recurrent state per sequence; sharing a prefix would need "
+                "state snapshots at block boundaries (not implemented). "
+                "Pass EngineConfig(prefix_cache=False)"
+            )
+        if self.cfg.spec_k > 0:
+            raise ValueError(
+                f"spec_k={self.cfg.spec_k} with {what}: verifying k drafted "
+                "tokens advances the recurrent state k steps and a rejection "
+                "would have to roll it back, which needs a state snapshot per "
+                "window (not implemented). Pass EngineConfig(spec_k=0)"
+            )
+        if self.cfg.tp > 1:
+            raise ValueError(
+                f"tp={self.cfg.tp} with {what}: tensor parallelism here "
+                "shards K/V heads and the paged kernels' pools; the state "
+                "pool and the retention kernel have no sharded form yet. "
+                "Pass EngineConfig(tp=1)"
+            )
 
     # -- public API --------------------------------------------------------
 
@@ -880,9 +929,7 @@ class LLMEngine:
             # copied onto itself: identity, real pool contents untouched)
             with self._lock:
                 z = np.zeros(self.cfg.max_slots, np.int32)
-                self.pool.k, self.pool.v = self.runner.fork_blocks(
-                    self.pool.k, self.pool.v, z, z
-                )
+                self.pool.arrays = self.runner.fork_blocks(*self.pool.arrays, z, z)
         if self._drafter is not None:
             with self._lock:
                 self._spec_skip = 1 << 30  # force the plain-decode path
@@ -891,8 +938,8 @@ class LLMEngine:
                 self._spec_skip = 0
                 self._spec_backoff = 0
                 S, W = self.cfg.max_slots, self.cfg.spec_k + 1
-                k, v, _, _, _ = self.runner.verify_step(
-                    self.pool.k, self.pool.v,
+                *self.pool.arrays, _, _, _ = self.runner.verify_step(
+                    *self.pool.arrays,
                     np.zeros((S, W), np.int32),
                     np.zeros(S, np.int32),
                     np.zeros((S, self.pool.cfg.max_blocks_per_seq), np.int32),
@@ -902,7 +949,6 @@ class LLMEngine:
                     np.zeros(S, np.uint32),
                     np.zeros(S, np.int32),
                 )
-                self.pool.k, self.pool.v = k, v
 
     def stats(self) -> dict:
         with self._lock:
@@ -962,6 +1008,12 @@ class LLMEngine:
             }
             if self.prefix_cache is not None:
                 s["prefix_cache"] = self.prefix_cache.stats()
+            if not self.pool.paged:
+                s["state_pool"] = dict(
+                    self._state_n, slots=self.pool.cfg.slots,
+                    live=led["seq_bytes"] // led["block_bytes"],
+                    bytes=led["pool_bytes"],
+                )
             s["hbm"] = led
             s["retraces"] = self.runner.prof.retraces
             s["tp"] = self.cfg.tp
@@ -993,9 +1045,10 @@ class LLMEngine:
             rep["hbm"] = self.hbm_ledger()
         rep["attention"] = {
             "configured": self.cfg.attn_impl,
+            # the paged kernels' dispatch rule; a state model has none
             "auto_rule": auto_impl(
                 self.pool.cfg.block_size, self.model_cfg.head_dim
-            ),
+            ) if self.pool.paged else None,
             # lowering takes seconds at full depth: outside the lock
             "mosaic_kernels": self.runner.kernels_in_steps(),
         }
@@ -1149,9 +1202,7 @@ class LLMEngine:
             dst = np.zeros(F, np.int32)
             for j, (s, d, _rid) in enumerate(batch):
                 src[j], dst[j] = s, d
-            self.pool.k, self.pool.v = self.runner.fork_blocks(
-                self.pool.k, self.pool.v, src, dst
-            )
+            self.pool.arrays = self.runner.fork_blocks(*self.pool.arrays, src, dst)
         now = time.time()
         for _s, _d, rid in pend:
             req = self._requests.get(rid)
@@ -1187,14 +1238,16 @@ class LLMEngine:
                 p.seed,
             )
         with self._phase("prefill_launch"):
-            k, v, _logits, tok, logp = self.runner.prefill_chunk(
-                self.pool.k, self.pool.v, tokens, req.prefill_pos, n_valid, table,
+            *arrays, _logits, tok, logp = self.runner.prefill_chunk(
+                *self.pool.arrays, tokens, req.prefill_pos, n_valid, table,
                 sampling,
             )
         # the chunk is in flight; what follows is the host's book-keeping
         # for it, billed to prefill_build like the work before the launch
         with self._phase("prefill_build"):
-            self.pool.k, self.pool.v = k, v
+            self.pool.arrays = arrays
+            if req.prefill_pos == 0:
+                self._state_n["overwrites"] += 1  # read by a state pool only
             req.prefill_pos += n_valid
             self._prefill_tokens += n_valid
             if req.phase_led is not None:
@@ -1374,11 +1427,12 @@ class LLMEngine:
             return None
         rows, first_tok, patch = built
         with self._phase("decode_launch"):
-            k, v, self._carry, nxt, logp = self.runner.decode_step(
-                self.pool.k, self.pool.v, self._carry, first_tok, patch,
+            *self.pool.arrays, self._carry, nxt, logp = self.runner.decode_step(
+                *self.pool.arrays, self._carry, first_tok, patch,
                 self._tables[1], self._knobs[1],
             )
-            self.pool.k, self.pool.v = k, v
+            self._state_n["decodes"] += 1
+            self._state_n["decode_rows"] += len(rows)
             return _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
 
     def _build_decode(self, rows: list) -> Optional[tuple]:
@@ -1529,11 +1583,10 @@ class LLMEngine:
             self._note_sampler(temp)
             self._slot_ids = None  # the window moves rows past the carry
         with self._phase("decode_launch", "verify_launch"):
-            k, v, n_acc, out, out_lp = self.runner.verify_step(
-                self.pool.k, self.pool.v, tokens, base_pos, tables,
+            *self.pool.arrays, n_acc, out, out_lp = self.runner.verify_step(
+                *self.pool.arrays, tokens, base_pos, tables,
                 temp, top_k, top_p, seeds, counters,
             )
-            self.pool.k, self.pool.v = k, v
         with self._phase("decode_fetch", "verify_fetch"):
             n_acc, out, out_lp = jax.device_get((n_acc, out, out_lp))  # ONE host sync
         with self._phase("emit"):

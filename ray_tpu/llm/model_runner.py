@@ -158,12 +158,32 @@ def _scatter_kv(pool: jax.Array, vals: jax.Array, phys: jax.Array, off: jax.Arra
         ].set(vals)
 
 
+def _carry_loop(blocks, x, pools: tuple, layer_fn):
+    """Scan over the stacked layer weights with ``pools`` as CARRY, each
+    whole, so that a layer writes into the buffer the caller donated and
+    reads that same buffer.  Each pool is ``(L, N, ...)`` and rides as the
+    free ``(L * N, ...)`` view, in which layer ``l``'s entry ``n`` is ``l *
+    N + n``: ``layer_fn(x, layer, *views, base) -> (x, *views)`` gets the
+    views and ``base = l * N``.  Returns (x, *pools), pools in their own
+    shape."""
+    n_layers, n = pools[0].shape[:2]
+
+    def body(carry, inputs):
+        layer, base = inputs
+        return layer_fn(carry[0], layer, *carry[1:], base), None
+
+    (x, *views), _ = jax.lax.scan(
+        body,
+        (x, *(p.reshape((n_layers * n,) + p.shape[2:]) for p in pools)),
+        (blocks, jnp.arange(n_layers, dtype=jnp.int32) * n),
+    )
+    return (x, *(v.reshape(p.shape) for v, p in zip(views, pools)))
+
+
 def _layer_loop(blocks, x, k_pool, v_pool, layer_fn):
-    """THE layer loop of every jitted step (decode, verify, prefill; the
-    three tensor-parallel shard bodies): scan over the stacked layer
-    weights with the pools as CARRY, whole, so each layer scatters its
-    rows into the buffer the caller donated and attention reads that same
-    buffer.  A pool that rides a scan as ``xs``/``ys`` is sliced per
+    """THE layer loop of every jitted step over K/V pools (decode, verify,
+    prefill; the three tensor-parallel shard bodies): ``_carry_loop`` with
+    the two pools.  A pool that rides a scan as ``xs``/``ys`` is sliced per
     layer and stacked again: six pool-sized copies a step on a v5e
     (PERF.md, PR 24).
 
@@ -174,20 +194,7 @@ def _layer_loop(blocks, x, k_pool, v_pool, layer_fn):
     it writes (``_scatter_kv``) or reads (the block tables handed to
     ``ops.paged_attention``).  Returns (x, k_pool, v_pool), pools in
     their own shape."""
-    n_layers, nb = k_pool.shape[:2]
-    view = (n_layers * nb,) + k_pool.shape[2:]
-
-    def body(carry, inputs):
-        x, k, v = carry
-        layer, base = inputs
-        return layer_fn(x, layer, k, v, base), None
-
-    (x, k, v), _ = jax.lax.scan(
-        body,
-        (x, k_pool.reshape(view), v_pool.reshape(view)),
-        (blocks, jnp.arange(n_layers, dtype=jnp.int32) * nb),
-    )
-    return x, k.reshape(k_pool.shape), v.reshape(v_pool.shape)
+    return _carry_loop(blocks, x, (k_pool, v_pool), layer_fn)
 
 
 def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
@@ -286,39 +293,17 @@ def _prefill_sample(logits, sampling):
     )
 
 
-class PagedModelRunner:
-    """Owns the jitted step functions for one (config, params) pair."""
+class StepRunner:
+    """What every runner of jitted steps shares, whatever its sequences
+    hold on the device: the compile marker, the first-call record that
+    ``kernels_in_steps`` lowers again, the retrace detector, placement."""
 
-    def __init__(self, cfg: Any, params: dict, block_size: int, attn_impl: str = "auto"):
-        if isinstance(cfg, GPTJConfig):
-            self.arch = "gptj"
-        elif isinstance(cfg, GPTConfig):
-            if cfg.n_experts > 0:
-                raise NotImplementedError("paged decode supports dense GPT only")
-            self.arch = "gpt"
-        else:
-            raise TypeError(f"unsupported model config {type(cfg).__name__}")
+    arch = "?"
+
+    def __init__(self, cfg: Any, params: dict):
         ensure_compile_cache()
         self.cfg = cfg
         self.params = params
-        self.block_size = block_size
-        self.attn_impl = attn_impl
-        # heads THIS runner's traced bodies see: all of them single-chip;
-        # the tensor-parallel subclass (llm.multichip) narrows this to its
-        # per-device head group and reuses _qkv_rows unchanged
-        self.n_local_heads = cfg.n_heads
-        # donate the pool buffers: a step writes its rows into the buffers
-        # it was given and hands the same buffers back.  Donation alone did
-        # not make that so: it takes the pool as the layer loop's CARRY
-        # (_layer_loop); as the scan's xs/ys the pool was copied six times a
-        # step, more than the step's math.  tests/test_llm_pool_inplace.py
-        # holds every step to it through the compiled program's temp size
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2, 3))
-        self._prefill = jax.jit(
-            self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
-        )
-        self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
-        self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
         self._compiled: set = set()  # (fn, shape-key)s already traced
         #: site -> (jitted fn, abstract operands, static kwargs) of its
         #: first call: what kernels_in_steps lowers again, so the report
@@ -383,6 +368,45 @@ class PagedModelRunner:
             site: mosaic_kernels(fn.lower(*args, **static))
             for site, (fn, args, static) in self._first_operands.items()
         }
+
+    def place(self, x):
+        """A small host operand onto the device the steps run on, to stay
+        there over many steps (replicated under ``tp``): uncommitted, like
+        the pool and the weights, so a step's cache key never changes."""
+        return jax.device_put(x)
+
+
+class PagedModelRunner(StepRunner):
+    """Owns the jitted step functions for one (config, params) pair."""
+
+    def __init__(self, cfg: Any, params: dict, block_size: int, attn_impl: str = "auto"):
+        if isinstance(cfg, GPTJConfig):
+            self.arch = "gptj"
+        elif isinstance(cfg, GPTConfig):
+            if cfg.n_experts > 0:
+                raise NotImplementedError("paged decode supports dense GPT only")
+            self.arch = "gpt"
+        else:
+            raise TypeError(f"unsupported model config {type(cfg).__name__}")
+        super().__init__(cfg, params)
+        self.block_size = block_size
+        self.attn_impl = attn_impl
+        # heads THIS runner's traced bodies see: all of them single-chip;
+        # the tensor-parallel subclass (llm.multichip) narrows this to its
+        # per-device head group and reuses _qkv_rows unchanged
+        self.n_local_heads = cfg.n_heads
+        # donate the pool buffers: a step writes its rows into the buffers
+        # it was given and hands the same buffers back.  Donation alone did
+        # not make that so: it takes the pool as the layer loop's CARRY
+        # (_layer_loop); as the scan's xs/ys the pool was copied six times a
+        # step, more than the step's math.  tests/test_llm_pool_inplace.py
+        # holds every step to it through the compiled program's temp size
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2, 3))
+        self._prefill = jax.jit(
+            self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
+        )
+        self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
+        self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
 
     # -- shared layer math -------------------------------------------------
 
@@ -520,12 +544,6 @@ class PagedModelRunner:
         return (
             k_pool, v_pool, _advance_slots(live, nxt, positions, counters), nxt, logp
         )
-
-    def place(self, x):
-        """A small host operand onto the device the steps run on, to stay
-        there over many steps (replicated under ``tp``): uncommitted, like
-        the pool and the weights, so a step's cache key never changes."""
-        return jax.device_put(x)
 
     def decode_step(self, k_pool, v_pool, carry, first_tok, patch, tables, knobs):
         return self._call(

@@ -77,8 +77,17 @@ def _build_model(model: str, model_cfg, params, seed: int, tp: int = 1):
         if not isinstance(cfg, GPTConfig):
             raise TypeError(f"model_cfg must be a GPTConfig, got {type(cfg).__name__}")
         init = gpt_init
+    elif model == "brumby":
+        from ray_tpu.models.brumby import BrumbyConfig, brumby_init
+
+        cfg = model_cfg or BrumbyConfig()
+        if not isinstance(cfg, BrumbyConfig):
+            raise TypeError(f"model_cfg must be a BrumbyConfig, got {type(cfg).__name__}")
+        init = brumby_init
     else:
-        raise ValueError(f"unknown model family {model!r}; expected 'gptj' or 'gpt'")
+        raise ValueError(
+            f"unknown model family {model!r}; expected 'gptj', 'gpt' or 'brumby'"
+        )
     if params is None:
         params = _seeded_params(init, cfg, seed, tp)
     return cfg, params
