@@ -937,14 +937,17 @@ class Head:
         while not self._shutdown:
             try:
                 conn = listener.accept()
-            except (OSError, EOFError):
-                return
             except Exception as e:
-                # A client that died mid-handshake (AuthenticationError) or
+                if self._shutdown:
+                    return  # the listener was closed under the accept
+                # A client that died mid-handshake (EOFError or a reset when
+                # it was killed while it connected, AuthenticationError) or
                 # sent garbage must not kill the accept loop — that would
                 # silently stop ALL future worker registration. Drop the
-                # connection and keep accepting.
+                # connection and keep accepting; the pause keeps a lasting
+                # accept error (EMFILE) from spinning.
                 warn_throttled("head accept loop", e)
+                time.sleep(0.05)
                 continue
             t = threading.Thread(
                 target=self._serve_conn, args=(conn, remote), daemon=True

@@ -97,3 +97,36 @@ def test_data_pipeline_under_kills(chaos_cluster):
         total = sum(r["v"] for r in ds.take_all())
     assert total == 3 * sum(range(400))
     assert killer.kills
+
+
+@pytest.mark.parametrize("dies", ["connected", "challenged"])
+def test_a_client_dead_mid_handshake_does_not_end_registration(chaos_cluster, dies):
+    """A worker killed while it connects (the killer above does it, and so
+    does the registration timeout on a starved box) leaves the listener an
+    EOF or a reset inside ``accept()``'s handshake. The accept loop must
+    drop that one connection: were it to end, no later worker could ever
+    register and every wait on one would last until its timeout."""
+    import socket
+
+    from ray_tpu._private.runtime import get_ctx
+
+    head = get_ctx().head
+    for _ in range(3):
+        s = socket.socket(socket.AF_UNIX)
+        s.settimeout(10)  # a listener that has stopped sends no challenge
+        s.connect(head.socket_path)
+        if dies == "challenged":
+            s.recv(64)  # the listener's challenge is out, its answer never comes
+        s.close()
+        time.sleep(0.2)  # the listener has met this one before the next comes
+
+    @ray_tpu.remote(num_cpus=0)
+    class Fresh:  # an actor is a worker process of its own: it has to register
+        def pid(self):
+            import os
+
+            return os.getpid()
+
+    fresh = [Fresh.remote() for _ in range(3)]
+    pids = ray_tpu.get([a.pid.remote() for a in fresh], timeout=60)
+    assert len(set(pids)) == 3

@@ -19,42 +19,13 @@ import time
 import pytest
 
 import ray_tpu
-
-HEAD_SCRIPT = (
-    "import ray_tpu, time;"
-    "info = ray_tpu.init(num_cpus=2);"
-    "from ray_tpu._private.runtime import get_ctx;"
-    "head = get_ctx().head;"
-    "h, p = head.listen_tcp('127.0.0.1', 0);"
-    "print(f'ADDR {h}:{p}', flush=True);"
-    "time.sleep(120)"
-)
+from conftest import announced_child, tcp_head_child
 
 
 @pytest.fixture
 def tcp_head():
-    key = os.urandom(16).hex()
-    env = dict(
-        os.environ,
-        RAY_TPU_AUTHKEY=key,
-        RAY_TPU_CLIENT_RECONNECT_GRACE_S="2",
-        RAY_TPU_HEALTH_CHECK_INTERVAL_S="0.2",
-    )
-    proc = subprocess.Popen(
-        [sys.executable, "-c", HEAD_SCRIPT], stdout=subprocess.PIPE, text=True, env=env
-    )
-    os.environ["RAY_TPU_AUTHKEY"] = key
-    line = proc.stdout.readline()
-    assert line.startswith("ADDR"), line
-    addr = line.split()[1]
-    try:
+    with tcp_head_child(reconnect_grace_s=2) as addr:
         yield addr
-    finally:
-        os.environ.pop("RAY_TPU_AUTHKEY", None)
-        if ray_tpu.is_initialized():
-            ray_tpu.shutdown()
-        proc.terminate()
-        proc.wait(timeout=10)
 
 
 CLIENT_A = """
@@ -81,15 +52,10 @@ ray_tpu.shutdown()
 def test_two_clients_namespaces_isolated(tcp_head):
     """Client B must not see client A's named actor (each anonymous
     session gets its own namespace), while both share the cluster."""
-    a = subprocess.Popen(
-        [sys.executable, "-c", CLIENT_A.format(addr=tcp_head)],
-        stdout=subprocess.PIPE,
-        stdin=subprocess.PIPE,
-        text=True,
-        env=dict(os.environ),
-    )
-    try:
-        assert a.stdout.readline().strip() == "A-READY"
+    # A stays (reading its stdin) until the block ends it
+    with announced_child(
+        [sys.executable, "-c", CLIENT_A.format(addr=tcp_head)], "A-READY", env=dict(os.environ), stdin=subprocess.PIPE
+    ):
         ray_tpu.init(address=f"ray://{tcp_head}")
         try:
             with pytest.raises(ValueError):
@@ -116,13 +82,6 @@ def test_two_clients_namespaces_isolated(tcp_head):
             )
         finally:
             ray_tpu.shutdown()
-    finally:
-        try:
-            a.stdin.write("exit\n")
-            a.stdin.flush()
-        except OSError:
-            pass
-        a.wait(timeout=15)
 
 
 def test_explicit_shared_namespace(tcp_head):

@@ -19,15 +19,13 @@
   a window the head DID process would double-submit its tasks).
 """
 
-import os
-import subprocess
-import sys
 import threading
 import time
 
 import pytest
 
 import ray_tpu
+from conftest import tcp_head_child
 from ray_tpu import exceptions as rex
 from ray_tpu.util import metrics as um
 from ray_tpu.util import tracing
@@ -319,41 +317,11 @@ class TestBatchTelemetry:
 # chaos: head socket death mid-burst
 # ---------------------------------------------------------------------------
 
-HEAD_SCRIPT = (
-    "import ray_tpu, time;"
-    "info = ray_tpu.init(num_cpus=2);"
-    "from ray_tpu._private.runtime import get_ctx;"
-    "head = get_ctx().head;"
-    "h, p = head.listen_tcp('127.0.0.1', 0);"
-    "print(f'ADDR {h}:{p}', flush=True);"
-    "time.sleep(180)"
-)
-
 
 @pytest.fixture
 def tcp_head():
-    key = os.urandom(16).hex()
-    env = dict(
-        os.environ,
-        RAY_TPU_AUTHKEY=key,
-        RAY_TPU_CLIENT_RECONNECT_GRACE_S="5",
-        RAY_TPU_HEALTH_CHECK_INTERVAL_S="0.2",
-    )
-    proc = subprocess.Popen(
-        [sys.executable, "-c", HEAD_SCRIPT], stdout=subprocess.PIPE, text=True, env=env
-    )
-    os.environ["RAY_TPU_AUTHKEY"] = key
-    line = proc.stdout.readline()
-    assert line.startswith("ADDR"), line
-    addr = line.split()[1]
-    try:
+    with tcp_head_child(reconnect_grace_s=5) as addr:
         yield addr
-    finally:
-        os.environ.pop("RAY_TPU_AUTHKEY", None)
-        if ray_tpu.is_initialized():
-            ray_tpu.shutdown()
-        proc.terminate()
-        proc.wait(timeout=10)
 
 
 class TestChaosMidBurst:

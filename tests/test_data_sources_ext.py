@@ -81,31 +81,10 @@ class TestGatedSources:
 class TestRayClientScheme:
     def test_ray_scheme_attaches_over_tcp(self):
         """ray://host:port behaves as client mode against a live head."""
-        import os
-        import subprocess
-        import sys
+        from conftest import tcp_head_child
 
-        # both sides must share the cluster secret (resolve_authkey)
-        key = os.urandom(16).hex()
-        env = dict(os.environ, RAY_TPU_AUTHKEY=key)
         # head in a separate process serving TCP
-        script = (
-            "import ray_tpu, time;"
-            "info = ray_tpu.init(num_cpus=2);"
-            "from ray_tpu._private.runtime import get_ctx;"
-            "head = get_ctx().head;"
-            "h, p = head.listen_tcp('127.0.0.1', 0);"
-            "print(f'ADDR {h}:{p}', flush=True);"
-            "time.sleep(60)"
-        )
-        proc = subprocess.Popen(
-            [sys.executable, "-c", script], stdout=subprocess.PIPE, text=True, env=env
-        )
-        os.environ["RAY_TPU_AUTHKEY"] = key
-        try:
-            line = proc.stdout.readline()
-            assert line.startswith("ADDR"), line
-            addr = line.split()[1]
+        with tcp_head_child() as addr:
             ray_tpu.init(address=f"ray://{addr}")
             try:
 
@@ -116,10 +95,6 @@ class TestRayClientScheme:
                 assert ray_tpu.get(f.remote(6), timeout=60) == 42
             finally:
                 ray_tpu.shutdown()
-        finally:
-            os.environ.pop("RAY_TPU_AUTHKEY", None)
-            proc.terminate()
-            proc.wait(timeout=10)
 
 
 # ---------------------------------------------------------------------------

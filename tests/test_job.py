@@ -9,6 +9,7 @@ import time
 import pytest
 
 import ray_tpu
+from conftest import REPO_ROOT
 from ray_tpu import job
 
 
@@ -66,7 +67,7 @@ def test_entrypoint_attaches_to_cluster(ray_start_regular):
     path = tempfile.mktemp(suffix=".py")
     with open(path, "w") as f:
         f.write(script)
-    env_path = "/root/repo" + os.pathsep + os.environ.get("PYTHONPATH", "")
+    env_path = REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")
     jid = job.submit_job(
         f"{sys.executable} {path}", env_vars={"PYTHONPATH": env_path}
     )

@@ -23,6 +23,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import join_all
 from ray_tpu.llm import CacheConfig, EngineConfig, KVBlockPool, LLMEngine, SamplingParams
 from ray_tpu.models.gptj import GPTJConfig, gptj_decode, gptj_init
 
@@ -503,8 +504,7 @@ def test_batch_queue_exports_saturation_metrics(serve_instance):
     ]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads)
     assert sorted(results) == [0, 2, 4, 6]
     q = getattr(m, "__serve_batch_queues_predict")[""]
     assert isinstance(q, _BatchQueue)

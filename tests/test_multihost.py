@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from conftest import REPO_ROOT
 from ray_tpu._private.config import resolve_authkey
 from ray_tpu._private.head import Head
 from ray_tpu._private.node_agent import NodeAgent
@@ -143,7 +144,7 @@ def test_train_spreads_across_hosts(tcp_cluster):
     assert len(nodes) == 2, f"train workers were not spread across hosts: {nodes}"
 
 
-CLI_ENV = dict(os.environ, PYTHONPATH="/root/repo" + os.pathsep + os.environ.get("PYTHONPATH", ""))
+CLI_ENV = dict(os.environ, PYTHONPATH=REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""))
 
 
 def test_cli_head_node_driver_roundtrip(tmp_path):
@@ -159,7 +160,7 @@ def test_cli_head_node_driver_roundtrip(tmp_path):
     BENCH_r06-r08), the interpreter-boot timing would measure the
     NEIGHBORS, not the control plane — skip with the measurement cited.
     An unloaded box still gates at full strength."""
-    from conftest import SPIN_CANARY_FLOOR_MOPS, spin_mops
+    from conftest import SPIN_CANARY_FLOOR_MOPS, announced_child, spin_mops
 
     canary = spin_mops()
     if canary < SPIN_CANARY_FLOOR_MOPS:
@@ -168,57 +169,35 @@ def test_cli_head_node_driver_roundtrip(tmp_path):
             "cold-interpreter boots under 60s/120s budgets measure the "
             "ambient load, not the CLI control plane"
         )
-    head_proc = subprocess.Popen(
-        [sys.executable, "-m", "ray_tpu", "start", "--head", "--port", "0", "--num-cpus", "0"],
-        stdout=subprocess.PIPE,
-        text=True,
-        env=CLI_ENV,
-    )
-    node_proc = None
-    try:
-        line = head_proc.stdout.readline()
-        assert "listening on" in line, line
+    cli = [sys.executable, "-m", "ray_tpu"]
+    with announced_child(
+        cli + ["start", "--head", "--port", "0", "--num-cpus", "0"], "listening on", env=CLI_ENV
+    ) as (_, line):
         address = line.strip().rsplit(" ", 1)[-1]
-        node_proc = subprocess.Popen(
-            [sys.executable, "-m", "ray_tpu", "start", "--address", address,
-             "--num-cpus", "2"],
-            stdout=subprocess.PIPE,
-            text=True,
-            env=CLI_ENV,
-        )
-        assert "joined" in node_proc.stdout.readline()
+        with announced_child(cli + ["start", "--address", address, "--num-cpus", "2"], "joined", env=CLI_ENV):
+            ray_tpu.init(address=address)
 
-        ray_tpu.init(address=address)
+            @ray_tpu.remote
+            def f(x):
+                return x + 1
 
-        @ray_tpu.remote
-        def f(x):
-            return x + 1
+            assert ray_tpu.get([f.remote(i) for i in range(4)], timeout=60) == [1, 2, 3, 4]
+            ray_tpu.shutdown()
 
-        assert ray_tpu.get([f.remote(i) for i in range(4)], timeout=60) == [1, 2, 3, 4]
-        ray_tpu.shutdown()
-
-        out = subprocess.run(
-            [sys.executable, "-m", "ray_tpu", "summary", "--address", address],
-            capture_output=True,
-            text=True,
-            timeout=120,
-            env=CLI_ENV,
-        )
-        assert out.returncode == 0, out.stderr
-        # stray runtime prints (warnings may even CONTAIN braces) can
-        # precede the document: the JSON starts at the first bare '{' line
-        lines = out.stdout.splitlines()
-        summ = json.loads("\n".join(lines[lines.index("{"):]))
-        assert summ["tasks"]["by_state"].get("FINISHED", 0) >= 4
-        assert len(summ["nodes"]) == 2
-    finally:
-        for p in (node_proc, head_proc):
-            if p is not None:
-                p.terminate()
-                try:
-                    p.wait(timeout=10)
-                except Exception:
-                    p.kill()
+            out = subprocess.run(
+                cli + ["summary", "--address", address],
+                capture_output=True,
+                text=True,
+                timeout=120,
+                env=CLI_ENV,
+            )
+            assert out.returncode == 0, out.stderr
+            # stray runtime prints (warnings may even CONTAIN braces) can
+            # precede the document: the JSON starts at the first bare '{' line
+            lines = out.stdout.splitlines()
+            summ = json.loads("\n".join(lines[lines.index("{"):]))
+            assert summ["tasks"]["by_state"].get("FINISHED", 0) >= 4
+            assert len(summ["nodes"]) == 2
 
 
 def test_system_config_ships_to_agents(monkeypatch):

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from conftest import join_all
 from ray_tpu import serve
 
 
@@ -76,8 +77,7 @@ def test_replicas_share_load(serve_instance):
         threads = [threading.Thread(target=call, args=(i,)) for i in range(20)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
+        join_all(threads)
     assert len(seen) == 2, f"expected 2 replica pids, saw {seen}"
 
 
@@ -128,8 +128,7 @@ def test_batching_coalesces(serve_instance):
     threads = [threading.Thread(target=call, args=(i,)) for i in range(16)]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads)
     assert sorted(outs) == [(i, i + 1) for i in range(16)]
     sizes = h.sizes.remote().result(timeout=30)
     assert max(sizes) > 1, f"batching never coalesced: {sizes}"
@@ -184,8 +183,7 @@ def test_autoscaling_up_and_down(serve_instance):
             scaled_up = True
             break
         time.sleep(0.2)
-    for t in threads:
-        t.join()
+    join_all(threads)
     assert scaled_up, "never scaled above 1 replica under load"
     # idle: must come back down to min_replicas
     deadline = time.time() + 15
@@ -231,8 +229,7 @@ def test_http_ingress(serve_instance):
     threads = [threading.Thread(target=worker, args=(i,)) for i in range(100)]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads)
     assert sorted(results) == list(range(100))
 
 
@@ -297,8 +294,7 @@ def test_serve_jax_model(serve_instance):
     ]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads)
     assert all(r in (0, 1) for r in results)
 
 

@@ -9,6 +9,7 @@ import time
 import pytest
 
 import ray_tpu
+from conftest import join_all
 from ray_tpu import workflow
 
 
@@ -78,7 +79,7 @@ def test_wait_for_event_delivery(ray_start_regular, tmp_path):
     t = threading.Thread(target=deliver)
     t.start()
     out = workflow.run(dag, workflow_id="evt1", storage=str(tmp_path))
-    t.join()
+    join_all([t])
     assert out == 42
     # delivered payload is durable: a resume never waits again
     assert workflow.resume("evt1", storage=str(tmp_path)) == 42
