@@ -2549,6 +2549,11 @@ class Head:
             if ent is not None:
                 ent.refcount += 1  # held by the stream until handed out/disposed
             st["items"][payload["index"]] = payload["obj_id"]
+            # the `head_hold` leg starts (rpc_stream_next ends it); the
+            # producer is remembered for the ack of an item handed out
+            # after its task is done and gone from self.tasks
+            st.setdefault("t_in", {})[payload["index"]] = time.perf_counter()
+            st["wh"] = wh
             self._wake_stream(st)
             self.cv.notify_all()  # the object's readiness, as every store
 
@@ -2559,11 +2564,15 @@ class Head:
         if cond is not None:
             cond.notify_all()
 
-    def rpc_stream_next(self, task_id, index, timeout=None):
+    def rpc_stream_next(self, task_id, index, timeout=None, delivered=None):
         """Blocking: ('item', obj_id) when the index exists; ('end', count)
         past the final item; ('error', completion_obj_id) when the task
         failed (the completion object holds the exception). Acks the
-        consumed index to the producing worker for backpressure."""
+        consumed index to the producing worker for backpressure; the ack
+        carries ``hold_s`` (how long the item lay here) and passes on
+        ``delivered``, the consumer's own report of the gaps between the
+        items it has written out since it last asked (the producer's
+        ``head_hold`` and ``written`` readings, ``_private.stream_stats``)."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self.lock:
             while True:
@@ -2572,10 +2581,12 @@ class Head:
                 st = self.streams.get(task_id)
                 if st is not None:
                     if index in st["items"]:
-                        oid = st["items"][index]
+                        out = ("item", st["items"][index])
                         st["next"] = max(st["next"], index + 1)
-                        rec = self.tasks.get(task_id)
-                        wh = rec.get("worker") if rec is not None else None
+                        ack = {"task_id": task_id, "consumed": index + 1}
+                        t_in = st.get("t_in", {}).pop(index, None)
+                        if t_in is not None:
+                            ack["hold_s"] = time.perf_counter() - t_in
                         break
                     if st["count"] is not None and index >= st["count"]:
                         comp = st.get("completion")
@@ -2583,7 +2594,10 @@ class Head:
                             ent = self.objects.get(comp)
                             if ent is not None and ent.is_error:
                                 return ("error", comp)
-                        return ("end", st["count"])
+                        out = ("end", st["count"])
+                        # the last write gaps ride an ack of their own
+                        ack = {"task_id": task_id, "consumed": st["count"]} if delivered else None
+                        break
                 remaining = None if deadline is None else deadline - time.monotonic()
                 if remaining is not None and remaining <= 0:
                     raise rex.GetTimeoutError(f"stream_next timed out on {TaskID(task_id)}")
@@ -2598,9 +2612,12 @@ class Head:
                     cond = st["cond"] = threading.Condition(self.lock)
                 # the timeout bounds what no wake-up reaches: shutdown
                 cond.wait(timeout=min(remaining, 1.0) if remaining else 1.0)
-        if wh is not None and wh.alive:
-            wh.send(("stream_ack", {"task_id": task_id, "consumed": index + 1}))
-        return ("item", oid)
+        wh = st.get("wh")  # the producer, as _on_stream_item saw it
+        if ack is not None and wh is not None and wh.alive:
+            if delivered:
+                ack["delivered"] = delivered
+            wh.send(("stream_ack", ack))
+        return out
 
     def rpc_stream_dispose(self, task_id):
         """Consumer dropped its generator: cancel the producer if it is

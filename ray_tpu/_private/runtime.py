@@ -287,6 +287,7 @@ class ObjectRefGenerator:
         self._i = 0
         self._done = False
         self._disposed = False
+        self._delivered: list = []  # report_delivered's, until the next ask
 
     def __iter__(self):
         return self
@@ -294,12 +295,23 @@ class ObjectRefGenerator:
     def __next__(self) -> "ObjectRef":
         return self._next(timeout=None)
 
+    def report_delivered(self, gaps) -> None:
+        """A consumer that passes items on (the HTTP proxy) says what its
+        own clock read between the items it has written out since it last
+        reported: seconds, one gap an item from the stream's second on.
+        They ride the next ask for an item to the head and the ack to the
+        producing worker, which observes them as the ``written`` station
+        (``_private.stream_stats``); a consumer that never reports leaves
+        that station empty."""
+        self._delivered.extend(gaps)
+
     def _next(self, timeout: Optional[float]) -> "ObjectRef":
         if self._done or self._disposed:
             raise StopIteration
-        kind, payload = self._ctx.call(
-            "stream_next", task_id=self._task_id, index=self._i, timeout=timeout
-        )
+        ask = {"task_id": self._task_id, "index": self._i, "timeout": timeout}
+        if self._delivered:
+            ask["delivered"], self._delivered = self._delivered, []
+        kind, payload = self._ctx.call("stream_next", **ask)
         if kind == "end":
             self._done = True
             raise StopIteration

@@ -1,0 +1,105 @@
+"""The streamed token's stations (OBSERVABILITY.md, "The streamed token's
+stations"): the gap between two items of one stream, measured where the
+item passes — never a clock compared across processes.
+
+A delay that is the same for token n-1 and token n adds nothing to the
+gap a client sees; only a delay that DIFFERS does.  So each station keeps,
+per stream, when the previous item passed it (one ``perf_counter`` float of
+its own process) and observes the gap; where the gap's 95th percentile
+grows from one station to the next is where the tail is made.
+
+* ``emit`` — the engine's loop thread at ``req.stream.put``: this is
+  ``llm_inter_token_latency_s`` (``llm.engine``), on the same boundaries.
+* ``sent`` — the producing worker's handler thread, ``send_raw`` of the
+  ``stream_item`` returned (``worker_main._stream_results_inner``).
+* ``acked`` — the worker's recv loop, the item's ``stream_ack`` arrived:
+  the consumer took it from the head, plus the hop back.
+* ``written`` — the consumer's own report (``ObjectRefGenerator
+  .report_delivered``; the HTTP proxy reports the gaps between chunks
+  written and drained), carried by ``stream_next`` and the ack.
+
+Two legs are durations inside one process: ``wake`` (a token's wait in
+``req.stream`` for its handler thread, ``LLMEngine.stream_tokens``) and
+``head_hold`` (an item's stay in the head before its consumer had it,
+``hold_s`` on the ack).  All of it lands in the PRODUCING worker's
+registry, where ``snapshot()`` reads it for ``LLMDeployment.stats()``.
+"""
+
+from __future__ import annotations
+
+import threading
+
+from ray_tpu.util.metrics import FINE_LATENCY_BOUNDS_S
+
+#: raylint RL012 registry
+METRIC_NAMES = (
+    "core_stream_gap_s",
+    "core_stream_leg_s",
+    "core_stream_backpressure_waits",
+    "core_stream_backpressure_wait_s",
+)
+
+STATIONS = ("sent", "acked", "written")
+LEGS = ("wake", "head_hold")
+
+
+class _Stations:
+    """The process's station series, each bound to its tag set once."""
+
+    __slots__ = STATIONS + LEGS + ("waits", "wait_s")
+
+    def __init__(self):
+        from ray_tpu.util.metrics import Counter, Histogram
+
+        gap = Histogram(
+            "core_stream_gap_s",
+            "gap between consecutive items of one stream, by the station "
+            "they passed (first item excluded)",
+            boundaries=FINE_LATENCY_BOUNDS_S, tag_keys=("station",),
+        )
+        leg = Histogram(
+            "core_stream_leg_s",
+            "time an item spent on one leg of the streaming path",
+            boundaries=FINE_LATENCY_BOUNDS_S, tag_keys=("leg",),
+        )
+        for s in STATIONS:
+            setattr(self, s, gap.bind({"station": s}))
+        for name in LEGS:
+            setattr(self, name, leg.bind({"leg": name}))
+        self.waits = Counter(
+            "core_stream_backpressure_waits",
+            "times a stream's producer waited for its ack window",
+        )
+        self.wait_s = Counter(
+            "core_stream_backpressure_wait_s",
+            "seconds stream producers waited for their ack windows",
+        )
+
+
+_STATIONS = None
+_STATIONS_LOCK = threading.Lock()
+
+
+def stations() -> _Stations:
+    global _STATIONS
+    if _STATIONS is not None:
+        return _STATIONS  # lock-free fast path: resolved once a stream
+    with _STATIONS_LOCK:
+        if _STATIONS is None:
+            _STATIONS = _Stations()
+    return _STATIONS
+
+
+def snapshot(emit: list) -> dict:
+    """``stats()["stream"]``: this process's cumulative bucket vectors on
+    one ``bounds_s`` (``emit`` is the caller's: the engine owns it).  Reads
+    the metric registry alone — no lock of the engine or the worker."""
+    st = stations()
+    out = {"bounds_s": list(FINE_LATENCY_BOUNDS_S), "emit": emit}
+    for name in STATIONS + LEGS:
+        out[name] = getattr(st, name).buckets()
+    out["backpressure"] = {
+        "waits": int(st.waits.value()),
+        "wait_s": st.wait_s.value(),
+    }
+    return out

@@ -18,6 +18,7 @@ import ctypes
 import os
 import queue
 import threading
+import time
 import traceback
 from typing import Optional
 
@@ -54,6 +55,25 @@ LOCKFREE = (
     "WorkerState.async_tasks: atomic",
     "WorkerState.group_sems: atomic",
 )
+
+
+class _Stream:
+    """The producer's side of one streaming task (under ``stream_lock``)."""
+
+    __slots__ = ("acked", "cond", "t_ack", "rid", "count")
+
+    def __init__(self, rid: str):
+        self.acked = 0      # highest consumer-acked index + 1
+        self.cond: Optional[threading.Condition] = None  # made at the first wait
+        self.t_ack: Optional[float] = None  # perf_counter() of the last ack
+        self.rid = rid      # the request's trace id, for the spans
+        self.count: Optional[int] = None    # items sent, once the producer ended
+
+
+#: streams whose producer ended before their last acks came in are kept for
+#: those acks (the `acked` station's stamp); a consumer that walked away
+#: never sends them, so past this many the oldest is dropped
+_ENDED_STREAMS_KEPT = 1024
 
 
 class WorkerState:
@@ -97,15 +117,14 @@ class WorkerState:
         # pool thread for max_concurrency>1 actors) — cancel targets THAT
         # thread, never the dispatch loop.
         self.task_threads: dict[bytes, int] = {}
-        # streaming-generator backpressure: task_id -> highest consumer-acked
-        # index+1, fed by the head's stream_ack pushes (_recv_loop); a
-        # producer that is a window ahead waits on a condition of ITS OWN
-        # (all on stream_lock): an ack wakes the one producer it is for,
+        # streaming generators this worker produces: task_id -> _Stream
+        # (all on stream_lock), fed by the head's stream_ack pushes
+        # (_recv_loop).  A producer that is a window ahead waits on a
+        # condition of ITS OWN: an ack wakes the one producer it is for,
         # not every stream of the worker (a replica streaming 900 tokens a
         # second over 32 streams woke 28,000 threads a second that way)
-        self.stream_acked: dict[bytes, int] = {}
+        self.streams: dict[bytes, _Stream] = {}
         self.stream_lock = threading.Lock()
-        self.stream_conds: dict[bytes, threading.Condition] = {}
 
 
 def connect_head(address: str, authkey: bytes, retries: int = 3):
@@ -323,14 +342,7 @@ def _recv_loop(conn, ctx: WorkerContext, state: WorkerState):
         elif kind == "cancel":
             _handle_cancel(state, msg[1])
         elif kind == "stream_ack":
-            with state.stream_lock:
-                tid = msg[1]["task_id"]
-                state.stream_acked[tid] = max(
-                    state.stream_acked.get(tid, 0), msg[1]["consumed"]
-                )
-                cond = state.stream_conds.get(tid)
-                if cond is not None:
-                    cond.notify()
+            _on_stream_ack(state, msg[1])
         elif kind == "profile":
             _start_profile(ctx, msg[1])
         elif kind == "events_drain":
@@ -405,16 +417,18 @@ _prof_exit = None  # set by main() when RAY_TPU_WORKER_CPROFILE is on
 # deliberately keep startup import-light), then the per-task path pays
 # module-global loads instead of sys.modules lookups
 _renv = None
+_stream_stats = None
 _tracing = None
 _waterfall = None
 
 
 def _bind_task_mods() -> None:
-    global _renv, _tracing, _waterfall
+    global _renv, _stream_stats, _tracing, _waterfall
     from ray_tpu._private import runtime_env as renv
+    from ray_tpu._private import stream_stats
     from ray_tpu.util import tracing, waterfall
 
-    _renv, _tracing, _waterfall = renv, tracing, waterfall
+    _renv, _stream_stats, _tracing, _waterfall = renv, stream_stats, tracing, waterfall
 
 
 def _start_profile(ctx, req: dict) -> None:
@@ -657,6 +671,39 @@ def _store_results(state: WorkerState, spec: dict, value, is_error=False):
     return results
 
 
+def _on_stream_ack(state: WorkerState, ack: dict) -> None:
+    """Recv loop: the consumer took an item from the head.  Opens the
+    producer's window, and feeds three stations of the streaming path
+    (``_private.stream_stats``): the gap between this stream's acks
+    (``acked``), how long the head held the item (``hold_s`` →
+    ``head_hold``) and the write gaps the consumer reported (``delivered``
+    → ``written``)."""
+    now = time.perf_counter()
+    tid = ack["task_id"]
+    t_prev = rid = None
+    with state.stream_lock:
+        stream = state.streams.get(tid)
+        # (None: its last ack is in, or the producer failed or was cancelled)
+        if stream is not None and ack["consumed"] > stream.acked:
+            stream.acked = ack["consumed"]
+            t_prev, stream.t_ack, rid = stream.t_ack, now, stream.rid
+            if stream.cond is not None:
+                stream.cond.notify()
+            if stream.count is not None and stream.acked >= stream.count:
+                del state.streams[tid]  # ended, and this was its last ack
+    st = _stream_stats.stations()
+    if t_prev is not None:
+        st.acked.observe(now - t_prev)
+    hold_s = ack.get("hold_s")
+    if hold_s is not None:
+        st.head_hold.observe(hold_s)
+    for gap in ack.get("delivered") or ():
+        st.written.observe(gap)
+    if rid is not None:
+        with _tracing.annotate("core.stream.ack", rid=rid, i=ack["consumed"] - 1):
+            pass  # an instant on the recv thread's line
+
+
 def _stream_results(state: WorkerState, spec: dict, gen) -> None:
     """Drive a streaming-generator task (num_returns="streaming"): each
     yielded item becomes its own object, reported to the head as it is
@@ -700,6 +747,12 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
             ),
         )
         it = iter(())
+    st = _stream_stats.stations()
+    rid = _tracing.current_request_id() or task_id.hex()
+    stream = _Stream(rid)
+    with state.stream_lock:
+        state.streams[task_id] = stream
+    t_sent = None  # the `sent` station's stamp: when the item before left
     while err is None:
         if task_id in state.cancel_requested:
             err = rex.TaskCancelledError()
@@ -713,42 +766,56 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
                 spec.get("name", "task"), e
             )
             break
-        try:
-            sv = ser.serialize(item)
-        except Exception as e:  # unserializable item
-            err = rex.RayTaskError.from_exception(spec.get("name", "task"), e)
-            break
-        locator = state.ctx.store_value(sv)
-        if locator[0] == "shm":
-            events.emit(
-                "core.object.put",
-                size=locator[1].total_size,
-                node=locator[1].node,
-                seg=locator[1].name,
+        # the item's way through this thread, on the profiler's clock
+        with _tracing.annotate("core.stream.item", rid=rid, i=idx):
+            try:
+                sv = ser.serialize(item)
+            except Exception as e:  # unserializable item
+                err = rex.RayTaskError.from_exception(spec.get("name", "task"), e)
+                break
+            locator = state.ctx.store_value(sv)
+            if locator[0] == "shm":
+                events.emit(
+                    "core.object.put",
+                    size=locator[1].total_size,
+                    node=locator[1].node,
+                    seg=locator[1].name,
+                )
+            with state.stream_lock:
+                if idx - stream.acked >= cap:
+                    if stream.cond is None:
+                        stream.cond = threading.Condition(state.stream_lock)
+                    t0 = time.perf_counter()
+                    with _tracing.annotate("core.stream.backpressure", rid=rid, i=idx):
+                        while (
+                            idx - stream.acked >= cap
+                            and task_id not in state.cancel_requested
+                        ):
+                            # a missed wake-up costs this timeout
+                            stream.cond.wait(timeout=0.5)
+                    st.waits.inc()
+                    st.wait_s.inc(time.perf_counter() - t0)
+            if task_id in state.cancel_requested:
+                err = rex.TaskCancelledError()
+                break
+            oid = ObjectID.for_task_return(TaskID(task_id), 1 + idx).binary()
+            state.ctx.send_raw(
+                ("stream_item", {"task_id": task_id, "index": idx, "obj_id": oid, "locator": locator})
             )
-        with state.stream_lock:
-            if idx - state.stream_acked.get(task_id, 0) >= cap:
-                cond = state.stream_conds.get(task_id)
-                if cond is None:
-                    cond = state.stream_conds[task_id] = threading.Condition(
-                        state.stream_lock
-                    )
-                while (
-                    idx - state.stream_acked.get(task_id, 0) >= cap
-                    and task_id not in state.cancel_requested
-                ):
-                    cond.wait(timeout=0.5)
-        if task_id in state.cancel_requested:
-            err = rex.TaskCancelledError()
-            break
-        oid = ObjectID.for_task_return(TaskID(task_id), 1 + idx).binary()
-        state.ctx.send_raw(
-            ("stream_item", {"task_id": task_id, "index": idx, "obj_id": oid, "locator": locator})
-        )
+            now = time.perf_counter()
+            if t_sent is not None:
+                st.sent.observe(now - t_sent)
+            t_sent = now
         idx += 1
     with state.stream_lock:
-        state.stream_acked.pop(task_id, None)
-        state.stream_conds.pop(task_id, None)
+        if err is None and stream.acked < idx:
+            # acks still on their way: _on_stream_ack drops it at the last
+            stream.count = idx
+            if len(state.streams) > _ENDED_STREAMS_KEPT:
+                old = next((t for t, o in state.streams.items() if o.count is not None), None)
+                state.streams.pop(old, None)
+        else:
+            state.streams.pop(task_id, None)
     is_error = err is not None
     try:
         results = _store_results(state, spec, err if is_error else None, is_error)

@@ -74,6 +74,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from ray_tpu._private import events as _events
+from ray_tpu._private import stream_stats as _stream_stats
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool, StateConfig, StatePool
 from ray_tpu.llm.model_runner import (
     PATCH_JOIN,
@@ -183,6 +184,14 @@ _METRICS = None
 _METRICS_LOCK = threading.Lock()
 
 
+def stream_stats() -> dict:
+    """``LLMDeployment.stats()["stream"]``: this process's bucket vectors
+    of the streamed token's stations (``_private.stream_stats``), ``emit``
+    being ``llm_inter_token_latency_s``.  They are the metric registry's,
+    not the engine's: read WITHOUT ``LLMEngine._lock``."""
+    return _stream_stats.snapshot(emit=_metrics()["itl"].buckets())
+
+
 def _metrics() -> dict:
     """Engine metric set, created once per process (util.metrics registers
     globally; duplicates would fight in collect())."""
@@ -192,7 +201,12 @@ def _metrics() -> dict:
     with _METRICS_LOCK:
         if _METRICS is not None:
             return _METRICS
-        from ray_tpu.util.metrics import Counter, Gauge, Histogram
+        from ray_tpu.util.metrics import (
+            FINE_LATENCY_BOUNDS_S,
+            Counter,
+            Gauge,
+            Histogram,
+        )
 
         _METRICS = {
             "tokens": Counter("llm_generated_tokens", "tokens sampled by the engine"),
@@ -210,10 +224,13 @@ def _metrics() -> dict:
             "waiting": Gauge("llm_waiting_requests", "requests queued for admission"),
             "kv_util": Gauge("llm_kv_block_utilization", "fraction of KV blocks in use"),
             "ttft": Histogram("llm_time_to_first_token_s", "submit → first token"),
+            # the `emit` station of the streaming path (_private.
+            # stream_stats): a stream's token gap where the loop emits it,
+            # on the boundaries every later station shares
             "itl": Histogram(
                 "llm_inter_token_latency_s",
                 "gap between consecutive streamed tokens",
-                boundaries=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0),
+                boundaries=FINE_LATENCY_BOUNDS_S,
             ),
             # speculative decode: drafted vs accepted counters give the
             # lifetime acceptance rate; the gauges give the latest step's
@@ -850,9 +867,10 @@ class LLMEngine:
         it."""
         import queue as _q
 
+        wake = _stream_stats.stations().wake
         while True:
             try:
-                kind, val = req.stream.get(timeout=timeout)
+                item = req.stream.get(timeout=timeout)
             except _q.Empty:
                 from ray_tpu.llm.watchdog import EngineStalledError
 
@@ -871,8 +889,11 @@ class LLMEngine:
                     queue_depth=pending,
                     kv_utilization=kv,
                 ) from None
-            if kind == "token":
-                yield val
+            if item[0] == "token":
+                # the `wake` leg: how long the token lay in the queue
+                # before this handler thread ran (one process, one clock)
+                wake.observe(time.perf_counter() - item[2])
+                yield item[1]
             else:
                 return
 
@@ -1647,21 +1668,21 @@ class LLMEngine:
         """Record one sampled token: stream it, capture its behavior
         logprob, update latency metrics, finish on stop token /
         max_tokens / model-length cap."""
-        now = time.time()
+        t = time.perf_counter()  # the `emit` station's stamp, and `wake`'s start
         m = _metrics()
         if req.first_token_t is None:
-            req.first_token_t = now
+            req.first_token_t = now = time.time()
             m["ttft"].observe(now - req.arrival_t)
             _events.record(
                 "llm.first_token", request_id=req.trace_id,
                 engine_req=req.id, ttft_s=round(now - req.arrival_t, 6),
             )
         elif req.last_token_t is not None:
-            m["itl"].observe(now - req.last_token_t)
-        req.last_token_t = now
+            m["itl"].observe(t - req.last_token_t)
+        req.last_token_t = t
         req.out.append(tok)
         req.out_logprobs.append(logp)
-        req.stream.put(("token", tok))
+        req.stream.put(("token", tok, t))
         self._tokens_generated += 1
         m["tokens"].inc()
         p = req.params

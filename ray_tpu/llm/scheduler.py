@@ -134,7 +134,9 @@ class Request:
         # bumps the cache's epoch, and this request's (partly old-weight)
         # blocks then must not enter the tree (prefix_cache.insert)
         self.cache_epoch = 0
-        self.first_token_t: Optional[float] = None
+        self.first_token_t: Optional[float] = None  # time.time()
+        # perf_counter() of the last token's emit: the `emit` station's
+        # per-stream stamp (compared with nothing but itself)
         self.last_token_t: Optional[float] = None
         # phase-attribution ledger (util.phases): cursor + per-phase
         # accumulators, anchored at submit. A resumed request gets a FRESH

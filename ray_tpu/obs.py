@@ -143,6 +143,17 @@ def hist_pcts_row(p: Optional[dict]) -> str:
     return _fmt_pcts(p)
 
 
+def itl_top_row(emit: dict, gaps: dict) -> str:
+    """``obs top``'s ITL line: the token gap at ``emit``
+    (``llm_inter_token_latency_s``) and at ``written``
+    (``core_stream_gap_s{station="written"}``; ``—`` until a consumer has
+    reported: a plain handle, the gRPC proxy never do)."""
+    from ray_tpu.util.metrics import _tag_key
+
+    written = gaps.get(_tag_key({"station": "written"}))
+    return f"ITL:  {hist_pcts_row(emit)}  written: {hist_pcts_row(written)}"
+
+
 def _first_series(per_tag: dict):
     """A metric's sole (or first) tagged series — engine metrics are
     untagged, so this is the value."""
@@ -263,7 +274,10 @@ def _render_top() -> None:
         if ttft:
             lines.append(f"TTFT: {hist_pcts_row(ttft)}")
         if itl:
-            lines.append(f"ITL:  {hist_pcts_row(itl)}")
+            # what the engine made beside what a client got: the gap where
+            # the loop emits it and where the proxy has written it (the
+            # first and the last station of the streaming path)
+            lines.append(itl_top_row(itl, pcts.get("core_stream_gap_s", {})))
     else:
         lines.append("engine: (no llm_* metrics published — no LLM replica running)")
     firing = _firing_alerts()

@@ -369,11 +369,17 @@ class ProxyActor:
 
     def _run_stream(self, app: str, payload, loop, q: "asyncio.Queue",
                     cancel: threading.Event, window: threading.Semaphore,
-                    request_id: str = "", deadline_s=None, stamps=None):
+                    request_id: str = "", deadline_s=None, stamps=None,
+                    written=None):
         """Dedicated thread per stream (long-lived by nature — must not
         occupy the dispatch pool): iterates the streaming generator with a
         bounded chunk window and stops (disposing the remote stream) when
-        the client disconnects. Sentinels: ("end", None) | ("error", exc)."""
+        the client disconnects. Sentinels: ("end", None) | ("error", exc).
+
+        ``written`` is the coroutine's list of gaps between the chunks it
+        has written and drained (``_respond_stream``): before each ask for
+        an item this thread hands what is there to the generator, which
+        carries it to the producing replica (the ``written`` station)."""
 
         def post(item):
             loop.call_soon_threadsafe(q.put_nowait, item)
@@ -409,6 +415,10 @@ class ProxyActor:
                 if cancel.is_set():
                     raise _StreamCancelled
                 post(("chunk", data))
+                if written:
+                    n = len(written)  # the coroutine may append meanwhile
+                    gen.report_delivered(written[:n])
+                    del written[:n]
             if stamps is not None:
                 # done-sentinel receipt ≈ engine finish + one hop; the
                 # `stream` phase (delivery tail) starts here
@@ -493,10 +503,15 @@ class ProxyActor:
         stamps = {} if _phases.enabled() else None
         if t_recv is None:
             t_recv = time.time()
+        # the `written` station: the gap between two chunks of this stream
+        # written and drained, on this coroutine's clock; the stream thread
+        # takes them from the list
+        written: list = []
+        t_written = None
         threading.Thread(
             target=self._run_stream,
             args=(app, payload, loop, q, cancel, window, request_id,
-                  deadline_s, stamps),
+                  deadline_s, stamps, written),
             name="proxy-stream",
             daemon=True,
         ).start()
@@ -530,6 +545,10 @@ class ProxyActor:
                     if stamps is not None and "t_first" not in stamps:
                         stamps["t_first"] = time.time()
                     await self._send(writer, conn, h11.Data(data=val))
+                    now = time.perf_counter()
+                    if t_written is not None:
+                        written.append(now - t_written)
+                    t_written = now
                 elif kind == "end":
                     await self._send(writer, conn, h11.EndOfMessage())
                     _count_request(200)

@@ -339,6 +339,12 @@ class StreamingDeploymentResponse:
         self._replica = replica
         self._resume = resume  # callable(items so far) -> successor response
         self._done = False
+        self._live = self  # the attempt __iter__ reads now (a failover moves it)
+
+    def report_delivered(self, gaps) -> None:
+        """``ObjectRefGenerator.report_delivered`` of the attempt being
+        read: the gaps between the items this consumer has written out."""
+        self._live._gen.report_delivered(gaps)
 
     def __iter__(self):
         import ray_tpu
@@ -370,7 +376,7 @@ class StreamingDeploymentResponse:
                         raise  # no resume contract / budget exhausted
                     nxt = cur._resume(list(emitted))
                     cur.close()
-                    cur = nxt
+                    cur = self._live = nxt
                     emitted = []
         finally:
             cur.close()

@@ -31,7 +31,7 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
-from ray_tpu.llm.engine import EngineConfig, LLMEngine
+from ray_tpu.llm.engine import EngineConfig, LLMEngine, stream_stats
 from ray_tpu.llm.scheduler import SamplingParams
 
 
@@ -279,7 +279,13 @@ class LLMDeployment:
         return m
 
     def stats(self) -> dict:
-        return self._engine.stats()
+        """The engine's counters, and under ``"stream"`` the replica
+        process's station histograms of the streaming path (read after the
+        engine answered, without its lock: OBSERVABILITY.md, "The streamed
+        token's stations")."""
+        s = self._engine.stats()
+        s["stream"] = stream_stats()
+        return s
 
     def audit(self) -> dict:
         """The KV-pool ledger and prefix-tree audits the watchdog runs

@@ -17,6 +17,7 @@ import json
 import os
 import threading
 import time
+from bisect import bisect_left
 from collections import defaultdict, deque
 from typing import Optional, Sequence
 
@@ -186,6 +187,12 @@ class Counter(Metric):
             cell = self._cell()
         cell[key] = cell.get(key, 0.0) + value
 
+    def value(self, tags: Optional[dict] = None) -> float:
+        """This PROCESS's running total of one tag set."""
+        key = self._tags(tags)
+        with self._lock:
+            return float(self._merged_data().get(key, 0.0))
+
 
 class Gauge(Metric):
     """Last-value-wins measurement. ``set`` is a single atomic dict store
@@ -200,6 +207,16 @@ class Gauge(Metric):
 
 
 DEFAULT_BOUNDARIES = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10)
+#: boundaries (seconds) for a latency whose TAIL is read from the buckets:
+#: 0.5 ms apart to 64 ms, then a factor of √2 to 4.096 s (140 in all), so a
+#: percentile interpolated by ``percentiles_from_buckets`` stands within
+#: 0.25 ms of the sample's for values under 64 ms.  The token gap at every
+#: station of the streaming path is bucketed on it
+#: (``llm_inter_token_latency_s``, ``core_stream_gap_s``,
+#: ``core_stream_leg_s``), so their vectors subtract and compare
+FINE_LATENCY_BOUNDS_S = tuple(0.0005 * i for i in range(1, 129)) + tuple(
+    0.064 * 2.0 ** (i / 2) for i in range(1, 13)
+)
 
 
 class Histogram(Metric):
@@ -225,7 +242,9 @@ class Histogram(Metric):
         self.boundaries = tuple(sorted(boundaries))
 
     def observe(self, value: float, tags: Optional[dict] = None):
-        key = self._tags(tags)
+        self._observe(self._tags(tags), value)
+
+    def _observe(self, key: str, value: float) -> None:
         try:
             cell = self._tls.cell
         except AttributeError:
@@ -234,16 +253,28 @@ class Histogram(Metric):
         if not isinstance(cur, list):
             cur = [0] * (len(self.boundaries) + 1) + [0.0, 0]  # buckets+sum+count
             cell[key] = cur
-        idx = len(self.boundaries)
-        for i, b in enumerate(self.boundaries):
-            if value <= b:
-                idx = i
-                break
-        cur[idx] += 1
+        cur[bisect_left(self.boundaries, value)] += 1  # first bound >= value
         cur[-2] += value
         cur[-1] += 1
 
     record = observe  # reference alias
+
+    def bind(self, tags: Optional[dict] = None) -> "BoundHistogram":
+        """One tag set of this histogram with its key made ONCE: an emit
+        site that observes per token pays no tag merge and no JSON."""
+        return BoundHistogram(self, self._tags(tags))
+
+    def buckets(self, tags: Optional[dict] = None) -> list:
+        """This PROCESS's cumulative per-bucket counts of one tag set (one
+        slot a boundary plus overflow, as ``percentiles_from_buckets``
+        takes them): a reader differences two of them over a window."""
+        return self._buckets_of(self._tags(tags))
+
+    def _buckets_of(self, key: str) -> list:
+        with self._lock:
+            cur = self._merged_data().get(key)
+        n = len(self.boundaries) + 1
+        return list(cur[:n]) if isinstance(cur, list) else [0] * n
 
     def percentiles(
         self, qs: Sequence[float] = (0.5, 0.95, 0.99), tags: Optional[dict] = None
@@ -256,6 +287,22 @@ class Histogram(Metric):
             cur = self._merged_data().get(key)
             data = list(cur) if isinstance(cur, list) else None
         return _percentile_summary(self.boundaries, data, qs)
+
+
+class BoundHistogram:
+    """``Histogram.bind``: ``observe`` into one tag set, lock-free like
+    ``Histogram.observe`` (the same per-thread cells, the same vectors)."""
+
+    __slots__ = ("hist", "key")
+
+    def __init__(self, hist: Histogram, key: str):
+        self.hist, self.key = hist, key
+
+    def observe(self, value: float) -> None:
+        self.hist._observe(self.key, value)
+
+    def buckets(self) -> list:
+        return self.hist._buckets_of(self.key)
 
 
 def safe_counter(name: str, description: str = "") -> Optional["Counter"]:

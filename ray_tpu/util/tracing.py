@@ -33,6 +33,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -400,9 +401,14 @@ def annotate(name: str, **kw: Any):
 
     This is the clock new timing instruments (ROADMAP D5): ``span()``
     above writes the Python ring that ``obs timeline`` reads, which no
-    device event shares a clock with."""
+    device event shares a clock with.  A process that has not loaded jax
+    holds no profiler and nothing would record the span: it gets the
+    shared null manager, and is not made to import jax for it (the
+    runtime's ``core.stream.*`` spans run in every worker)."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return _NULL_SPAN
         from jax.profiler import TraceAnnotation
 
         _TraceAnnotation = TraceAnnotation

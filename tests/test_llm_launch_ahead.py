@@ -89,7 +89,7 @@ def _streamed(req):
     """What the request's stream holds: (tokens, the reason after them)."""
     toks, reason = [], None
     while not req.stream.empty():
-        kind, val = req.stream.get_nowait()
+        kind, val = req.stream.get_nowait()[:2]  # a token carries its emit stamp third
         if kind == "token":
             assert reason is None, "a token after the stream's end"
             toks.append(val)

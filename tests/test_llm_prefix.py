@@ -92,7 +92,7 @@ def _drain(eng, req):
     got = []
     while True:
         try:
-            kind, val = req.stream.get_nowait()
+            kind, val = req.stream.get_nowait()[:2]  # a token carries its emit stamp third
         except queue.Empty:
             break
         if kind == "token":

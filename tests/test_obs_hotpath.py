@@ -79,6 +79,15 @@ HOT_PATHS = (
     # pays for assembly, never the per-step charge)
     ("ray_tpu/util/phases.py", "ray_tpu.util.phases", "new_ledger"),
     ("ray_tpu/util/phases.py", "ray_tpu.util.phases", "charge"),
+    # the streamed token's stations (ISSUE 38): every station and leg
+    # observes through a series bound once a stream (emit, wake, sent in
+    # the replica's loop and handler threads, acked / written / head_hold
+    # in its recv loop), and the proxy's stream thread hands its write
+    # gaps to the generator once an item — none may take a lock
+    ("ray_tpu/util/metrics.py", "ray_tpu.util.metrics",
+     "BoundHistogram.observe"),
+    ("ray_tpu/_private/runtime.py", "ray_tpu._private.runtime",
+     "ObjectRefGenerator.report_delivered"),
 )
 
 
