@@ -19,8 +19,9 @@ Three entry shapes, each jitted once per engine:
   feeds LIVES ON THE DEVICE ("Slot state" below): the engine launches
   step N+1 before it has read step N's tokens.
 * ``prefill_chunk`` — (chunk,) tokens of ONE sequence at positions
-  ``start..start+chunk`` (tail-padded; padded positions scatter to the
-  trash block).  Returns the last valid position's logits and the token
+  ``start..start+chunk`` (tail-padded; a padded position writes nothing:
+  the chunk's k/v enter the pool as the whole blocks they touch,
+  ``_chunk_write``).  Returns the last valid position's logits and the token
   sampled from them by the request's own knobs (one row of
   ``_sample_rows``): the final chunk's seeds generation, on the device.
 * ``verify_step`` — (slots, k+1) speculative-decode verification: each
@@ -150,12 +151,108 @@ def _scatter_kv(pool: jax.Array, vals: jax.Array, phys: jax.Array, off: jax.Arra
     """Write per-row k or v into physical blocks of the WHOLE pool, seen as
     ``_layer_loop`` carries it.  pool: (layers * num_blocks, heads, block,
     d); vals: (n, heads, d); phys: (n,) int32 block ids IN THAT VIEW (the
-    layer's first block + the table's entry); off: (n,) int32."""
+    layer's first block + the table's entry); off: (n,) int32.
+
+    The form for rows of which several may share a block (verify: a window
+    of a few positions a slot).  One scatter index a (row, head), on
+    purpose: the shorter ``pool.at[phys, :, off, :].set(vals)`` (a scatter
+    whose update window spans heads AND d) makes XLA transpose the pool:
+    compiled for a v5e at the one-chip cell's size it holds a temporary of
+    2,114,025,984 B, one whole pool, against 0 B for this index (AOT, jax
+    0.9.0 / libtpu 0.0.34; tests/test_llm_pool_inplace.py pins the same on
+    the CPU backend).  The price is the index: 77-93 ns each on a v5e,
+    whatever the pool's size, because a v5e lays the pool out in ``(8,
+    128)(2, 1)`` tiles over (block, d): the 16 rows of one (block, head)
+    are ONE tile, and one row is a masked read-modify-write of half-words
+    (PERF.md section 5)."""
     heads = vals.shape[1]
     with jax.named_scope("kv_write"):
         return pool.at[
             phys[:, None], jnp.arange(heads)[None, :], off[:, None], :
         ].set(vals)
+
+
+def _scatter_kv_blocks(pool: jax.Array, rows: jax.Array, ids: jax.Array, mask):
+    """``_scatter_kv`` by WHOLE blocks, for a step that knows which blocks
+    its rows fall into and that no two of its rows' blocks are one (the
+    trash block aside): the same pool afterwards, bit for bit, with one
+    scatter index a BLOCK, each a run of whole tiles.  pool: (layers *
+    num_blocks, heads, block, d); ids: (n,) block ids IN THAT VIEW; rows:
+    what each block's rows would become, broadcastable to (n, heads, block,
+    d); mask: (n, 1, block, 1) bool, true where a block row IS written.
+
+    The blocks' present content is gathered, ``rows`` laid over it where
+    ``mask`` says, the blocks scattered back, in place: a block keeps every
+    row that is not written.  Several ``ids`` may be 0: each writes back
+    what it read there, so whole blocks leave the trash block as it was.
+    (Block by block with ``dynamic_update_slice`` is 0.5 ms a chunk faster
+    in today's prefill program and NOT kept: around the same write alone
+    XLA re-laid the whole pool out for it, a 2.1 GB temporary, and a
+    decode's 64 blocks a layer cost three times the rows' form under
+    ``tp=4``; PERF.md section 6, PR 39.)"""
+    with jax.named_scope("kv_write"):
+        return pool.at[ids].set(jnp.where(mask, rows, pool[ids]))
+
+
+def _rows_write(phys, off):
+    """``write(pool, vals, base)`` row by row (``_scatter_kv`` at block
+    ``base + phys``): the verify step, whose window rows share blocks."""
+    return lambda pool, vals, base: _scatter_kv(pool, vals, base + phys, off)
+
+
+def _slots_write(phys, off, block: int):
+    """``write(pool, vals, base)`` of a decode: ONE position of each of
+    many sequences, so each row is alone in its block (empty slots meet in
+    the trash block) and goes as that whole block: 32 indices a layer and a
+    pool on one chip where the rows' form has 512."""
+    mask = (jnp.arange(block, dtype=jnp.int32) == off[:, None])[:, None, :, None]
+    return lambda pool, vals, base: _scatter_kv_blocks(
+        pool, vals[:, :, None, :], base + phys, mask
+    )
+
+
+def _chunk_blocks(table: jax.Array, start, n_valid, chunk: int, block: int):
+    """Where a prefill chunk's k/v go, as whole blocks.  A chunk is
+    ``chunk`` consecutive positions of ONE sequence from ``start``, the
+    first ``n_valid`` of them real, so it touches the ``chunk // block + 1``
+    blocks from ``start // block`` on.  Returns ``(ids, shift, mask)``: ids
+    (nb,) int32, the table's entry of each touched block, 0 (trash) for one
+    that holds no valid position (past the valid rows, or past the table's
+    reach); shift, a scalar: block row ``(j, r)`` holds chunk row ``j *
+    block + r - shift``; mask (nb, 1, block, 1) bool, true where that chunk
+    row is a valid one."""
+    nb = chunk // block + 1
+    first, shift = start // block, start % block
+    logical = first + jnp.arange(nb, dtype=jnp.int32)
+    rows = jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, block) - shift
+    mask = (rows >= 0) & (rows < n_valid)
+    ids = jnp.where(
+        mask.any(axis=1), table[jnp.minimum(logical, table.shape[0] - 1)], 0
+    )
+    return ids, shift, mask[:, None, :, None]
+
+
+def _chunk_write(table, start, n_valid, chunk: int, block: int):
+    """``write(pool, vals, base)`` of a prefill chunk: the 9 whole blocks
+    that 128 consecutive positions touch, where the rows' form has 2,048
+    indices a layer and a pool.  A start inside a block (a prefix hit that
+    diverged there, after its copy-on-write fork) keeps that block's
+    earlier tokens; a padded row writes nothing; no block before ``start //
+    block`` is touched (it may be shared).  All but ``base +`` and the
+    rows' own reordering is computed here, once a step, outside the layer
+    loop."""
+    ids, shift, mask = _chunk_blocks(table, start, n_valid, chunk, block)
+    nb = ids.shape[0]
+
+    def write(pool, vals, base):
+        with jax.named_scope("kv_write"):
+            # chunk row j * block + r - shift sits at j * block + r of the slice
+            padded = jnp.pad(vals, ((block, block), (0, 0), (0, 0)))
+            rows = jax.lax.dynamic_slice_in_dim(padded, block - shift, nb * block)
+            rows = rows.reshape(nb, block, *vals.shape[1:]).transpose(0, 2, 1, 3)
+        return _scatter_kv_blocks(pool, rows, base + ids, mask)
+
+    return write
 
 
 def _carry_loop(blocks, x, pools: tuple, layer_fn):
@@ -191,9 +288,16 @@ def _layer_loop(blocks, x, k_pool, v_pool, layer_fn):
     (L * NB, H, BS, D) view, in which layer ``l``'s block ``b`` is block
     ``l * NB + b``: ``layer_fn(x, layer, k, v, base) -> (x, k, v)`` gets
     that view and ``base = l * NB``, and adds ``base`` to every block id
-    it writes (``_scatter_kv``) or reads (the block tables handed to
-    ``ops.paged_attention``).  Returns (x, k_pool, v_pool), pools in
-    their own shape."""
+    it writes or reads (the block tables handed to ``ops.paged_attention``).
+    How a layer's k/v enter the pool follows from the step's shape, fixed
+    when it is traced: a prefill chunk (consecutive positions of ONE
+    sequence: ``_chunk_write``) and a decode (one position of many:
+    ``_slots_write``) write the whole blocks their rows fall into
+    (``_scatter_kv_blocks``); a verify window, whose rows share blocks,
+    goes row by row (``_rows_write`` → ``_scatter_kv``).  All leave the
+    same pool, and none may use a scatter window over heads
+    (``_scatter_kv``: a pool-sized temporary).  Returns (x, k_pool,
+    v_pool), pools in their own shape."""
     return _carry_loop(blocks, x, (k_pool, v_pool), layer_fn)
 
 
@@ -453,24 +557,24 @@ class PagedModelRunner(StepRunner):
                 out = out + layer["attn_out"]["bias"].astype(dt)
         return out
 
-    def _qkv_write(self, x, layer, k, v, base, positions, phys, off):
+    def _qkv_write(self, x, layer, k, v, base, positions, write):
         """The head of a layer, shared with the tensor-parallel runner:
         ln1, the rows' q/k/v, and their k/v written into the whole pools
-        (``_layer_loop``'s view) at block ``base + phys`` — the ONE place
-        a write is offset to its layer.  Returns (ln1, q, k, v)."""
+        (``_layer_loop``'s view) by the step's ``write(pool, vals, base)``
+        (``_chunk_write`` / ``_slots_write`` / ``_rows_write``, which add
+        ``base`` to their block ids).  Returns (ln1, q, k, v)."""
         ln1 = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
         q, kr, vr = self._qkv_rows(layer, ln1, positions)
-        blocks = base + phys
-        k = _scatter_kv(k, kr.astype(k.dtype), blocks, off)
-        v = _scatter_kv(v, vr.astype(v.dtype), blocks, off)
+        k = write(k, kr.astype(k.dtype), base)
+        v = write(v, vr.astype(v.dtype), base)
         return ln1, q, k, v
 
-    def _layer(self, x, layer, k, v, base, positions, phys, off, attend):
+    def _layer(self, x, layer, k, v, base, positions, write, attend):
         """One transformer layer over the whole pools (``_layer_loop``'s
         view; ``base`` is this layer's first block there).
         ``attend(q, k, v, base) -> (rows, d_model)`` supplies the step
         shape's paged attention, its block tables offset by ``base``."""
-        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, phys, off)
+        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, write)
         att = self._attn_out(layer, attend(q, k, v, base))
         if self.arch == "gptj":
             out = x + att + self._mlp(layer, ln1)  # parallel residual
@@ -536,7 +640,8 @@ class PagedModelRunner(StepRunner):
         x, k_pool, v_pool = _layer_loop(
             params["blocks"], x, k_pool, v_pool,
             functools.partial(
-                self._layer, positions=positions, phys=phys, off=off, attend=attend
+                self._layer, positions=positions, write=_slots_write(phys, off, bs),
+                attend=attend,
             ),
         )
         logits = self._lm_head(params, x)  # (S, V)
@@ -597,7 +702,8 @@ class PagedModelRunner(StepRunner):
         x, k_pool, v_pool = _layer_loop(
             params["blocks"], x, k_pool, v_pool,
             functools.partial(
-                self._layer, positions=pos_flat, phys=phys, off=off, attend=attend
+                self._layer, positions=pos_flat, write=_rows_write(phys, off),
+                attend=attend,
             ),
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)  # (S, W, V)
@@ -641,12 +747,8 @@ class PagedModelRunner(StepRunner):
         chunk: int,
     ):
         cfg = self.cfg
-        bs = self.block_size
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
-        valid = jnp.arange(chunk) < n_valid
         x = self._embed(params, tokens, positions)  # (chunk, d)
-        phys = jnp.where(valid, table[positions // bs], 0)  # padded → trash
-        off = positions % bs
 
         def attend(q, k, v, base):
             return paged_prefill_attention_xla(
@@ -656,7 +758,8 @@ class PagedModelRunner(StepRunner):
         x, k_pool, v_pool = _layer_loop(
             params["blocks"], x, k_pool, v_pool,
             functools.partial(
-                self._layer, positions=positions, phys=phys, off=off, attend=attend
+                self._layer, positions=positions, attend=attend,
+                write=_chunk_write(table, start, n_valid, chunk, self.block_size),
             ),
         )
         last = x[jnp.maximum(n_valid - 1, 0)]  # (d,)

@@ -76,12 +76,15 @@ from ray_tpu.llm.cache import CacheConfig, KVBlockPool
 from ray_tpu.llm.model_runner import (
     PagedModelRunner,
     _advance_slots,
+    _chunk_write,
     _decode_sample,
     _fork_impl,
     _layer_loop,
     _layernorm,
     _merge_slots,
     _prefill_sample,
+    _rows_write,
+    _slots_write,
     _verify_rows,
 )
 from ray_tpu.ops.paged_attention import (
@@ -381,7 +384,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
     # -- per-device layer math --------------------------------------------
 
     def _tp_layer(
-        self, x, layer, k, v, base, positions, phys, off, attend, noted
+        self, x, layer, k, v, base, positions, write, attend, noted
     ):
         """One transformer layer on THIS device's head/ff shard, over the
         whole local pools (``_layer_loop``'s view; ``base`` is this
@@ -411,7 +414,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             with jax.named_scope(scope):
                 return h + layer[mod]["bias"].astype(dt)
 
-        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, phys, off)
+        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, write)
         att_p = attn_partial(q, k, v)
         if self.arch == "gptj":
             # parallel residual: attention + MLP partials share ONE
@@ -472,7 +475,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
         x, k_pool, v_pool = self._tp_layers(
             "decode", params, x, k_pool, v_pool,
-            positions=positions, phys=phys, off=off, attend=attend,
+            positions=positions, write=_slots_write(phys, off, bs), attend=attend,
         )
         logits = self._lm_head(params, x)
         live, nxt, logp = _decode_sample(logits, knobs, counters)
@@ -511,7 +514,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
         x, k_pool, v_pool = self._tp_layers(
             "verify", params, x, k_pool, v_pool,
-            positions=pos_flat, phys=phys, off=off, attend=attend,
+            positions=pos_flat, write=_rows_write(phys, off), attend=attend,
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)
         n_acc, out, logp = _verify_rows(
@@ -525,13 +528,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         # chunk is tokens.shape[0] — static under jit, but NOT a static
         # kwarg: shard_map takes positional specs only, and the engine
         # always pads to cfg.prefill_chunk so this still traces once
-        bs = self.block_size
         chunk = tokens.shape[0]
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
-        valid = jnp.arange(chunk) < n_valid
         x = self._embed(params, tokens, positions)
-        phys = jnp.where(valid, table[positions // bs], 0)
-        off = positions % bs
 
         def attend(q, k, v, base):
             return paged_prefill_attention_xla(
@@ -540,7 +539,8 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
 
         x, k_pool, v_pool = self._tp_layers(
             "prefill", params, x, k_pool, v_pool,
-            positions=positions, phys=phys, off=off, attend=attend,
+            positions=positions, attend=attend,
+            write=_chunk_write(table, start, n_valid, chunk, self.block_size),
         )
         last = x[jnp.maximum(n_valid - 1, 0)]
         logits = self._lm_head(params, last[None, :])[0]

@@ -2,7 +2,12 @@
 WHOLE pool in place (``model_runner._layer_loop``, PR 24).
 
 Two properties per step (decode, prefill, verify) x arch (gpt, gptj), and
-the three tensor-parallel shard bodies at tp=2 on host devices:
+the three tensor-parallel shard bodies at tp=2 on host devices.  A prefill
+chunk and a decode write WHOLE blocks (``model_runner._scatter_kv_blocks``),
+a verify window rows (``_scatter_kv``): all are held to the same reference,
+the chunk at a block of 16 and a chunk of 128 for every ``start`` x
+``n_valid`` of ``STARTS`` x ``N_VALID`` (a step named
+``prefill@<start>+<n_valid>``):
 
 * **no pool-sized temporary** — the compiled step's
   ``memory_analysis().temp_size_in_bytes`` stays under half of ONE pool's
@@ -18,7 +23,12 @@ the three tensor-parallel shard bodies at tp=2 on host devices:
   rows hold layer ``l``'s k/v: the reference is a plain Python loop over
   layers on per-layer pools with ``.at[].set`` and the unshifted block
   tables, in float32.  That catches an ``l * NB`` offset applied to the
-  wrong table, or a write into the wrong layer.
+  wrong table, or a write into the wrong layer.  A chunk leaves the trash
+  block as it was (a padded row writes nothing), and no block before
+  ``start // block`` is touched: those may be another sequence's too.
+
+Last, the trap beside ``_scatter_kv``'s index: the scatter whose window
+spans heads compiles to a pool-sized temporary, the two forms in use do not.
 """
 
 import functools
@@ -32,8 +42,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from ray_tpu.llm.model_runner import (  # noqa: E402
     PagedModelRunner,
+    _chunk_blocks,
+    _chunk_write,
     _layernorm,
     _sample_rows,
+    _scatter_kv,
+    _slots_write,
     _verify_rows,
     host_batch,
     pack_knobs,
@@ -48,17 +62,23 @@ from ray_tpu.parallel.mesh import make_tp_mesh  # noqa: E402
 # x 16 = 1.5 MB a pool in float32, against ~0.2 MB of temporaries for the rest
 L, NB, BS, TMAX, HEADS, HD = 3, 512, 4, 6, 4, 16
 SLOTS, CHUNK, W = 4, 8, 3
+# the chunk's own cases: a block of 16, a chunk of 128 (the served sizes), a
+# table of 15 blocks, so that a chunk from 112 ends in the table's LAST block
+# and its ninth block lies past the table's reach
+WIDE = (16, 128, 15)
+STARTS = (0, 5, 16, 21, 112)
+N_VALID = (1, 15, 16, 17, 127, 128)
 ARCHS = {
     "gpt": (
         GPTConfig(
             vocab_size=96, d_model=HEADS * HD, n_layers=L, n_heads=HEADS,
-            seq_len=64, dtype="float32",
+            seq_len=256, dtype="float32",
         ),
         gpt_init,
     ),
     "gptj": (
         GPTJConfig(
-            vocab_size=96, seq_len=64, d_model=HEADS * HD, n_layers=L,
+            vocab_size=96, seq_len=256, d_model=HEADS * HD, n_layers=L,
             n_heads=HEADS, rotary_dim=8, dtype="float32", remat=False,
             attn_impl="xla", fused_loss=False,
         ),
@@ -66,16 +86,22 @@ ARCHS = {
     ),
 }
 STEPS = ("decode", "prefill", "verify")
-CASES = [(step, arch, 1) for step in STEPS for arch in ARCHS] + [
-    pytest.param(
-        step, "gptj", 2,
-        marks=pytest.mark.skipif(
-            len(jax.devices("cpu")) < 2,
-            reason="needs 2 host devices (conftest's XLA_FLAGS)",
-        ),
+CHUNKS = tuple(f"prefill@{s}+{n}" for s in STARTS for n in N_VALID)
+
+
+def _cases(steps):
+    two_devices = pytest.mark.skipif(
+        len(jax.devices("cpu")) < 2,
+        reason="needs 2 host devices (conftest's XLA_FLAGS)",
     )
-    for step in STEPS
-]
+    return [(step, arch, 1) for step in steps for arch in ARCHS] + [
+        pytest.param(step, "gptj", 2, marks=two_devices) for step in steps
+    ]
+
+
+def _geometry(step):
+    """(block, chunk, table width) of a step's case."""
+    return WIDE if "@" in step else (BS, CHUNK, TMAX)
 
 
 @functools.lru_cache(maxsize=None)
@@ -84,8 +110,8 @@ def _params(arch):
     return init(jax.random.PRNGKey(0), cfg)
 
 
-def _noise_pools(tp):
-    shape = (L, NB, HEADS, BS, HD)
+def _noise_pools(tp, bs=BS):
+    shape = (L, NB, HEADS, bs, HD)
     k = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
     if tp > 1:
@@ -104,14 +130,20 @@ def _operands(step):
         np.ones(SLOTS, np.float32), np.zeros(SLOTS, np.uint32),
         np.zeros(SLOTS, np.int32),
     )
-    if step == "prefill":
-        table = rng.choice(np.arange(1, NB), TMAX, replace=False).astype(np.int32)
-        start, n_valid = 5, CHUNK - 2   # mid-block start; two padded rows
-        tokens = rng.integers(0, 96, CHUNK).astype(np.int32)
-        pos = start + np.arange(CHUNK)
-        phys = np.where(np.arange(CHUNK) < n_valid, table[pos // BS], 0)
+    if step.startswith("prefill"):
+        bs, chunk, tmax = _geometry(step)
+        table = rng.choice(np.arange(1, NB), tmax, replace=False).astype(np.int32)
+        start, n_valid = 5, chunk - 2   # mid-block start; two padded rows
+        if "@" in step:
+            start, n_valid = map(int, step.split("@")[1].split("+"))
+        tokens = rng.integers(0, 96, chunk).astype(np.int32)
+        pos = start + np.arange(chunk)
+        # a padded row belongs nowhere: the reference sends it to trash
+        phys = np.where(
+            np.arange(chunk) < n_valid, table[np.minimum(pos // bs, tmax - 1)], 0
+        )
         ops = (tokens, np.int32(start), np.int32(n_valid), table)
-        return ops, (pos, phys, pos % BS)
+        return ops, (pos, phys, pos % bs)
     tables = rng.choice(np.arange(1, NB), (SLOTS, TMAX), replace=False).astype(np.int32)
     if step == "decode":
         # slot 3 is inactive: position 0, an all-trash table
@@ -132,11 +164,14 @@ def _operands(step):
     return (tokens, base, tables) + greedy, (pos, phys, pos % BS)
 
 
-def _runner(arch, tp):
+@functools.lru_cache(maxsize=None)
+def _runner(arch, tp, bs=BS):
+    """One runner a (arch, tp, block): its jitted steps compile once for all
+    the cases that call them (``start`` and ``n_valid`` are traced)."""
     cfg, _ = ARCHS[arch]
     if tp > 1:
-        return TensorParallelPagedModelRunner(cfg, _params(arch), BS, "xla", tp=tp)
-    return PagedModelRunner(cfg, _params(arch), BS, "xla")
+        return TensorParallelPagedModelRunner(cfg, _params(arch), bs, "xla", tp=tp)
+    return PagedModelRunner(cfg, _params(arch), bs, "xla")
 
 
 def _jitted(runner, step, ops):
@@ -145,16 +180,17 @@ def _jitted(runner, step, ops):
     state and a patch (``host_batch``), the prefill a sampler row."""
     if step == "decode":
         return runner._decode, host_batch(*ops), {}
-    if step == "prefill":
+    if step.startswith("prefill"):
         static = {} if hasattr(runner, "tp") else {"chunk": len(ops[0])}
         return runner._prefill, ops + (pack_knobs(0, 0.0, 0, 1.0, 0),), static
     return runner._verify, ops, {}
 
 
-@pytest.mark.parametrize("step,arch,tp", CASES)
+@pytest.mark.parametrize("step,arch,tp", _cases(STEPS + ("prefill@5+127",)))
 def test_step_holds_no_pool_sized_temporary(step, arch, tp):
-    runner = _runner(arch, tp)
-    k, v = _noise_pools(tp)
+    bs = _geometry(step)[0]
+    runner = _runner(arch, tp, bs)
+    k, v = _noise_pools(tp, bs)
     ops, _rows = _operands(step)
     fn, ops, static = _jitted(runner, step, ops)
     compiled = fn.lower(runner.params, k, v, *ops, **static).compile()
@@ -166,15 +202,19 @@ def test_step_holds_no_pool_sized_temporary(step, arch, tp):
     )
 
 
-def _reference(arch, step, ops, rows, k_pool, v_pool):
+@functools.lru_cache(maxsize=None)
+def _reference(arch, step):
     """The same step as a plain Python loop over layers: layer ``l``
     scatters into and attends over ITS pool ``k_pool[l]`` with the
     tables as given.  Single-chip float32; the layer math is the
     runner's own helpers (not under test here), the loop, the writes and
-    the reads are not."""
+    the reads are not.  (One a (arch, step): tp 1 and 2 share it.)"""
     cfg, _ = ARCHS[arch]
     params = _params(arch)
-    ref = PagedModelRunner(cfg, params, BS, "xla")
+    bs = _geometry(step)[0]
+    ops, rows = _operands(step)
+    k_pool, v_pool = _noise_pools(1, bs)
+    ref = PagedModelRunner(cfg, params, bs, "xla")
     pos, phys, off = (jnp.asarray(a, jnp.int32) for a in rows)
     n = pos.shape[0]
     tokens = jnp.asarray(ops[0]).reshape(-1)
@@ -188,7 +228,7 @@ def _reference(arch, step, ops, rows, k_pool, v_pool):
         v_l = v_pool[l].at[phys[:, None], heads, off[:, None], :].set(vr)
         if step == "decode":
             att = pa.paged_attention_xla(q, k_l, v_l, ops[2], pos + 1)
-        elif step == "prefill":
+        elif step.startswith("prefill"):
             att = pa.paged_prefill_attention_xla(q, k_l, v_l, ops[3], pos)
         else:
             att = pa.paged_verify_attention_xla(
@@ -204,26 +244,25 @@ def _reference(arch, step, ops, rows, k_pool, v_pool):
                 layer, _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
             )
         k_pool, v_pool = k_pool.at[l].set(k_l), v_pool.at[l].set(v_l)
-    if step == "prefill":
+    if step.startswith("prefill"):
         out = (ref._lm_head(params, x[int(ops[2]) - 1][None, :])[0],)
     elif step == "decode":
         out = _sample_rows(ref._lm_head(params, x), ops[6], ops[7], *ops[3:6])
     else:
         logits = ref._lm_head(params, x).reshape(SLOTS, W, -1)
         out = _verify_rows(logits, ops[0][:, 1:], ops[6], ops[7], *ops[3:6])
-    return k_pool, v_pool, out
+    return np.asarray(k_pool), np.asarray(v_pool), out
 
 
-@pytest.mark.parametrize("step,arch,tp", CASES)
+@pytest.mark.parametrize("step,arch,tp", _cases(STEPS + CHUNKS))
 def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
-    runner = _runner(arch, tp)
-    k0, v0 = (np.asarray(a) for a in _noise_pools(1))
+    bs = _geometry(step)[0]
+    runner = _runner(arch, tp, bs)
+    k0, v0 = (np.asarray(a) for a in _noise_pools(1, bs))
     ops, rows = _operands(step)
-    ref_k, ref_v, ref_out = _reference(
-        arch, step, ops, rows, jnp.asarray(k0), jnp.asarray(v0)
-    )
+    ref_k, ref_v, ref_out = _reference(arch, step)
     fn, sent, static = _jitted(runner, step, ops)
-    k, v = _noise_pools(tp)
+    k, v = _noise_pools(tp, bs)
     k1, v1, *out = fn(runner.params, k, v, *sent, **static)
     if step == "decode":
         # the carry the next step feeds from: the sampled token, one
@@ -234,8 +273,12 @@ def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
         )
 
     _pos, phys, off = rows
-    fed = np.zeros((L, NB, BS), bool)
+    fed = np.zeros((L, NB, bs), bool)
     fed[:, phys, off] = True
+    chunk = step.startswith("prefill")
+    if chunk:
+        # whole blocks: a padded row writes nothing, trash stays as it was
+        fed[:, 0] = False
     # splitting the row-parallel sums over devices moves activations by an
     # ulp a layer (llm.multichip's module text); one device adds none
     tol = 1e-4 if tp > 1 else 1e-5
@@ -247,6 +290,11 @@ def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
             f"{np.argwhere(changed.any(axis=(2, 4)) & ~fed)[:4].tolist()}"
         )
         assert changed.all(axis=(2, 4))[fed].all(), f"{name}: a fed row kept its noise"
+        if chunk:
+            # the blocks before the chunk's first may be ANOTHER sequence's
+            # too (a shared prefix): bit for bit as they were
+            shared = ops[3][: int(ops[1]) // bs]
+            np.testing.assert_array_equal(after[:, shared], before[:, shared])
         # block 0 is the trash block: several rows may land on one place
         np.testing.assert_allclose(
             after[:, 1:], want[:, 1:], rtol=tol, atol=tol,
@@ -258,3 +306,103 @@ def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
             np.testing.assert_array_equal(got, want)
         else:
             np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
+
+
+def test_chunk_blocks_send_every_padded_block_to_trash():
+    """One valid row: the chunk's other eight blocks hold no valid position
+    and are block 0, whatever the table says; so is a block past the table's
+    reach.  A start inside a block keeps the rows before it out of the mask."""
+    bs, chunk, tmax = WIDE
+    table = np.arange(100, 100 + tmax, dtype=np.int32)
+    ids, shift, mask = _chunk_blocks(table, np.int32(21), np.int32(1), chunk, bs)
+    assert np.asarray(ids).tolist() == [101] + [0] * 8 and int(shift) == 5
+    assert np.argwhere(np.asarray(mask)[:, 0, :, 0]).tolist() == [[0, 5]]
+    ids, _shift, mask = _chunk_blocks(table, np.int32(112), np.int32(128), chunk, bs)
+    assert np.asarray(ids).tolist() == list(range(107, 115)) + [0]
+    assert np.asarray(mask)[:8].all() and not np.asarray(mask)[8].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("start", STARTS + (37,))
+def test_whole_blocks_leave_the_pool_the_rows_leave(start, dtype):
+    """The two forms' contract, without a model around them: the same
+    operands in ``_layer_loop``'s view (a layer's ``base`` added), the same
+    pool afterwards bit for bit, in the pool's served dtype too.  The trash
+    block aside: rows overwrite it, whole blocks leave it as it was."""
+    bs, chunk, tmax = WIDE
+    base = 2 * NB
+    pool = jax.random.normal(jax.random.PRNGKey(3), (L * NB, HEADS, bs, HD)).astype(dtype)
+    vals = jax.random.normal(jax.random.PRNGKey(4), (chunk, HEADS, HD)).astype(dtype)
+    table = np.random.default_rng(5).choice(np.arange(1, NB), tmax, replace=False)
+    pos = start + np.arange(chunk)
+    for n_valid in (0,) + N_VALID:
+        phys = np.where(
+            np.arange(chunk) < n_valid, table[np.minimum(pos // bs, tmax - 1)], 0
+        ).astype(np.int32)
+        want = np.array(_scatter_kv(pool, vals, base + phys, pos % bs))
+        write = _chunk_write(
+            table.astype(np.int32), np.int32(start), np.int32(n_valid), chunk, bs
+        )
+        want[base] = np.asarray(pool[base])
+        np.testing.assert_array_equal(
+            np.asarray(write(pool, vals, base)), want, err_msg=f"n_valid={n_valid}"
+        )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_decode_s_blocks_leave_the_pool_its_rows_leave(dtype):
+    """The same contract for one position of many sequences: every live
+    slot's row lands where the rows' form puts it, and nothing else of its
+    block moves; the empty slots (position 0, an all-trash table) meet in
+    the trash block, where either form may leave any of their rows."""
+    bs, slots, base = WIDE[0], 32, NB
+    pool = jax.random.normal(jax.random.PRNGKey(3), (L * NB, HEADS, bs, HD)).astype(dtype)
+    vals = jax.random.normal(jax.random.PRNGKey(4), (slots, HEADS, HD)).astype(dtype)
+    rng = np.random.default_rng(6)
+    phys = rng.choice(np.arange(1, NB), slots, replace=False).astype(np.int32)
+    off = rng.integers(0, bs, slots).astype(np.int32)
+    phys[::5], off[::5] = 0, 0
+    want = np.array(_scatter_kv(pool, vals, base + phys, off))
+    got = np.array(_slots_write(phys, off, bs)(pool, vals, base))
+    want[base] = got[base] = 0
+    np.testing.assert_array_equal(got, want)
+
+
+def _wide_window(pool, vals, phys, off):
+    """The index a reader would write first: one scatter index a ROW, its
+    update window over (heads, d)."""
+    return pool.at[phys, :, off, :].set(vals)
+
+
+def _as_blocks(pool, vals, phys, off):
+    table = jnp.arange(1, 1 + WIDE[2], dtype=jnp.int32)
+    return _chunk_write(table, off[0], vals.shape[0], WIDE[1], WIDE[0])(pool, vals, 0)
+
+
+def _as_slots(pool, vals, phys, off):
+    return _slots_write(phys, off, WIDE[0])(pool, vals, 0)
+
+
+@pytest.mark.parametrize(
+    "form,copies",
+    [(_scatter_kv, False), (_as_blocks, False), (_as_slots, False), (_wide_window, True)],
+    ids=["rows", "chunk_blocks", "slot_blocks", "wide_window"],
+)
+def test_scatter_over_heads_would_copy_the_pool(form, copies):
+    """Why ``_scatter_kv`` carries ``arange(heads)`` in its index: without
+    it XLA transposes the pool (a temporary of one pool here, 2,114,025,984
+    B on a v5e at the one-chip cell's size); the rows' index and the whole
+    blocks of a chunk or a decode stay in place.  float32: in bfloat16 the CPU
+    backend reports two pools for all three."""
+    bs, chunk, _tmax = WIDE
+    pool = jax.ShapeDtypeStruct((L * NB, HEADS, bs, HD), jnp.float32)
+    vals = jax.ShapeDtypeStruct((chunk, HEADS, HD), jnp.float32)
+    rows = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+    compiled = jax.jit(form, donate_argnums=0).lower(pool, vals, rows, rows).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    pool_bytes = np.prod(pool.shape) * 4
+    # 1.00 pool for the wide window; 0.008 for the rows' index, 0.009 for a
+    # chunk's blocks, 0.083 for the blocks of 128 slots (the gathered blocks)
+    assert (temp > 0.9 * pool_bytes) if copies else (temp < 0.15 * pool_bytes), (
+        f"{temp} B of temporaries against a pool of {pool_bytes} B"
+    )
