@@ -1,0 +1,11 @@
+"""Device milliseconds per decode execution in the leaf ops whose ``op_name``
+lies in the ``window_attention`` scope: the differential attention of every
+window layer over its rings (first chip), with the slice's live rows and
+tokens beside it on a ``program_spans`` line.  None where the program has no
+such scope."""
+
+from _decode_scope import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "window_attention")

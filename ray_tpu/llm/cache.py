@@ -1,6 +1,7 @@
-"""What sequences hold on the device: the paged KV-cache block pool, and
-the recurrent-state pool of a model without keys and values (``StatePool``,
-at the end) — static-shape JAX storage, host-side ledger.
+"""What sequences hold on the device: the paged KV-cache block pool, the
+pool of fixed-size states of a model without keys and values (``StatePool``)
+and the two behind one ledger for a model with both (``HybridPool``, at the
+end) — static-shape JAX storage, host-side ledger.
 
 vLLM-style paging on the TPU shape discipline: the device side is two
 fixed arrays per model
@@ -433,11 +434,16 @@ class StateConfig:
     scheduler use of a paged pool: a sequence's whole state is ONE block
     that holds ``max_seq_len`` tokens, so nothing grows and the length
     limit is the model's positions.  ``num_blocks`` is one more than the
-    slots, which keeps the callers' ``num_blocks - 1`` usable blocks true;
-    no state is reserved (a dead decode row writes nothing)."""
+    slots, which keeps the callers' ``num_blocks - 1`` usable blocks true.
+    ``trash``: slot 0 of the arrays is reserved, as block 0 of a paged pool
+    is, for the steps of a model whose dead decode rows and padded chunk
+    rows write SOMEWHERE (a ring of K/V); sequences then own slots 1 ..
+    ``slots``.  Without it no state is reserved (a dead decode row writes
+    nothing)."""
 
     slots: int
     max_seq_len: int
+    trash: bool = False
 
     def __post_init__(self):
         if self.slots < 1 or self.max_seq_len < 1:
@@ -457,46 +463,63 @@ class StateConfig:
 
 
 class StatePool:
-    """Recurrent states of a model without keys or values: ONE device array
-    ``(layers, slots) + state_shape`` allocated at engine start, and the
-    ledger calls the scheduler and the engine make of ``KVBlockPool``.  A
-    sequence owns one slot from admission to its end: admission needs a
-    free slot, nothing grows, nothing is shared, nobody is preempted for
-    memory.  The jitted steps take ``state`` donated and hand it back; a
-    slot's state is overwritten by its next owner's first prefill chunk,
-    never cleared."""
+    """Fixed-size state of a sequence, a slot each: device arrays
+    ``(layers, slots) + shape`` allocated at engine start, one a KIND of
+    state (``leaves``: name -> (layers, one slot's shape, dtype); a model
+    with one kind gives ``n_layers, state_shape, dtype`` and the leaf is
+    ``state``), and the ledger calls the scheduler and the engine make of
+    ``KVBlockPool``.  A sequence owns one slot, the same in every leaf,
+    from admission to its end: admission needs a free slot, nothing grows,
+    nothing is shared, nobody is preempted for memory.  The jitted steps
+    take the leaves donated and hand them back; a slot's state is
+    overwritten by its next owner's first prefill chunk, never cleared."""
 
     paged = False
 
-    def __init__(self, cfg: StateConfig, n_layers: int, state_shape: tuple,
-                 dtype="float32"):
+    def __init__(self, cfg: StateConfig, n_layers: int = 0, state_shape: tuple = (),
+                 dtype="float32", leaves: Optional[dict] = None):
         import jax.numpy as jnp
 
         self.cfg = cfg
-        self.state = jnp.zeros((n_layers, cfg.slots) + tuple(state_shape), jnp.dtype(dtype))
+        if leaves is None:
+            leaves = {"state": (n_layers, state_shape, dtype)}
+        self._first = int(cfg.trash)
+        self.leaves = {
+            name: jnp.zeros((layers, cfg.slots + self._first) + tuple(shape), jnp.dtype(dt))
+            for name, (layers, shape, dt) in leaves.items()
+        }
         self._lock = threading.Lock()
-        self._free = list(range(cfg.slots - 1, -1, -1))  # LIFO
+        self._free = list(range(cfg.slots - 1 + self._first, self._first - 1, -1))  # LIFO
         self._owned: dict[str, int] = {}
 
     @property
+    def state(self):
+        """The one leaf of a pool made from ``n_layers, state_shape``."""
+        return self.leaves["state"]
+
+    @property
     def arrays(self) -> tuple:
-        return (self.state,)
+        return tuple(self.leaves.values())
 
     @arrays.setter
     def arrays(self, new) -> None:
-        (self.state,) = new
+        self.leaves = dict(zip(self.leaves, new, strict=True))
 
     def blocks_for(self, n_tokens: int) -> int:
         return -(-max(n_tokens, 1) // self.cfg.max_seq_len)
 
     @property
     def block_bytes(self) -> int:
-        """Device bytes of one slot's state across every layer."""
-        return self.state.nbytes // self.cfg.slots
+        """Device bytes of one slot's state across every leaf and layer."""
+        return self.device_bytes // (self.cfg.slots + self._first)
 
     @property
     def device_bytes(self) -> int:
-        return self.state.nbytes
+        return sum(a.nbytes for a in self.leaves.values())
+
+    def leaf_bytes(self) -> dict:
+        """name -> device bytes of that kind of state, every slot."""
+        return {name: a.nbytes for name, a in self.leaves.items()}
 
     @property
     def num_free_blocks(self) -> int:
@@ -568,7 +591,8 @@ class StatePool:
             free, owned = list(self._free), dict(self._owned)
         held = free + list(owned.values())
         duplicates = len(held) != len(set(held))
-        out_of_range = sum(1 for s in held if not 0 <= s < self.cfg.slots)
+        out_of_range = sum(
+            1 for s in held if not self._first <= s < self.cfg.slots + self._first)
         missing = self.cfg.slots - len(held)
         return {
             "ok": not duplicates and not out_of_range and missing == 0,
@@ -580,8 +604,137 @@ class StatePool:
 
     def table_row(self, seq_id: Optional[str]) -> np.ndarray:
         """(1,) int32: the sequence's slot; ``None`` (an empty decode row)
-        is 0, and a dead row touches no state whatever it names."""
+        is 0: the trash slot where the pool has one, else a slot that a
+        dead row does not touch whatever it names."""
         row = np.zeros(1, np.int32)
         if seq_id is not None:
             row[0] = self.blocks_of(seq_id)[0]
         return row
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """Geometry of a ``HybridPool``: ``CacheConfig``'s for the blocks, and
+    the slots of state beside them."""
+
+    num_blocks: int
+    block_size: int
+    max_blocks_per_seq: int
+    slots: int
+
+    @property
+    def max_seq_len(self) -> int:
+        return self.max_blocks_per_seq * self.block_size
+
+
+class HybridPool:
+    """Two kinds of cache for one sequence behind ONE ledger: growing
+    blocks of K/V (``kv``, a ``KVBlockPool``) and a slot of fixed-size
+    state (``states``, a ``StatePool`` with a trash slot).  It answers the
+    calls the scheduler and the engine make of ``KVBlockPool``: a sequence
+    is admitted when a slot AND its blocks are free, grows by blocks, is
+    preempted for blocks (recompute: its next first chunk overwrites its
+    new slot), and a free returns both.  Block counts, ``block_bytes`` and
+    the free / owned partition are the K/V pool's, where the pressure is;
+    the slots stand beside them (``ledger_counts``, ``audit``).
+
+    A table row is ``[slot, block table...]`` and ``arrays`` the K/V pool's
+    two followed by the state's leaves, in the order the model's steps take
+    them.  It has no lock of its own: each part's is enough, because the
+    calls that change both (``allocate``, ``free``) come from under the
+    engine's lock, and ``audit`` reads the parts until they agree."""
+
+    paged = True
+
+    def __init__(self, cfg: HybridConfig, kv_layout: dict, leaves: dict):
+        self.cfg = cfg
+        self.kv = KVBlockPool(
+            CacheConfig(cfg.num_blocks, cfg.block_size, cfg.max_blocks_per_seq), **kv_layout)
+        self.states = StatePool(
+            StateConfig(cfg.slots, cfg.max_seq_len, trash=True), leaves=leaves)
+
+    @property
+    def k(self):
+        return self.kv.k
+
+    @property
+    def arrays(self) -> tuple:
+        return self.kv.arrays + self.states.arrays
+
+    @arrays.setter
+    def arrays(self, new) -> None:
+        self.kv.arrays, self.states.arrays = new[:2], new[2:]
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return self.kv.blocks_for(n_tokens)
+
+    @property
+    def block_bytes(self) -> int:
+        return self.kv.block_bytes
+
+    @property
+    def device_bytes(self) -> int:
+        return self.kv.device_bytes + self.states.device_bytes
+
+    @property
+    def num_free_blocks(self) -> int:
+        return self.kv.num_free_blocks
+
+    @property
+    def num_used_blocks(self) -> int:
+        return self.kv.num_used_blocks
+
+    num_evictable_blocks = 0
+
+    def ledger_counts(self) -> dict:
+        slots = self.states.ledger_counts()
+        return dict(self.kv.ledger_counts(), slots_free=slots["free"],
+                    slots_owned=slots["seq_owned"])
+
+    def utilization(self) -> float:
+        return self.kv.utilization()
+
+    def can_allocate(self, n_tokens: int, shared: int = 0) -> bool:
+        return self.states.num_free_blocks > 0 and self.kv.can_allocate(n_tokens, shared)
+
+    def allocate(self, seq_id: str, n_tokens: int, shared: Sequence[int] = ()) -> list[int]:
+        """Claim a slot and the blocks for ``n_tokens``, or neither."""
+        if shared:
+            raise ValueError("a sequence with a state shares no blocks")
+        self.states.allocate(seq_id, 1)
+        try:
+            # the ledger entry is the caller's, under ``seq_id``, exactly as
+            # KVBlockPool.allocate's own: this only forwards it
+            return self.kv.allocate(seq_id, n_tokens)  # raylint: disable=RL015
+        except Exception:
+            self.states.free(seq_id)
+            raise
+
+    def grow_to(self, seq_id: str, n_tokens: int) -> bool:
+        return self.kv.grow_to(seq_id, n_tokens)
+
+    def free(self, seq_id: str) -> int:
+        freed = self.kv.free(seq_id)
+        self.states.free(seq_id)
+        return freed
+
+    def blocks_of(self, seq_id: str) -> list[int]:
+        return self.kv.blocks_of(seq_id)
+
+    def audit(self) -> dict:
+        """Both parts' audits, and every owner holding a slot AND blocks:
+        the K/V pool's keys, ``slots`` the state pool's, ``unpaired`` the
+        owners of one and not the other (read again once: an ``allocate``
+        in flight holds its slot before its blocks)."""
+        for _ in range(2):
+            kv, slots = self.kv.audit(), self.states.audit()
+            unpaired = sorted(set(kv["owners"]) ^ set(slots["owners"]))
+            if not unpaired:
+                break
+        return dict(kv, ok=kv["ok"] and slots["ok"] and not unpaired,
+                    slots=slots, unpaired=unpaired)
+
+    def table_row(self, seq_id: Optional[str]) -> np.ndarray:
+        """(1 + max_blocks_per_seq,) int32: the slot, then the block table;
+        ``None`` is the trash slot and all trash blocks."""
+        return np.concatenate([self.states.table_row(seq_id), self.kv.table_row(seq_id)])

@@ -5,7 +5,11 @@ one scheduler.  A model without keys and values (``cache_kind == "state"``:
 ``models.brumby``) gets ``cache.StatePool`` and ``state_runner`` behind the
 same calls: a sequence owns one fixed-size recurrent state, so admission is
 a free slot, nothing grows or is preempted, and the prefix cache,
-speculation and ``tp > 1`` are refused.  ``step()`` is the whole design:
+speculation and ``tp > 1`` are refused.  A model with BOTH
+(``cache_kind == "hybrid"``: ``models.phi4flash``) gets ``cache.HybridPool``
+and ``state_runner.HybridModelRunner``: a slot of state and growing blocks
+of one K/V layer behind one ledger, admitted when both are free, preempted
+for blocks, refused the same three.  ``step()`` is the whole design:
 
 1. reap cancellations and blown deadlines;
 2. admit waiting requests into free decode slots (FIFO, memory-gated,
@@ -75,7 +79,14 @@ import numpy as np
 
 from ray_tpu._private import events as _events
 from ray_tpu._private import stream_stats as _stream_stats
-from ray_tpu.llm.cache import CacheConfig, KVBlockPool, StateConfig, StatePool
+from ray_tpu.llm.cache import (
+    CacheConfig,
+    HybridConfig,
+    HybridPool,
+    KVBlockPool,
+    StateConfig,
+    StatePool,
+)
 from ray_tpu.llm.model_runner import (
     PATCH_JOIN,
     PATCH_SET,
@@ -397,7 +408,23 @@ class LLMEngine:
             block_size=self.cfg.block_size,
             max_blocks_per_seq=self.cfg.max_blocks_per_seq,
         )
-        if getattr(model_cfg, "cache_kind", "kv") == "state":
+        cache_kind = getattr(model_cfg, "cache_kind", "kv")
+        if cache_kind == "hybrid":
+            # blocks of K/V AND a slot of state a sequence, one ledger
+            # (cache.HybridPool); the family gives both layouts
+            self._refuse_for_state_model()
+            from ray_tpu.llm.state_runner import HybridModelRunner
+
+            self.runner = HybridModelRunner(model_cfg, params, self.cfg.block_size)
+            cache_cfg = HybridConfig(
+                self.cfg.num_blocks, self.cfg.block_size,
+                self.cfg.max_blocks_per_seq, self.cfg.max_slots,
+            )
+            body = self.runner.body
+            self.pool = HybridPool(
+                cache_cfg, body.kv_layout(), body.state_leaves(self.cfg.block_size)
+            )
+        elif cache_kind == "state":
             # a model without keys and values: a sequence owns one
             # fixed-size recurrent state (cache.StatePool), admission is a
             # free slot and the length limit is the model's positions
@@ -536,7 +563,7 @@ class LLMEngine:
         self._carry = place(np.zeros((3, S), np.int32))
         self._no_patch = place(np.zeros((S, 4), np.int32))
         self._no_first = place(np.zeros(1, np.int32))
-        tables = np.zeros((S, cache_cfg.max_blocks_per_seq), np.int32)
+        tables = np.zeros((S, len(self.pool.table_row(None))), np.int32)
         knobs = pack_knobs(
             np.zeros(S), np.zeros(S), np.zeros(S), np.ones(S), np.zeros(S)
         )
@@ -557,18 +584,28 @@ class LLMEngine:
         # a state pool's own account (stats()["state_pool"]): first chunks
         # that overwrote a slot's state, decodes launched and the live rows
         # they were sent (each row's state is read and written once a layer)
-        self._state_n = {"overwrites": 0, "decodes": 0, "decode_rows": 0}
+        # and the tokens of context those rows stood at (what an attention
+        # over a sequence's own K/V reads)
+        self._state_n = {"overwrites": 0, "decodes": 0, "decode_rows": 0,
+                         "decode_tokens": 0}
+        #: the pool of fixed-size states, where the model has one: the pool
+        #: itself, or the part of a hybrid pool
+        self._states = self.pool if isinstance(self.pool, StatePool) else getattr(
+            self.pool, "states", None)
 
     def _refuse_for_state_model(self) -> None:
         """What is built on K/V blocks means nothing for a recurrent state,
         and snapshots of states, which would stand in for it, are later
         work: say so instead of serving wrong tokens."""
         what = type(self.model_cfg).__name__
+        holds = "no keys or values, only one recurrent state"
+        if getattr(self.model_cfg, "cache_kind", "") == "hybrid":
+            holds = "a recurrent state beside one layer's keys and values"
         if self.cfg.prefix_cache:
             raise ValueError(
                 f"prefix_cache=True with {what}: the radix prefix cache shares "
-                "K/V blocks, and this model keeps no keys or values, only one "
-                "recurrent state per sequence; sharing a prefix would need "
+                f"K/V blocks, and this model keeps {holds} per sequence; "
+                "sharing a prefix would need "
                 "state snapshots at block boundaries (not implemented). "
                 "Pass EngineConfig(prefix_cache=False)"
             )
@@ -1029,12 +1066,19 @@ class LLMEngine:
             }
             if self.prefix_cache is not None:
                 s["prefix_cache"] = self.prefix_cache.stats()
-            if not self.pool.paged:
+            if self._states is not None:
+                st = self._states
                 s["state_pool"] = dict(
-                    self._state_n, slots=self.pool.cfg.slots,
-                    live=led["seq_bytes"] // led["block_bytes"],
-                    bytes=led["pool_bytes"],
+                    self._state_n, slots=st.cfg.slots, live=st.num_used_blocks,
+                    bytes=st.device_bytes, kinds=st.leaf_bytes(),
                 )
+                if st is not self.pool:  # a hybrid pool: its ONE K/V layer
+                    s["kv_pool"] = {
+                        "blocks": self.pool.cfg.num_blocks - 1,
+                        "live": led["seq_bytes"] // led["block_bytes"],
+                        "block_tokens": self.pool.cfg.block_size,
+                        "bytes": led["pool_bytes"] - led["state_bytes"],
+                    }
             s["hbm"] = led
             s["retraces"] = self.runner.prof.retraces
             s["tp"] = self.cfg.tp
@@ -1067,8 +1111,10 @@ class LLMEngine:
         rep["attention"] = {
             "configured": self.cfg.attn_impl,
             # the paged kernels' dispatch rule; a state model has none
+            # (by the width of a head AS THE POOL HOLDS IT: a pair of
+            # differential heads is one)
             "auto_rule": auto_impl(
-                self.pool.cfg.block_size, self.model_cfg.head_dim
+                self.pool.cfg.block_size, self.pool.k.shape[-1]
             ) if self.pool.paged else None,
             # lowering takes seconds at full depth: outside the lock
             "mosaic_kernels": self.runner.kernels_in_steps(),
@@ -1454,6 +1500,7 @@ class LLMEngine:
             )
             self._state_n["decodes"] += 1
             self._state_n["decode_rows"] += len(rows)
+            self._state_n["decode_tokens"] += sum(r.seq_len + a for _, r, a in rows)
             return _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
 
     def _build_decode(self, rows: list) -> Optional[tuple]:
@@ -1723,6 +1770,11 @@ class LLMEngine:
             "utilization": counts["seq_owned"]
             / max(self.pool.cfg.num_blocks - 1, 1),
         }
+        if "slots_owned" in counts:
+            # a hybrid pool: the partition above is the K/V blocks'; the
+            # slots of state are allocated beside them, once
+            led["state_bytes"] = self._states.device_bytes
+            led["state_seq_bytes"] = counts["slots_owned"] * self._states.block_bytes
         if self.cfg.tp > 1:
             pool_dev = self.pool.per_device_bytes()
             par_dev = self.runner.per_device_param_bytes()

@@ -261,9 +261,12 @@ def _carry_loop(blocks, x, pools: tuple, layer_fn):
     reads that same buffer.  Each pool is ``(L, N, ...)`` and rides as the
     free ``(L * N, ...)`` view, in which layer ``l``'s entry ``n`` is ``l *
     N + n``: ``layer_fn(x, layer, *views, base) -> (x, *views)`` gets the
-    views and ``base = l * N``.  Returns (x, *pools), pools in their own
-    shape."""
-    n_layers, n = pools[0].shape[:2]
+    views and ``base = l * N``.  The loop is as long as ``blocks`` is
+    deep: a pool may hold more layers than this loop runs (the layers of
+    another segment), and with no pool at all ``base = l``.  Returns (x,
+    *pools), pools in their own shape."""
+    n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    n = pools[0].shape[1] if pools else 1
 
     def body(carry, inputs):
         layer, base = inputs
@@ -271,7 +274,7 @@ def _carry_loop(blocks, x, pools: tuple, layer_fn):
 
     (x, *views), _ = jax.lax.scan(
         body,
-        (x, *(p.reshape((n_layers * n,) + p.shape[2:]) for p in pools)),
+        (x, *(p.reshape((-1,) + p.shape[2:]) for p in pools)),
         (blocks, jnp.arange(n_layers, dtype=jnp.int32) * n),
     )
     return (x, *(v.reshape(p.shape) for v, p in zip(views, pools)))

@@ -24,6 +24,17 @@ that has two: ``decode_step(state, carry, first_tok, patch, tables, knobs)
 start, n_valid, table, sampling) -> (state, logits, token, logprob)``.
 There is no verify step and no block fork: ``LLMEngine`` refuses
 speculation, the prefix cache and ``tp > 1`` for such a model.
+
+``HybridModelRunner`` is the same for a model whose sequences hold blocks
+of K/V AND a slot of state (``cache_kind == "hybrid"``,
+``cache.HybridPool``; ``models.phi4flash`` is the one such family): the
+body gives the whole layer program of each step, ``decode(params, x,
+arrays, positions, tables) -> (hidden, arrays)`` and ``chunk(params, x,
+arrays, start, n_valid, table) -> (last hidden, arrays)``, because its
+layers are of several kinds in several loops; ``arrays`` is the pool's
+tuple, every one donated and handed back in its place, and a table row is
+``[slot, block table...]``.  A dead decode row feeds position 0 of the
+trash slot and the trash block.
 """
 
 from __future__ import annotations
@@ -103,5 +114,58 @@ class StateModelRunner(StepRunner):
         return self._call(
             "prefill", self._prefill, len(tokens),
             self.params, state, tokens,
+            np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
+        )
+
+
+class HybridModelRunner(StepRunner):
+    arch = "hybrid"
+
+    def __init__(self, cfg: Any, params: dict, block_size: int):
+        super().__init__(cfg, params)
+        self.body = cfg.serving_body()
+        # the K/V pool's two arrays and one a kind of state
+        self.n_arrays = n = 2 + len(self.body.state_leaves(block_size))
+        pools = tuple(range(1, 1 + n))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=pools + (1 + n,))
+        self._prefill = jax.jit(
+            self._prefill_impl, donate_argnums=pools, static_argnames=("chunk",)
+        )
+
+    def _decode_logits(self, params, arrays, tokens, positions, tables):
+        """The model's part of a decode.  Returns (arrays, logits (S, V))."""
+        body = self.body
+        x, arrays = body.decode(params, body.embed(params, tokens), arrays, positions, tables)
+        return arrays, body.lm_head(params, x)
+
+    def _decode_impl(self, params, *rest):
+        """rest: the pool's arrays, then ``carry, first_tok, patch, tables,
+        knobs`` as ``PagedModelRunner._decode_impl``, ``tables`` (S, 1 + T)."""
+        arrays, (carry, first_tok, patch, tables, knobs) = (
+            rest[:self.n_arrays], rest[self.n_arrays:])
+        tokens, positions, counters = _merge_slots(carry, first_tok, patch)
+        arrays, logits = self._decode_logits(params, arrays, tokens, positions, tables)
+        live, nxt, logp = _decode_sample(logits, knobs, counters)
+        return (*arrays, _advance_slots(live, nxt, positions, counters), nxt, logp)
+
+    def decode_step(self, *operands):
+        return self._call(
+            "decode", self._decode, jnp.shape(operands[-2])[0], self.params, *operands)
+
+    def _prefill_impl(self, params, *rest, chunk: int):
+        arrays, (tokens, start, n_valid, table, sampling) = (
+            rest[:self.n_arrays], rest[self.n_arrays:])
+        body = self.body
+        last, arrays = body.chunk(
+            params, body.embed(params, tokens), arrays, start, n_valid, table)
+        logits = body.lm_head(params, last)[0]  # (V,)
+        tok, logp = _prefill_sample(logits, sampling)
+        return (*arrays, logits, tok, logp)
+
+    def prefill_chunk(self, *operands):
+        *arrays, tokens, start, n_valid, table, sampling = operands
+        return self._call(
+            "prefill", self._prefill, len(tokens),
+            self.params, *arrays, tokens,
             np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
         )

@@ -59,37 +59,36 @@ def _seeded_params(init, cfg, seed: int, tp: int):
     return jax.jit(make, out_shardings=shardings)()
 
 
+#: served families: name -> (module, configuration class, initializer, what
+#: stands in for a missing ``model_cfg``: the class itself, or an instance)
+_FAMILIES = {
+    "gptj": ("ray_tpu.models.gptj", "GPTJConfig", "gptj_init", "GPTJ_6B"),
+    "gpt": ("ray_tpu.models.gpt", "GPTConfig", "gpt_init", "GPTConfig"),
+    "brumby": ("ray_tpu.models.brumby", "BrumbyConfig", "brumby_init", "BrumbyConfig"),
+    "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig", "phi4flash_init",
+                  "Phi4FlashConfig"),
+}
+
+
 def _build_model(model: str, model_cfg, params, seed: int, tp: int = 1):
     """Materialize (cfg, params) inside the replica — shipping a seed
     instead of a parameter pytree keeps deployment specs small and lets
     each replica initialize straight onto its own device(s)."""
-    if model == "gptj":
-        from ray_tpu.models.gptj import GPTJ_6B, GPTJConfig, gptj_init
+    import importlib
 
-        cfg = model_cfg or GPTJ_6B
-        if not isinstance(cfg, GPTJConfig):
-            raise TypeError(f"model_cfg must be a GPTJConfig, got {type(cfg).__name__}")
-        init = gptj_init
-    elif model == "gpt":
-        from ray_tpu.models.gpt import GPTConfig, gpt_init
-
-        cfg = model_cfg or GPTConfig()
-        if not isinstance(cfg, GPTConfig):
-            raise TypeError(f"model_cfg must be a GPTConfig, got {type(cfg).__name__}")
-        init = gpt_init
-    elif model == "brumby":
-        from ray_tpu.models.brumby import BrumbyConfig, brumby_init
-
-        cfg = model_cfg or BrumbyConfig()
-        if not isinstance(cfg, BrumbyConfig):
-            raise TypeError(f"model_cfg must be a BrumbyConfig, got {type(cfg).__name__}")
-        init = brumby_init
-    else:
+    if model not in _FAMILIES:
         raise ValueError(
-            f"unknown model family {model!r}; expected 'gptj', 'gpt' or 'brumby'"
+            f"unknown model family {model!r}; expected one of "
+            + ", ".join(repr(name) for name in _FAMILIES)
         )
+    module, cfg_name, init_name, default = _FAMILIES[model]
+    mod = importlib.import_module(module)
+    cfg_cls, default = getattr(mod, cfg_name), getattr(mod, default)
+    cfg = model_cfg or (default() if isinstance(default, type) else default)
+    if not isinstance(cfg, cfg_cls):
+        raise TypeError(f"model_cfg must be a {cfg_name}, got {type(cfg).__name__}")
     if params is None:
-        params = _seeded_params(init, cfg, seed, tp)
+        params = _seeded_params(getattr(mod, init_name), cfg, seed, tp)
     return cfg, params
 
 

@@ -1,0 +1,52 @@
+"""The main path's Pallas kernels compiled by Mosaic for a v5e at real
+widths, WITHOUT the chip: libtpu is installed, and the TPU's compiler
+compiles for a chip that is described and not attached.  What interpret mode
+cannot show (a slice off the tiling, too much fast memory) fails here and
+costs no chip time.  Nothing runs, so nothing here is a number of the device.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process may load the TPU's library, and every xdist
+worker imports every test file.  All such tests live in THIS file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops import diff_attention as da
+from ray_tpu.ops import paged_attention as pa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("what,blocks,tmax", [
+    # phi4-mini-flash-1chip, 32 rows: the ONE shared K/V layer's pool and table
+    ("shared_kv", 32 * 144 + 1, 144),
+    # one window layer's rings as a pool: 33 slots of 512 / 16 blocks, a fixed table
+    ("ring", 33 * 32, 32),
+])
+def test_differential_pairs_through_the_paged_kernel_compile_for_a_v5e(
+        one_chip, monkeypatch, what, blocks, tmax):
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+    rows, heads, kv, e, block = 32, 40, 20, 64, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((blocks, kv // 2, block, 2 * e), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, p: da.diff_paged_attention(q, k, v, t, p, kv, impl="pallas")
+    ).lower(sds((rows, heads, e), jnp.bfloat16), pool, pool,
+            sds((rows, tmax), jnp.int32), sds((rows,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pools stay where they are: no gathered copy of a table's blocks
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
