@@ -11,13 +11,19 @@ Grid layout: ``(bh, q_block, kv_block)`` with the KV dimension minor — TPU
 grids execute the minor dimension sequentially, so VMEM scratch accumulators
 (acc/m/l for forward, dq / dk+dv for backward) carry across KV (resp. Q)
 steps of one output block and are flushed on the block's last step.
-Causally-dead (q, kv) cells are skipped with ``pl.when``.
+Causally-dead (q, kv) cells are skipped with ``pl.when``; a cell the
+diagonal crosses is walked in sub-tiles, and a sub-tile the mask kills
+whole is never issued (``_live_tiles``); only sub-tiles the diagonal
+crosses build a mask.
 
-TPU tiling notes: per-row stats (logsumexp, delta) live as ``(bh, 8, seq)``
-— value broadcast over 8 sublanes so the (sublane, lane) block shape
-``(8, block_q)`` satisfies Mosaic's (8, 128) fp32 tile constraint. Sequence
-lengths must tile by 128 on the TPU path (the public entry raises
-otherwise; ``ops.attention.auto_impl`` routes such shapes to XLA).
+TPU tiling notes: per-row stats (logsumexp, delta) live in HBM as
+``(bh, 8, seq)`` — value broadcast over 8 sublanes so the (sublane, lane)
+block shape ``(8, block_q)`` satisfies Mosaic's (8, 128) fp32 tile
+constraint. Inside the forward the running max and denominator are
+``(rows, 1)`` columns, the layout a reduction over a score tile's columns
+leaves them in (a change of layout a tile made the forward twice as
+slow). Sequence lengths must tile by 128 on the TPU path (the public entry
+raises otherwise; ``ops.attention.auto_impl`` routes such shapes to XLA).
 
 This is the hot op behind ``ray_tpu.ops.attention.causal_attention`` — the
 reference has no attention kernel of its own (user torch code runs inside
@@ -30,6 +36,7 @@ code paths.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,10 +50,144 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _causal_mask(q_start, k_start, block_q, block_k):
-    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return cols <= rows
+# ---------------------------------------------------------------------------
+# sub-tiles: what of a grid cell is issued at all
+# ---------------------------------------------------------------------------
+
+#: Edges (q rows, kv columns) of the sub-tiles that a grid cell ON the
+#: diagonal is walked in.  The grid block stays fat (one head's whole
+#: sequence where it fits: the grid's per-step cost, see
+#: ``flash_attention``); the causal skipping happens INSIDE it: 10 of 16
+#: sub-tiles of a 1024 x 1024 cell hold a live score, the other 6 are never
+#: issued.  A cell wholly below the diagonal has nothing to skip and runs
+#: as ONE tile.  Measured on a v5e, ms a layer forward + dQ + dK/dV, at
+#: bf16[416,1024,64] / [64,2048,64] / [16,4096,64] (PERF.md section 6,
+#: PR 44): 256 x 256 3.79 / 2.69 / 2.13; 128 x 256 3.91 / 2.80 / 2.19;
+#: 256 x 512 4.27 / 2.77 / 2.17; 512 x 512 4.29 / 2.78 / 2.18;
+#: 256 x 128 4.38 / 2.95 / 2.26 and 128 x 128 4.76 / 3.03 / 2.30 (products
+#: too small to keep an MXU's weights loaded); no sub-tiles 5.37 / 2.84 /
+#: 2.21; before PR 44 7.03 / 3.49 / 2.73.  Head width 64; no other width
+#: has been measured.
+_SUB_Q, _SUB_K = 256, 256
+
+
+def _sub_tiles(block_q: int, block_k: int) -> tuple[int, int]:
+    """The sub-tile of a grid block: the constants above where they divide
+    the block, else the largest edge that does (a block smaller than a
+    sub-tile is one sub-tile)."""
+    return math.gcd(block_q, _SUB_Q), math.gcd(block_k, _SUB_K)
+
+
+def _diag_offsets(block_q: int, block_k: int) -> list[int]:
+    """Every ``q_start - k_start`` of a grid cell the diagonal crosses: the
+    cell holds a live score (``k_start <= q_start + block_q - 1``) AND a dead
+    one (``k_start + block_k - 1 > q_start``).  A cell further below the
+    diagonal is live whole, one further above it is dead whole."""
+    g = math.gcd(block_q, block_k)  # every offset is a multiple of it
+    return list(range(g - block_q, block_k - 1, g))
+
+
+def _live_tiles(block_q, block_k, sub_q, sub_k, off):
+    """``(i, j, crossed)`` of every sub-tile of one grid cell that holds a
+    live score, row-major: the products a kernel body issues.  ``off`` is
+    the cell's ``q_start - k_start`` where the diagonal crosses it and None
+    for a cell wholly below the diagonal; ``crossed`` says the sub-tile also
+    holds a dead score and needs the mask."""
+    tiles = []
+    for i in range(block_q // sub_q):
+        for j in range(block_k // sub_k):
+            if off is None:
+                tiles.append((i, j, False))
+                continue
+            r0, c0 = off + i * sub_q, j * sub_k  # first row, first column
+            if c0 <= r0 + sub_q - 1:             # else dead whole: never issued
+                tiles.append((i, j, c0 + sub_k - 1 > r0))
+    return tiles
+
+
+def _walk_cell(q_start, k_start, block_q, block_k, seq, walk):
+    """Run ``walk`` for the ONE case this grid cell is, decided by
+    ``program_id`` alone: ``walk(off, sub_q, sub_k)`` where the diagonal
+    crosses it at the static offset ``off``, in sub-tiles;
+    ``walk(None, block_q, block_k)`` where it is live whole, as one tile.
+    A dead cell runs nothing.  A case that no cell of this grid can be
+    (``seq`` is static) is not traced at all: where the sequence is one
+    block, the diagonal cell is the only one."""
+    off = q_start - k_start
+    for o in _diag_offsets(block_q, block_k):
+        if block_k - seq <= o <= seq - block_q:
+            pl.when(off == o)(functools.partial(walk, o, *_sub_tiles(block_q, block_k)))
+    if seq - block_q >= block_k - 1:
+        pl.when(off >= block_k - 1)(functools.partial(walk, None, block_q, block_k))
+
+
+def _tile_mask(shape, diag, transposed=False):
+    """col <= row inside a sub-tile whose first row stands ``diag`` below
+    its first column (``diag = off + i * sub_q - j * sub_k``, static):
+    (sub_q, sub_k), or (sub_k, sub_q) for a tile of transposed scores."""
+    q_axis = 1 if transposed else 0
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return cols - rows <= diag
+
+
+def _by(tiles, axis):
+    """Group ``_live_tiles`` by q sub-tile (axis 0) or kv sub-tile (axis 1):
+    ``{outer: [(inner, crossed), ...]}`` in issue order."""
+    groups: dict[int, list] = {}
+    for t in tiles:
+        groups.setdefault(t[axis], []).append((t[1 - axis], t[2]))
+    return groups
+
+
+def _f32_rows(ref, sub):
+    """``load(n)``: sub-tile ``n`` of a (1, block, d) ref as float32, made
+    once however many sub-tiles of the other axis meet it."""
+    return functools.cache(lambda n: ref[0, pl.ds(n * sub, sub), :].astype(jnp.float32))
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# One sub-tile's work is a jitted function of VALUES: jax traces it once a
+# (shape, ``diag``) and a kernel body that issues it ten times holds ten
+# calls, not ten copies (the kernels are traced four times a train step, and
+# unrolled copies cost the cell 2 s of set-up).  Mosaic inlines the calls.
+# ``diag`` is None for a sub-tile the diagonal does not cross: no mask.
+
+
+@functools.partial(jax.jit, static_argnames=("diag",))
+def _fwd_tile(q, k, v, m, l, acc, *, diag):
+    """The online softmax of a q sub-tile over one more kv sub-tile."""
+    s = _dot(q, k, _NT)  # (sub_q, sub_k)
+    if diag is not None:
+        s = jnp.where(_tile_mask(s.shape, diag), s, NEG_INF)
+    m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l = l * corr + p.sum(axis=1, keepdims=True)
+    return m_new, l, acc * corr + _dot(p, v, _NN)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "diag", "transposed"))
+def _p_and_ds(q, k, v, do, lse, delta, *, scale, diag, transposed=False):
+    """One sub-tile's softmax weights and score gradients, recomputed from
+    the forward's row statistics: (sub_q, sub_k) with ``lse`` / ``delta`` as
+    (sub_q, 1) columns, or TRANSPOSED, (sub_k, sub_q) with them as
+    (1, sub_q) rows: the same products, element for element."""
+    if transposed:
+        s, dp = _dot(k, q, _NT), _dot(v, do, _NT)
+    else:
+        s, dp = _dot(q, k, _NT), _dot(do, v, _NT)
+    p = jnp.exp(scale * s - lse)
+    if diag is not None:
+        p = jnp.where(_tile_mask(p.shape, diag, transposed), p, 0.0)
+    return p, p * (dp - delta) * scale
 
 
 # ---------------------------------------------------------------------------
@@ -54,49 +195,61 @@ def _causal_mask(q_start, k_start, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, seq):
     """Grid (bh, qi, kj), kj minor/sequential. Scratch carries the online
-    softmax state across kj steps of one q block."""
+    softmax state across kj steps of one q block; inside a step it is held
+    in values, a q sub-tile at a time, across the kv sub-tiles it meets.
+    The row statistics are (rows, 1) columns throughout (the layout a
+    reduction over a score tile's columns leaves them in): the one change
+    of layout is ``lse``'s, into the lanes of its output, once a q block.
+    Where the kv grid has ONE step (``seq == block_k``) the state never
+    touches the scratch."""
     qi, kj = pl.program_id(1), pl.program_id(2)
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
+    block_q, block_k, d = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
     q_start = qi * block_q
     k_start = kj * block_k
     j_last = (q_start + block_q - 1) // block_k  # last causally-live kv block
+    one_step = seq == block_k
 
-    @pl.when(k_start <= q_start + block_q - 1)  # skip causally-dead cells
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse = (m + jnp.log(l)).T  # (1, rows): rows into lanes
+        lse_ref[0, :, rows] = jnp.broadcast_to(lse, (lse_ref.shape[1], lse.shape[1]))
+
+    def walk(off, sub_q, sub_k):
+        k_of, v_of = _f32_rows(k_ref, sub_k), _f32_rows(v_ref, sub_k)
+        for i, kv_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 0).items():
+            rows = pl.ds(i * sub_q, sub_q)
+            q = q_ref[0, rows, :].astype(jnp.float32) * scale
+            if one_step:
+                m = jnp.full((sub_q, 1), NEG_INF, jnp.float32)
+                l = jnp.zeros((sub_q, 1), jnp.float32)
+                acc = jnp.zeros((sub_q, d), jnp.float32)
+            else:
+                m, l, acc = m_sc[rows, :], l_sc[rows, :], acc_sc[rows, :]
+            for j, crossed in kv_tiles:
+                diag = off + i * sub_q - j * sub_k if crossed else None
+                m, l, acc = _fwd_tile(q, k_of(j), v_of(j), m, l, acc, diag=diag)
+            if one_step:
+                finish(rows, m, l, acc)
+            else:
+                m_sc[rows, :], l_sc[rows, :], acc_sc[rows, :] = m, l, acc
+
+    if one_step:
+        return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+
+    @pl.when(kj == 0)
     def _():
-        @pl.when(kj == 0)
-        def _():
-            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-            l_sc[:] = jnp.zeros_like(l_sc)
-            acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+        acc_sc[:] = jnp.zeros_like(acc_sc)
 
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-        s = jnp.where(_causal_mask(q_start, k_start, block_q, block_k), s, NEG_INF)
-        m_prev = m_sc[0]
-        l_prev = l_sc[0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=1)
-        acc_sc[:] = acc_sc[:] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_sc[:] = jnp.broadcast_to(m_new[None, :], m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new[None, :], l_sc.shape)
+    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
 
-        @pl.when(kj == j_last)
-        def _():
-            l = jnp.maximum(l_sc[0], 1e-30)
-            o_ref[0] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
-            lse = m_sc[0] + jnp.log(l)
-            lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+    @pl.when(kj == j_last)
+    def _():
+        finish(slice(None), m_sc[:], l_sc[:], acc_sc[:])
 
 
 def _flash_fwd(q, k, v, *, block_q, block_k):
@@ -104,7 +257,7 @@ def _flash_fwd(q, k, v, *, block_q, block_k):
     scale = 1.0 / (d**0.5)
     grid = (bh, seq // block_q, seq // block_k)
     out, lse8 = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale),
+        functools.partial(_fwd_kernel, scale=scale, seq=seq),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -120,8 +273,8 @@ def _flash_fwd(q, k, v, *, block_q, block_k):
             jax.ShapeDtypeStruct((bh, 8, seq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((8, block_q), jnp.float32),   # running max (broadcast)
-            pltpu.VMEM((8, block_q), jnp.float32),   # running denom
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max, a column
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=_interpret(),
@@ -135,94 +288,97 @@ def _flash_fwd(q, k, v, *, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *, scale):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *, scale, seq):
     qi, kj = pl.program_id(1), pl.program_id(2)
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
+    block_q, block_k, d = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
     q_start, k_start = qi * block_q, kj * block_k
     j_last = (q_start + block_q - 1) // block_k
+    one_step = seq == block_k  # ONE kv step: dq never touches the scratch
 
-    @pl.when(k_start <= q_start + block_q - 1)
+    def walk(off, sub_q, sub_k):
+        k_of, v_of = _f32_rows(k_ref, sub_k), _f32_rows(v_ref, sub_k)
+        for i, kv_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 0).items():
+            rows = pl.ds(i * sub_q, sub_q)
+            q, do = q_ref[0, rows, :].astype(jnp.float32), do_ref[0, rows, :].astype(jnp.float32)
+            lse, delta = lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None]  # columns
+            dq = jnp.zeros((sub_q, d), jnp.float32) if one_step else dq_sc[rows, :]
+            for j, crossed in kv_tiles:
+                diag = off + i * sub_q - j * sub_k if crossed else None
+                _, ds = _p_and_ds(q, k_of(j), v_of(j), do, lse, delta, scale=scale, diag=diag)
+                dq = dq + _dot(ds, k_of(j), _NN)
+            if one_step:
+                dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+            else:
+                dq_sc[rows, :] = dq
+
+    if one_step:
+        return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+
+    @pl.when(kj == 0)
     def _():
-        @pl.when(kj == 0)
-        def _():
-            dq_sc[:] = jnp.zeros_like(dq_sc)
+        dq_sc[:] = jnp.zeros_like(dq_sc)
 
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        p = jnp.where(
-            _causal_mask(q_start, k_start, block_q, block_k),
-            jnp.exp(s - lse[:, None]),
-            0.0,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dq_sc[:] = dq_sc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
 
-        @pl.when(kj == j_last)
-        def _():
-            dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+    @pl.when(kj == j_last)
+    def _():
+        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale
+    k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale, seq
 ):
     """Grid (bh, kb, qi), qi minor/sequential; accumulates dk/dv for one kv
-    block across its causally-live q blocks."""
+    block across its causally-live q blocks, a kv sub-tile at a time in
+    values across the q sub-tiles it meets inside a step.  The scores are
+    made TRANSPOSED, (sub_k, sub_q): ``lse`` and ``delta`` then broadcast
+    from the lanes they are stored in, and ``p^T do`` / ``ds^T q`` are
+    plain products with no transpose of a score tile."""
     kb, qi = pl.program_id(1), pl.program_id(2)
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
-    block_q = q_ref.shape[1]
+    block_k, block_q, d = k_ref.shape[1], q_ref.shape[1], k_ref.shape[2]
     k_start, q_start = kb * block_k, qi * block_q
     i_first = k_start // block_q     # first q block the diagonal touches
     n_q = pl.num_programs(2)
+    one_step = seq == block_q  # ONE q step: dk, dv never touch the scratch
 
-    @pl.when(q_start + block_q - 1 >= k_start)
+    def walk(off, sub_q, sub_k):
+        q_of, do_of = _f32_rows(q_ref, sub_q), _f32_rows(do_ref, sub_q)
+        for j, q_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 1).items():
+            cols = pl.ds(j * sub_k, sub_k)
+            k, v = k_ref[0, cols, :].astype(jnp.float32), v_ref[0, cols, :].astype(jnp.float32)
+            if one_step:
+                dk = dv = jnp.zeros((sub_k, d), jnp.float32)
+            else:
+                dk, dv = dk_sc[cols, :], dv_sc[cols, :]
+            for i, crossed in q_tiles:
+                rows = pl.ds(i * sub_q, sub_q)
+                diag = off + i * sub_q - j * sub_k if crossed else None
+                p, ds = _p_and_ds(  # (sub_k, sub_q); lse, delta: (1, sub_q) rows
+                    q_of(i), k, v, do_of(i), lse_ref[0, :, rows], delta_ref[0, :, rows],
+                    scale=scale, diag=diag, transposed=True,
+                )
+                dv = dv + _dot(p, do_of(i), _NN)
+                dk = dk + _dot(ds, q_of(i), _NN)
+            if one_step:
+                dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
+                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+            else:
+                dk_sc[cols, :], dv_sc[cols, :] = dk, dv
+
+    if one_step:
+        return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+
+    @pl.when(qi == i_first)
     def _():
-        @pl.when(qi == i_first)
-        def _():
-            dk_sc[:] = jnp.zeros_like(dk_sc)
-            dv_sc[:] = jnp.zeros_like(dv_sc)
+        dk_sc[:] = jnp.zeros_like(dk_sc)
+        dv_sc[:] = jnp.zeros_like(dv_sc)
 
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-        p = jnp.where(
-            _causal_mask(q_start, k_start, block_q, block_k),
-            jnp.exp(s - lse[:, None]),
-            0.0,
-        )
-        dv_sc[:] = dv_sc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dk_sc[:] = dk_sc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
 
-        @pl.when(qi == n_q - 1)
-        def _():
-            dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+    @pl.when(qi == n_q - 1)
+    def _():
+        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
@@ -232,7 +388,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
     delta = delta[:, None, :]  # (bh, 1, seq)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale),
+        functools.partial(_dq_kernel, scale=scale, seq=seq),
         grid=(bh, seq // block_q, seq // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -250,7 +406,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale),
+        functools.partial(_dkv_kernel, scale=scale, seq=seq),
         grid=(bh, seq // block_k, seq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
@@ -321,20 +477,6 @@ def _flash_core_bwd(block_q, block_k, block_q_bwd, block_k_bwd, res, do):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _env_block(name: str, default: int) -> int:
-    import os
-
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        return int(raw)
-    except ValueError:
-        # a typo'd sweep var must fail loudly, or every sweep point silently
-        # benchmarks the identical default configuration
-        raise ValueError(f"{name}={raw!r} is not an integer block size") from None
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -347,27 +489,23 @@ def flash_attention(
     """Causal flash attention. q,k,v: (batch, heads, seq, head_dim).
 
     O(seq) HBM / O(block) VMEM; differentiable (custom VJP with
-    blockwise-recompute backward). Forward and backward block shapes tune
-    independently (the dQ/dKV kernels have different reuse patterns than the
-    forward); defaults are overridable via RAY_TPU_FLASH_{BQ,BK,BQB,BKB} for
-    sweeps. On TPU the blocks must tile by 128 (Mosaic lane constraint) —
+    blockwise-recompute backward). Forward and backward grid blocks may
+    differ (the dQ/dKV kernels have different reuse patterns than the
+    forward). On TPU the blocks must tile by 128 (Mosaic lane constraint) —
     anything else raises; interpret mode (CPU CI) accepts any
     power-of-two-friendly blocking.
     """
     b, h, s, d = q.shape
-    # Default 1024×1024 measured fastest on v5e at (bh 256, s 1024, d 64):
-    # fewer, fatter grid steps win — the kernel is latency-bound per step at
-    # small head_dim, not VMEM-bound (sweep: 4.1 ms/layer at 256×512 →
-    # 2.6 ms at 1024×1024; jax's own tuned kernel measures 2.2 at this
-    # shape). _pick_blocks clamps to the actual sequence length.
-    block_q = block_q if block_q is not None else _env_block("RAY_TPU_FLASH_BQ", 1024)
-    block_k = block_k if block_k is not None else _env_block("RAY_TPU_FLASH_BK", 1024)
-    block_q_bwd = (
-        block_q_bwd if block_q_bwd is not None else _env_block("RAY_TPU_FLASH_BQB", block_q)
-    )
-    block_k_bwd = (
-        block_k_bwd if block_k_bwd is not None else _env_block("RAY_TPU_FLASH_BKB", block_k)
-    )
+    # Grid blocks of 1024×1024 measured fastest on v5e at (bh 256, s 1024,
+    # d 64): fewer, fatter grid steps win — the kernel is latency-bound per
+    # step at small head_dim, not VMEM-bound (sweep: 4.1 ms/layer at 256×512
+    # → 2.6 ms at 1024×1024). The causally dead part of a fat block is
+    # skipped inside the step, in sub-tiles (``_sub_tiles``). _pick_blocks
+    # clamps to the actual sequence length.
+    block_q = block_q if block_q is not None else 1024
+    block_k = block_k if block_k is not None else 1024
+    block_q_bwd = block_q_bwd if block_q_bwd is not None else block_q
+    block_k_bwd = block_k_bwd if block_k_bwd is not None else block_k
     bq, bk = _pick_blocks(s, block_q, block_k)
     bqb, bkb = _pick_blocks(s, block_q_bwd, block_k_bwd)
     if not _interpret() and (bq % 128 or bk % 128 or bqb % 128 or bkb % 128):

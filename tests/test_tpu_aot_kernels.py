@@ -50,3 +50,21 @@ def test_differential_pairs_through_the_paged_kernel_compile_for_a_v5e(
     assert "tpu_custom_call" in compiled.as_text()
     # the pools stay where they are: no gathered copy of a table's blocks
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+@pytest.mark.parametrize("bh,seq", [
+    (416, 1024),  # gpt2m_train: batch 26 x 16 heads, ONE grid cell a head, the diagonal one
+    (16, 4096),   # a 4 x 4 grid: cells below the diagonal run whole, with no mask
+])
+def test_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch, bh, seq):
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)  # Mosaic, not the interpreter
+    x = jax.ShapeDtypeStruct((1, bh, seq, 64), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
