@@ -1,0 +1,25 @@
+"""The expert layers' share of their roofline in decode.  At 16 rows they
+are weight reads: the least time is the bytes the mathematics reads (the
+family's ``moe_decode_bytes``: every layer's router and shared expert, and an
+expert for every held expert that at least one row chose, the program's own
+count a decode) over the chip's HBM bandwidth; the time taken is the device
+time of every leaf op under the three ``moe_*`` scopes in the slice over the
+decode programs executed.  An implementation that read all held experts
+whatever the load could read ``bytes(touched) / bytes(held)`` at best."""
+
+from _common import family_piece
+from _latent_decode import occupancy, scope_ms
+from moe_decode_dev_ms import SCOPES
+
+
+def read(run):
+    if not run.get("peaks"):
+        return None  # a rehearsal has no chip to compare with
+    live = occupancy(run)
+    if live is None:
+        return None
+    need = family_piece(run["config"], "moe_decode_bytes")(live["touched"], run["model"])
+    ms = scope_ms(run, SCOPES, moe_bytes=need)
+    if not ms:
+        return None
+    return 100.0 * (need / run["peaks"]["hbm_bytes_per_s"]) / (ms * 1e-3)

@@ -466,6 +466,61 @@ def phase_kernels(args) -> None:
         ["retention_decode"],
     )
 
+    # latent attention at Kimi-K2.5's widths: 64 heads over ONE row of 512 +
+    # 64 lanes (padded to 640) a token, blocks of 128, a table of 138: the
+    # absorbed decode kernel against its XLA path (ragged rows as above), and
+    # the expert layer's tiles against every held expert on every row
+    from ray_tpu.ops import latent_attention as la, moe
+
+    heads, rank, rope, bs, slots, tmax, nb = (
+        (64, 512, 64, 128, 16, 138, 600) if on_chip else (4, 128, 8, 4, 4, 6, 24))
+    width = la.padded_width(rank, rope)
+    if on_chip:
+        check(la.auto_impl(bs, width, rank) == "pallas", "latent auto rule")
+    rows_pool = rnd(11, (layers * nb, bs, width)).at[..., rank + rope:].set(0)
+    tables = layer * nb + jax.random.randint(jax.random.PRNGKey(12), (slots, tmax), 1, nb)
+    cap = tmax * bs
+    at = jnp.zeros(slots, jnp.int32).at[jnp.arange(4) * (slots // 4)].set(
+        jnp.array([0, bs + 1, cap // 2 + 3, cap - 1], jnp.int32))
+    q_abs = (rnd(13, (slots, heads, width)) * width**-0.25).at[..., rank + rope:].set(0)
+
+    def latent(impl):
+        return lambda q, pool, t, p: la.latent_decode_attention(
+            q, pool, t, p, rank=rank, scale=width**-0.5, impl=impl)
+
+    compare(
+        f"latent_decode_h{heads}_r{rank}+{rope}_b{bs}_s{slots}_t{tmax}",
+        latent("auto" if on_chip else "pallas"), latent("xla"),
+        (q_abs, rows_pool, tables, at), ["latent_attention_decode"],
+    )
+    # the chunk's flash kernel against the dense softmax: 512 queries that
+    # start inside a block and inside a key tile, over the same table
+    c_len, dn, dv = (512, 128, 128) if on_chip else (8, 8, 16)
+    for start in (0, 5 * bs + 3) if on_chip else (bs + 1,):
+        def chunk(impl, start=start):
+            return lambda qn, qr, pool, wk, wv: la.latent_chunk_attention(
+                qn, qr, pool, tables[0], start + jnp.arange(c_len, dtype=jnp.int32), wk, wv,
+                rank=rank, scale=(dn + rope)**-0.5, impl=impl)
+
+        compare(
+            f"latent_chunk_h{heads}_c{c_len}_t{tmax}x{bs}_at{start}",
+            chunk("auto" if on_chip else "pallas"), chunk("xla"),
+            (rnd(20, (c_len, heads, dn)), rnd(21, (c_len, heads, rope)), rows_pool,
+             rnd(22, (rank, heads, dn)) * rank**-0.5, rnd(23, (rank, heads, dv)) * rank**-0.5),
+            ["latent_attention_chunk"],
+        )
+    d, f, held, n = (7168, 2048, 12, 16) if on_chip else (32, 16, 4, 6)
+    mask = jax.random.bernoulli(jax.random.PRNGKey(14), 0.25, (n, held)).at[:, 1].set(False)
+    wmat = jnp.where(mask, jax.random.uniform(jax.random.PRNGKey(15), (n, held)), 0.0)
+    compare(
+        f"moe_experts_d{d}_f{f}_held{held}_rows{n}",
+        lambda x, g, u, dn: moe.expert_layer(x, mask, wmat, g, u, dn),
+        lambda x, g, u, dn: sum(
+            wmat[:, e:e + 1] * moe.swiglu(x, g[e], u[e], dn[e]) for e in range(held)),
+        (rnd(16, (n, d)), rnd(17, (held, d, f)) * d**-0.5, rnd(18, (held, d, f)) * d**-0.5,
+         rnd(19, (held, f, d)) * f**-0.5), [],
+    )
+
     # flash forward + backward at the train shape, then at tp=4's heads
     flash_shapes = ((26, 16, 1024, 64), (26, 4, 1024, 64))
     for b, h, s, d in flash_shapes if on_chip else ((2, 2, 128, 16),):

@@ -88,6 +88,7 @@ class KVBlockPool:
         head_dim: int,
         dtype="float32",
         sharding=None,
+        values: bool = True,
     ):
         import jax.numpy as jnp
 
@@ -99,7 +100,10 @@ class KVBlockPool:
         # it; the host ledger below is unchanged — block ids are global,
         # every device holds the same blocks' local heads
         self.k = jnp.zeros(shape, jnp.dtype(dtype), device=sharding)
-        self.v = jnp.zeros(shape, jnp.dtype(dtype), device=sharding)
+        # ``values=False``: a block holds ONE array, whatever the family
+        # says a token leaves behind (``models.kimi_k2``: a latent row that
+        # is key and value at once); the ledger below does not care
+        self.v = jnp.zeros(shape, jnp.dtype(dtype), device=sharding) if values else None
         self._lock = threading.Lock()
         # LIFO free list of physical block ids; 0 reserved (trash)
         self._free = list(range(cfg.num_blocks - 1, 0, -1))
@@ -111,14 +115,20 @@ class KVBlockPool:
         self._ref: dict[int, int] = {}
         self._cache_held: set[int] = set()
 
+    @staticmethod
+    def n_arrays(values: bool = True, **_layout) -> int:
+        """How many device arrays a pool of this layout holds (a step
+        program's donated arguments are counted before there is a pool)."""
+        return 2 if values else 1
+
     @property
     def arrays(self) -> tuple:
         """The device arrays a jitted step takes (donated) and hands back."""
-        return self.k, self.v
+        return (self.k,) if self.v is None else (self.k, self.v)
 
     @arrays.setter
     def arrays(self, new) -> None:
-        self.k, self.v = new
+        self.k, self.v = new if self.v is not None else (*new, None)
 
     # -- capacity ----------------------------------------------------------
 
@@ -130,13 +140,13 @@ class KVBlockPool:
         """Device bytes ONE physical block occupies across both pool
         arrays and every layer (k + v) — the unit the HBM ledger gauges
         multiply block counts by."""
-        return (self.k.nbytes + self.v.nbytes) // self.cfg.num_blocks
+        return self.device_bytes // self.cfg.num_blocks
 
     @property
     def device_bytes(self) -> int:
         """Total device footprint of the pool arrays (k + v), trash
         block included — allocated once at engine start, never resized."""
-        return self.k.nbytes + self.v.nbytes
+        return sum(a.nbytes for a in self.arrays)
 
     @property
     def num_free_blocks(self) -> int:

@@ -9,7 +9,12 @@ speculation and ``tp > 1`` are refused.  A model with BOTH
 (``cache_kind == "hybrid"``: ``models.phi4flash``) gets ``cache.HybridPool``
 and ``state_runner.HybridModelRunner``: a slot of state and growing blocks
 of one K/V layer behind one ledger, admitted when both are free, preempted
-for blocks, refused the same three.  ``step()`` is the whole design:
+for blocks, refused the same three.  A family whose body keeps blocks
+ALONE, of whatever it says a token leaves behind (``cache_kind == "paged"``:
+``models.kimi_k2``, one array of latent rows), gets the same runner over a
+``cache.KVBlockPool`` of that layout: its blocks are shared, forked and
+evicted as GPT-J's, so the prefix cache runs, and only speculation and
+``tp > 1`` are refused.  ``step()`` is the whole design:
 
 1. reap cancellations and blown deadlines;
 2. admit waiting requests into free decode slots (FIFO, memory-gated,
@@ -409,26 +414,31 @@ class LLMEngine:
             max_blocks_per_seq=self.cfg.max_blocks_per_seq,
         )
         cache_kind = getattr(model_cfg, "cache_kind", "kv")
-        if cache_kind == "hybrid":
-            # blocks of K/V AND a slot of state a sequence, one ledger
-            # (cache.HybridPool); the family gives both layouts
-            self._refuse_for_state_model()
+        if cache_kind in ("hybrid", "paged"):
+            # a family that gives its own layer programs and the layout of
+            # what its sequences hold: blocks ("paged": a KVBlockPool of the
+            # body's layout, shared and forked as any), or blocks AND a slot
+            # of state behind one ledger ("hybrid": cache.HybridPool)
+            self._refuse_for_hooks_body(state=cache_kind == "hybrid")
             from ray_tpu.llm.state_runner import HybridModelRunner
 
             self.runner = HybridModelRunner(model_cfg, params, self.cfg.block_size)
-            cache_cfg = HybridConfig(
-                self.cfg.num_blocks, self.cfg.block_size,
-                self.cfg.max_blocks_per_seq, self.cfg.max_slots,
-            )
             body = self.runner.body
-            self.pool = HybridPool(
-                cache_cfg, body.kv_layout(), body.state_leaves(self.cfg.block_size)
-            )
+            if cache_kind == "hybrid":
+                cache_cfg = HybridConfig(
+                    self.cfg.num_blocks, self.cfg.block_size,
+                    self.cfg.max_blocks_per_seq, self.cfg.max_slots,
+                )
+                self.pool = HybridPool(
+                    cache_cfg, body.kv_layout(), body.state_leaves(self.cfg.block_size)
+                )
+            else:
+                self.pool = KVBlockPool(cache_cfg, **body.kv_layout())
         elif cache_kind == "state":
             # a model without keys and values: a sequence owns one
             # fixed-size recurrent state (cache.StatePool), admission is a
             # free slot and the length limit is the model's positions
-            self._refuse_for_state_model()
+            self._refuse_for_hooks_body(state=True)
             from ray_tpu.llm.state_runner import StateModelRunner
 
             self.runner = StateModelRunner(model_cfg, params)
@@ -593,35 +603,48 @@ class LLMEngine:
         self._states = self.pool if isinstance(self.pool, StatePool) else getattr(
             self.pool, "states", None)
 
-    def _refuse_for_state_model(self) -> None:
-        """What is built on K/V blocks means nothing for a recurrent state,
-        and snapshots of states, which would stand in for it, are later
-        work: say so instead of serving wrong tokens."""
+    def _refuse_for_hooks_body(self, state: bool) -> None:
+        """What a family that brings its own layer programs cannot have.
+        With a recurrent ``state`` beside or instead of blocks, what is built
+        on shared K/V blocks means nothing, and snapshots of states, which
+        would stand in for it, are later work.  Blocks alone are shared and
+        forked as GPT-J's, so the prefix cache runs; the body has no verify
+        program and no sharded form.  Say so instead of serving wrong
+        tokens."""
         what = type(self.model_cfg).__name__
         holds = "no keys or values, only one recurrent state"
         if getattr(self.model_cfg, "cache_kind", "") == "hybrid":
             holds = "a recurrent state beside one layer's keys and values"
-        if self.cfg.prefix_cache:
+        if self.cfg.prefix_cache and state:
             raise ValueError(
                 f"prefix_cache=True with {what}: the radix prefix cache shares "
-                f"K/V blocks, and this model keeps {holds} per sequence; "
+                f"blocks, and this model keeps {holds} per sequence; "
                 "sharing a prefix would need "
                 "state snapshots at block boundaries (not implemented). "
                 "Pass EngineConfig(prefix_cache=False)"
             )
         if self.cfg.spec_k > 0:
             raise ValueError(
-                f"spec_k={self.cfg.spec_k} with {what}: verifying k drafted "
-                "tokens advances the recurrent state k steps and a rejection "
-                "would have to roll it back, which needs a state snapshot per "
-                "window (not implemented). Pass EngineConfig(spec_k=0)"
+                f"spec_k={self.cfg.spec_k} with {what}: " + (
+                    "verifying k drafted "
+                    "tokens advances the recurrent state k steps and a rejection "
+                    "would have to roll it back, which needs a state snapshot per "
+                    "window (not implemented)." if state else
+                    "its body gives a decode and a prefill-chunk program and no "
+                    "verify program over a window of drafted tokens (not "
+                    "implemented).") + " Pass EngineConfig(spec_k=0)"
             )
         if self.cfg.tp > 1:
             raise ValueError(
                 f"tp={self.cfg.tp} with {what}: tensor parallelism here "
-                "shards K/V heads and the paged kernels' pools; the state "
-                "pool and the retention kernel have no sharded form yet. "
-                "Pass EngineConfig(tp=1)"
+                "shards K/V heads and the paged kernels' pools; " + (
+                    "the state "
+                    "pool and the retention kernel have no sharded form yet."
+                    if state else
+                    "this body's pool has no head axis to shard and its layer "
+                    "programs no sharded form yet (its experts are spread over "
+                    "chips by expert parallelism, one share a chip).")
+                + " Pass EngineConfig(tp=1)"
             )
 
     # -- public API --------------------------------------------------------
@@ -1073,12 +1096,16 @@ class LLMEngine:
                     bytes=st.device_bytes, kinds=st.leaf_bytes(),
                 )
                 if st is not self.pool:  # a hybrid pool: its ONE K/V layer
-                    s["kv_pool"] = {
-                        "blocks": self.pool.cfg.num_blocks - 1,
-                        "live": led["seq_bytes"] // led["block_bytes"],
-                        "block_tokens": self.pool.cfg.block_size,
-                        "bytes": led["pool_bytes"] - led["state_bytes"],
-                    }
+                    s["kv_pool"] = self._kv_pool_stats(led)
+            elif self.runner.arch == "hybrid":
+                # a body over blocks alone: the same account of its decodes
+                # beside the pool's
+                s["kv_pool"] = dict(self._kv_pool_stats(led), **{
+                    k: self._state_n[k] for k in ("decodes", "decode_rows", "decode_tokens")})
+            # what the body counted on the device: the reader is taken here
+            # and called below, outside the lock, because it waits for every
+            # step launched so far and the step loop must not wait with it
+            counted = self.runner.counters() if self.runner.arch == "hybrid" else dict
             s["hbm"] = led
             s["retraces"] = self.runner.prof.retraces
             s["tp"] = self.cfg.tp
@@ -1091,7 +1118,17 @@ class LLMEngine:
                     self._spec_proposed, 1
                 )
                 s["spec_draft_seconds"] = self._spec_draft_s
-            return s
+        s.update(counted())
+        return s
+
+    def _kv_pool_stats(self, led: dict) -> dict:
+        """The paged part of a hooks body's pool (``stats()["kv_pool"]``)."""
+        return {
+            "blocks": self.pool.cfg.num_blocks - 1,
+            "live": led["seq_bytes"] // led["block_bytes"],
+            "block_tokens": self.pool.cfg.block_size,
+            "bytes": led["pool_bytes"] - led.get("state_bytes", 0),
+        }
 
     def device_report(self) -> dict:
         """``util.device_prof.device_report()`` for the engine's process

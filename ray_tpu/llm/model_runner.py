@@ -491,7 +491,11 @@ class PagedModelRunner(StepRunner):
             self.arch = "gptj"
         elif isinstance(cfg, GPTConfig):
             if cfg.n_experts > 0:
-                raise NotImplementedError("paged decode supports dense GPT only")
+                raise NotImplementedError(
+                    "this runner serves dense GPT only: models.gpt's expert "
+                    "layer is the training path's and drops tokens over its "
+                    "capacity; a family with its own serving_body() serves "
+                    "experts droplessly (models.kimi_k2, ops.moe)")
             self.arch = "gpt"
         else:
             raise TypeError(f"unsupported model config {type(cfg).__name__}")

@@ -25,16 +25,28 @@ start, n_valid, table, sampling) -> (state, logits, token, logprob)``.
 There is no verify step and no block fork: ``LLMEngine`` refuses
 speculation, the prefix cache and ``tp > 1`` for such a model.
 
-``HybridModelRunner`` is the same for a model whose sequences hold blocks
-of K/V AND a slot of state (``cache_kind == "hybrid"``,
-``cache.HybridPool``; ``models.phi4flash`` is the one such family): the
-body gives the whole layer program of each step, ``decode(params, x,
-arrays, positions, tables) -> (hidden, arrays)`` and ``chunk(params, x,
-arrays, start, n_valid, table) -> (last hidden, arrays)``, because its
-layers are of several kinds in several loops; ``arrays`` is the pool's
-tuple, every one donated and handed back in its place, and a table row is
-``[slot, block table...]``.  A dead decode row feeds position 0 of the
-trash slot and the trash block.
+``HybridModelRunner`` is the same for a model whose body gives the whole
+layer program of each step, ``decode(params, x, arrays, positions, tables)
+-> (hidden, arrays)`` and ``chunk(params, x, arrays, start, n_valid, table)
+-> (last hidden, arrays)``, because its layers are of several kinds in
+several loops; ``arrays`` is the pool's tuple, every one donated and handed
+back in its place.  Two kinds of family take it:
+
+* sequences hold blocks of K/V AND a slot of state (``cache_kind ==
+  "hybrid"``, ``cache.HybridPool``; ``models.phi4flash``): a table row is
+  ``[slot, block table...]``, and a dead decode row feeds position 0 of the
+  trash slot and the trash block;
+* sequences hold blocks alone, of whatever the body's ``kv_layout()`` says a
+  token leaves behind (``cache_kind == "paged"``, a ``cache.KVBlockPool``;
+  ``models.kimi_k2``: one array of latent rows): a table row is the block
+  table, blocks are shared, so the runner has ``fork_blocks`` and the engine
+  runs the radix prefix cache over it.
+
+A body may count on the device (``counters()``: shapes; ``models.kimi_k2``
+counts the router's load): those arrays ride every step after the pool's
+and are handed back, NOT donated (a few dozen numbers), and stay HERE and not
+in the pool; ``counters()`` of the runner gives a reader that fetches them
+(``LLMEngine.stats()`` alone does, outside its lock).
 """
 
 from __future__ import annotations
@@ -45,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.llm.cache import KVBlockPool
 from ray_tpu.llm.model_runner import (
     StepRunner,
     _advance_slots,
@@ -124,13 +137,23 @@ class HybridModelRunner(StepRunner):
     def __init__(self, cfg: Any, params: dict, block_size: int):
         super().__init__(cfg, params)
         self.body = cfg.serving_body()
-        # the K/V pool's two arrays and one a kind of state
-        self.n_arrays = n = 2 + len(self.body.state_leaves(block_size))
-        pools = tuple(range(1, 1 + n))
+        #: what the body counts on the device; none for most
+        self._counts = tuple(
+            jnp.zeros(c.shape, c.dtype) for c in getattr(self.body, "counters", tuple)())
+        # the paged pool's arrays (K and V, or what the body's layout says),
+        # one a kind of state, and the body's counters
+        self.n_paged = KVBlockPool.n_arrays(**self.body.kv_layout())
+        self.n_arrays = n = (
+            self.n_paged + len(self.body.state_leaves(block_size)) + len(self._counts))
+        # the pool's arrays are donated; the counters are not, so that a
+        # reader holding them (``counters()``) outlives the next step
+        pools = tuple(range(1, 1 + n - len(self._counts)))
         self._decode = jax.jit(self._decode_impl, donate_argnums=pools + (1 + n,))
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=pools, static_argnames=("chunk",)
         )
+        self._fork = jax.jit(
+            self._fork_impl, donate_argnums=tuple(range(self.n_paged)))
 
     def _decode_logits(self, params, arrays, tokens, positions, tables):
         """The model's part of a decode.  Returns (arrays, logits (S, V))."""
@@ -148,9 +171,28 @@ class HybridModelRunner(StepRunner):
         live, nxt, logp = _decode_sample(logits, knobs, counters)
         return (*arrays, _advance_slots(live, nxt, positions, counters), nxt, logp)
 
+    def _with_counts(self, site, fn, key, operands, n_pool: int, **static):
+        """Run a step with the body's counters after the pool's arrays, and
+        keep what it hands back of them."""
+        k = len(self._counts)
+        out = self._call(
+            site, fn, key, self.params, *operands[:n_pool], *self._counts,
+            *operands[n_pool:], **static)
+        self._counts = tuple(out[n_pool:n_pool + k])
+        return (*out[:n_pool], *out[n_pool + k:])
+
+    def counters(self):
+        """A reader of what the body has counted on the device in the steps
+        launched so far: called, it fetches (and waits for those steps) and
+        gives sections of ``stats()`` under the body's own names, {} for a
+        body that counts nothing.  Take it under the engine's lock and call
+        it outside: the arrays it holds are never donated."""
+        counts, body = self._counts, self.body
+        return lambda: body.read_counters(counts) if counts else {}
+
     def decode_step(self, *operands):
-        return self._call(
-            "decode", self._decode, jnp.shape(operands[-2])[0], self.params, *operands)
+        return self._with_counts(
+            "decode", self._decode, jnp.shape(operands[-2])[0], operands, len(operands) - 5)
 
     def _prefill_impl(self, params, *rest, chunk: int):
         arrays, (tokens, start, n_valid, table, sampling) = (
@@ -164,8 +206,24 @@ class HybridModelRunner(StepRunner):
 
     def prefill_chunk(self, *operands):
         *arrays, tokens, start, n_valid, table, sampling = operands
-        return self._call(
+        return self._with_counts(
             "prefill", self._prefill, len(tokens),
-            self.params, *arrays, tokens,
-            np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
+            (*arrays, tokens, np.int32(start), np.int32(n_valid), table, sampling),
+            len(arrays), chunk=len(tokens),
         )
+
+    def _fork_impl(self, *rest):
+        *pools, src, dst = rest
+        with jax.named_scope("kv_fork"):
+            return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
+
+    def fork_blocks(self, *operands):
+        """Copy-on-write for the prefix cache: blocks ``src`` onto ``dst`` in
+        every layer of every PAGED array (``model_runner._fork_impl``'s, for
+        whatever a block holds).  The engine refuses the prefix cache for a
+        body with state, so the state's leaves pass through untouched."""
+        *arrays, src, dst = operands
+        forked = self._call(
+            "fork", self._fork, len(src), *arrays[:self.n_paged],
+            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+        return (*forked, *arrays[self.n_paged:])

@@ -67,6 +67,7 @@ _FAMILIES = {
     "brumby": ("ray_tpu.models.brumby", "BrumbyConfig", "brumby_init", "BrumbyConfig"),
     "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig", "phi4flash_init",
                   "Phi4FlashConfig"),
+    "kimi_k2": ("ray_tpu.models.kimi_k2", "KimiK2Config", "kimi_k2_init", "KimiK2Config"),
 }
 
 

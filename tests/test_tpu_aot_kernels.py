@@ -68,3 +68,43 @@ def test_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch, bh, seq):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert kernel in text, kernel
+
+
+def test_the_latent_decode_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
+    """Kimi-K2.5's absorbed decode at the cell's sizes: 16 rows, 64 heads
+    over ONE row of 640 lanes a token, 7 layers of 2,049 blocks of 128, a
+    table of 138 blocks."""
+    from ray_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, pool, t, p: la.latent_decode_attention(
+            q, pool, t, p, rank=512, scale=0.14468, impl="auto")
+    ).lower(sds((16, 64, 640), jnp.bfloat16), sds((7 * 2049, 128, 640), jnp.bfloat16),
+            sds((16, 138), jnp.int32), sds((16,), jnp.int32)).compile()
+    assert "latent_attention_decode" in compiled.as_text()
+    # the pool stays where it is: no gathered copy of a table's blocks
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip):
+    """The tile loop indexes the experts of every layer where they lie: a
+    decode's expert layer at the published widths holds no temporary the size
+    of an expert (88 MB), let alone of a layer's twelve."""
+    from ray_tpu.ops import moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, mask, wmat, gate, up, down, first):
+        return moe.expert_layer(x, mask, wmat, gate, up, down, first=first)
+
+    compiled = jax.jit(layer).lower(
+        sds((16, 7168), jnp.bfloat16), sds((16, 12), jnp.bool_), sds((16, 12), jnp.float32),
+        sds((72, 7168, 2048), jnp.bfloat16), sds((72, 7168, 2048), jnp.bfloat16),
+        sds((72, 2048, 7168), jnp.bfloat16), sds((), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
