@@ -98,6 +98,7 @@ from ray_tpu.llm.model_runner import (
     PagedModelRunner,
     pack_knobs,
 )
+from ray_tpu.models.sampling import sort_ladder, sort_rung
 from ray_tpu.util import phases as _phases
 from ray_tpu.util import tracing as _tracing
 from ray_tpu.llm.scheduler import (
@@ -541,8 +542,11 @@ class LLMEngine:
         self._submit_lock_wait_s = 0.0
         self._submit_lock_wait_max_s = 0.0
         # decode / verify batches by the branch the sampler takes on the
-        # device (models.sampling._draw_rows): no row sampled, no sort
-        self._sampler_steps = {"greedy_steps": 0, "sorted_steps": 0}
+        # device (models.sampling._draw_rows): no row sampled, no sort; else
+        # the rows that sample and the width of the ladder they were sorted at
+        self._sampler_steps = {
+            "greedy_steps": 0, "sorted_steps": 0, "sorted_rows": 0, "sort_width_rows": 0,
+        }
         # liveness beat, read LOCK-FREE by the watchdog and stream_tokens'
         # stall diagnosis (a wedged step holds the engine lock, so the
         # observers must never need it): (monotonic t of the last completed
@@ -1403,12 +1407,20 @@ class LLMEngine:
             self._preemptions += sched.preempt_count - before
             _metrics()["preempt"].inc(sched.preempt_count - before)
 
-    def _note_sampler(self, temp: np.ndarray) -> None:
-        """Count the batch about to be launched by the sampler's own
-        predicate (``any(temp > 0)``; empty slots carry 0): how often the
-        all-greedy skip engages, in ``stats()["sampler"]``."""
-        key = "sorted_steps" if (temp > 0.0).any() else "greedy_steps"
-        self._sampler_steps[key] += 1
+    def _note_sampler(self, temp: np.ndarray, window: int = 1) -> None:
+        """Count the batch about to be launched by the sampler's own rule
+        (``sort_rung`` of the rows with ``temp > 0``; empty slots carry 0;
+        a verify batch has ``window`` rows a slot): how often the all-greedy
+        skip engages, how many rows sampled and how wide the sort that drew
+        them was, in ``stats()["sampler"]``."""
+        n, b = int((temp > 0.0).sum()) * window, temp.size * window
+        s = self._sampler_steps
+        if n == 0:
+            s["greedy_steps"] += 1
+            return
+        s["sorted_steps"] += 1
+        s["sorted_rows"] += n
+        s["sort_width_rows"] += sort_ladder(b)[sort_rung(n, b)]
 
     # -- the decode in flight ------------------------------------------------
 
@@ -1685,7 +1697,7 @@ class LLMEngine:
                 top_p[i] = p.top_p
                 seeds[i] = p.seed & 0xFFFFFFFF
                 counters[i] = len(req.out)
-            self._note_sampler(temp)
+            self._note_sampler(temp, W)
             self._slot_ids = None  # the window moves rows past the carry
         with self._phase("decode_launch", "verify_launch"):
             *self.pool.arrays, n_acc, out, out_lp = self.runner.verify_step(

@@ -310,7 +310,8 @@ def _sample_rows(logits, seeds, counters, temp, top_k, top_p):
     no matter which slot or step it lands in.  Returns (tokens (n,),
     logprobs (n,)) — the chosen-token behavior logprob rides along free
     (``models.sampling`` module doc).  One batched call under the ``sample``
-    scope: a batch with no sampled row skips the sort."""
+    scope: a batch with no sampled row skips the sort, and one with a few
+    sorts those alone (``models.sampling._draw_rows``)."""
     with jax.named_scope("sample"):
         return sample_rows_logprobs(logits, seeds, counters, temp, top_k, top_p)
 

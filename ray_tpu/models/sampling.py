@@ -36,10 +36,27 @@ and the chosen token's logprob are all taken where the sort left the row:
 nothing is scattered back, since no caller needs filtered logits in
 vocabulary order.  The tokens did not change: a row's key, the noise
 drawn from it, the stable order and the mask are what they were, and
-``max(x[order] + g[order])`` picks the token ``max(x + g)`` picks.  A
-batch with no sampled row (``temperature <= 0`` everywhere, empty engine
-slots included) skips the sort: one ``lax.cond`` on ``any(temperature >
-0)``, inside the one compiled program, leaves the two greedy reductions.
+``max(x[order] + g[order])`` picks the token ``max(x + g)`` picks.
+
+Only the rows that SAMPLE are sorted (PR 46).  The sort, the noise made for
+it, the softmax and the running sum behind top-p are all per row, and an
+engine's batch is mostly greedy rows and empty slots.  So the sampled rows
+are packed to the front, in slot order, and drawn at the narrowest of a few
+FIXED widths that holds them: ``sort_ladder(b)`` is 8, doubling, ending at
+the batch itself (8, 16, 32, 64 for 64 slots; 1 for the prefill program's
+row), and one ``lax.switch`` inside the one compiled program picks the rung
+from the count of ``temperature > 0``, read on the device (``sort_rung``,
+which the engine's ``stats()["sampler"]`` counter calls too).  A batch with
+no sampled row (empty engine slots included) takes branch 0: no sort, the
+two greedy reductions.  A row's draw depends on its logits, its key and its
+knobs and on nothing of its neighbours, so which rows stand beside it, how
+many, and at which width it was sorted change nothing it sees: tokens and
+logprobs are those of sorting every row, bit for bit on a CPU
+(tests/test_sampling_sorted.py keeps that program as a reference).  On the
+chip the tokens are equal too, and a logprob may differ in its last place:
+there the order of a row's ``sum(exp(.))`` follows the compiled operand's
+row count, as it always did between the prefill program's one row, the
+decode's slots and a verify window's ``S x W`` (PERF.md section 6, PR 46).
 ``token_logprobs`` alone keeps the filter in vocabulary order
 (``_filtered_logits``): it scores GIVEN ids and is differentiated.
 
@@ -77,6 +94,8 @@ replicas.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -150,6 +169,31 @@ def _filtered_logits(logits, temp, kk, pp):
     )
 
 
+#: the narrowest batch the sampler sorts: one float32 vreg holds 8 rows
+_FIRST_RUNG = 8
+
+
+def sort_ladder(b):
+    """The widths the sampler may sort at, for a batch of ``b`` rows: 0 (no
+    row samples, no sort), then a first rung, doubling, ending at ``b``
+    itself — (0, 8, 16, 32) for 32 rows, (0, 1) for the prefill program's
+    one.  Fixed by the operand's shape alone."""
+    widths = [0]
+    w = _FIRST_RUNG
+    while w < b:
+        widths.append(w)
+        w *= 2
+    return (*widths, b)
+
+
+def sort_rung(n, b):
+    """Index into ``sort_ladder(b)`` of the narrowest width that holds ``n``
+    sampled rows.  ``n`` is a traced count on the device (``_draw_rows``'s
+    ``switch``) or an int on the host (the engine's ``stats()["sampler"]``):
+    one rule for both."""
+    return sum((n > w) * 1 for w in sort_ladder(b)[:-1])
+
+
 def _draw_rows(logits, keys, temp, kk, pp):
     """The sampler: one token and its logprob a row.  logits: (b, v) fp32;
     keys: (b, 2) — row i's is the key ``jax.random.categorical`` would be
@@ -159,9 +203,11 @@ def _draw_rows(logits, keys, temp, kk, pp):
     Greedy rows are two reductions over the raw logits.  Sampled rows are
     drawn where ONE stable descending sort leaves them (module doc): it
     carries each token's id and Gumbel noise beside the key, so neither a
-    vocabulary-wide gather nor a scatter follows it.  No row sampled, no
-    sort: the ``cond`` reads the operands on the device, so one compiled
-    program serves an all-greedy batch at the cost of its reductions."""
+    vocabulary-wide gather nor a scatter follows it.  Only the rows that
+    sample are sorted: a ``switch`` on their count, read on the device,
+    packs them into the narrowest width of ``sort_ladder(b)`` that holds
+    them, so one compiled program serves an all-greedy batch at the cost of
+    its reductions and one sampled row among 64 slots at the cost of 8."""
     b, v = logits.shape
     sampled_row = temp > 0.0
     top = jnp.max(logits, axis=-1)
@@ -169,15 +215,27 @@ def _draw_rows(logits, keys, temp, kk, pp):
     # log_softmax(logits) at the argmax, where the shifted logit is 0
     greedy_lp = -jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), axis=-1))
 
-    def sorted_draw():
-        scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
+    def sorted_draw(w):
+        # the w rows to sort: the sampled ones in slot order, then padding
+        # (index b: a copy of the last row, its result dropped).  A row's
+        # place is the count of sampled rows before it, by comparison: no
+        # sort of slots, and no ``cumsum``, which jax lowers out of line,
+        # so that a trace would count it to no scope (``_running_sum``)
+        slot = jnp.arange(b, dtype=jnp.int32)
+        place = jnp.sum(sampled_row[None, :] & (slot[None, :] < slot[:, None]), axis=1)
+        pick = sampled_row[None, :] & (place[None, :] == slot[:w, None])
+        rows = jnp.min(jnp.where(pick, slot[None, :], b), axis=1)
+        lg, ks, t, k, p = (
+            jnp.take(x, rows, axis=0, mode="clip") for x in (logits, keys, temp, kk, pp)
+        )
+        scaled = lg / jnp.maximum(t, 1e-6)[:, None]
         # the noise categorical(key, row) adds to a (v,) row, token by token
-        noise = jax.vmap(lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
-        ids = jax.lax.broadcasted_iota(jnp.int32, (b, v), 1)
+        noise = jax.vmap(lambda key: jax.random.gumbel(key, (v,), jnp.float32))(ks)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (w, v), 1)
         neg, order, noise = jax.lax.sort(
             (-scaled, ids, noise), dimension=1, is_stable=True, num_keys=1
         )
-        masked = jnp.where(_keep_sorted(-neg, kk, pp), -neg, _NEG_INF)
+        masked = jnp.where(_keep_sorted(-neg, k, p), -neg, _NEG_INF)
         # Gumbel-max; among exact ties the lowest token id, as an argmax in
         # vocabulary order would break them
         z = masked + noise
@@ -190,12 +248,17 @@ def _draw_rows(logits, keys, temp, kk, pp):
         # log_softmax(masked) at the token: rank 0 survives every filter,
         # so masked[:, 0] is the row's maximum
         norm = jnp.log(jnp.sum(jnp.exp(masked - masked[:, :1]), axis=-1))
-        return tok, (chosen - masked[:, 0]) - norm
+        lp = (chosen - masked[:, 0]) - norm
+        return (
+            greedy.at[rows].set(tok, mode="drop"),
+            greedy_lp.at[rows].set(lp, mode="drop"),
+        )
 
-    tok, lp = jax.lax.cond(
-        jnp.any(sampled_row), sorted_draw, lambda: (greedy, greedy_lp)
+    return jax.lax.switch(
+        sort_rung(jnp.sum(sampled_row), b),
+        [lambda: (greedy, greedy_lp)]
+        + [functools.partial(sorted_draw, w) for w in sort_ladder(b)[1:]],
     )
-    return jnp.where(sampled_row, tok, greedy), jnp.where(sampled_row, lp, greedy_lp)
 
 
 def _request_keys(seeds, counters):
@@ -273,7 +336,7 @@ def sample_rows_logprobs(logits, seeds, counters, temperature, top_k, top_p):
     of the request seeded ``seeds[i]`` and draws from those two alone, so
     a request gets the same tokens whatever slot, step or replica it lands
     in.  logits: (n, vocab); every other operand (n,).  ONE batched call,
-    so the all-greedy skip of ``_draw_rows`` is a real branch.  Returns
+    so the ``switch`` of ``_draw_rows`` is a real branch, not a select.  Returns
     (tokens (n,), logprobs (n,))."""
     logits = logits.astype(jnp.float32)
     temp, kk, pp = _broadcast_knobs(logits.shape[0], temperature, top_k, top_p)
