@@ -2564,7 +2564,8 @@ class Head:
         if cond is not None:
             cond.notify_all()
 
-    def rpc_stream_next(self, task_id, index, timeout=None, delivered=None):
+    def rpc_stream_next(self, task_id, index, timeout=None, delivered=None,
+                        values=False):
         """Blocking: ('item', obj_id) when the index exists; ('end', count)
         past the final item; ('error', completion_obj_id) when the task
         failed (the completion object holds the exception). Acks the
@@ -2572,7 +2573,17 @@ class Head:
         carries ``hold_s`` (how long the item lay here) and passes on
         ``delivered``, the consumer's own report of the gaps between the
         items it has written out since it last asked (the producer's
-        ``head_hold`` and ``written`` readings, ``_private.stream_stats``)."""
+        ``head_hold`` and ``written`` readings, ``_private.stream_stats``).
+
+        With ``values`` (a consumer that wants the items, not references to
+        them: ``ObjectRefGenerator.values``) the answer is ('items', [...]):
+        EVERY item that has arrived from ``index`` on, in one ask, so a
+        consumer that has fallen behind catches up at one round trip for
+        all of them.  An item stored inline rides the answer as ('v', its
+        bytes) and is released here: no object id leaves, nothing is fetched
+        or freed for it afterwards.  Any other rides as ('r', obj_id), held
+        by the consumer as the plain answer's is.  One ack covers the lot;
+        its ``hold_s`` is then a list, an entry an item."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self.lock:
             while True:
@@ -2580,6 +2591,9 @@ class Head:
                     return ("end", 0)
                 st = self.streams.get(task_id)
                 if st is not None:
+                    if values and index in st["items"]:
+                        out, ack = self._take_stream_items(task_id, st, index)
+                        break
                     if index in st["items"]:
                         out = ("item", st["items"][index])
                         st["next"] = max(st["next"], index + 1)
@@ -2618,6 +2632,27 @@ class Head:
                 ack["delivered"] = delivered
             wh.send(("stream_ack", ack))
         return out
+
+    def _take_stream_items(self, task_id, st: dict, index: int) -> tuple:
+        """Lock held. Hand out every item of the stream from ``index`` on
+        (``rpc_stream_next`` with ``values``): the answer and its ack."""
+        items, t_in = st["items"], st.get("t_in", {})
+        now = time.perf_counter()
+        out, holds = [], []
+        while index in items:
+            oid = items[index]
+            ent = self.objects.get(oid)
+            if ent is not None and ent.small is not None and not ent.is_error:
+                out.append(("v", ent.small))
+                ent.refcount -= 1  # the stream's hold: the value has left
+                self._maybe_evict(oid, ent)
+            else:
+                out.append(("r", oid))
+            t = t_in.pop(index, None)
+            holds.append(0.0 if t is None else now - t)
+            index += 1
+        st["next"] = max(st["next"], index)
+        return ("items", out), {"task_id": task_id, "consumed": index, "hold_s": holds}
 
     def rpc_stream_dispose(self, task_id):
         """Consumer dropped its generator: cancel the producer if it is

@@ -312,17 +312,46 @@ class ObjectRefGenerator:
         if self._delivered:
             ask["delivered"], self._delivered = self._delivered, []
         kind, payload = self._ctx.call("stream_next", **ask)
-        if kind == "end":
-            self._done = True
+        if kind != "item":
+            self._end(kind, payload)
             raise StopIteration
+        self._i += 1
+        return ObjectRef(payload, owned=True)
+
+    def values(self, timeout: Optional[float] = None):
+        """Iterate the stream's VALUES, not references to them: for a
+        consumer that reads every item once and passes it on (a serve
+        handle).  One ask brings every item that has arrived
+        (``Head.rpc_stream_next`` with ``values``): an item small enough to
+        be stored inline comes in the answer itself and is an object no
+        longer; one that is not comes as a reference and is fetched here,
+        within ``timeout``.  An ask waits as ``next()`` does: until the
+        producer yields, ends or fails."""
+        while not (self._done or self._disposed):
+            ask = {"task_id": self._task_id, "index": self._i,
+                   "timeout": None, "values": True}
+            if self._delivered:
+                ask["delivered"], self._delivered = self._delivered, []
+            kind, payload = self._ctx.call("stream_next", **ask)
+            if kind != "items":
+                self._end(kind, payload)
+                return
+            refs = [ObjectRef(p, owned=True) if k == "r" else None for k, p in payload]
+            self._i += len(payload)
+            for (k, p), ref in zip(payload, refs):
+                if ref is not None:
+                    yield self._ctx.get([ref], timeout)[0]
+                else:
+                    yield self._ctx._materialize(b"", ("inline", p, False))
+
+    def _end(self, kind: str, payload) -> None:
+        """The stream is over: quietly ('end'), or by the producer's
+        exception, which the completion object carries ('error')."""
+        self._done = True
         if kind == "error":
-            self._done = True
-            # the completion object carries the producer's exception;
             # resolving it raises with proper cause chaining
             self._ctx.get([ObjectRef(payload)], timeout=30)
             raise rex.RayError("stream failed but completion held no error")
-        self._i += 1
-        return ObjectRef(payload, owned=True)
 
     def close(self) -> None:
         self._dispose(blocking=True)

@@ -13,7 +13,9 @@ grows from one station to the next is where the tail is made.
 * ``sent`` — the producing worker's handler thread, ``send_raw`` of the
   ``stream_item`` returned (``worker_main._stream_results_inner``).
 * ``acked`` — the worker's recv loop, the item's ``stream_ack`` arrived:
-  the consumer took it from the head, plus the hop back.
+  the consumer took it from the head, plus the hop back.  A consumer that
+  asks for values (``ObjectRefGenerator.values``) takes every item that
+  has arrived in one ask: one ack, and a gap of 0 between its items.
 * ``written`` — the consumer's own report (``ObjectRefGenerator
   .report_delivered``; the HTTP proxy reports the gaps between chunks
   written and drained), carried by ``stream_next`` and the ack.

@@ -675,16 +675,19 @@ def _on_stream_ack(state: WorkerState, ack: dict) -> None:
     """Recv loop: the consumer took an item from the head.  Opens the
     producer's window, and feeds three stations of the streaming path
     (``_private.stream_stats``): the gap between this stream's acks
-    (``acked``), how long the head held the item (``hold_s`` →
+    (``acked``; an ack for several items taken in one ask reads 0 between
+    them), how long the head held the item (``hold_s`` →
     ``head_hold``) and the write gaps the consumer reported (``delivered``
     → ``written``)."""
     now = time.perf_counter()
     tid = ack["task_id"]
     t_prev = rid = None
+    together = 0  # items this ack covers beyond its first: taken in one ask
     with state.stream_lock:
         stream = state.streams.get(tid)
         # (None: its last ack is in, or the producer failed or was cancelled)
         if stream is not None and ack["consumed"] > stream.acked:
+            together = ack["consumed"] - stream.acked - 1
             stream.acked = ack["consumed"]
             t_prev, stream.t_ack, rid = stream.t_ack, now, stream.rid
             if stream.cond is not None:
@@ -694,9 +697,13 @@ def _on_stream_ack(state: WorkerState, ack: dict) -> None:
     st = _stream_stats.stations()
     if t_prev is not None:
         st.acked.observe(now - t_prev)
+    for _ in range(together):
+        st.acked.observe(0.0)  # the consumer had them at one moment
     hold_s = ack.get("hold_s")
     if hold_s is not None:
-        st.head_hold.observe(hold_s)
+        # an item's stay in the head; a list where one ask took several
+        for h in hold_s if type(hold_s) is list else (hold_s,):
+            st.head_hold.observe(h)
     for gap in ack.get("delivered") or ():
         st.written.observe(gap)
     if rid is not None:

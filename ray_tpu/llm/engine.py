@@ -24,7 +24,12 @@ evicted as GPT-J's, so the prefix cache runs, and only speculation and
    applied as one batched device copy);
 3. run ONE chunked-prefill piece for the oldest still-prefilling
    admission — interleaved with, never instead of, decode; completed
-   prompt blocks are inserted into the prefix tree as they fill;
+   prompt blocks are inserted into the prefix tree as they fill.  Where
+   the runner offers it (``PagedModelRunner.prefill_with_slots``: the
+   one-chip string path) and rows decode, the chunk goes out IN the
+   decode's launch below, one program and one pass over the weights
+   (``_chunk_rides``); into an empty batch, under a drafter and on the
+   other runners it is a launch of its own, in front of the decode's;
 4. run ONE batched decode step across every running slot (single jitted
    call, static slot count), sample per-slot tokens (per-request
    temperature/top-k/top-p/seed), stream them out, finish requests that
@@ -55,8 +60,12 @@ window, not a traced slice).  OBSERVABILITY.md, "Engine step timeline".
 The decode in flight: ``_flight`` is the launched step whose tokens are
 still on the device.  A step launches the next decode first and reads
 ``_flight`` after, and after that a final prefill chunk's first token,
-sampled inside the prefill program (``_first``).  A row is left out of a
-launch once its LAST token is sampled (``max_tokens`` / the model length,
+sampled inside the prefill program (``_first``).  A final chunk that went
+out alone lets its row decode in the SAME step (``PATCH_JOIN``: the token
+never visits the host); one that rode the decode's launch leaves its token
+beside that launch's, so the row joins the NEXT launch the same way and
+the token is read a step on, right after the flight it rode.  A row is
+left out of a launch once its LAST token is sampled (``max_tokens`` / the model length,
 counted with the tokens in flight); a stop token shows one step late, so
 that row's extra token is dropped at the read (``discarded_tokens``; its
 blocks were freed when it finished, and the device runs its programs in
@@ -164,17 +173,19 @@ STEP_PHASES = (
 #: delta of the histogram says whether ANY step took seconds
 STEP_WALL_BOUNDS_S = tuple(0.001 * 2.0 ** (i / 2) for i in range(33))
 
-
 class _Flight:
     """A launched decode whose tokens are still on the device: the
-    ``(slot, request)`` rows it sampled for and the two arrays to read."""
+    ``(slot, request)`` rows it sampled for and the two arrays to read.
+    ``first``: the ``(request, token, logprob)`` of a FINAL chunk that rode
+    this launch (``_first`` once the step's reads are done), else None."""
 
-    __slots__ = ("rows", "ids", "nxt", "logp", "step")
+    __slots__ = ("rows", "ids", "nxt", "logp", "step", "first")
 
     def __init__(self, rows, nxt, logp, step):
         self.rows = rows
         self.ids = {r.id for _, r in rows}
         self.nxt, self.logp, self.step = nxt, logp, step
+        self.first = None
 
 
 class _Phase:
@@ -593,6 +604,9 @@ class LLMEngine:
         self._pipe = {
             "ahead_steps": 0, "serial_steps": 0, "drains": {},
             "discarded_tokens": 0,
+            # chunks launched WITH the step's decode, as one program, and
+            # alone, by a runner that offers that program (0 / 0 otherwise)
+            "joint_steps": 0, "lone_chunks": 0,
             "uploads": {"full": 0, "none": 0, "partial": 0},
         }
         # a state pool's own account (stats()["state_pool"]): first chunks
@@ -997,18 +1011,31 @@ class LLMEngine:
         return list(req.out)
 
     def warmup(self) -> None:
-        """Compile every jitted step path — prefill, decode, and (when
-        speculating) verify — so the first real request runs at
-        steady-state latency.  A speculating engine routes decode through
-        ``verify_step`` until acceptance drops, so one generate would
-        leave the PLAIN decode path (the backoff fallback) cold.  The
+        """Compile every jitted step path — prefill, decode, the two as one
+        program where the runner offers it, and (when speculating) verify —
+        so the first real request runs at steady-state latency.  A
+        speculating engine routes decode through ``verify_step`` until
+        acceptance drops, so one generate would leave the PLAIN decode path
+        (the backoff fallback) cold.  The
         verify jit is driven DIRECTLY with a dummy batch rather than via
         generate: whether a generate ever reaches verification is gated
         on the drafter finding a confident match in the (model-dependent)
         warmup output, so only a direct call guarantees the compile.  The
         dummy batch's all-zero block tables route every provisional write
         to the reserved trash block — real pool contents are untouched."""
-        self.generate([0], SamplingParams(max_tokens=2))
+        if self._joint is not None and self._drafter is None:
+            # the program of a step that carries a chunk needs a chunk AND
+            # a decoding row, which a lone generate never has: a second
+            # request arrives while the first decodes, and its chunk rides
+            # (the engine's own builders pack the operands, as in service)
+            first = self.submit([0], SamplingParams(max_tokens=4))
+            self.step()  # its chunk alone; its first decode launched
+            second = self.submit([0], SamplingParams(max_tokens=2))
+            while not (first.finished and second.finished):
+                if not self.step():
+                    time.sleep(0.001)
+        else:
+            self.generate([0], SamplingParams(max_tokens=2))
         if self.prefix_cache is not None:
             # compile the CoW fork jit with trash→trash lanes (block 0
             # copied onto itself: identity, real pool contents untouched)
@@ -1220,11 +1247,13 @@ class LLMEngine:
                         self._drain(reason)
                         with self._phase("admit"):
                             self._admit(doomed)
-                    did = self._prefill_one()
+                    with self._phase("prefill_build"):
+                        rides = self._chunk_rides()
+                    did = False if rides else self._prefill_one()
                     if self._drafter is not None and self._spec_skip == 0:
                         did = self._spec_decode_all(spec_info) or did
                     else:
-                        did_decode = self._decode_all()
+                        did_decode = self._decode_all(chunk=rides)
                         if did_decode and self._spec_skip > 0:
                             self._spec_skip -= 1  # backoff ticks on real decodes
                         did = did_decode or did
@@ -1317,16 +1346,44 @@ class LLMEngine:
             if req is not None and req.phase_led is not None:
                 _phases.charge(req.phase_led, _phases.COW_FORK, now)
 
-    def _prefill_one(self) -> bool:
-        """One chunk for the oldest admission still prefilling."""
+    @property
+    def _joint(self):
+        """The runner's ONE program for a chunk and the decode rows
+        (``PagedModelRunner.prefill_with_slots``), or None where it offers
+        none: the tensor-parallel runner, a hooks-body runner."""
+        return getattr(self.runner, "prefill_with_slots", None)
+
+    def _next_prefill(self) -> Optional[Request]:
+        """The oldest admission still prefilling."""
+        pre = [
+            r for r in self.scheduler.slots if r is not None and r.state == PREFILL
+        ]
+        if not pre:
+            return None
+        return min(pre, key=lambda r: self.scheduler._admitted_at.get(r.id, 0))
+
+    def _chunk_rides(self) -> bool:
+        """Whether this step's chunk goes out WITH its decode, as one
+        program (``model_runner`` ``_prefill_with_slots_impl``: a layer's
+        weights fetched once for both).  By what the engine observes: the
+        runner offers the program, a sequence is prefilling and rows decode;
+        a drafter reads every token on the host at once and keeps two
+        launches."""
+        return (
+            self._drafter is None
+            and self._joint is not None
+            and self._next_prefill() is not None
+            and bool(self._decode_rows())
+        )
+
+    def _build_chunk(self) -> Optional[tuple]:
+        """One chunk for the oldest admission still prefilling: (request,
+        valid tokens, whether it is the prompt's last, the chunk's operands
+        after the pools), or None when nothing prefills."""
         with self._phase("prefill_build"):
-            pre = [
-                r for r in self.scheduler.slots
-                if r is not None and r.state == PREFILL
-            ]
-            if not pre:
-                return False
-            req = min(pre, key=lambda r: self.scheduler._admitted_at.get(r.id, 0))
+            req = self._next_prefill()
+            if req is None:
+                return None
             chunk = self.cfg.prefill_chunk
             # a preempted request replays prompt + already-generated tokens
             # to rebuild its cache; a fresh one just prefills its prompt —
@@ -1345,15 +1402,13 @@ class LLMEngine:
                 len(req.out), p.temperature if final else 0.0, p.top_k, p.top_p,
                 p.seed,
             )
-        with self._phase("prefill_launch"):
-            *arrays, _logits, tok, logp = self.runner.prefill_chunk(
-                *self.pool.arrays, tokens, req.prefill_pos, n_valid, table,
-                sampling,
-            )
-        # the chunk is in flight; what follows is the host's book-keeping
-        # for it, billed to prefill_build like the work before the launch
+            return req, n_valid, final, (tokens, req.prefill_pos, n_valid, table, sampling)
+
+    def _chunk_launched(self, req: Request, n_valid: int, final: bool, tok, logp):
+        """The host's book-keeping for a chunk in flight, billed to
+        ``prefill_build`` like the work before the launch.  Returns the
+        ``(request, token, logprob)`` a FINAL chunk left on the device."""
         with self._phase("prefill_build"):
-            self.pool.arrays = arrays
             if req.prefill_pos == 0:
                 self._state_n["overwrites"] += 1  # read by a state pool only
             req.prefill_pos += n_valid
@@ -1368,7 +1423,7 @@ class LLMEngine:
             _metrics()["prefill_tokens"].inc(n_valid)
             _events.record(
                 "llm.prefill_chunk", request_id=req.trace_id, engine_req=req.id,
-                pos=req.prefill_pos, of=len(full), n=n_valid,
+                pos=req.prefill_pos, of=len(req.prompt) + len(req.out), n=n_valid,
             )
             if self.prefix_cache is not None:
                 # register the now-complete PROMPT blocks (generated tokens
@@ -1381,13 +1436,35 @@ class LLMEngine:
                     limit=min(req.prefill_pos, len(req.prompt)),
                     epoch=req.cache_epoch,
                 )
-            if final:
-                # the first generated token is on the device: the row can
-                # decode in THIS step (PATCH_JOIN) and the token is read
-                # once that decode is launched, not before
-                req.state = RUNNING
-                req.phase_recompute = False  # recompute ends where decode resumes
-                self._first = (req, tok, logp)
+            if not final:
+                return None
+            req.state = RUNNING
+            req.phase_recompute = False  # recompute ends where decode resumes
+            return req, tok, logp
+
+    def _prefill_one(self) -> bool:
+        """One chunk in a launch of its own: into an empty batch, under a
+        drafter, on a runner with no joint program."""
+        built = self._build_chunk()
+        if built is None:
+            return False
+        if self._first is not None:
+            # the first token a final chunk left a step ago beside a decode
+            # (``_launch_decode``), of a row that decodes no further: out,
+            # before this chunk's takes its place
+            self._drain("lone_chunk")
+        req, n_valid, final, operands = built
+        with self._phase("prefill_launch"):
+            *arrays, _logits, tok, logp = self.runner.prefill_chunk(
+                *self.pool.arrays, *operands
+            )
+            self.pool.arrays = arrays
+        if self._joint is not None:
+            self._pipe["lone_chunks"] += 1
+        # the first generated token is on the device: the row can decode in
+        # THIS step (PATCH_JOIN) and the token is read once that decode is
+        # launched, not before
+        self._first = self._chunk_launched(req, n_valid, final, tok, logp)
         if final and self._drafter is not None:
             self._read_first()  # a drafter reads the host's tokens: wait now
         return True
@@ -1524,9 +1601,9 @@ class LLMEngine:
         free = pool.num_free_blocks
         return need > free and need > free + pool.num_evictable_blocks
 
-    def _launch_decode(self) -> Optional[_Flight]:
-        """Build and launch one batched decode over every row that has a
-        token to sample; None when there is none."""
+    def _ready_decode(self) -> Optional[tuple]:
+        """``_build_decode`` over every row that has a token to sample, the
+        device read dry first where growing them would preempt."""
         with self._phase("decode_build"):
             rows = self._decode_rows()
             # a row about to be preempted replays prompt + out: its token in
@@ -1539,18 +1616,48 @@ class LLMEngine:
             self._drain("preempt")
             with self._phase("decode_build"):
                 built = self._build_decode(self._decode_rows())
+        return built
+
+    def _launch_decode(self, chunk: bool = False) -> Optional[_Flight]:
+        """Build and launch one batched decode over every row that has a
+        token to sample; None when there is none.  With ``chunk``
+        (``_chunk_rides``) the step's prefill chunk goes out in the SAME
+        program.  A final chunk's first token then leaves the device beside
+        this launch's tokens, so its row cannot decode in this pass: it
+        joins the NEXT launch (``PATCH_JOIN``, still no host round trip) and
+        the token is read where this flight is read (``_Flight.first``)."""
+        built = self._ready_decode()
+        if chunk and built is None:
+            # growing the rows preempted every one of them: the chunk goes
+            # alone, as into an empty batch, and a final chunk's row decodes
+            self._prefill_one()
+            built, chunk = self._ready_decode(), False
         if built is None:
             return None
         rows, first_tok, patch = built
-        with self._phase("decode_launch"):
-            *self.pool.arrays, self._carry, nxt, logp = self.runner.decode_step(
-                *self.pool.arrays, self._carry, first_tok, patch,
-                self._tables[1], self._knobs[1],
-            )
+        # the decode is built FIRST: growing its rows may have preempted the
+        # sequence the chunk was for (the youngest), whose blocks are gone
+        piece = self._build_chunk() if chunk else None
+        decode = (
+            self._carry, first_tok, patch, self._tables[1], self._knobs[1]
+        )
+        with self._phase("decode_launch" if piece is None else "prefill_launch"):
+            if piece is None:
+                *self.pool.arrays, self._carry, nxt, logp = self.runner.decode_step(
+                    *self.pool.arrays, *decode
+                )
+            else:
+                *self.pool.arrays, self._carry, nxt, logp, tok, tok_logp = (
+                    self._joint(*self.pool.arrays, *decode, *piece[3])
+                )
             self._state_n["decodes"] += 1
             self._state_n["decode_rows"] += len(rows)
             self._state_n["decode_tokens"] += sum(r.seq_len + a for _, r, a in rows)
-            return _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
+            flight = _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
+        if piece is not None:
+            self._pipe["joint_steps"] += 1
+            flight.first = self._chunk_launched(*piece[:3], tok, tok_logp)
+        return flight
 
     def _build_decode(self, rows: list) -> Optional[tuple]:
         """Grow ``rows`` and bring the device's slot state up to them: the
@@ -1617,12 +1724,13 @@ class LLMEngine:
         self._note_sampler(temp)
         return rows, first_tok, patch
 
-    def _decode_all(self) -> bool:
-        """One batched decode step over every RUNNING slot: launch the
+    def _decode_all(self, chunk: bool = False) -> bool:
+        """One batched decode step over every RUNNING slot (and, with
+        ``chunk``, the step's prefill chunk in the same program): launch the
         next one, THEN read the one in flight, so the device works under
         the emit and everything up to the next launch.  A drafter needs
         the tokens on the host: depth 0, each launch read at once."""
-        nxt = self._launch_decode()
+        nxt = self._launch_decode(chunk)
         if nxt is None:
             return self._drain("empty")
         self._pipe["ahead_steps" if self._flight is not None else "serial_steps"] += 1
@@ -1636,7 +1744,10 @@ class LLMEngine:
         if self._first is not None:
             self._read_first()
         if nxt is not None:
-            self._flight = nxt
+            # a final chunk that rode this launch: its token is read where
+            # this flight is, a step on, never by waiting on the program
+            # just launched
+            self._flight, self._first = nxt, nxt.first
         return True
 
     def _spec_decode_all(self, spec_info: dict) -> bool:

@@ -7,7 +7,7 @@ positions' k/v into physical blocks, attend via ``ops.paged_attention``,
 and hand back the updated pool arrays (functional updates; the engine
 holds the current version).
 
-Three entry shapes, each jitted once per engine:
+Four entry shapes, each jitted once per engine:
 
 * ``decode_step`` — (slots,) one token per running slot, batched across
   heterogeneous sequences (different lengths, block tables, sampling
@@ -24,6 +24,11 @@ Three entry shapes, each jitted once per engine:
   ``_chunk_write``).  Returns the last valid position's logits and the token
   sampled from them by the request's own knobs (one row of
   ``_sample_rows``): the final chunk's seeds generation, on the device.
+* ``prefill_with_slots`` — the two above as ONE program, for a step that
+  carries a chunk AND has rows to decode: the ``slots`` rows and the
+  ``chunk`` rows go through the layer loop together, so a layer's weights
+  cross HBM once a step where two programs fetched them twice.  The
+  tensor-parallel runner and the hooks-body runners do not offer it.
 * ``verify_step`` — (slots, k+1) speculative-decode verification: each
   slot feeds its last emitted token plus ``k`` drafted tokens, their k/v
   scatter PROVISIONALLY into the pool, one multi-query paged attention
@@ -517,6 +522,10 @@ class PagedModelRunner(StepRunner):
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
         )
+        self._prefill_with_slots = jax.jit(
+            self._prefill_with_slots_impl, donate_argnums=(1, 2, 3),
+            static_argnames=("chunk",),
+        )
         self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
         self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
 
@@ -782,4 +791,96 @@ class PagedModelRunner(StepRunner):
             "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
             np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
+        )
+
+    # -- a prefill chunk AND the decode rows, one program --------------------
+
+    def _prefill_with_slots_impl(
+        self,
+        params,
+        k_pool,
+        v_pool,
+        carry,       # ``_decode_impl``'s operands: the slots' rows
+        first_tok,
+        patch,
+        tables,
+        knobs,
+        tokens,      # ``_prefill_impl``'s operands: ONE sequence's chunk
+        start,
+        n_valid,
+        table,
+        sampling,
+        *,
+        chunk: int,
+    ):
+        """A step that carries a chunk, as ONE program: the ``S`` slots'
+        rows and the chunk's ``chunk`` rows go through the layer loop
+        together, so a layer's weights cross HBM once for both where a
+        prefill program followed by a decode program fetched them twice.
+        Row-wise work (ln1, q / k / v, rotary, ``attn_out``, the MLP,
+        ``lm_head``) is one product over ``S + chunk`` rows; what differs by
+        the rows' shape is done side by side: the two K/V writes
+        (``_slots_write``, ``_chunk_write``: the chunk's sequence and the
+        decoding rows own different blocks, so neither reads what the other
+        writes here), the two attentions (the decode kernel over the slots,
+        ``paged_prefill_attention_xla`` over the chunk) and the two samplers.
+        A FINAL chunk's token leaves with this program's decode tokens: its
+        row joins the NEXT launch (``PATCH_JOIN``).  Returns what the two
+        programs return: pools, the advanced carry, the slots' tokens and
+        logprobs, the chunk's token and logprob.
+
+        The NAME is read: a trace's readers tell programs apart by it, and
+        this is "the program of a step that carries a chunk" (``prefill``),
+        not a plain decode."""
+        cfg = self.cfg
+        bs = self.block_size
+        S = tables.shape[0]
+        s_tokens, s_pos, counters = _merge_slots(carry, first_tok, patch)
+        c_pos = start + jnp.arange(chunk, dtype=jnp.int32)
+        positions = jnp.concatenate([s_pos, c_pos])
+        x = self._embed(params, jnp.concatenate([s_tokens, tokens]), positions)
+        phys = jnp.take_along_axis(tables, (s_pos // bs)[:, None], axis=1)[:, 0]
+        lengths = s_pos + 1
+        slots_write = _slots_write(phys, s_pos % bs, bs)
+        chunk_write = _chunk_write(table, start, n_valid, chunk, bs)
+
+        def write(pool, vals, base):
+            return chunk_write(slots_write(pool, vals[:S], base), vals[S:], base)
+
+        def attend(q, k, v, base):
+            slots = paged_attention(
+                q[:S], k, v, tables + base, lengths, impl=self.attn_impl
+            ).astype(x.dtype).reshape(S, cfg.d_model)
+            rows = paged_prefill_attention_xla(
+                q[S:], k, v, table + base, c_pos
+            ).astype(x.dtype).reshape(chunk, cfg.d_model)
+            return jnp.concatenate([slots, rows])
+
+        x, k_pool, v_pool = _layer_loop(
+            params["blocks"], x, k_pool, v_pool,
+            functools.partial(
+                self._layer, positions=positions, write=write, attend=attend
+            ),
+        )
+        last = x[S + jnp.maximum(n_valid - 1, 0)]
+        logits = self._lm_head(params, jnp.concatenate([x[:S], last[None, :]]))
+        live, nxt, logp = _decode_sample(logits[:S], knobs, counters)
+        tok, tok_logp = _prefill_sample(logits[S], sampling)
+        return (
+            k_pool, v_pool, _advance_slots(live, nxt, s_pos, counters), nxt, logp,
+            tok, tok_logp,
+        )
+
+    def prefill_with_slots(self, k_pool, v_pool, carry, first_tok, patch, tables,
+                           knobs, tokens, start, n_valid, table, sampling):
+        """``decode_step`` and ``prefill_chunk`` in one launch.  A runner
+        OFFERS this by having it: the engine launches a step's chunk with
+        its decode where it finds the method, and as two programs where it
+        finds None (``llm.multichip``; the hooks-body runners have none)."""
+        return self._call(
+            "prefill_with_slots", self._prefill_with_slots,
+            (jnp.shape(tables)[0], len(tokens)),
+            self.params, k_pool, v_pool, carry, first_tok, patch, tables, knobs,
+            tokens, np.int32(start), np.int32(n_valid), table, sampling,
+            chunk=len(tokens),
         )

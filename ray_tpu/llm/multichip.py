@@ -201,6 +201,13 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
     ``fork_blocks``) and the engine-facing contract are inherited; only
     the traced bodies and parameter placement change."""
 
+    #: NOT inherited: the base class's joint program (a chunk and the decode
+    #: rows in one launch) is its single-chip body, and this runner has
+    #: shard bodies of its own; the engine finds no offer here and launches
+    #: two programs.  Separation, until the string path and this one are one
+    #: body under a mesh (ROADMAP D1), when the joint program exists once
+    prefill_with_slots = None
+
     def __init__(
         self,
         cfg: Any,

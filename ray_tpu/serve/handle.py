@@ -347,7 +347,6 @@ class StreamingDeploymentResponse:
         self._live._gen.report_delivered(gaps)
 
     def __iter__(self):
-        import ray_tpu
         from ray_tpu.exceptions import RayActorError
 
         cur = self
@@ -359,8 +358,7 @@ class StreamingDeploymentResponse:
         try:
             while True:
                 try:
-                    for ref in cur._gen:
-                        item = ray_tpu.get(ref, timeout=60)
+                    for item in cur._gen.values(timeout=60):
                         emitted.append(item)
                         yield item
                     return

@@ -373,10 +373,15 @@ def test_steady_steps_send_nothing_and_wait_only_after_their_launches(tp, monkey
     _assert_clean(eng)
 
 
-def test_a_final_chunk_waits_for_nothing_of_its_own(monkeypatch):
-    """The steps that carry a prompt's last chunk: launch the chunk, launch
-    the decode the new row joins, THEN read."""
-    eng = _shared(1)
+@pytest.mark.parametrize("tp", TP)
+def test_a_final_chunk_waits_for_nothing_of_its_own(monkeypatch, tp):
+    """The steps that carry a prompt's last chunk.  Two launches (the
+    tensor-parallel runner): launch the chunk, launch the decode the new
+    row joins, THEN read.  One launch (``tp == 1``: the chunk rides the
+    decode, ISSUE 47): the first token leaves with that launch's tokens, so
+    the row joins the NEXT launch and the token is read where that flight
+    is read, after it, never by a wait on the program just launched."""
+    eng = _shared(tp)
     first = eng.submit(_prompt(90), _mixed(0, 30))
     while len(first.out) < 3:
         eng.step()
@@ -386,11 +391,19 @@ def test_a_final_chunk_waits_for_nothing_of_its_own(monkeypatch):
     while not late.out:
         order.append("step")
         eng.step()
-    assert late.id in eng._flight.ids and len(late.out) == 1  # it decoded in its chunk's step
-    last = " ".join(order).split("step")[-1].split()
-    # the decode's tokens are read and out before the wait for the chunk's
-    assert [k for k in last if k.endswith(("_launch", "_fetch", "_sample", "emit"))] == [
-        "prefill_launch", "decode_launch", "decode_fetch", "emit", "prefill_sample", "emit"], last
+    assert late.id in eng._flight.ids and len(late.out) == 1  # its decode is launched
+    steps = [[k for k in keys.split() if k.endswith(("_launch", "_fetch", "_sample", "emit"))]
+             for keys in " ".join(order).split("step")[1:]]
+    if tp > 1:
+        # the decode's tokens are read and out before the wait for the chunk's
+        assert steps[-1] == ["prefill_launch", "decode_launch", "decode_fetch", "emit",
+                             "prefill_sample", "emit"], steps
+    else:
+        # one launch a step, billed once; the step after the final chunk's
+        # reads the flight it rode, then its token (already there)
+        assert steps[-2:] == [["prefill_launch", "decode_fetch", "emit"],
+                              ["decode_launch", "decode_fetch", "emit", "prefill_sample",
+                               "emit"]], steps
     monkeypatch.undo()
     _run(eng, [first, late])
     _assert_reference(first)

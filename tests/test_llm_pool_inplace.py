@@ -2,7 +2,9 @@
 WHOLE pool in place (``model_runner._layer_loop``, PR 24).
 
 Two properties per step (decode, prefill, verify) x arch (gpt, gptj), and
-the three tensor-parallel shard bodies at tp=2 on host devices.  A prefill
+the three tensor-parallel shard bodies at tp=2 on host devices; the first
+also for the program that carries a chunk AND the slots' rows (ISSUE 47;
+``tests/test_llm_joint_step.py`` holds its writes to the two programs').  A prefill
 chunk and a decode write WHOLE blocks (``model_runner._scatter_kv_blocks``),
 a verify window rows (``_scatter_kv``): all are held to the same reference,
 the chunk at a block of 16 and a chunk of 128 for every ``start`` x
@@ -199,6 +201,28 @@ def test_step_holds_no_pool_sized_temporary(step, arch, tp):
     assert temp < pool_bytes / 2, (
         f"{step}/{arch}/tp{tp}: {temp} B of temporaries against a pool of "
         f"{pool_bytes} B: the step copies the pool"
+    )
+
+
+@pytest.mark.parametrize("chunk_step", ["prefill", "prefill@5+127"])
+@pytest.mark.parametrize("arch", list(ARCHS))
+def test_joint_step_holds_no_pool_sized_temporary(arch, chunk_step):
+    """The program of a step that carries a chunk (the slots' rows and the
+    chunk's rows in one layer loop: two writes and two attentions on the
+    same carried pools) at the tests' sizes and at the served block and
+    chunk: as in place as the two programs it stands for."""
+    bs = _geometry(chunk_step)[0]
+    runner = _runner(arch, 1, bs)
+    k, v = _noise_pools(1, bs)
+    decode = host_batch(*_operands("decode")[0])
+    chunk = _operands(chunk_step)[0] + (pack_knobs(0, 0.0, 0, 1.0, 0),)
+    compiled = runner._prefill_with_slots.lower(
+        runner.params, k, v, *decode, *chunk, chunk=len(chunk[0])
+    ).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < k.nbytes / 2, (
+        f"{chunk_step}/{arch}: {temp} B of temporaries against a pool of "
+        f"{k.nbytes} B: the joint step copies the pool"
     )
 
 

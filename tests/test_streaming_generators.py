@@ -245,3 +245,123 @@ def test_an_item_wakes_its_own_stream_only(ray_start_regular):
     waiter.join(30)
     assert not waiter.is_alive() and got == ["second"] and Counting.notified >= 1
     assert list(slow) == []
+
+
+# -- values(): the items themselves, every one that has arrived in one ask --
+
+
+def test_values_are_the_items_in_order(ray_start_regular):
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i * i
+
+    assert list(gen.remote(40).values(timeout=30)) == [i * i for i in range(40)]
+    # a stream read by reference and one read by value give the same items
+    assert [ray_tpu.get(r, timeout=30) for r in gen.remote(7)] == list(
+        gen.remote(7).values(timeout=30))
+
+
+def test_values_error_mid_stream(ray_start_regular):
+    """What was yielded before the failure is delivered, then the
+    producer's own exception is raised."""
+    @ray_tpu.remote(num_returns="streaming")
+    def boom():
+        yield 1
+        yield 2
+        raise ValueError("stream exploded")
+
+    got = []
+    with pytest.raises(ValueError, match="stream exploded"):
+        for v in boom.remote().values(timeout=30):
+            got.append(v)
+    assert got == [1, 2]
+
+
+def test_a_late_consumer_catches_up_in_one_ask(ray_start_regular):
+    """A consumer that has fallen behind is handed everything that has
+    arrived in ONE ask (its backlog costs one round trip, not one an item),
+    and one ack opens the producer's window for all of it."""
+    from ray_tpu._private.runtime import get_ctx
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i
+
+    ctx = get_ctx()
+    asks = []
+    call = ctx.call
+
+    def counting(method, **payload):
+        if method == "stream_next":
+            asks.append(payload["index"])
+        return call(method, **payload)
+
+    ctx.call = counting
+    try:
+        g = gen.remote(12)
+        head = ctx.head
+        deadline = time.time() + 30
+        while time.time() < deadline:  # the whole stream has arrived (window 16)
+            with head.lock:
+                st = head.streams.get(g._task_id)
+                if st is not None and st["count"] == 12:
+                    break
+            time.sleep(0.01)
+        assert list(g.values(timeout=30)) == list(range(12))
+    finally:
+        del ctx.call
+    # one ask took the twelve, one more met the end
+    assert asks == [0, 12]
+
+
+def test_values_leave_no_object_behind(ray_start_regular):
+    """An inline item rides the answer and is released where it is handed
+    out; an item too large for that comes as a reference, is fetched, and is
+    freed with the reference."""
+    import numpy as np
+
+    from ray_tpu._private.ids import ObjectID, TaskID
+    from ray_tpu._private.runtime import get_ctx
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield 7
+        yield np.arange(400_000, dtype=np.int64)  # 3.2 MB: not inline
+        yield "last"
+
+    g = gen.remote()
+    vals = list(g.values(timeout=30))
+    assert vals[0] == 7 and vals[2] == "last"
+    assert vals[1].shape == (400_000,) and int(vals[1][-1]) == 399_999
+    del vals
+    head = get_ctx().head
+    oids = [ObjectID.for_task_return(TaskID(g._task_id), 1 + i).binary() for i in range(3)]
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        with head.lock:
+            left = [o for o in oids if o in head.objects]
+        if not left:
+            break
+        time.sleep(0.05)  # the big item's free rides the gc drain
+    assert left == []
+
+
+def test_serve_stream_reads_values(ray_start_regular):
+    """The serve handle's stream iterates values: a generator deployment's
+    items arrive whole and in order through it."""
+    from ray_tpu import serve
+
+    @serve.deployment
+    class Counter:
+        def __call__(self, n):
+            for i in range(n):
+                yield {"i": i}
+
+    try:
+        handle = serve.run(Counter.bind(), name="counter")
+        got = list(handle.options(stream=True).remote(25))
+        assert got == [{"i": i} for i in range(25)]
+    finally:
+        serve.shutdown()
