@@ -41,6 +41,11 @@ Four entry shapes, each jitted once per engine:
   the table's reach scatter to the trash block, so slots at the model-
   length cap stay safe (their surplus logits are discarded host-side).
 
+The bodies are written for a SHARD of the heads and of ``d_ff`` (``-1``,
+``n_local_heads``, a layer's two row-parallel products as partial sums that
+go through ``_sum``), of which one chip holds the only one:
+``llm.multichip`` runs these same bodies under its mesh.
+
 Slot state: the decode program carries ``(token, position, counter)`` of
 every slot from one step to the next in ONE donated ``(3, slots)`` int32
 array, which it returns advanced by the token it sampled, so the next step
@@ -287,7 +292,7 @@ def _carry_loop(blocks, x, pools: tuple, layer_fn):
 
 def _layer_loop(blocks, x, k_pool, v_pool, layer_fn):
     """THE layer loop of every jitted step over K/V pools (decode, verify,
-    prefill; the three tensor-parallel shard bodies): ``_carry_loop`` with
+    prefill, on one chip and under ``tp``): ``_carry_loop`` with
     the two pools.  A pool that rides a scan as ``xs``/``ys`` is sliced per
     layer and stacked again: six pool-sized copies a step on a v5e
     (PERF.md, PR 24).
@@ -331,17 +336,18 @@ def _abstract(x) -> jax.ShapeDtypeStruct:
     )
 
 
-def _fork_impl(k_pool, v_pool, src, dst):
-    """Copy-on-write block fork for the prefix cache: duplicate whole
-    physical blocks across every layer — ``pool[:, dst[i]] = pool[:,
-    src[i]]``.  A block copy is a memmove; recomputing the same positions
-    through the model is L layer matmuls — the fork wins by orders of
-    magnitude.  Unused lanes pad with (0, 0): trash copied onto trash,
-    harmless and value-deterministic even with duplicate dst indices."""
+def _fork_impl(*rest):
+    """Copy-on-write block fork for the prefix cache, ``rest`` any number
+    of paged arrays ``(L, NB, ...)`` (K and V, a latent pool), then ``src,
+    dst``: duplicate whole physical blocks across every layer — ``pool[:,
+    dst[i]] = pool[:, src[i]]``.  A block copy is a memmove; recomputing
+    the same positions through the model is L layer matmuls — the fork
+    wins by orders of magnitude.  Unused lanes pad with (0, 0): trash
+    copied onto trash, harmless and value-deterministic even with
+    duplicate dst indices.  Head-agnostic, so it runs per shard unchanged."""
+    *pools, src, dst = rest
     with jax.named_scope("kv_fork"):
-        k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-        v_pool = v_pool.at[:, dst].set(v_pool[:, src])
-    return k_pool, v_pool
+        return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
 def _verify_rows(logits, draft, seeds, counters, temp, top_k, top_p):
@@ -510,7 +516,7 @@ class PagedModelRunner(StepRunner):
         self.attn_impl = attn_impl
         # heads THIS runner's traced bodies see: all of them single-chip;
         # the tensor-parallel subclass (llm.multichip) narrows this to its
-        # per-device head group and reuses _qkv_rows unchanged
+        # per-device head group and runs the same bodies
         self.n_local_heads = cfg.n_heads
         # donate the pool buffers: a step writes its rows into the buffers
         # it was given and hands the same buffers back.  Donation alone did
@@ -519,12 +525,9 @@ class PagedModelRunner(StepRunner):
         # step, more than the step's math.  tests/test_llm_pool_inplace.py
         # holds every step to it through the compiled program's temp size
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2, 3))
-        self._prefill = jax.jit(
-            self._prefill_impl, donate_argnums=(1, 2), static_argnames=("chunk",)
-        )
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1, 2))
         self._prefill_with_slots = jax.jit(
-            self._prefill_with_slots_impl, donate_argnums=(1, 2, 3),
-            static_argnames=("chunk",),
+            self._prefill_with_slots_impl, donate_argnums=(1, 2, 3)
         )
         self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
         self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
@@ -556,26 +559,28 @@ class PagedModelRunner(StepRunner):
         return q, k, v
 
     def _mlp(self, layer, h):
+        """The MLP over this device's ``d_ff`` columns: a PARTIAL sum,
+        without ``mlp_out``'s bias (``_layer`` adds it once, after ``_sum``)."""
         dt = h.dtype
         with jax.named_scope("mlp"):
             mid = jax.nn.gelu(
                 h @ layer["mlp_in"]["kernel"].astype(dt)
                 + layer["mlp_in"]["bias"].astype(dt)
             )
-            return mid @ layer["mlp_out"]["kernel"].astype(dt) + layer["mlp_out"][
-                "bias"
-            ].astype(dt)
+            return mid @ layer["mlp_out"]["kernel"].astype(dt)
 
     def _attn_out(self, layer, att_flat):
-        dt = att_flat.dtype
+        """The output projection of this device's heads: a partial sum too."""
         with jax.named_scope("attn_out"):
-            out = att_flat @ layer["attn_out"]["kernel"].astype(dt)
-            if self.arch == "gpt":
-                out = out + layer["attn_out"]["bias"].astype(dt)
-        return out
+            return att_flat @ layer["attn_out"]["kernel"].astype(att_flat.dtype)
+
+    def _sum(self, x):
+        """A row-parallel product's partial sums, summed over the devices
+        that hold the shards: on one chip the partial sum IS the sum."""
+        return x
 
     def _qkv_write(self, x, layer, k, v, base, positions, write):
-        """The head of a layer, shared with the tensor-parallel runner:
+        """The head of a layer:
         ln1, the rows' q/k/v, and their k/v written into the whole pools
         (``_layer_loop``'s view) by the step's ``write(pool, vals, base)``
         (``_chunk_write`` / ``_slots_write`` / ``_rows_write``, which add
@@ -587,19 +592,39 @@ class PagedModelRunner(StepRunner):
         return ln1, q, k, v
 
     def _layer(self, x, layer, k, v, base, positions, write, attend):
-        """One transformer layer over the whole pools (``_layer_loop``'s
-        view; ``base`` is this layer's first block there).
-        ``attend(q, k, v, base) -> (rows, d_model)`` supplies the step
-        shape's paged attention, its block tables offset by ``base``."""
+        """One transformer layer on THIS device's head / ``d_ff`` shard (on
+        one chip: all of them), over the whole local pools
+        (``_layer_loop``'s view; ``base`` is this layer's first block there).
+        ``attend(q, k, v, base) -> (rows, local heads * head_dim)`` supplies
+        the step shape's paged attention, its block tables offset by
+        ``base``.  The two row-parallel products are partial sums that go
+        through ``_sum``; each replicated bias is added once, after, under
+        the scope of its product."""
+        dt = x.dtype
+
+        def biased(h, mod, scope):
+            with jax.named_scope(scope):
+                return h + layer[mod]["bias"].astype(dt)
+
         ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, write)
-        att = self._attn_out(layer, attend(q, k, v, base))
+        att_p = self._attn_out(layer, attend(q, k, v, base))
         if self.arch == "gptj":
-            out = x + att + self._mlp(layer, ln1)  # parallel residual
+            # parallel residual: attention and MLP partials share ONE sum a
+            # layer (under tp: half the collectives of the arch below)
+            out = biased(x + self._sum(att_p + self._mlp(layer, ln1)), "mlp_out", "mlp")
         else:
-            h = x + att
+            h = biased(x + self._sum(att_p), "attn_out", "attn_out")
             ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-            out = h + self._mlp(layer, ln2)
+            out = biased(h + self._sum(self._mlp(layer, ln2)), "mlp_out", "mlp")
         return out, k, v
+
+    def _layers(self, site, params, x, k_pool, v_pool, **rows):
+        """``_layer_loop`` over ``_layer`` with the step's ``rows``
+        (positions, write, attend), for step program ``site`` (the
+        tensor-parallel runner keeps its reductions' ledger by it)."""
+        return _layer_loop(
+            params["blocks"], x, k_pool, v_pool, functools.partial(self._layer, **rows)
+        )
 
     def _embed(self, params, tokens, positions):
         # params flows through the TRACED argument, never self.params: the
@@ -639,7 +664,6 @@ class PagedModelRunner(StepRunner):
         tables,      # (S, T) int32
         knobs,       # (S, 5) int32 — pack_knobs
     ):
-        cfg = self.cfg
         bs = self.block_size
         # tokens: the token being FED per slot; positions: its position (==
         # cache length before it); counters: index of the token being sampled
@@ -652,14 +676,11 @@ class PagedModelRunner(StepRunner):
         def attend(q, k, v, base):
             return paged_attention(
                 q, k, v, tables + base, lengths, impl=self.attn_impl
-            ).astype(x.dtype).reshape(x.shape[0], cfg.d_model)
+            ).astype(x.dtype).reshape(x.shape[0], -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._layer, positions=positions, write=_slots_write(phys, off, bs),
-                attend=attend,
-            ),
+        x, k_pool, v_pool = self._layers(
+            "decode", params, x, k_pool, v_pool,
+            positions=positions, write=_slots_write(phys, off, bs), attend=attend,
         )
         logits = self._lm_head(params, x)  # (S, V)
         live, nxt, logp = _decode_sample(logits, knobs, counters)
@@ -712,16 +733,13 @@ class PagedModelRunner(StepRunner):
 
         def attend(q, k, v, base):
             return paged_verify_attention(
-                q.reshape(S, W, cfg.n_heads, cfg.head_dim),
+                q.reshape(S, W, self.n_local_heads, cfg.head_dim),
                 k, v, tables + base, positions, impl=self.attn_impl,
-            ).astype(x.dtype).reshape(S * W, cfg.d_model)
+            ).astype(x.dtype).reshape(S * W, -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._layer, positions=pos_flat, write=_rows_write(phys, off),
-                attend=attend,
-            ),
+        x, k_pool, v_pool = self._layers(
+            "verify", params, x, k_pool, v_pool,
+            positions=pos_flat, write=_rows_write(phys, off), attend=attend,
         )
         logits = self._lm_head(params, x).reshape(S, W, -1)  # (S, W, V)
         n_acc, out, logp = _verify_rows(
@@ -760,24 +778,22 @@ class PagedModelRunner(StepRunner):
         n_valid,    # scalar int32 — valid tokens in this chunk
         table,      # (T,) int32 — THIS sequence's block table
         sampling,   # (5,) int32 — pack_knobs(counter, ...) of this request
-        *,
-        chunk: int,
     ):
-        cfg = self.cfg
+        # static under jit: the engine pads every chunk to cfg.prefill_chunk,
+        # so this traces once
+        chunk = tokens.shape[0]
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
         x = self._embed(params, tokens, positions)  # (chunk, d)
 
         def attend(q, k, v, base):
             return paged_prefill_attention_xla(
                 q, k, v, table + base, positions
-            ).astype(x.dtype).reshape(chunk, cfg.d_model)
+            ).astype(x.dtype).reshape(chunk, -1)
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._layer, positions=positions, attend=attend,
-                write=_chunk_write(table, start, n_valid, chunk, self.block_size),
-            ),
+        x, k_pool, v_pool = self._layers(
+            "prefill", params, x, k_pool, v_pool,
+            positions=positions, attend=attend,
+            write=_chunk_write(table, start, n_valid, chunk, self.block_size),
         )
         last = x[jnp.maximum(n_valid - 1, 0)]  # (d,)
         logits = self._lm_head(params, last[None, :])[0]  # (V,)
@@ -790,7 +806,7 @@ class PagedModelRunner(StepRunner):
         return self._call(
             "prefill", self._prefill, len(tokens),
             self.params, k_pool, v_pool, tokens,
-            np.int32(start), np.int32(n_valid), table, sampling, chunk=len(tokens),
+            np.int32(start), np.int32(n_valid), table, sampling,
         )
 
     # -- a prefill chunk AND the decode rows, one program --------------------
@@ -810,8 +826,6 @@ class PagedModelRunner(StepRunner):
         n_valid,
         table,
         sampling,
-        *,
-        chunk: int,
     ):
         """A step that carries a chunk, as ONE program: the ``S`` slots'
         rows and the chunk's ``chunk`` rows go through the layer loop
@@ -832,9 +846,8 @@ class PagedModelRunner(StepRunner):
         The NAME is read: a trace's readers tell programs apart by it, and
         this is "the program of a step that carries a chunk" (``prefill``),
         not a plain decode."""
-        cfg = self.cfg
         bs = self.block_size
-        S = tables.shape[0]
+        S, chunk = tables.shape[0], tokens.shape[0]
         s_tokens, s_pos, counters = _merge_slots(carry, first_tok, patch)
         c_pos = start + jnp.arange(chunk, dtype=jnp.int32)
         positions = jnp.concatenate([s_pos, c_pos])
@@ -850,17 +863,15 @@ class PagedModelRunner(StepRunner):
         def attend(q, k, v, base):
             slots = paged_attention(
                 q[:S], k, v, tables + base, lengths, impl=self.attn_impl
-            ).astype(x.dtype).reshape(S, cfg.d_model)
+            ).astype(x.dtype).reshape(S, -1)
             rows = paged_prefill_attention_xla(
                 q[S:], k, v, table + base, c_pos
-            ).astype(x.dtype).reshape(chunk, cfg.d_model)
+            ).astype(x.dtype).reshape(chunk, -1)
             return jnp.concatenate([slots, rows])
 
-        x, k_pool, v_pool = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(
-                self._layer, positions=positions, write=write, attend=attend
-            ),
+        x, k_pool, v_pool = self._layers(
+            "prefill_with_slots", params, x, k_pool, v_pool,
+            positions=positions, write=write, attend=attend,
         )
         last = x[S + jnp.maximum(n_valid - 1, 0)]
         logits = self._lm_head(params, jnp.concatenate([x[:S], last[None, :]]))
@@ -882,5 +893,4 @@ class PagedModelRunner(StepRunner):
             (jnp.shape(tables)[0], len(tokens)),
             self.params, k_pool, v_pool, carry, first_tok, patch, tables, knobs,
             tokens, np.int32(start), np.int32(n_valid), table, sampling,
-            chunk=len(tokens),
         )

@@ -25,13 +25,15 @@ Megatron column/row split, chosen so the PAGED layout shards for free:
   replicated result.
 
 The three jitted entry points (decode / prefill / verify) and the CoW
-``fork_blocks`` keep their single-chip signatures (the decode's slot
-state and every small operand replicated, placed by ``place``) — ``LLMEngine``,
+``fork_blocks`` are the single-chip BODIES (``model_runner``'s ``_*_impl``,
+written for a shard of the heads and of ``d_ff``) under ``shard_map``, with
+their signatures (the decode's slot state and every small operand
+replicated, placed by ``place``) — ``LLMEngine``,
 speculative decoding, preemption-recompute, failover ``resume_tokens``
 and the prefix cache run UNCHANGED on top; ``EngineConfig(tp=N)`` is
 the only switch.  Off-TPU this runs on jax host-platform device-count
 meshes (``XLA_FLAGS=--xla_force_host_platform_device_count``), which is
-how tier-1 exercises tp=2/4 on CPU; on a TPU the shard bodies' ``auto``
+how tier-1 exercises tp=2/4 on CPU; on a TPU the bodies' ``auto``
 attention is the Mosaic-compiled paged kernel over each device's local
 heads (``ops.paged_attention.auto_impl``).  Weights and pool are born
 sharded (``param_shardings`` as the seeded init's ``out_shardings``,
@@ -53,10 +55,10 @@ that depends on the element's place in the buffer, which in bf16 moved
 tokens between a cold prompt and its prefix hit on four v5e chips
 (PERF.md, PR 21).
 
-On the device the shard bodies carry the one-chip bodies' scope names
+On the device the programs carry the one-chip scope names
 (``model_runner.SCOPES``) plus ``tp_sum`` with its halves nested
 (``tp_sum/gather``, ``tp_sum/add``).  ``_tp_sum`` also notes itself
-while a shard body is traced, so the runner's ledger of the reduction
+while a body is traced, so the runner's ledger of the reduction
 (``tp_sum_stats``: calls and received bytes of each program as it was
 traced) follows the code; ``LLMEngine.stats()`` reports it as
 ``tp_sum`` beside ``tp``.  What it costs on the chip: PERF.md section 5.
@@ -64,34 +66,15 @@ traced) follows the code; ``LLMEngine.stats()`` reports it as
 
 from __future__ import annotations
 
-import functools
+import threading
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
-from ray_tpu.llm.model_runner import (
-    PagedModelRunner,
-    _advance_slots,
-    _chunk_write,
-    _decode_sample,
-    _fork_impl,
-    _layer_loop,
-    _layernorm,
-    _merge_slots,
-    _prefill_sample,
-    _rows_write,
-    _slots_write,
-    _verify_rows,
-)
-from ray_tpu.ops.paged_attention import (
-    paged_attention,
-    paged_prefill_attention_xla,
-    paged_verify_attention,
-)
+from ray_tpu.llm.model_runner import PagedModelRunner, _fork_impl
 from ray_tpu.parallel.mesh import make_tp_mesh
 
 
@@ -196,16 +179,16 @@ class ShardedKVBlockPool(KVBlockPool):
 
 
 class TensorParallelPagedModelRunner(PagedModelRunner):
-    """``PagedModelRunner`` with the jitted steps shard_map'd over the
-    tp mesh.  Wrapper methods (``decode_step``/``verify_step``/
-    ``fork_blocks``) and the engine-facing contract are inherited; only
-    the traced bodies and parameter placement change."""
+    """``PagedModelRunner`` with its step bodies shard_map'd over the tp
+    mesh.  The traced bodies, the layer, the wrapper methods
+    (``decode_step``/``verify_step``/``prefill_chunk``/``fork_blocks``) and
+    the engine-facing contract are inherited; what changes is parameter
+    placement, the mesh around each program and ``_sum``, a collective."""
 
-    #: NOT inherited: the base class's joint program (a chunk and the decode
-    #: rows in one launch) is its single-chip body, and this runner has
-    #: shard bodies of its own; the engine finds no offer here and launches
-    #: two programs.  Separation, until the string path and this one are one
-    #: body under a mesh (ROADMAP D1), when the joint program exists once
+    #: NOT inherited: no sharded joint program (a chunk and the decode rows
+    #: in one launch) is built here, so the engine finds no offer and
+    #: launches two programs.  The inherited body would run under
+    #: ``_on_mesh`` as the others do: ROADMAP Queue 1, a claim of its own
     prefill_with_slots = None
 
     def __init__(
@@ -226,92 +209,44 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             raise ValueError(f"d_ff={cfg.d_ff} not divisible by tp={tp}")
         self.tp = tp
         #: site -> {calls, bytes} of ``_tp_sum`` in one execution of that
-        #: step program, noted when it was traced (``_tp_layers``)
+        #: step program, noted when it was traced (``_layers``)
         self._tp_sums: dict = {}
+        #: ``.noted``: what the ``_sum`` calls of the layer being traced on
+        #: this thread have noted (the engine's thread traces a first call
+        #: while a ``device_report()`` lowers the others again)
+        self._tracing = threading.local()
         self._mesh = make_tp_mesh(tp)
-        # inherited _qkv_rows reshapes to this many heads — the ones
-        # whose kernels' column shards live on this device
+        # the inherited bodies reshape to this many heads: the ones whose
+        # kernels' column shards live on this device
         self.n_local_heads = cfg.n_heads // tp
         self.params = self.prepare_params(params)
-        pspecs = self._param_spec_tree()
-        # re-jit the step functions over the mesh (the base jits were
+        weights = (self._param_spec_tree(),)
+        # re-jit the inherited bodies over the mesh (the base jits were
         # never traced); donation contract is the base class's — the
         # pool shards update in place
-        self._decode = jax.jit(
+        self._decode = self._on_mesh(self._decode_impl, weights, 5, 3, (1, 2, 3))
+        self._verify = self._on_mesh(self._verify_impl, weights, 8, 3, (1, 2))
+        self._prefill = self._on_mesh(self._prefill_impl, weights, 5, 3, (1, 2))
+        self._fork = self._on_mesh(_fork_impl, (), 2, 0, (0, 1))
+
+    def _on_mesh(self, body, weights: tuple, n_in: int, n_out: int, donate: tuple):
+        """``body`` as one program over the mesh: its operands are
+        ``weights`` (the specs of a leading weight tree, or none), the two
+        pools with their heads sharded, then ``n_in`` replicated operands;
+        it returns the pools and ``n_out`` replicated results (reduced
+        activations are the same on every device, so ``lm_head`` and the
+        sampler run identically on each and ``P()`` reads one copy).
+        ``donate``: the operands updated in place."""
+        pool = P(None, None, "tp", None, None)
+        return jax.jit(
             jax.shard_map(
-                self._decode_shard,
+                body,
                 mesh=self._mesh,
-                in_specs=(
-                    pspecs,
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(), P(), P(),
-                ),
-                out_specs=(
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(),
-                ),
+                in_specs=(*weights, pool, pool, *((P(),) * n_in)),
+                out_specs=(pool, pool, *((P(),) * n_out)),
                 check_vma=False,
             ),
-            donate_argnums=(1, 2, 3),
-        )
-        self._verify = jax.jit(
-            jax.shard_map(
-                self._verify_shard,
-                mesh=self._mesh,
-                in_specs=(
-                    pspecs,
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(), P(), P(), P(), P(), P(),
-                ),
-                out_specs=(
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(),
-                ),
-                check_vma=False,
-            ),
-            donate_argnums=(1, 2),
-        )
-        self._prefill = jax.jit(
-            jax.shard_map(
-                self._prefill_shard,
-                mesh=self._mesh,
-                in_specs=(
-                    pspecs,
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(), P(), P(),
-                ),
-                out_specs=(
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(), P(),
-                ),
-                check_vma=False,
-            ),
-            donate_argnums=(1, 2),
-        )
-        # CoW fork copies whole blocks along axis 1 — head-agnostic, so
-        # the single-chip impl runs per-shard unchanged
-        self._fork = jax.jit(
-            jax.shard_map(
-                _fork_impl,
-                mesh=self._mesh,
-                in_specs=(
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                    P(), P(),
-                ),
-                out_specs=(
-                    P(None, None, "tp", None, None),
-                    P(None, None, "tp", None, None),
-                ),
-                check_vma=False,
-            ),
-            donate_argnums=(0, 1),
+            donate_argnums=donate,
         )
 
     # -- parameter placement ----------------------------------------------
@@ -378,7 +313,7 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         """Count and received bytes (per device) of the ``_tp_sum`` calls
         in every step launched so far, and ``per_step`` of one execution
         of each program, as its layer noted them when it was traced
-        (``_tp_layers``): a change of the reduction shows here, not only
+        (``_layers``): a change of the reduction shows here, not only
         in a trace."""
         launched = self.prof.stats()
         totals = {
@@ -388,68 +323,17 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         }
         return dict(totals, per_step=dict(self._tp_sums))
 
-    # -- per-device layer math --------------------------------------------
+    # -- the collective of the inherited layer -----------------------------
 
-    def _tp_layer(
-        self, x, layer, k, v, base, positions, write, attend, noted
-    ):
-        """One transformer layer on THIS device's head/ff shard, over the
-        whole local pools (``_layer_loop``'s view; ``base`` is this
-        layer's first block there).  ``attend(q, k, v, base) -> (rows,
-        local_d)`` supplies the step shape's paged attention over the
-        local head group; the two row-parallel projections produce
-        partial sums reduced over "tp" by ``_tp_sum`` (replicated biases
-        added once, after)."""
-        dt = x.dtype
+    def _sum(self, x):
+        return _tp_sum(x, "tp", self._tracing.noted)
 
-        def attn_partial(q, k, v):
-            att = attend(q, k, v, base)  # paged_attention names its own scope
-            with jax.named_scope("attn_out"):
-                return att @ layer["attn_out"]["kernel"].astype(dt)
-
-        def mlp_partial(h):
-            with jax.named_scope("mlp"):
-                mid = jax.nn.gelu(
-                    h @ layer["mlp_in"]["kernel"].astype(dt)
-                    + layer["mlp_in"]["bias"].astype(dt)
-                )
-                return mid @ layer["mlp_out"]["kernel"].astype(dt)
-
-        def biased(h, mod, scope):
-            # the replicated bias, added once after the reduction, under
-            # the scope in which the one-chip body adds it
-            with jax.named_scope(scope):
-                return h + layer[mod]["bias"].astype(dt)
-
-        ln1, q, k, v = self._qkv_write(x, layer, k, v, base, positions, write)
-        att_p = attn_partial(q, k, v)
-        if self.arch == "gptj":
-            # parallel residual: attention + MLP partials share ONE
-            # fused reduction per layer (half the collectives of the
-            # sequential-residual arch below)
-            out = biased(
-                x + _tp_sum(att_p + mlp_partial(ln1), "tp", noted),
-                "mlp_out", "mlp",
-            )
-        else:
-            h = biased(
-                x + _tp_sum(att_p, "tp", noted), "attn_out", "attn_out"
-            )
-            ln2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-            out = biased(
-                h + _tp_sum(mlp_partial(ln2), "tp", noted), "mlp_out", "mlp"
-            )
-        return out, k, v
-
-    def _tp_layers(self, site, params, x, k_pool, v_pool, **rows):
-        """``_layer_loop`` over ``_tp_layer``.  The loop traces its layer
-        once for all of them: what that layer's ``_tp_sum`` calls noted,
-        times the layers, is program ``site``'s line in ``tp_sum_stats``."""
-        noted: list = []
-        out = _layer_loop(
-            params["blocks"], x, k_pool, v_pool,
-            functools.partial(self._tp_layer, noted=noted, **rows),
-        )
+    def _layers(self, site, params, x, k_pool, v_pool, **rows):
+        """The inherited loop, which traces its layer once for all of them:
+        what that layer's ``_sum`` calls noted, times the layers, is
+        program ``site``'s line in ``tp_sum_stats``."""
+        noted = self._tracing.noted = []
+        out = super()._layers(site, params, x, k_pool, v_pool, **rows)
         n_layers = k_pool.shape[0]
         # written, never read, while tracing: the program's own account
         # of itself, the one thing meant to be fixed at trace time
@@ -457,107 +341,3 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             "calls": n_layers * len(noted), "bytes": n_layers * sum(noted)
         }
         return out
-
-    # -- shard bodies ------------------------------------------------------
-    # Same control flow as the PagedModelRunner._*_impl bodies, with the
-    # pool/head math local and the reductions explicit.  Reduced
-    # activations are replicated, so lm_head + sampling run identically
-    # on every device and the P() out_specs read one copy.
-
-    def _decode_shard(
-        self, params, k_pool, v_pool, carry, first_tok, patch, tables, knobs,
-    ):
-        bs = self.block_size
-        tokens, positions, counters = _merge_slots(carry, first_tok, patch)
-        S = tokens.shape[0]
-        x = self._embed(params, tokens, positions)
-        phys = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
-        off = positions % bs
-        lengths = positions + 1
-
-        def attend(q, k, v, base):
-            return paged_attention(
-                q, k, v, tables + base, lengths, impl=self.attn_impl
-            ).astype(x.dtype).reshape(S, -1)
-
-        x, k_pool, v_pool = self._tp_layers(
-            "decode", params, x, k_pool, v_pool,
-            positions=positions, write=_slots_write(phys, off, bs), attend=attend,
-        )
-        logits = self._lm_head(params, x)
-        live, nxt, logp = _decode_sample(logits, knobs, counters)
-        return (
-            k_pool, v_pool, _advance_slots(live, nxt, positions, counters), nxt, logp
-        )
-
-    def _verify_shard(
-        self, params, k_pool, v_pool, tokens, base_pos, tables,
-        temp, top_k, top_p, seeds, counters,
-    ):
-        cfg = self.cfg
-        bs = self.block_size
-        S, W = tokens.shape
-        tmax = tables.shape[1]
-        positions = base_pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-        pos_flat = positions.reshape(-1)
-        x = self._embed(params, tokens.reshape(-1), pos_flat)
-        # overflow positions clamp to trash exactly like the base impl
-        valid = pos_flat < tmax * bs
-        logical = jnp.minimum(pos_flat // bs, tmax - 1)
-        tables_rep = jnp.repeat(tables, W, axis=0)
-        phys = jnp.where(
-            valid,
-            jnp.take_along_axis(tables_rep, logical[:, None], axis=1)[:, 0],
-            0,
-        )
-        off = pos_flat % bs
-        nh, hd = self.n_local_heads, cfg.head_dim
-
-        def attend(q, k, v, base):
-            return paged_verify_attention(
-                q.reshape(S, W, nh, hd), k, v, tables + base, positions,
-                impl=self.attn_impl,
-            ).astype(x.dtype).reshape(S * W, -1)
-
-        x, k_pool, v_pool = self._tp_layers(
-            "verify", params, x, k_pool, v_pool,
-            positions=pos_flat, write=_rows_write(phys, off), attend=attend,
-        )
-        logits = self._lm_head(params, x).reshape(S, W, -1)
-        n_acc, out, logp = _verify_rows(
-            logits, tokens[:, 1:], seeds, counters, temp, top_k, top_p
-        )
-        return k_pool, v_pool, n_acc, out, logp
-
-    def _prefill_shard(
-        self, params, k_pool, v_pool, tokens, start, n_valid, table, sampling,
-    ):
-        # chunk is tokens.shape[0] — static under jit, but NOT a static
-        # kwarg: shard_map takes positional specs only, and the engine
-        # always pads to cfg.prefill_chunk so this still traces once
-        chunk = tokens.shape[0]
-        positions = start + jnp.arange(chunk, dtype=jnp.int32)
-        x = self._embed(params, tokens, positions)
-
-        def attend(q, k, v, base):
-            return paged_prefill_attention_xla(
-                q, k, v, table + base, positions
-            ).astype(x.dtype).reshape(chunk, -1)
-
-        x, k_pool, v_pool = self._tp_layers(
-            "prefill", params, x, k_pool, v_pool,
-            positions=positions, attend=attend,
-            write=_chunk_write(table, start, n_valid, chunk, self.block_size),
-        )
-        last = x[jnp.maximum(n_valid - 1, 0)]
-        logits = self._lm_head(params, last[None, :])[0]
-        tok, logp = _prefill_sample(logits, sampling)
-        return k_pool, v_pool, logits, tok, logp
-
-    def prefill_chunk(self, k_pool, v_pool, tokens, start, n_valid, table, sampling):
-        # base passes chunk= as a static kwarg; the shard body derives it
-        return self._call(
-            "prefill", self._prefill, len(tokens),
-            self.params, k_pool, v_pool, tokens,
-            np.int32(start), np.int32(n_valid), table, sampling,
-        )

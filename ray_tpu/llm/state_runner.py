@@ -63,6 +63,7 @@ from ray_tpu.llm.model_runner import (
     _advance_slots,
     _carry_loop,
     _decode_sample,
+    _fork_impl,
     _merge_slots,
     _prefill_sample,
 )
@@ -152,8 +153,7 @@ class HybridModelRunner(StepRunner):
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=pools, static_argnames=("chunk",)
         )
-        self._fork = jax.jit(
-            self._fork_impl, donate_argnums=tuple(range(self.n_paged)))
+        self._fork = jax.jit(_fork_impl, donate_argnums=tuple(range(self.n_paged)))
 
     def _decode_logits(self, params, arrays, tokens, positions, tables):
         """The model's part of a decode.  Returns (arrays, logits (S, V))."""
@@ -212,14 +212,9 @@ class HybridModelRunner(StepRunner):
             len(arrays), chunk=len(tokens),
         )
 
-    def _fork_impl(self, *rest):
-        *pools, src, dst = rest
-        with jax.named_scope("kv_fork"):
-            return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
-
     def fork_blocks(self, *operands):
         """Copy-on-write for the prefix cache: blocks ``src`` onto ``dst`` in
-        every layer of every PAGED array (``model_runner._fork_impl``'s, for
+        every layer of every PAGED array (``model_runner._fork_impl``, for
         whatever a block holds).  The engine refuses the prefix cache for a
         body with state, so the state's leaves pass through untouched."""
         *arrays, src, dst = operands

@@ -16,7 +16,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness as H  # noqa: E402
 
-PRE = "jit(_decode_shard)/shard_map/while/body/closed_call/"
+PRE = "jit(_decode_impl)/shard_map/while/body/closed_call/"
 GATHER = "%all-gather.7 = bf16[256,4096]{1,0} all-gather(bf16[64,4096]{1,0} %fusion.196)"
 ADD = "%fusion.12 = bf16[64,4096]{1,0} fusion(bf16[256,4096]{1,0} %all-gather.7), kind=kLoop"
 MLP = "%fusion.3 = bf16[64,4096]{1,0} fusion(bf16[64,4096]{1,0} %p.1), kind=kOutput"
@@ -26,7 +26,7 @@ def _trace(nested: bool):
     """Two decodes of 10 ms and one prefill; ns.  In each decode a gather of
     0.2 ms, adds of 0.1 ms and an MLP op; the prefill's gather is not decode's."""
     ops, modules = [], []
-    for i, prog in enumerate(["jit__decode_shard", "jit__prefill_shard", "jit__decode_shard"]):
+    for i, prog in enumerate(["jit__decode_impl", "jit__prefill_impl", "jit__decode_impl"]):
         t = i * 20e6
         modules.append((t, t + 10e6, prog))
         ops += [(GATHER, t + 1e6, 0.2e6), (ADD, t + 2e6, 0.1e6), (MLP, t + 3e6, 5e6)]

@@ -108,13 +108,12 @@ def test_joint_program_is_the_two_programs(arch, chunk_case, sampled):
     runner = _runner(arch)
     dec, pre = _operands(chunk_case, sampled)
     k, v = _pools()
-    k, v, _logits, tok, tok_logp = runner._prefill(
-        runner.params, k, v, *pre, chunk=CHUNK)
+    k, v, _logits, tok, tok_logp = runner._prefill(runner.params, k, v, *pre)
     k, v, carry, nxt, logp = runner._decode(runner.params, k, v, *dec)
     want = dict(k=k, v=v, carry=carry, nxt=nxt, logp=logp, tok=tok, tok_logp=tok_logp)
     got = dict(zip(
         ("k", "v", "carry", "nxt", "logp", "tok", "tok_logp"),
-        runner._prefill_with_slots(runner.params, *_pools(), *dec, *pre, chunk=CHUNK),
+        runner._prefill_with_slots(runner.params, *_pools(), *dec, *pre),
     ))
     # bit for bit, the trash block too: on a CPU a row's products do not
     # depend on how many rows stand beside it (on the chip they may, in the

@@ -244,6 +244,57 @@ def test_stats_carry_tp_and_the_reductions_ledger(tp):
     )
 
 
+@pytest.mark.parametrize("site", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("arch,sums_a_layer", [("gptj", 1), ("gpt", 2)])
+def test_reductions_a_layer_follow_the_inherited_layer(arch, sums_a_layer, site):
+    """The one ``_layer`` under the mesh, each program lowered (traced, not
+    run): GPT-J's parallel residual sums attention and MLP partials ONCE a
+    layer, GPT's sequential residual twice, and each call receives the
+    other device's rows."""
+    import jax
+
+    from ray_tpu.llm.multichip import TensorParallelPagedModelRunner
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+
+    cfg, init = TINY, gptj_init
+    if arch == "gpt":
+        cfg, init = GPTConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
+                              seq_len=64, dtype="float32"), gpt_init
+    runner = TensorParallelPagedModelRunner(
+        cfg, init(jax.random.PRNGKey(0), cfg), 4, "xla", tp=2)
+
+    def a(*shape, dtype="int32"):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    S, W, T = 3, 3, 12  # slots, window, table width; a chunk of 8
+    pool = a(cfg.n_layers, 16, cfg.n_heads, 4, cfg.head_dim, dtype="float32")
+    rows, operands = {
+        "decode": (S, (a(3, S), a(1), a(S, 4), a(S, T), a(S, 5))),
+        "prefill": (8, (a(8), a(), a(), a(T), a(5))),
+        "verify": (S * W, (a(S, W), a(S), a(S, T), a(S, dtype="float32"), a(S),
+                           a(S, dtype="float32"), a(S, dtype="uint32"), a(S))),
+    }[site]
+    getattr(runner, "_" + site).lower(runner.params, pool, pool, *operands)
+    calls = cfg.n_layers * sums_a_layer
+    assert runner.tp_sum_stats()["per_step"] == {
+        site: {"calls": calls, "bytes": calls * rows * cfg.d_model * 4}}
+
+
+@pytest.mark.parametrize("name", [
+    "_layer", "_decode_impl", "_verify_impl", "_prefill_impl",
+    "_prefill_with_slots_impl", "prefill_chunk",
+    "_tp_layer", "_tp_layers", "_decode_shard", "_verify_shard", "_prefill_shard",
+])
+def test_tp_runner_owns_no_step_body(name):
+    """The step programs are written once: the mesh wraps the one-chip
+    runner's bodies, and the names of the second copies are gone."""
+    from ray_tpu.llm.model_runner import PagedModelRunner
+    from ray_tpu.llm.multichip import TensorParallelPagedModelRunner as TP
+
+    assert name not in vars(TP)
+    assert getattr(TP, name, None) is getattr(PagedModelRunner, name, None)
+
+
 @pytest.mark.parametrize("tp", [2, 4])
 def test_per_device_hbm_ledger(tp):
     """Per-device attribution: the pool splits exactly 1/tp per device,

@@ -2,7 +2,7 @@
 WHOLE pool in place (``model_runner._layer_loop``, PR 24).
 
 Two properties per step (decode, prefill, verify) x arch (gpt, gptj), and
-the three tensor-parallel shard bodies at tp=2 on host devices; the first
+the same three bodies under the tensor-parallel mesh at tp=2 on host devices; the first
 also for the program that carries a chunk AND the slots' rows (ISSUE 47;
 ``tests/test_llm_joint_step.py`` holds its writes to the two programs').  A prefill
 chunk and a decode write WHOLE blocks (``model_runner._scatter_kv_blocks``),
@@ -177,15 +177,14 @@ def _runner(arch, tp, bs=BS):
 
 
 def _jitted(runner, step, ops):
-    """(jitted fn, its operands after the pools, static kwargs) of a step,
-    as the runner's wrappers call it: the decode takes its batch as slot
-    state and a patch (``host_batch``), the prefill a sampler row."""
+    """(jitted fn, its operands after the pools) of a step, as the runner's
+    wrappers call it: the decode takes its batch as slot state and a patch
+    (``host_batch``), the prefill a sampler row."""
     if step == "decode":
-        return runner._decode, host_batch(*ops), {}
+        return runner._decode, host_batch(*ops)
     if step.startswith("prefill"):
-        static = {} if hasattr(runner, "tp") else {"chunk": len(ops[0])}
-        return runner._prefill, ops + (pack_knobs(0, 0.0, 0, 1.0, 0),), static
-    return runner._verify, ops, {}
+        return runner._prefill, ops + (pack_knobs(0, 0.0, 0, 1.0, 0),)
+    return runner._verify, ops
 
 
 @pytest.mark.parametrize("step,arch,tp", _cases(STEPS + ("prefill@5+127",)))
@@ -194,8 +193,8 @@ def test_step_holds_no_pool_sized_temporary(step, arch, tp):
     runner = _runner(arch, tp, bs)
     k, v = _noise_pools(tp, bs)
     ops, _rows = _operands(step)
-    fn, ops, static = _jitted(runner, step, ops)
-    compiled = fn.lower(runner.params, k, v, *ops, **static).compile()
+    fn, ops = _jitted(runner, step, ops)
+    compiled = fn.lower(runner.params, k, v, *ops).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     pool_bytes = k.nbytes // tp  # one pool's bytes on one device
     assert temp < pool_bytes / 2, (
@@ -217,7 +216,7 @@ def test_joint_step_holds_no_pool_sized_temporary(arch, chunk_step):
     decode = host_batch(*_operands("decode")[0])
     chunk = _operands(chunk_step)[0] + (pack_knobs(0, 0.0, 0, 1.0, 0),)
     compiled = runner._prefill_with_slots.lower(
-        runner.params, k, v, *decode, *chunk, chunk=len(chunk[0])
+        runner.params, k, v, *decode, *chunk
     ).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < k.nbytes / 2, (
@@ -231,8 +230,9 @@ def _reference(arch, step):
     """The same step as a plain Python loop over layers: layer ``l``
     scatters into and attends over ITS pool ``k_pool[l]`` with the
     tables as given.  Single-chip float32; the layer math is the
-    runner's own helpers (not under test here), the loop, the writes and
-    the reads are not.  (One a (arch, step): tp 1 and 2 share it.)"""
+    runner's own helpers (not under test here; the two row-parallel
+    products come without their bias, added here where a layer adds it,
+    after the residual), the loop, the writes and the reads are not.  (One a (arch, step): tp 1 and 2 share it.)"""
     cfg, _ = ARCHS[arch]
     params = _params(arch)
     bs = _geometry(step)[0]
@@ -261,12 +261,12 @@ def _reference(arch, step):
             )
         att = ref._attn_out(layer, att.reshape(n, HEADS * HD))
         if arch == "gptj":
-            x = x + att + ref._mlp(layer, ln1)
+            x = x + (att + ref._mlp(layer, ln1)) + layer["mlp_out"]["bias"]
         else:
-            h = x + att
+            h = x + att + layer["attn_out"]["bias"]
             x = h + ref._mlp(
                 layer, _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-            )
+            ) + layer["mlp_out"]["bias"]
         k_pool, v_pool = k_pool.at[l].set(k_l), v_pool.at[l].set(v_l)
     if step.startswith("prefill"):
         out = (ref._lm_head(params, x[int(ops[2]) - 1][None, :])[0],)
@@ -285,9 +285,9 @@ def test_step_writes_only_its_rows_in_their_layer(step, arch, tp):
     k0, v0 = (np.asarray(a) for a in _noise_pools(1, bs))
     ops, rows = _operands(step)
     ref_k, ref_v, ref_out = _reference(arch, step)
-    fn, sent, static = _jitted(runner, step, ops)
+    fn, sent = _jitted(runner, step, ops)
     k, v = _noise_pools(tp, bs)
-    k1, v1, *out = fn(runner.params, k, v, *sent, **static)
+    k1, v1, *out = fn(runner.params, k, v, *sent)
     if step == "decode":
         # the carry the next step feeds from: the sampled token, one
         # position and one counter on
