@@ -646,7 +646,10 @@ class HybridPool:
     preempted for blocks (recompute: its next first chunk overwrites its
     new slot), and a free returns both.  Block counts, ``block_bytes`` and
     the free / owned partition are the K/V pool's, where the pressure is;
-    the slots stand beside them (``ledger_counts``, ``audit``).
+    the slots stand beside them (``ledger_counts``, ``audit``).  The K/V pool
+    has the layers the family's ``kv_layout()`` gives it (ONE shared layer:
+    ``models.phi4flash``; every layer's: ``models.falcon_h1``), and a block's
+    bytes are its rows in all of them.
 
     A table row is ``[slot, block table...]`` and ``arrays`` the K/V pool's
     two followed by the state's leaves, in the order the model's steps take

@@ -6,10 +6,11 @@ one scheduler.  A model without keys and values (``cache_kind == "state"``:
 same calls: a sequence owns one fixed-size recurrent state, so admission is
 a free slot, nothing grows or is preempted, and the prefix cache,
 speculation and ``tp > 1`` are refused.  A model with BOTH
-(``cache_kind == "hybrid"``: ``models.phi4flash``) gets ``cache.HybridPool``
-and ``state_runner.HybridModelRunner``: a slot of state and growing blocks
-of one K/V layer behind one ledger, admitted when both are free, preempted
-for blocks, refused the same three.  A family whose body keeps blocks
+(``cache_kind == "hybrid"``: ``models.phi4flash``, ``models.falcon_h1``) gets
+``cache.HybridPool`` and ``state_runner.HybridModelRunner``: a slot of state
+and growing blocks of K/V (as many layers of them as the family's
+``kv_layout()`` says: one shared layer, or every layer's) behind one ledger,
+admitted when both are free, preempted for blocks, refused the same three.  A family whose body keeps blocks
 ALONE, of whatever it says a token leaves behind (``cache_kind == "paged"``:
 ``models.kimi_k2``, one array of latent rows), gets the same runner over a
 ``cache.KVBlockPool`` of that layout: its blocks are shared, forked and
@@ -613,9 +614,12 @@ class LLMEngine:
         # that overwrote a slot's state, decodes launched and the live rows
         # they were sent (each row's state is read and written once a layer)
         # and the tokens of context those rows stood at (what an attention
-        # over a sequence's own K/V reads)
+        # over a sequence's own K/V reads); and the chunks launched, their
+        # valid tokens and the tokens of context they attended (a chunk's
+        # attention walks the sequence's K/V up to its own last token)
         self._state_n = {"overwrites": 0, "decodes": 0, "decode_rows": 0,
-                         "decode_tokens": 0}
+                         "decode_tokens": 0, "chunks": 0, "chunk_tokens": 0,
+                         "chunk_context_tokens": 0}
         #: the pool of fixed-size states, where the model has one: the pool
         #: itself, or the part of a hybrid pool
         self._states = self.pool if isinstance(self.pool, StatePool) else getattr(
@@ -632,7 +636,9 @@ class LLMEngine:
         what = type(self.model_cfg).__name__
         holds = "no keys or values, only one recurrent state"
         if getattr(self.model_cfg, "cache_kind", "") == "hybrid":
-            holds = "a recurrent state beside one layer's keys and values"
+            n = self.model_cfg.serving_body().kv_layout()["n_layers"]
+            holds = ("a recurrent state beside "
+                     + ("one layer's" if n == 1 else f"{n} layers'") + " keys and values")
         if self.cfg.prefix_cache and state:
             raise ValueError(
                 f"prefix_cache=True with {what}: the radix prefix cache shares "
@@ -1126,7 +1132,7 @@ class LLMEngine:
                     self._state_n, slots=st.cfg.slots, live=st.num_used_blocks,
                     bytes=st.device_bytes, kinds=st.leaf_bytes(),
                 )
-                if st is not self.pool:  # a hybrid pool: its ONE K/V layer
+                if st is not self.pool:  # a hybrid pool: its K/V layers
                     s["kv_pool"] = self._kv_pool_stats(led)
             elif self.runner.arch == "hybrid":
                 # a body over blocks alone: the same account of its decodes
@@ -1413,6 +1419,9 @@ class LLMEngine:
                 self._state_n["overwrites"] += 1  # read by a state pool only
             req.prefill_pos += n_valid
             self._prefill_tokens += n_valid
+            self._state_n["chunks"] += 1
+            self._state_n["chunk_tokens"] += n_valid
+            self._state_n["chunk_context_tokens"] += req.prefill_pos
             if req.phase_led is not None:
                 # a recompute's re-prefill is preemption cost, not prefill
                 _phases.charge(

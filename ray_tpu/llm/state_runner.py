@@ -33,9 +33,10 @@ several loops; ``arrays`` is the pool's tuple, every one donated and handed
 back in its place.  Two kinds of family take it:
 
 * sequences hold blocks of K/V AND a slot of state (``cache_kind ==
-  "hybrid"``, ``cache.HybridPool``; ``models.phi4flash``): a table row is
-  ``[slot, block table...]``, and a dead decode row feeds position 0 of the
-  trash slot and the trash block;
+  "hybrid"``, ``cache.HybridPool``; ``models.phi4flash``: ONE shared K/V
+  layer; ``models.falcon_h1``: K/V in every layer): a table row is ``[slot,
+  block table...]``, and a dead decode row feeds position 0 of the trash slot
+  and the trash block;
 * sequences hold blocks alone, of whatever the body's ``kv_layout()`` says a
   token leaves behind (``cache_kind == "paged"``, a ``cache.KVBlockPool``;
   ``models.kimi_k2``: one array of latent rows): a table row is the block

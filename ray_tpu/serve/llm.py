@@ -68,6 +68,8 @@ _FAMILIES = {
     "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig", "phi4flash_init",
                   "Phi4FlashConfig"),
     "kimi_k2": ("ray_tpu.models.kimi_k2", "KimiK2Config", "kimi_k2_init", "KimiK2Config"),
+    "falcon_h1": ("ray_tpu.models.falcon_h1", "FalconH1Config", "falcon_h1_init",
+                  "FalconH1Config"),
 }
 
 

@@ -108,3 +108,44 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip):
         sds((72, 7168, 2048), jnp.bfloat16), sds((72, 7168, 2048), jnp.bfloat16),
         sds((72, 2048, 7168), jnp.bfloat16), sds((), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(one_chip):
+    """Falcon-H1's Mamba-2 update at the cell's sizes: 16 rows, 32 heads of
+    128 x 256 float32, 8 heads a grid step.  The pool is aliased and no copy
+    of it stands among the temporaries."""
+    from ray_tpu.ops import ssd
+
+    s, h, p, n, slots = 16, 32, 128, 256, 34
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda state, *rest: ssd._decode_core_pallas(state, *rest, interpret=False),
+        donate_argnums=(0,),
+    ).lower(sds((slots, h, p, n)), sds((s, h, p)), sds((s, h)), sds((s, h, n)),
+            sds((s, h, n)), sds((s,), jnp.int32), sds((s,), jnp.bool_)).compile()
+    mem, pool = compiled.memory_analysis(), slots * h * p * n * 4
+    assert "tpu_custom_call" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes < pool // 16
+
+
+def test_five_query_heads_a_kv_head_through_the_paged_kernel_compile_for_a_v5e(
+        one_chip, monkeypatch):
+    """Falcon-H1's decode attention: 20 query heads on 4 key-value heads of
+    128 ride the window axis (w = 5) over 8 layers of 1,601 blocks of 128."""
+    from ray_tpu.ops import gqa_attention as ga
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((8 * 1601, 4, 128, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, p: ga.gqa_paged_attention(q, k, v, t, p, impl="pallas")
+    ).lower(sds((16, 20, 128), jnp.bfloat16), pool, pool,
+            sds((16, 100), jnp.int32), sds((16,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
