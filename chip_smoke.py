@@ -494,9 +494,11 @@ def phase_kernels(args) -> None:
         (q_abs, rows_pool, tables, at), ["latent_attention_decode"],
     )
     # the chunk's flash kernel against the dense softmax: 512 queries that
-    # start inside a block and inside a key tile, over the same table
+    # start at key 0, inside a block and inside a key tile, and where the
+    # cell's chunks do (16,384: 21 key tiles every query sees whole take the
+    # path without a mask, the 22nd the masked one), over the same table
     c_len, dn, dv = (512, 128, 128) if on_chip else (8, 8, 16)
-    for start in (0, 5 * bs + 3) if on_chip else (bs + 1,):
+    for start in (0, 5 * bs + 3, 128 * bs) if on_chip else (bs + 1,):
         def chunk(impl, start=start):
             return lambda qn, qr, pool, wk, wv: la.latent_chunk_attention(
                 qn, qr, pool, tables[0], start + jnp.arange(c_len, dtype=jnp.int32), wk, wv,

@@ -33,10 +33,34 @@ shapes tile, else ``xla`` (the reference path, and what runs off a TPU):
 * chunk: ``xla`` expands keys and values a group of heads at a time and
   takes a dense masked softmax over the table's whole width: its float32
   scores cross HBM six times (measured: 117 of a chunk's 142 ms on a v5e at
-  the published sizes).  ``pallas`` expands every head's keys and values
-  ONCE (two plain matrix products) and runs a flash kernel a head: key tiles
-  stream through VMEM, the scores never leave it, and a tile past the
-  chunk's last position is neither fetched anew nor computed.
+  the published sizes, PR 45).  ``pallas`` expands every head's keys and
+  values ONCE (two plain matrix products) and runs a flash kernel over them
+  (``latent_attention_chunk``): key tiles stream through VMEM, the scores
+  never leave it, and a tile past the chunk's last position is neither
+  fetched anew nor computed.  What sets its pace is the MXU (PR 51; the
+  kernel it replaced spent 38% of a grid step outside the products):
+
+  - a grid step holds FOUR heads' key tile and walks it in sub-tiles of 384
+    keys as one straight line, the next sub-tile's score product written
+    BEFORE this one's softmax (Mosaic's scheduler keeps the order it is
+    given: that, not the scheduler, is what puts the products under the
+    ``exp``), the walk running on across the step's heads;
+  - the running maximum and sum are held REPLICATED over 128 lanes, (rows,
+    128): as (rows, 1) columns every use of one against a score tile is a
+    lane broadcast through the XLU, which then sets the pace of a
+    sub-tile (a sub-tile of 256 cost 7.0 ms a layer against the whole
+    tile's 3.6);
+  - the two score products are ONE 192-deep contraction over ``[nope |
+    rope]`` (no float32 add over the tile); the scale stays in float32
+    on the scores' side (on ``score - maximum``: the VALU has the room),
+    the queries coming rounded from ``_project``, whose signature the
+    benchmark's controls override;
+  - a tile every query of the chunk sees whole takes a path with no iota,
+    compare or select; the one or two tiles the diagonal crosses keep the
+    mask.  Both paths are the same operations in the same order, sub-tile
+    edges are multiples of 384 from key 0 whatever ``start`` is, and a
+    row's statistics are its own: a query's output is the same to the bit
+    whichever chunk brings it (a prefix hit's tokens are the cold run's).
 """
 
 from __future__ import annotations
@@ -196,30 +220,61 @@ def latent_decode_attention(q, pool, tables, positions, *, rank: int, scale: flo
     return fn(q, pool, tables, positions, rank=rank, scale=scale)
 
 
-def _key_tile(total: int, interpret: bool):
-    """Keys a grid step of the chunk kernel takes: the largest divisor of
-    the table's width up to 1,024 that is whole lane tiles (768 of 17,664);
-    None where there is none (the XLA path then)."""
-    if interpret and total <= 1024:
-        return total
+def _divisor(n: int, cap: int, interpret: bool):
+    """The largest divisor of ``n`` up to ``cap`` that is whole lane tiles
+    (any divisor under the interpreter); None where there is none."""
     step = 1 if interpret else 128
-    return next((t for t in range(1024 // step * step, 0, -step) if total % t == 0), None)
+    return next((d for d in range(min(n, cap) // step * step, 0, -step) if n % d == 0), None)
+
+
+#: keys of one SUB-TILE of a grid step's key tile, at most (the step walks
+#: its tile in these: 384 read best of 128 / 256 / 384 / 768 on a v5e), and
+#: heads a grid step takes, at most (a step costs about 0.3 us whatever it
+#: holds: 1 / 2 / 4 / 8 heads read 2.72 / 2.60 / 2.47 / 2.42 ms a layer)
+_SUB_KEYS = 384
+_STEP_HEADS = 4
+
+
+def _key_tile(total: int, interpret: bool):
+    """Keys a grid step of the chunk kernel takes: the largest such divisor
+    of the table's width up to 1,024 (768 of 17,664); None: the XLA path."""
+    return _divisor(total, 1024, interpret)
+
+
+def _sub_tile(tile: int, interpret: bool) -> int:
+    """Keys of one sub-tile of a key tile (384 of 768).  Sub-tile edges are
+    multiples of it from key 0."""
+    return _divisor(tile, _SUB_KEYS, interpret)
+
+
+def _lanes(stat, width: int):
+    """A row statistic against ``width`` columns.  It is held replicated
+    over 128 lanes, (rows, 128), and tiled from there; a (rows, 1) column
+    (widths off the lane tiling: the interpreter) broadcasts by itself."""
+    held = stat.shape[-1]
+    return stat if held in (1, width) else pltpu.repeat(stat, width // held, axis=1)
 
 
 def _chunk_flash_kernel(
     start_ref,    # scalar prefetch: (1,) int32, the chunk's first position
-    qn_ref,       # (1, C, dn)
-    qr_ref,       # (1, C, dr)
-    kn_ref,       # (tile, dn): this head's expanded keys
+    q_ref,        # (heads, C, dn + dr): [q_nope | q_rope]
+    kn_ref,       # (tile, heads * dn): these heads' expanded keys
     kr_ref,       # (tile, dr): the rotated key part, one for all heads
-    v_ref,        # (tile, dv)
-    o_ref,        # (1, C, dv) float32
-    m_sc, l_sc, acc_sc,
-    *, tile: int, scale: float,
+    v_ref,        # (tile, heads * dv)
+    o_ref,        # (heads, C, dv) float32
+    m_sc, l_sc,   # (heads, C, 128 | 1) float32: the running maximum (unscaled) and sum
+    acc_sc,       # (heads, C, dv) float32
+    *, tile: int, sub: int, scale: float,
 ):
     j = pl.program_id(1)
-    c_len = qn_ref.shape[1]
+    heads, c_len, _ = q_ref.shape
+    dn, dv = kn_ref.shape[1] // heads, v_ref.shape[1] // heads
     start = start_ref[0]
+    first = j * tile
+    nt = (((1,), (1,)), ((), ()))
+    # (head, sub-tile) pairs in the order walked; a head's sub-tiles are
+    # multiples of ``sub`` from key 0 of the table, whatever ``start`` is
+    units = [(i, t * sub) for i in range(heads) for t in range(tile // sub)]
 
     @pl.when(j == 0)
     def _open():
@@ -227,36 +282,71 @@ def _chunk_flash_kernel(
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    @pl.when(j * tile < start + c_len)    # the chunk's last query sees into this tile
-    def _tile():
-        nt = (((1,), (1,)), ((), ()))
-        scores = (
-            jax.lax.dot_general(qn_ref[0], kn_ref[...], nt, preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(qr_ref[0], kr_ref[...], nt,
-                                  preferred_element_type=jnp.float32)
-        ) * scale                                               # (C, tile)
-        q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (c_len, 1), 0)
-        k_pos = j * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-        seen = k_pos <= q_pos
-        scores = jnp.where(seen, scores, NEG_INF)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(scores - m_new), 0.0)
-        l_sc[...] = l_sc[...] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
+    def scores(i, lo, masked):
+        """(C, sub) float32, UNSCALED: one product over [nope | rope]."""
+        keys = pl.ds(lo, sub)
+        k = jnp.concatenate([kn_ref[keys, pl.ds(i * dn, dn)], kr_ref[keys, :]], axis=1)
+        s = jax.lax.dot_general(q_ref[i], k, nt, preferred_element_type=jnp.float32)
+        if masked:
+            q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (c_len, 1), 0)
+            k_pos = first + lo + jax.lax.broadcasted_iota(jnp.int32, (1, sub), 1)
+            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        return s
+
+    def update(i, lo, s, carry):
+        # no second select after the ``exp``: every query sees key 0 and the
+        # walk starts there, so a row's maximum is finite from its first
+        # sub-tile on and an unseen key's ``exp`` IS 0.  The maximum is kept
+        # in the UNSCALED scores' units and the scale multiplies the
+        # difference: nothing stands between the product and the select, so
+        # the two paths cannot be compiled apart (a multiply there was fused
+        # into the subtraction on one path and not the other: one ulp)
+        m_prev, l_prev, acc = carry
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp((m_prev - m_new) * scale)
+        p = jnp.exp((s - _lanes(m_new, sub)) * scale)
+        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        v = v_ref[pl.ds(lo, sub), pl.ds(i * dv, dv)]
+        acc = acc * _lanes(alpha, dv) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    def walk(masked):
+        """Every unit of the step as ONE straight line, the NEXT unit's
+        score product written before this unit's softmax: the scheduler
+        keeps the order it is given, so that is what lets the MXU run
+        under the ``exp``.  Masked or not, a (query, sub-tile) pair goes
+        through the same operations in the same order: a seen key's select
+        returns the score itself."""
+        s_next = scores(*units[0], masked)
+        for n, (i, lo) in enumerate(units):
+            if lo == 0:
+                carry = (m_sc[i], l_sc[i], acc_sc[i])
+            s_now = s_next
+            if n + 1 < len(units):
+                s_next = scores(*units[n + 1], masked)
+            carry = update(i, lo, s_now, carry)
+            if lo == tile - sub:
+                m_sc[i], l_sc[i], acc_sc[i] = carry
+
+    live = first < start + c_len          # the chunk's last query sees into this tile
+    whole = first + tile - 1 <= start     # and its first query sees all of it
+    pl.when(jnp.logical_and(live, whole))(lambda: walk(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(lambda: walk(True))
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _close():
-        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+        o_ref[...] = acc_sc[...] / _lanes(jnp.maximum(l_sc[...], 1e-30), dv)
 
 
 def _latent_chunk_pallas(q_nope, q_rope, rows, start, w_k, w_v, *, rank, scale, tile):
     c_len, heads, dn = q_nope.shape
     dr, dv = q_rope.shape[-1], w_v.shape[-1]
+    interpret = not _on_tpu()
+    sub = _sub_tile(tile, interpret)
+    step_heads = next(n for n in (_STEP_HEADS, 2, 1) if heads % n == 0)
+    stat = 128 if sub % 128 == 0 and dv % 128 == 0 else 1
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
     lat, k_r = rows[:, :rank], rows[:, rank:rank + dr]
     # every head's keys and values ONCE, as columns: (T, H * d)
     k_nope = jnp.dot(lat, w_k.astype(lat.dtype).reshape(rank, heads * dn))
@@ -266,31 +356,30 @@ def _latent_chunk_pallas(q_nope, q_rope, rows, start, w_k, w_v, *, rank, scale, 
     last = lambda s: (s[0] + c_len - 1) // tile  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(heads, rows.shape[0] // tile),
+        grid=(heads // step_heads, rows.shape[0] // tile),
         in_specs=[
-            pl.BlockSpec((1, c_len, dn), lambda h, j, s: (h, 0, 0)),
-            pl.BlockSpec((1, c_len, dr), lambda h, j, s: (h, 0, 0)),
-            pl.BlockSpec((tile, dn), lambda h, j, s: (jnp.minimum(j, last(s)), h)),
+            pl.BlockSpec((step_heads, c_len, dn + dr), lambda h, j, s: (h, 0, 0)),
+            pl.BlockSpec((tile, step_heads * dn), lambda h, j, s: (jnp.minimum(j, last(s)), h)),
             pl.BlockSpec((tile, dr), lambda h, j, s: (jnp.minimum(j, last(s)), 0)),
-            pl.BlockSpec((tile, dv), lambda h, j, s: (jnp.minimum(j, last(s)), h)),
+            pl.BlockSpec((tile, step_heads * dv), lambda h, j, s: (jnp.minimum(j, last(s)), h)),
         ],
-        out_specs=pl.BlockSpec((1, c_len, dv), lambda h, j, s: (h, 0, 0)),
+        out_specs=pl.BlockSpec((step_heads, c_len, dv), lambda h, j, s: (h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((c_len, 1), jnp.float32),
-            pltpu.VMEM((c_len, 1), jnp.float32),
-            pltpu.VMEM((c_len, dv), jnp.float32),
+            pltpu.VMEM((step_heads, c_len, stat), jnp.float32),
+            pltpu.VMEM((step_heads, c_len, stat), jnp.float32),
+            pltpu.VMEM((step_heads, c_len, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_flash_kernel, tile=tile, scale=scale),
+        functools.partial(_chunk_flash_kernel, tile=tile, sub=sub, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((heads, c_len, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=not _on_tpu(),
+        interpret=interpret,
         name="latent_attention_chunk",
     )(jnp.reshape(start, (1,)).astype(jnp.int32),
-      q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2), k_nope, k_r, v)
+      q.transpose(1, 0, 2), k_nope, k_r, v)
     return out.transpose(1, 0, 2)
 
 

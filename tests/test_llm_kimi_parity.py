@@ -207,23 +207,70 @@ def test_absorbed_equals_expanded(impl):
         assert np.abs(np.asarray(absorbed[row]) - np.asarray(expanded[0])).max() < 1e-5
 
 
-@pytest.mark.parametrize("start,table_blocks", [(0, 6), (9, 6), (1030, 300)])
-def test_the_chunk_kernel_equals_the_dense_softmax(start, table_blocks):
-    """A chunk of 8 queries from ``start`` (inside a block; past the first
-    key tile of 1,200 keys' two) through the flash kernel and through XLA."""
-    rank, rope, heads, dn, dv, bs, chunk = 128, 8, 4, 8, 16, 4, 8
-    ks = jax.random.split(jax.random.PRNGKey(4), 6)
-    pool = jax.random.normal(ks[0], (320, bs, la.padded_width(rank, rope)))
-    table = np.asarray(jax.random.permutation(ks[1], 319)[:table_blocks] + 1)
-    args = (jax.random.normal(ks[2], (chunk, heads, dn)),
-            jax.random.normal(ks[3], (chunk, heads, rope)), pool, table,
-            start + jnp.arange(chunk, dtype=jnp.int32),
+def _chunk_operands(table_blocks, heads, bs=4, dv=16, rows=8, seed=4):
+    """(pool, table, q_nope, q_rope, w_k, w_v) of a tiny latent chunk."""
+    rank, rope, dn = 128, 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    pool = jax.random.normal(ks[0], (table_blocks + 21, bs, la.padded_width(rank, rope)))
+    table = np.asarray(jax.random.permutation(ks[1], table_blocks + 20)[:table_blocks] + 1)
+    return (pool, table, jax.random.normal(ks[2], (rows, heads, dn)),
+            jax.random.normal(ks[3], (rows, heads, rope)),
             jax.random.normal(ks[4], (rank, heads, dn)) * rank**-0.5,
             jax.random.normal(ks[5], (rank, heads, dv)) * rank**-0.5)
-    want = la.latent_chunk_attention(*args, rank=rank, scale=0.3, impl="xla")
-    got = la.latent_chunk_attention(*args, rank=rank, scale=0.3, impl="pallas")
-    assert got.shape == (chunk, heads, dv)
+
+
+def _chunk(impl, pool, table, q_nope, q_rope, w_k, w_v, start):
+    return la.latent_chunk_attention(
+        q_nope, q_rope, pool, table, start + jnp.arange(q_nope.shape[0], dtype=jnp.int32),
+        w_k, w_v, rank=128, scale=0.3, impl=impl)
+
+
+@pytest.mark.parametrize("start,table_blocks,heads,bs,dv", [
+    (0, 6, 4, 4, 16), (9, 6, 4, 4, 16), (1030, 300, 4, 4, 16),
+    # 1,200 keys: two key tiles of 600, each walked in two sub-tiles of 300
+    (600, 300, 4, 4, 16),     # the chunk starts exactly on a key tile's edge
+    (596, 300, 4, 4, 16),     # its diagonal crosses TWO tiles
+    (1192, 300, 4, 4, 16),    # a tile every query sees whole, then the diagonal's
+    (1500, 450, 2, 4, 16),    # tiles of 900 in THREE sub-tiles; two heads, one grid step
+    (9, 6, 3, 4, 16),         # an odd head count: one head a grid step
+    (596, 300, 6, 4, 16),     # six heads: two a grid step, three steps a tile
+    (700, 6, 2, 128, 128),    # whole lane tiles: the statistics replicated over 128 lanes
+])
+def test_the_chunk_kernel_equals_the_dense_softmax(start, table_blocks, heads, bs, dv):
+    """A chunk of 8 queries from ``start`` (inside a block; on, across and
+    past key tiles' edges) through the flash kernel and through XLA."""
+    pool, table, *rest = _chunk_operands(table_blocks, heads, bs, dv)
+    assert start + 8 <= table_blocks * bs
+    want = _chunk("xla", pool, table, *rest, start)
+    got = _chunk("pallas", pool, table, *rest, start)
+    assert got.shape == (8, heads, dv)
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+
+
+def test_a_row_s_bits_do_not_depend_on_the_chunk_it_comes_in():
+    """The same query row at the same position over the same table and pool,
+    inside two chunks of different ``start``: keys 0-599 are a tile every
+    query of the chunk from 604 sees whole (the path without a mask) and the
+    diagonal's tile of the chunk from 592 (the masked path), and the row's
+    output is the same to the bit.  So is a real row's whatever the padded
+    rows behind it hold (a chunk is padded to its fixed length)."""
+    pool, table, q_nope, q_rope, w_k, w_v = _chunk_operands(300, 4, rows=28)
+    assert la._key_tile(1200, interpret=True) == 600
+
+    def rows(lo, start, pad_from=16, pad=0.0):
+        """16 rows from position ``start``; row i is query ``lo + i``, from
+        ``pad_from`` on something else."""
+        qn, qr = q_nope[lo:lo + 16], q_rope[lo:lo + 16]
+        keep = (jnp.arange(16) < pad_from)[:, None, None]
+        return np.asarray(_chunk("pallas", pool, table, jnp.where(keep, qn, pad),
+                                 jnp.where(keep, qr, -pad), w_k, w_v, start))
+
+    early, late = rows(0, 592), rows(12, 604)       # positions 604-607 lie in both
+    assert np.array_equal(early[12:], late[:4])
+    assert np.abs(early[12:]).max() > 0
+    padded = rows(12, 604, pad_from=4, pad=7.0)     # 4 real rows, 12 of padding
+    assert np.array_equal(padded[:4], late[:4])
+    assert not np.array_equal(padded[4:], late[4:])
 
 
 # -- the expert layer -------------------------------------------------------------------------

@@ -91,6 +91,33 @@ def test_the_latent_decode_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
 
 
+def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
+    """Kimi-K2.5's prefill chunk at the cell's sizes: 512 queries of 64
+    heads x (128 + 64) against a table of 138 blocks of 128 latent rows,
+    four heads a grid step, key tiles of 768 walked in sub-tiles of 384,
+    the statistics replicated over 128 lanes (what interpret mode at the
+    tests' widths never builds)."""
+    from ray_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert (la._key_tile(138 * 128, False), la._sub_tile(768, False)) == (768, 384)
+    compiled = jax.jit(
+        lambda qn, qr, pool, t, wk, wv: la.latent_chunk_attention(
+            qn, qr, pool, t, 16384 + jnp.arange(512, dtype=jnp.int32), wk, wv,
+            rank=512, scale=0.14468, impl="auto")
+    ).lower(sds((512, 64, 128), jnp.bfloat16), sds((512, 64, 64), jnp.bfloat16),
+            sds((7 * 2049, 128, 640), jnp.bfloat16), sds((138,), jnp.int32),
+            sds((512, 64, 128), jnp.bfloat16), sds((512, 64, 128), jnp.bfloat16)).compile()
+    assert "latent_attention_chunk" in compiled.as_text()
+    # keys and values expanded once (2 x 17,664 x 8,192 bf16) and the gathered
+    # rows; no float32 score array of the table's width beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 700 * 2**20
+
+
 def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip):
     """The tile loop indexes the experts of every layer where they lie: a
     decode's expert layer at the published widths holds no temporary the size
