@@ -523,31 +523,43 @@ def phase_kernels(args) -> None:
          rnd(19, (held, f, d)) * f**-0.5), [],
     )
 
-    # flash forward + backward at the train shape, then at tp=4's heads
-    flash_shapes = ((26, 16, 1024, 64), (26, 4, 1024, 64))
-    for b, h, s, d in flash_shapes if on_chip else ((2, 2, 128, 16),):
-        impl = "auto" if on_chip else "flash"
-        if on_chip:
-            check(attention.auto_impl(s) == "flash", f"auto rule at seq {s}")
-        do = rnd(9, (b, h, s, d)).astype(jnp.float32)
+    # flash forward + backward.  The train shape as the train step hands it
+    # over: the fused projection's own (26, 1024, 3 x 1024) output, sixteen
+    # heads of 64 read as column blocks of two heads, the gradient ONE
+    # (26, 1024, 3072) array (``flash_attention_packed``).  Then tp=4's heads
+    # through the head-major entry, which lays them out for the same kernels.
+    from ray_tpu.ops.flash_attention import flash_attention_packed
+
+    def flash_pair(name, flash, ref, operands, out_shape):
+        do = rnd(9, out_shape).astype(jnp.float32)
 
         def grads(fn):
-            def loss(q, k, v):
-                return (fn(q, k, v).astype(jnp.float32) * do).sum()
+            return jax.grad(
+                lambda *xs: (fn(*xs).astype(jnp.float32) * do).sum(),
+                argnums=tuple(range(len(operands))))
 
-            return jax.grad(loss, argnums=(0, 1, 2))
+        compare(f"flash_fwd_{name}", flash, ref, operands, ["flash_fwd"])
+        compare(f"flash_bwd_{name}", grads(flash), grads(ref), operands,
+                ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])
 
-        qkv = tuple(rnd(i, (b, h, s, d)) for i in (6, 7, 8))
-        flash = lambda q, k, v: attention.causal_attention(q, k, v, impl=impl)  # noqa: E731
-        compare(
-            f"flash_fwd_b{b}_h{h}_s{s}_d{d}", flash, attention._xla_attention,
-            qkv, ["flash_fwd"],
-        )
-        compare(
-            f"flash_bwd_b{b}_h{h}_s{s}_d{d}", grads(flash),
-            grads(attention._xla_attention), qkv,
-            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
-        )
+    b, h, s, d = (26, 16, 1024, 64) if on_chip else (2, 2, 128, 16)
+    if on_chip:
+        check(attention.auto_impl(s) == "flash", f"auto rule at seq {s}")
+
+    def dense_packed(qkv):
+        q, k, v = (t.reshape(b, s, h, d).transpose(0, 2, 1, 3) for t in jnp.split(qkv, 3, axis=-1))
+        return attention._xla_attention(q, k, v).transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+    flash_pair(
+        f"packed_b{b}_s{s}_w{3 * h * d}_h{h}", lambda qkv: flash_attention_packed(qkv, h),
+        dense_packed, (rnd(6, (b, s, 3 * h * d)),), (b, s, h * d),
+    )
+    h = 4 if on_chip else h
+    impl = "auto" if on_chip else "flash"
+    flash_pair(
+        f"b{b}_h{h}_s{s}_d{d}", lambda q, k, v: attention.causal_attention(q, k, v, impl=impl),
+        attention._xla_attention, tuple(rnd(i, (b, h, s, d)) for i in (6, 7, 8)), (b, h, s, d),
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -198,52 +198,54 @@ def _block(cfg: GPTConfig, x, layer, mesh=None):
         # seq stays sharded over sp end-to-end (sequence parallelism); sp=1
         # meshes make these the same constraints as before.
         qkv = c(qkv, P(("dp", "fsdp"), "sp", "tp"))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(t):
-            return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        def split_heads():
+            """q, k, v head-major, (b, h, s, hd) each: what every path but
+            the one-chip flash kernels wants."""
+            return [t.reshape(b, s, h, hd).transpose(0, 2, 1, 3) for t in jnp.split(qkv, 3, axis=-1)]
+
+        def merge_heads(t):
+            return t.transpose(0, 2, 1, 3).reshape(b, s, d)
 
         impl = cfg.attn_impl
+        sharded = mesh is not None and mesh.size > 1
         if impl == "ring" and mesh is None:
             raise ValueError(
                 "attn_impl='ring' needs a device mesh with an 'sp' axis; pass "
                 "mesh= (or use attn_impl='auto', which picks ring only when the "
                 "mesh shards sequence)"
             )
-        if impl == "ring" or (
-            impl == "auto" and mesh is not None and mesh.shape.get("sp", 1) > 1
-        ):
+        if impl == "ring" or (impl == "auto" and sharded and mesh.shape.get("sp", 1) > 1):
             # sequence sharded over sp: ring attention rotates KV over ICI
             from ray_tpu.ops.ring_attention import ring_attention_sharded
 
-            att = ring_attention_sharded(heads(q), heads(k), heads(v), mesh)
+            att = merge_heads(ring_attention_sharded(*split_heads(), mesh))
         else:
             from ray_tpu.ops.attention import auto_impl
-            from ray_tpu.ops.flash_attention import flash_shardable
+            from ray_tpu.ops.flash_attention import (
+                flash_attention_packed,
+                flash_attention_sharded,
+                flash_shardable,
+            )
 
             want_flash = impl == "flash" or (impl == "auto" and auto_impl(s) == "flash")
-            if (
-                want_flash
-                and mesh is not None
-                and mesh.size > 1
-                and s >= 128
-                and s % 128 == 0
-                and flash_shardable(b, h, mesh)
-            ):
+            if want_flash and not sharded:
+                # the kernels read q, k and v as column blocks of the
+                # projection's own output and write what ``attn_out`` reads:
+                # no split, no head-major copy, forward or backward
+                att = flash_attention_packed(qkv, h)
+            elif want_flash and s >= 128 and s % 128 == 0 and flash_shardable(b, h, mesh):
                 # multi-device pjit: shard_map the Pallas kernel so it runs on
                 # each chip's dp/tp shard instead of being replicated (no GSPMD
                 # rule for a bare pallas_call)
-                from ray_tpu.ops.flash_attention import flash_attention_sharded
-
-                att = flash_attention_sharded(heads(q), heads(k), heads(v), mesh)
-            elif want_flash and mesh is not None and mesh.size > 1:
-                # multi-device but not shardable (batch/heads don't divide the
-                # mesh): a bare pallas_call would replicate on every chip — the
-                # XLA einsum partitions correctly instead
-                att = causal_attention(heads(q), heads(k), heads(v), impl="xla")
+                att = merge_heads(flash_attention_sharded(*split_heads(), mesh))
             else:
-                att = causal_attention(heads(q), heads(k), heads(v), impl=impl)
-        att = att.transpose(0, 2, 1, 3).reshape(b, s, d)
+                # XLA's einsum: asked for, or picked by ``auto`` for this
+                # shape, or multi-device and not shardable (batch/heads don't
+                # divide the mesh: a bare pallas_call would replicate on every
+                # chip — the XLA einsum partitions correctly instead)
+                att = merge_heads(
+                    causal_attention(*split_heads(), impl="xla" if want_flash else impl))
         att = att @ layer["attn_out"]["kernel"].astype(dt) + layer["attn_out"]["bias"].astype(dt)
         x = x + c(att, P(("dp", "fsdp"), "sp", None))
 

@@ -2,35 +2,55 @@
 
 The blockwise online-softmax formulation (Flash Attention 2) — no (seq, seq)
 score matrix ever reaches HBM and no kernel instance ever holds more than one
-(block_q, d) + (block_k, d) working set in VMEM, so memory is O(seq) in HBM
-and O(block) in VMEM at ANY sequence length. Forward saves only out +
+(block_q, width) + (block_k, width) working set in VMEM, so memory is O(seq)
+in HBM and O(block) in VMEM at ANY sequence length. Forward saves only out +
 logsumexp per row; backward recomputes scores blockwise with two kernels
 (dQ, then dK/dV). All accumulation fp32, inputs bf16/fp32.
 
-Grid layout: ``(bh, q_block, kv_block)`` with the KV dimension minor — TPU
-grids execute the minor dimension sequentially, so VMEM scratch accumulators
-(acc/m/l for forward, dq / dk+dv for backward) carry across KV (resp. Q)
-steps of one output block and are flushed on the block's last step.
-Causally-dead (q, kv) cells are skipped with ``pl.when``; a cell the
-diagonal crosses is walked in sub-tiles, and a sub-tile the mask kills
-whole is never issued (``_live_tiles``); only sub-tiles the diagonal
-crosses build a mask.
+Operand layout: the kernels index heads as COLUMN blocks of
+``(batch, seq, heads x head_dim)`` arrays, the layout a projection writes
+and the next product reads, so a transformer block moves no head around:
+``flash_attention_packed`` reads q, k and v out of the fused projection's
+own ``(batch, seq, 3 x heads x head_dim)`` output (three ``BlockSpec``s over
+ONE array) and writes ``(batch, seq, heads x head_dim)``; its backward reads
+``do`` and writes dq, dk, dv in the same column blocks.  A head-major
+``(batch x heads, seq, 64)`` operand would cost a transposing copy of every
+operand on each side of every kernel (16 or so a layer of a train step, a
+third of the layer outside the kernels), and a 64-wide minor dimension is
+half a 128-lane tile: such an array takes twice its bytes in HBM and every
+fetch of it is half empty.  A column block is ``_heads_per_block`` heads
+wide: TWO heads at ``head_dim`` 64 (128 lanes), one at 128, one block of 256
+lanes at 256.  A grid step serves every head of its block with the SAME
+products a head alone would issue: the queries (for dK/dV the keys and
+values) of one head at a time with the other heads' lanes zeroed, contracted
+over the block's whole width (a 64-deep product fills half the MXU's depth
+anyway), each head's softmax its own; ``p @ v`` over the whole block is
+right in that head's lanes, and ``_merge_heads`` takes them.
+
+Grid layout: ``(batch, head block, q_block, kv_block)`` with the KV
+dimension minor — TPU grids execute the minor dimension sequentially, so
+VMEM scratch accumulators (acc/m/l for forward, dq / dk+dv for backward)
+carry across KV (resp. Q) steps of one output block and are flushed on the
+block's last step.  Causally-dead (q, kv) cells are skipped with
+``pl.when``; a cell the diagonal crosses is walked in sub-tiles, and a
+sub-tile the mask kills whole is never issued (``_live_tiles``); only
+sub-tiles the diagonal crosses build a mask.
 
 TPU tiling notes: per-row stats (logsumexp, delta) live in HBM as
-``(bh, 8, seq)`` — value broadcast over 8 sublanes so the (sublane, lane)
-block shape ``(8, block_q)`` satisfies Mosaic's (8, 128) fp32 tile
-constraint. Inside the forward the running max and denominator are
-``(rows, 1)`` columns, the layout a reduction over a score tile's columns
-leaves them in (a change of layout a tile made the forward twice as
-slow). Sequence lengths must tile by 128 on the TPU path (the public entry
-raises otherwise; ``ops.attention.auto_impl`` routes such shapes to XLA).
+``(batch, head blocks, heads a block, seq)``, rows in the lanes, one
+sublane a head of the block.  Inside the forward the running max and
+denominator are ``(rows, 1)`` columns, the layout a reduction over a score
+tile's columns leaves them in (a change of layout a tile made the forward
+twice as slow). Sequence lengths must tile by 128 on the TPU path and a
+column block must be whole 128-lane tiles (the public entries raise
+otherwise; ``ops.attention.auto_impl`` routes such shapes to XLA).
 
-This is the hot op behind ``ray_tpu.ops.attention.causal_attention`` — the
-reference has no attention kernel of its own (user torch code runs inside
-``train_loop_per_worker``); SURVEY.md §5.7 makes long-context attention a
-first-class mandate for the TPU build. On non-TPU backends the same kernels
-run under ``interpret=True`` so CI (virtual CPU mesh) exercises identical
-code paths.
+This is the hot op behind ``ray_tpu.ops.attention.causal_attention`` and
+``models.gpt``'s block — the reference has no attention kernel of its own
+(user torch code runs inside ``train_loop_per_worker``); SURVEY.md §5.7
+makes long-context attention a first-class mandate for the TPU build. On
+non-TPU backends the same kernels run under ``interpret=True`` so CI
+(virtual CPU mesh) exercises identical code paths.
 """
 
 from __future__ import annotations
@@ -141,9 +161,42 @@ def _by(tiles, axis):
 
 
 def _f32_rows(ref, sub):
-    """``load(n)``: sub-tile ``n`` of a (1, block, d) ref as float32, made
-    once however many sub-tiles of the other axis meet it."""
+    """``load(n)``: sub-tile ``n`` of a (1, block, width) ref as float32, made
+    once however many sub-tiles of the other axis (and heads) meet it."""
     return functools.cache(lambda n: ref[0, pl.ds(n * sub, sub), :].astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# heads of one column block
+# ---------------------------------------------------------------------------
+
+
+def _heads_per_block(heads: int, head_dim: int) -> int:
+    """Heads side by side in one column block: as many as fill a 128-lane
+    tile and divide the head count (two at ``head_dim`` 64), one where a
+    head is 128 lanes or wider.  The block is ``_heads_per_block x
+    head_dim`` lanes wide; on a TPU that must be whole tiles (``_blocks``)."""
+    return math.gcd(heads, max(1, 128 // head_dim))
+
+
+def _own_lanes(x, g, hd):
+    """``x`` (rows, block width) with every lane outside head ``g``'s
+    zeroed: contracted over the whole width, it meets head ``g``'s lanes of
+    the other operand alone."""
+    if x.shape[1] == hd:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= g * hd) & (lane < (g + 1) * hd), x, 0.0)
+
+
+def _merge_heads(parts, hd):
+    """Lanes of head ``g`` from ``parts[g]``, side by side: a product over
+    the block's whole width is right in its own head's lanes only."""
+    out = parts[0]
+    for g in range(1, len(parts)):
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        out = jnp.where(lane >= g * hd, parts[g], out)
+    return out
 
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -195,46 +248,54 @@ def _p_and_ds(q, k, v, do, lse, delta, *, scale, diag, transposed=False):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, seq):
-    """Grid (bh, qi, kj), kj minor/sequential. Scratch carries the online
-    softmax state across kj steps of one q block; inside a step it is held
-    in values, a q sub-tile at a time, across the kv sub-tiles it meets.
-    The row statistics are (rows, 1) columns throughout (the layout a
-    reduction over a score tile's columns leaves them in): the one change
-    of layout is ``lse``'s, into the lanes of its output, once a q block.
-    Where the kv grid has ONE step (``seq == block_k``) the state never
-    touches the scratch."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    block_q, block_k, d = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, seq, hd):
+    """Grid (batch, head block, qi, kj), kj minor/sequential. Scratch
+    carries each head's online softmax state across kj steps of one q block;
+    inside a step it is held in values, a q sub-tile and a head at a time,
+    across the kv sub-tiles it meets.  The row statistics are (rows, 1)
+    columns throughout (the layout a reduction over a score tile's columns
+    leaves them in): the one change of layout is ``lse``'s, into the lanes
+    of its output, once a q block.  Where the kv grid has ONE step
+    (``seq == block_k``) the state never touches the scratch."""
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    block_q, block_k, width = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
+    n_heads = width // hd
     q_start = qi * block_q
     k_start = kj * block_k
     j_last = (q_start + block_q - 1) // block_k  # last causally-live kv block
     one_step = seq == block_k
 
-    def finish(rows, m, l, acc):
-        l = jnp.maximum(l, 1e-30)
-        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-        lse = (m + jnp.log(l)).T  # (1, rows): rows into lanes
-        lse_ref[0, :, rows] = jnp.broadcast_to(lse, (lse_ref.shape[1], lse.shape[1]))
+    def finish(rows, states):
+        outs = []
+        for g, (m, l, acc) in enumerate(states):
+            l = jnp.maximum(l, 1e-30)
+            outs.append(acc / l)
+            lse_ref[0, 0, pl.ds(g, 1), rows] = (m + jnp.log(l)).T  # (1, rows): rows into lanes
+        o_ref[0, rows, :] = _merge_heads(outs, hd).astype(o_ref.dtype)
 
     def walk(off, sub_q, sub_k):
         k_of, v_of = _f32_rows(k_ref, sub_k), _f32_rows(v_ref, sub_k)
         for i, kv_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 0).items():
             rows = pl.ds(i * sub_q, sub_q)
-            q = q_ref[0, rows, :].astype(jnp.float32) * scale
+            q_all = q_ref[0, rows, :].astype(jnp.float32) * scale
+            states = []
+            for g in range(n_heads):
+                q = _own_lanes(q_all, g, hd)
+                if one_step:
+                    m = jnp.full((sub_q, 1), NEG_INF, jnp.float32)
+                    l = jnp.zeros((sub_q, 1), jnp.float32)
+                    acc = jnp.zeros((sub_q, width), jnp.float32)
+                else:
+                    m, l, acc = m_sc[g, rows, :], l_sc[g, rows, :], acc_sc[g, rows, :]
+                for j, crossed in kv_tiles:
+                    diag = off + i * sub_q - j * sub_k if crossed else None
+                    m, l, acc = _fwd_tile(q, k_of(j), v_of(j), m, l, acc, diag=diag)
+                if one_step:
+                    states.append((m, l, acc))
+                else:
+                    m_sc[g, rows, :], l_sc[g, rows, :], acc_sc[g, rows, :] = m, l, acc
             if one_step:
-                m = jnp.full((sub_q, 1), NEG_INF, jnp.float32)
-                l = jnp.zeros((sub_q, 1), jnp.float32)
-                acc = jnp.zeros((sub_q, d), jnp.float32)
-            else:
-                m, l, acc = m_sc[rows, :], l_sc[rows, :], acc_sc[rows, :]
-            for j, crossed in kv_tiles:
-                diag = off + i * sub_q - j * sub_k if crossed else None
-                m, l, acc = _fwd_tile(q, k_of(j), v_of(j), m, l, acc, diag=diag)
-            if one_step:
-                finish(rows, m, l, acc)
-            else:
-                m_sc[rows, :], l_sc[rows, :], acc_sc[rows, :] = m, l, acc
+                finish(rows, states)
 
     if one_step:
         return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
@@ -249,38 +310,51 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scal
 
     @pl.when(kj == j_last)
     def _():
-        finish(slice(None), m_sc[:], l_sc[:], acc_sc[:])
+        finish(slice(None), [(m_sc[g], l_sc[g], acc_sc[g]) for g in range(n_heads)])
 
 
-def _flash_fwd(q, k, v, *, block_q, block_k):
-    bh, seq, d = q.shape
-    scale = 1.0 / (d**0.5)
-    grid = (bh, seq // block_q, seq // block_k)
-    out, lse8 = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, seq=seq),
-        grid=grid,
+def _col_spec(block, width, first, seq_axis):
+    """A (1, block, width) block of a (batch, seq, columns) array: the
+    sequence block the grid's ``seq_axis`` names (2 or 3), and the column
+    block ``first`` + the grid's head block."""
+    return pl.BlockSpec((1, block, width), lambda *ids: (ids[0], ids[seq_axis], first + ids[1]))
+
+
+def _stat_spec(n_heads, block_q, q_axis):
+    """One head block's rows of a (batch, head blocks, heads a block, seq)
+    statistic."""
+    return pl.BlockSpec((1, 1, n_heads, block_q), lambda *ids: (ids[0], ids[1], 0, ids[q_axis]))
+
+
+def _flash_fwd(q, k, v, first, heads, hd, *, block_q, block_k):
+    """``q``, ``k``, ``v``: (batch, seq, columns) arrays (one array three
+    times where the projection is fused) whose heads start at the column
+    blocks ``first``."""
+    b, seq, _ = q.shape
+    n = _heads_per_block(heads, hd)
+    width = n * hd
+    scale = 1.0 / (hd**0.5)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, seq=seq, hd=hd),
+        grid=(b, heads // n, seq // block_q, seq // block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            _col_spec(block_q, width, first[0], 2),
+            _col_spec(block_k, width, first[1], 3),
+            _col_spec(block_k, width, first[2], 3),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
+        out_specs=[_col_spec(block_q, width, 0, 2), _stat_spec(n, block_q, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, seq), jnp.float32),
+            jax.ShapeDtypeStruct((b, seq, heads * hd), q.dtype),
+            jax.ShapeDtypeStruct((b, heads // n, n, seq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max, a column
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((n, block_q, 1), jnp.float32),      # running max, a column a head
+            pltpu.VMEM((n, block_q, 1), jnp.float32),      # running denom
+            pltpu.VMEM((n, block_q, width), jnp.float32),  # output accumulators
         ],
         interpret=_interpret(),
         name="flash_fwd",
     )(q, k, v)
-    return out, lse8[:, :1, :]  # (bh, 1, seq)
 
 
 # ---------------------------------------------------------------------------
@@ -288,9 +362,17 @@ def _flash_fwd(q, k, v, *, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *, scale, seq):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    block_q, block_k, d = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
+def _dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref, dq_sc, *, scale, seq, hd
+):
+    """Grid as the forward's.  Also makes ``delta`` = rowsum(do * out) a
+    head, as the column this kernel wants, and writes it (rows into lanes,
+    as ``lse`` lies) for the dK/dV kernel: outside the kernels it is a
+    reduction over half a lane tile that XLA pays a transposing float32
+    copy of the whole product for."""
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    block_q, block_k, width = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
+    n_heads = width // hd
     q_start, k_start = qi * block_q, kj * block_k
     j_last = (q_start + block_q - 1) // block_k
     one_step = seq == block_k  # ONE kv step: dq never touches the scratch
@@ -299,17 +381,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *
         k_of, v_of = _f32_rows(k_ref, sub_k), _f32_rows(v_ref, sub_k)
         for i, kv_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 0).items():
             rows = pl.ds(i * sub_q, sub_q)
-            q, do = q_ref[0, rows, :].astype(jnp.float32), do_ref[0, rows, :].astype(jnp.float32)
-            lse, delta = lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None]  # columns
-            dq = jnp.zeros((sub_q, d), jnp.float32) if one_step else dq_sc[rows, :]
-            for j, crossed in kv_tiles:
-                diag = off + i * sub_q - j * sub_k if crossed else None
-                _, ds = _p_and_ds(q, k_of(j), v_of(j), do, lse, delta, scale=scale, diag=diag)
-                dq = dq + _dot(ds, k_of(j), _NN)
+            q_all = q_ref[0, rows, :].astype(jnp.float32)
+            do_all = do_ref[0, rows, :].astype(jnp.float32)
+            o_all = o_ref[0, rows, :].astype(jnp.float32)
+            dqs = []
+            for g in range(n_heads):
+                q, do = _own_lanes(q_all, g, hd), _own_lanes(do_all, g, hd)
+                lse = lse_ref[0, 0, g, rows][:, None]  # a column
+                delta = (do * o_all).sum(axis=1, keepdims=True)  # head g's lanes alone are left in do
+                delta_ref[0, 0, pl.ds(g, 1), rows] = delta.T
+                dq = jnp.zeros((sub_q, width), jnp.float32) if one_step else dq_sc[g, rows, :]
+                for j, crossed in kv_tiles:
+                    diag = off + i * sub_q - j * sub_k if crossed else None
+                    _, ds = _p_and_ds(q, k_of(j), v_of(j), do, lse, delta, scale=scale, diag=diag)
+                    dq = dq + _dot(ds, k_of(j), _NN)
+                if one_step:
+                    dqs.append(dq)
+                else:
+                    dq_sc[g, rows, :] = dq
             if one_step:
-                dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
-            else:
-                dq_sc[rows, :] = dq
+                dq_ref[0, rows, :] = _merge_heads(dqs, hd).astype(dq_ref.dtype)
 
     if one_step:
         return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
@@ -322,116 +413,157 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *
 
     @pl.when(kj == j_last)
     def _():
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+        dq_ref[0] = _merge_heads([dq_sc[g] for g in range(n_heads)], hd).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale, seq
+    k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, _, dkv_ref, dk_sc, dv_sc, dv_out,
+    *, scale, seq, hd,
 ):
-    """Grid (bh, kb, qi), qi minor/sequential; accumulates dk/dv for one kv
-    block across its causally-live q blocks, a kv sub-tile at a time in
-    values across the q sub-tiles it meets inside a step.  The scores are
-    made TRANSPOSED, (sub_k, sub_q): ``lse`` and ``delta`` then broadcast
-    from the lanes they are stored in, and ``p^T do`` / ``ds^T q`` are
-    plain products with no transpose of a score tile."""
-    kb, qi = pl.program_id(1), pl.program_id(2)
-    block_k, block_q, d = k_ref.shape[1], q_ref.shape[1], k_ref.shape[2]
+    """Grid (batch, head block, kb, qi), qi minor/sequential, ONE step
+    longer than the q blocks; accumulates dk/dv for one kv block across its
+    causally-live q blocks, a kv sub-tile and a head at a time in values
+    across the q sub-tiles it meets inside a step.  The scores are made
+    TRANSPOSED, (sub_k, sub_q): ``lse`` and ``delta`` then broadcast from
+    the lanes they are stored in, and ``p^T do`` / ``ds^T q`` are plain
+    products with no transpose of a score tile.
+
+    ONE output: the array the dQ kernel wrote its columns of, aliased.
+    ``dkv_ref`` is dk's column block during the q steps and dv's in the
+    step after the last (``_flash_bwd``'s index map), which only moves dv
+    out of ``dv_out``: two column blocks of one array from one kernel, so
+    the gradient of a fused projection is never concatenated.  The inputs
+    of that step are already the NEXT kv block's, fetched under this
+    block's last products: it must not touch them."""
+    kb, qi = pl.program_id(2), pl.program_id(3)
+    block_k, block_q, width = k_ref.shape[1], q_ref.shape[1], k_ref.shape[2]
+    n_heads = width // hd
     k_start, q_start = kb * block_k, qi * block_q
     i_first = k_start // block_q     # first q block the diagonal touches
-    n_q = pl.num_programs(2)
-    one_step = seq == block_q  # ONE q step: dk, dv never touch the scratch
+    n_q = pl.num_programs(3) - 1
+    one_step = seq == block_q  # ONE q step: dk never touches the scratch
 
     def walk(off, sub_q, sub_k):
         q_of, do_of = _f32_rows(q_ref, sub_q), _f32_rows(do_ref, sub_q)
         for j, q_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 1).items():
             cols = pl.ds(j * sub_k, sub_k)
-            k, v = k_ref[0, cols, :].astype(jnp.float32), v_ref[0, cols, :].astype(jnp.float32)
+            k_all = k_ref[0, cols, :].astype(jnp.float32)
+            v_all = v_ref[0, cols, :].astype(jnp.float32)
+            dks, dvs = [], []
+            for g in range(n_heads):
+                k, v = _own_lanes(k_all, g, hd), _own_lanes(v_all, g, hd)
+                if one_step:
+                    dk = dv = jnp.zeros((sub_k, width), jnp.float32)
+                else:
+                    dk, dv = dk_sc[g, cols, :], dv_sc[g, cols, :]
+                for i, crossed in q_tiles:
+                    rows = pl.ds(i * sub_q, sub_q)
+                    diag = off + i * sub_q - j * sub_k if crossed else None
+                    p, ds = _p_and_ds(  # (sub_k, sub_q); lse, delta: (1, sub_q) rows
+                        q_of(i), k, v, do_of(i),
+                        lse_ref[0, 0, pl.ds(g, 1), rows], delta_ref[0, 0, pl.ds(g, 1), rows],
+                        scale=scale, diag=diag, transposed=True,
+                    )
+                    dv = dv + _dot(p, do_of(i), _NN)
+                    dk = dk + _dot(ds, q_of(i), _NN)
+                if one_step:
+                    dks.append(dk)
+                    dvs.append(dv)
+                else:
+                    dk_sc[g, cols, :], dv_sc[g, cols, :] = dk, dv
             if one_step:
-                dk = dv = jnp.zeros((sub_k, d), jnp.float32)
-            else:
-                dk, dv = dk_sc[cols, :], dv_sc[cols, :]
-            for i, crossed in q_tiles:
-                rows = pl.ds(i * sub_q, sub_q)
-                diag = off + i * sub_q - j * sub_k if crossed else None
-                p, ds = _p_and_ds(  # (sub_k, sub_q); lse, delta: (1, sub_q) rows
-                    q_of(i), k, v, do_of(i), lse_ref[0, :, rows], delta_ref[0, :, rows],
-                    scale=scale, diag=diag, transposed=True,
-                )
-                dv = dv + _dot(p, do_of(i), _NN)
-                dk = dk + _dot(ds, q_of(i), _NN)
-            if one_step:
-                dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
-                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
-            else:
-                dk_sc[cols, :], dv_sc[cols, :] = dk, dv
+                dkv_ref[0, cols, :] = _merge_heads(dks, hd).astype(dkv_ref.dtype)
+                dv_out[cols, :] = _merge_heads(dvs, hd).astype(dv_out.dtype)
 
-    if one_step:
-        return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+    if not one_step:
+        @pl.when(qi == i_first)
+        def _():
+            dk_sc[:] = jnp.zeros_like(dk_sc)
+            dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    @pl.when(qi == i_first)
+    @pl.when(qi < n_q)
     def _():
-        dk_sc[:] = jnp.zeros_like(dk_sc)
-        dv_sc[:] = jnp.zeros_like(dv_sc)
+        _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
 
-    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+    if not one_step:
+        @pl.when(qi == n_q - 1)
+        def _():
+            heads_of = lambda sc: [sc[g] for g in range(n_heads)]  # noqa: E731
+            dkv_ref[0] = _merge_heads(heads_of(dk_sc), hd).astype(dkv_ref.dtype)
+            dv_out[:] = _merge_heads(heads_of(dv_sc), hd).astype(dv_out.dtype)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(qi == n_q)
     def _():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+        dkv_ref[0] = dv_out[:]
 
 
-def _flash_bwd(q, k, v, out, lse, do, *, block_q, block_k):
-    bh, seq, d = q.shape
-    scale = 1.0 / (d**0.5)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # (bh, seq)
-    delta = delta[:, None, :]  # (bh, 1, seq)
+def _flash_bwd(q, k, v, first, heads, hd, out, lse, do, *, block_q, block_k):
+    """dq, dk, dv side by side in ONE (batch, seq, 3 x heads x head_dim)
+    array, as a fused projection holds q, k and v: the dQ kernel writes its
+    columns, the dK/dV kernel the rest of the same buffer."""
+    b, seq, _ = q.shape
+    n = _heads_per_block(heads, hd)
+    width, part = n * hd, heads // n  # a column block; column blocks of one of dq, dk, dv
+    scale = 1.0 / (hd**0.5)
+    n_q, n_k = seq // block_q, seq // block_k
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, seq=seq),
-        grid=(bh, seq // block_q, seq // block_k),
+    dq, delta = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, seq=seq, hd=hd),
+        grid=(b, part, n_q, seq // block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            _col_spec(block_q, width, first[0], 2),
+            _col_spec(block_k, width, first[1], 3),
+            _col_spec(block_k, width, first[2], 3),
+            _col_spec(block_q, width, 0, 2),
+            _col_spec(block_q, width, 0, 2),
+            _stat_spec(n, block_q, 2),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=[_col_spec(block_q, width, 0, 2), _stat_spec(n, block_q, 2)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, seq, 3 * heads * hd), q.dtype),
+            jax.ShapeDtypeStruct(lse.shape, jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((n, block_q, width), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dq",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, out, lse)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, seq=seq),
-        grid=(bh, seq // block_k, seq // block_q),
+    def ahead(spec):
+        """``spec`` for the dK/dV grid: in the step after the q blocks, which
+        reads no input, the blocks of the NEXT kv block's first step (the
+        grid's order: kv block, then head block, then batch), so that they
+        are fetched while this block's last products run and not after."""
+        def ids(bi, h, kk, i):
+            kk = kk + i // n_q  # + 1 in that step
+            h = h + kk // n_k
+            return jnp.minimum(bi + h // part, b - 1), h % part, kk % n_k, i % n_q
+
+        return pl.BlockSpec(spec.block_shape, lambda *at: spec.index_map(*ids(*at)))
+
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, seq=seq, hd=hd),
+        grid=(b, part, n_k, n_q + 1),
         in_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, kk, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, kk, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, kk, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, kk, i: (b, 0, i)),
+            ahead(_col_spec(block_k, width, first[1], 2)),
+            ahead(_col_spec(block_k, width, first[2], 2)),
+            ahead(_col_spec(block_q, width, first[0], 3)),
+            ahead(_col_spec(block_q, width, 0, 3)),
+            ahead(_stat_spec(n, block_q, 3)),
+            ahead(_stat_spec(n, block_q, 3)),
+            pl.BlockSpec(memory_space=pl.ANY),  # dq's columns: kept where they are
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq, d), v.dtype),
-        ],
+        out_specs=pl.BlockSpec(  # dk's column block, then dv's in the step after the q blocks
+            (1, block_k, width), lambda bi, h, kk, i: (bi, kk, part * (1 + i // n_q) + h)),
+        out_shape=jax.ShapeDtypeStruct(dq.shape, dq.dtype),
+        input_output_aliases={6: 0},
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((n, block_k, width), jnp.float32),
+            pltpu.VMEM((n, block_k, width), jnp.float32),
+            pltpu.VMEM((block_k, width), dq.dtype),  # dv on its way out
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
-    )(k, v, q, do, lse, delta)
-    return dq, dk, dv
+    )(k, v, q, do, lse, delta, dq)
 
 
 # ---------------------------------------------------------------------------
@@ -449,14 +581,55 @@ def _pick_blocks(seq: int, block_q: int, block_k: int) -> tuple[int, int]:
     return max(bq, 1), max(bk, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_core(q, k, v, block_q, block_k, block_q_bwd, block_k_bwd):
-    out, _ = _flash_fwd(q, k, v, block_q=block_q, block_k=block_k)
-    return out
+def _blocks(seq, heads, hd, block_q, block_k, block_q_bwd, block_k_bwd):
+    """The four grid blocks, clamped to the sequence; on a TPU everything
+    must be whole tiles, or this raises."""
+    # Grid blocks of 1024×1024 measured fastest on v5e at (bh 256, s 1024,
+    # d 64): fewer, fatter grid steps win — the kernel is latency-bound per
+    # step at small head_dim, not VMEM-bound (sweep: 4.1 ms/layer at 256×512
+    # → 2.6 ms at 1024×1024). The causally dead part of a fat block is
+    # skipped inside the step, in sub-tiles (``_sub_tiles``). _pick_blocks
+    # clamps to the actual sequence length.
+    block_q = block_q if block_q is not None else 1024
+    block_k = block_k if block_k is not None else 1024
+    block_q_bwd = block_q_bwd if block_q_bwd is not None else block_q
+    block_k_bwd = block_k_bwd if block_k_bwd is not None else block_k
+    bq, bk = _pick_blocks(seq, block_q, block_k)
+    bqb, bkb = _pick_blocks(seq, block_q_bwd, block_k_bwd)
+    width = _heads_per_block(heads, hd) * hd
+    if not _interpret() and (bq % 128 or bk % 128 or bqb % 128 or bkb % 128 or width % 128):
+        # never a silent change of implementation: ``auto`` callers are
+        # routed by ``ops.attention.auto_impl`` before they get here
+        raise ValueError(
+            f"flash attention on TPU needs blocks that tile by 128 (Mosaic "
+            f"lane constraint); seq={seq} picked fwd {bq}x{bk}, bwd {bqb}x{bkb}, "
+            f"{heads} heads of {hd} a column block of {width} lanes "
+            "— use impl='xla' for this shape"
+        )
+    return bq, bk, bqb, bkb
 
 
-def _flash_core_fwd(q, k, v, block_q, block_k, block_q_bwd, block_k_bwd):
-    out, lse = _flash_fwd(q, k, v, block_q=block_q, block_k=block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flash_core(operands, heads, hd, blocks):
+    """``operands``: ``(qkv,)``, ONE (batch, seq, 3 x heads x hd) array
+    that holds q, k and v side by side as a fused projection writes them, or
+    ``(q, k, v)``, three (batch, seq, heads x hd) arrays.  Returns (batch,
+    seq, heads x hd); the cotangents come back in the operands' own form."""
+    return _flash_core_fwd(operands, heads, hd, blocks)[0]
+
+
+def _first_blocks(operands, heads, hd):
+    """The three arrays the kernels read and the column block at which the
+    heads of q, k and v start in each."""
+    if len(operands) == 3:
+        return operands, (0, 0, 0)
+    n = heads // _heads_per_block(heads, hd)  # column blocks of one of q, k, v
+    return operands * 3, (0, n, 2 * n)
+
+
+def _flash_core_fwd(operands, heads, hd, blocks):
+    (q, k, v), first = _first_blocks(operands, heads, hd)
+    out, lse = _flash_fwd(q, k, v, first, heads, hd, block_q=blocks[0], block_k=blocks[1])
     # Name the kernel's own residuals so a jax.checkpoint policy
     # (save_only_these_names, models/gpt.py remat_policy="attn"/"big") can
     # keep exactly these and dead-code the whole forward kernel out of the
@@ -466,15 +639,44 @@ def _flash_core_fwd(q, k, v, block_q, block_k, block_q_bwd, block_k_bwd):
 
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
-    return out, (q, k, v, out, lse)
+    return out, (operands, out, lse)
 
 
-def _flash_core_bwd(block_q, block_k, block_q_bwd, block_k_bwd, res, do):
-    q, k, v, out, lse = res
-    return _flash_bwd(q, k, v, out, lse, do, block_q=block_q_bwd, block_k=block_k_bwd)
+def _flash_core_bwd(heads, hd, blocks, res, do):
+    operands, out, lse = res
+    (q, k, v), first = _first_blocks(operands, heads, hd)
+    dqkv = _flash_bwd(
+        q, k, v, first, heads, hd, out, lse, do, block_q=blocks[2], block_k=blocks[3])
+    return ((dqkv,) if len(operands) == 1 else tuple(jnp.split(dqkv, 3, axis=-1)),)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+def flash_attention_packed(
+    qkv: jax.Array,
+    heads: int,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    block_q_bwd: int | None = None,
+    block_k_bwd: int | None = None,
+) -> jax.Array:
+    """Causal flash attention over a fused projection's output. qkv:
+    (batch, seq, 3 x heads x head_dim), q, k and v side by side; returns
+    (batch, seq, heads x head_dim), what the output projection reads.
+
+    The kernels take every head as a column block of these arrays (module
+    docstring): nothing is split, transposed or copied on either side, and
+    the gradient comes back as ONE (batch, seq, 3 x heads x head_dim) array.
+    O(seq) HBM / O(block) VMEM; differentiable (custom VJP with
+    blockwise-recompute backward).  Raises on a TPU where the shapes do not
+    tile, as ``flash_attention`` does."""
+    b, s, width = qkv.shape
+    hd = width // (3 * heads)
+    if width != 3 * heads * hd:
+        raise ValueError(f"qkv of {width} columns is not 3 x {heads} heads")
+    blocks = _blocks(s, heads, hd, block_q, block_k, block_q_bwd, block_k_bwd)
+    return _flash_core((qkv,), heads, hd, blocks)
 
 
 def flash_attention(
@@ -488,37 +690,26 @@ def flash_attention(
 ) -> jax.Array:
     """Causal flash attention. q,k,v: (batch, heads, seq, head_dim).
 
+    The thin head-major entry, for callers that hold head-major arrays
+    already (rotary models, the ``shard_map``'d form): it lays q, k, v out
+    as (batch, seq, heads x head_dim), the layout the kernels read and
+    write (a head's 64 lanes alone are half a lane tile; module docstring),
+    and the output back.  A caller with a fused projection uses
+    ``flash_attention_packed`` and pays for no layout at all.
+
     O(seq) HBM / O(block) VMEM; differentiable (custom VJP with
     blockwise-recompute backward). Forward and backward grid blocks may
     differ (the dQ/dKV kernels have different reuse patterns than the
-    forward). On TPU the blocks must tile by 128 (Mosaic lane constraint) —
-    anything else raises; interpret mode (CPU CI) accepts any
+    forward). On TPU the blocks must tile by 128 and a column block
+    (``_heads_per_block`` heads) must be whole 128-lane tiles (Mosaic lane
+    constraint) — anything else raises; interpret mode (CPU CI) accepts any
     power-of-two-friendly blocking.
     """
     b, h, s, d = q.shape
-    # Grid blocks of 1024×1024 measured fastest on v5e at (bh 256, s 1024,
-    # d 64): fewer, fatter grid steps win — the kernel is latency-bound per
-    # step at small head_dim, not VMEM-bound (sweep: 4.1 ms/layer at 256×512
-    # → 2.6 ms at 1024×1024). The causally dead part of a fat block is
-    # skipped inside the step, in sub-tiles (``_sub_tiles``). _pick_blocks
-    # clamps to the actual sequence length.
-    block_q = block_q if block_q is not None else 1024
-    block_k = block_k if block_k is not None else 1024
-    block_q_bwd = block_q_bwd if block_q_bwd is not None else block_q
-    block_k_bwd = block_k_bwd if block_k_bwd is not None else block_k
-    bq, bk = _pick_blocks(s, block_q, block_k)
-    bqb, bkb = _pick_blocks(s, block_q_bwd, block_k_bwd)
-    if not _interpret() and (bq % 128 or bk % 128 or bqb % 128 or bkb % 128):
-        # never a silent change of implementation: ``auto`` callers are
-        # routed by ``ops.attention.auto_impl`` before they get here
-        raise ValueError(
-            f"flash attention on TPU needs blocks that tile by 128 (Mosaic "
-            f"lane constraint); seq={s} picked fwd {bq}x{bk}, bwd {bqb}x{bkb} "
-            "— use impl='xla' for this shape"
-        )
-    merge = lambda t: t.reshape(b * h, s, d)  # noqa: E731
-    out = _flash_core(merge(q), merge(k), merge(v), bq, bk, bqb, bkb)
-    return out.reshape(b, h, s, d)
+    blocks = _blocks(s, h, d, block_q, block_k, block_q_bwd, block_k_bwd)
+    lay = lambda t: t.transpose(0, 2, 1, 3).reshape(b, s, h * d)  # noqa: E731
+    out = _flash_core((lay(q), lay(k), lay(v)), h, d, blocks)
+    return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
 
 def flash_shardable(batch: int, heads: int, mesh) -> bool:
@@ -539,6 +730,11 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, mesh) -> j
     — so each chip runs the kernel on exactly its shard (attention has no
     cross-batch/cross-head communication). Callers must check
     ``flash_shardable`` first.
+
+    Head-major on purpose: under ``tp`` the fused projection's columns are
+    split across chips as one run, so a chip's shard interleaves q, k and v
+    and is no ``flash_attention_packed`` operand; the caller's transposes
+    stay, and ``flash_attention`` lays each shard out for the kernels.
     """
     from jax.sharding import PartitionSpec as P
 

@@ -60,6 +60,120 @@ def test_flash_matches_dense(seq, block_q, block_k, dtype):
             np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(r), atol=4e-2, rtol=2e-2)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _dense_packed(qkv, heads):
+    """Dense causal attention over a fused projection's output, through the
+    XLA path: split, head-major, attend, merge."""
+    b, s, width = qkv.shape
+    hd = width // (3 * heads)
+    q, k, v = (t.reshape(b, s, heads, hd).transpose(0, 2, 1, 3) for t in jnp.split(qkv, 3, axis=-1))
+    return _xla_attention(q, k, v).transpose(0, 2, 1, 3).reshape(b, s, heads * hd)
+
+
+@pytest.mark.parametrize("head_dim,heads,per_block", [
+    (64, 4, 2), (64, 6, 2),    # two heads a 128-lane block: two and three column blocks
+    (128, 2, 1), (128, 3, 1),  # one head a block
+    (256, 2, 1), (256, 3, 1),  # a head is one block of 256 lanes
+    (16, 2, 2),                # tiny widths (CPU tests' models): the heads share one narrow block
+])
+def test_flash_packed_matches_dense(head_dim, heads, per_block):
+    """The column-blocked entry reads q, k and v out of ONE (batch, seq, 3 x
+    heads x head_dim) array and answers in (batch, seq, heads x head_dim):
+    the forward against the dense softmax and the ONE packed gradient
+    against ``jax.grad`` of the XLA path, over a sequence of several grid
+    blocks (cells on, below and above the diagonal)."""
+    assert fa._heads_per_block(heads, head_dim) == per_block
+    qkv = jax.random.normal(jax.random.PRNGKey(3), (2, 512, 3 * heads * head_dim), jnp.float32)
+    w = jnp.cos(jnp.arange(heads * head_dim, dtype=jnp.float32))
+    out = fa.flash_attention_packed(qkv, heads, block_q=128, block_k=256)
+    assert out.shape == (2, 512, heads * head_dim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_dense_packed(qkv, heads)), atol=2e-5)
+    grad = jax.grad(
+        lambda x: (fa.flash_attention_packed(x, heads, block_q=128, block_k=256) * w).sum())(qkv)
+    ref = jax.grad(lambda x: (_dense_packed(x, heads) * w).sum())(qkv)
+    assert grad.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(ref), atol=5e-5)
+
+
+@pytest.mark.parametrize("seq,block", [(256, 256), (512, 256)], ids=["one_step", "grid"])
+def test_two_heads_of_a_lane_block_do_not_leak(seq, block):
+    """Two heads of 64 share a 128-lane column block and every product runs
+    over the block's whole width: head A's output and gradients must not
+    move when head B's q, k, v and cotangent change."""
+    heads, hd = 2, 64
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    qkv = jax.random.normal(keys[0], (1, seq, 3 * heads * hd), jnp.float32)
+    do = jax.random.normal(keys[1], (1, seq, heads * hd), jnp.float32)
+    lane = jnp.arange(heads * hd)
+    head_b = jnp.tile(lane >= hd, 3)  # head B's columns of q, k and v
+    other = jnp.where(head_b, 3.0 * jax.random.normal(keys[2], qkv.shape), qkv)
+    other_do = jnp.where(lane >= hd, jax.random.normal(keys[3], do.shape), do)
+
+    def run(x, d):
+        f = lambda x: fa.flash_attention_packed(x, heads, block_q=block, block_k=block)  # noqa: E731
+        out, vjp = jax.vjp(f, x)
+        return out, vjp(d)[0]
+
+    (out, grad), (out2, grad2) = run(qkv, do), run(other, other_do)
+    assert float(jnp.abs(out - out2)[..., hd:].max()) > 0.1  # head B did change
+    np.testing.assert_array_equal(np.asarray(out[..., :hd]), np.asarray(out2[..., :hd]))
+    np.testing.assert_array_equal(np.asarray(grad[..., ~head_b]), np.asarray(grad2[..., ~head_b]))
+
+
+def test_flash_packed_refuses_what_it_cannot_tile(monkeypatch):
+    """On a TPU a column block must be whole 128-lane tiles: an odd head
+    count at width 64 leaves one head a block and raises, as unaligned
+    sequence blocks do; never a silent second path."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    assert fa._heads_per_block(3, 64) == 1
+    with pytest.raises(ValueError, match="tile by 128"):
+        fa.flash_attention_packed(jnp.zeros((1, 128, 3 * 3 * 64), jnp.bfloat16), 3)
+    with pytest.raises(ValueError, match="tile by 128"):
+        fa.flash_attention(*[jnp.zeros((1, 3, 128, 64), jnp.bfloat16)] * 3)
+    with pytest.raises(ValueError, match="not 3 x 4 heads"):
+        fa.flash_attention_packed(jnp.zeros((1, 128, 200), jnp.bfloat16), 4)
+
+
+def test_gpt_block_hands_the_kernels_the_projection_as_it_is():
+    """``models.gpt``'s block under ``attn_impl="flash"``: the traced loss
+    and its gradient hold the three kernels and NO transpose, split or
+    concatenate of a head (the kernels read the fused projection's own
+    output and write what ``attn_out`` reads); and it agrees with the XLA
+    path, loss and gradients."""
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+
+    kw = dict(vocab_size=128, seq_len=128, d_model=128, n_layers=2, n_heads=2, dtype="float32",
+              remat_policy="attn")
+    flash, xla = GPTConfig(attn_impl="flash", **kw), GPTConfig(attn_impl="xla", **kw)
+    params = gpt_init(jax.random.PRNGKey(0), flash)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 128, jnp.int32)
+    step = lambda cfg: jax.value_and_grad(lambda p: gpt_loss(cfg, p, tokens))  # noqa: E731
+
+    def traced(cfg):
+        eqns = list(_eqns(jax.make_jaxpr(step(cfg))(params).jaxpr))
+        head_major = [e for e in eqns if e.primitive.name == "transpose"
+                      and len(e.invars[0].aval.shape) == 4]
+        return {e.primitive.name for e in eqns}, head_major, eqns
+
+    names, head_major, eqns = traced(flash)
+    kernels = {e.params["name"] for e in eqns if e.primitive.name == "pallas_call"}
+    assert kernels == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert not {"split", "concatenate"} & names and head_major == []
+    names_xla, head_major_xla, _ = traced(xla)  # what the check would see if it were there
+    assert "split" in names_xla and head_major_xla
+    (l_f, g_f), (l_x, g_x) = step(flash)(params), step(xla)(params)
+    np.testing.assert_allclose(float(l_f), float(l_x), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g_f), jax.tree_util.tree_leaves(g_x)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
 def _brute_tiles(block_q, block_k, sub_q, sub_k, off):
     """(i, j, crossed) of the sub-tiles with a live score, from the mask
     itself; ``off`` None is a cell wholly below the diagonal."""
@@ -100,14 +214,6 @@ def test_flash_issues_only_live_sub_tiles(block_q, block_k, sub_q, sub_k, n_diag
     assert len(fa._live_tiles(block_q, block_k, sub_q, sub_k, None)) == total
     if n_diag is not None:
         assert len(fa._live_tiles(block_q, block_k, sub_q, sub_k, 0)) == n_diag < total
-
-
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in it."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
 
 
 @pytest.mark.parametrize("seq", [1024, 2048])

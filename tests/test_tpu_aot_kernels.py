@@ -8,6 +8,8 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU's library, and every xdist
 worker imports every test file.  All such tests live in THIS file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -68,6 +70,61 @@ def test_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch, bh, seq):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert kernel in text, kernel
+
+
+def test_the_column_blocked_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch):
+    """gpt2m_train's attention as the train step hands it over: q, k and v
+    read out of the projection's own (26, 1024, 3 x 1024) array, two heads of
+    64 a 128-lane column block, the gradient ONE (26, 1024, 3072) array that
+    both backward kernels write (aliased): nothing head-major, no copy."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)  # Mosaic, not the interpreter
+    qkv = jax.ShapeDtypeStruct((26, 1024, 3072), jnp.bfloat16, sharding=one_chip)
+
+    def loss(qkv):
+        return fa.flash_attention_packed(qkv, 16).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss)).lower(qkv).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert not re.search(r"\[26,16,1024,64\]|\[416,1024,64\]| copy\(", text)
+    # out, dqkv, the statistics: no second (26, 1024, 3072) array beside the gradient
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 26 * 1024 * 1024 * 2 * 2
+
+
+def test_the_train_step_moves_no_head_around_on_a_v5e(one_chip, monkeypatch):
+    """``gpt_loss``'s value and gradient at gpt2m_train's widths (batch 26,
+    1,024 positions, 16 heads of 64, bf16, ``remat_policy`` "attn"; 2 layers
+    suffice under ``lax.scan``) compiled for a v5e: the three flash kernels
+    are there, once each a layer body, and NO op of the program has a
+    head-major result: the kernels read the fused projection's output and
+    write what the next product reads (a head-major copy cost a third of a
+    layer outside the kernels, PERF.md section 6, PR 52)."""
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.ops import attention
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)  # Mosaic, not the interpreter
+    monkeypatch.setattr(attention, "auto_impl", lambda seq: "flash")  # the rule on a TPU backend
+    cfg = GPTConfig(vocab_size=50304, seq_len=1024, d_model=1024, n_layers=2, n_heads=16,
+                    dtype="bfloat16", remat_policy="attn", ce_chunks=1)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((26, 1025), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(lambda p, t: gpt_loss(cfg, p, t))).lower(
+        params, tokens).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    head_major = [ln.strip()[:160] for ln in text.splitlines()
+                  if re.search(r" = \(?\w+\[(1,)?(26,16,1024,64|416,1024,64)\]", ln)]
+    assert head_major == []
+    # the gradient of the fused projection is one array the kernels wrote
+    assert re.search(r"bf16\[26,1024,3072\][^ ]* custom-call\(.*flash_bwd_dkv", text)
 
 
 def test_the_latent_decode_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
