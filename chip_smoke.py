@@ -540,7 +540,7 @@ def phase_kernels(args) -> None:
 
         compare(f"flash_fwd_{name}", flash, ref, operands, ["flash_fwd"])
         compare(f"flash_bwd_{name}", grads(flash), grads(ref), operands,
-                ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])
+                ["flash_bwd", "flash_fwd"])
 
     b, h, s, d = (26, 16, 1024, 64) if on_chip else (2, 2, 128, 16)
     if on_chip:
@@ -645,7 +645,7 @@ def _train(args) -> None:
     if not args.cpu_rehearsal:
         check(dev["platform"] == "tpu", f"train worker computes on {dev['platform']}")
         check(
-            m["mosaic_kernels"] == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
+            m["mosaic_kernels"] == ["flash_bwd", "flash_fwd"],
             f"train step holds kernels {m['mosaic_kernels']}",
         )
     check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")

@@ -1,11 +1,16 @@
 """Pallas TPU flash attention (causal) with a full custom-VJP backward.
 
 The blockwise online-softmax formulation (Flash Attention 2) — no (seq, seq)
-score matrix ever reaches HBM and no kernel instance ever holds more than one
-(block_q, width) + (block_k, width) working set in VMEM, so memory is O(seq)
-in HBM and O(block) in VMEM at ANY sequence length. Forward saves only out +
-logsumexp per row; backward recomputes scores blockwise with two kernels
-(dQ, then dK/dV). All accumulation fp32, inputs bf16/fp32.
+score matrix ever reaches HBM, so memory is O(seq) in HBM; the forward holds
+one (block_q, width) + (block_k, width) working set in VMEM at any sequence
+length. Forward saves only out + logsumexp per row; the backward recomputes
+scores blockwise in ONE kernel: a live sub-tile's softmax weights and score
+gradients are made once (2 products, one ``exp`` pass) and feed dv, dk and
+dq together (3 products), where a dQ kernel and a dK/dV kernel made every
+tile's scores twice (3 + 4 products, two ``exp`` passes).  Its price is dq
+of a head block's whole sequence in VMEM (seq x block width float32: 0.5 MB
+at 1,024 x 128 lanes, 4 MB at 8k) across the kv blocks, asked for from the
+shapes (``_bwd_vmem_bytes``). All accumulation fp32, inputs bf16/fp32.
 
 Operand layout: the kernels index heads as COLUMN blocks of
 ``(batch, seq, heads x head_dim)`` arrays, the layout a projection writes
@@ -21,7 +26,7 @@ half a 128-lane tile: such an array takes twice its bytes in HBM and every
 fetch of it is half empty.  A column block is ``_heads_per_block`` heads
 wide: TWO heads at ``head_dim`` 64 (128 lanes), one at 128, one block of 256
 lanes at 256.  A grid step serves every head of its block with the SAME
-products a head alone would issue: the queries (for dK/dV the keys and
+products a head alone would issue: the queries (in the backward the keys and
 values) of one head at a time with the other heads' lanes zeroed, contracted
 over the block's whole width (a 64-deep product fills half the MXU's depth
 anyway), each head's softmax its own; ``p @ v`` over the whole block is
@@ -29,16 +34,18 @@ right in that head's lanes, and ``_merge_heads`` takes them.
 
 Grid layout: ``(batch, head block, q_block, kv_block)`` with the KV
 dimension minor — TPU grids execute the minor dimension sequentially, so
-VMEM scratch accumulators (acc/m/l for forward, dq / dk+dv for backward)
-carry across KV (resp. Q) steps of one output block and are flushed on the
-block's last step.  Causally-dead (q, kv) cells are skipped with
+VMEM scratch accumulators (acc/m/l for forward) carry across KV steps of
+one output block and are flushed on the block's last step; the backward's
+grid is ``(batch, head block, kv_block, q_block)``, dk+dv carried across
+the Q steps of a kv block and dq across the kv blocks (``_bwd_kernel``).  Causally-dead (q, kv) cells are skipped with
 ``pl.when``; a cell the diagonal crosses is walked in sub-tiles, and a
 sub-tile the mask kills whole is never issued (``_live_tiles``); only
 sub-tiles the diagonal crosses build a mask.
 
-TPU tiling notes: per-row stats (logsumexp, delta) live in HBM as
-``(batch, head blocks, heads a block, seq)``, rows in the lanes, one
-sublane a head of the block.  Inside the forward the running max and
+TPU tiling notes: the per-row logsumexp lives in HBM as ``(batch, head
+blocks, heads a block, seq)``, rows in the lanes, one sublane a head of the
+block; the backward's delta = rowsum(do * out) lies the same way in its
+scratch and never reaches HBM.  Inside the forward the running max and
 denominator are ``(rows, 1)`` columns, the layout a reduction over a score
 tile's columns leaves them in (a change of layout a tile made the forward
 twice as slow). Sequence lengths must tile by 128 on the TPU path and a
@@ -227,19 +234,15 @@ def _fwd_tile(q, k, v, m, l, acc, *, diag):
     return m_new, l, acc * corr + _dot(p, v, _NN)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "diag", "transposed"))
-def _p_and_ds(q, k, v, do, lse, delta, *, scale, diag, transposed=False):
+@functools.partial(jax.jit, static_argnames=("scale", "diag"))
+def _p_and_ds(q, k, v, do, lse, delta, *, scale, diag):
     """One sub-tile's softmax weights and score gradients, recomputed from
-    the forward's row statistics: (sub_q, sub_k) with ``lse`` / ``delta`` as
-    (sub_q, 1) columns, or TRANSPOSED, (sub_k, sub_q) with them as
-    (1, sub_q) rows: the same products, element for element."""
-    if transposed:
-        s, dp = _dot(k, q, _NT), _dot(v, do, _NT)
-    else:
-        s, dp = _dot(q, k, _NT), _dot(do, v, _NT)
+    the forward's row statistics, TRANSPOSED: (sub_k, sub_q), with ``lse``
+    and ``delta`` as the (1, sub_q) rows they are stored as."""
+    s, dp = _dot(k, q, _NT), _dot(v, do, _NT)
     p = jnp.exp(scale * s - lse)
     if diag is not None:
-        p = jnp.where(_tile_mask(p.shape, diag, transposed), p, 0.0)
+        p = jnp.where(_tile_mask(p.shape, diag, transposed=True), p, 0.0)
     return p, p * (dp - delta) * scale
 
 
@@ -362,87 +365,98 @@ def _flash_fwd(q, k, v, first, heads, hd, *, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref, dq_sc, *, scale, seq, hd
+def _when(always, cond):
+    """``pl.when(cond)``; a plain call where the grid makes ``cond`` hold in
+    every step (``always``, static), so that a kernel of ONE step stays one
+    straight run of code the scheduler can overlap from end to end."""
+    return (lambda body: body()) if always else pl.when(cond)
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, out_ref,
+    dq_sc, delta_sc, dk_sc, dv_sc, dq_out, dk_out, dv_out, sems, *, scale, seq, hd,
 ):
-    """Grid as the forward's.  Also makes ``delta`` = rowsum(do * out) a
-    head, as the column this kernel wants, and writes it (rows into lanes,
-    as ``lse`` lies) for the dK/dV kernel: outside the kernels it is a
-    reduction over half a lane tile that XLA pays a transposing float32
-    copy of the whole product for."""
-    qi, kj = pl.program_id(2), pl.program_id(3)
-    block_q, block_k, width = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
-    n_heads = width // hd
-    q_start, k_start = qi * block_q, kj * block_k
-    j_last = (q_start + block_q - 1) // block_k
-    one_step = seq == block_k  # ONE kv step: dq never touches the scratch
+    """dq, dk AND dv.  Grid (batch, head block, kb, qi), qi minor/sequential.
+    A live (q sub-tile, kv sub-tile, head) makes its softmax weights and
+    score gradients ONCE (``_p_and_ds``: 2 products) and feeds all three
+    gradients from them (3 products).
 
-    def walk(off, sub_q, sub_k):
-        k_of, v_of = _f32_rows(k_ref, sub_k), _f32_rows(v_ref, sub_k)
-        for i, kv_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 0).items():
-            rows = pl.ds(i * sub_q, sub_q)
-            q_all = q_ref[0, rows, :].astype(jnp.float32)
-            do_all = do_ref[0, rows, :].astype(jnp.float32)
-            o_all = o_ref[0, rows, :].astype(jnp.float32)
-            dqs = []
-            for g in range(n_heads):
-                q, do = _own_lanes(q_all, g, hd), _own_lanes(do_all, g, hd)
-                lse = lse_ref[0, 0, g, rows][:, None]  # a column
-                delta = (do * o_all).sum(axis=1, keepdims=True)  # head g's lanes alone are left in do
-                delta_ref[0, 0, pl.ds(g, 1), rows] = delta.T
-                dq = jnp.zeros((sub_q, width), jnp.float32) if one_step else dq_sc[g, rows, :]
-                for j, crossed in kv_tiles:
-                    diag = off + i * sub_q - j * sub_k if crossed else None
-                    _, ds = _p_and_ds(q, k_of(j), v_of(j), do, lse, delta, scale=scale, diag=diag)
-                    dq = dq + _dot(ds, k_of(j), _NN)
-                if one_step:
-                    dqs.append(dq)
-                else:
-                    dq_sc[g, rows, :] = dq
-            if one_step:
-                dq_ref[0, rows, :] = _merge_heads(dqs, hd).astype(dq_ref.dtype)
+    The score tile is TRANSPOSED, (sub_k, sub_q), and never changes layout
+    (a layout change a tile doubled the forward): ``lse`` and ``delta``
+    broadcast from the lanes they are stored in, ``dv += p^T do`` and ``dk
+    += ds^T q`` are plain products, and dq is accumulated transposed too,
+    ``dq^T += k^T ds^T``, a plain product whose left operand is turned once
+    a kv sub-tile.  dk and dv of one kv block are summed over its causally
+    live q blocks, a kv sub-tile and a head at a time in values across the
+    q sub-tiles it meets (``dk_sc`` / ``dv_sc`` between grid steps); dq^T of
+    the head block's WHOLE sequence stays in ``dq_sc`` (q blocks, width,
+    block_q) across the kv blocks: the other head's rows of ``k^T`` are
+    zero, so the heads of a block share one accumulator and nothing is
+    merged.  A q block's dq is complete in the step of its last live kv
+    block and is turned once there.  ``delta`` = rowsum(do * out) a head is
+    made in a q block's first step (kv block 0 is live for every q block)
+    and kept in ``delta_sc``, rows in the lanes.
 
-    if one_step:
-        return _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
-
-    @pl.when(kj == 0)
-    def _():
-        dq_sc[:] = jnp.zeros_like(dq_sc)
-
-    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
-
-    @pl.when(kj == j_last)
-    def _():
-        dq_ref[0] = _merge_heads([dq_sc[g] for g in range(n_heads)], hd).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, _, dkv_ref, dk_sc, dv_sc, dv_out,
-    *, scale, seq, hd,
-):
-    """Grid (batch, head block, kb, qi), qi minor/sequential, ONE step
-    longer than the q blocks; accumulates dk/dv for one kv block across its
-    causally-live q blocks, a kv sub-tile and a head at a time in values
-    across the q sub-tiles it meets inside a step.  The scores are made
-    TRANSPOSED, (sub_k, sub_q): ``lse`` and ``delta`` then broadcast from
-    the lanes they are stored in, and ``p^T do`` / ``ds^T q`` are plain
-    products with no transpose of a score tile.
-
-    ONE output: the array the dQ kernel wrote its columns of, aliased.
-    ``dkv_ref`` is dk's column block during the q steps and dv's in the
-    step after the last (``_flash_bwd``'s index map), which only moves dv
-    out of ``dv_out``: two column blocks of one array from one kernel, so
-    the gradient of a fused projection is never concatenated.  The inputs
-    of that step are already the NEXT kv block's, fetched under this
-    block's last products: it must not touch them."""
-    kb, qi = pl.program_id(2), pl.program_id(3)
+    ``out_ref`` is the whole (batch, seq, 3 x heads x head_dim) gradient in
+    HBM: a finished block leaves through a staging buffer of two slots
+    (``dq_out``, ``dk_out``, ``dv_out``) by a copy of its own into dq's,
+    dk's or dv's column block, three column blocks of one array from one
+    kernel, so the gradient of a fused projection is never concatenated.  A
+    slot's copy is waited for at the top of the step that fills the slot
+    again (two finished blocks later), and at the grid's last step."""
+    bi, h, kb, qi = (pl.program_id(a) for a in range(4))
     block_k, block_q, width = k_ref.shape[1], q_ref.shape[1], k_ref.shape[2]
     n_heads = width // hd
+    n_k, n_q = seq // block_k, seq // block_q
+    part = out_ref.shape[2] // (3 * width)  # column blocks of one of dq, dk, dv
     k_start, q_start = kb * block_k, qi * block_q
-    i_first = k_start // block_q     # first q block the diagonal touches
-    n_q = pl.num_programs(3) - 1
-    one_step = seq == block_q  # ONE q step: dk never touches the scratch
+    i_first = k_start // block_q                # first q block the diagonal touches
+    j_last = (q_start + block_q - 1) // block_k  # last causally-live kv block
+    one_q, one_k = n_q == 1, n_k == 1
 
+    # -- finished blocks on their way out ------------------------------------
+    q_done, kv_done = kb == j_last, qi == n_q - 1
+    head_block = bi * pl.num_programs(1) + h
+    leaving = {  # which gradient: (staging buffer, its rows of the sequence, blocks sent so far)
+        0: (dq_out, q_start, head_block * n_q + qi),
+        1: (dk_out, k_start, head_block * n_k + kb),
+        2: (dv_out, k_start, head_block * n_k + kb),
+    }
+
+    def slot_of(which):
+        return leaving[which][2] % 2
+
+    def copy_out(which, slot=None):
+        stage, start, _ = leaving[which]
+        slot = slot_of(which) if slot is None else slot
+        cols = pl.ds(pl.multiple_of((which * part + h) * width, width), width)
+        rows = pl.ds(pl.multiple_of(start, stage.shape[1]), stage.shape[1])
+        return pltpu.make_async_copy(
+            stage.at[slot], out_ref.at[bi, rows, cols], sems.at[which, slot])
+
+    @pl.when(kv_done & (leaving[1][2] >= 2))
+    def _():
+        copy_out(1).wait()
+        copy_out(2).wait()
+
+    @pl.when(q_done & (leaving[0][2] >= 2))
+    def _():
+        copy_out(0).wait()
+
+    # -- a q block's first step: its delta, and dq^T from zero -----------------
+    @_when(one_k, kb == 0)
+    def _():
+        dq_sc[qi] = jnp.zeros(dq_sc.shape[1:], jnp.float32)
+        sub_q = _sub_tiles(block_q, block_k)[0]
+        for i in range(block_q // sub_q):
+            rows = pl.ds(i * sub_q, sub_q)
+            do_all = do_ref[0, rows, :].astype(jnp.float32)
+            o_all = o_ref[0, rows, :].astype(jnp.float32)
+            for g in range(n_heads):  # a column, turned into the lanes once a q sub-tile
+                delta = (_own_lanes(do_all, g, hd) * o_all).sum(axis=1, keepdims=True)
+                delta_sc[qi, pl.ds(g, 1), rows] = delta.T
+
+    # -- the cell's live sub-tiles ---------------------------------------------
     def walk(off, sub_q, sub_k):
         q_of, do_of = _f32_rows(q_ref, sub_q), _f32_rows(do_ref, sub_q)
         for j, q_tiles in _by(_live_tiles(block_q, block_k, sub_q, sub_k, off), 1).items():
@@ -452,7 +466,8 @@ def _dkv_kernel(
             dks, dvs = [], []
             for g in range(n_heads):
                 k, v = _own_lanes(k_all, g, hd), _own_lanes(v_all, g, hd)
-                if one_step:
+                k_t = k.T  # (width, sub_k): head g's rows, the others zero
+                if one_q:
                     dk = dv = jnp.zeros((sub_k, width), jnp.float32)
                 else:
                     dk, dv = dk_sc[g, cols, :], dv_sc[g, cols, :]
@@ -461,109 +476,120 @@ def _dkv_kernel(
                     diag = off + i * sub_q - j * sub_k if crossed else None
                     p, ds = _p_and_ds(  # (sub_k, sub_q); lse, delta: (1, sub_q) rows
                         q_of(i), k, v, do_of(i),
-                        lse_ref[0, 0, pl.ds(g, 1), rows], delta_ref[0, 0, pl.ds(g, 1), rows],
-                        scale=scale, diag=diag, transposed=True,
+                        lse_ref[0, 0, pl.ds(g, 1), rows], delta_sc[qi, pl.ds(g, 1), rows],
+                        scale=scale, diag=diag,
                     )
                     dv = dv + _dot(p, do_of(i), _NN)
                     dk = dk + _dot(ds, q_of(i), _NN)
-                if one_step:
+                    dq_sc[qi, :, rows] += _dot(k_t, ds, _NN)  # (width, sub_q): in kv order
+                if one_q:
                     dks.append(dk)
                     dvs.append(dv)
                 else:
                     dk_sc[g, cols, :], dv_sc[g, cols, :] = dk, dv
-            if one_step:
-                dkv_ref[0, cols, :] = _merge_heads(dks, hd).astype(dkv_ref.dtype)
-                dv_out[cols, :] = _merge_heads(dvs, hd).astype(dv_out.dtype)
+            if one_q:
+                dk_out[slot_of(1), cols, :] = _merge_heads(dks, hd).astype(dk_out.dtype)
+                dv_out[slot_of(2), cols, :] = _merge_heads(dvs, hd).astype(dv_out.dtype)
 
-    if not one_step:
+    if not one_q:
         @pl.when(qi == i_first)
         def _():
             dk_sc[:] = jnp.zeros_like(dk_sc)
             dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    @pl.when(qi < n_q)
-    def _():
-        _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
+    _walk_cell(q_start, k_start, block_q, block_k, seq, walk)
 
-    if not one_step:
-        @pl.when(qi == n_q - 1)
-        def _():
+    # -- what this step finished leaves ----------------------------------------
+    @_when(one_q, kv_done)
+    def _():
+        if not one_q:
             heads_of = lambda sc: [sc[g] for g in range(n_heads)]  # noqa: E731
-            dkv_ref[0] = _merge_heads(heads_of(dk_sc), hd).astype(dkv_ref.dtype)
-            dv_out[:] = _merge_heads(heads_of(dv_sc), hd).astype(dv_out.dtype)
+            dk_out[slot_of(1)] = _merge_heads(heads_of(dk_sc), hd).astype(dk_out.dtype)
+            dv_out[slot_of(2)] = _merge_heads(heads_of(dv_sc), hd).astype(dv_out.dtype)
+        copy_out(1).start()
+        copy_out(2).start()
 
-    @pl.when(qi == n_q)
+    @_when(one_k, q_done)
     def _():
-        dkv_ref[0] = dv_out[:]
+        sub_q = _sub_tiles(block_q, block_k)[0]
+        for i in range(block_q // sub_q):  # turned once, a sub-tile at a time
+            rows = pl.ds(i * sub_q, sub_q)
+            dq_out[slot_of(0), rows, :] = dq_sc[qi, :, rows].T.astype(dq_out.dtype)
+        copy_out(0).start()
+
+    last = (bi == pl.num_programs(0) - 1) & (h == pl.num_programs(1) - 1) & kv_done & (kb == n_k - 1)
+
+    @pl.when(last)
+    def _():
+        for which in range(3):
+            copy_out(which).wait()
+            if pl.num_programs(0) * pl.num_programs(1) * (n_k if which else n_q) > 1:
+                copy_out(which, 1 - slot_of(which)).wait()  # the block before the last
+
+
+#: What Mosaic gives a kernel when it is asked for nothing, and the most the
+#: backward asks for: a v5e's core holds 128 MiB.
+_VMEM_DEFAULT, _VMEM_MOST = 16 * 2**20, 100 * 2**20
+
+
+def _bwd_vmem_bytes(seq, width, n, block_q, block_k, itemsize):
+    """The fast memory ``_bwd_kernel`` is allowed, from its shapes.  What it
+    declares: dq^T of the whole sequence (seq x width float32: 0.5 MB at
+    gpt2m_train's 1,024 x 128, 4 MB at 8k), delta (a head pads to 8
+    sublanes), dk and dv between grid steps, the three staging buffers and
+    the pipeline's two copies of every fetched block.  What the compiler
+    keeps of the largest tile the body issues (a sub-tile where the sequence
+    is one block, else a WHOLE cell below the diagonal, 4 MB of float32 at
+    1024 x 1024): it held two tiles and three to five float32 operand
+    blocks at every shape read (18.4 MB at seq 4,096 x 128 lanes, 27.8 MB at
+    4,096 x 256; libtpu's report, PR 55); three and six are asked for."""
+    f32 = 4
+    declared = (
+        seq * width * f32 + (seq // block_q) * 8 * block_q * f32 + 2 * n * block_k * width * f32
+        + 2 * (block_q + 2 * block_k) * width * itemsize           # staging, two slots each
+        + 2 * ((3 * block_q + 2 * block_k) * width * itemsize + 8 * block_q * f32)  # fetched
+    )
+    whole = seq - block_q >= block_k - 1  # ``_walk_cell``'s rule: a cell below the diagonal exists
+    tile_q, tile_k = (block_q, block_k) if whole else _sub_tiles(block_q, block_k)
+    kept = 3 * tile_q * tile_k * f32 + 6 * max(block_q, block_k) * width * f32
+    return max(_VMEM_DEFAULT, declared + kept)
 
 
 def _flash_bwd(q, k, v, first, heads, hd, out, lse, do, *, block_q, block_k):
     """dq, dk, dv side by side in ONE (batch, seq, 3 x heads x head_dim)
-    array, as a fused projection holds q, k and v: the dQ kernel writes its
-    columns, the dK/dV kernel the rest of the same buffer."""
+    array, as a fused projection holds q, k and v, from ONE kernel."""
     b, seq, _ = q.shape
     n = _heads_per_block(heads, hd)
     width, part = n * hd, heads // n  # a column block; column blocks of one of dq, dk, dv
-    scale = 1.0 / (hd**0.5)
-    n_q, n_k = seq // block_q, seq // block_k
-
-    dq, delta = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, seq=seq, hd=hd),
-        grid=(b, part, n_q, seq // block_k),
-        in_specs=[
-            _col_spec(block_q, width, first[0], 2),
-            _col_spec(block_k, width, first[1], 3),
-            _col_spec(block_k, width, first[2], 3),
-            _col_spec(block_q, width, 0, 2),
-            _col_spec(block_q, width, 0, 2),
-            _stat_spec(n, block_q, 2),
-        ],
-        out_specs=[_col_spec(block_q, width, 0, 2), _stat_spec(n, block_q, 2)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, seq, 3 * heads * hd), q.dtype),
-            jax.ShapeDtypeStruct(lse.shape, jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((n, block_q, width), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(q, k, v, do, out, lse)
-
-    def ahead(spec):
-        """``spec`` for the dK/dV grid: in the step after the q blocks, which
-        reads no input, the blocks of the NEXT kv block's first step (the
-        grid's order: kv block, then head block, then batch), so that they
-        are fetched while this block's last products run and not after."""
-        def ids(bi, h, kk, i):
-            kk = kk + i // n_q  # + 1 in that step
-            h = h + kk // n_k
-            return jnp.minimum(bi + h // part, b - 1), h % part, kk % n_k, i % n_q
-
-        return pl.BlockSpec(spec.block_shape, lambda *at: spec.index_map(*ids(*at)))
-
+    n_q = seq // block_q
+    vmem = _bwd_vmem_bytes(seq, width, n, block_q, block_k, q.dtype.itemsize)
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, seq=seq, hd=hd),
-        grid=(b, part, n_k, n_q + 1),
+        functools.partial(_bwd_kernel, scale=1.0 / (hd**0.5), seq=seq, hd=hd),
+        grid=(b, part, seq // block_k, n_q),
         in_specs=[
-            ahead(_col_spec(block_k, width, first[1], 2)),
-            ahead(_col_spec(block_k, width, first[2], 2)),
-            ahead(_col_spec(block_q, width, first[0], 3)),
-            ahead(_col_spec(block_q, width, 0, 3)),
-            ahead(_stat_spec(n, block_q, 3)),
-            ahead(_stat_spec(n, block_q, 3)),
-            pl.BlockSpec(memory_space=pl.ANY),  # dq's columns: kept where they are
+            _col_spec(block_q, width, first[0], 3),
+            _col_spec(block_k, width, first[1], 2),
+            _col_spec(block_k, width, first[2], 2),
+            _col_spec(block_q, width, 0, 3),
+            _col_spec(block_q, width, 0, 3),
+            _stat_spec(n, block_q, 3),
         ],
-        out_specs=pl.BlockSpec(  # dk's column block, then dv's in the step after the q blocks
-            (1, block_k, width), lambda bi, h, kk, i: (bi, kk, part * (1 + i // n_q) + h)),
-        out_shape=jax.ShapeDtypeStruct(dq.shape, dq.dtype),
-        input_output_aliases={6: 0},
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((b, seq, 3 * heads * hd), q.dtype),
         scratch_shapes=[
+            pltpu.VMEM((n_q, width, block_q), jnp.float32),  # dq^T, the whole sequence
+            pltpu.VMEM((n_q, n, block_q), jnp.float32),      # delta, rows in the lanes
+            pltpu.VMEM((n, block_k, width), jnp.float32),    # dk, dv between q blocks
             pltpu.VMEM((n, block_k, width), jnp.float32),
-            pltpu.VMEM((n, block_k, width), jnp.float32),
-            pltpu.VMEM((block_k, width), dq.dtype),  # dv on its way out
+            pltpu.VMEM((2, block_q, width), q.dtype),        # dq, dk, dv on their way out
+            pltpu.VMEM((2, block_k, width), q.dtype),
+            pltpu.VMEM((2, block_k, width), q.dtype),
+            pltpu.SemaphoreType.DMA((3, 2)),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=_interpret(),
-        name="flash_bwd_dkv",
-    )(k, v, q, do, lse, delta, dq)
+        name="flash_bwd",
+    )(q, k, v, do, out, lse)
 
 
 # ---------------------------------------------------------------------------
@@ -605,6 +631,14 @@ def _blocks(seq, heads, hd, block_q, block_k, block_q_bwd, block_k_bwd):
             f"lane constraint); seq={seq} picked fwd {bq}x{bk}, bwd {bqb}x{bkb}, "
             f"{heads} heads of {hd} a column block of {width} lanes "
             "— use impl='xla' for this shape"
+        )
+    n = _heads_per_block(heads, hd)
+    if not _interpret() and _bwd_vmem_bytes(seq, width, n, bqb, bkb, 4) > _VMEM_MOST:
+        # the one backward kernel holds dq^T of a head block's whole sequence
+        raise ValueError(
+            f"flash attention's backward needs {seq * width * 4 / 2**20:.0f} MB of fast memory "
+            f"for dq at seq={seq} and a column block of {width} lanes, and a kernel may ask for "
+            f"{_VMEM_MOST / 2**20:.0f}: shard the sequence (ops.ring_attention)"
         )
     return bq, bk, bqb, bkb
 
@@ -668,7 +702,8 @@ def flash_attention_packed(
     The kernels take every head as a column block of these arrays (module
     docstring): nothing is split, transposed or copied on either side, and
     the gradient comes back as ONE (batch, seq, 3 x heads x head_dim) array.
-    O(seq) HBM / O(block) VMEM; differentiable (custom VJP with
+    O(seq) HBM; O(block) VMEM forward, plus dq of a head block's sequence
+    in the backward (module docstring); differentiable (custom VJP with
     blockwise-recompute backward).  Raises on a TPU where the shapes do not
     tile, as ``flash_attention`` does."""
     b, s, width = qkv.shape
@@ -697,13 +732,15 @@ def flash_attention(
     and the output back.  A caller with a fused projection uses
     ``flash_attention_packed`` and pays for no layout at all.
 
-    O(seq) HBM / O(block) VMEM; differentiable (custom VJP with
+    O(seq) HBM; O(block) VMEM forward, plus dq of a head block's sequence
+    in the backward (module docstring); differentiable (custom VJP with
     blockwise-recompute backward). Forward and backward grid blocks may
-    differ (the dQ/dKV kernels have different reuse patterns than the
+    differ (the backward kernel has another reuse pattern than the
     forward). On TPU the blocks must tile by 128 and a column block
     (``_heads_per_block`` heads) must be whole 128-lane tiles (Mosaic lane
-    constraint) — anything else raises; interpret mode (CPU CI) accepts any
-    power-of-two-friendly blocking.
+    constraint), and the backward's dq must fit a core's fast memory
+    (``_bwd_vmem_bytes``) — anything else raises; interpret mode (CPU CI)
+    accepts any power-of-two-friendly blocking.
     """
     b, h, s, d = q.shape
     blocks = _blocks(s, h, d, block_q, block_k, block_q_bwd, block_k_bwd)

@@ -77,26 +77,32 @@ def _dense_packed(qkv, heads):
     return _xla_attention(q, k, v).transpose(0, 2, 1, 3).reshape(b, s, heads * hd)
 
 
+@pytest.mark.parametrize("block_q,block_k", [
+    (128, 256),  # 4 x 2 grid cells: dq^T waits in its scratch across kv blocks, delta is kept from the first
+    (256, 128),  # 2 x 4, the kv block the smaller: a q block's dq is done before the kv blocks are
+    (512, 512),  # ONE grid block (gpt2m_train's case): every accumulator a value, nothing revisited
+], ids=["grid_128x256", "grid_256x128", "one_block"])
 @pytest.mark.parametrize("head_dim,heads,per_block", [
     (64, 4, 2), (64, 6, 2),    # two heads a 128-lane block: two and three column blocks
     (128, 2, 1), (128, 3, 1),  # one head a block
     (256, 2, 1), (256, 3, 1),  # a head is one block of 256 lanes
     (16, 2, 2),                # tiny widths (CPU tests' models): the heads share one narrow block
 ])
-def test_flash_packed_matches_dense(head_dim, heads, per_block):
+def test_flash_packed_matches_dense(head_dim, heads, per_block, block_q, block_k):
     """The column-blocked entry reads q, k and v out of ONE (batch, seq, 3 x
     heads x head_dim) array and answers in (batch, seq, heads x head_dim):
-    the forward against the dense softmax and the ONE packed gradient
-    against ``jax.grad`` of the XLA path, over a sequence of several grid
-    blocks (cells on, below and above the diagonal)."""
+    the forward against the dense softmax and the ONE packed gradient (dq,
+    dk and dv, the one backward kernel's three column blocks) against
+    ``jax.grad`` of the XLA path, over a sequence of one grid block and of
+    several (cells on, below and above the diagonal)."""
     assert fa._heads_per_block(heads, head_dim) == per_block
     qkv = jax.random.normal(jax.random.PRNGKey(3), (2, 512, 3 * heads * head_dim), jnp.float32)
     w = jnp.cos(jnp.arange(heads * head_dim, dtype=jnp.float32))
-    out = fa.flash_attention_packed(qkv, heads, block_q=128, block_k=256)
+    out = fa.flash_attention_packed(qkv, heads, block_q=block_q, block_k=block_k)
     assert out.shape == (2, 512, heads * head_dim)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_dense_packed(qkv, heads)), atol=2e-5)
     grad = jax.grad(
-        lambda x: (fa.flash_attention_packed(x, heads, block_q=128, block_k=256) * w).sum())(qkv)
+        lambda x: (fa.flash_attention_packed(x, heads, block_q=block_q, block_k=block_k) * w).sum())(qkv)
     ref = jax.grad(lambda x: (_dense_packed(x, heads) * w).sum())(qkv)
     assert grad.shape == qkv.shape
     np.testing.assert_allclose(np.asarray(grad), np.asarray(ref), atol=5e-5)
@@ -143,7 +149,7 @@ def test_flash_packed_refuses_what_it_cannot_tile(monkeypatch):
 
 def test_gpt_block_hands_the_kernels_the_projection_as_it_is():
     """``models.gpt``'s block under ``attn_impl="flash"``: the traced loss
-    and its gradient hold the three kernels and NO transpose, split or
+    and its gradient hold the two kernels and NO transpose, split or
     concatenate of a head (the kernels read the fused projection's own
     output and write what ``attn_out`` reads); and it agrees with the XLA
     path, loss and gradients."""
@@ -164,7 +170,7 @@ def test_gpt_block_hands_the_kernels_the_projection_as_it_is():
 
     names, head_major, eqns = traced(flash)
     kernels = {e.params["name"] for e in eqns if e.primitive.name == "pallas_call"}
-    assert kernels == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert kernels == {"flash_fwd", "flash_bwd"}
     assert not {"split", "concatenate"} & names and head_major == []
     names_xla, head_major_xla, _ = traced(xla)  # what the check would see if it were there
     assert "split" in names_xla and head_major_xla
@@ -193,7 +199,7 @@ def _brute_tiles(block_q, block_k, sub_q, sub_k, off):
 ])
 def test_flash_issues_only_live_sub_tiles(block_q, block_k, sub_q, sub_k, n_diag):
     """The skipping is seen, not only timed: the sub-tile products a kernel
-    body issues (``_live_tiles``, which all three kernels build their loops
+    body issues (``_live_tiles``, which both kernels build their loops
     from) are exactly the sub-tiles that hold a live score, for a cell on
     the diagonal and for one below it; and ``_diag_offsets`` names exactly
     the grid cells the diagonal crosses."""
@@ -216,27 +222,80 @@ def test_flash_issues_only_live_sub_tiles(block_q, block_k, sub_q, sub_k, n_diag
         assert len(fa._live_tiles(block_q, block_k, sub_q, sub_k, 0)) == n_diag < total
 
 
+def _kernel_bodies(seq, count):
+    """``{kernel name: count(equations of its body)}`` of the traced
+    gradient of a head-major call at ``seq``."""
+    x = jax.ShapeDtypeStruct((1, 1, seq, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(q, k, v).sum().astype(
+        jnp.float32), argnums=(0, 1, 2)))(x, x, x)
+    return {
+        eqn.params["name"]: count(list(_eqns(eqn.params["jaxpr"])))
+        for eqn in _eqns(jaxpr.jaxpr) if eqn.primitive.name == "pallas_call"
+    }
+
+
 @pytest.mark.parametrize("seq", [1024, 2048])
 def test_flash_kernel_bodies_hold_only_live_products(seq):
-    """Count the matrix products in the three kernels' bodies as traced:
-    2, 3 and 4 a live sub-tile of the diagonal case, and where the grid
-    has cells below the diagonal 2, 3 and 4 more for the whole case, which
-    runs as ONE tile; nothing for a dead sub-tile or a dead cell."""
+    """Count the matrix products in the two kernels' bodies as traced: 2 a
+    live sub-tile of the diagonal case in the forward and 5 in the ONE
+    backward (scores and ``do v^T`` once, then dv, dk and dq from them: the
+    dQ and dK/dV kernels it replaces held 3 + 4), and where the grid has
+    cells below the diagonal 2 and 5 more for the whole case, which runs as
+    ONE tile; nothing for a dead sub-tile or a dead cell."""
     block = 1024
     sub_q, sub_k = fa._sub_tiles(block, block)
     live = len(fa._live_tiles(block, block, sub_q, sub_k, 0))
     if seq > block:  # a grid of ONE cell a head does not even trace the whole case
         live += len(fa._live_tiles(block, block, block, block, None))
     assert live == (10 if seq == block else 11)
-    x = jax.ShapeDtypeStruct((1, 1, seq, 64), jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(q, k, v).sum().astype(
-        jnp.float32), argnums=(0, 1, 2)))(x, x, x)
-    dots = {
-        eqn.params["name"]: sum(
-            e.primitive.name == "dot_general" for e in _eqns(eqn.params["jaxpr"]))
-        for eqn in _eqns(jaxpr.jaxpr) if eqn.primitive.name == "pallas_call"
-    }
-    assert dots == {"flash_fwd": 2 * live, "flash_bwd_dq": 3 * live, "flash_bwd_dkv": 4 * live}
+    dots = _kernel_bodies(seq, lambda es: sum(e.primitive.name == "dot_general" for e in es))
+    assert dots == {"flash_fwd": 2 * live, "flash_bwd": 5 * live}
+
+
+@pytest.mark.parametrize("seq", [1024, 2048])
+def test_flash_backward_makes_a_tile_s_softmax_weights_once(seq):
+    """ONE ``exp`` pass a live sub-tile in the backward (the two kernels it
+    replaces made every tile's weights twice), two in the forward (the
+    weights and the running maximum's correction); and the backward is one
+    ``pallas_call`` with ONE result, the packed gradient: ``delta`` stays in
+    the kernel's scratch and never reaches HBM."""
+    live = 10 if seq == 1024 else 11
+    exps = _kernel_bodies(seq, lambda es: sum(e.primitive.name == "exp" for e in es))
+    assert exps == {"flash_fwd": 2 * live, "flash_bwd": live}
+    qkv = jax.ShapeDtypeStruct((2, seq, 3 * 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x: fa.flash_attention_packed(x, 2).sum().astype(jnp.float32)))(qkv)
+    (bwd,) = [e for e in _eqns(jaxpr.jaxpr)
+              if e.primitive.name == "pallas_call" and e.params["name"] == "flash_bwd"]
+    assert [v.aval.shape for v in bwd.outvars] == [(2, seq, 3 * 128)]
+
+
+@pytest.mark.parametrize("seq,width,n,block,most_mb", [
+    (1024, 128, 2, 1024, 16),   # gpt2m_train: what Mosaic gives unasked
+    (4096, 128, 2, 1024, 28),   # a whole 1024 x 1024 cell below the diagonal, dq^T of 2 MB
+    (8192, 128, 2, 1024, 30),   # dq^T of 4 MB
+    (4096, 256, 1, 1024, 40),   # a head of 256 lanes
+    (131072, 128, 2, 1024, 100),  # dq^T of 64 MB: the longest the budget carries
+])
+def test_flash_backward_asks_for_the_fast_memory_its_shapes_need(seq, width, n, block, most_mb):
+    """dq^T of the head block's WHOLE sequence lives in the one backward
+    kernel's scratch, so its fast memory grows with the sequence: the
+    kernel asks for it from ``seq``, the block's width and the grid blocks,
+    never under Mosaic's own default, and the public entries refuse a
+    sequence whose dq^T no core could hold."""
+    need = fa._bwd_vmem_bytes(seq, width, n, block, block, 2)
+    assert fa._VMEM_DEFAULT <= need <= most_mb * 2**20
+    assert need >= seq * width * 4 or need == fa._VMEM_DEFAULT
+    assert need <= fa._VMEM_MOST
+
+
+def test_flash_refuses_a_sequence_its_backward_cannot_hold(monkeypatch):
+    """On a TPU a sequence whose dq^T does not fit a core's fast memory is
+    refused by name, before any kernel is built; the interpreter takes it."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    with pytest.raises(ValueError, match="fast memory"):
+        fa._blocks(2**18, 16, 64, None, None, None, None)
+    assert fa._blocks(2**17, 16, 64, None, None, None, None) == (1024,) * 4
 
 
 def test_causal_attention_auto_dispatch_small_seq():

@@ -198,10 +198,10 @@ def test_mosaic_kernels_reads_kernel_names_from_lowered_text():
                 '{backend_config = "{...}", kernel_name = "_fwd_kernel", x = 1}\n'
                 '%4 = stablehlo.custom_call @Sharding(%3) {kernel_name = "no"}\n'
                 '%5 = stablehlo.custom_call @tpu_custom_call(%4) '
-                '{backend_config = "{...}", kernel_name = "_dq_kernel"}\n'
+                '{backend_config = "{...}", kernel_name = "_bwd_kernel"}\n'
             )
 
-    assert mosaic_kernels(Lowered()) == ["_fwd_kernel", "_dq_kernel"]
+    assert mosaic_kernels(Lowered()) == ["_fwd_kernel", "_bwd_kernel"]
 
 
 def test_engine_device_report_names_device_and_step_contents():
