@@ -25,9 +25,18 @@ from typing import Optional
 from ray_tpu import exceptions as rex
 from ray_tpu._private import events
 from ray_tpu._private import serialization as ser
+from ray_tpu._private import startup as _startup
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu._private.log_util import warn_throttled
 from ray_tpu._private.runtime import ObjectRef, WorkerContext, set_ctx
+
+#: ``t_process_start`` of a worker exec'd fresh (start-up ledger): this
+#: module's first line after its imports (10 ms; the interpreter's start and
+#: ``import ray_tpu``, a quarter of a second, lie before it either way:
+#: ``python -m`` imports the package first).  A worker forked from the
+#: template carries the template's value and is stamped with the arrival of
+#: its fork request instead (``worker_template``).
+_T_FIRST_LINE = time.time()
 
 #: flight-recorder events this module emits (raylint RL012 registry): a
 #: task result / stream item entering the shm object plane from this
@@ -158,6 +167,7 @@ def main(
     token: str = "",
     remote: bool = False,
 ):
+    _startup.process_started(_T_FIRST_LINE)  # a forked worker's stamp stands
     # Fault injection for the registration-timeout path (tests): the FIRST
     # process to claim the sentinel wedges pre-registration, like an
     # interpreter that hangs at startup; respawns find the sentinel taken

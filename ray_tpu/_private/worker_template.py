@@ -48,9 +48,11 @@ def main(
     import asyncio  # noqa: F401  (async actor event loops)
     import concurrent.futures  # noqa: F401  (threaded actors / io pools)
     import inspect  # noqa: F401  (actor engine selection)
+    import time
 
     import ray_tpu._private.data_plane  # noqa: F401  (remote arg fetches)
     import ray_tpu._private.runtime_env  # noqa: F401  (renv.applied per task)
+    from ray_tpu._private import startup
 
     import threading
 
@@ -78,6 +80,7 @@ def main(
             token = line.decode().strip()
             if not token:
                 continue
+            t_request = time.time()  # the forked worker's t_process_start
             try:
                 pid = os.fork()
             except OSError as e:
@@ -97,6 +100,7 @@ def main(
                         os.close(fd)  # command + report pipes stay with the
                     except OSError:  # template only
                         pass
+                startup.process_started(t_request)
                 try:
                     worker_main.main(
                         socket_path, authkey, node_id, token, remote=remote

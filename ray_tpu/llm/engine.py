@@ -1172,14 +1172,16 @@ class LLMEngine:
         plus what the engine put on the device: the attention dispatch
         rule's answer for this pool next to the names of the Mosaic
         kernels actually inside each jitted step called so far, each
-        step's first-call (compile) seconds, the jit-cache sizes the
-        retrace detector reads, and the HBM ledger."""
+        step's first-call seconds and their split into trace, lower,
+        compile-or-cache-load and run (``StepRunner.first_call``), the
+        jit-cache sizes the retrace detector reads, and the HBM ledger."""
         from ray_tpu.ops.paged_attention import auto_impl
         from ray_tpu.util.device_prof import device_report
 
         rep = device_report()
         with self._lock:
             rep["first_call_s"] = dict(self.runner.first_call_s)
+            rep["first_call"] = {k: dict(v) for k, v in self.runner.first_call.items()}
             rep["jit_sites"] = self.runner.prof.stats()
             rep["hbm"] = self.hbm_ledger()
         rep["attention"] = {

@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import events as _events
-from ray_tpu._private.compile_cache import ensure_compile_cache
+from ray_tpu._private import compile_cache
 from ray_tpu.models.gpt import GPTConfig, _layernorm
 from ray_tpu.util.device_prof import JitProfiler, mosaic_kernels
 from ray_tpu.models.gptj import GPTJConfig
@@ -420,7 +420,7 @@ class StepRunner:
     arch = "?"
 
     def __init__(self, cfg: Any, params: dict):
-        ensure_compile_cache()
+        compile_cache.ensure_compile_cache()
         self.cfg = cfg
         self.params = params
         self._compiled: set = set()  # (fn, shape-key)s already traced
@@ -431,6 +431,12 @@ class StepRunner:
         #: site -> wall seconds of its first call (trace + compile, or the
         #: persistent-cache load) — set-up time, reported by device_report
         self.first_call_s: dict = {}
+        #: site -> that call split by jax's own timers (the compile
+        #: listener's totals on both sides of it): ``trace_s``, ``lower_s``,
+        #: ``compile_s`` (the compiler, or the cache's load: ``cache_hit``)
+        #: and ``run_s``, the rest: dispatch and whatever the call waited for
+        self.first_call: dict = {}
+        self._totals_before = None  # set by a site's first call alone
         # device-step profiler: per-call wall time into device_step_seconds
         # {site=decode|prefill|verify|fork} + retrace detection against the
         # jit cache size — a site recompiling after its warmup baseline
@@ -447,10 +453,24 @@ class StepRunner:
         if (fn, key) in self._compiled:
             return
         self._compiled.add((fn, key))
-        self.first_call_s[fn] = round(time.perf_counter() - t0, 3)
+        whole = time.perf_counter() - t0
+        self.first_call_s[fn] = round(whole, 3)
+        before, self._totals_before = self._totals_before, None
+        split = {}
+        if before is None:  # the site under a second shape (a retrace):
+            self.first_call.pop(fn, None)  # first_call_s is that call's now
+        else:
+            trace_s, lower_s, compile_s, requests, hits = (
+                b - a for a, b in zip(before, compile_cache.totals())
+            )
+            split = self.first_call[fn] = {
+                "trace_s": trace_s, "lower_s": lower_s, "compile_s": compile_s,
+                "cache_hit": requests > 0 and hits == requests,
+                "run_s": whole - trace_s - lower_s - compile_s,
+            }
         _events.record(
             "llm.compile", fn=fn, shape=str(key), arch=self.arch,
-            first_call_s=self.first_call_s[fn],
+            first_call_s=self.first_call_s[fn], **split,
         )
 
     def prepare_params(self, params: dict) -> dict:
@@ -471,6 +491,7 @@ class StepRunner:
             self._first_operands[site] = (
                 fn, jax.tree_util.tree_map(_abstract, args), static
             )
+            self._totals_before = compile_cache.totals()
         out = fn(*args, **static)
         self._note_compile(site, key, t0)
         self.prof.note(site, fn, time.perf_counter() - t0)

@@ -435,13 +435,19 @@ class ServeController:
                         ready_refs, _ = ray_tpu.wait([r.init_ref], timeout=0)
                         if ready_refs:
                             try:
-                                ray_tpu.get(r.init_ref, timeout=5.0)
+                                ready_at = ray_tpu.get(r.init_ref, timeout=5.0)
                                 r.initialized = True
                                 state.init_failures, state.init_error = 0, None
+                                now = time.time()
                                 _events.record(
                                     "serve.replica_initialized",
                                     replica=r.replica_id,
-                                    init_s=round(time.time() - r.started_at, 3),
+                                    init_s=round(now - r.started_at, 3),
+                                    # the replica's own clock says when it
+                                    # could answer; the rest of init_s is
+                                    # this loop's period and the ping's way
+                                    ready_at=ready_at,
+                                    detect_lag_s=round(now - ready_at, 3),
                                 )
                                 self._bump_version_locked()  # routers may now use it
                             except Exception as e:

@@ -30,6 +30,9 @@ class Replica:
         self._callable = callable_cls(*init_args, **init_kwargs)
         if user_config is not None:
             self.reconfigure(user_config)
+        #: the instant this replica could answer, on the host's wall clock:
+        #: what its first ``check_health`` tells the controller
+        self._ready_at = time.time()
 
     # -- request path ------------------------------------------------------
 
@@ -143,8 +146,12 @@ class Replica:
                 pass  # a broken exporter must not break health/metrics RPCs
         return m
 
-    def check_health(self) -> bool:
+    def check_health(self) -> float:
+        """Raises what the user's ``check_health`` raises; answers with
+        the instant ``__init__`` returned (``serve.replica_initialized``
+        carries it as ``ready_at``, beside how much later the controller
+        noticed)."""
         fn = getattr(self._callable, "check_health", None)
         if fn is not None:
             fn()
-        return True
+        return self._ready_at

@@ -11,9 +11,11 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import time
 from typing import Any, Callable, Optional, Union
 
 import ray_tpu
+from ray_tpu._private import events as _events
 from ray_tpu.serve._private.common import (
     CONTROLLER_NAME,
     AutoscalingConfig,
@@ -218,9 +220,12 @@ def run(
     proxy ingress is up (``GET/POST /<name>`` with a JSON body);
     ``grpc=True`` the gRPC ingress (``ray.serve.GenericService/Predict``
     with ``application`` metadata — see _private/grpc_proxy.py)."""
+    t0 = time.perf_counter()
     controller = _get_or_start_controller()
+    t1 = time.perf_counter()
     specs, ingress = _collect_specs(app, name)
     ray_tpu.get(controller.deploy_application.remote(name, specs), timeout=120)
+    t2 = time.perf_counter()
     if http:
         if http_port is None:
             http_port = _default_http_port()
@@ -229,8 +234,16 @@ def run(
         ray_tpu.get(
             controller.ensure_grpc_proxy.remote(int(grpc_port or 0)), timeout=120
         )
+    t3 = time.perf_counter()
     if _blocking:
         _wait_ready(controller, [spec.name for spec in specs])
+    # the driver's share of a start (the replica's own is its start-up
+    # ledger): nearly all of it is wait_ready_s, the replicas' __init__
+    _events.record(
+        "serve.run", app=name, controller_s=round(t1 - t0, 4),
+        deploy_s=round(t2 - t1, 4), proxy_s=round(t3 - t2, 4),
+        wait_ready_s=round(time.perf_counter() - t3, 4),
+    )
     return DeploymentHandle(ingress)
 
 
@@ -240,8 +253,6 @@ def _wait_ready(controller, deployment_names: list) -> None:
     raise what the replicas' ``__init__`` raised once it has failed
     ``MAX_INIT_FAILURES`` times in a row: waiting out minutes of restart
     loop tells the caller nothing."""
-    import time
-
     from ray_tpu.serve._private.controller import REPLICA_INIT_TIMEOUT_S
 
     deadline = time.time() + REPLICA_INIT_TIMEOUT_S

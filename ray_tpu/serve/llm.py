@@ -29,10 +29,19 @@ depth (> 0 means the engine is admission-bound) and KV utilization
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
-from ray_tpu.llm.engine import EngineConfig, LLMEngine, stream_stats
-from ray_tpu.llm.scheduler import SamplingParams
+from ray_tpu._private import startup as _startup
+
+# a replica's worker first meets jax HERE, unpickling its actor's class:
+# the start-up ledger's ``import`` is this module's own imports, timed
+_t_import = time.perf_counter()
+from ray_tpu._private.compile_cache import ensure_compile_cache  # noqa: E402
+from ray_tpu.llm.engine import EngineConfig, LLMEngine, stream_stats  # noqa: E402
+from ray_tpu.llm.scheduler import SamplingParams  # noqa: E402
+
+_startup.imported(time.perf_counter() - _t_import)
 
 
 def _seeded_params(init, cfg, seed: int, tp: int):
@@ -111,42 +120,59 @@ class LLMDeployment:
         draft_model_cfg=None,
         draft_params: Optional[dict] = None,
     ):
+        # set-up accounts for itself (``_private.startup``): each phase
+        # below is a ``startup.<phase>`` span and a line of the ledger that
+        # ``device_report()`` and ``stats()`` carry
+        _startup.init_begin()
+        with _startup.phase("backend_init"):
+            import jax
+
+            # the first touch of the backend, which used to hide inside
+            # the weights' first jit; the compile listener starts BEFORE
+            # that program compiles, so it is counted (and cached) too
+            jax.devices()
+            ensure_compile_cache()
         tp = engine_config.tp if engine_config is not None else 1
-        cfg, params = _build_model(model, model_cfg, params, seed, tp)
-        # speculative decoding with the small-model drafter
-        # (engine_config.spec_drafter == "model"): the draft model's
-        # config + params pass straight through to the engine; the
-        # default n-gram drafter needs neither
-        if draft_model_cfg is not None and draft_params is None:
-            _, draft_params = _build_model(
-                model, draft_model_cfg, None, seed
-            )
+        with _startup.phase("weights"):  # host time to dispatch, no wait
+            cfg, params = _build_model(model, model_cfg, params, seed, tp)
+            # speculative decoding with the small-model drafter
+            # (engine_config.spec_drafter == "model"): the draft model's
+            # config + params pass straight through to the engine; the
+            # default n-gram drafter needs neither
+            if draft_model_cfg is not None and draft_params is None:
+                _, draft_params = _build_model(
+                    model, draft_model_cfg, None, seed
+                )
+        _startup.stamp_when_ready(jax.tree_util.tree_leaves(params))
         #: max wait for the next streamed token — must cover the ADMISSION
         #: wait of a request queued behind a saturated engine, not just
         #: inter-token gaps (the engine's own 60s default is too tight for
         #: a deployment whose whole point is absorbing a deep queue)
         self._stream_timeout_s = stream_timeout_s
-        self._engine = LLMEngine(
-            cfg, params, engine_config,
-            draft_model_cfg=draft_model_cfg, draft_params=draft_params,
-        )
-        # per-engine watchdog (llm.watchdog): stall detection, wedge-proof
-        # deadline/cancel reaping, KV-pool leak audit — a serving replica
-        # always runs one
-        self._engine.start_watchdog()
+        with _startup.phase("engine_init"):
+            self._engine = LLMEngine(
+                cfg, params, engine_config,
+                draft_model_cfg=draft_model_cfg, draft_params=draft_params,
+            )
+            # per-engine watchdog (llm.watchdog): stall detection, wedge-proof
+            # deadline/cancel reaping, KV-pool leak audit — a serving replica
+            # always runs one
+            self._engine.start_watchdog()
         if warmup:
             # compile the prefill/decode/verify/sampling jits NOW, inside
             # replica creation, so serve.run's readiness gate covers
             # compile time and the first real request streams at
             # steady-state latency (covers BOTH decode paths of a
             # speculating engine — see LLMEngine.warmup)
-            self._engine.warmup()
+            with _startup.phase("warmup"):
+                self._engine.warmup()
         self._stop = threading.Event()
         self._loop = threading.Thread(
             target=self._engine.run_loop, args=(self._stop,),
             name="llm-engine-loop", daemon=True,
         )
         self._loop.start()
+        _startup.ready()
 
     # -- request path ------------------------------------------------------
 
@@ -281,12 +307,14 @@ class LLMDeployment:
         return m
 
     def stats(self) -> dict:
-        """The engine's counters, and under ``"stream"`` the replica
+        """The engine's counters, under ``"stream"`` the replica
         process's station histograms of the streaming path (read after the
         engine answered, without its lock: OBSERVABILITY.md, "The streamed
-        token's stations")."""
+        token's stations"), and under ``"startup"`` the process's start-up
+        ledger (OBSERVABILITY.md, "Start-up ledger")."""
         s = self._engine.stats()
         s["stream"] = stream_stats()
+        s["startup"] = _startup.report()
         return s
 
     def audit(self) -> dict:

@@ -103,7 +103,7 @@ class BackendExecutor:
         config: Optional[dict],
         checkpoint,
         dataset_splitter: Optional[Callable[[int, int], dict]] = None,
-    ) -> None:
+    ) -> list:
         assert self.wg is not None
         calls = []
         for i, w in enumerate(self.wg.workers):
@@ -111,7 +111,7 @@ class BackendExecutor:
             shards = dataset_splitter(ctx.world_rank, ctx.world_size) if dataset_splitter else None
             calls.append(w.start_training.remote(train_fn, config, ctx, checkpoint, shards))
         try:
-            ray_tpu.get(calls)
+            return ray_tpu.get(calls)  # the instant each worker's loop was entered
         except Exception as e:
             # a worker can die before even acking start (instant user crash)
             raise TrainingWorkerError(-1, e, None) from e

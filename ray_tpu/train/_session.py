@@ -19,6 +19,7 @@ import queue
 import threading
 from typing import Any, Callable, Optional
 
+from ray_tpu._private import startup as _startup
 from ray_tpu.train._checkpoint import Checkpoint
 
 
@@ -77,9 +78,15 @@ class _TrainSession:
         self.thread: Optional[threading.Thread] = None
         self.finished = False
 
-    def start(self):
+    def start(self) -> float:
+        """Start the loop's thread; returns the instant (the start-up
+        ledger's ``t_init_begin``).  Process start -> here is the ledger's
+        ``worker_boot``: the loop is the user's from its first line, and the
+        user's code touches jax first."""
         self.thread = threading.Thread(target=self._run, name="train-loop", daemon=True)
+        entered_at = _startup.init_begin()
         self.thread.start()
+        return entered_at
 
     def _run(self):
         global _session

@@ -52,11 +52,12 @@ class RayTrainWorker:
         context: TrainContext,
         checkpoint,
         dataset_shards: Optional[dict],
-    ) -> bool:
+    ) -> float:
+        """Returns the instant the loop was entered in this worker (the
+        trainer's ``train.worker_start`` event carries it)."""
         assert self.session is None or self.session.finished, "training already running"
         self.session = _TrainSession(train_fn, config, context, checkpoint, dataset_shards)
-        self.session.start()
-        return True
+        return self.session.start()
 
     def next_result(self, timeout: float = 1.0):
         """One session event or None: ('result', metrics, ckpt) |

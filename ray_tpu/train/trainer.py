@@ -16,6 +16,7 @@ import os
 import time
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu._private import events as _events
 from ray_tpu.train._backend_executor import (
     BackendExecutor,
     JaxBackend,
@@ -117,12 +118,20 @@ class DataParallelTrainer:
                 experiment_name=run_name,
             )
             try:
+                t_spawn = time.perf_counter()
                 executor.start()
-                executor.start_training(
+                spawn_s = time.perf_counter() - t_spawn
+                entered = executor.start_training(
                     self.train_loop_per_worker,
                     self.train_loop_config,
                     manager.latest() or start_ckpt,
                     self._dataset_splitter(),
+                )
+                # the driver's share of a start; each worker's own is its
+                # start-up ledger (device_report()["startup"])
+                _events.record(
+                    "train.worker_start", run=run_name, spawn_s=round(spawn_s, 4),
+                    loop_entered_at=max(entered),
                 )
                 # history is shared so results committed before a mid-run
                 # worker failure survive the restart

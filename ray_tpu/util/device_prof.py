@@ -179,8 +179,11 @@ def mosaic_kernels(lowered) -> list:
 def device_report() -> dict:
     """What THIS process computes on, as jax reports it: platform,
     device_kind, device count, the jax/jaxlib/libtpu versions, each
-    device's ``memory_stats()`` (where the backend reports one) and the
-    persistent compile cache's location and hit count.  Initializes the
+    device's ``memory_stats()`` (where the backend reports one), the
+    persistent compile cache's location and hit count with the seconds
+    this process spent compiling, and its start-up ledger
+    (``_private.startup``), so that a serve replica and a train worker
+    answer alike.  Initializes the
     backend — call it only in a process that computes (a serve replica,
     a train worker), never in a driver that must leave the chip free."""
     import importlib.metadata
@@ -188,7 +191,7 @@ def device_report() -> dict:
     import jax
     import jaxlib
 
-    from ray_tpu._private import compile_cache
+    from ray_tpu._private import compile_cache, startup
 
     try:
         libtpu = importlib.metadata.version("libtpu")
@@ -214,4 +217,5 @@ def device_report() -> dict:
         },
         "memory": memory,
         "compile_cache": compile_cache.stats(),
+        "startup": startup.report(),
     }
