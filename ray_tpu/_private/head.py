@@ -1213,6 +1213,8 @@ class Head:
             wh.send(("submit_ack", {"wid": msg[1]["wid"]}))
         elif kind == "stream_item":
             self._on_stream_item(wh, msg[1])
+        elif kind == "stream_items":
+            self._on_stream_items(wh, msg[1])
         elif kind == "actor_ready":
             self._on_actor_ready(wh, msg[1])
         elif kind == "profile_result":
@@ -2529,33 +2531,48 @@ class Head:
     # ------------------------------------------------- streaming generators
 
     def _on_stream_item(self, wh: WorkerHandle, payload: dict):
-        """A streaming task yielded one item: store its object and publish
-        the index so blocked ``stream_next`` calls wake (reference:
-        ReportGeneratorItemReturns, task_manager.cc)."""
-        task_id = payload["task_id"]
-        locator = self._normalize_locator(payload["locator"])
+        """A streaming task yielded one item (the per-item producer path):
+        the one-item case of ``_on_stream_items``."""
+        self._on_stream_items(wh, (payload,))
+
+    def _on_stream_items(self, wh: WorkerHandle, payloads) -> None:
+        """Items of one producer's streaming tasks, in the order they were
+        yielded: store each one's object and publish its index so blocked
+        ``stream_next`` calls wake (reference: ReportGeneratorItemReturns,
+        task_manager.cc).  ONE message of the batched producer path
+        (``_private.stream_sink``: a step's tokens, an item a stream or
+        several) costs one take of the lock, one wake-up a stream and one
+        ``cv.notify_all()``, however many items it carries."""
+        located = [(p, self._normalize_locator(p["locator"])) for p in payloads]
         with self.lock:
-            self._store_locator(payload["obj_id"], locator)
-            ent = self.objects.get(payload["obj_id"])
-            if task_id in self._disposed_streams:
-                # consumer walked away; the producer raced the cancel —
-                # free the stored bytes immediately instead of leaking them
+            now = time.perf_counter()
+            woken = {}
+            for payload, locator in located:
+                task_id = payload["task_id"]
+                self._store_locator(payload["obj_id"], locator)
+                ent = self.objects.get(payload["obj_id"])
+                if task_id in self._disposed_streams:
+                    # consumer walked away; the producer raced the cancel —
+                    # free the stored bytes immediately instead of leaking them
+                    if ent is not None:
+                        self._maybe_evict(payload["obj_id"], ent)
+                    continue
+                st = self.streams.setdefault(
+                    task_id, {"items": {}, "count": None, "next": 0}
+                )
                 if ent is not None:
-                    self._maybe_evict(payload["obj_id"], ent)
-                return
-            st = self.streams.setdefault(
-                task_id, {"items": {}, "count": None, "next": 0}
-            )
-            if ent is not None:
-                ent.refcount += 1  # held by the stream until handed out/disposed
-            st["items"][payload["index"]] = payload["obj_id"]
-            # the `head_hold` leg starts (rpc_stream_next ends it); the
-            # producer is remembered for the ack of an item handed out
-            # after its task is done and gone from self.tasks
-            st.setdefault("t_in", {})[payload["index"]] = time.perf_counter()
-            st["wh"] = wh
-            self._wake_stream(st)
-            self.cv.notify_all()  # the object's readiness, as every store
+                    ent.refcount += 1  # held by the stream until handed out/disposed
+                st["items"][payload["index"]] = payload["obj_id"]
+                # the `head_hold` leg starts (rpc_stream_next ends it); the
+                # producer is remembered for the ack of an item handed out
+                # after its task is done and gone from self.tasks
+                st.setdefault("t_in", {})[payload["index"]] = now
+                st["wh"] = wh
+                woken[task_id] = st
+            for st in woken.values():
+                self._wake_stream(st)
+            if woken:
+                self.cv.notify_all()  # the objects' readiness, as every store
 
     def _wake_stream(self, st: Optional[dict]) -> None:
         """Lock held. Wake the consumers blocked in ``stream_next`` on this

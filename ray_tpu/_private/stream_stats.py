@@ -8,10 +8,15 @@ per stream, when the previous item passed it (one ``perf_counter`` float of
 its own process) and observes the gap; where the gap's 95th percentile
 grows from one station to the next is where the tail is made.
 
-* ``emit`` — the engine's loop thread at ``req.stream.put``: this is
+* ``emit`` — the engine's loop thread at ``LLMEngine._emit``: this is
   ``llm_inter_token_latency_s`` (``llm.engine``), on the same boundaries.
-* ``sent`` — the producing worker's handler thread, ``send_raw`` of the
-  ``stream_item`` returned (``worker_main._stream_results_inner``).
+* ``sent`` — the producing worker: ``send_raw`` of the message that carried
+  the item returned.  On the per-item path that is the stream's own handler
+  thread and a ``stream_item`` (``worker_main._stream_results_inner``); on
+  the batched path (a body that adopted its stream's sink:
+  ``_private.stream_sink``) it is the flushing thread and ONE
+  ``stream_items`` for every stream's items of a step, and items of one
+  stream in one message read a gap of 0 between them.
 * ``acked`` — the worker's recv loop, the item's ``stream_ack`` arrived:
   the consumer took it from the head, plus the hop back.  A consumer that
   asks for values (``ObjectRefGenerator.values``) takes every item that
@@ -20,11 +25,20 @@ grows from one station to the next is where the tail is made.
   .report_delivered``; the HTTP proxy reports the gaps between chunks
   written and drained), carried by ``stream_next`` and the ack.
 
-Two legs are durations inside one process: ``wake`` (a token's wait in
-``req.stream`` for its handler thread, ``LLMEngine.stream_tokens``) and
-``head_hold`` (an item's stay in the head before its consumer had it,
-``hold_s`` on the ack).  All of it lands in the PRODUCING worker's
-registry, where ``snapshot()`` reads it for ``LLMDeployment.stats()``.
+Two legs are durations inside one process: ``wake`` (a token's wait for
+the thread that sends it: in ``req.stream`` for its handler thread,
+``LLMEngine.stream_tokens``, or in the outbox until ``stream_sink.Outbox.flush``
+picks it up) and ``head_hold`` (an item's stay in the head before its
+consumer had it, ``hold_s`` on the ack).  All of it lands in the PRODUCING
+worker's registry, where ``snapshot()`` reads it for
+``LLMDeployment.stats()``.
+
+The batched path counts itself under ``batch``: ``sends`` (``stream_items``
+messages), ``items`` and ``streams`` (what they carried: ``items / sends``
+is the rows a message carries, about the live rows of a step) and
+``deferred`` (items a stream's ack window held back to a later message).
+``backpressure`` keeps its meaning on both paths: an item that had to wait
+for its stream's window, and how long.
 """
 
 from __future__ import annotations
@@ -39,16 +53,21 @@ METRIC_NAMES = (
     "core_stream_leg_s",
     "core_stream_backpressure_waits",
     "core_stream_backpressure_wait_s",
+    "core_stream_batch_sends",
+    "core_stream_batch_items",
+    "core_stream_batch_streams",
+    "core_stream_batch_deferred",
 )
 
 STATIONS = ("sent", "acked", "written")
 LEGS = ("wake", "head_hold")
+BATCH = ("sends", "items", "streams", "deferred")
 
 
 class _Stations:
     """The process's station series, each bound to its tag set once."""
 
-    __slots__ = STATIONS + LEGS + ("waits", "wait_s")
+    __slots__ = STATIONS + LEGS + BATCH + ("waits", "wait_s")
 
     def __init__(self):
         from ray_tpu.util.metrics import Counter, Histogram
@@ -75,6 +94,21 @@ class _Stations:
         self.wait_s = Counter(
             "core_stream_backpressure_wait_s",
             "seconds stream producers waited for their ack windows",
+        )
+        self.sends = Counter(
+            "core_stream_batch_sends",
+            "stream_items messages sent by the batched producer path",
+        )
+        self.items = Counter(
+            "core_stream_batch_items", "items those messages carried",
+        )
+        self.streams = Counter(
+            "core_stream_batch_streams",
+            "streams those messages carried items of, summed over messages",
+        )
+        self.deferred = Counter(
+            "core_stream_batch_deferred",
+            "items a stream's ack window held back to a later message",
         )
 
 
@@ -104,4 +138,5 @@ def snapshot(emit: list) -> dict:
         "waits": int(st.waits.value()),
         "wait_s": st.wait_s.value(),
     }
+    out["batch"] = {name: int(getattr(st, name).value()) for name in BATCH}
     return out

@@ -69,7 +69,7 @@ LOCKFREE = (
 class _Stream:
     """The producer's side of one streaming task (under ``stream_lock``)."""
 
-    __slots__ = ("acked", "cond", "t_ack", "rid", "count")
+    __slots__ = ("acked", "cond", "t_ack", "rid", "count", "sink")
 
     def __init__(self, rid: str):
         self.acked = 0      # highest consumer-acked index + 1
@@ -77,6 +77,9 @@ class _Stream:
         self.t_ack: Optional[float] = None  # perf_counter() of the last ack
         self.rid = rid      # the request's trace id, for the spans
         self.count: Optional[int] = None    # items sent, once the producer ended
+        # the batched path's end of this stream, once the task's body adopted
+        # it (``_private.stream_sink``); None on the per-item path
+        self.sink = None
 
 
 #: streams whose producer ended before their last acks came in are kept for
@@ -134,6 +137,9 @@ class WorkerState:
         # second over 32 streams woke 28,000 threads a second that way)
         self.streams: dict[bytes, _Stream] = {}
         self.stream_lock = threading.Lock()
+        # the batched path's one outbox (``_private.stream_sink.Outbox``),
+        # made when a streaming task's body first adopts its sink
+        self.outbox = None
 
 
 def connect_head(address: str, authkey: bytes, retries: int = 3):
@@ -427,18 +433,20 @@ _prof_exit = None  # set by main() when RAY_TPU_WORKER_CPROFILE is on
 # deliberately keep startup import-light), then the per-task path pays
 # module-global loads instead of sys.modules lookups
 _renv = None
+_stream_sink = None
 _stream_stats = None
 _tracing = None
 _waterfall = None
 
 
 def _bind_task_mods() -> None:
-    global _renv, _stream_stats, _tracing, _waterfall
+    global _renv, _stream_sink, _stream_stats, _tracing, _waterfall
     from ray_tpu._private import runtime_env as renv
-    from ray_tpu._private import stream_stats
+    from ray_tpu._private import stream_sink, stream_stats
     from ray_tpu.util import tracing, waterfall
 
-    _renv, _stream_stats, _tracing, _waterfall = renv, stream_stats, tracing, waterfall
+    _renv, _stream_sink, _stream_stats = renv, stream_sink, stream_stats
+    _tracing, _waterfall = tracing, waterfall
 
 
 def _start_profile(ctx, req: dict) -> None:
@@ -537,6 +545,10 @@ def _object_report(ctx, req: dict) -> None:
 
 def _handle_cancel(state: WorkerState, task_id: bytes):
     state.cancel_requested.add(task_id)
+    with state.stream_lock:
+        stream = state.streams.get(task_id)
+    if stream is not None and stream.sink is not None:
+        stream.sink.abandon()  # an adopted stream's body waits on no item: end its wait
     atask = state.async_tasks.get(task_id)
     if atask is not None and state.async_loop is not None:
         state.async_loop.call_soon_threadsafe(atask.cancel)
@@ -693,6 +705,7 @@ def _on_stream_ack(state: WorkerState, ack: dict) -> None:
     tid = ack["task_id"]
     t_prev = rid = None
     together = 0  # items this ack covers beyond its first: taken in one ask
+    behind = False  # the batched path holds items this ack's window lets out
     with state.stream_lock:
         stream = state.streams.get(tid)
         # (None: its last ack is in, or the producer failed or was cancelled)
@@ -704,6 +717,9 @@ def _on_stream_ack(state: WorkerState, ack: dict) -> None:
                 stream.cond.notify()
             if stream.count is not None and stream.acked >= stream.count:
                 del state.streams[tid]  # ended, and this was its last ack
+            behind = stream.sink is not None and bool(stream.sink.held)
+    if behind:
+        state.outbox.flush_soon()  # the sender's: this thread parks on no send
     st = _stream_stats.stations()
     if t_prev is not None:
         st.acked.observe(now - t_prev)
@@ -743,12 +759,11 @@ def _stream_results(state: WorkerState, spec: dict, gen) -> None:
     try:
         _stream_results_inner(state, spec, gen)
     finally:
+        _stream_sink.drive_ended()  # this thread drives no stream any more
         _tracing.set_trace_context(prev_trace)
 
 
 def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
-    from ray_tpu._private.ids import ObjectID, TaskID
-
     task_id = spec["task_id"]
     cap = max(1, GLOBAL_CONFIG.streaming_backpressure_items)
     idx = 0
@@ -769,6 +784,8 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
     stream = _Stream(rid)
     with state.stream_lock:
         state.streams[task_id] = stream
+    # from here on the body may adopt the stream's sink (the batched path)
+    _stream_sink.drive(state, task_id, stream, lambda: idx)
     t_sent = None  # the `sent` station's stamp: when the item before left
     while err is None:
         if task_id in state.cancel_requested:
@@ -783,6 +800,11 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
                 spec.get("name", "task"), e
             )
             break
+        if stream.sink is not None:
+            # the body adopted its sink: what it still yields goes that way
+            stream.sink.push(item)
+            stream.sink.flush()
+            continue
         # the item's way through this thread, on the profiler's clock
         with _tracing.annotate("core.stream.item", rid=rid, i=idx):
             try:
@@ -790,14 +812,7 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
             except Exception as e:  # unserializable item
                 err = rex.RayTaskError.from_exception(spec.get("name", "task"), e)
                 break
-            locator = state.ctx.store_value(sv)
-            if locator[0] == "shm":
-                events.emit(
-                    "core.object.put",
-                    size=locator[1].total_size,
-                    node=locator[1].node,
-                    seg=locator[1].name,
-                )
+            entry = _stream_sink.entry(state.ctx, task_id, idx, sv)
             with state.stream_lock:
                 if idx - stream.acked >= cap:
                     if stream.cond is None:
@@ -815,15 +830,21 @@ def _stream_results_inner(state: WorkerState, spec: dict, gen) -> None:
             if task_id in state.cancel_requested:
                 err = rex.TaskCancelledError()
                 break
-            oid = ObjectID.for_task_return(TaskID(task_id), 1 + idx).binary()
-            state.ctx.send_raw(
-                ("stream_item", {"task_id": task_id, "index": idx, "obj_id": oid, "locator": locator})
-            )
+            state.ctx.send_raw(("stream_item", entry))
             now = time.perf_counter()
             if t_sent is not None:
                 st.sent.observe(now - t_sent)
             t_sent = now
         idx += 1
+    sink = stream.sink
+    if sink is not None:
+        # the batched path: what the body pushed leaves before the stream's
+        # end does (a cancelled stream drops it), and counts
+        idx = sink.close(drain=not isinstance(err, rex.TaskCancelledError))
+        if err is None:
+            err = rex.TaskCancelledError() if sink.cancelled else sink.error
+            if err is not None and not isinstance(err, rex.RayError):
+                err = rex.RayTaskError.from_exception(spec.get("name", "task"), err)
     with state.stream_lock:
         if err is None and stream.acked < idx:
             # acks still on their way: _on_stream_ack drops it at the last

@@ -533,6 +533,7 @@ class LLMEngine:
         self._requests: dict[str, Request] = {}
         self._step_n = 0
         self._tokens_generated = 0
+        self._flush = None  # a sink's ``flush_soon``, once ``_emit`` has pushed to one
         self._prefill_tokens = 0
         self._preemptions = 0
         self._finished_published = 0  # scheduler.finish_count already counted
@@ -679,9 +680,17 @@ class LLMEngine:
         params: Optional[SamplingParams] = None,
         deadline_s: Optional[float] = None,
         resume_tokens: tuple = (),
+        sink=None,
     ) -> Request:
         """Queue a request; returns immediately (drive with ``step()`` or a
         loop thread; consume with ``stream_tokens``).
+
+        ``sink`` — of a caller that took over its stream's sink
+        (``_private.stream_sink.adopt``; the serve deployment): the tokens
+        go to ``sink.push(token, t_emit)`` instead of ``req.stream``, the
+        step hands every row's over with ONE ``sink.flush_soon()``
+        (``_flush_streams``), and ``stream_tokens`` then yields nothing
+        and returns at the request's end.
 
         ``resume_tokens`` — tokens a previous replica already generated for
         this request before dying (mid-stream failover, RESILIENCE.md).
@@ -735,7 +744,9 @@ class LLMEngine:
                 f"(num_blocks={self.pool.cfg.num_blocks}, block 0 reserved)"
             )
         deadline = time.time() + deadline_s if deadline_s is not None else None
-        req = Request(prompt, params, deadline=deadline, resume_tokens=resume_tokens)
+        req = Request(
+            prompt, params, deadline=deadline, resume_tokens=resume_tokens, sink=sink,
+        )
         if req.phase_led is not None:
             # cross-process dispatch leg: the proxy's stream thread stamped
             # its dispatch anchor into the sampled trace-ctx dict it minted
@@ -952,10 +963,18 @@ class LLMEngine:
         import queue as _q
 
         wake = _stream_stats.stations().wake
+        wait = timeout
         while True:
             try:
-                item = req.stream.get(timeout=timeout)
+                item = req.stream.get(timeout=wait)
             except _q.Empty:
+                last = req.last_token_t
+                if req.sink is not None and last is not None:
+                    # its tokens go to its sink and none passes here: the
+                    # stall is ``timeout`` without one, counted from the last
+                    wait = timeout - (time.perf_counter() - last)
+                    if wait > 0:
+                        continue
                 from ray_tpu.llm.watchdog import EngineStalledError
 
                 age, pending = self.progress()
@@ -1274,6 +1293,7 @@ class LLMEngine:
                     self._requests = {
                         k: r for k, r in self._requests.items() if not r.finished
                     }
+                    self._flush_streams()  # the speculative emit, the admit's reap
                     self._publish_gauges()
             self._beat = (
                 time.monotonic(), sched.num_running + sched.num_waiting
@@ -1295,7 +1315,9 @@ class LLMEngine:
         reason = self._doomed_in_flight(doomed)
         if reason is not None:
             self._drain(reason)
-        return self._finish_doomed(doomed)
+        n = self._finish_doomed(doomed)
+        self._flush_streams()  # the watchdog's call is followed by no step
+        return n
 
     def _doomed(self) -> list:
         """(request, finish reason) of what the reap is about to finish."""
@@ -1566,6 +1588,22 @@ class LLMEngine:
             got = self._take_first()
         with self._phase("emit"):
             self._emit(*got)
+            self._flush_streams()
+
+    def _flush_streams(self) -> None:
+        """Hand what ``_emit`` pushed since the last call to the worker's
+        ONE sender thread (``_private.stream_sink.Outbox.flush_soon``): it
+        leaves as one message for every row's token.  This thread only
+        wakes the sender: under its lock and between two launches it
+        serializes nothing and touches no connection.  Called once after a
+        step's emit, after the emits out of turn (``_read_first``,
+        ``_drain``, the reap) and at the step's end; nothing pushed,
+        nothing done.  A stream's end cannot overtake its last tokens: the
+        worker sends what a sink still holds before the completion
+        (``Sink.close``)."""
+        flush, self._flush = self._flush, None
+        if flush is not None:
+            flush()
 
     def _drain(self, reason: str) -> bool:
         """Read and emit whatever is still on the device, out of turn:
@@ -1577,6 +1615,7 @@ class LLMEngine:
                 self._emit_flight(*self._take_flight())
             if self._first is not None:
                 self._emit(*self._take_first())
+            self._flush_streams()
         drains = self._pipe["drains"]
         drains[reason] = drains.get(reason, 0) + 1
         return True
@@ -1752,6 +1791,7 @@ class LLMEngine:
                 got = self._take_flight()
             with self._phase("emit"):
                 self._emit_flight(*got)
+                self._flush_streams()
         if self._first is not None:
             self._read_first()
         if nxt is not None:
@@ -1900,7 +1940,11 @@ class LLMEngine:
         req.last_token_t = t
         req.out.append(tok)
         req.out_logprobs.append(logp)
-        req.stream.put(("token", tok, t))
+        if req.sink is None:
+            req.stream.put(("token", tok, t))
+        else:
+            req.sink.push(tok, t)
+            self._flush = req.sink.flush_soon
         self._tokens_generated += 1
         m["tokens"].inc()
         p = req.params

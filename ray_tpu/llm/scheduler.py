@@ -98,6 +98,7 @@ class Request:
         params: SamplingParams,
         deadline: Optional[float] = None,  # absolute time.time() cutoff
         resume_tokens: tuple = (),
+        sink=None,
     ):
         if not prompt:
             raise ValueError("prompt must contain at least one token")
@@ -154,8 +155,14 @@ class Request:
         # carries the anchor
         self.phase_dispatch_s: Optional[float] = None
         self.cancelled = threading.Event()
-        # stream events: ("token", id) ... ("done", reason)
+        # stream events: ("token", id, t_emit) ... ("done", reason)
         self.stream: queue.SimpleQueue = queue.SimpleQueue()
+        # where the tokens go INSTEAD of ``stream`` when the caller took
+        # over its stream's sink (``sink.push(token, t_emit)``; serve: every
+        # row's token of a step leaves the replica in one message,
+        # ``_private.stream_sink``); the end marker still comes through
+        # ``stream``
+        self.sink = sink
 
     @property
     def seq_len(self) -> int:

@@ -33,13 +33,14 @@ import time
 from typing import Optional
 
 from ray_tpu._private import startup as _startup
+from ray_tpu._private import stream_sink
 
 # a replica's worker first meets jax HERE, unpickling its actor's class:
 # the start-up ledger's ``import`` is this module's own imports, timed
 _t_import = time.perf_counter()
 from ray_tpu._private.compile_cache import ensure_compile_cache  # noqa: E402
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, stream_stats  # noqa: E402
-from ray_tpu.llm.scheduler import SamplingParams  # noqa: E402
+from ray_tpu.llm.scheduler import FINISH_CANCELLED, SamplingParams  # noqa: E402
 
 _startup.imported(time.perf_counter() - _t_import)
 
@@ -231,10 +232,19 @@ class LLMDeployment:
             stop_token_ids=tuple(stop_token_ids),
             seed=seed,
         )
+        # a streaming task's body takes over its stream's sink: the engine
+        # then hands every row's token of a step to ONE sender and this
+        # thread wakes once, at the request's end (None under a plain call
+        # such as ``generate``: the tokens are yielded one by one as ever)
+        sink = stream_sink.adopt()
         req = self._engine.submit(
             [int(t) for t in prompt], params, deadline_s,
             resume_tokens=tuple(int(t) for t in resume_tokens),
+            sink=sink,
         )
+        if sink is not None:
+            # a consumer that walks away ends the wait below at once
+            sink.on_cancel(lambda: req.stream.put(("done", FINISH_CANCELLED)))
         # with an explicit deadline the engine itself ends the stream at
         # the deadline; the get-timeout only needs to outlast it
         timeout = (

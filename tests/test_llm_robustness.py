@@ -124,9 +124,13 @@ class TestResumeTokens:
 
     def test_resume_on_delivered_stop_token(self, shared_engine):
         eng = shared_engine
-        sp = SamplingParams(max_tokens=20, stop_token_ids=(114,))
+        # the stop token is one this model does emit for this prompt, taken
+        # from an unconstrained run (no literal of a seeded toy model's)
+        free = eng.generate(PROMPT, SamplingParams(max_tokens=20))
+        stop = free[5]
+        sp = SamplingParams(max_tokens=20, stop_token_ids=(stop,))
         full = eng.generate(PROMPT, sp)
-        assert full[-1] == 114
+        assert full == free[: free.index(stop) + 1] and full[-1] == stop
         req = eng.submit(PROMPT, sp, resume_tokens=full)
         assert req.finished and req.finish_reason == "stop"
 

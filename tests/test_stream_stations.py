@@ -16,6 +16,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu._private import stream_stats
+from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.util.metrics import (
     FINE_LATENCY_BOUNDS_S,
     Histogram,
@@ -153,6 +154,8 @@ def test_backpressure_waits_are_counted(monkeypatch):
     producer waits for acks, and says how often and how long."""
     # workers read the flag from their environment
     monkeypatch.setenv("RAY_TPU_STREAMING_BACKPRESSURE_ITEMS", "2")
+    # (``init`` reads it into this process's config too: put that back after)
+    monkeypatch.setattr(GLOBAL_CONFIG, "streaming_backpressure_items", int("2"))
     ray_tpu.init(num_cpus=4)
     try:
         actor = Producer.remote()
@@ -228,7 +231,7 @@ def test_llm_deployment_stats_carry_the_stations():
             if gained["acked"] >= 2 * per_stream or time.time() > deadline:
                 break
             time.sleep(0.05)
-        assert set(stream) == set(STATION_KEYS) | {"bounds_s", "backpressure"}
+        assert set(stream) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch"}
         assert stream["bounds_s"] == list(FINE_LATENCY_BOUNDS_S)
         for k in STATION_KEYS:
             assert len(stream[k]) == len(stream["bounds_s"]) + 1, k
@@ -238,9 +241,97 @@ def test_llm_deployment_stats_carry_the_stations():
         # next item: a stream's last gaps can miss its last ask
         assert 2 * per_stream - 6 <= gained["written"] <= 2 * per_stream, gained
         assert set(stream["backpressure"]) == {"waits", "wait_s"}
+        # the replica's streams take the batched path: every token left in
+        # a ``stream_items`` message (a consumer late to ask has several of
+        # its tokens ride one message, once its window opens)
+        batch = {k: stream["batch"][k] - before["batch"][k] for k in stream["batch"]}
+        assert batch["items"] == 2 * n_new, batch
+        assert 0 < batch["sends"] <= batch["streams"] <= batch["items"], batch
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
+
+
+def test_a_steps_rows_leave_the_replica_together():
+    """``sent`` / ``wake`` / ``batch`` on the batched path: two streams on a
+    two-slot engine decode side by side, so a message carries a token of
+    each while both run; ``wake`` counts every token once, ``sent`` a gap a token from each
+    stream's second on, and the tokens are the blocking call's."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import build_llm_app
+
+    ray_tpu.init(num_cpus=8, num_tpus=0)
+    try:
+        handle = serve.run(build_llm_app(**_tiny_llm()), name="llm2")
+        n_new, prompts = 40, ([3, 4, 5, 7], [9, 8, 7, 6])
+        want = [handle.generate.remote(p, max_tokens=n_new).result(timeout=120) for p in prompts]
+        before = handle.stats.remote().result(timeout=60)["stream"]
+        got = [None, None]
+
+        def one(i):
+            got[i] = list(handle.options(stream=True).remote(prompts[i], max_tokens=n_new))
+
+        threads = [threading.Thread(target=one, args=(i,)) for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert got == want
+        deadline = time.time() + 10
+        while True:
+            stream = handle.stats.remote().result(timeout=60)["stream"]
+            gained = {k: sum(stream[k]) - sum(before[k]) for k in STATION_KEYS}
+            if gained["acked"] >= 2 * (n_new - 1) or time.time() > deadline:
+                break
+            time.sleep(0.05)
+        batch = {k: stream["batch"][k] - before["batch"][k] for k in stream["batch"]}
+        assert batch["items"] == gained["wake"] == 2 * n_new, (batch, gained)
+        assert gained["sent"] == gained["emit"] == 2 * (n_new - 1), gained
+        # side by side for part of their 40 steps: fewer messages than tokens
+        assert 0 < batch["sends"] < batch["items"] and batch["streams"] <= batch["items"], batch
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+
+
+def test_the_engine_hands_a_steps_tokens_over_once():
+    """A request with a ``sink``: its tokens go there and not through
+    ``req.stream``, a step that emitted asks for ONE flush once every row's
+    token is pushed, and ``stream_tokens`` sees the end marker alone."""
+    from ray_tpu.llm.scheduler import SamplingParams
+    from ray_tpu.serve.llm import LLMDeployment
+
+    dep = LLMDeployment(warmup=False, **_tiny_llm())
+    dep._stop.set()  # drive the engine by hand
+    dep._engine.start_watchdog().stop()
+    dep._loop.join(timeout=10)
+    eng = dep._engine
+    sp = SamplingParams(max_tokens=12)
+    want = eng.generate([3, 4, 5, 6], sp)
+    flushes, unflushed, toks = [], [0, 0], ([], [])
+
+    class Sink:  # what the engine asks of ``stream_sink.Sink``
+        def __init__(self, i):
+            self.i = i
+
+        def push(self, tok, t_emit):
+            toks[self.i].append(tok)
+            unflushed[self.i] += 1
+
+        def flush_soon(self):
+            flushes.append(tuple(unflushed))
+            unflushed[:] = [0, 0]
+
+    reqs = [eng.submit([3, 4, 5, 6 + i], sp, sink=Sink(i)) for i in (0, 1)]
+    while not all(r.finished for r in reqs):
+        eng.step()
+    assert toks[0] == want and len(toks[1]) == len(want)
+    assert unflushed == [0, 0], "a token pushed and no flush asked for"
+    # one ask a step that emitted, both rows' tokens behind it: never an
+    # ask a row (each first token comes out of turn, in an ask of its own)
+    assert len(flushes) <= len(want) + 4 and (1, 1) in flushes, flushes
+    for r in reqs:
+        assert list(eng.stream_tokens(r, timeout=5)) == []  # the end marker alone
 
 
 def test_the_stream_section_needs_no_engine_lock():
@@ -268,7 +359,7 @@ def test_the_stream_section_needs_no_engine_lock():
         dep._engine.start_watchdog().stop()  # the one the deployment started
         dep._loop.join(timeout=10)
     assert not dep._loop.is_alive()
-    assert set(section) == set(STATION_KEYS) | {"bounds_s", "backpressure"}
+    assert set(section) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch"}
     assert set(whole[0]["stream"]) == set(section) and "steps" in whole[0]
 
 
