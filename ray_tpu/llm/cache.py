@@ -648,8 +648,9 @@ class HybridPool:
     the free / owned partition are the K/V pool's, where the pressure is;
     the slots stand beside them (``ledger_counts``, ``audit``).  The K/V pool
     has the layers the family's ``kv_layout()`` gives it (ONE shared layer:
-    ``models.phi4flash``; every layer's: ``models.falcon_h1``), and a block's
-    bytes are its rows in all of them.
+    ``models.phi4flash``; every layer's: ``models.falcon_h1``; the attention
+    layers of a pattern, the state's leaves counting the others:
+    ``models.granite_h``), and a block's bytes are its rows in all of them.
 
     A table row is ``[slot, block table...]`` and ``arrays`` the K/V pool's
     two followed by the state's leaves, in the order the model's steps take

@@ -6,7 +6,8 @@ one scheduler.  A model without keys and values (``cache_kind == "state"``:
 same calls: a sequence owns one fixed-size recurrent state, so admission is
 a free slot, nothing grows or is preempted, and the prefix cache,
 speculation and ``tp > 1`` are refused.  A model with BOTH
-(``cache_kind == "hybrid"``: ``models.phi4flash``, ``models.falcon_h1``) gets
+(``cache_kind == "hybrid"``: ``models.phi4flash``, ``models.falcon_h1``,
+``models.granite_h``) gets
 ``cache.HybridPool`` and ``state_runner.HybridModelRunner``: a slot of state
 and growing blocks of K/V (as many layers of them as the family's
 ``kv_layout()`` says: one shared layer, or every layer's) behind one ledger,
@@ -1154,10 +1155,8 @@ class LLMEngine:
                 if st is not self.pool:  # a hybrid pool: its K/V layers
                     s["kv_pool"] = self._kv_pool_stats(led)
             elif self.runner.arch == "hybrid":
-                # a body over blocks alone: the same account of its decodes
-                # beside the pool's
-                s["kv_pool"] = dict(self._kv_pool_stats(led), **{
-                    k: self._state_n[k] for k in ("decodes", "decode_rows", "decode_tokens")})
+                # a body over blocks alone
+                s["kv_pool"] = self._kv_pool_stats(led)
             # what the body counted on the device: the reader is taken here
             # and called below, outside the lock, because it waits for every
             # step launched so far and the step loop must not wait with it
@@ -1184,6 +1183,9 @@ class LLMEngine:
             "live": led["seq_bytes"] // led["block_bytes"],
             "block_tokens": self.pool.cfg.block_size,
             "bytes": led["pool_bytes"] - led.get("state_bytes", 0),
+            # the decodes' occupancy, the same three counts ``state_pool``
+            # has where there is one: a reader of either finds them
+            **{k: self._state_n[k] for k in ("decodes", "decode_rows", "decode_tokens")},
         }
 
     def device_report(self) -> dict:

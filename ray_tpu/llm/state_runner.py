@@ -34,7 +34,8 @@ back in its place.  Two kinds of family take it:
 
 * sequences hold blocks of K/V AND a slot of state (``cache_kind ==
   "hybrid"``, ``cache.HybridPool``; ``models.phi4flash``: ONE shared K/V
-  layer; ``models.falcon_h1``: K/V in every layer): a table row is ``[slot,
+  layer; ``models.falcon_h1``: K/V in every layer; ``models.granite_h``: K/V
+  in the attention layers of a pattern, state in the others): a table row is ``[slot,
   block table...]``, and a dead decode row feeds position 0 of the trash slot
   and the trash block;
 * sequences hold blocks alone, of whatever the body's ``kv_layout()`` says a
@@ -44,7 +45,7 @@ back in its place.  Two kinds of family take it:
   runs the radix prefix cache over it.
 
 A body may count on the device (``counters()``: shapes; ``models.kimi_k2``
-counts the router's load): those arrays ride every step after the pool's
+and ``models.granite_h`` count the router's load): those arrays ride every step after the pool's
 and are handed back, NOT donated (a few dozen numbers), and stay HERE and not
 in the pool; ``counters()`` of the runner gives a reader that fetches them
 (``LLMEngine.stats()`` alone does, outside its lock).

@@ -2,7 +2,9 @@
 is spread over many (expert parallelism, this chip's share).
 
 The router scores every token over ALL of the layer's experts and chooses
-``top_k`` of them (``route``); this chip holds experts ``offset .. offset +
+``top_k`` of them (``route``: sigmoid scores and a selection bias;
+``route_logits``: the largest logits, a softmax over the chosen; a family
+calls the one its model has); this chip holds experts ``offset .. offset +
 held`` and computes THEIR part of the result: ``sum over chosen AND held e
 of w_e * Expert_e(x)``.  What the absent experts would add is left out (it
 is the other chips' part); nothing here stands in for them.
@@ -24,6 +26,10 @@ import jax
 import jax.numpy as jnp
 
 
+#: rows a tile of ``expert_layer`` holds (a batch of fewer rows is one tile)
+TILE = 64
+
+
 def route(x32, router_kernel, select_bias, top_k: int, scaling: float):
     """Sigmoid scores over every expert, ``top_k`` chosen by ``score +
     select_bias`` (the bias chooses and does not weigh), weights the chosen
@@ -37,6 +43,18 @@ def route(x32, router_kernel, select_bias, top_k: int, scaling: float):
     _, chosen = jax.lax.top_k(p + select_bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(p, chosen, axis=-1)
     return chosen, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
+
+
+def route_logits(x32, router_kernel, top_k: int):
+    """The second router (Granite-4.0-H's): the ``top_k`` largest LOGITS over
+    every expert chosen, weights a softmax over the chosen logits alone: no
+    sigmoid, no selection bias, no scaling factor.  All float32, the product
+    at ``highest`` precision.  x32: (N, d) float32.  Returns (chosen (N,
+    top_k) int32, weights (N, top_k) float32, summing to 1)."""
+    z = jnp.dot(x32, router_kernel.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    picked, chosen = jax.lax.top_k(z, top_k)
+    return chosen, jax.nn.softmax(picked, axis=-1)
 
 
 def held_pairs(chosen, weights, offset: int, held: int, live):
@@ -57,7 +75,14 @@ def swiglu(x, gate, up, down):
     return dot((jax.nn.silu(dot(x, gate)) * dot(x, up)).astype(x.dtype), down)
 
 
-def expert_layer(x, mask, wmat, gate, up, down, *, first=0, tile: int = 64):
+def tile_rows(load, n: int, tile: int = TILE):
+    """The rows ``expert_layer``'s tiles compute for a ``load`` (pairs by
+    held expert) over ``n`` rows: every tile is whole, whatever it holds."""
+    tile = min(tile, n)
+    return ((load + (tile - 1)) // tile).sum().astype(jnp.int32) * tile
+
+
+def expert_layer(x, mask, wmat, gate, up, down, *, first=0, tile: int = TILE):
     """``sum_e wmat[:, e] * Expert_e(x)`` over exactly the pairs in ``mask``.
     x: (N, d) in the products' dtype; mask, wmat: (N, E); gate, up: (.., d,
     f), down: (.., f, d): expert e's weights at ``first + e`` (``first`` may

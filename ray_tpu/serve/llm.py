@@ -80,6 +80,8 @@ _FAMILIES = {
     "kimi_k2": ("ray_tpu.models.kimi_k2", "KimiK2Config", "kimi_k2_init", "KimiK2Config"),
     "falcon_h1": ("ray_tpu.models.falcon_h1", "FalconH1Config", "falcon_h1_init",
                   "FalconH1Config"),
+    "granite_h": ("ray_tpu.models.granite_h", "GraniteHConfig", "granite_h_init",
+                  "GraniteHConfig"),
 }
 
 

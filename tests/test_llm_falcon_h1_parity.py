@@ -363,8 +363,10 @@ def test_the_engines_account_of_both_caches():
     state, led = s["state_pool"], s["hbm"]
     assert state["slots"] == SLOTS and state["live"] == 0
     assert set(state["kinds"]) == {"conv", "ssd"} and state["bytes"] == sum(state["kinds"].values())
+    # the decodes' occupancy stands under both pools, the same three counts
     assert s["kv_pool"] == {"blocks": 39, "live": 0, "block_tokens": BLOCK,
-                            "bytes": eng.pool.kv.device_bytes}
+                            "bytes": eng.pool.kv.device_bytes,
+                            **{k: state[k] for k in ("decodes", "decode_rows", "decode_tokens")}}
     # the memory split: K/V blocks by the ledger, the slots of state beside them
     assert led["pool_bytes"] == eng.pool.device_bytes == state["bytes"] + s["kv_pool"]["bytes"]
     assert led["block_bytes"] == eng.pool.kv.device_bytes // 40

@@ -324,8 +324,10 @@ def test_the_audit_holds_after_a_churn_of_200_requests():
     assert audit["ok"] and audit["owned"] == 0 and audit["free"] == 39
     assert audit["slots"]["free"] == SLOTS and not audit["unpaired"]
     assert s["state_pool"]["slots"] == SLOTS and s["state_pool"]["live"] == 0
+    # the decodes' occupancy stands under both pools, the same three counts
     assert s["kv_pool"] == {"blocks": 39, "live": 0, "block_tokens": BLOCK,
-                            "bytes": eng.pool.kv.device_bytes}
+                            "bytes": eng.pool.kv.device_bytes,
+                            **{k: s["state_pool"][k] for k in ("decodes", "decode_rows", "decode_tokens")}}
     assert set(s["state_pool"]["kinds"]) == {"conv", "scan", "ring_k", "ring_v"}
     assert s["state_pool"]["decode_tokens"] > s["state_pool"]["decode_rows"] > 0
     assert s["hbm"]["pool_bytes"] == eng.pool.device_bytes

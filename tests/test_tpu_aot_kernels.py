@@ -236,10 +236,14 @@ def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 700 * 2**20
 
 
-def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip):
+@pytest.mark.parametrize("d,f,held,layers", [
+    (7168, 2048, 12, 6),    # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
+    (4096, 768, 36, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
+], ids=["kimi", "granite_h"])
+def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip, d, f, held, layers):
     """The tile loop indexes the experts of every layer where they lie: a
     decode's expert layer at the published widths holds no temporary the size
-    of an expert (88 MB), let alone of a layer's twelve."""
+    of an expert, let alone of a layer's held ones."""
     from ray_tpu.ops import moe
 
     def sds(shape, dtype):
@@ -248,20 +252,27 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip):
     def layer(x, mask, wmat, gate, up, down, first):
         return moe.expert_layer(x, mask, wmat, gate, up, down, first=first)
 
+    n = held * layers
     compiled = jax.jit(layer).lower(
-        sds((16, 7168), jnp.bfloat16), sds((16, 12), jnp.bool_), sds((16, 12), jnp.float32),
-        sds((72, 7168, 2048), jnp.bfloat16), sds((72, 7168, 2048), jnp.bfloat16),
-        sds((72, 2048, 7168), jnp.bfloat16), sds((), jnp.int32)).compile()
+        sds((16, d), jnp.bfloat16), sds((16, held), jnp.bool_), sds((16, held), jnp.float32),
+        sds((n, d, f), jnp.bfloat16), sds((n, d, f), jnp.bfloat16),
+        sds((n, f, d), jnp.bfloat16), sds((), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
-def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(one_chip):
-    """Falcon-H1's Mamba-2 update at the cell's sizes: 16 rows, 32 heads of
-    128 x 256 float32, 8 heads a grid step.  The pool is aliased and no copy
+@pytest.mark.parametrize("h,p,n,slots,hb", [
+    (32, 128, 256, 34, 8),        # falcon-h1-34b-l8-1chip
+    (128, 64, 128, 9 * 17, 32),   # granite-4.0-h-small-ep2-l10-1chip: half a vreg's lanes a row
+], ids=["falcon_h1", "granite_h"])
+def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(
+        one_chip, h, p, n, slots, hb):
+    """The Mamba-2 update at each cell's sizes: 16 rows, ``h`` heads of ``p x
+    n`` float32, 1 MB of heads a grid step.  The pool is aliased and no copy
     of it stands among the temporaries."""
     from ray_tpu.ops import ssd
 
-    s, h, p, n, slots = 16, 32, 128, 256, 34
+    s = 16
+    assert ssd.heads_per_block(h, p, n) == hb
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -276,10 +287,14 @@ def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(one_chip)
     assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes < pool // 16
 
 
-def test_five_query_heads_a_kv_head_through_the_paged_kernel_compile_for_a_v5e(
-        one_chip, monkeypatch):
-    """Falcon-H1's decode attention: 20 query heads on 4 key-value heads of
-    128 ride the window axis (w = 5) over 8 layers of 1,601 blocks of 128."""
+@pytest.mark.parametrize("heads,kv,blocks,tmax", [
+    (20, 4, 8 * 1601, 100),  # falcon-h1-34b-l8-1chip: w = 5 over 8 layers of 1,601 blocks
+    (32, 8, 225, 14),        # granite-4.0-h-small-ep2-l10-1chip: w = 4 over ONE layer
+], ids=["falcon_h1", "granite_h"])
+def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
+        one_chip, monkeypatch, heads, kv, blocks, tmax):
+    """The decode attention of the two grouped-query families: the query
+    heads of a key-value head of 128 ride the window axis over blocks of 128."""
     from ray_tpu.ops import gqa_attention as ga
 
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
@@ -287,10 +302,10 @@ def test_five_query_heads_a_kv_head_through_the_paged_kernel_compile_for_a_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((8 * 1601, 4, 128, 128), jnp.bfloat16)
+    pool = sds((blocks, kv, 128, 128), jnp.bfloat16)
     compiled = jax.jit(
         lambda q, k, v, t, p: ga.gqa_paged_attention(q, k, v, t, p, impl="pallas")
-    ).lower(sds((16, 20, 128), jnp.bfloat16), pool, pool,
-            sds((16, 100), jnp.int32), sds((16,), jnp.int32)).compile()
+    ).lower(sds((16, heads, 128), jnp.bfloat16), pool, pool,
+            sds((16, tmax), jnp.int32), sds((16,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
