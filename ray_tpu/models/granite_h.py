@@ -46,7 +46,8 @@ layer loop is one ``_carry_loop`` for each RUN of layers of one kind
 four Mamba layers), every run over the same pools; a layer's held experts
 are indexed out of ONE flat array of every layer's (``ops.moe.expert_layer``).
 ``counters`` is what the programs count on the device, under Kimi-K2.5's
-names and one more (``decode_tile_rows``: the rows the tile loop computed).
+names and one more (``decode_tile_rows``: the rows the expert layer computed
+in decodes, in either of its forms).
 """
 
 from __future__ import annotations
@@ -61,12 +62,13 @@ import numpy as np
 
 from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
 from ray_tpu.ops.gqa_attention import gqa_chunk_attention, gqa_paged_attention
-from ray_tpu.ops.moe import expert_layer, held_pairs, route_logits, swiglu, tile_rows
+from ray_tpu.ops.moe import (
+    batch_steps, expert_layer, held_pairs, route_logits, swiglu, tile_rows)
 from ray_tpu.ops.ssd import ssd_chunk, ssd_decode
 
 #: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
 COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
-            "decode_tile_rows")
+            "decode_tile_rows", "decode_expert_steps")
 #: the published pattern's first period
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -277,8 +279,8 @@ class GraniteHBody:
     ``arrays`` is ``(k, v, conv, ssd, counters)``: K and V ``(attention
     layers, blocks, K, block, e)``, the convolution's tails ``(Mamba layers,
     slots + 1, d_conv - 1, conv_dim)``, the SSD states ``(Mamba layers, slots
-    + 1, H, P, N)`` and the device's own counts ``(1, 6 + experts_held)``
-    int32.  A table row is ``[slot, block table...]``, slot 0 and block 0 the
+    + 1, H, P, N)`` and the device's own counts ``(1, len(COUNTERS) +
+    experts_held)`` int32.  A table row is ``[slot, block table...]``, slot 0 and block 0 the
     trash a dead decode row and a padded chunk row write; a dead row has no
     pair in the expert layer and counts nowhere."""
 
@@ -393,8 +395,8 @@ class GraniteHBody:
     def _expert_mlp(self, h, layer, live, counts, phase: str, experts, index):
         """The expert layer's part this chip holds, and the shared MLP.
         ``counts`` gets this layer's pairs under ``<phase>_pairs``, its load
-        by held expert and, in a decode, its touched experts and the rows
-        its tiles computed.  ``experts``: the held experts of every layer,
+        by held expert and, in a decode, its touched experts, the rows the
+        expert layer computed and the steps its batch form made.  ``experts``: the held experts of every layer,
         flat, this layer's from ``index * experts_held``."""
         cfg = self.cfg
         with jax.named_scope("moe_router"):
@@ -409,10 +411,13 @@ class GraniteHBody:
                     (load > 0).sum().astype(jnp.int32))
                 counts = counts.at[COUNTERS.index("decode_tile_rows")].add(
                     tile_rows(load, mask.shape[0]))
+                counts = counts.at[COUNTERS.index("decode_expert_steps")].add(
+                    batch_steps(load, mask.shape[0]))
         y, sh = y32.astype(self.dt), layer["shared"]
         with jax.named_scope("moe_experts"):
             routed = expert_layer(y, mask, wmat, experts["gate"], experts["up"],
-                                  experts["down"], first=index * cfg.experts_held)
+                                  experts["down"], first=index * cfg.experts_held,
+                                  impl=cfg.attn_impl)
         with jax.named_scope("moe_shared"):
             return h + cfg.residual_multiplier * (
                 routed + swiglu(y, sh["gate"], sh["up"], sh["down"])), counts

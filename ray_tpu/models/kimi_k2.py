@@ -54,10 +54,11 @@ from ray_tpu.ops.latent_attention import (
     latent_decode_attention,
     padded_width,
 )
-from ray_tpu.ops.moe import expert_layer, held_pairs, route, swiglu
+from ray_tpu.ops.moe import batch_steps, expert_layer, held_pairs, route, swiglu
 
 #: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
-COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks")
+COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
+            "decode_expert_steps")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,11 +226,11 @@ def _dot32(x, kernel):
 class KimiK2Body:
     """The family's traced layer programs for ``HybridModelRunner``.
     ``arrays`` is ``(pool, counters)``: the latent pool ``(L, blocks, 1,
-    block, width)`` and the device's own counts ``(1, 6 + experts_held)``
-    int32.  A table row is the sequence's block table; block 0 is the trash
-    a dead decode row and a padded chunk row write, and a row whose first
-    block is 0 is dead: it has no pair in the expert layer and counts
-    nowhere."""
+    block, width)`` and the device's own counts ``(1, len(COUNTERS) +
+    experts_held)`` int32.  A table row is the sequence's block table; block
+    0 is the trash a dead decode row and a padded chunk row write, and a row
+    whose first block is 0 is dead: it has no pair in the expert layer and
+    counts nowhere."""
 
     def __init__(self, cfg: KimiK2Config):
         self.cfg = cfg
@@ -348,9 +349,10 @@ class KimiK2Body:
         """The expert layer's part this chip holds, and the shared expert.
         ``counts`` gets this layer's pairs under ``<phase>_pairs``, its load
         by held expert and, in a decode, its touched experts (what the
-        decode reads of the held weights).  ``experts``: the
-        held experts of every layer, flat, this layer's from ``index *
-        experts_held`` (None: the layer's own, ``layer["experts"]``)."""
+        decode reads of the held weights) and the steps the expert layer's
+        batch form made of them.  ``experts``: the held experts of every
+        layer, flat, this layer's from ``index * experts_held`` (None: the
+        layer's own, ``layer["experts"]``)."""
         cfg = self.cfg
         with jax.named_scope("moe_router"):
             y32 = _rmsnorm(h, layer["ln2"]["scale"], cfg.rms_norm_eps)
@@ -364,11 +366,13 @@ class KimiK2Body:
             if phase == "decode":
                 counts = counts.at[COUNTERS.index("decode_touched")].add(
                     (load > 0).sum().astype(jnp.int32))
+                counts = counts.at[COUNTERS.index("decode_expert_steps")].add(
+                    batch_steps(load, mask.shape[0]))
         y = y32.astype(self.dt)
         ex, sh = experts or layer["experts"], layer["shared"]
         with jax.named_scope("moe_experts"):
             routed = expert_layer(y, mask, wmat, ex["gate"], ex["up"], ex["down"],
-                                  first=index * cfg.experts_held)
+                                  first=index * cfg.experts_held, impl=cfg.attn_impl)
         with jax.named_scope("moe_shared"):
             return h + routed + swiglu(y, sh["gate"], sh["up"], sh["down"]), counts
 

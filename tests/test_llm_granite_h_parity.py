@@ -320,6 +320,160 @@ def test_the_tile_loops_rows_by_hand(load, n, rows):
     assert int(moe.tile_rows(jnp.asarray(load, jnp.int32), n)) == rows
 
 
+# -- the batch form: a batch of no more rows than a tile makes no tile ---------------------
+
+
+@pytest.mark.parametrize("load,n,steps", [
+    ([0, 0, 0], 16, 0),            # no expert touched, no step
+    ([1, 0, 3], 16, 2),            # a step a touched expert, whatever it holds
+    ([16, 2, 1], moe.TILE, 3),     # a batch of a tile's rows is still the batch form
+    ([16, 2, 1], moe.TILE + 1, 0),  # one row more: the tile loop, which counts none
+    ([70, 1, 64], 512, 0),
+])
+def test_the_batch_forms_steps_by_hand(load, n, steps):
+    assert int(moe.batch_steps(jnp.asarray(load, jnp.int32), n)) == steps
+
+
+@pytest.mark.parametrize("hit,ids,count", [
+    ([0, 0, 0, 0], [0, 0, 0, 0], 0),          # nothing touched: every step names expert 0
+    ([0, 1, 0, 1], [1, 3, 3, 3], 2),          # past the count: the last touched, again
+    ([1, 1, 1, 1], [0, 1, 2, 3], 4),
+    ([0, 0, 1, 0], [2, 2, 2, 2], 1),
+])
+def test_the_touched_experts_are_listed_in_order_without_a_sort(hit, ids, count):
+    mask = jnp.zeros((5, 4), bool).at[2].set(jnp.asarray(hit, bool))
+    got, n = moe.touched(mask)
+    assert got.tolist() == ids and int(n) == count
+    assert "sort" not in str(jax.make_jaxpr(moe.touched)(mask))
+
+
+@pytest.mark.parametrize("d,f,size,bf", [
+    (4096, 768, 2, 768),      # granite-4.0-h-small: 18.9 MB an expert, whole
+    (7168, 2048, 2, 512),     # kimi-k2.5: 88 MB an expert, a quarter of f a step
+    (7168, 2048, 4, 256),
+    (64, 16, 4, 16),
+])
+def test_the_kernels_blocks_follow_the_shape_and_the_budget(d, f, size, bf):
+    assert moe.block_f(d, f, size) == bf
+    assert 2 * 3 * d * bf * size <= moe.VMEM_BUDGET
+    with pytest.raises(ValueError, match="no block"):
+        moe.block_f(d, f, size, budget=6 * d * min(f, 128) * size - 1)
+
+
+def _by_hand(case, n, experts=6, seed=3):
+    """A load set by hand over ``n`` rows and 6 held experts: (mask, wmat)."""
+    rng = np.random.default_rng(seed)
+    wmat = rng.uniform(0.1, 1.0, (n, experts)).astype(np.float32)
+    mask = np.zeros((n, experts), bool)
+    if case == "every_row_one_expert":
+        mask[:, 4] = True
+    elif case == "a_dead_row":            # row 0 has no pair; the rest two or three each
+        mask[1:] = rng.random((n - 1, experts)) < 0.4
+        mask[1:, 1] = True
+    elif case == "scattered":
+        mask[:] = rng.random((n, experts)) < 0.3
+    else:
+        assert case == "no_row_any_expert"
+    return jnp.asarray(mask), jnp.asarray(np.where(mask, wmat, 0.0))
+
+
+def _dense(lay, mask, wmat, first):
+    """The uncut sum: every held expert on every row, the weight 0 where the
+    row did not choose it."""
+    with jax.default_matmul_precision("highest"):
+        return sum(wmat[:, e:e + 1] * moe.swiglu(
+            lay["x"], lay["gate"][first + e], lay["up"][first + e], lay["down"][first + e])
+            for e in range(mask.shape[1]))
+
+
+def _through_the_tile_loop(lay, mask, wmat, first):
+    """The same rows with one dead row appended, so that they are one more
+    than the tile: the tile loop, whatever ``n`` is."""
+    n = mask.shape[0]
+    pad = lambda a: jnp.concatenate([a, jnp.zeros_like(a[:1])])  # noqa: E731
+    return moe.expert_layer(pad(lay["x"]), pad(mask), pad(wmat), lay["gate"], lay["up"],
+                            lay["down"], first=first, tile=n)[:n]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("case,n", [
+    ("every_row_one_expert", 16), ("no_row_any_expert", 16), ("a_dead_row", 16),
+    ("scattered", 1), ("scattered", 5), ("scattered", 16),
+])
+def test_the_batch_form_equals_the_tile_loop_and_the_uncut_sum(case, n, impl):
+    lay = _layer(n=n, experts=10)        # the 6 held experts lie at 3..8 of the flat array
+    mask, wmat = _by_hand(case, n)
+    got = moe.expert_layer(lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"],
+                           first=3, impl=impl)
+    assert got.shape == lay["x"].shape and got.dtype == jnp.float32
+    assert np.abs(np.asarray(got) - np.asarray(_dense(lay, mask, wmat, 3))).max() < 1e-5
+    loop = _through_the_tile_loop(lay, mask, wmat, 3)
+    assert np.abs(np.asarray(got) - np.asarray(loop)).max() < 1e-5
+    if case == "no_row_any_expert":
+        assert not np.asarray(got).any()
+    if case == "a_dead_row":
+        assert not np.asarray(got[0]).any() and np.asarray(got[1]).any()
+
+
+@pytest.mark.parametrize("d,f,bf,dtype", [
+    (256, 384, None, "bfloat16"),    # granite-4.0-h-small's 4096 x 768, a sixteenth: whole
+    (448, 256, 128, "bfloat16"),     # kimi-k2.5's 7168 x 2048: f cut in blocks
+    (448, 256, 128, "float32"),
+    (64, 48, 16, "float32"),
+], ids=["granite_h", "kimi", "kimi_f32", "three_blocks"])
+def test_the_kernel_in_interpret_mode_equals_the_plain_batch_form(d, f, bf, dtype):
+    lay = {k: v.astype(dtype) if k != "router" else v
+           for k, v in _layer(n=16, d=d, f=f, experts=12).items()}
+    mask, wmat = _by_hand("scattered", 16, experts=5, seed=8)
+    ids, count = moe.touched(mask)
+    assert 2 <= int(count) <= 5
+    args = (lay["x"], jnp.where(mask, wmat, 0.0).T, ids, count,
+            lay["gate"], lay["up"], lay["down"], 7)
+    plain = moe._batch_xla(*args)
+    kernel = moe._batch_pallas(*args, interpret=True, bf=bf)
+    # the same products on the same dtype; cut blocks add the down product's
+    # partial sums in float32 in another order
+    assert np.abs(np.asarray(kernel) - np.asarray(plain)).max() < 1e-5 * np.abs(
+        np.asarray(plain)).max()
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_rows_result_is_bit_equal_whatever_the_other_rows_chose(impl):
+    lay = _layer(n=16, experts=6)
+    mine = jnp.asarray([False, True, False, True, True, False])
+    w = jnp.asarray(np.random.default_rng(1).uniform(0.1, 1.0, (16, 6)).astype(np.float32))
+    outs = []
+    for others in ("none", "all", "some", "other_experts"):
+        mask = {"none": jnp.zeros((16, 6), bool), "all": jnp.ones((16, 6), bool),
+                "some": _by_hand("scattered", 16)[0],
+                "other_experts": jnp.broadcast_to(~mine, (16, 6))}[others].at[3].set(mine)
+        out = moe.expert_layer(lay["x"], mask, jnp.where(mask, w, 0.0), lay["gate"], lay["up"],
+                               lay["down"], impl=impl)
+        outs.append(np.asarray(out[3]))
+    assert outs[0].any() and all((o == outs[0]).all() for o in outs[1:])
+
+
+def _primitives(n, impl):
+    lay = _layer(n=n, experts=6)
+    mask, wmat = _by_hand("scattered", n)
+    text = str(jax.make_jaxpr(lambda *a: moe.expert_layer(*a, impl=impl))(
+        lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"]))
+    return {name for name in ("pallas_call", "sort", "scatter-add", "gather") if name in text}
+
+
+def test_the_rows_choose_the_form_and_nothing_else_does():
+    # one row more than a tile: the tile loop, its sort, gather and scatter-add,
+    # and no kernel whatever ``impl`` says
+    for impl in ("auto", "xla", "pallas"):
+        assert _primitives(moe.TILE + 1, impl) == {"sort", "scatter-add", "gather"}
+    # a tile's rows: the batch form, which sorts, gathers and scatters nothing
+    assert _primitives(moe.TILE, "pallas") == {"pallas_call"}
+    assert _primitives(moe.TILE, "xla") == set() == _primitives(moe.TILE, "auto")
+    assert _primitives(16, "pallas") == {"pallas_call"}
+    with pytest.raises(ValueError, match="unknown expert impl"):
+        _primitives(16, "mosaic")
+
+
 # -- in place ---------------------------------------------------------------------------
 
 
@@ -442,6 +596,8 @@ def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
     # and each touched expert is one tile of the batch's rows
     assert moe_n["decode_touched"] == moe_n["decode_pairs"]
     assert moe_n["decode_tile_rows"] == moe_n["decode_touched"] * SLOTS
+    # every one of them through the batch form (3 rows are no more than a tile)
+    assert moe_n["decode_expert_steps"] == moe_n["decode_touched"] > 0
     assert moe_n["load"] == [int(x) for x in held.sum(axis=(0, 1))]
     # the decodes' occupancy: the same three counts under both pools
     for pool_n in (kv_n, state_n):
@@ -451,6 +607,16 @@ def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
     assert kv_n["bytes"] == eng.pool.kv.device_bytes  # ONE layer's K and V
     assert set(state_n["kinds"]) == {"conv", "ssd"} and state_n["slots"] == SLOTS
     assert state_n["chunks"] == 3 and state_n["chunk_tokens"] == len(prompt)
+
+
+def test_chunks_alone_count_no_step_of_the_batch_form():
+    """``decode_expert_steps`` is the decodes': a request that ends with its
+    prompt's last chunk has made none, whatever form its chunks took."""
+    eng = LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+    eng.generate(_prompt(41, 19), SamplingParams(max_tokens=1))
+    moe_n = eng.stats()["moe"]
+    assert moe_n["chunks"] == 3 and moe_n["chunk_pairs"] > 0
+    assert moe_n["decodes"] == moe_n["decode_touched"] == moe_n["decode_expert_steps"] == 0
 
 
 @pytest.mark.parametrize("knob,why", [

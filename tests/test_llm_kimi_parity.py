@@ -287,14 +287,14 @@ def _layer(seed=5, n=21, d=16, f=8, experts=16):
         bias=jnp.zeros(experts))
 
 
-def _share(lay, offset, held, top_k=4, tile=64, bias=None):
+def _share(lay, offset, held, top_k=4, tile=64, bias=None, impl="auto"):
     """The routed part one chip holding ``held`` experts from ``offset`` adds."""
     chosen, weights = moe.route(lay["x"], lay["router"], lay["bias"] if bias is None else bias,
                                 top_k, 2.5)
     mask, wmat = moe.held_pairs(chosen, weights, offset, held, jnp.ones(lay["x"].shape[0], bool))
     cut = lambda k: lay[k][offset:offset + held]  # noqa: E731
     return moe.expert_layer(lay["x"], mask, wmat, cut("gate"), cut("up"), cut("down"),
-                            tile=tile), mask
+                            tile=tile, impl=impl), mask
 
 
 def _uncut(lay, top_k=4, bias=None):
@@ -309,11 +309,14 @@ def _uncut(lay, top_k=4, bias=None):
     return out
 
 
-@pytest.mark.parametrize("shares,tile", [(4, 64), (2, 4), (16, 3)])
-def test_the_shares_add_up_to_the_uncut_layer(shares, tile):
+@pytest.mark.parametrize("shares,tile,impl", [
+    # 21 rows: no more than a tile of 64 (the batch form, plain and as the
+    # kernel in interpret mode), more than one of 4 or 3 (the tile loop)
+    (4, 64, "xla"), (4, 64, "pallas"), (16, 64, "pallas"), (2, 4, "auto"), (16, 3, "auto")])
+def test_the_shares_add_up_to_the_uncut_layer(shares, tile, impl):
     lay = _layer()
     held = 16 // shares
-    total = sum(_share(lay, s * held, held, tile=tile)[0] for s in range(shares))
+    total = sum(_share(lay, s * held, held, tile=tile, impl=impl)[0] for s in range(shares))
     assert np.abs(np.asarray(total) - np.asarray(_uncut(lay))).max() < 1e-5
 
 
@@ -345,11 +348,11 @@ def test_the_model_s_shares_and_the_shared_expert_once_equal_the_uncut_reference
     assert int(counts[0]) == int(counts[len(COUNTERS):].sum()) > 0
 
 
-@pytest.mark.parametrize("tile", [64, 4])
-def test_no_pair_is_dropped_when_every_row_chooses_one_expert(tile):
+@pytest.mark.parametrize("tile,impl", [(64, "xla"), (64, "pallas"), (4, "auto")])
+def test_no_pair_is_dropped_when_every_row_chooses_one_expert(tile, impl):
     lay = _layer()
     bias = jnp.zeros(16).at[jnp.array([5, 1, 2, 3])].set(10.0)   # every row: 5 and three others
-    out, mask = _share(lay, 4, 4, tile=tile, bias=bias)
+    out, mask = _share(lay, 4, 4, tile=tile, bias=bias, impl=impl)
     assert mask[:, 1].all() and int(mask.sum()) == 21               # expert 5 alone is held
     assert np.abs(np.asarray(out) - np.asarray(
         sum(_share(lay, e, 1, bias=bias)[0] for e in range(4, 8)))).max() < 1e-5
@@ -471,7 +474,19 @@ def test_stats_moe_counts_what_a_hand_count_gives():
     assert moe_n["decode_pairs"] == fed_by_decodes.sum()
     # a decode of ONE live row touches as many held experts as it has pairs
     assert moe_n["decode_touched"] == moe_n["decode_pairs"]
+    # and each went through the batch form: the slots are no more than a tile
+    assert moe_n["decode_expert_steps"] == moe_n["decode_touched"] > 0
     assert moe_n["load"] == [int(x) for x in held.sum(axis=(0, 1))]
     assert pool_n["decode_rows"] == n_out - 1
     assert pool_n["decode_tokens"] == sum(range(len(prompt) + 1, len(prompt) + n_out))
     assert pool_n["block_tokens"] == BLOCK and pool_n["blocks"] == SLOTS * TABLE
+
+
+def test_chunks_alone_count_no_step_of_the_batch_form():
+    """``decode_expert_steps`` is the decodes': a request that ends with its
+    prompt's last chunk has made none."""
+    eng = _engine()
+    eng.generate(_prompt(41, 19), SamplingParams(max_tokens=1))
+    moe_n = eng.stats()["moe"]
+    assert moe_n["chunks"] == 3 and moe_n["chunk_pairs"] > 0
+    assert moe_n["decodes"] == moe_n["decode_touched"] == moe_n["decode_expert_steps"] == 0

@@ -236,15 +236,22 @@ def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 700 * 2**20
 
 
+@pytest.mark.parametrize("rows", [16, 512], ids=["decode", "chunk"])
 @pytest.mark.parametrize("d,f,held,layers", [
     (7168, 2048, 12, 6),    # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
     (4096, 768, 36, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
 ], ids=["kimi", "granite_h"])
-def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip, d, f, held, layers):
-    """The tile loop indexes the experts of every layer where they lie: a
-    decode's expert layer at the published widths holds no temporary the size
-    of an expert, let alone of a layer's held ones."""
+def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
+        one_chip, monkeypatch, d, f, held, layers, rows):
+    """Both forms index the experts of every layer where they lie: the expert
+    layer at the published widths holds no temporary the size of an expert,
+    let alone of a layer's held ones.  A decode's 16 rows go through the batch
+    form's ONE kernel, its weight blocks inside the module's VMEM budget (the
+    compiler refuses a kernel past its ``vmem_limit_bytes``); a chunk's 512
+    through the tile loop, which has no kernel."""
     from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -254,10 +261,20 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(one_chip, d, f, 
 
     n = held * layers
     compiled = jax.jit(layer).lower(
-        sds((16, d), jnp.bfloat16), sds((16, held), jnp.bool_), sds((16, held), jnp.float32),
-        sds((n, d, f), jnp.bfloat16), sds((n, d, f), jnp.bfloat16),
-        sds((n, f, d), jnp.bfloat16), sds((), jnp.int32)).compile()
+        sds((rows, d), jnp.bfloat16), sds((rows, held), jnp.bool_),
+        sds((rows, held), jnp.float32), sds((n, d, f), jnp.bfloat16),
+        sds((n, d, f), jnp.bfloat16), sds((n, f, d), jnp.bfloat16), sds((), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    text = compiled.as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if rows > moe.TILE:
+        assert kernels == 0
+        return
+    assert kernels == 1 and "moe_batch_experts" in text
+    # granite's expert goes whole, kimi's in quarters of f; two buffers of the
+    # three blocks are what the budget is stated for
+    bf = moe.block_f(d, f, 2)
+    assert bf == {768: 768, 2048: 512}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET < 128 * 2**20
 
 
 @pytest.mark.parametrize("h,p,n,slots,hb", [
