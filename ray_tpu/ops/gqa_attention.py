@@ -6,7 +6,11 @@ key-value heads, query head ``i`` reads key-value head ``i // (H / K)``.
   axis, all at one position, as ``ops.diff_attention`` does it:
   ``paged_verify_attention`` with ``w = H / K`` is grouped-query attention
   with no kernel of its own, and a cached head is read once for its whole
-  group.
+  group.  Heads NARROWER than a lane row (64-wide: LFM2) lie ``pack`` side by
+  side in a row of the pool, ``(blocks, K / pack, block, pack * e)``: a
+  token's K is the same bytes in the same order, unpadded, where a pool
+  padded to the kernel's 128-lane rows would hold twice the bytes.  Both
+  functions read the packing off the pool's shape.
 * ``gqa_chunk_attention`` — a prefill chunk's queries over ONE sequence's
   cache, the chunk's own keys already written.  It walks the block table in
   RUNS of ``run_blocks`` blocks with a running maximum and sum (the online
@@ -44,23 +48,47 @@ def rotary_half(x, positions, theta: float):
 def gqa_paged_attention(q, k_pool, v_pool, tables, positions, impl: str = "auto"):
     """q: (rows, H, e) in the pools' dtype; pools: (blocks, K, block, e);
     tables: (rows, tmax) int32; positions: (rows,) int32, the query's position
-    (its own k/v already written).  Returns (rows, H, e) in q's dtype."""
+    (its own k/v already written).  Returns (rows, H, e) in q's dtype.
+
+    Pools of ``pack`` heads a row, (blocks, K / pack, block, pack * e): ``q``
+    may be any float dtype (it is rounded HERE, once) and the result is in
+    the pools'.  A query head is laid into ITS head's ``e`` lanes of a ``pack * e`` row with
+    zeros in the others, so ``q . [k_a | k_b] = q . k_a`` exactly; the kernel
+    has the ``pack * H / K`` query heads of a packed row on its window axis and
+    scales by ``(pack * e) ** -0.5``, so ``q`` is multiplied by ``sqrt(pack)``
+    as it is rounded; ``p . [v_a | v_b]`` has the head's result in its own
+    lanes and the neighbour's in the others, which are dropped.  The cached
+    bytes are read once, as before; the kernel multiplies ``pack`` times the
+    lanes."""
     rows, h, e = q.shape
-    kv = k_pool.shape[1]
-    grouped = q.reshape(rows, kv, h // kv, e).transpose(0, 2, 1, 3)   # (rows, w, K, e)
+    kv, pack = k_pool.shape[1], k_pool.shape[3] // e
+    group = h // (kv * pack)
+    if pack == 1:
+        grouped = q.reshape(rows, kv, h // kv, e).transpose(0, 2, 1, 3)   # (rows, w, K, e)
+    else:
+        # (rows, K / pack, head of the row, query of the head, lanes' head, e)
+        own = jnp.eye(pack, dtype=jnp.float32)[None, None, :, None, :, None]
+        laid = (q.astype(jnp.float32) * pack**0.5).reshape(rows, kv, pack, group, 1, e) * own
+        grouped = laid.astype(k_pool.dtype).reshape(
+            rows, kv, pack * group, pack * e).transpose(0, 2, 1, 3)
     pos = jnp.broadcast_to(positions[:, None], grouped.shape[:2])
     att = paged_verify_attention(grouped, k_pool, v_pool, tables, pos, impl=impl)
-    return att.transpose(0, 2, 1, 3).reshape(rows, h, e)
+    if pack == 1:
+        return att.transpose(0, 2, 1, 3).reshape(rows, h, e)
+    att = att.transpose(0, 2, 1, 3).reshape(rows, kv, pack, group, pack, e)
+    return jnp.stack([att[:, :, j, :, j] for j in range(pack)], axis=2).reshape(rows, h, e)
 
 
 def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx):
     """q: (C, H, e) in the pools' dtype, at ``positions`` (C,) of ONE
-    sequence; pools: (blocks, K, block, e); table: (tmax,) int32; ``n_ctx``:
+    sequence; pools: (blocks, K, block, e), or ``pack`` heads a row (above);
+    table: (tmax,) int32; ``n_ctx``:
     how many positions of the sequence are written (the chunk's last valid
     one, plus one).  Query ``c`` attends every position ``<= positions[c]``.
     Returns (C, H, e) float32."""
     c, h, e = q.shape
-    kv, bs = k_pool.shape[1], k_pool.shape[2]
+    pack = k_pool.shape[3] // e
+    kv, bs = k_pool.shape[1] * pack, k_pool.shape[2]
     run = max(1, min(table.shape[0], _RUN_TOKENS // bs))
     span = run * bs
     # the table padded to whole runs (with the trash block, never attended)
@@ -70,7 +98,10 @@ def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx):
     reach = jnp.tile(positions, h // kv)[None, :, None]               # (1, G * C, 1)
 
     def tokens_of(pool, ids):
-        return pool[ids].transpose(1, 0, 2, 3).reshape(kv, span, e)
+        if pack == 1:
+            return pool[ids].transpose(1, 0, 2, 3).reshape(kv, span, e)
+        return pool[ids].reshape(run, kv // pack, bs, pack, e).transpose(
+            1, 3, 0, 2, 4).reshape(kv, span, e)
 
     def over_runs(r, carry):
         m_prev, l_prev, acc = carry

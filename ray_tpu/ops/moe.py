@@ -1,5 +1,8 @@
 """A DROPLESS expert layer over the experts ONE chip holds of a layer that
-is spread over many (expert parallelism, this chip's share).
+is spread over many (expert parallelism, this chip's share: 12 of 384 held,
+Kimi-K2.5; 36 of 72, Granite-4.0-H), or ALL of it (``offset`` 0, ``held`` the
+router's whole width: LFM2's 64 of 64, where nothing is left out and the
+"share" below is the layer).
 
 The router scores every token over ALL of the layer's experts and chooses
 ``top_k`` of them (``route``: sigmoid scores and a selection bias;
@@ -21,6 +24,10 @@ name, no option:
   LOOP: the pairs of one expert go through its weights in tiles of ``tile``
   rows, gathered by a sort of the mask and scattered back, and the loop over
   tiles is as long as the load says (a ``fori_loop`` with a traced bound).
+  Where a chip holds the whole layer the pairs spread thinner: 512 rows x 4
+  of LFM2's 64 experts are 32 an expert, ONE tile of 64 rows HALF full
+  (``tile_rows`` over a chunk's load: ``stats()["moe"]["chunk_tile_rows"]``),
+  and every expert is touched, so a chunk reads the layer whole.
 * ``n <= tile`` (every decode: 16 rows), the BATCH FORM: no tile is made.  A
   touched expert sees ALL ``n`` rows and the router's weight column does the
   selecting, ``out = sum over touched e, in expert order, of where(mask[:,
@@ -40,7 +47,10 @@ name, no option:
   ``i`` multiplies, which a loop of three XLA dots on dynamically indexed
   operands does not do: with 36 experts of 18.9 MB a layer (Granite-4.0-H)
   a decode IS these reads, 37 us a touched expert through the tile loop
-  where the bytes are 23 (PR 59), 25 through the kernel (PR 60).  Elsewhere (``impl="xla"``, and ``auto`` off a TPU) the same form is a
+  where the bytes are 23 (PR 59), 25 through the kernel (PR 60).  The
+  compacted list is what keeps the static grid honest where 16 rows x 4 touch
+  41 of 64 held experts (LFM2): a third of the grid's steps fetch nothing.
+  Elsewhere (``impl="xla"``, and ``auto`` off a TPU) the same form is a
   ``fori_loop`` of plain ``jax.numpy``.
 
 The kernel's weight blocks are cut along ``f`` alone (``block_f``): the
@@ -67,11 +77,12 @@ TILE = 64
 VMEM_BUDGET = 64 << 20
 
 
-def route(x32, router_kernel, select_bias, top_k: int, scaling: float):
+def route(x32, router_kernel, select_bias, top_k: int, scaling: float, eps: float = 1e-20):
     """Sigmoid scores over every expert, ``top_k`` chosen by ``score +
     select_bias`` (the bias chooses and does not weigh), weights the chosen
-    scores normalised to sum 1, times ``scaling``.  All float32, the product
-    at ``highest`` precision: the choice is discontinuous in the scores and
+    scores over their sum plus ``eps`` (1e-20 is DeepSeek-V3's and
+    Kimi-K2.5's; LFM2 publishes 1e-6), times ``scaling``.  All float32, the
+    product at ``highest`` precision: the choice is discontinuous in the scores and
     the 8th and 9th lie about 0.05 apart.  x32: (N, d) float32.  Returns
     (chosen (N, top_k) int32, weights (N, top_k) float32)."""
     z = jnp.dot(x32, router_kernel.astype(jnp.float32),
@@ -79,7 +90,7 @@ def route(x32, router_kernel, select_bias, top_k: int, scaling: float):
     p = jax.nn.sigmoid(z)
     _, chosen = jax.lax.top_k(p + select_bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(p, chosen, axis=-1)
-    return chosen, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
+    return chosen, picked / (picked.sum(-1, keepdims=True) + eps) * scaling
 
 
 def route_logits(x32, router_kernel, top_k: int):

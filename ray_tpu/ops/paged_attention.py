@@ -60,7 +60,13 @@ interchangeable paths behind one signature (same contract as
 
 ``auto`` follows ONE rule, ``auto_impl``: the Pallas kernel on a TPU
 backend when the pool tiles (block_size a multiple of 8, head_dim of
-128), else XLA.
+128), else XLA.  The rule reads the POOL's last axis, not the model's head:
+a family whose heads are narrower than a lane row (64-wide: LFM2) keeps its
+K/V unpadded by laying two key-value heads side by side in one 128-lane pool
+row and their query heads on the window axis, each in its own half of the
+lanes (``ops.gqa_attention.gqa_paged_attention``), and this kernel, which
+knows nothing of it, runs as it is at ``head_dim`` 128; a pool given to it at
+64 lanes would take the gathering XLA path.
 
 Convention: table entries past a sequence's allocation MUST point at a
 valid physical block (the engine pads with block 0, its reserved trash

@@ -82,6 +82,7 @@ _FAMILIES = {
                   "FalconH1Config"),
     "granite_h": ("ray_tpu.models.granite_h", "GraniteHConfig", "granite_h_init",
                   "GraniteHConfig"),
+    "lfm2_moe": ("ray_tpu.models.lfm2", "Lfm2MoeConfig", "lfm2_moe_init", "Lfm2MoeConfig"),
 }
 
 

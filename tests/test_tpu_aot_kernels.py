@@ -240,7 +240,8 @@ def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
 @pytest.mark.parametrize("d,f,held,layers", [
     (7168, 2048, 12, 6),    # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
     (4096, 768, 36, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
-], ids=["kimi", "granite_h"])
+    (2048, 1536, 64, 8),    # lfm2-24b-a2b-l10-1chip: 512 experts of 18.9 MB, a layer's WHOLE
+], ids=["kimi", "granite_h", "lfm2_moe"])
 def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
         one_chip, monkeypatch, d, f, held, layers, rows):
     """Both forms index the experts of every layer where they lie: the expert
@@ -274,7 +275,7 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
     # granite's expert goes whole, kimi's in quarters of f; two buffers of the
     # three blocks are what the budget is stated for
     bf = moe.block_f(d, f, 2)
-    assert bf == {768: 768, 2048: 512}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET < 128 * 2**20
+    assert bf == {768: 768, 2048: 512, 1536: 1536}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET < 128 * 2**20
 
 
 @pytest.mark.parametrize("h,p,n,slots,hb", [
@@ -304,14 +305,17 @@ def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(
     assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes < pool // 16
 
 
-@pytest.mark.parametrize("heads,kv,blocks,tmax", [
-    (20, 4, 8 * 1601, 100),  # falcon-h1-34b-l8-1chip: w = 5 over 8 layers of 1,601 blocks
-    (32, 8, 225, 14),        # granite-4.0-h-small-ep2-l10-1chip: w = 4 over ONE layer
-], ids=["falcon_h1", "granite_h"])
+@pytest.mark.parametrize("heads,kv,blocks,tmax,e", [
+    (20, 4, 8 * 1601, 100, 128),  # falcon-h1-34b-l8-1chip: w = 5 over 8 layers of 1,601 blocks
+    (32, 8, 225, 14, 128),        # granite-4.0-h-small-ep2-l10-1chip: w = 4 over ONE layer
+    # lfm2-24b-a2b-l10-1chip: 8 heads of 64 as 4 rows of 128 lanes, w = 8 over 2 layers
+    (32, 8, 2 * 1601, 100, 64),
+], ids=["falcon_h1", "granite_h", "lfm2_moe"])
 def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
-        one_chip, monkeypatch, heads, kv, blocks, tmax):
-    """The decode attention of the two grouped-query families: the query
-    heads of a key-value head of 128 ride the window axis over blocks of 128."""
+        one_chip, monkeypatch, heads, kv, blocks, tmax, e):
+    """The decode attention of the grouped-query families: the query heads of
+    a key-value head of 128 ride the window axis over blocks of 128; heads of
+    64 lie two a row, unpadded, and a row's 8 query heads ride it."""
     from ray_tpu.ops import gqa_attention as ga
 
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
@@ -319,10 +323,10 @@ def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((blocks, kv, 128, 128), jnp.bfloat16)
+    pool = sds((blocks, kv * e // 128, 128, 128), jnp.bfloat16)
     compiled = jax.jit(
         lambda q, k, v, t, p: ga.gqa_paged_attention(q, k, v, t, p, impl="pallas")
-    ).lower(sds((16, heads, 128), jnp.bfloat16), pool, pool,
+    ).lower(sds((16, heads, e), jnp.bfloat16 if e == 128 else jnp.float32), pool, pool,
             sds((16, tmax), jnp.int32), sds((16,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
