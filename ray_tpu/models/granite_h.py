@@ -417,7 +417,7 @@ class GraniteHBody:
         with jax.named_scope("moe_experts"):
             routed = expert_layer(y, mask, wmat, experts["gate"], experts["up"],
                                   experts["down"], first=index * cfg.experts_held,
-                                  impl=cfg.attn_impl)
+                                  top_k=cfg.experts_per_tok, impl=cfg.attn_impl)
         with jax.named_scope("moe_shared"):
             return h + cfg.residual_multiplier * (
                 routed + swiglu(y, sh["gate"], sh["up"], sh["down"])), counts

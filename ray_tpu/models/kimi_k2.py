@@ -372,7 +372,8 @@ class KimiK2Body:
         ex, sh = experts or layer["experts"], layer["shared"]
         with jax.named_scope("moe_experts"):
             routed = expert_layer(y, mask, wmat, ex["gate"], ex["up"], ex["down"],
-                                  first=index * cfg.experts_held, impl=cfg.attn_impl)
+                                  first=index * cfg.experts_held, top_k=cfg.experts_per_tok,
+                                  impl=cfg.attn_impl)
         with jax.named_scope("moe_shared"):
             return h + routed + swiglu(y, sh["gate"], sh["up"], sh["down"]), counts
 
@@ -381,7 +382,7 @@ class KimiK2Body:
         expert layers, whose blocks lie ``n_dense_layers`` pools further."""
         blocks = pool.shape[1]
         shift = self.cfg.n_dense_layers * blocks
-        # the experts stay OUT of the layer loop's slices: the tile loop
+        # the experts stay OUT of the layer loop's slices: the expert layer
         # indexes every layer's in one flat array (ops.moe.expert_layer)
         moe = {k: v for k, v in params["moe"].items() if k != "experts"}
         experts = {k: v.reshape((-1,) + v.shape[2:]) for k, v in params["moe"]["experts"].items()}

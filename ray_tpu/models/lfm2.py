@@ -41,7 +41,7 @@ published layers two dense conv layers, then attention, three conv, attention,
 three conv, all with experts); the experts of every EXPERT layer lie in one
 flat array from the first expert layer on.  ``counters`` is what the programs
 count on the device: Granite-4.0-H's seven names, and a chunk's touched
-experts and tile rows beside its pairs.
+experts, computed rows and grouped-form steps beside its pairs.
 """
 
 from __future__ import annotations
@@ -56,12 +56,14 @@ import numpy as np
 from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
 from ray_tpu.ops.gqa_attention import (
     gqa_chunk_attention, gqa_paged_attention, rotary_half)
-from ray_tpu.ops.moe import batch_steps, expert_layer, held_pairs, route, swiglu, tile_rows
+from ray_tpu.ops.moe import (
+    batch_steps, expert_layer, grouped_steps, held_pairs, route, swiglu, tile_rows)
 from ray_tpu.ops.short_conv import short_conv_chunk, short_conv_decode
 
 #: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
 COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
-            "decode_tile_rows", "decode_expert_steps", "chunk_touched", "chunk_tile_rows")
+            "decode_tile_rows", "decode_expert_steps", "chunk_touched", "chunk_tile_rows",
+            "chunk_expert_steps")
 #: key-value heads side by side in one row of the pool: two heads of 64 fill
 #: the 128 lanes the paged kernel's rows have
 KV_PACK = 2
@@ -363,8 +365,10 @@ class Lfm2MoeBody:
 
     def _expert_mlp(self, h, layer, live, counts, phase: str, experts, index):
         """The expert layer's part this chip holds.  ``counts`` gets this
-        layer's pairs, touched experts and tile rows under ``<phase>_*``, its
-        load by held expert and, in a decode, the steps the batch form made.
+        layer's pairs, touched experts, computed rows and expert steps under
+        ``<phase>_*`` (a decode's steps are the batch form's, a chunk's the
+        grouped form's: none where the other form ran) and its load by held
+        expert.
         ``experts``: the held experts of every expert layer, flat, this
         layer's from ``index * experts_held``."""
         cfg = self.cfg
@@ -379,13 +383,14 @@ class Lfm2MoeBody:
             for name, n in (("pairs", load.sum()), ("touched", (load > 0).sum()),
                             ("tile_rows", tile_rows(load, mask.shape[0]))):
                 counts = counts.at[COUNTERS.index(f"{phase}_{name}")].add(n.astype(jnp.int32))
-            if phase == "decode":
-                counts = counts.at[COUNTERS.index("decode_expert_steps")].add(
-                    batch_steps(load, mask.shape[0]))
+            steps = batch_steps if phase == "decode" else grouped_steps
+            counts = counts.at[COUNTERS.index(f"{phase}_expert_steps")].add(
+                steps(load, mask.shape[0]))
         with jax.named_scope("moe_experts"):
             return h + expert_layer(
                 y32.astype(self.dt), mask, wmat, experts["gate"], experts["up"], experts["down"],
-                first=index * cfg.experts_held, impl=cfg.attn_impl), counts
+                first=index * cfg.experts_held, top_k=cfg.experts_per_tok,
+                impl=cfg.attn_impl), counts
 
     def _layers(self, params, x, arrays, mixers: dict, live, phase: str):
         """One ``_carry_loop`` a run of layers of one (mixer, feed-forward)

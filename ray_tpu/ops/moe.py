@@ -16,64 +16,85 @@ No capacity and no drop: every (token, held expert) pair the router chose is
 computed, whatever the load's shape (``models.gpt._moe_mlp``, the training
 path's layer, drops what exceeds a fixed capacity and so matches no
 reference; this one does).  Shapes stay static all the same, and an expert
-no row chose is never read.  ``expert_layer`` has TWO forms, and the batch's
-rows ``n`` against ``tile`` choose between them -- the shape, no family's
+no row chose is never read.  Both of ``expert_layer``'s forms visit a
+compacted list of the touched experts, made once a layer from ``load > 0``
+(``touched``), and on a TPU each is ONE Pallas kernel a layer (the pattern of
+``ops.ssd``): the list is scalar prefetch, a weight block's index is ``first +
+ids[i]`` in the flat arrays of every layer's experts, the grid is the static
+``(experts held, blocks of f)``; a step past the list's end names the block
+before it (nothing is fetched) and computes nothing (a layer whose list is
+EMPTY still names one block of its first expert: the one read no row asked
+for).  Pallas' double buffering has expert ``i + 1``'s weights in flight
+while expert ``i`` multiplies, which a loop of XLA dots on dynamically
+indexed operands does not do: an expert of 18.9 MB is 23 us of reads, 37-45
+through such a loop and 25 through either kernel (PR 59, 60, 62).  An
+expert's weights are read ONCE a layer however many pairs it has.  The
+batch's rows ``n`` against ``tile`` choose the form -- the shape, no family's
 name, no option:
 
-* ``n > tile`` (a prefill chunk: 512 rows, 71 pairs an expert), the TILE
-  LOOP: the pairs of one expert go through its weights in tiles of ``tile``
-  rows, gathered by a sort of the mask and scattered back, and the loop over
-  tiles is as long as the load says (a ``fori_loop`` with a traced bound).
-  Where a chip holds the whole layer the pairs spread thinner: 512 rows x 4
-  of LFM2's 64 experts are 32 an expert, ONE tile of 64 rows HALF full
-  (``tile_rows`` over a chunk's load: ``stats()["moe"]["chunk_tile_rows"]``),
-  and every expert is touched, so a chunk reads the layer whole.
-* ``n <= tile`` (every decode: 16 rows), the BATCH FORM: no tile is made.  A
-  touched expert sees ALL ``n`` rows and the router's weight column does the
-  selecting, ``out = sum over touched e, in expert order, of where(mask[:,
-  e], wmat[:, e], 0)[:, None] * swiglu(x, W_e)``: no sort, no gather, no
-  scatter; ``x`` stays where it is and ``out`` is an accumulator that is
-  never indexed.  A row gets an exact ``+0.0`` from an expert it did not
-  choose, so its result does not depend on what the other rows chose.  The
-  experts to visit are a compacted list made once a layer from ``load > 0``
-  (``touched``).  On a TPU it is ONE Pallas kernel a layer
-  (``moe_batch_experts``, the pattern of ``ops.ssd``): the list is scalar
-  prefetch, a weight block's index is ``first + ids[i]`` in the flat arrays
-  of every layer's experts, the grid is the static ``(experts held, blocks
-  of f)``; a step past the list's end names the block before it (nothing is
-  fetched) and computes nothing (a layer whose list is EMPTY still names
-  one block of its first expert: the one read no row asked for).  Pallas'
-  double buffering has expert ``i + 1``'s weights in flight while expert
-  ``i`` multiplies, which a loop of three XLA dots on dynamically indexed
-  operands does not do: with 36 experts of 18.9 MB a layer (Granite-4.0-H)
-  a decode IS these reads, 37 us a touched expert through the tile loop
-  where the bytes are 23 (PR 59), 25 through the kernel (PR 60).  The
-  compacted list is what keeps the static grid honest where 16 rows x 4 touch
-  41 of 64 held experts (LFM2): a third of the grid's steps fetch nothing.
-  Elsewhere (``impl="xla"``, and ``auto`` off a TPU) the same form is a
-  ``fori_loop`` of plain ``jax.numpy``.
+* ``n <= tile`` (every decode: 16 rows), the BATCH FORM
+  (``moe_batch_experts``): a touched expert sees ALL ``n`` rows and the
+  router's weight column does the selecting, ``out = sum over touched e, in
+  expert order, of where(mask[:, e], wmat[:, e], 0)[:, None] * swiglu(x,
+  W_e)``: no ordering, no gather, no scatter; ``x`` stays where it is and
+  ``out`` is an accumulator that is never indexed.  A row gets an exact
+  ``+0.0`` from an expert it did not choose, so its result does not depend on
+  what the other rows chose.  The compacted list is what keeps the static grid
+  honest where 16 rows x 4 touch 41 of 64 held experts (LFM2): a third of the
+  grid's steps fetch nothing.
+* ``n > tile`` (a prefill chunk: 512 rows, 32 pairs an expert of LFM2's 64,
+  71 of Granite-4.0-H's 36), the GROUPED FORM (``moe_grouped_experts``): a
+  touched expert sees ITS pairs, all of them, in one grid step.  The pairs
+  are put in expert order ONCE a layer (``expert_order``: running counts and
+  comparisons, no sort, no scatter; the static bound is ``n x min(top_k,
+  held)`` places, so ``top_k`` is a shape fact the callers pass) and the
+  order is scalar prefetch too: every row of the batch lies in VMEM (float32,
+  copied in once), the step copies its expert's rows out of it by token, row
+  by row, into blocks of ``row_block(n)`` rows (the MXU's 128; as many blocks
+  as the expert has pairs: a traced bound), runs ``swiglu`` on each block,
+  and adds each result row, times the router's weight, to ITS row of an (n,
+  d) float32 accumulator that lies in VMEM through the whole grid and leaves
+  it once.  So the un-sort is the kernel's own: nothing in expert order is
+  ever written to HBM, and since experts come in order a row's sum is added
+  in expert order, the batch form's sum and (at whole ``f``) bit for bit
+  what a tile loop of XLA dots gave (PR 62, on the chip).
 
-The kernel's weight blocks are cut along ``f`` alone (``block_f``): the
+Elsewhere (``impl="xla"``, and ``auto`` off a TPU) each form is a
+``fori_loop`` of plain ``jax.numpy`` over the same list (the grouped one
+gathers a block's rows and scatter-adds its results: what the tile loop of
+PR 45-61 did a tile of 64, without its sort and its search).
+
+The kernels' weight blocks are cut along ``f`` alone (``block_f``): the
 widest multiple of 128 lanes that divides ``f`` and keeps two buffers of the
 three matrices' blocks inside ``VMEM_BUDGET``.  An expert of 3 x 4096 x 768
 bfloat16 (18.9 MB) goes whole, every product one dot and every read
 contiguous; one of 3 x 7168 x 2048 (88 MB, Kimi-K2.5) goes in blocks of
-``f``, the down product's partial sums added in float32.
+``f``, and a row's partial sums of the down product are added to the
+accumulator block by block, in float32, in both forms.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.paged_attention import _on_tpu
 
-#: rows a tile of ``expert_layer`` holds; a batch of no more rows makes no
-#: tile at all (the batch form)
+#: the rows that choose ``expert_layer``'s form: a batch of no more takes the
+#: batch form (every touched expert sees all of them), a larger one the
+#: grouped form (every touched expert sees its own pairs)
 TILE = 64
-#: VMEM the batch kernel's weight blocks may take: two buffers of the three
-#: matrices' blocks (a v5e has 128 MiB; ``x``, the accumulator and the
-#: products' results are under 2 MB beside them)
+#: rows of an expert's pairs one product of the grouped form takes: the MXU's
+#: height (on a v5e blocks of 32, 64 and 128 rows read alike, PR 62: a touched
+#: expert's step is its weights' reads; a taller block only adds padding)
+BLOCK_ROWS = 128
+#: VMEM the kernels' weight blocks may take: two buffers of the three
+#: matrices' blocks (a v5e has 128 MiB; the batch kernel's ``x`` and
+#: accumulator are under 2 MB beside them, the grouped kernel's rows,
+#: accumulator and one block of each 8 x (512 + 128) x d bytes: 37 MB at
+#: Kimi-K2.5's 7168)
 VMEM_BUDGET = 64 << 20
 
 
@@ -123,20 +144,33 @@ def swiglu(x, gate, up, down):
     return dot((jax.nn.silu(dot(x, gate)) * dot(x, up)).astype(x.dtype), down)
 
 
+def row_block(n: int) -> int:
+    """Rows of an expert's pairs one product of the grouped form takes: the
+    MXU's height, or all ``n`` rows (in whole sublane tiles) where a batch has
+    fewer."""
+    return min(BLOCK_ROWS, -(-n // 16) * 16)
+
+
 def tile_rows(load, n: int, tile: int = TILE):
     """The rows ``expert_layer`` computes for a ``load`` (pairs by held
-    expert) over ``n`` rows, in either form: every tile is whole, whatever it
-    holds, and the batch form gives a touched expert all ``n`` rows (what one
-    tile of ``min(tile, n)`` rows was)."""
-    tile = min(tile, n)
-    return ((load + (tile - 1)) // tile).sum().astype(jnp.int32) * tile
+    expert) over ``n`` rows, in the form that runs: the batch form gives a
+    touched expert all ``n`` rows, the grouped form its pairs in whole blocks
+    of ``row_block(n)``."""
+    rows = n if n <= tile else row_block(n)
+    return ((load + (rows - 1)) // rows).sum().astype(jnp.int32) * rows
 
 
 def batch_steps(load, n: int, tile: int = TILE):
     """The expert steps the BATCH FORM makes for a ``load`` over ``n`` rows:
-    one a touched expert where ``n <= tile``; none where the tile loop
+    one a touched expert where ``n <= tile``; none where the grouped form
     runs."""
     return (load > 0).sum().astype(jnp.int32) * int(n <= tile)
+
+
+def grouped_steps(load, n: int, tile: int = TILE):
+    """The expert steps the GROUPED FORM makes: one a touched expert where
+    ``n > tile``; none where the batch form runs."""
+    return (load > 0).sum().astype(jnp.int32) * int(n > tile)
 
 
 def touched(mask):
@@ -199,6 +233,29 @@ def _batch_kernel(ids_ref, meta_ref, x_ref, w_ref, gate_ref, up_ref, down_ref, o
         o_ref[...] += w_ref[0] * swiglu(x_ref[...], gate_ref[0], up_ref[0], down_ref[0])
 
 
+def _weight_specs(d: int, bf: int, steps: int):
+    """The block specs of an expert's three matrices over a grid of (listed
+    expert ``i``, block ``j`` of ``f``) whose first two scalar-prefetch
+    operands are the compacted list ``ids`` and ``meta = (count, first)``:
+    expert ``ids[i]`` of this layer; a step past the list's end names the
+    block the step before it held."""
+    from jax.experimental import pallas as pl
+
+    def place(i, j, ids, meta):
+        return meta[1] + ids[i], jnp.where(i < meta[0], j, steps - 1)
+
+    def columns(i, j, ids, meta, *_):
+        e, b = place(i, j, ids, meta)
+        return e, 0, b
+
+    def rows_of(i, j, ids, meta, *_):
+        e, b = place(i, j, ids, meta)
+        return e, b, 0
+
+    return [pl.BlockSpec((1, d, bf), columns), pl.BlockSpec((1, d, bf), columns),
+            pl.BlockSpec((1, bf, d), rows_of)]
+
+
 def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool,
                   bf: int | None = None):
     """The batch form as ONE kernel over the compacted list.  Rows are padded
@@ -209,34 +266,17 @@ def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool
     (n, d), experts, f = x.shape, wsel.shape[0], gate.shape[-1]
     size = gate.dtype.itemsize
     bf = bf or block_f(d, f, size)
-    steps = f // bf
     pad = -n % (32 // x.dtype.itemsize)
     x = jnp.pad(x, ((0, pad), (0, 0)))
     w = jnp.pad(wsel, ((0, 0), (0, pad)))[:, :, None]
     rows = n + pad
-
-    # expert ``ids[i]`` of this layer, block ``j`` of f; a step past the
-    # list's end names the block the step before it held
-    def place(i, j, ids, meta):
-        return meta[1] + ids[i], jnp.where(i < meta[0], j, steps - 1)
-
-    def columns(i, j, ids, meta):
-        e, b = place(i, j, ids, meta)
-        return e, 0, b
-
-    def rows_of(i, j, ids, meta):
-        e, b = place(i, j, ids, meta)
-        return e, b, 0
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(experts, steps),
+        grid=(experts, f // bf),
         in_specs=[
             pl.BlockSpec((rows, d), lambda i, j, ids, meta: (0, 0)),
             pl.BlockSpec((1, rows, 1), lambda i, j, ids, meta: (ids[i], 0, 0)),
-            pl.BlockSpec((1, d, bf), columns),
-            pl.BlockSpec((1, d, bf), columns),
-            pl.BlockSpec((1, bf, d), rows_of),
+            *_weight_specs(d, bf, f // bf),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i, j, ids, meta: (0, 0)),
     )
@@ -255,45 +295,185 @@ def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool
     return out[:n]
 
 
-def expert_layer(x, mask, wmat, gate, up, down, *, first=0, tile: int = TILE,
-                 impl: str = "auto"):
+def expert_order(mask, wmat, top_k: int | None):
+    """The pairs of ``mask`` (N, E) in EXPERT ORDER, made once a layer from
+    running counts and comparisons (no sort, no scatter): expert ``e``'s pairs
+    are the places ``starts[e] .. starts[e] + counts[e]``, in token order.
+    Returns ``(starts (E,), counts (E,), token (P,): the row a place's pair
+    belongs to, weight (P,): the router's for it)``, ``P = N x min(top_k, E)``
+    the static bound (no ``top_k``: a row may choose every expert); places
+    past the pairs hold row 0 at weight 0.  A row's pairs past ``top_k``
+    would have no place: it is the router's."""
+    n, experts = mask.shape
+    k = min(top_k or experts, experts)
+    hits = mask.astype(jnp.int32)
+    counts = hits.sum(axis=0)
+    starts = jnp.cumsum(counts) - counts
+    place = starts[None, :] + jnp.cumsum(hits, axis=0) - 1            # (N, E)
+    # a row's pairs counted along the experts: its j-th is where that count is
+    # j, which brings (N, E) down to the (N, k) that can hold a pair
+    pick = mask[:, :, None] & ((jnp.cumsum(hits, axis=1) - 1)[:, :, None]
+                               == jnp.arange(k, dtype=jnp.int32))     # (N, E, k)
+    # (N k,): where each of a row's pairs lies, -1 where it has no j-th
+    place = (jnp.where(pick, place[:, :, None] + 1, 0).sum(axis=1) - 1).reshape(-1)
+    weight = jnp.where(pick, wmat[:, :, None], 0.0).sum(axis=1).reshape(-1)
+    # the inverse: place p holds the pair that lies at p
+    at = jnp.arange(n * k, dtype=jnp.int32)
+    here = place[None, :] == at[:, None]
+    token = jnp.where(here, (at // k)[None, :], 0).sum(axis=1)
+    return starts, counts, token, jnp.where(here, weight[None, :], 0.0).sum(axis=1)
+
+
+def _grouped_xla(x, ids, count, starts, counts, token, weight, gate, up, down, first):
+    """The grouped form in plain ``jax.numpy``: a touched expert's pairs go
+    through its weights a block of rows at a time, gathered by ``token`` and
+    added back to their rows (a block's places past the expert's pairs are
+    dropped)."""
+    n, rb = x.shape[0], row_block(x.shape[0])
+    at = lambda k, e: jax.lax.dynamic_index_in_dim(  # noqa: E731
+        k, first + e, 0, keepdims=False)
+    token, weight = jnp.pad(token, (0, rb)), jnp.pad(weight, (0, rb))
+
+    def one_expert(i, out):
+        e = ids[i]
+        weights = at(gate, e), at(up, e), at(down, e)
+
+        def one_block(b, out):
+            row0 = starts[e] + b * rb
+            valid = b * rb + jnp.arange(rb, dtype=jnp.int32) < counts[e]
+            rows = jax.lax.dynamic_slice_in_dim(token, row0, rb)
+            w = jax.lax.dynamic_slice_in_dim(weight, row0, rb)
+            y = swiglu(x[rows], *weights)
+            return out.at[jnp.where(valid, rows, n)].add(y * w[:, None], mode="drop")
+
+        return jax.lax.fori_loop(0, (counts[e] + (rb - 1)) // rb, one_block, out)
+
+    return jax.lax.fori_loop(0, count, one_expert, jnp.zeros(x.shape, jnp.float32))
+
+
+def _grouped_kernel(ids_ref, meta_ref, start_ref, count_ref, token_ref, weight_ref,
+                    x_hbm, gate_ref, up_ref, down_ref, o_hbm, x_all, out, x_blk, y_blk, sem,
+                    *, dtype):
+    """One (touched expert, block of ``f``).  ``x_all`` (N, d) float32, every
+    row of the batch, comes into VMEM once and ``out`` (N, d) float32, the
+    accumulator, leaves it once: neither is double-buffered.  The expert's
+    pairs go through the weight blocks ``x_blk``'s rows at a time (a traced
+    bound: as many blocks as it has pairs): their rows are copied out of
+    ``x_all`` by ``token``, row by row, ``swiglu`` runs on the block in the products'
+    ``dtype``, and each result row is added to ITS row of ``out`` times the
+    router's weight -- experts come in order and a block of ``f`` after its
+    predecessor, so a row's sum is the batch kernel's.  Rows of a block past
+    the pairs hold what the block held before; theirs are results nobody
+    takes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i, j, rb = pl.program_id(0), pl.program_id(1), x_blk.shape[0]
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        copy = pltpu.make_async_copy(x_hbm, x_all, sem.at[0])
+        copy.start()
+        out[...] = jnp.zeros(out.shape, out.dtype)
+        copy.wait()
+
+    @pl.when(i < meta_ref[0])
+    def _():
+        e = ids_ref[i]
+        start, count = start_ref[e], count_ref[e]
+
+        def block(b, carry):
+            row0 = start + b * rb
+            rows = jnp.minimum(rb, count - b * rb)
+
+            def take(r, carry):
+                x_blk[pl.ds(r, 1), :] = x_all[pl.ds(token_ref[row0 + r], 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, rows, take, 0)
+            y_blk[...] = swiglu(x_blk[...].astype(dtype), gate_ref[0], up_ref[0], down_ref[0])
+
+            def give(r, carry):
+                to = pl.ds(token_ref[row0 + r], 1)
+                out[to, :] += weight_ref[row0 + r] * y_blk[pl.ds(r, 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, rows, give, 0)
+            return carry
+
+        jax.lax.fori_loop(0, (count + (rb - 1)) // rb, block, 0)
+
+    @pl.when((i == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+    def _():
+        copy = pltpu.make_async_copy(out, o_hbm, sem.at[0])
+        copy.start()
+        copy.wait()
+
+
+def _grouped_pallas(x, ids, count, starts, counts, token, weight, gate, up, down, first, *,
+                    interpret: bool, bf: int | None = None):
+    """The grouped form as ONE kernel over the compacted list: the weight
+    blocks are the batch kernel's (expert ``i + 1``'s in flight while expert
+    ``i`` multiplies, each read once however many pairs the expert has); the
+    ordering is scalar prefetch."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (n, d), experts, f = x.shape, ids.shape[0], gate.shape[-1]
+    size = gate.dtype.itemsize
+    bf = bf or block_f(d, f, size)
+    rows, rb = n + -n % 8, row_block(n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(experts, f // bf),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), *_weight_specs(d, bf, f // bf)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rb, d), jnp.float32),
+            pltpu.VMEM((rb, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, dtype=x.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the three matrices' blocks, double-buffered, the batch's rows
+            # and their sums, a block of rows and its results, and the rest
+            vmem_limit_bytes=2 * 3 * d * bf * size + 2 * (rows + rb) * d * 4 + (16 << 20),
+        ),
+        interpret=interpret,
+        name="moe_grouped_experts",
+    )(ids, jnp.stack([count, jnp.asarray(first, jnp.int32)]), starts, counts, token, weight,
+      jnp.pad(x.astype(jnp.float32), ((0, rows - n), (0, 0))), gate, up, down)
+    return out[:n]
+
+
+def expert_layer(x, mask, wmat, gate, up, down, *, first=0, top_k: int | None = None,
+                 tile: int = TILE, impl: str = "auto"):
     """``sum_e wmat[:, e] * Expert_e(x)`` over exactly the pairs in ``mask``.
     x: (N, d) in the products' dtype; mask, wmat: (N, E); gate, up: (.., d,
     f), down: (.., f, d): expert e's weights at ``first + e`` (``first`` may
     be traced: the experts of EVERY layer in one array, so that a layer
     loop hands this one no slice of them -- XLA would copy it, all of a
-    layer's experts a layer).  Returns (N, d) float32; experts are added in
-    order, so the sum does not depend on the load's shape.  ``N <= tile``
-    takes the batch form (``impl``: ``pallas`` the kernel, ``xla`` plain
-    ``jax.numpy``, ``auto`` the kernel on a TPU), ``N > tile`` the tile loop
-    whatever ``impl`` says."""
+    layer's experts a layer).  ``top_k``: the most pairs a row of ``mask``
+    has (the router's; a shape fact, the grouped form's bound on pairs; none
+    given, every held expert).  Returns (N, d) float32; a row's experts are
+    added in order, so the sum does not depend on the load's shape.  ``N <=
+    tile`` takes the batch form, ``N > tile`` the grouped form (``impl``:
+    ``pallas`` the form's kernel, ``xla`` plain ``jax.numpy``, ``auto`` the
+    kernel on a TPU)."""
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown expert impl {impl!r}; expected 'auto', 'xla' or 'pallas'")
-    n, experts = mask.shape
+    n = mask.shape[0]
+    plain = impl == "xla" or (impl == "auto" and not _on_tpu())
+    ids, count = touched(mask)
     if n <= tile:
-        ids, count = touched(mask)
         args = (x, jnp.where(mask, wmat, 0.0).T, ids, count, gate, up, down, first)
-        if impl == "xla" or (impl == "auto" and not _on_tpu()):
-            return _batch_xla(*args)
-        return _batch_pallas(*args, interpret=not _on_tpu())
-    counts = mask.sum(axis=0).astype(jnp.int32)                  # (E,)
-    # per expert, the tokens that chose it first, in token order; a tile's
-    # slice may run past N where N is no multiple of the tile
-    order = jnp.argsort(~mask, axis=0, stable=True).T.astype(jnp.int32)
-    order = jnp.pad(order, ((0, 0), (0, tile)))
-    tiles = (counts + (tile - 1)) // tile
-    ends = jnp.cumsum(tiles)
-
-    def one_tile(t, out):
-        e = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
-        row0 = (t - (ends[e] - tiles[e])) * tile
-        rows = jax.lax.dynamic_slice(order, (e, row0), (1, tile))[0]
-        valid = row0 + jnp.arange(tile, dtype=jnp.int32) < counts[e]
-        rows = jnp.where(valid, rows, 0)
-        at = lambda k: jax.lax.dynamic_index_in_dim(  # noqa: E731
-            k, first + e, 0, keepdims=False)
-        y = swiglu(x[rows], at(gate), at(up), at(down))
-        w = jnp.where(valid, wmat[rows, e], 0.0)
-        return out.at[jnp.where(valid, rows, n)].add(y * w[:, None], mode="drop")
-
-    return jax.lax.fori_loop(0, ends[-1], one_tile, jnp.zeros(x.shape, jnp.float32))
+        return _batch_xla(*args) if plain else _batch_pallas(*args, interpret=not _on_tpu())
+    args = (x, ids, count, *expert_order(mask, wmat, top_k), gate, up, down, first)
+    return _grouped_xla(*args) if plain else _grouped_pallas(*args, interpret=not _on_tpu())

@@ -217,7 +217,7 @@ def _share(lay, offset, held, top_k=4, tile=64):
     mask, wmat = moe.held_pairs(chosen, weights, offset, held, jnp.ones(lay["x"].shape[0], bool))
     cut = lambda k: lay[k][offset:offset + held]  # noqa: E731
     return moe.expert_layer(lay["x"], mask, wmat, cut("gate"), cut("up"), cut("down"),
-                            tile=tile), mask
+                            top_k=top_k, tile=tile), mask
 
 
 def test_the_router_is_a_softmax_over_the_chosen_logits():
@@ -311,10 +311,12 @@ def test_a_dead_row_has_no_pair():
 
 
 @pytest.mark.parametrize("load,n,rows", [
-    ([0, 0, 0], 16, 0),            # no pair, no tile
-    ([1, 0, 3], 16, 32),           # a tile is the batch's 16 rows, whatever it holds
+    ([0, 0, 0], 16, 0),            # no pair, no row
+    ([1, 0, 3], 16, 32),           # the batch form: a touched expert sees the batch's 16 rows
     ([16, 2, 0], 16, 32),
-    ([70, 1, 64], 512, 4 * 64),    # a chunk: tiles of 64, two for 70 pairs
+    ([70, 1, 64], 512, 3 * 128),   # a chunk, the grouped form: an expert's pairs in blocks of 128
+    ([130, 0, 256], 512, 4 * 128),  # two blocks for 130 pairs and for 256, none for none
+    ([70, 1, 64], 72, 3 * 80),     # fewer rows than a block of 128: all 72, in whole tiles of 16
 ])
 def test_the_tile_loops_rows_by_hand(load, n, rows):
     assert int(moe.tile_rows(jnp.asarray(load, jnp.int32), n)) == rows
@@ -327,11 +329,37 @@ def test_the_tile_loops_rows_by_hand(load, n, rows):
     ([0, 0, 0], 16, 0),            # no expert touched, no step
     ([1, 0, 3], 16, 2),            # a step a touched expert, whatever it holds
     ([16, 2, 1], moe.TILE, 3),     # a batch of a tile's rows is still the batch form
-    ([16, 2, 1], moe.TILE + 1, 0),  # one row more: the tile loop, which counts none
+    ([16, 2, 1], moe.TILE + 1, 0),  # one row more: the grouped form, which counts none here
     ([70, 1, 64], 512, 0),
 ])
 def test_the_batch_forms_steps_by_hand(load, n, steps):
     assert int(moe.batch_steps(jnp.asarray(load, jnp.int32), n)) == steps
+
+
+@pytest.mark.parametrize("load,n,steps", [
+    ([0, 0, 0], 512, 0),           # no expert touched, no step
+    ([70, 0, 300], 512, 2),        # ONE step a touched expert, however many blocks its pairs fill
+    ([16, 2, 1], moe.TILE + 1, 3),
+    ([16, 2, 1], moe.TILE, 0),     # a tile's rows: the batch form, which counts none here
+])
+def test_the_grouped_forms_steps_by_hand(load, n, steps):
+    assert int(moe.grouped_steps(jnp.asarray(load, jnp.int32), n)) == steps
+
+
+@pytest.mark.parametrize("top_k,places", [(2, 10), (4, 20), (None, 20), (9, 20)])
+def test_the_pairs_in_expert_order_by_hand(top_k, places):
+    """5 rows over 4 experts, two pairs a row at most: expert 0 has rows 1 and
+    4, expert 1 none, expert 2 rows 0, 1 and 3, expert 3 row 3; row 2 is dead.
+    The bound is rows x min(top_k, experts) places, and no sort makes them."""
+    mask = jnp.asarray([[0, 0, 1, 0], [1, 0, 1, 0], [0, 0, 0, 0], [0, 0, 1, 1], [1, 0, 0, 0]], bool)
+    wmat = jnp.where(mask, jnp.arange(20, dtype=jnp.float32).reshape(5, 4) + 1, 0.0)
+    starts, counts, token, weight = moe.expert_order(mask, wmat, top_k)
+    assert starts.tolist() == [0, 2, 2, 5] and counts.tolist() == [2, 0, 3, 1]
+    assert token.shape == weight.shape == (places,)
+    assert token[:6].tolist() == [1, 4, 0, 1, 3, 3] and not token[6:].any()
+    assert weight[:6].tolist() == [5.0, 17.0, 3.0, 7.0, 15.0, 16.0] and not weight[6:].any()
+    text = str(jax.make_jaxpr(lambda m, w: moe.expert_order(m, w, top_k))(mask, wmat))
+    assert "sort" not in text and "scatter" not in text
 
 
 @pytest.mark.parametrize("hit,ids,count", [
@@ -386,13 +414,13 @@ def _dense(lay, mask, wmat, first):
             for e in range(mask.shape[1]))
 
 
-def _through_the_tile_loop(lay, mask, wmat, first):
+def _through_the_grouped_form(lay, mask, wmat, first, impl):
     """The same rows with one dead row appended, so that they are one more
-    than the tile: the tile loop, whatever ``n`` is."""
+    than the tile: the grouped form, whatever ``n`` is."""
     n = mask.shape[0]
     pad = lambda a: jnp.concatenate([a, jnp.zeros_like(a[:1])])  # noqa: E731
     return moe.expert_layer(pad(lay["x"]), pad(mask), pad(wmat), lay["gate"], lay["up"],
-                            lay["down"], first=first, tile=n)[:n]
+                            lay["down"], first=first, tile=n, impl=impl)[:n]
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -400,15 +428,15 @@ def _through_the_tile_loop(lay, mask, wmat, first):
     ("every_row_one_expert", 16), ("no_row_any_expert", 16), ("a_dead_row", 16),
     ("scattered", 1), ("scattered", 5), ("scattered", 16),
 ])
-def test_the_batch_form_equals_the_tile_loop_and_the_uncut_sum(case, n, impl):
+def test_the_batch_form_equals_the_grouped_form_and_the_uncut_sum(case, n, impl):
     lay = _layer(n=n, experts=10)        # the 6 held experts lie at 3..8 of the flat array
     mask, wmat = _by_hand(case, n)
     got = moe.expert_layer(lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"],
                            first=3, impl=impl)
     assert got.shape == lay["x"].shape and got.dtype == jnp.float32
     assert np.abs(np.asarray(got) - np.asarray(_dense(lay, mask, wmat, 3))).max() < 1e-5
-    loop = _through_the_tile_loop(lay, mask, wmat, 3)
-    assert np.abs(np.asarray(got) - np.asarray(loop)).max() < 1e-5
+    grouped = _through_the_grouped_form(lay, mask, wmat, 3, impl)
+    assert np.abs(np.asarray(got) - np.asarray(grouped)).max() < 1e-5
     if case == "no_row_any_expert":
         assert not np.asarray(got).any()
     if case == "a_dead_row":
@@ -438,19 +466,86 @@ def test_the_kernel_in_interpret_mode_equals_the_plain_batch_form(d, f, bf, dtyp
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_a_rows_result_is_bit_equal_whatever_the_other_rows_chose(impl):
-    lay = _layer(n=16, experts=6)
+@pytest.mark.parametrize("n", [16, 70], ids=["batch_form", "grouped_form"])
+def test_a_rows_result_is_bit_equal_whatever_the_other_rows_chose(impl, n):
+    lay = _layer(n=n, experts=6)
     mine = jnp.asarray([False, True, False, True, True, False])
-    w = jnp.asarray(np.random.default_rng(1).uniform(0.1, 1.0, (16, 6)).astype(np.float32))
+    w = jnp.asarray(np.random.default_rng(1).uniform(0.1, 1.0, (n, 6)).astype(np.float32))
     outs = []
-    for others in ("none", "all", "some", "other_experts"):
-        mask = {"none": jnp.zeros((16, 6), bool), "all": jnp.ones((16, 6), bool),
-                "some": _by_hand("scattered", 16)[0],
-                "other_experts": jnp.broadcast_to(~mine, (16, 6))}[others].at[3].set(mine)
+    for others in ("none", "all", "some", "permuted", "other_experts"):
+        some = _by_hand("scattered", n)[0]
+        mask = {"none": jnp.zeros((n, 6), bool), "all": jnp.ones((n, 6), bool), "some": some,
+                "permuted": some[::-1],
+                "other_experts": jnp.broadcast_to(~mine, (n, 6))}[others].at[3].set(mine)
         out = moe.expert_layer(lay["x"], mask, jnp.where(mask, w, 0.0), lay["gate"], lay["up"],
                                lay["down"], impl=impl)
         outs.append(np.asarray(out[3]))
     assert outs[0].any() and all((o == outs[0]).all() for o in outs[1:])
+
+
+# -- the grouped form: more rows than a tile, every touched expert sees ITS pairs ---------
+
+
+def _grouped_case(case):
+    """(layer, mask, wmat, first, top_k): loads set by hand over 6 held
+    experts of a flat array of 18 (three layers' worth)."""
+    n = {"an_expert_takes_every_row": 150, "rows_no_multiple_of_the_block": 131,
+         "dead_rows_and_a_padded_tail": 80}.get(case, 70)
+    f = 48 if case == "f_in_blocks" else 8
+    lay = _layer(n=n, f=f, experts=18)
+    first, top_k = 6, None
+    rng = np.random.default_rng(11)
+    wmat = rng.uniform(0.1, 1.0, (n, 6)).astype(np.float32)
+    mask = rng.random((n, 6)) < 0.3
+    if case == "an_expert_with_no_pair":
+        mask[:, 2] = False
+    elif case == "an_expert_takes_every_row":       # two blocks: 128 rows and 22
+        mask[:, 4] = True
+    elif case == "rows_no_multiple_of_the_block":   # 131 rows, half of them an expert
+        mask = rng.random((n, 6)) < 0.5
+    elif case == "dead_rows_and_a_padded_tail":     # 61 valid rows of 80, every 7th dead
+        mask[(np.arange(n) % 7 == 0) | (np.arange(n) >= 61)] = False
+    elif case == "a_traced_first":                  # the third layer's experts
+        first = jnp.int32(12)
+    elif case == "top_k_is_held":                   # some rows choose all six
+        top_k = 6
+        mask[::3] = True
+    elif case == "top_k_of_the_router":             # three a row, as a router gives them
+        top_k = 3
+        mask = np.zeros((n, 6), bool)
+        np.put_along_axis(mask, rng.permuted(np.tile(np.arange(6), (n, 1)), axis=1)[:, :3], True, 1)
+    elif case == "no_row_any_expert":
+        mask[:] = False
+    else:
+        assert case in ("f_in_blocks", "bfloat16")
+    if case == "bfloat16":
+        lay = {k: v.astype(jnp.bfloat16) for k, v in lay.items()}
+    return lay, jnp.asarray(mask), jnp.asarray(np.where(mask, wmat, 0.0)), first, top_k
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("case", [
+    "an_expert_with_no_pair", "an_expert_takes_every_row", "rows_no_multiple_of_the_block",
+    "dead_rows_and_a_padded_tail", "a_traced_first", "f_in_blocks", "top_k_is_held",
+    "top_k_of_the_router", "no_row_any_expert", "bfloat16"])
+def test_the_grouped_form_equals_the_uncut_masked_sum(case, impl, monkeypatch):
+    """Every (row, held expert) pair of the mask and no other, whatever the
+    load's shape: against every held expert on every row, the weight 0 where
+    the row did not choose it.  The kernel runs under the interpreter."""
+    lay, mask, wmat, first, top_k = _grouped_case(case)
+    if case == "f_in_blocks":                       # three blocks of f a touched expert
+        monkeypatch.setattr(moe, "block_f", lambda d, f, size: 16)
+    layer = jax.jit(lambda x, m, w, g, u, dn, first: moe.expert_layer(
+        x, m, w, g, u, dn, first=first, top_k=top_k, impl=impl))
+    got = layer(lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"], first)
+    assert got.shape == lay["x"].shape and got.dtype == jnp.float32
+    want = np.asarray(_dense(lay, mask, wmat, int(first)))
+    assert np.abs(np.asarray(got) - want).max() < (2e-2 if case == "bfloat16" else 1e-5)
+    if case == "no_row_any_expert":
+        assert not np.asarray(got).any()
+    if case == "dead_rows_and_a_padded_tail":
+        assert not np.asarray(got[61:]).any() and not np.asarray(got[::7]).any()
+        assert np.asarray(got[1]).any()
 
 
 def _primitives(n, impl):
@@ -458,14 +553,17 @@ def _primitives(n, impl):
     mask, wmat = _by_hand("scattered", n)
     text = str(jax.make_jaxpr(lambda *a: moe.expert_layer(*a, impl=impl))(
         lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"]))
-    return {name for name in ("pallas_call", "sort", "scatter-add", "gather") if name in text}
+    # ``sort[``: the primitive, not a gather's ``indices_are_sorted``
+    return {name.strip("[") for name in ("pallas_call", "sort[", "scatter-add", "gather")
+            if name in text}
 
 
 def test_the_rows_choose_the_form_and_nothing_else_does():
-    # one row more than a tile: the tile loop, its sort, gather and scatter-add,
-    # and no kernel whatever ``impl`` says
-    for impl in ("auto", "xla", "pallas"):
-        assert _primitives(moe.TILE + 1, impl) == {"sort", "scatter-add", "gather"}
+    # one row more than a tile: the grouped form, which sorts nothing; its
+    # kernel gathers and scatters in VMEM, its plain rendering a block at a time
+    assert _primitives(moe.TILE + 1, "pallas") == {"pallas_call"}
+    assert _primitives(moe.TILE + 1, "xla") == {"scatter-add", "gather"}
+    assert _primitives(moe.TILE + 1, "auto") == {"scatter-add", "gather"}
     # a tile's rows: the batch form, which sorts, gathers and scatters nothing
     assert _primitives(moe.TILE, "pallas") == {"pallas_call"}
     assert _primitives(moe.TILE, "xla") == set() == _primitives(moe.TILE, "auto")

@@ -294,7 +294,7 @@ def _share(lay, offset, held, top_k=4, tile=64, bias=None, impl="auto"):
     mask, wmat = moe.held_pairs(chosen, weights, offset, held, jnp.ones(lay["x"].shape[0], bool))
     cut = lambda k: lay[k][offset:offset + held]  # noqa: E731
     return moe.expert_layer(lay["x"], mask, wmat, cut("gate"), cut("up"), cut("down"),
-                            tile=tile, impl=impl), mask
+                            top_k=top_k, tile=tile, impl=impl), mask
 
 
 def _uncut(lay, top_k=4, bias=None):
@@ -311,8 +311,9 @@ def _uncut(lay, top_k=4, bias=None):
 
 @pytest.mark.parametrize("shares,tile,impl", [
     # 21 rows: no more than a tile of 64 (the batch form, plain and as the
-    # kernel in interpret mode), more than one of 4 or 3 (the tile loop)
-    (4, 64, "xla"), (4, 64, "pallas"), (16, 64, "pallas"), (2, 4, "auto"), (16, 3, "auto")])
+    # kernel in interpret mode), more than one of 4 or 3 (the grouped form, alike)
+    (4, 64, "xla"), (4, 64, "pallas"), (16, 64, "pallas"), (2, 4, "auto"), (16, 3, "auto"),
+    (2, 4, "pallas"), (16, 3, "pallas")])
 def test_the_shares_add_up_to_the_uncut_layer(shares, tile, impl):
     lay = _layer()
     held = 16 // shares

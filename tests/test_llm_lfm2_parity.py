@@ -377,7 +377,8 @@ def _share(lay, offset, held, tile=64):
     chosen, weights = moe.route(lay["x"], lay["router"], lay["bias"], 4, 1.0, eps=1e-6)
     mask, wmat = moe.held_pairs(chosen, weights, offset, held, jnp.ones(lay["x"].shape[0], bool))
     cut = lambda k: lay[k][offset:offset + held]  # noqa: E731
-    return moe.expert_layer(lay["x"], mask, wmat, cut("gate"), cut("up"), cut("down"), tile=tile)
+    return moe.expert_layer(lay["x"], mask, wmat, cut("gate"), cut("up"), cut("down"),
+                            top_k=4, tile=tile)
 
 
 @pytest.mark.parametrize("shares,tile", [(1, 64), (2, 64), (4, 64), (2, 5), (4, 3)])
@@ -404,7 +405,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares, tile):
 def test_the_expert_layer_probe_holds_both_forms_to_the_reference_layer_by_layer(fault):
     """``families/lfm2_moe.py::expert_layer_deviation``, what the cell's
     reference step runs on the chip: the program's expert layer as a chunk (the
-    tile loop) and as a decode batch (the batch form) on the stream the
+    grouped form) and as a decode batch (the batch form) on the stream the
     reference has at each expert layer.  The programs pass; every pair through
     the NEXT expert's weights, or gate matrices 3% off, fail in every layer
     and both forms."""
@@ -588,8 +589,9 @@ def test_the_served_path_preempted_and_resumed_matches_the_reference():
         assert (logits.max(-1) - logits[np.arange(len(out)), out]).max() < TOL
 
 
-def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
-    eng = LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+@pytest.mark.parametrize("chunk", [CHUNK, 72], ids=["batch_form_chunks", "grouped_form_chunk"])
+def test_stats_moe_and_both_pools_count_what_a_hand_count_gives(chunk):
+    eng = LLMEngine(TINY, _params(), EngineConfig(**dict(ENGINE, prefill_chunk=chunk)))
     prompt, n_out = _prompt(40, 19), 9
     out = eng.generate(prompt, SamplingParams(max_tokens=n_out))
     got = eng.stats()
@@ -600,14 +602,26 @@ def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
     held = np.stack([np.asarray(m) for m in held])              # (expert layers, tokens, held)
     assert held.shape == (4, len(seq), 8)
     by_chunks, by_decodes = held[:, :len(prompt)], held[:, len(prompt):]
-    assert moe_n["chunks"] == 3 and moe_n["decodes"] == kv_n["decodes"] == n_out - 1
+    assert moe_n["chunks"] == -(-len(prompt) // chunk) and chunk % BLOCK == 0
+    assert moe_n["decodes"] == kv_n["decodes"] == n_out - 1
     assert moe_n["chunk_pairs"] == by_chunks.sum() == 4 * 19 * 3
     assert moe_n["decode_pairs"] == by_decodes.sum()
-    # a chunk's touched experts and tile rows, chunk by chunk and layer by layer
-    pieces = [by_chunks[:, a:a + CHUNK] for a in range(0, len(prompt), CHUNK)]
+    # a chunk's touched experts, computed rows and steps, chunk by chunk and
+    # layer by layer
+    pieces = [by_chunks[:, a:a + chunk] for a in range(0, len(prompt), chunk)]
     assert moe_n["chunk_touched"] == sum(int(p.any(axis=1).sum()) for p in pieces)
-    # 8 rows are no more than a tile: a touched expert sees the chunk's 8 rows
-    assert moe_n["chunk_tile_rows"] == moe_n["chunk_touched"] * CHUNK
+    if chunk <= moe.TILE:
+        # 8 rows are no more than a tile: the batch form, where a touched
+        # expert sees the chunk's 8 rows and the grouped form makes no step
+        assert moe_n["chunk_tile_rows"] == moe_n["chunk_touched"] * chunk
+        assert moe_n["chunk_expert_steps"] == 0
+    else:
+        # 72 rows, 19 of them the prompt's: the grouped form, ONE step a
+        # touched expert, its pairs in blocks of the 80 rows that hold 72
+        blocks = sum(int(np.ceil(p.sum(axis=1) / 80).sum()) for p in pieces)
+        assert moe.row_block(chunk) == 80 and blocks == moe_n["chunk_touched"]
+        assert moe_n["chunk_tile_rows"] == blocks * 80
+        assert moe_n["chunk_expert_steps"] == moe_n["chunk_touched"] > 0
     # a decode of ONE live row touches as many experts as it has pairs, and
     # each touched expert sees the batch's rows, all through the batch form
     assert moe_n["decode_touched"] == moe_n["decode_pairs"]
@@ -623,7 +637,7 @@ def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
     assert kv_n["bytes"] == eng.pool.kv.device_bytes  # TWO layers' K and V, unpadded
     assert kv_n["bytes"] == 2 * 2 * (SLOTS * TABLE + 1) * 4 * BLOCK * 8 * 4
     assert set(state_n["kinds"]) == {"tails"} and state_n["slots"] == SLOTS
-    assert state_n["chunks"] == 3 and state_n["chunk_tokens"] == len(prompt)
+    assert state_n["chunks"] == moe_n["chunks"] and state_n["chunk_tokens"] == len(prompt)
 
 
 @pytest.mark.parametrize("knob,why", [

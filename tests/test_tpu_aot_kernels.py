@@ -237,19 +237,24 @@ def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
 
 
 @pytest.mark.parametrize("rows", [16, 512], ids=["decode", "chunk"])
-@pytest.mark.parametrize("d,f,held,layers", [
-    (7168, 2048, 12, 6),    # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
-    (4096, 768, 36, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
-    (2048, 1536, 64, 8),    # lfm2-24b-a2b-l10-1chip: 512 experts of 18.9 MB, a layer's WHOLE
+@pytest.mark.parametrize("d,f,held,layers,top_k", [
+    (7168, 2048, 12, 6, 8),     # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
+    (4096, 768, 36, 10, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
+    (2048, 1536, 64, 8, 4),     # lfm2-24b-a2b-l10-1chip: 512 experts of 18.9 MB, a layer's WHOLE
 ], ids=["kimi", "granite_h", "lfm2_moe"])
 def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
-        one_chip, monkeypatch, d, f, held, layers, rows):
+        one_chip, monkeypatch, d, f, held, layers, top_k, rows):
     """Both forms index the experts of every layer where they lie: the expert
     layer at the published widths holds no temporary the size of an expert,
-    let alone of a layer's held ones.  A decode's 16 rows go through the batch
-    form's ONE kernel, its weight blocks inside the module's VMEM budget (the
-    compiler refuses a kernel past its ``vmem_limit_bytes``); a chunk's 512
-    through the tile loop, which has no kernel."""
+    let alone of a layer's held ones (the smallest layer here holds 679 MB).
+    A decode's 16 rows go through the batch form's ONE kernel, a chunk's 512
+    through the grouped form's ONE kernel (nothing in expert order is written
+    out beside it: the ordering is 512 x ``top_k`` integers and weights); the
+    weight blocks lie inside the module's VMEM budget, and what a kernel asks
+    for in all -- the compiler refuses one past its ``vmem_limit_bytes`` --
+    inside the 128 MiB a v5e has."""
+    import re
+
     from ray_tpu.ops import moe
 
     monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
@@ -258,24 +263,28 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def layer(x, mask, wmat, gate, up, down, first):
-        return moe.expert_layer(x, mask, wmat, gate, up, down, first=first)
+        return moe.expert_layer(x, mask, wmat, gate, up, down, first=first, top_k=top_k)
 
     n = held * layers
     compiled = jax.jit(layer).lower(
         sds((rows, d), jnp.bfloat16), sds((rows, held), jnp.bool_),
         sds((rows, held), jnp.float32), sds((n, d, f), jnp.bfloat16),
         sds((n, d, f), jnp.bfloat16), sds((n, f, d), jnp.bfloat16), sds((), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20 < 3 * d * f * 2
     text = compiled.as_text()
-    kernels = text.count('custom_call_target="tpu_custom_call"')
-    if rows > moe.TILE:
-        assert kernels == 0
-        return
-    assert kernels == 1 and "moe_batch_experts" in text
+    name = "moe_grouped_experts" if rows > moe.TILE else "moe_batch_experts"
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and name in calls[0]
     # granite's expert goes whole, kimi's in quarters of f; two buffers of the
     # three blocks are what the budget is stated for
     bf = moe.block_f(d, f, 2)
-    assert bf == {768: 768, 2048: 512, 1536: 1536}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET < 128 * 2**20
+    assert bf == {768: 768, 2048: 512, 1536: 1536}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET
+    # ... and all the kernel's VMEM (its scoped limit): the blocks, the rows
+    # and the accumulator (a chunk's: 512 x d float32 each) and the rest
+    limit = int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+                          r'"size":"(\d+)"', calls[0]).group(1))
+    assert 2 * 3 * d * bf * 2 < limit < 128 * 2**20
 
 
 @pytest.mark.parametrize("h,p,n,slots,hb", [
