@@ -27,6 +27,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.blocks import rmsnorm
 from ray_tpu.ops.power_retention import retention_chunk, retention_decode, state_dims
 
 
@@ -101,12 +102,6 @@ def brumby_init(rng: jax.Array, cfg: BrumbyConfig) -> dict:
     }
 
 
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    out = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (out * scale.astype(jnp.float32)).astype(x.dtype)
-
-
 def _rotary_half(x, positions, theta):
     """Half-split rotary over the whole head, per-row positions.  x: (n,
     heads, hd); positions: (n,) int32."""
@@ -129,14 +124,17 @@ class BrumbyBody:
         self.state_shape = (cfg.n_kv_heads,) + state_dims(cfg.head_dim)
         self.state_dtype = cfg.state_dtype
 
+    def _norm(self, x, scale):
+        """RMSNorm, handed on in x's dtype."""
+        return rmsnorm(x, scale, self.cfg.rms_eps).astype(x.dtype)
+
     def embed(self, params, tokens):
         with jax.named_scope("embed"):
             return params["embed"]["tokens"][tokens].astype(jnp.dtype(self.cfg.dtype))
 
     def lm_head(self, params, h):
-        cfg = self.cfg
         with jax.named_scope("lm_head"):
-            h = _rmsnorm(h, params["ln_f"]["scale"], cfg.rms_eps)
+            h = self._norm(h, params["ln_f"]["scale"])
             return jnp.dot(h, params["lm_head"]["kernel"].astype(h.dtype),
                            preferred_element_type=jnp.float32)
 
@@ -152,8 +150,8 @@ class BrumbyBody:
                         preferred_element_type=jnp.float32)
             log_g = jax.nn.log_sigmoid(z + cfg.gate_shift)
         with jax.named_scope("qk_norm"):
-            q = _rmsnorm(q, layer["q_norm"]["scale"], cfg.rms_eps)
-            k = _rmsnorm(k, layer["k_norm"]["scale"], cfg.rms_eps)
+            q = self._norm(q, layer["q_norm"]["scale"])
+            k = self._norm(k, layer["k_norm"]["scale"])
             q = _rotary_half(q, positions, cfg.rope_theta)
             k = _rotary_half(k, positions, cfg.rope_theta)
         return q, k, v, log_g
@@ -161,11 +159,11 @@ class BrumbyBody:
     def _finish(self, x, layer, att):
         """The residual adds after the retention: its output projection,
         then the SwiGLU MLP on the second norm."""
-        cfg, dt = self.cfg, x.dtype
+        dt = x.dtype
         with jax.named_scope("attn_out"):
             x = x + att.astype(dt).reshape(x.shape[0], -1) @ layer["attn_out"]["kernel"].astype(dt)
         with jax.named_scope("mlp"):
-            h = _rmsnorm(x, layer["ln2"]["scale"], cfg.rms_eps)
+            h = self._norm(x, layer["ln2"]["scale"])
             mid = jax.nn.silu(h @ layer["mlp_gate"]["kernel"].astype(dt)) * (
                 h @ layer["mlp_up"]["kernel"].astype(dt))
             return x + mid @ layer["mlp_down"]["kernel"].astype(dt)
@@ -174,7 +172,7 @@ class BrumbyBody:
         """One layer of a decode batch: row i updates and reads the state
         at ``base + slots[i]`` where ``live[i]``; a dead row touches none."""
         cfg = self.cfg
-        h = _rmsnorm(x, layer["ln1"]["scale"], cfg.rms_eps)
+        h = self._norm(x, layer["ln1"]["scale"])
         q, k, v, log_g = self._qkvg(layer, h, positions)
         with jax.named_scope("retention"):
             state, att = retention_decode(
@@ -187,7 +185,7 @@ class BrumbyBody:
         + slot``.  A chunk at position 0 OVERWRITES what the slot's last
         owner left; a later one reads the state, and both write it back."""
         cfg = self.cfg
-        h = _rmsnorm(x, layer["ln1"]["scale"], cfg.rms_eps)
+        h = self._norm(x, layer["ln1"]["scale"])
         q, k, v, log_g = self._qkvg(layer, h, positions)
         with jax.named_scope("retention_chunk"):
             at = base + slot

@@ -31,21 +31,25 @@ state: the SSD state ``(H, P, N)`` float32 and the convolution's last
 ``d_conv - 1`` inputs.  The configuration, the seeded initializer and the
 layer programs ``llm.state_runner.HybridModelRunner`` (which names no
 family) takes through ``serving_body()`` are all HERE: one ``_carry_loop``
-over the layers with the four pools as its carry.
+over the layers with the four pools as its carry.  The Mamba-2 mixer with its
+decode and chunk step and the paged K/V step are ``models.blocks``' (shared
+with Granite-4.0-H and LFM2); the muP vector, the multipliers and the
+parallel block are this family's.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
-from ray_tpu.ops.gqa_attention import gqa_chunk_attention, gqa_paged_attention, rotary_half
-from ray_tpu.ops.ssd import ssd_chunk, ssd_decode
+from ray_tpu.llm.model_runner import _carry_loop
+from ray_tpu.models.blocks import (
+    Mamba2, dot32, last_valid, normal_layers, paged_kv_chunk, paged_kv_decode, rmsnorm)
+from ray_tpu.ops.gqa_attention import rotary_half
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,6 +130,13 @@ class FalconH1Config:
         return np.concatenate([
             np.full(w, m, np.float32) for w, m in zip(self.ssm_segments(), self.ssm_multipliers)])
 
+    def mixer(self) -> Mamba2:
+        """The mixer (``models.blocks``), the muP vector over ``W_in``'s columns."""
+        return Mamba2(
+            d_ssm=self.d_ssm, heads=self.ssm_heads, d_state=self.d_state, n_groups=self.n_groups,
+            d_conv=self.d_conv, eps=self.rms_norm_eps, dtype=jnp.dtype(self.dtype),
+            sub=self.ssm_chunk, impl=self.attn_impl, in_scale=self.mup_vector())
+
     def serving_body(self) -> "FalconH1Body":
         return FalconH1Body(self)
 
@@ -150,17 +161,9 @@ def falcon_h1_init(rng: jax.Array, cfg: FalconH1Config) -> dict:
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
 
     def normal(key, shape: tuple, std, layers: int = n):
-        """(layers,) + shape, one layer at a time; ``std`` a number or a
-        vector over the last axis."""
-        std = jnp.asarray(std, jnp.float32)
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(dt),
-            jax.random.split(key, layers))
+        return normal_layers(key, layers, shape, std, dt)
 
     ks = jax.random.split(rng, 14)
-    in_std = d**-0.5 / (cfg.ssm_in_multiplier * cfg.mup_vector())
-    step = jnp.exp(jax.random.uniform(ks[0], (n, cfg.ssm_heads)) * (
-        math.log(cfg.dt_max) - math.log(cfg.dt_min)) + math.log(cfg.dt_min))
     blocks = {
         "ln_in": {"scale": jnp.ones((n, d), dt)},
         "ln_ff": {"scale": jnp.ones((n, d), dt)},
@@ -170,18 +173,11 @@ def falcon_h1_init(rng: jax.Array, cfg: FalconH1Config) -> dict:
             cfg.attention_in_multiplier * cfg.key_multiplier))},
         "v": {"kernel": normal(ks[3], (d, hkv), d**-0.5 / cfg.attention_in_multiplier)},
         "o": {"kernel": normal(ks[4], (hq, d), hq**-0.5 / cfg.attention_out_multiplier)},
-        "ssm_in": {"kernel": normal(ks[5], (d, in_std.shape[0]), in_std)},
-        "conv": {"kernel": (jax.random.uniform(
-            ks[6], (n, cfg.d_conv, cfg.conv_dim), jnp.float32, -1.0, 1.0)
-            * cfg.d_conv**-0.5).astype(dt),
-            "bias": jnp.zeros((n, cfg.conv_dim), dt)},
-        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-        "A_log": jnp.log(jax.random.uniform(
-            ks[7], (n, cfg.ssm_heads), jnp.float32, cfg.a_min, cfg.a_max)),
-        "D": jnp.ones((n, cfg.ssm_heads), jnp.float32),
-        "ssm_norm": {"scale": jnp.ones((n, cfg.d_ssm), dt)},
-        "ssm_out": {"kernel": normal(
-            ks[8], (cfg.d_ssm, d), cfg.d_ssm**-0.5 / cfg.ssm_out_multiplier)},
+        **cfg.mixer().init(
+            (ks[0], ks[5], ks[6], None, ks[7], ks[8]), n, d,
+            d**-0.5 / (cfg.ssm_in_multiplier * cfg.mup_vector()),
+            cfg.d_ssm**-0.5 / cfg.ssm_out_multiplier,
+            (cfg.a_min, cfg.a_max), (cfg.dt_min, cfg.dt_max)),
         "gate": {"kernel": normal(ks[9], (d, dff), d**-0.5 / cfg.mlp_multipliers[0])},
         "up": {"kernel": normal(ks[10], (d, dff), d**-0.5)},
         "down": {"kernel": normal(ks[11], (dff, d), dff**-0.5 / cfg.mlp_multipliers[1])},
@@ -196,17 +192,6 @@ def falcon_h1_init(rng: jax.Array, cfg: FalconH1Config) -> dict:
     }
 
 
-def _rmsnorm(x, scale, eps):
-    """RMSNorm in float32 (x: the float32 stream, or a float32 product)."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
-
-
-def _dot32(x, kernel):
-    """x @ kernel on x's dtype, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
-
-
 class FalconH1Body:
     """The family's traced layer programs for ``HybridModelRunner``.  The
     pools ride as ``HybridPool.arrays`` has them: ``(k, v, conv, ssd)``: K and
@@ -218,11 +203,7 @@ class FalconH1Body:
     def __init__(self, cfg: FalconH1Config):
         self.cfg = cfg
         self.dt = jnp.dtype(cfg.dtype)
-        self.mup = cfg.mup_vector()
-        seg = np.cumsum(cfg.ssm_segments())
-        #: where z ends, and x, B, C end within ``[x | B | C]``
-        self.z_end, self.conv_end = int(seg[0]), int(seg[3])
-        self.x_end, self.b_end = cfg.d_ssm, cfg.d_ssm + cfg.n_groups * cfg.d_state
+        self.ssm = cfg.mixer()
 
     # -- what the pools hold ----------------------------------------------
 
@@ -234,12 +215,7 @@ class FalconH1Body:
 
     def state_leaves(self, block_size: int) -> dict:
         """name -> (layers, one slot's shape, dtype) of the state pool."""
-        cfg = self.cfg
-        return {
-            "conv": (cfg.n_layers, (cfg.d_conv - 1, cfg.conv_dim), cfg.dtype),
-            "ssd": (cfg.n_layers, (cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state),
-                    cfg.state_dtype),
-        }
+        return self.ssm.state_leaves(self.cfg.n_layers, self.cfg.state_dtype)
 
     # -- shared layer math --------------------------------------------------
 
@@ -250,53 +226,18 @@ class FalconH1Body:
 
     def lm_head(self, params, h):
         with jax.named_scope("lm_head"):
-            y = _rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
-            return _dot32(y, params["lm_head"]["kernel"]) * self.cfg.lm_head_multiplier
-
-    def _ssm_in(self, u, layer):
-        """The input projection and the muP vector: (z (n, d_ssm) float32,
-        ``[x | B | C]`` before the convolution in the compute dtype, the step
-        size (n, H) float32 after its softplus)."""
-        cfg = self.cfg
-        p = _dot32((u * cfg.ssm_in_multiplier).astype(self.dt),
-                   layer["ssm_in"]["kernel"]) * self.mup
-        step = jax.nn.softplus(p[:, self.conv_end:] + layer["dt_bias"].astype(jnp.float32))
-        return p[:, :self.z_end], p[:, self.z_end:self.conv_end].astype(self.dt), step
-
-    def _conv(self, window, layer):
-        """``window``: (..., d_conv + n - 1, conv_dim) inputs, the oldest
-        first -> SiLU of the causal depthwise convolution at the last ``n``,
-        float32, split into x (n, H, P), B and C (n, G, N)."""
-        cfg = self.cfg
-        n = window.shape[-2] - cfg.d_conv + 1
-        w32, kern = window.astype(jnp.float32), layer["conv"]["kernel"].astype(jnp.float32)
-        out = sum(w32[..., i:i + n, :] * kern[i] for i in range(cfg.d_conv))
-        out = jax.nn.silu(out + layer["conv"]["bias"].astype(jnp.float32))
-        out = out.reshape(-1, cfg.conv_dim)
-        rows = out.shape[0]
-        return (out[:, :self.x_end].reshape(rows, cfg.ssm_heads, cfg.ssm_head_dim),
-                out[:, self.x_end:self.b_end].reshape(rows, cfg.n_groups, cfg.d_state),
-                out[:, self.b_end:].reshape(rows, cfg.n_groups, cfg.d_state))
-
-    def _ssm_out(self, y, z, layer):
-        """Gate, THEN the grouped norm, then the output projection."""
-        cfg = self.cfg
-        gated = y.reshape(z.shape) * jax.nn.silu(z)
-        grouped = gated.reshape(z.shape[0], cfg.n_groups, -1)
-        normed = grouped * jax.lax.rsqrt(
-            (grouped * grouped).mean(-1, keepdims=True) + cfg.rms_norm_eps)
-        normed = normed.reshape(z.shape) * layer["ssm_norm"]["scale"].astype(jnp.float32)
-        return _dot32(normed.astype(self.dt), layer["ssm_out"]["kernel"])
+            y = rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
+            return dot32(y, params["lm_head"]["kernel"]) * self.cfg.lm_head_multiplier
 
     def _qkv(self, u, layer, positions):
         """q (n, H, e), k, v (n, K, e) in the compute dtype, q and k rotated."""
         cfg, n = self.cfg, u.shape[0]
         with jax.named_scope("qkv"):
             a = (u * cfg.attention_in_multiplier).astype(self.dt)
-            q = _dot32(a, layer["q"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
-            k = (_dot32(a, layer["k"]["kernel"]) * cfg.key_multiplier).reshape(
+            q = dot32(a, layer["q"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
+            k = (dot32(a, layer["k"]["kernel"]) * cfg.key_multiplier).reshape(
                 n, cfg.n_kv_heads, cfg.head_dim)
-            v = _dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+            v = dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
             q = rotary_half(q, positions, cfg.rope_theta).astype(self.dt)
             k = rotary_half(k, positions, cfg.rope_theta).astype(self.dt)
             return q, k, v.astype(self.dt)
@@ -305,96 +246,53 @@ class FalconH1Body:
         """Both branches onto the stream, then the MLP."""
         cfg = self.cfg
         with jax.named_scope("attn_out"):
-            h = h + cfg.ssm_out_multiplier * ssm + cfg.attention_out_multiplier * _dot32(
+            h = h + cfg.ssm_out_multiplier * ssm + cfg.attention_out_multiplier * dot32(
                 att.astype(self.dt).reshape(h.shape[0], -1), layer["o"]["kernel"])
         with jax.named_scope("mlp"):
-            y = _rmsnorm(h, layer["ln_ff"]["scale"], cfg.rms_norm_eps).astype(self.dt)
-            gate = jax.nn.silu(_dot32(y, layer["gate"]["kernel"]) * cfg.mlp_multipliers[0])
-            mid = (gate * _dot32(y, layer["up"]["kernel"])).astype(self.dt)
-            return h + _dot32(mid, layer["down"]["kernel"]) * cfg.mlp_multipliers[1]
+            y = rmsnorm(h, layer["ln_ff"]["scale"], cfg.rms_norm_eps).astype(self.dt)
+            gate = jax.nn.silu(dot32(y, layer["gate"]["kernel"]) * cfg.mlp_multipliers[0])
+            mid = (gate * dot32(y, layer["up"]["kernel"])).astype(self.dt)
+            return h + dot32(mid, layer["down"]["kernel"]) * cfg.mlp_multipliers[1]
 
     def _norm_in(self, h, layer):
         with jax.named_scope("norm_in"):
-            return _rmsnorm(h, layer["ln_in"]["scale"], self.cfg.rms_norm_eps)
+            return rmsnorm(h, layer["ln_in"]["scale"], self.cfg.rms_norm_eps)
 
-    @staticmethod
-    def _a(layer):
-        return -jnp.exp(layer["A_log"].astype(jnp.float32))
-
-    # -- decode: one token of many sequences ---------------------------------
-
-    def decode(self, params, x, arrays, positions, tables):
-        """x: (S, d) embedded tokens at ``positions``; tables: (S, 1 + T).
-        Returns (hidden (S, d), arrays)."""
-        cfg = self.cfg
-        slots, btab = tables[:, 0], tables[:, 1:]
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        live = slots > 0
-        phys = jnp.take_along_axis(btab, (positions // bs)[:, None], axis=1)[:, 0]
-        write = _slots_write(phys, positions % bs, bs)
+    def _layers(self, params, x, arrays, positions, slots, ssm, attend):
+        """A step's layers, written once for both steps: one ``_carry_loop``
+        with the four pools as its carry.  ``ssm(u, layer, conv, ssd, at)`` is
+        the step's Mamba-2 step (``self.ssm.decode`` / ``.chunk``), ``attend``
+        its paged K/V step, ``slots`` its slot(s) of state."""
+        n_blocks, n_slots = arrays[0].shape[1], arrays[2].shape[1]
 
         def layer_fn(h, layer, k_pool, v_pool, conv, ssd, base):
             at = (base // n_blocks) * n_slots + slots
             u = self._norm_in(h, layer)
             with jax.named_scope("ssm"):
-                z, raw, step = self._ssm_in(u, layer)
-                window = jnp.concatenate([conv[at], raw[:, None, :]], axis=1)
-                conv = conv.at[at].set(window[:, 1:])
-                xs, b, c = self._conv(window, layer)
-                with jax.named_scope("ssd_update"):
-                    ssd, y = ssd_decode(ssd, xs, step, self._a(layer), b, c, layer["D"],
-                                        at, live, impl=cfg.attn_impl)
-                ssm = self._ssm_out(y, z, layer)
+                y, conv, ssd = ssm(u * self.cfg.ssm_in_multiplier, layer, conv, ssd, at)
             q, k, v = self._qkv(u, layer, positions)
-            k_pool, v_pool = write(k_pool, k, base), write(v_pool, v, base)
-            with jax.named_scope("gqa_attention"):
-                att = gqa_paged_attention(q, k_pool, v_pool, btab + base, positions,
-                                          impl=cfg.attn_impl)
-            return self._close(h, layer, ssm, att), k_pool, v_pool, conv, ssd
+            att, k_pool, v_pool = attend(q, k, v, k_pool, v_pool, base)
+            return self._close(h, layer, y, att), k_pool, v_pool, conv, ssd
 
         x, *arrays = _carry_loop(params["blocks"], x, tuple(arrays), layer_fn)
         return x, tuple(arrays)
 
-    # -- prefill: a chunk of one sequence -------------------------------------
+    def decode(self, params, x, arrays, positions, tables):
+        """One token of many sequences.  x: (S, d) embedded tokens at
+        ``positions``; tables: (S, 1 + T).  Returns (hidden (S, d), arrays)."""
+        slots, btab = tables[:, 0], tables[:, 1:]
+        ssm = functools.partial(self.ssm.decode, live=slots > 0)
+        attend = paged_kv_decode(arrays[0], btab, positions, self.cfg.attn_impl)
+        return self._layers(params, x, arrays, positions, slots, ssm, attend)
 
     def chunk(self, params, x, arrays, start, n_valid, table):
-        """x: (C, d) embedded tokens of ONE sequence at ``start ..``, the
-        first ``n_valid`` real; table: (1 + T,).  Returns (the last valid
-        token's hidden (1, d), arrays)."""
-        cfg = self.cfg
+        """A prefill chunk.  x: (C, d) embedded tokens of ONE sequence at
+        ``start ..``, the first ``n_valid`` real; table: (1 + T,).  Returns
+        (the last valid token's hidden (1, d), arrays)."""
         slot, btab = table[0], table[1:]
-        C, taps = x.shape[0], cfg.d_conv - 1
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        valid, fresh = jnp.arange(C) < n_valid, start == 0
-        write = _chunk_write(btab, start, n_valid, C, bs)
-
-        def layer_fn(h, layer, k_pool, v_pool, conv, ssd, base):
-            at = (base // n_blocks) * n_slots + slot
-            u = self._norm_in(h, layer)
-            with jax.named_scope("ssm"):
-                z, raw, step = self._ssm_in(u, layer)
-                # a sequence's first chunk overwrites what the slot's last
-                # owner left; the last ``taps`` valid inputs are what the
-                # next token needs
-                tail = jnp.where(fresh, 0, jax.lax.dynamic_index_in_dim(conv, at, 0, False))
-                seq = jnp.concatenate([tail, raw], axis=0)              # (taps + C, D)
-                conv = jax.lax.dynamic_update_index_in_dim(
-                    conv, jax.lax.dynamic_slice_in_dim(seq, n_valid, taps), at, 0)
-                xs, b, c = self._conv(seq, layer)
-                with jax.named_scope("ssd_chunk"):
-                    s0 = jnp.where(fresh, 0.0, jax.lax.dynamic_index_in_dim(
-                        ssd, at, 0, False).astype(jnp.float32))
-                    y, s1 = ssd_chunk(s0, xs, step, self._a(layer), b, c, layer["D"], valid,
-                                      sub=cfg.ssm_chunk)
-                    ssd = jax.lax.dynamic_update_index_in_dim(ssd, s1.astype(ssd.dtype), at, 0)
-                ssm = self._ssm_out(y, z, layer)
-            q, k, v = self._qkv(u, layer, positions)
-            k_pool, v_pool = write(k_pool, k, base), write(v_pool, v, base)
-            with jax.named_scope("chunk_attention"):
-                att = gqa_chunk_attention(q, k_pool, v_pool, btab + base, positions,
-                                          start + n_valid)
-            return self._close(h, layer, ssm, att), k_pool, v_pool, conv, ssd
-
-        x, *arrays = _carry_loop(params["blocks"], x, tuple(arrays), layer_fn)
-        return jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1), tuple(arrays)
+        positions = start + jnp.arange(x.shape[0], dtype=jnp.int32)
+        valid, fresh = jnp.arange(x.shape[0]) < n_valid, start == 0
+        ssm = functools.partial(self.ssm.chunk, fresh=fresh, n_valid=n_valid, valid=valid)
+        attend = paged_kv_chunk(arrays[0], btab, positions, start, n_valid)
+        x, arrays = self._layers(params, x, arrays, positions, slot, ssm, attend)
+        return last_valid(x, n_valid), arrays

@@ -41,34 +41,32 @@ LAYER KIND: a slot of SSD state ``(H, P, N)`` float32 and of the
 convolution's last ``d_conv - 1`` inputs in each Mamba layer, blocks of K and
 V in each attention layer, nothing else.  So ``kv_layout()["n_layers"]``
 counts the attention layers and ``state_leaves()`` the Mamba layers.  The
-layer loop is one ``_carry_loop`` for each RUN of layers of one kind
-(``runs()``: at the published pattern five Mamba layers, one attention layer,
-four Mamba layers), every run over the same pools; a layer's held experts
-are indexed out of ONE flat array of every layer's (``ops.moe.expert_layer``).
-``counters`` is what the programs count on the device, under Kimi-K2.5's
-names and one more (``decode_tile_rows``: the rows the expert layer computed
-in decodes, in either of its forms).
+layer loop is ``blocks.pattern_layers``: one ``_carry_loop`` for each RUN of
+layers of one kind (``runs()``: at the published pattern five Mamba layers,
+one attention layer, four Mamba layers), every run over the same pools; a
+layer's held experts are indexed out of ONE flat array of every layer's
+(``ops.moe.expert_layer``).  What the family shares with others it takes from
+``models.blocks`` (the Mamba-2 mixer and its two steps, the paged K/V step,
+the pattern loop) and ``ops.moe`` (the routed layer's ledger: ``counters`` is
+what the programs count on the device, ``stats()["moe"]``); its projections,
+its multipliers and its pattern are HERE.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
-from ray_tpu.ops.gqa_attention import gqa_chunk_attention, gqa_paged_attention
+from ray_tpu.models.blocks import (
+    Mamba2, check_share, dot32, gated_mlp_init, last_valid, normal_layers, paged_kv_chunk,
+    paged_kv_decode, pattern_layers, pattern_of, rmsnorm, runs_of)
 from ray_tpu.ops.moe import (
-    batch_steps, expert_layer, held_pairs, route_logits, swiglu, tile_rows)
-from ray_tpu.ops.ssd import ssd_chunk, ssd_decode
+    count_routed, counters_shape, expert_layer, held_pairs, read_counters, route_logits, swiglu)
 
-#: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
-COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
-            "decode_tile_rows", "decode_expert_steps")
 #: the published pattern's first period
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -134,18 +132,14 @@ class GraniteHConfig:
     cache_kind = "hybrid"
 
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if len(self.layer_types) != self.n_layers or set(self.layer_types) != {
-                "mamba", "attention"}:
-            raise ValueError("layer_types names n_layers mixers, 'mamba' and 'attention' both")
+        object.__setattr__(self, "layer_types", pattern_of(
+            self.layer_types, self.n_layers, ("mamba", "attention")))
         if self.n_heads % self.n_kv_heads or self.ssm_heads % self.n_groups:
             raise ValueError("query heads and SSM heads come in whole groups")
         if self.d_ssm % self.ssm_heads:
             raise ValueError("d_ssm must be whole heads")
-        if self.expert_offset + self.experts_held > self.n_routed_experts:
-            raise ValueError("the held experts lie outside the router's width")
-        if self.experts_per_tok > self.n_routed_experts:
-            raise ValueError("more experts a token than the router has")
+        check_share(
+            self.n_routed_experts, self.expert_offset, self.experts_held, self.experts_per_tok)
 
     @property
     def ssm_head_dim(self) -> int:
@@ -161,7 +155,14 @@ class GraniteHConfig:
 
     def runs(self) -> tuple:
         """The layers as runs of one kind: ``((kind, how many), ...)``."""
-        return tuple((kind, len(list(g))) for kind, g in itertools.groupby(self.layer_types))
+        return runs_of(self.layer_types)
+
+    def mixer(self) -> Mamba2:
+        """The Mamba layers' mixer (``models.blocks``); no muP vector."""
+        return Mamba2(
+            d_ssm=self.d_ssm, heads=self.ssm_heads, d_state=self.d_state, n_groups=self.n_groups,
+            d_conv=self.d_conv, eps=self.rms_norm_eps, dtype=jnp.dtype(self.dtype),
+            sub=self.ssm_chunk, impl=self.attn_impl)
 
     def serving_body(self) -> "GraniteHBody":
         return GraniteHBody(self)
@@ -202,43 +203,20 @@ def granite_h_init(rng: jax.Array, cfg: GraniteHConfig) -> dict:
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     onto = 1.0 / cfg.residual_multiplier
 
-    def normal(key, n: int, shape: tuple, std: float):
-        """(n,) + shape, one layer at a time."""
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(dt),
-            jax.random.split(key, n))
-
-    def mlp(key, n: int, width: int) -> dict:
-        ks = jax.random.split(key, 3)
-        return {"gate": normal(ks[0], n, (d, width), d**-0.5),
-                "up": normal(ks[1], n, (d, width), d**-0.5),
-                "down": normal(ks[2], n, (width, d), width**-0.5)}
+    normal = functools.partial(normal_layers, dtype=dt)
+    mlp = functools.partial(gated_mlp_init, d=d, make=normal)
 
     def closing(key, n: int) -> dict:
         ks = jax.random.split(key, 2)
         return {"ln1": {"scale": jnp.ones((n, d), dt)}, "ln2": {"scale": jnp.ones((n, d), dt)},
                 "router": {"kernel": normal(ks[0], n, (d, cfg.n_routed_experts), d**-0.5)},
-                "shared": mlp(ks[1], n, cfg.d_shared)}
+                "shared": mlp(ks[1], n, width=cfg.d_shared)}
 
     def mamba(key, n: int) -> dict:
         ks = jax.random.split(key, 7)
-        step = jnp.exp(jax.random.uniform(ks[0], (n, cfg.ssm_heads)) * (
-            math.log(cfg.dt_max) - math.log(cfg.dt_min)) + math.log(cfg.dt_min))
-        width = cfg.d_ssm + cfg.conv_dim + cfg.ssm_heads
-        taps = lambda k, shape: (jax.random.uniform(  # noqa: E731
-            k, shape, jnp.float32, -1.0, 1.0) * cfg.d_conv**-0.5).astype(dt)
-        return dict(
-            closing(ks[1], n),
-            ssm_in={"kernel": normal(ks[2], n, (d, width), d**-0.5)},
-            conv={"kernel": taps(ks[3], (n, cfg.d_conv, cfg.conv_dim)),
-                  "bias": taps(ks[4], (n, cfg.conv_dim))},
-            dt_bias=step + jnp.log(-jnp.expm1(-step)),
-            A_log=jnp.log(jax.random.uniform(
-                ks[5], (n, cfg.ssm_heads), jnp.float32, cfg.a_min, cfg.a_max)),
-            D=jnp.ones((n, cfg.ssm_heads), jnp.float32),
-            ssm_norm={"scale": jnp.ones((n, cfg.d_ssm), dt)},
-            ssm_out={"kernel": normal(ks[6], n, (cfg.d_ssm, d), cfg.d_ssm**-0.5 * onto)},
-        )
+        return dict(closing(ks[1], n), **cfg.mixer().init(
+            (ks[0], *ks[2:]), n, d, d**-0.5, cfg.d_ssm**-0.5 * onto,
+            (cfg.a_min, cfg.a_max), (cfg.dt_min, cfg.dt_max)))
 
     def attention(key, n: int) -> dict:
         ks = jax.random.split(key, 5)
@@ -258,20 +236,9 @@ def granite_h_init(rng: jax.Array, cfg: GraniteHConfig) -> dict:
         "embed": {"tokens": normal(
             ks[0], 1, (cfg.vocab_size, d), cfg.init_range)[0]},
         "runs": [made[kind](k, n) for k, (kind, n) in zip(ks[2:], runs)],
-        "experts": mlp(ks[1], cfg.n_layers * cfg.experts_held, cfg.d_expert),
+        "experts": mlp(ks[1], cfg.n_layers * cfg.experts_held, width=cfg.d_expert),
         "ln_f": {"scale": jnp.ones((d,), dt)},
     }
-
-
-def _rmsnorm(x, scale, eps):
-    """RMSNorm in float32 (x: the float32 stream, or a float32 product)."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
-
-
-def _dot32(x, kernel):
-    """x @ kernel on x's dtype, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
 class GraniteHBody:
@@ -279,18 +246,15 @@ class GraniteHBody:
     ``arrays`` is ``(k, v, conv, ssd, counters)``: K and V ``(attention
     layers, blocks, K, block, e)``, the convolution's tails ``(Mamba layers,
     slots + 1, d_conv - 1, conv_dim)``, the SSD states ``(Mamba layers, slots
-    + 1, H, P, N)`` and the device's own counts ``(1, len(COUNTERS) +
-    experts_held)`` int32.  A table row is ``[slot, block table...]``, slot 0 and block 0 the
-    trash a dead decode row and a padded chunk row write; a dead row has no
-    pair in the expert layer and counts nowhere."""
+    + 1, H, P, N)`` and the device's own counts (``ops.moe.counters_shape``).
+    A table row is ``[slot, block table...]``, slot 0 and block 0 the trash a
+    dead decode row and a padded chunk row write; a dead row has no pair in
+    the expert layer and counts nowhere."""
 
     def __init__(self, cfg: GraniteHConfig):
         self.cfg = cfg
         self.dt = jnp.dtype(cfg.dtype)
-        #: where z ends and ``[x | B | C]`` ends in ``W_in``'s columns, and
-        #: where x and B end within ``[x | B | C]``
-        self.z_end, self.conv_end = cfg.d_ssm, cfg.d_ssm + cfg.conv_dim
-        self.x_end, self.b_end = cfg.d_ssm, cfg.d_ssm + cfg.n_groups * cfg.d_state
+        self.ssm = cfg.mixer()
         #: ``ops.gqa_attention`` scales by ``e ** -0.5``; the rest goes into q
         self.q_scale = cfg.attention_multiplier * math.sqrt(cfg.head_dim)
 
@@ -305,23 +269,12 @@ class GraniteHBody:
 
     def state_leaves(self, block_size: int) -> dict:
         """name -> (layers, one slot's shape, dtype): the MAMBA layers'."""
-        cfg, n = self.cfg, self.cfg.n_of("mamba")
-        return {
-            "conv": (n, (cfg.d_conv - 1, cfg.conv_dim), cfg.dtype),
-            "ssd": (n, (cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state), cfg.state_dtype),
-        }
+        return self.ssm.state_leaves(self.cfg.n_of("mamba"), self.cfg.state_dtype)
 
     def counters(self) -> tuple:
-        """Shapes and dtypes of what the steps carry beside the pools."""
-        return (jax.ShapeDtypeStruct((1, len(COUNTERS) + self.cfg.experts_held), jnp.int32),)
+        return counters_shape(self.cfg.experts_held)
 
-    @staticmethod
-    def read_counters(arrays) -> dict:
-        """``stats()``'s part from the fetched counters: ``{"moe": ...}``."""
-        flat = np.asarray(arrays[0]).reshape(-1)
-        out = {name: int(flat[i]) for i, name in enumerate(COUNTERS)}
-        out["load"] = [int(x) for x in flat[len(COUNTERS):]]
-        return {"moe": out}
+    read_counters = staticmethod(read_counters)
 
     # -- shared layer math --------------------------------------------------
 
@@ -333,86 +286,40 @@ class GraniteHBody:
     def lm_head(self, params, h):
         """The tied head: the embedding's rows against the normed stream."""
         with jax.named_scope("lm_head"):
-            y = _rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
+            y = rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
             return jnp.einsum("nd,vd->nv", y, params["embed"]["tokens"].astype(self.dt),
                               preferred_element_type=jnp.float32) / self.cfg.logits_scaling
 
     def _norm(self, h, layer, which: str):
-        return _rmsnorm(h, layer[which]["scale"], self.cfg.rms_norm_eps)
-
-    def _ssm_in(self, u, layer):
-        """The input projection: (z (n, d_ssm) float32, ``[x | B | C]``
-        before the convolution in the compute dtype, the step size (n, H)
-        float32 after its softplus)."""
-        p = _dot32(u.astype(self.dt), layer["ssm_in"]["kernel"])
-        step = jax.nn.softplus(p[:, self.conv_end:] + layer["dt_bias"].astype(jnp.float32))
-        return p[:, :self.z_end], p[:, self.z_end:self.conv_end].astype(self.dt), step
-
-    def _conv(self, window, layer):
-        """``window``: (..., d_conv + n - 1, conv_dim) inputs, the oldest
-        first -> SiLU of the causal depthwise convolution at the last ``n``,
-        float32, split into x (n, H, P), B and C (n, G, N)."""
-        cfg = self.cfg
-        n = window.shape[-2] - cfg.d_conv + 1
-        w32, kern = window.astype(jnp.float32), layer["conv"]["kernel"].astype(jnp.float32)
-        out = sum(w32[..., i:i + n, :] * kern[i] for i in range(cfg.d_conv))
-        out = jax.nn.silu(out + layer["conv"]["bias"].astype(jnp.float32))
-        out = out.reshape(-1, cfg.conv_dim)
-        rows = out.shape[0]
-        return (out[:, :self.x_end].reshape(rows, cfg.ssm_heads, cfg.ssm_head_dim),
-                out[:, self.x_end:self.b_end].reshape(rows, cfg.n_groups, cfg.d_state),
-                out[:, self.b_end:].reshape(rows, cfg.n_groups, cfg.d_state))
-
-    def _ssm_out(self, y, z, layer):
-        """Gate, THEN the norm within each group, then the output projection."""
-        cfg = self.cfg
-        gated = (y.reshape(z.shape) * jax.nn.silu(z)).reshape(z.shape[0], cfg.n_groups, -1)
-        normed = gated * jax.lax.rsqrt(
-            (gated * gated).mean(-1, keepdims=True) + cfg.rms_norm_eps)
-        normed = normed.reshape(z.shape) * layer["ssm_norm"]["scale"].astype(jnp.float32)
-        return _dot32(normed.astype(self.dt), layer["ssm_out"]["kernel"])
+        return rmsnorm(h, layer[which]["scale"], self.cfg.rms_norm_eps)
 
     def _qkv(self, u, layer):
         """q (n, H, e), k, v (n, K, e) in the compute dtype; NO rotary."""
         cfg, n = self.cfg, u.shape[0]
         with jax.named_scope("qkv"):
             a = u.astype(self.dt)
-            q = (_dot32(a, layer["q"]["kernel"]) * self.q_scale).reshape(
+            q = (dot32(a, layer["q"]["kernel"]) * self.q_scale).reshape(
                 n, cfg.n_heads, cfg.head_dim)
-            k = _dot32(a, layer["k"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-            v = _dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+            k = dot32(a, layer["k"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+            v = dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
             return q.astype(self.dt), k.astype(self.dt), v.astype(self.dt)
 
     def _attn_out(self, h, layer, att):
         with jax.named_scope("attn_out"):
-            return h + self.cfg.residual_multiplier * _dot32(
+            return h + self.cfg.residual_multiplier * dot32(
                 att.astype(self.dt).reshape(h.shape[0], -1), layer["o"]["kernel"])
-
-    @staticmethod
-    def _a(layer):
-        return -jnp.exp(layer["A_log"].astype(jnp.float32))
 
     def _expert_mlp(self, h, layer, live, counts, phase: str, experts, index):
         """The expert layer's part this chip holds, and the shared MLP.
-        ``counts`` gets this layer's pairs under ``<phase>_pairs``, its load
-        by held expert and, in a decode, its touched experts, the rows the
-        expert layer computed and the steps its batch form made.  ``experts``: the held experts of every layer,
-        flat, this layer's from ``index * experts_held``."""
+        ``counts`` (``ops.moe``'s ledger) gets this layer through
+        ``count_routed``.  ``experts``: the held experts of every layer, flat,
+        this layer's from ``index * experts_held``."""
         cfg = self.cfg
         with jax.named_scope("moe_router"):
             y32 = self._norm(h, layer, "ln2")
             chosen, weights = route_logits(y32, layer["router"]["kernel"], cfg.experts_per_tok)
             mask, wmat = held_pairs(chosen, weights, cfg.expert_offset, cfg.experts_held, live)
-            load = mask.sum(axis=0).astype(jnp.int32)
-            counts = counts.at[COUNTERS.index(f"{phase}_pairs")].add(load.sum())
-            counts = counts.at[len(COUNTERS):].add(load)
-            if phase == "decode":
-                counts = counts.at[COUNTERS.index("decode_touched")].add(
-                    (load > 0).sum().astype(jnp.int32))
-                counts = counts.at[COUNTERS.index("decode_tile_rows")].add(
-                    tile_rows(load, mask.shape[0]))
-                counts = counts.at[COUNTERS.index("decode_expert_steps")].add(
-                    batch_steps(load, mask.shape[0]))
+            counts = count_routed(counts, mask, phase)
         y, sh = y32.astype(self.dt), layer["shared"]
         with jax.named_scope("moe_experts"):
             routed = expert_layer(y, mask, wmat, experts["gate"], experts["up"],
@@ -422,109 +329,51 @@ class GraniteHBody:
             return h + cfg.residual_multiplier * (
                 routed + swiglu(y, sh["gate"], sh["up"], sh["down"])), counts
 
-    def _layers(self, params, x, arrays, mixers: dict, live, phase: str):
-        """One ``_carry_loop`` a run of layers of one kind, each over ALL the
-        pools (a run leaves the other kind's as they came).  ``mixers[kind](h,
-        layer, k, v, conv, ssd, l)`` is the step's mixer of the ``l``-th layer
-        of that kind and gives ``(h, k, v, conv, ssd)``."""
-        n_blocks, experts = arrays[0].shape[1], params["experts"]
-        seen, done = {"mamba": 0, "attention": 0}, 0
-        for (kind, n), run in zip(self.cfg.runs(), params["runs"]):
-
-            def layer_fn(h, layer, k, v, conv, ssd, counts, base,
-                         mix=mixers[kind], first=seen[kind], index=done):
-                at = base // n_blocks  # the layer's place in its run
-                h, k, v, conv, ssd = mix(h, layer, k, v, conv, ssd, first + at)
-                h, counts = self._expert_mlp(h, layer, live, counts, phase, experts, index + at)
-                return h, k, v, conv, ssd, counts
-
-            x, *arrays = _carry_loop(run, x, tuple(arrays), layer_fn)
-            seen[kind] += n
-            done += n
-        counts = arrays[4].at[0, COUNTERS.index(f"{phase}s")].add(1)
-        return x, (*arrays[:4], counts)
-
-    # -- decode: one token of many sequences ---------------------------------
-
-    def decode(self, params, x, arrays, positions, tables):
-        """x: (S, d) embedded tokens at ``positions``; tables: (S, 1 + T).
-        Returns (hidden (S, d), arrays)."""
-        cfg = self.cfg
-        slots, btab = tables[:, 0], tables[:, 1:]
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        live = slots > 0
-        phys = jnp.take_along_axis(btab, (positions // bs)[:, None], axis=1)[:, 0]
-        write = _slots_write(phys, positions % bs, bs)
+    def _layers(self, params, x, arrays, live, phase: str, slots, ssm, attend):
+        """``blocks.pattern_layers`` over the runs, the expert layer closing
+        EVERY layer: a step's layers, written once for both steps.  ``ssm(u,
+        layer, conv, ssd, at)`` is the step's Mamba-2 step (``self.ssm.decode``
+        / ``.chunk``), ``attend`` its paged K/V step, ``slots`` its slot(s) of
+        state, ``live`` its rows that count."""
+        cfg, experts = self.cfg, params["experts"]
+        n_blocks, n_slots = arrays[0].shape[1], arrays[2].shape[1]
 
         def mamba(h, layer, k_pool, v_pool, conv, ssd, l):
             at = l * n_slots + slots
             with jax.named_scope("ssm"):
-                z, raw, step = self._ssm_in(self._norm(h, layer, "ln1"), layer)
-                window = jnp.concatenate([conv[at], raw[:, None, :]], axis=1)
-                conv = conv.at[at].set(window[:, 1:])
-                xs, b, c = self._conv(window, layer)
-                with jax.named_scope("ssd_update"):
-                    ssd, y = ssd_decode(ssd, xs, step, self._a(layer), b, c, layer["D"],
-                                        at, live, impl=cfg.attn_impl)
-                h = h + cfg.residual_multiplier * self._ssm_out(y, z, layer)
+                y, conv, ssd = ssm(self._norm(h, layer, "ln1"), layer, conv, ssd, at)
+                h = h + cfg.residual_multiplier * y
             return h, k_pool, v_pool, conv, ssd
 
         def attention(h, layer, k_pool, v_pool, conv, ssd, l):
             base = l * n_blocks
             q, k, v = self._qkv(self._norm(h, layer, "ln1"), layer)
-            k_pool, v_pool = write(k_pool, k, base), write(v_pool, v, base)
-            with jax.named_scope("gqa_attention"):
-                att = gqa_paged_attention(q, k_pool, v_pool, btab + base, positions,
-                                          impl=cfg.attn_impl)
+            att, k_pool, v_pool = attend(q, k, v, k_pool, v_pool, base)
             return self._attn_out(h, layer, att), k_pool, v_pool, conv, ssd
 
-        return self._layers(
-            params, x, arrays, {"mamba": mamba, "attention": attention}, live, "decode")
+        closing = lambda h, layer, counts, m: self._expert_mlp(  # noqa: E731
+            h, layer, live, counts, phase, experts, m)
+        return pattern_layers(
+            [(kind, "moe", n) for kind, n in cfg.runs()], params["runs"], x, arrays,
+            {"mamba": mamba, "attention": attention}, {"moe": closing}, phase)
 
-    # -- prefill: a chunk of one sequence -------------------------------------
+    def decode(self, params, x, arrays, positions, tables):
+        """One token of many sequences.  x: (S, d) embedded tokens at
+        ``positions``; tables: (S, 1 + T).  Returns (hidden (S, d), arrays)."""
+        slots, btab = tables[:, 0], tables[:, 1:]
+        live = slots > 0
+        attend = paged_kv_decode(arrays[0], btab, positions, self.cfg.attn_impl)
+        ssm = functools.partial(self.ssm.decode, live=live)
+        return self._layers(params, x, arrays, live, "decode", slots, ssm, attend)
 
     def chunk(self, params, x, arrays, start, n_valid, table):
-        """x: (C, d) embedded tokens of ONE sequence at ``start ..``, the
-        first ``n_valid`` real; table: (1 + T,).  Returns (the last valid
-        token's hidden (1, d), arrays)."""
-        cfg = self.cfg
+        """A prefill chunk.  x: (C, d) embedded tokens of ONE sequence at
+        ``start ..``, the first ``n_valid`` real; table: (1 + T,).  Returns
+        (the last valid token's hidden (1, d), arrays)."""
         slot, btab = table[0], table[1:]
-        C, taps = x.shape[0], cfg.d_conv - 1
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        valid, fresh = jnp.arange(C) < n_valid, start == 0
-        write = _chunk_write(btab, start, n_valid, C, bs)
-
-        def mamba(h, layer, k_pool, v_pool, conv, ssd, l):
-            at = l * n_slots + slot
-            with jax.named_scope("ssm"):
-                z, raw, step = self._ssm_in(self._norm(h, layer, "ln1"), layer)
-                # a sequence's first chunk overwrites what the slot's last
-                # owner left; the last ``taps`` valid inputs are what the
-                # next token needs
-                tail = jnp.where(fresh, 0, jax.lax.dynamic_index_in_dim(conv, at, 0, False))
-                seq = jnp.concatenate([tail, raw], axis=0)              # (taps + C, D)
-                conv = jax.lax.dynamic_update_index_in_dim(
-                    conv, jax.lax.dynamic_slice_in_dim(seq, n_valid, taps), at, 0)
-                xs, b, c = self._conv(seq, layer)
-                with jax.named_scope("ssd_chunk"):
-                    s0 = jnp.where(fresh, 0.0, jax.lax.dynamic_index_in_dim(
-                        ssd, at, 0, False).astype(jnp.float32))
-                    y, s1 = ssd_chunk(s0, xs, step, self._a(layer), b, c, layer["D"], valid,
-                                      sub=cfg.ssm_chunk)
-                    ssd = jax.lax.dynamic_update_index_in_dim(ssd, s1.astype(ssd.dtype), at, 0)
-                h = h + cfg.residual_multiplier * self._ssm_out(y, z, layer)
-            return h, k_pool, v_pool, conv, ssd
-
-        def attention(h, layer, k_pool, v_pool, conv, ssd, l):
-            base = l * n_blocks
-            q, k, v = self._qkv(self._norm(h, layer, "ln1"), layer)
-            k_pool, v_pool = write(k_pool, k, base), write(v_pool, v, base)
-            with jax.named_scope("chunk_attention"):
-                att = gqa_chunk_attention(q, k_pool, v_pool, btab + base, positions,
-                                          start + n_valid)
-            return self._attn_out(h, layer, att), k_pool, v_pool, conv, ssd
-
-        x, arrays = self._layers(
-            params, x, arrays, {"mamba": mamba, "attention": attention}, valid, "chunk")
-        return jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1), arrays
+        positions = start + jnp.arange(x.shape[0], dtype=jnp.int32)
+        valid, fresh = jnp.arange(x.shape[0]) < n_valid, start == 0
+        attend = paged_kv_chunk(arrays[0], btab, positions, start, n_valid)
+        ssm = functools.partial(self.ssm.chunk, fresh=fresh, n_valid=n_valid, valid=valid)
+        x, arrays = self._layers(params, x, arrays, valid, "chunk", slot, ssm, attend)
+        return last_valid(x, n_valid), arrays

@@ -35,8 +35,9 @@ and the layer programs ``llm.state_runner.HybridModelRunner`` takes through
 ``serving_body()``.  The body holds NO state beside its blocks, so the
 engine shares, forks and evicts them as it does GPT-J's: the radix prefix
 cache runs on latent blocks.  ``counters`` is what the programs count on the
-device (the router's load is known nowhere else): it rides every step
-beside the pool and is fetched by ``LLMEngine.stats()`` alone.
+device (``ops.moe``'s ledger of the routed layer: the router's load is known
+nowhere else): it rides every step beside the pool and is fetched by
+``LLMEngine.stats()`` alone.
 """
 
 from __future__ import annotations
@@ -49,16 +50,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
+from ray_tpu.models.blocks import (
+    check_share, dot32, gated_mlp_init, last_valid, normal_layers, rmsnorm)
+from ray_tpu.models.blocks import rmsnorm as _rmsnorm  # noqa: F401  the benchmark's controls' name
 from ray_tpu.ops.latent_attention import (
     latent_chunk_attention,
     latent_decode_attention,
     padded_width,
 )
-from ray_tpu.ops.moe import batch_steps, expert_layer, held_pairs, route, swiglu
-
-#: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
-COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
-            "decode_expert_steps")
+from ray_tpu.ops.moe import (
+    count_routed, count_step, counters_shape, expert_layer, held_pairs, read_counters, route,
+    swiglu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,10 +110,8 @@ class KimiK2Config:
     def __post_init__(self):
         if not 0 < self.n_dense_layers < self.n_layers:
             raise ValueError("at least one dense and one expert layer")
-        if self.expert_offset + self.experts_held > self.n_routed_experts:
-            raise ValueError("the held experts lie outside the router's width")
-        if self.experts_per_tok > self.n_routed_experts:
-            raise ValueError("more experts a token than the router has")
+        check_share(
+            self.n_routed_experts, self.expert_offset, self.experts_held, self.experts_per_tok)
 
     @property
     def head_dim(self) -> int:
@@ -163,10 +163,7 @@ def kimi_k2_init(rng: jax.Array, cfg: KimiK2Config) -> dict:
     nd, nm = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
 
     def normal(key, layers: int, shape: tuple, fan_in: int):
-        """(layers,) + shape, one layer at a time."""
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5).astype(dt),
-            jax.random.split(key, layers))
+        return normal_layers(key, layers, shape, fan_in**-0.5, dt)
 
     def attention(key, n: int) -> dict:
         ks = jax.random.split(key, 6)
@@ -187,13 +184,10 @@ def kimi_k2_init(rng: jax.Array, cfg: KimiK2Config) -> dict:
             "ln2": {"scale": jnp.ones((n, d), dt)},
         }
 
-    def mlp(key, n: int, shape: tuple, width: int) -> dict:
-        ks = jax.random.split(key, 3)
-        return {
-            "gate": normal(ks[0], n, shape + (d, width), d),
-            "up": normal(ks[1], n, shape + (d, width), d),
-            "down": normal(ks[2], n, shape + (width, d), width),
-        }
+    def mlp(key, n: int, lead: tuple, width: int) -> dict:
+        """``n`` layers' gated MLPs, ``lead`` an axis of experts before each matrix's own."""
+        return gated_mlp_init(key, n, d, width, lambda k, n, shape, std: normal_layers(
+            k, n, lead + shape, std, dt))
 
     ks = jax.random.split(rng, 8)
     return {
@@ -212,21 +206,10 @@ def kimi_k2_init(rng: jax.Array, cfg: KimiK2Config) -> dict:
     }
 
 
-def _rmsnorm(x, scale, eps):
-    """RMSNorm in float32 (x: the float32 stream, or a float32 product)."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
-
-
-def _dot32(x, kernel):
-    """x @ kernel on x's dtype, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
-
-
 class KimiK2Body:
     """The family's traced layer programs for ``HybridModelRunner``.
     ``arrays`` is ``(pool, counters)``: the latent pool ``(L, blocks, 1,
-    block, width)`` and the device's own counts ``(1, len(COUNTERS) +
+    block, width)`` and the device's own counts ``(1, len(ops.moe.COUNTERS) +
     experts_held)`` int32.  A table row is the sequence's block table; block
     0 is the trash a dead decode row and a padded chunk row write, and a row
     whose first block is 0 is dead: it has no pair in the expert layer and
@@ -252,16 +235,9 @@ class KimiK2Body:
         return {}
 
     def counters(self) -> tuple:
-        """Shapes and dtypes of what the steps carry beside the pool."""
-        return (jax.ShapeDtypeStruct((1, len(COUNTERS) + self.cfg.experts_held), jnp.int32),)
+        return counters_shape(self.cfg.experts_held)
 
-    @staticmethod
-    def read_counters(arrays) -> dict:
-        """``stats()``'s part from the fetched counters: ``{"moe": ...}``."""
-        flat = np.asarray(arrays[0]).reshape(-1)
-        out = {name: int(flat[i]) for i, name in enumerate(COUNTERS)}
-        out["load"] = [int(x) for x in flat[len(COUNTERS):]]
-        return {"moe": out}
+    read_counters = staticmethod(read_counters)
 
     # -- shared layer math ----------------------------------------------------
 
@@ -271,8 +247,8 @@ class KimiK2Body:
 
     def lm_head(self, params, h):
         with jax.named_scope("lm_head"):
-            y = _rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
-            return _dot32(y, params["lm_head"]["kernel"])
+            y = rmsnorm(h, params["ln_f"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
+            return dot32(y, params["lm_head"]["kernel"])
 
     def _rotate(self, x, positions):
         """Half-split rotary of (n, ..., rope) float32 at ``positions`` (n,)."""
@@ -288,14 +264,14 @@ class KimiK2Body:
         q_rope (n, H, dr) in the compute dtype, the token's cache row (n,
         width))."""
         cfg, dt, n = self.cfg, self.dt, x.shape[0]
-        y = _rmsnorm(x, layer["ln1"]["scale"], cfg.rms_norm_eps).astype(dt)
-        c_q = _rmsnorm(_dot32(y, layer["q_a"]["kernel"]), layer["q_a_norm"]["scale"],
+        y = rmsnorm(x, layer["ln1"]["scale"], cfg.rms_norm_eps).astype(dt)
+        c_q = rmsnorm(dot32(y, layer["q_a"]["kernel"]), layer["q_a_norm"]["scale"],
                        cfg.rms_norm_eps).astype(dt)
-        q = _dot32(c_q, layer["q_b"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
+        q = dot32(c_q, layer["q_b"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
         q_nope = q[..., :cfg.qk_nope_head_dim].astype(dt)
         q_rope = self._rotate(q[..., cfg.qk_nope_head_dim:], positions).astype(dt)
-        kv = _dot32(y, layer["kv_a"]["kernel"])
-        c = _rmsnorm(kv[:, :self.rank], layer["kv_a_norm"]["scale"], cfg.rms_norm_eps)
+        kv = dot32(y, layer["kv_a"]["kernel"])
+        c = rmsnorm(kv[:, :self.rank], layer["kv_a_norm"]["scale"], cfg.rms_norm_eps)
         k_r = self._rotate(kv[:, self.rank:], positions)
         row = jnp.concatenate(
             [c, k_r, jnp.zeros((n, self.width - self.rank - self.rope))], axis=-1)
@@ -303,7 +279,7 @@ class KimiK2Body:
 
     def _attn_out(self, x, layer, o):
         """o: (n, H, dv) float32 -> the residual after ``W_o``."""
-        return x + _dot32(o.astype(self.dt).reshape(x.shape[0], -1), layer["o"]["kernel"])
+        return x + dot32(o.astype(self.dt).reshape(x.shape[0], -1), layer["o"]["kernel"])
 
     def _decode_attention(self, x, layer, pool, base, positions, tables, write):
         with jax.named_scope("mla_proj"):
@@ -341,33 +317,24 @@ class KimiK2Body:
 
     def _dense_mlp(self, h, layer):
         with jax.named_scope("mlp"):
-            y = _rmsnorm(h, layer["ln2"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
+            y = rmsnorm(h, layer["ln2"]["scale"], self.cfg.rms_norm_eps).astype(self.dt)
             mlp = layer["mlp"]
             return h + swiglu(y, mlp["gate"], mlp["up"], mlp["down"])
 
     def _expert_mlp(self, h, layer, live, counts, phase: str, experts=None, index=0):
         """The expert layer's part this chip holds, and the shared expert.
-        ``counts`` gets this layer's pairs under ``<phase>_pairs``, its load
-        by held expert and, in a decode, its touched experts (what the
-        decode reads of the held weights) and the steps the expert layer's
-        batch form made of them.  ``experts``: the held experts of every
-        layer, flat, this layer's from ``index * experts_held`` (None: the
-        layer's own, ``layer["experts"]``)."""
+        ``counts`` (``ops.moe``'s ledger) gets this layer through
+        ``count_routed``.  ``experts``: the held experts of every layer, flat,
+        this layer's from ``index * experts_held`` (None: the layer's own,
+        ``layer["experts"]``)."""
         cfg = self.cfg
         with jax.named_scope("moe_router"):
-            y32 = _rmsnorm(h, layer["ln2"]["scale"], cfg.rms_norm_eps)
+            y32 = rmsnorm(h, layer["ln2"]["scale"], cfg.rms_norm_eps)
             chosen, weights = route(
                 y32, layer["router"]["kernel"], layer["router"]["bias"],
                 cfg.experts_per_tok, cfg.routed_scaling_factor)
             mask, wmat = held_pairs(chosen, weights, cfg.expert_offset, cfg.experts_held, live)
-            load = mask.sum(axis=0).astype(jnp.int32)
-            counts = counts.at[COUNTERS.index(f"{phase}_pairs")].add(load.sum())
-            counts = counts.at[len(COUNTERS):].add(load)
-            if phase == "decode":
-                counts = counts.at[COUNTERS.index("decode_touched")].add(
-                    (load > 0).sum().astype(jnp.int32))
-                counts = counts.at[COUNTERS.index("decode_expert_steps")].add(
-                    batch_steps(load, mask.shape[0]))
+            counts = count_routed(counts, mask, phase)
         y = y32.astype(self.dt)
         ex, sh = experts or layer["experts"], layer["shared"]
         with jax.named_scope("moe_experts"):
@@ -399,7 +366,7 @@ class KimiK2Body:
 
         x, pool = _carry_loop(params["dense"], x, (pool,), dense)
         x, pool, counts = _carry_loop(moe, x, (pool, counts), expert)
-        return x, pool, counts.at[0, COUNTERS.index(f"{phase}s")].add(1)
+        return x, pool, count_step(counts, phase)
 
     # -- the two step programs -------------------------------------------------
 
@@ -432,4 +399,4 @@ class KimiK2Body:
 
         x, pool, counts = self._layers(
             params, x, pool, counts, attention, jnp.arange(chunk) < n_valid, "chunk")
-        return jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1), (pool, counts)
+        return last_valid(x, n_valid), (pool, counts)

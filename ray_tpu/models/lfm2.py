@@ -35,35 +35,34 @@ anywhere, no residual or logit multiplier::
 
 What a sequence holds on the device (``llm.cache.HybridPool``) is split by
 layer kind: the tails of every ``conv`` layer in ONE state leaf, blocks of K
-and V in the attention layers.  The layer loop is one ``_carry_loop`` a RUN of
-layers of one (mixer, feed-forward) kind (``runs()``: at the first ten
-published layers two dense conv layers, then attention, three conv, attention,
-three conv, all with experts); the experts of every EXPERT layer lie in one
-flat array from the first expert layer on.  ``counters`` is what the programs
-count on the device: Granite-4.0-H's seven names, and a chunk's touched
-experts, computed rows and grouped-form steps beside its pairs.
+and V in the attention layers.  The layer loop is ``blocks.pattern_layers``:
+one ``_carry_loop`` a RUN of layers of one (mixer, feed-forward) kind
+(``runs()``: at the first ten published layers two dense conv layers, then
+attention, three conv, attention, three conv, all with experts); the experts
+of every EXPERT layer lie in one flat array from the first expert layer on.
+The paged K/V step and the pattern loop are ``models.blocks``', the routed
+layer's ledger ``ops.moe``'s (``counters`` is what the programs count on the
+device, ``stats()["moe"]``); the short convolution, the packed heads, the
+QK-norm and the pattern are HERE.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
-from ray_tpu.ops.gqa_attention import (
-    gqa_chunk_attention, gqa_paged_attention, rotary_half)
+from ray_tpu.models.blocks import (
+    check_share, dot32, gated_mlp_init, last_valid, normal_layers, paged_kv_chunk,
+    paged_kv_decode, pattern_layers, pattern_of, rmsnorm, runs_of)
+from ray_tpu.ops.gqa_attention import rotary_half
+from ray_tpu.ops.moe import COUNTERS  # noqa: F401  benchmark/families/lfm2_moe.py imports it
 from ray_tpu.ops.moe import (
-    batch_steps, expert_layer, grouped_steps, held_pairs, route, swiglu, tile_rows)
+    count_routed, counters_shape, expert_layer, held_pairs, read_counters, route, swiglu)
 from ray_tpu.ops.short_conv import short_conv_chunk, short_conv_decode
 
-#: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
-COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
-            "decode_tile_rows", "decode_expert_steps", "chunk_touched", "chunk_tile_rows",
-            "chunk_expert_steps")
 #: key-value heads side by side in one row of the pool: two heads of 64 fill
 #: the 128 lanes the paged kernel's rows have
 KV_PACK = 2
@@ -120,19 +119,14 @@ class Lfm2MoeConfig:
     cache_kind = "hybrid"
 
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if len(self.layer_types) != self.n_layers or set(self.layer_types) != {
-                "conv", "full_attention"}:
-            raise ValueError(
-                "layer_types names n_layers mixers, 'conv' and 'full_attention' both")
+        object.__setattr__(self, "layer_types", pattern_of(
+            self.layer_types, self.n_layers, ("conv", "full_attention")))
         if not 0 <= self.n_dense_layers < self.n_layers:
             raise ValueError("the dense layers lead, and an expert layer follows them")
         if self.n_heads % self.n_kv_heads or self.n_kv_heads % KV_PACK:
             raise ValueError("query heads and packed key-value heads come in whole groups")
-        if self.expert_offset + self.experts_held > self.n_routed_experts:
-            raise ValueError("the held experts lie outside the router's width")
-        if self.experts_per_tok > self.n_routed_experts:
-            raise ValueError("more experts a token than the router has")
+        check_share(
+            self.n_routed_experts, self.expert_offset, self.experts_held, self.experts_per_tok)
 
     def n_of(self, kind: str) -> int:
         return self.layer_types.count(kind)
@@ -146,7 +140,7 @@ class Lfm2MoeConfig:
         many), ...)``, the feed-forward ``dense`` or ``moe``."""
         kinds = [(mixer, "dense" if i < self.n_dense_layers else "moe")
                  for i, mixer in enumerate(self.layer_types)]
-        return tuple((*kind, len(list(g))) for kind, g in itertools.groupby(kinds))
+        return tuple((*kind, n) for kind, n in runs_of(kinds))
 
     def serving_body(self) -> "Lfm2MoeBody":
         return Lfm2MoeBody(self)
@@ -183,17 +177,8 @@ def lfm2_moe_init(rng: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     d, dt, e = cfg.d_model, jnp.dtype(cfg.dtype), cfg.head_dim
     hq, hkv = cfg.n_heads * e, cfg.n_kv_heads * e
 
-    def normal(key, n: int, shape: tuple, std: float):
-        """(n,) + shape, one layer at a time."""
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(dt),
-            jax.random.split(key, n))
-
-    def mlp(key, n: int, width: int, make=normal, out_gain: float = 1.0) -> dict:
-        ks = jax.random.split(key, 3)
-        return {"gate": make(ks[0], n, (d, width), d**-0.5),
-                "up": make(ks[1], n, (d, width), d**-0.5),
-                "down": make(ks[2], n, (width, d), width**-0.5 * out_gain)}
+    normal = functools.partial(normal_layers, dtype=dt)
+    mlp = functools.partial(gated_mlp_init, d=d)
 
     def akin(key, n: int, shape: tuple, std: float):
         """``normal`` for the experts: the ``experts_held`` of a layer are
@@ -214,7 +199,7 @@ def lfm2_moe_init(rng: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     def closing(key, n: int, ff: str) -> dict:
         out = {"ln1": {"scale": jnp.ones((n, d), dt)}, "ln2": {"scale": jnp.ones((n, d), dt)}}
         if ff == "dense":
-            return dict(out, mlp=mlp(key, n, cfg.d_ff))
+            return dict(out, mlp=mlp(key, n, width=cfg.d_ff, make=normal))
         return dict(out, router={
             "kernel": normal(key, n, (d, cfg.n_routed_experts), d**-0.5),
             "bias": jnp.zeros((n, cfg.n_routed_experts), jnp.float32)})
@@ -248,21 +233,10 @@ def lfm2_moe_init(rng: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     return {
         "embed": {"tokens": normal(ks[0], 1, (cfg.vocab_size, d), cfg.init_range)[0]},
         "runs": [made[mixer](k, n, ff) for k, (mixer, ff, n) in zip(ks[2:], runs)],
-        "experts": mlp(ks[1], cfg.n_expert_layers * cfg.experts_held, cfg.d_expert, akin,
-                       cfg.expert_out_gain),
+        "experts": mlp(ks[1], cfg.n_expert_layers * cfg.experts_held, width=cfg.d_expert,
+                       make=akin, out_gain=cfg.expert_out_gain),
         "ln_f": {"scale": jnp.ones((d,), dt)},
     }
-
-
-def _rmsnorm(x, scale, eps):
-    """RMSNorm in float32 over the last axis."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
-
-
-def _dot32(x, kernel):
-    """x @ kernel on x's dtype, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
 class Lfm2MoeBody:
@@ -270,7 +244,7 @@ class Lfm2MoeBody:
     ``arrays`` is ``(k, v, tails, counters)``: K and V ``(attention layers,
     blocks, K / KV_PACK, block, KV_PACK * e)``, the convolutions' tails
     ``(conv layers, slots + 1, conv_taps - 1, d)`` and the device's own counts
-    ``(1, len(COUNTERS) + experts_held)`` int32.  A table row is ``[slot,
+    (``ops.moe.counters_shape``).  A table row is ``[slot,
     block table...]``, slot 0 and block 0 the trash a dead decode row and a
     padded chunk row write; a dead row has no pair in the expert layer and
     counts nowhere."""
@@ -295,16 +269,9 @@ class Lfm2MoeBody:
         return {"tails": (cfg.n_of("conv"), (cfg.conv_taps - 1, cfg.d_model), cfg.dtype)}
 
     def counters(self) -> tuple:
-        """Shapes and dtypes of what the steps carry beside the pools."""
-        return (jax.ShapeDtypeStruct((1, len(COUNTERS) + self.cfg.experts_held), jnp.int32),)
+        return counters_shape(self.cfg.experts_held)
 
-    @staticmethod
-    def read_counters(arrays) -> dict:
-        """``stats()``'s part from the fetched counters: ``{"moe": ...}``."""
-        flat = np.asarray(arrays[0]).reshape(-1)
-        out = {name: int(flat[i]) for i, name in enumerate(COUNTERS)}
-        out["load"] = [int(x) for x in flat[len(COUNTERS):]]
-        return {"moe": out}
+    read_counters = staticmethod(read_counters)
 
     # -- shared layer math --------------------------------------------------
 
@@ -315,22 +282,22 @@ class Lfm2MoeBody:
     def lm_head(self, params, h):
         """The tied head: the embedding's rows against the normed stream."""
         with jax.named_scope("lm_head"):
-            y = _rmsnorm(h, params["ln_f"]["scale"], self.cfg.norm_eps).astype(self.dt)
+            y = rmsnorm(h, params["ln_f"]["scale"], self.cfg.norm_eps).astype(self.dt)
             return jnp.einsum("nd,vd->nv", y, params["embed"]["tokens"].astype(self.dt),
                               preferred_element_type=jnp.float32)
 
     def _norm(self, h, layer, which: str):
-        return _rmsnorm(h, layer[which]["scale"], self.cfg.norm_eps)
+        return rmsnorm(h, layer[which]["scale"], self.cfg.norm_eps)
 
     def _conv_in(self, h, layer):
         """The input projection and the first gate: (the gated input ``B *
         x`` in the compute dtype, ``C`` float32), each (n, d)."""
         d = self.cfg.d_model
-        p = _dot32(self._norm(h, layer, "ln1").astype(self.dt), layer["conv_in"]["kernel"])
+        p = dot32(self._norm(h, layer, "ln1").astype(self.dt), layer["conv_in"]["kernel"])
         return (p[:, :d] * p[:, 2 * d:]).astype(self.dt), p[:, d:2 * d]
 
     def _conv_out(self, h, layer, gate, c):
-        return h + _dot32((gate * c).astype(self.dt), layer["conv_out"]["kernel"])
+        return h + dot32((gate * c).astype(self.dt), layer["conv_out"]["kernel"])
 
     def _qkv(self, h, layer, positions):
         """q (n, H, e), k (n, K, e) float32, each normed over its ``e`` and
@@ -338,12 +305,12 @@ class Lfm2MoeBody:
         cfg, n = self.cfg, h.shape[0]
         with jax.named_scope("qkv"):
             a = self._norm(h, layer, "ln1").astype(self.dt)
-            q = _dot32(a, layer["q"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
-            k = _dot32(a, layer["k"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-            v = _dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-            q = rotary_half(_rmsnorm(q, layer["q_norm"]["scale"], cfg.norm_eps),
+            q = dot32(a, layer["q"]["kernel"]).reshape(n, cfg.n_heads, cfg.head_dim)
+            k = dot32(a, layer["k"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+            v = dot32(a, layer["v"]["kernel"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+            q = rotary_half(rmsnorm(q, layer["q_norm"]["scale"], cfg.norm_eps),
                             positions, cfg.rope_theta)
-            k = rotary_half(_rmsnorm(k, layer["k_norm"]["scale"], cfg.norm_eps),
+            k = rotary_half(rmsnorm(k, layer["k_norm"]["scale"], cfg.norm_eps),
                             positions, cfg.rope_theta)
             return q, k, v.astype(self.dt)
 
@@ -356,7 +323,7 @@ class Lfm2MoeBody:
 
     def _attn_out(self, h, layer, att):
         with jax.named_scope("attn_out"):
-            return h + _dot32(att.astype(self.dt).reshape(h.shape[0], -1), layer["o"]["kernel"])
+            return h + dot32(att.astype(self.dt).reshape(h.shape[0], -1), layer["o"]["kernel"])
 
     def _dense_mlp(self, h, layer):
         with jax.named_scope("dense_mlp"):
@@ -364,13 +331,10 @@ class Lfm2MoeBody:
             return h + swiglu(y, w["gate"], w["up"], w["down"])
 
     def _expert_mlp(self, h, layer, live, counts, phase: str, experts, index):
-        """The expert layer's part this chip holds.  ``counts`` gets this
-        layer's pairs, touched experts, computed rows and expert steps under
-        ``<phase>_*`` (a decode's steps are the batch form's, a chunk's the
-        grouped form's: none where the other form ran) and its load by held
-        expert.
-        ``experts``: the held experts of every expert layer, flat, this
-        layer's from ``index * experts_held``."""
+        """The expert layer's part this chip holds.  ``counts`` (``ops.moe``'s
+        ledger) gets this layer through ``count_routed``.  ``experts``: the
+        held experts of every expert layer, flat, this layer's from ``index *
+        experts_held``."""
         cfg = self.cfg
         with jax.named_scope("moe_router"):
             y32 = self._norm(h, layer, "ln2")
@@ -378,110 +342,59 @@ class Lfm2MoeBody:
                 y32, layer["router"]["kernel"], layer["router"]["bias"], cfg.experts_per_tok,
                 cfg.routed_scaling, eps=cfg.route_eps)
             mask, wmat = held_pairs(chosen, weights, cfg.expert_offset, cfg.experts_held, live)
-            load = mask.sum(axis=0).astype(jnp.int32)
-            counts = counts.at[len(COUNTERS):].add(load)
-            for name, n in (("pairs", load.sum()), ("touched", (load > 0).sum()),
-                            ("tile_rows", tile_rows(load, mask.shape[0]))):
-                counts = counts.at[COUNTERS.index(f"{phase}_{name}")].add(n.astype(jnp.int32))
-            steps = batch_steps if phase == "decode" else grouped_steps
-            counts = counts.at[COUNTERS.index(f"{phase}_expert_steps")].add(
-                steps(load, mask.shape[0]))
+            counts = count_routed(counts, mask, phase)
         with jax.named_scope("moe_experts"):
             return h + expert_layer(
                 y32.astype(self.dt), mask, wmat, experts["gate"], experts["up"], experts["down"],
                 first=index * cfg.experts_held, top_k=cfg.experts_per_tok,
                 impl=cfg.attn_impl), counts
 
-    def _layers(self, params, x, arrays, mixers: dict, live, phase: str):
-        """One ``_carry_loop`` a run of layers of one (mixer, feed-forward)
-        kind, each over ALL the pools (a run leaves the other kind's as they
-        came).  ``mixers[kind](h, layer, k, v, tails, l)`` is the step's mixer
-        of the ``l``-th layer of that kind and gives ``(h, k, v, tails)``."""
-        n_blocks, experts = arrays[0].shape[1], params["experts"]
-        seen, expert_layers = {"conv": 0, "full_attention": 0}, 0
-        for (kind, ff, n), run in zip(self.cfg.runs(), params["runs"]):
+    def _layers(self, params, x, arrays, live, phase: str, positions, slots, conv_step, attend):
+        """``blocks.pattern_layers`` over the runs: a step's layers, written
+        once for both steps.  ``conv_step(tails, s, taps, at)`` is the step's
+        short convolution (``ops.short_conv``), ``attend`` its paged K/V step,
+        ``slots`` its slot(s) of tails, ``live`` its rows that count."""
+        n_blocks, n_slots, experts = arrays[0].shape[1], arrays[2].shape[1], params["experts"]
 
-            def layer_fn(h, layer, k, v, tails, counts, base, mix=mixers[kind], ff=ff,
-                         first=seen[kind], index=expert_layers):
-                at = base // n_blocks  # the layer's place in its run
-                h, k, v, tails = mix(h, layer, k, v, tails, first + at)
-                if ff == "dense":
-                    h = self._dense_mlp(h, layer)
-                else:
-                    h, counts = self._expert_mlp(
-                        h, layer, live, counts, phase, experts, index + at)
-                return h, k, v, tails, counts
+        def conv(h, layer, k_pool, v_pool, tails, l):
+            with jax.named_scope("short_conv"):
+                s, gate = self._conv_in(h, layer)
+                with jax.named_scope("conv_update"):
+                    tails, c = conv_step(tails, s, layer["conv"]["kernel"], l * n_slots + slots)
+                return self._conv_out(h, layer, gate, c), k_pool, v_pool, tails
 
-            x, *arrays = _carry_loop(run, x, tuple(arrays), layer_fn)
-            seen[kind] += n
-            expert_layers += n * (ff == "moe")
-        counts = arrays[3].at[0, COUNTERS.index(f"{phase}s")].add(1)
-        return x, (*arrays[:3], counts)
+        def attention(h, layer, k_pool, v_pool, tails, l):
+            base = l * n_blocks
+            q, k, v = self._qkv(h, layer, positions)
+            att, k_pool, v_pool = attend(q, k, v, k_pool, v_pool, base)
+            return self._attn_out(h, layer, att), k_pool, v_pool, tails
 
-    # -- decode: one token of many sequences ---------------------------------
+        closings = {
+            "dense": lambda h, layer, counts, m: (self._dense_mlp(h, layer), counts),
+            "moe": lambda h, layer, counts, m: self._expert_mlp(
+                h, layer, live, counts, phase, experts, m)}
+        return pattern_layers(
+            self.cfg.runs(), params["runs"], x, arrays,
+            {"conv": conv, "full_attention": attention}, closings, phase)
 
     def decode(self, params, x, arrays, positions, tables):
-        """x: (S, d) embedded tokens at ``positions``; tables: (S, 1 + T).
-        Returns (hidden (S, d), arrays)."""
-        cfg = self.cfg
+        """One token of many sequences.  x: (S, d) embedded tokens at
+        ``positions``; tables: (S, 1 + T).  Returns (hidden (S, d), arrays)."""
         slots, btab = tables[:, 0], tables[:, 1:]
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        phys = jnp.take_along_axis(btab, (positions // bs)[:, None], axis=1)[:, 0]
-        write = _slots_write(phys, positions % bs, bs)
-
-        def conv(h, layer, k_pool, v_pool, tails, l):
-            with jax.named_scope("short_conv"):
-                s, gate = self._conv_in(h, layer)
-                with jax.named_scope("conv_update"):
-                    tails, c = short_conv_decode(
-                        tails, s, layer["conv"]["kernel"], l * n_slots + slots)
-                return self._conv_out(h, layer, gate, c), k_pool, v_pool, tails
-
-        def attention(h, layer, k_pool, v_pool, tails, l):
-            base = l * n_blocks
-            q, k, v = self._qkv(h, layer, positions)
-            k_pool = write(k_pool, self._packed(k), base)
-            v_pool = write(v_pool, self._packed(v), base)
-            with jax.named_scope("gqa_attention"):
-                att = gqa_paged_attention(
-                    q, k_pool, v_pool, btab + base, positions, impl=cfg.attn_impl)
-            return self._attn_out(h, layer, att), k_pool, v_pool, tails
-
+        attend = paged_kv_decode(arrays[0], btab, positions, self.cfg.attn_impl, self._packed)
         return self._layers(
-            params, x, arrays, {"conv": conv, "full_attention": attention}, slots > 0, "decode")
-
-    # -- prefill: a chunk of one sequence -------------------------------------
+            params, x, arrays, slots > 0, "decode", positions, slots, short_conv_decode, attend)
 
     def chunk(self, params, x, arrays, start, n_valid, table):
-        """x: (C, d) embedded tokens of ONE sequence at ``start ..``, the
-        first ``n_valid`` real; table: (1 + T,).  Returns (the last valid
-        token's hidden (1, d), arrays)."""
+        """A prefill chunk.  x: (C, d) embedded tokens of ONE sequence at
+        ``start ..``, the first ``n_valid`` real; table: (1 + T,).  Returns
+        (the last valid token's hidden (1, d), arrays)."""
         slot, btab = table[0], table[1:]
-        C = x.shape[0]
-        n_blocks, bs, n_slots = arrays[0].shape[1], arrays[0].shape[3], arrays[2].shape[1]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        write = _chunk_write(btab, start, n_valid, C, bs)
-
-        def conv(h, layer, k_pool, v_pool, tails, l):
-            with jax.named_scope("short_conv"):
-                s, gate = self._conv_in(h, layer)
-                with jax.named_scope("conv_update"):
-                    tails, c = short_conv_chunk(
-                        tails, s, layer["conv"]["kernel"], l * n_slots + slot, start == 0,
-                        n_valid)
-                return self._conv_out(h, layer, gate, c), k_pool, v_pool, tails
-
-        def attention(h, layer, k_pool, v_pool, tails, l):
-            base = l * n_blocks
-            q, k, v = self._qkv(h, layer, positions)
-            k_pool = write(k_pool, self._packed(k), base)
-            v_pool = write(v_pool, self._packed(v), base)
-            with jax.named_scope("chunk_attention"):
-                att = gqa_chunk_attention(
-                    q.astype(self.dt), k_pool, v_pool, btab + base, positions, start + n_valid)
-            return self._attn_out(h, layer, att), k_pool, v_pool, tails
-
+        positions = start + jnp.arange(x.shape[0], dtype=jnp.int32)
+        attend = paged_kv_chunk(arrays[0], btab, positions, start, n_valid, self._packed)
+        conv_step = lambda tails, s, taps, at: short_conv_chunk(  # noqa: E731
+            tails, s, taps, at, start == 0, n_valid)
         x, arrays = self._layers(
-            params, x, arrays, {"conv": conv, "full_attention": attention},
-            jnp.arange(C) < n_valid, "chunk")
-        return jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1), arrays
+            params, x, arrays, jnp.arange(x.shape[0]) < n_valid, "chunk", positions, slot,
+            conv_step, attend)
+        return last_valid(x, n_valid), arrays

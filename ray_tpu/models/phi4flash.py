@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.llm.model_runner import _carry_loop, _chunk_write, _slots_write
+from ray_tpu.models.blocks import dot32 as _dot32
 from ray_tpu.ops.diff_attention import (
     diff_combine,
     diff_dense_attention,
@@ -205,11 +206,6 @@ def _layernorm(x, ln, eps, dtype):
     out = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return (out * ln["scale"].astype(jnp.float32)
             + ln["bias"].astype(jnp.float32)).astype(dtype)
-
-
-def _dot32(x, kernel):
-    """x @ kernel in x's dtype on the MXU, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
 class Phi4FlashBody:

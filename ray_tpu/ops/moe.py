@@ -71,6 +71,11 @@ bfloat16 (18.9 MB) goes whole, every product one dot and every read
 contiguous; one of 3 x 7168 x 2048 (88 MB, Kimi-K2.5) goes in blocks of
 ``f``, and a row's partial sums of the down product are added to the
 accumulator block by block, in float32, in both forms.
+
+What a family's step programs COUNT of the layer on the device is here too,
+once for every family (``COUNTERS``, ``counters_shape``, ``count_routed``,
+``count_step``, ``read_counters``, below ``grouped_steps``): a body calls its
+router and ``held_pairs`` and hands the mask to ``count_routed``.
 """
 
 from __future__ import annotations
@@ -79,6 +84,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops.paged_attention import _on_tpu
 
@@ -171,6 +177,58 @@ def grouped_steps(load, n: int, tile: int = TILE):
     """The expert steps the GROUPED FORM makes: one a touched expert where
     ``n > tile``; none where the batch form runs."""
     return (load > 0).sum().astype(jnp.int32) * int(n > tile)
+
+
+# -- the routed layer's ledger ------------------------------------------------
+#
+# What the step programs of a family with an expert layer count ON THE DEVICE
+# (the router's load is known nowhere else): one int32 array ``(1,
+# len(COUNTERS) + held)`` rides every step beside the pools, the scalar
+# counters first, then the pairs by held expert.  ``stats()["moe"]`` is its
+# reading.  One more counter is one name here and one line in
+# ``count_routed``.
+
+#: ``stats()["moe"]``: the scalar counters, then ``load`` (one a held expert)
+COUNTERS = ("decode_pairs", "decode_touched", "decodes", "chunk_pairs", "chunks",
+            "decode_tile_rows", "decode_expert_steps", "chunk_touched", "chunk_tile_rows",
+            "chunk_expert_steps")
+
+
+def counters_shape(held: int) -> tuple:
+    """Shapes and dtypes of what the steps carry beside the pools."""
+    return (jax.ShapeDtypeStruct((1, len(COUNTERS) + held), jnp.int32),)
+
+
+def count_routed(counts, mask, phase: str):
+    """One expert layer of a ``decode`` or a ``chunk`` into the ledger
+    (``counts``: its flat view): the load by held expert and, under
+    ``<phase>_*``, the layer's pairs, its touched experts (what the step reads
+    of the held weights), the rows ``expert_layer`` computes for them
+    (``tile_rows``) and the expert steps of the form that runs (a decode's are
+    the batch form's, a chunk's the grouped form's: none where the other form
+    ran).  ``mask``: ``held_pairs``', so a dead row counts nowhere."""
+    n = mask.shape[0]
+    load = mask.sum(axis=0).astype(jnp.int32)
+    counts = counts.at[len(COUNTERS):].add(load)
+    for name, value in (("pairs", load.sum()), ("touched", (load > 0).sum()),
+                        ("tile_rows", tile_rows(load, n))):
+        counts = counts.at[COUNTERS.index(f"{phase}_{name}")].add(value.astype(jnp.int32))
+    steps = batch_steps if phase == "decode" else grouped_steps
+    return counts.at[COUNTERS.index(f"{phase}_expert_steps")].add(steps(load, n))
+
+
+def count_step(counts, phase: str):
+    """One more ``decode`` or ``chunk`` (``counts``: the array as it rides)."""
+    return counts.at[0, COUNTERS.index(f"{phase}s")].add(1)
+
+
+def read_counters(arrays) -> dict:
+    """``stats()``'s part from the fetched ledger: ``{"moe": {name: count,
+    ..., "load": [pairs by held expert]}}``."""
+    flat = np.asarray(arrays[0]).reshape(-1)
+    out = {name: int(flat[i]) for i, name in enumerate(COUNTERS)}
+    out["load"] = [int(x) for x in flat[len(COUNTERS):]]
+    return {"moe": out}
 
 
 def touched(mask):

@@ -14,6 +14,7 @@ itself with does so through ``announced_child`` (below), never a bare
 """
 
 import contextlib
+import dataclasses
 import faulthandler
 import glob
 import os
@@ -241,6 +242,21 @@ def tcp_head_child(reconnect_grace_s=None):
             os.environ.pop("RAY_TPU_AUTHKEY", None)
             if ray_tpu.is_initialized():
                 ray_tpu.shutdown()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _config_ends_with_the_file():
+    """``init(_system_config=...)`` writes the PROCESS's ``GLOBAL_CONFIG`` and
+    ``shutdown()`` leaves it so: a file's overrides end with the file, or the
+    next file on this xdist worker inherits them (test_worker_timeout's 2 s
+    registration deadline, met by test_gc_deadlock's eight spawns under a GC
+    storm on a loaded box, was ROADMAP D7's "load flake")."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+
+    found = {f.name: getattr(GLOBAL_CONFIG, f.name) for f in dataclasses.fields(GLOBAL_CONFIG)}
+    yield
+    for name, value in found.items():
+        setattr(GLOBAL_CONFIG, name, value)
 
 
 @pytest.fixture

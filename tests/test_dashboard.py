@@ -6,6 +6,8 @@ the Prometheus metrics agent (``dashboard/modules/reporter``).
 """
 
 import json
+import time
+import urllib.error
 import urllib.request
 
 import pytest
@@ -21,11 +23,32 @@ def dash(ray_start_regular):
     dashboard.stop()
 
 
-def _get(url, path):
-    with urllib.request.urlopen(url + path, timeout=10) as r:
+#: how long a case waits for the dashboard to answer, or for what it has just
+#: written to show in an answer: well inside ``conftest.TEST_LIMIT_S``
+DEADLINE_S = 120.0
+
+
+def _get(url, path, timeout=10):
+    with urllib.request.urlopen(url + path, timeout=timeout) as r:
         ctype = r.headers.get("Content-Type", "")
         body = r.read()
     return ctype, body
+
+
+def _until(url, path, ok):
+    """The JSON of ``path`` once ``ok(it)`` holds: polled to ``DEADLINE_S``,
+    a request that times out on a loaded box asked again.  What a case
+    asserts is then WHAT the dashboard answers, not how soon."""
+    end, seen = time.monotonic() + DEADLINE_S, None
+    while time.monotonic() < end:
+        try:
+            seen = json.loads(_get(url, path, timeout=30)[1])
+            if ok(seen):
+                return seen
+        except (urllib.error.URLError, TimeoutError) as e:
+            seen = e
+        time.sleep(0.2)
+    raise AssertionError(f"{path} never answered as expected in {DEADLINE_S} s; last: {seen!r}"[:2000])
 
 
 def test_index_and_version(dash):
@@ -175,22 +198,18 @@ def test_observability_endpoints(dash):
     for v in (0.05, 0.5, 2.0):
         h.observe(v)
     um.flush()
-    _, body = _get(dash, "/api/percentiles")
-    pcts = json.loads(body)
+    pcts = _until(dash, "/api/percentiles", lambda p: any(
+        snap["count"] == 3 for snap in p.get("dash_lat_s", {}).values()))
     snap = next(iter(pcts["dash_lat_s"].values()))
     assert snap["count"] == 3 and snap["p50"] > 0
 
     events.record("dash.test_event", request_id="dash-rid-1", n=7)
     events.record("dash.other")
-    _, body = _get(dash, "/api/events?tail=50")
-    evs = json.loads(body)
-    assert any(e["type"] == "dash.test_event" for e in evs)
-    _, body = _get(dash, "/api/events?request_id=dash-rid-1")
-    only = json.loads(body)
-    assert only and all(e.get("request_id") == "dash-rid-1" for e in only)
+    _until(dash, "/api/events?tail=50",
+           lambda evs: any(e["type"] == "dash.test_event" for e in evs))
+    only = _until(dash, "/api/events?request_id=dash-rid-1", bool)
+    assert all(e.get("request_id") == "dash-rid-1" for e in only)
 
-    _, body = _get(dash, "/api/request?id=dash-rid-1")
-    req = json.loads(body)
-    assert any(e["type"] == "dash.test_event" and e["n"] == 7 for e in req)
-    _, body = _get(dash, "/api/request")
-    assert "error" in json.loads(body)
+    _until(dash, "/api/request?id=dash-rid-1",
+           lambda req: any(e["type"] == "dash.test_event" and e["n"] == 7 for e in req))
+    assert "error" in _until(dash, "/api/request", bool)

@@ -63,10 +63,11 @@ def test_actor_spawn_under_gc_storm(ray_start_regular):
         def ping(self):
             return 1
 
-    stop = threading.Event()
+    stop, rounds = threading.Event(), [0]
 
     def storm():
         while not stop.is_set():
+            rounds[0] += 1
             refs = [ray_tpu.put(b"x" * 64) for _ in range(32)]
             cyc = []
             for r in refs:
@@ -92,8 +93,13 @@ def test_actor_spawn_under_gc_storm(ray_start_regular):
 
         w = threading.Thread(target=spawn_and_call, daemon=True)
         w.start()
-        w.join(timeout=180)
+        # under the test's own limit (conftest.TEST_LIMIT_S), so that a wedge
+        # fails HERE, with this message; a worker's registration deadline is
+        # the configuration's 30 s, whatever an earlier file on this xdist
+        # worker set (conftest._config_ends_with_the_file)
+        w.join(timeout=150)
         assert ok, "actor spawn wedged under GC storm (__del__ deadlock?)"
+        assert rounds[0] > 0, "the storm never ran beside the spawns"
     finally:
         stop.set()
         gc.set_threshold(*old)

@@ -220,6 +220,14 @@ def test_phi_is_the_symmetric_square_and_its_padding_is_zero():
 # -- the engine around it -------------------------------------------------------------
 
 
+@pytest.fixture(scope="module")
+def engine():
+    """ONE engine at ``ENGINE``'s sizes for the cases that serve through it,
+    its two programs compiled once a module.  Its counters only grow: a case
+    reads what ITS requests added."""
+    return LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+
+
 def _drive(eng, reqs, serial=False):
     while not all(r.finished for r in reqs):
         eng.step()
@@ -230,24 +238,23 @@ def _drive(eng, reqs, serial=False):
 
 
 @pytest.mark.parametrize("sampled", [False, True])
-def test_launch_ahead_and_the_serial_path_give_the_same_tokens(sampled):
+def test_launch_ahead_and_the_serial_path_give_the_same_tokens(engine, sampled):
     knobs = dict(temperature=0.8, top_k=12, top_p=0.9) if sampled else {}
-    outs = []
+    outs, eng = [], engine
     for serial in (False, True):
-        eng = LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+        before = eng.stats()["pipeline"]["ahead_steps"]
         reqs = [eng.submit(_prompt(10 + i, 20 + 9 * i),
                            SamplingParams(max_tokens=14 + i, seed=i, **knobs))
                 for i in range(6)]  # more than the slots: two wait their turn
         outs.append(_drive(eng, reqs, serial))
-        pipe = eng.stats()["pipeline"]
-        assert (pipe["ahead_steps"] > 0) != serial
+        assert (eng.stats()["pipeline"]["ahead_steps"] > before) != serial
         audit = eng.pool.audit()
         assert audit["ok"] and audit["owned"] == 0 and audit["free"] == SLOTS
     assert outs[0] == outs[1]
 
 
-def test_served_tokens_are_the_references_choice():
-    eng = LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+def test_served_tokens_are_the_references_choice(engine):
+    eng = engine
     reqs = [eng.submit(_prompt(20 + i, 30 + 11 * i), SamplingParams(max_tokens=8))
             for i in range(3)]
     for req, out in zip(reqs, _drive(eng, reqs)):

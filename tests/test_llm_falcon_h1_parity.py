@@ -33,6 +33,7 @@ from ray_tpu.llm.cache import HybridConfig, HybridPool  # noqa: E402
 from ray_tpu.llm.model_runner import host_batch, pack_knobs  # noqa: E402
 from ray_tpu.llm.scheduler import SamplingParams  # noqa: E402
 from ray_tpu.llm.state_runner import HybridModelRunner  # noqa: E402
+from ray_tpu.models.blocks import Mamba2  # noqa: E402
 from ray_tpu.models.falcon_h1 import (  # noqa: E402
     FalconH1Body,
     FalconH1Config,
@@ -128,22 +129,35 @@ def test_the_kernels_interpreted_serve_the_same_logits():
 # small method of the body: the served programs hold no such switch.
 
 
-class _NormBeforeGate(FalconH1Body):
+def _with_mixer(mixer):
+    """A body whose Mamba-2 mixer (``models.blocks.Mamba2``, shared with
+    Granite-4.0-H) is the subclass ``mixer`` of it, at the body's own sizes."""
+
+    class Planted(FalconH1Body):
+        def __init__(self, cfg):
+            super().__init__(cfg)
+            self.ssm = mixer(**{f.name: getattr(self.ssm, f.name)
+                                for f in dataclasses.fields(Mamba2)})
+
+    Planted.__name__ = mixer.__name__
+    return Planted
+
+
+class _NormBeforeGate(Mamba2):
     """``mamba_norm_before_gate`` true: RMSNorm_grouped(y) . silu(z)."""
 
-    def _ssm_out(self, y, z, layer):
-        cfg = self.cfg
-        g = y.reshape(z.shape[0], cfg.n_groups, -1)
-        g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + cfg.rms_norm_eps)
+    def out(self, y, z, layer):
+        g = y.reshape(z.shape[0], self.n_groups, -1)
+        g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + self.eps)
         out = g.reshape(z.shape) * layer["ssm_norm"]["scale"] * jax.nn.silu(z)
         return jnp.dot(out, layer["ssm_out"]["kernel"])
 
 
-class _OneGroup(FalconH1Body):
+class _OneGroup(Mamba2):
     """Every head reads group 0's B and C."""
 
-    def _conv(self, window, layer):
-        x, b, c = super()._conv(window, layer)
+    def conv(self, window, layer):
+        x, b, c = super().conv(window, layer)
         return x, jnp.broadcast_to(b[:, :1], b.shape), jnp.broadcast_to(c[:, :1], c.shape)
 
 
@@ -157,8 +171,9 @@ class _MultipliersShifted(FalconH1Body):
 
     def __init__(self, cfg):
         super().__init__(cfg)
-        self.mup = dataclasses.replace(
-            cfg, ssm_multipliers=cfg.ssm_multipliers[1:] + cfg.ssm_multipliers[:1]).mup_vector()
+        shifted = dataclasses.replace(
+            cfg, ssm_multipliers=cfg.ssm_multipliers[1:] + cfg.ssm_multipliers[:1])
+        self.ssm = dataclasses.replace(self.ssm, in_scale=shifted.mup_vector())
 
 
 class _KeysUnscaled(FalconH1Body):
@@ -176,7 +191,8 @@ class _StateNotCarried(FalconH1Body):
 
 
 @pytest.mark.parametrize("broken", [
-    _NormBeforeGate, _OneGroup, _NoRotary, _MultipliersShifted, _KeysUnscaled, _StateNotCarried])
+    _with_mixer(_NormBeforeGate), _with_mixer(_OneGroup), _NoRotary, _MultipliersShifted,
+    _KeysUnscaled, _StateNotCarried], ids=lambda b: b.__name__)
 def test_one_broken_thing_fails(broken):
     class Config(FalconH1Config):
         def serving_body(self):
@@ -205,7 +221,7 @@ def test_the_mup_vector_lies_over_the_five_segments_in_order():
     body = cfg.serving_body()
     u = jnp.asarray(np.random.default_rng(0).normal(size=(2, 64)), jnp.float32)
     layer = jax.tree_util.tree_map(lambda a: a[0], _params()["blocks"])
-    z, raw, step = body._ssm_in(u, layer)
+    z, raw, step = body.ssm.project(u * cfg.ssm_in_multiplier, layer)
     p = np.asarray((u * cfg.ssm_in_multiplier) @ layer["ssm_in"]["kernel"])
     np.testing.assert_allclose(z, p[:, :64] * 2.0, rtol=1e-5)
     np.testing.assert_allclose(raw[:, :64], p[:, 64:128] * 3.0, rtol=1e-5)
@@ -222,7 +238,7 @@ def test_the_gate_comes_before_a_norm_within_each_group():
     scale = rng.normal(size=64)
     eye = {"ssm_norm": {"scale": jnp.asarray(scale, jnp.float32)},
            "ssm_out": {"kernel": jnp.eye(64, dtype=jnp.float32)}}
-    got = body._ssm_out(jnp.asarray(y, jnp.float32), jnp.asarray(z, jnp.float32), eye)
+    got = body.ssm.out(jnp.asarray(y, jnp.float32), jnp.asarray(z, jnp.float32), eye)
     gated = y.reshape(2, 64) * (z / (1.0 + np.exp(-z)))
     want = np.empty((2, 64))
     for g in range(2):  # 2 groups of 32 channels, each normalised by its own mean square

@@ -37,14 +37,15 @@ from ray_tpu.llm.cache import HybridConfig, HybridPool  # noqa: E402
 from ray_tpu.llm.model_runner import host_batch, pack_knobs  # noqa: E402
 from ray_tpu.llm.scheduler import SamplingParams  # noqa: E402
 from ray_tpu.llm.state_runner import HybridModelRunner  # noqa: E402
+from ray_tpu.models.blocks import Mamba2  # noqa: E402
 from ray_tpu.models.granite_h import (  # noqa: E402
-    COUNTERS,
     PERIOD,
     GraniteHBody,
     GraniteHConfig,
     granite_h_init,
 )
 from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.ops.moe import COUNTERS  # noqa: E402
 from ray_tpu.ops.gqa_attention import rotary_half  # noqa: E402
 
 TOL = 1e-3
@@ -166,13 +167,20 @@ class _ScaleRootOfTheHead(GraniteHBody):
 
 
 class _NormBeforeGate(GraniteHBody):
-    """RMSNorm(y) . silu(z) and not RMSNorm(y . silu(z))."""
+    """RMSNorm(y) . silu(z) and not RMSNorm(y . silu(z)): planted in the
+    shared mixer (``models.blocks.Mamba2``) this body holds."""
 
-    def _ssm_out(self, y, z, layer):
-        g = y.reshape(z.shape)
-        g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + self.cfg.rms_norm_eps)
-        return jnp.dot(g * layer["ssm_norm"]["scale"] * jax.nn.silu(z),
-                       layer["ssm_out"]["kernel"])
+    class Mixer(Mamba2):
+        def out(self, y, z, layer):
+            g = y.reshape(z.shape)
+            g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + self.eps)
+            return jnp.dot(g * layer["ssm_norm"]["scale"] * jax.nn.silu(z),
+                           layer["ssm_out"]["kernel"])
+
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        self.ssm = self.Mixer(**{f.name: getattr(self.ssm, f.name)
+                                 for f in dataclasses.fields(Mamba2)})
 
 
 @pytest.mark.parametrize("broken", [
@@ -654,7 +662,15 @@ def _drive(eng, reqs):
     return [list(r.out) for r in reqs]
 
 
-def test_the_served_path_preempted_and_resumed_matches_the_reference():
+@pytest.fixture(scope="module")
+def engine():
+    """ONE roomy engine at ``ENGINE``'s sizes for the cases that only serve
+    through it, its programs compiled once a module.  Its counters only grow:
+    a case reads what ITS requests added."""
+    return LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+
+
+def test_the_served_path_preempted_and_resumed_matches_the_reference(engine):
     """``LLMEngine`` itself, several requests side by side over several
     chunks each.  Few blocks: sequences growing past them are preempted
     (recompute: the next first chunk overwrites a slot) and must give the
@@ -662,8 +678,9 @@ def test_the_served_path_preempted_and_resumed_matches_the_reference():
     ``TOL`` of the reference's largest logit at its position."""
     prompts = [_prompt(30 + i, 12 + 5 * i) for i in range(4)]
     outs = []
-    for blocks in (SLOTS * TABLE + 1, 26):
-        eng = LLMEngine(TINY, _params(), EngineConfig(**dict(ENGINE, num_blocks=blocks)))
+    tight = LLMEngine(TINY, _params(), EngineConfig(**dict(ENGINE, num_blocks=26)))
+    for blocks, eng in ((SLOTS * TABLE + 1, engine), (26, tight)):
+        assert eng.cfg.num_blocks == blocks
         reqs = [eng.submit(p, SamplingParams(max_tokens=40)) for p in prompts]
         outs.append(_drive(eng, reqs))
         stats = eng.stats()
@@ -707,12 +724,13 @@ def test_stats_moe_and_both_pools_count_what_a_hand_count_gives():
     assert state_n["chunks"] == 3 and state_n["chunk_tokens"] == len(prompt)
 
 
-def test_chunks_alone_count_no_step_of_the_batch_form():
+def test_chunks_alone_count_no_step_of_the_batch_form(engine):
     """``decode_expert_steps`` is the decodes': a request that ends with its
     prompt's last chunk has made none, whatever form its chunks took."""
-    eng = LLMEngine(TINY, _params(), EngineConfig(**ENGINE))
+    eng = engine
+    before = eng.stats()["moe"]
     eng.generate(_prompt(41, 19), SamplingParams(max_tokens=1))
-    moe_n = eng.stats()["moe"]
+    moe_n = {name: n - before[name] for name, n in eng.stats()["moe"].items() if name != "load"}
     assert moe_n["chunks"] == 3 and moe_n["chunk_pairs"] > 0
     assert moe_n["decodes"] == moe_n["decode_touched"] == moe_n["decode_expert_steps"] == 0
 

@@ -34,7 +34,6 @@ from ray_tpu.llm.model_runner import pack_knobs  # noqa: E402
 from ray_tpu.llm.scheduler import SamplingParams  # noqa: E402
 from ray_tpu.llm.state_runner import HybridModelRunner  # noqa: E402
 from ray_tpu.models.kimi_k2 import (  # noqa: E402
-    COUNTERS,
     KimiK2Config,
     kimi_k2_init,
     softmax_scale,
@@ -42,6 +41,7 @@ from ray_tpu.models.kimi_k2 import (  # noqa: E402
 )
 from ray_tpu.ops import latent_attention as la  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.ops.moe import COUNTERS  # noqa: E402
 
 TOL = 1e-4
 TINY = KimiK2Config(
@@ -129,6 +129,14 @@ def _engine(**over):
     return LLMEngine(TINY, _params(), EngineConfig(**dict(ENGINE, prefix_cache=False, **over)))
 
 
+@pytest.fixture(scope="module")
+def engine():
+    """ONE roomy engine without a prefix cache for the cases that only serve
+    through it, its programs compiled once a module.  Its counters only grow:
+    a case reads what ITS requests added."""
+    return _engine()
+
+
 def _deficits(prompt, out):
     """The reference's largest logit less its logit of the engine's token,
     at every output position (the benchmark's own statistic)."""
@@ -137,9 +145,9 @@ def _deficits(prompt, out):
     return logits.max(-1) - logits[np.arange(len(out)), np.asarray(out)]
 
 
-def test_a_preempted_sequence_is_recomputed_to_the_same_tokens():
+def test_a_preempted_sequence_is_recomputed_to_the_same_tokens(engine):
     prompts = [_prompt(10 + i, 17 + 3 * i) for i in range(3)]
-    roomy = _engine()
+    roomy = engine
     want = [roomy.generate(p, SamplingParams(max_tokens=20)) for p in prompts]
     # 3 sequences of up to 43 tokens (11 blocks each) in 22 blocks: the youngest goes
     tight = _engine(num_blocks=23)
@@ -156,8 +164,8 @@ def test_a_preempted_sequence_is_recomputed_to_the_same_tokens():
 # -- the radix prefix cache on latent blocks -------------------------------------------
 
 
-def test_a_prefix_hit_and_a_forked_partial_block_give_the_cold_tokens():
-    cold = _engine()
+def test_a_prefix_hit_and_a_forked_partial_block_give_the_cold_tokens(engine):
+    cold = engine
     head = _prompt(30, 22)
     a, b = head + _prompt(31, 5), head[:18] + _prompt(32, 9)   # b parts INSIDE a block
     want = [cold.generate(p, SamplingParams(max_tokens=10)) for p in (a, a, b)]
@@ -483,11 +491,12 @@ def test_stats_moe_counts_what_a_hand_count_gives():
     assert pool_n["block_tokens"] == BLOCK and pool_n["blocks"] == SLOTS * TABLE
 
 
-def test_chunks_alone_count_no_step_of_the_batch_form():
+def test_chunks_alone_count_no_step_of_the_batch_form(engine):
     """``decode_expert_steps`` is the decodes': a request that ends with its
     prompt's last chunk has made none."""
-    eng = _engine()
+    eng = engine
+    before = eng.stats()["moe"]
     eng.generate(_prompt(41, 19), SamplingParams(max_tokens=1))
-    moe_n = eng.stats()["moe"]
+    moe_n = {name: n - before[name] for name, n in eng.stats()["moe"].items() if name != "load"}
     assert moe_n["chunks"] == 3 and moe_n["chunk_pairs"] > 0
     assert moe_n["decodes"] == moe_n["decode_touched"] == moe_n["decode_expert_steps"] == 0

@@ -3,8 +3,15 @@
     python3 tools/program_hashes.py [--out FILE] [--only CONFIG,...]
 
 A PR that adds a family or touches a shared op shows with it that the programs
-the benchmark already had are the ones they were: run this file on the parent
-and on the change and compare the outputs.  No chip: a CPU process.
+the benchmark already had are the ones they were.  ``tools/program_hashes.txt``
+is this file's ``--out`` on the tree as committed: a PR runs ``python3
+tools/program_hashes.py --out FILE`` on its own tree, ``diff``s FILE against
+the committed reading, says in its notes why each line that differs does (a
+program it meant to change, and in what), and commits FILE as the new
+``tools/program_hashes.txt``: one reading in the tree, not one a PR.  Where a
+difference has to be read line by line, run the tool on the parent's archive
+too (from any path) and compare the two programs' texts.  No chip: a CPU
+process, about a minute.
 
 What is hashed is the program and not where its source lies: ``as_text()``
 leaves the StableHLO's locations out, but a Mosaic kernel rides in its custom
