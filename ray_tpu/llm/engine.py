@@ -422,6 +422,13 @@ class LLMEngine:
         self.model_cfg = model_cfg
         if self.cfg.spec_k < 0:
             raise ValueError("spec_k must be >= 0")
+        if callable(params):
+            # a caller that would hold nothing but a second reference hands
+            # the tree over (``serve.llm``: ``[tree].pop``): this frame's is
+            # then the only one, and the ``del params`` below (the paged
+            # runners, which keep q / k / v in another form) lets go of the
+            # given kernels BEFORE the pool is made beside them
+            params = params()
         cache_cfg = CacheConfig(
             num_blocks=self.cfg.num_blocks,
             block_size=self.cfg.block_size,
@@ -474,6 +481,7 @@ class LLMEngine:
                 model_cfg, params, self.cfg.block_size,
                 attn_impl=self.cfg.attn_impl, tp=self.cfg.tp,
             )
+            del params
             self.pool = ShardedKVBlockPool(
                 cache_cfg,
                 n_layers=model_cfg.n_layers,
@@ -486,6 +494,7 @@ class LLMEngine:
             self.runner = PagedModelRunner(
                 model_cfg, params, self.cfg.block_size, attn_impl=self.cfg.attn_impl
             )
+            del params
             self.pool = KVBlockPool(
                 cache_cfg,
                 n_layers=model_cfg.n_layers,
@@ -526,7 +535,7 @@ class LLMEngine:
         # HBM ledger inputs fixed at init: params/drafter footprints never
         # change size (update_weights validates identical leaf shapes),
         # and the pool arrays are allocated once
-        self._params_bytes = _tree_device_bytes(params)
+        self._params_bytes = _tree_device_bytes(self.runner.params)
         self._drafter_bytes = _tree_device_bytes(
             getattr(self._drafter, "_params", None)
         )
@@ -885,9 +894,11 @@ class LLMEngine:
         ``rlhf.sync.apply_weight_update`` wraps this for chunked
         object-plane pushes).
 
-        The new pytree must match the current one's structure and leaf
-        shapes; leaves arrive in any float dtype (a learner pushes its
-        fp32 masters) and are cast to the resident leaf's dtype — then the
+        The new pytree must match the structure and leaf shapes of the
+        tree the engine was BUILT from (``runner.given``; the resident one
+        may keep it in another form); leaves arrive in any float dtype (a
+        learner pushes its fp32 masters) and are cast to the dtype the
+        engine was given — then the
         jitted step functions never retrace (they cache on shape and
         dtype, and params are a traced argument, not a captured
         constant). Leaves are ``device_put`` once here so steady-state
@@ -904,7 +915,10 @@ class LLMEngine:
         """
         import jax
 
-        resident = self.runner.params  # structure/shapes/dtypes never change
+        # what the runner was GIVEN, abstractly: its resident tree may hold
+        # the same weights in another form (q / k / v as one leaf), which
+        # prepare_params below makes of the new tree as it did of the first
+        resident = self.runner.given  # structure/shapes/dtypes never change
         old_struct = jax.tree_util.tree_structure(resident)
         new_struct = jax.tree_util.tree_structure(params)
         if old_struct != new_struct:
@@ -924,8 +938,8 @@ class LLMEngine:
             lambda new, cur: new if new.dtype == cur.dtype else new.astype(cur.dtype),
             params, resident,
         )
-        # prepare_params owns placement: plain device conversion single-
-        # chip, sharded device_put (+ fused-qkv permutation) under tp>1 —
+        # prepare_params owns form and placement: device conversion and the
+        # packed q / k / v on one chip, their column shards under tp>1 —
         # either way the swap lands with the compiled steps' exact layout
         new = self.runner.prepare_params(params)
         t0 = time.perf_counter()

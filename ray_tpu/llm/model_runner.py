@@ -412,6 +412,11 @@ def _prefill_sample(logits, sampling):
     )
 
 
+@jax.jit
+def _concat_last(*parts):
+    return jnp.concatenate(parts, axis=-1)
+
+
 class StepRunner:
     """What every runner of jitted steps shares, whatever its sequences
     hold on the device: the compile marker, the first-call record that
@@ -423,6 +428,12 @@ class StepRunner:
         compile_cache.ensure_compile_cache()
         self.cfg = cfg
         self.params = params
+        #: the tree this runner was GIVEN, as shapes and dtypes (no arrays):
+        #: what a later tree is held to (``LLMEngine.update_weights``),
+        #: whatever form ``prepare_params`` keeps it in on the device
+        self.given = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)), params
+        )
         self._compiled: set = set()  # (fn, shape-key)s already traced
         #: site -> (jitted fn, abstract operands, static kwargs) of its
         #: first call: what kernels_in_steps lowers again, so the report
@@ -474,12 +485,12 @@ class StepRunner:
         )
 
     def prepare_params(self, params: dict) -> dict:
-        """Normalize a (new) weight tree to the placement the compiled
-        steps expect.  Single-chip that is just host->device conversion;
-        the tensor-parallel runner overrides this with its sharded
-        ``device_put`` (plus the fused-qkv column permutation), and
-        ``LLMEngine.update_weights`` routes every hot-swap through here
-        so swapped weights land exactly like the originals."""
+        """A tree of ``given``'s structure in the form and placement the
+        compiled steps expect.  Here that is host->device conversion; the
+        paged runners also keep q / k / v as one leaf, the tensor-parallel
+        one shards; a runner's ``__init__`` and every hot-swap
+        (``LLMEngine.update_weights``) go through here, so swapped weights
+        land exactly like the originals."""
         return jax.tree_util.tree_map(jnp.asarray, params)
 
     def _call(self, site: str, fn, key: Any, *args, **static):
@@ -552,31 +563,51 @@ class PagedModelRunner(StepRunner):
         )
         self._verify = jax.jit(self._verify_impl, donate_argnums=(1, 2))
         self._fork = jax.jit(_fork_impl, donate_argnums=(0, 1))
+        self.params = self.prepare_params(params)
+
+    # -- the weights as the steps read them ----------------------------------
+
+    def prepare_params(self, params: dict) -> dict:
+        """The GIVEN tree as the compiled steps take it: on the device, and
+        a GPT-J layer's ``q`` / ``k`` / ``v`` kernels ``(L, d, d)`` as ONE
+        leaf ``attn_qkv.kernel`` ``(L, d, 3d)``, ``[Q | K | V]`` along its
+        last axis as ``arch="gpt"`` is given it, so that ``_qkv_rows`` is
+        one product whose operand is the layer loop's slice of the stacked
+        leaf.  (Three products of three sliced leaves each cost a v5e a
+        copy of the slice into fast memory and a re-laid copy of that: 2.4
+        ms of a 12.3 ms decode, PERF.md section 6, PR 65.)  The three are
+        not in the resident tree: it holds the given tree's bytes, once."""
+        blocks = dict(params["blocks"])
+        if self.arch == "gptj":
+            parts = [blocks.pop(m)["kernel"] for m in "qkv"]
+            blocks["attn_qkv"] = {"kernel": self._pack(parts)}
+        return super().prepare_params(dict(params, blocks=blocks))
+
+    def _pack(self, parts: list) -> jax.Array:
+        """``parts`` side by side along their last axis, on the device.
+        Waited for: when this returns, parts that nothing else holds are
+        gone, so the engine's pool is made beside ONE copy of them."""
+        return jax.block_until_ready(_concat_last(*map(jnp.asarray, parts)))
 
     # -- shared layer math -------------------------------------------------
 
     def _qkv_rows(self, layer, h, positions):
-        """h: (n, d) post-ln hidden → q/k/v (n, heads, hd), rotary applied
-        for gptj."""
+        """h: (n, d) post-ln hidden → q/k/v (n, heads, hd): ONE product
+        against ``attn_qkv`` (this device's ``[Q | K | V]`` columns), its
+        bias for gpt, rotary applied for gptj."""
         cfg = self.cfg
         dt = h.dtype
-        n = h.shape[0]
         nh, hd = self.n_local_heads, cfg.head_dim
         with jax.named_scope("qkv"):
+            qkv = h @ layer["attn_qkv"]["kernel"].astype(dt)
+            if self.arch == "gpt":
+                qkv = qkv + layer["attn_qkv"]["bias"].astype(dt)
+            q, k, v = (
+                x.reshape(h.shape[0], nh, hd) for x in jnp.split(qkv, 3, axis=-1)
+            )
             if self.arch == "gptj":
-                q = (h @ layer["q"]["kernel"].astype(dt)).reshape(n, nh, hd)
-                k = (h @ layer["k"]["kernel"].astype(dt)).reshape(n, nh, hd)
-                v = (h @ layer["v"]["kernel"].astype(dt)).reshape(n, nh, hd)
                 q = _rotary_rows(q, positions, cfg.rotary_dim)
                 k = _rotary_rows(k, positions, cfg.rotary_dim)
-            else:
-                qkv = h @ layer["attn_qkv"]["kernel"].astype(dt) + layer["attn_qkv"][
-                    "bias"
-                ].astype(dt)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(n, nh, hd)
-                k = k.reshape(n, nh, hd)
-                v = v.reshape(n, nh, hd)
         return q, k, v
 
     def _mlp(self, layer, h):

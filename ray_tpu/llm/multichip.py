@@ -12,8 +12,9 @@ Megatron column/row split, chosen so the PAGED layout shards for free:
   heads), so the host-side ledger, block tables, prefix-cache radix
   tree, watchdog ``audit()`` and CoW fork bookkeeping are untouched —
   the only sharded thing is the payload.
-* **Attention** — per-head math never crosses heads: q/k/v projections
-  are column-parallel (each device computes its own heads), the paged
+* **Attention** — per-head math never crosses heads: the q/k/v projection
+  is column-parallel (ONE leaf ``attn_qkv`` whose shard on device i is
+  ``[Q_i | K_i | V_i]``, ``_pack``: each device computes its own heads), the paged
   gather/scatter and softmax run on the local head group, and only the
   output projection is row-parallel (one reduction per layer).
 * **MLP** — ``mlp_in`` column-parallel, ``mlp_out`` row-parallel,
@@ -71,6 +72,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm.cache import CacheConfig, KVBlockPool
@@ -98,7 +100,8 @@ def _tp_sum(x: jax.Array, axis: str, noted: list) -> jax.Array:
 
 
 def _spec_for(path) -> P:
-    """Megatron split by param path: q/k/v + mlp_in column-parallel
+    """Megatron split by param path: q/k/v (as a tree is GIVEN them, and
+    the runner's one ``attn_qkv``) + mlp_in column-parallel
     (output dim sharded, biases ride along), attn_out + mlp_out
     row-parallel (input dim sharded, replicated biases added after
     the reduction), everything else replicated."""
@@ -200,14 +203,17 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         *,
         tp: int,
     ):
-        super().__init__(cfg, params, block_size, attn_impl)
         if tp < 1:
             raise ValueError(f"tp must be >= 1, got {tp}")
         if cfg.n_heads % tp:
             raise ValueError(f"n_heads={cfg.n_heads} not divisible by tp={tp}")
         if cfg.d_ff % tp:
             raise ValueError(f"d_ff={cfg.d_ff} not divisible by tp={tp}")
+        # before the base class, whose last act is ``prepare_params``
         self.tp = tp
+        self._mesh = make_tp_mesh(tp)
+        self._concat_columns = jax.jit(self._concat_columns_impl)
+        super().__init__(cfg, params, block_size, attn_impl)
         #: site -> {calls, bytes} of ``_tp_sum`` in one execution of that
         #: step program, noted when it was traced (``_layers``)
         self._tp_sums: dict = {}
@@ -215,11 +221,9 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
         #: this thread have noted (the engine's thread traces a first call
         #: while a ``device_report()`` lowers the others again)
         self._tracing = threading.local()
-        self._mesh = make_tp_mesh(tp)
         # the inherited bodies reshape to this many heads: the ones whose
         # kernels' column shards live on this device
         self.n_local_heads = cfg.n_heads // tp
-        self.params = self.prepare_params(params)
         weights = (self._param_spec_tree(),)
         # re-jit the inherited bodies over the mesh (the base jits were
         # never traced); donation contract is the base class's — the
@@ -261,44 +265,57 @@ class TensorParallelPagedModelRunner(PagedModelRunner):
             lambda path, _leaf: _spec_for(path), self.params
         )
 
-    def _shuffle_qkv(self, x: jax.Array) -> jax.Array:
-        """GPT's fused qkv projection lays its last axis out ``[Q|K|V]``;
-        plain column sharding would hand device i a slice of Q spilling
-        into K.  Permute host-side to the concat over devices of
-        ``[Q_i|K_i|V_i]`` so each device's contiguous shard splits
-        locally into its own head group's q/k/v (shape preserved, so
-        ``update_weights`` leaf validation is unaffected)."""
-        d = x.shape[-1] // 3
-        dl = d // self.tp
-        q, k, v = jnp.split(x, 3, axis=-1)
-        parts = []
-        for i in range(self.tp):
-            sl = slice(i * dl, (i + 1) * dl)
-            parts.extend([q[..., sl], k[..., sl], v[..., sl]])
-        return jnp.concatenate(parts, axis=-1)
+    def _pack(self, parts: list) -> jax.Array:
+        """``parts`` (a layer's Q, K and V columns, each whole) as ONE
+        column-parallel leaf whose last axis is the concatenation over
+        devices of ``[Q_i | K_i | V_i]``: plain column sharding of ``[Q | K
+        | V]`` would hand device i a slice of Q spilling into K, where
+        this shard splits locally into its own head group's q / k / v
+        (``_qkv_rows``).  Each part goes to its column shards (host ->
+        shards directly; a part that ``param_shardings`` placed stays
+        where it is) and a device concatenates its own three: nothing is
+        gathered, no device ever holds a whole part."""
+        sharding = NamedSharding(self._mesh, self._columns(jnp.ndim(parts[0])))
+        return jax.block_until_ready(
+            self._concat_columns(*(jax.device_put(x, sharding) for x in parts))
+        )
+
+    @staticmethod
+    def _columns(rank: int) -> P:
+        return P(*(None,) * (rank - 1), "tp")
+
+    def _concat_columns_impl(self, *parts):
+        """Each device's own column shards side by side."""
+        spec = self._columns(parts[0].ndim)
+        return jax.shard_map(
+            lambda *xs: jnp.concatenate(xs, axis=-1), mesh=self._mesh,
+            in_specs=(spec,) * len(parts), out_specs=spec,
+        )(*parts)
 
     def prepare_params(self, params: dict) -> dict:
-        """Sharded ``device_put`` of a (new) weight tree — the
-        ``update_weights`` hot-swap path and __init__ share it, so a
-        swap lands with the exact placement the compiled steps expect
-        (no silent retrace; RL024's runtime twin watches this)."""
+        """The given tree on the mesh, each leaf by ``_spec_for``, q / k /
+        v as ONE leaf ``attn_qkv`` by ``_pack``: GPT-J's three kernels,
+        GPT's fused ``[Q | K | V]`` kernel and bias by their thirds."""
+        blocks = dict(params["blocks"])
+        if self.arch == "gptj":
+            fused = {"kernel": [blocks.pop(m)["kernel"] for m in "qkv"]}
+        else:  # thirds of a host leaf are views: nothing is staged whole
+            fused = {
+                slot: (jnp.split if isinstance(x, jax.Array) else np.split)(x, 3, axis=-1)
+                for slot, x in blocks.pop("attn_qkv").items()
+            }
         # leaves go host -> their shards directly: staging a whole leaf on
         # the default device first would not fit a model tp exists for
-        new = params
-        if self.arch == "gpt":
-            new = dict(new)
-            blocks = dict(new["blocks"])
-            qkv = dict(blocks["attn_qkv"])
-            qkv["kernel"] = self._shuffle_qkv(qkv["kernel"])
-            qkv["bias"] = self._shuffle_qkv(qkv["bias"])
-            blocks["attn_qkv"] = qkv
-            new["blocks"] = blocks
-        return jax.tree_util.tree_map_with_path(
+        new = jax.tree_util.tree_map_with_path(
             lambda path, leaf: jax.device_put(
                 leaf, NamedSharding(self._mesh, _spec_for(path))
             ),
-            new,
+            dict(params, blocks=blocks),
         )
+        new["blocks"]["attn_qkv"] = {
+            slot: self._pack(parts) for slot, parts in fused.items()
+        }
+        return new
 
     def per_device_param_bytes(self) -> dict:
         """device-id label -> param bytes resident there (column/row

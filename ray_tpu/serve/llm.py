@@ -147,15 +147,22 @@ class LLMDeployment:
                 _, draft_params = _build_model(
                     model, draft_model_cfg, None, seed
                 )
-        _startup.stamp_when_ready(jax.tree_util.tree_leaves(params))
+        # one leaf stands for all (a seeded tree is ONE program's results):
+        # the waiter thread must not keep alive the kernels a runner re-packs
+        _startup.stamp_when_ready(jax.tree_util.tree_leaves(params)[-1:])
         #: max wait for the next streamed token — must cover the ADMISSION
         #: wait of a request queued behind a saturated engine, not just
         #: inter-token gaps (the engine's own 60s default is too tight for
         #: a deployment whose whole point is absorbing a deep queue)
         self._stream_timeout_s = stream_timeout_s
         with _startup.phase("engine_init"):
+            # the engine TAKES the tree (``[tree].pop``: no reference stays
+            # in this frame), so that kernels its runner keeps in another
+            # form are on the device once when the pool is made, not twice
+            handed = [params]
+            del params
             self._engine = LLMEngine(
-                cfg, params, engine_config,
+                cfg, handed.pop, engine_config,
                 draft_model_cfg=draft_model_cfg, draft_params=draft_params,
             )
             # per-engine watchdog (llm.watchdog): stall detection, wedge-proof

@@ -364,6 +364,47 @@ def test_update_weights_sharded_hot_swap(tp):
     assert eng.generate(list(PROMPT), GREEDY) == want
 
 
+@pytest.mark.parametrize("arch", ["gptj", "gpt"])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_packed_qkv_shard_is_its_head_groups_q_k_v(tp, arch):
+    """``attn_qkv``'s shard on device ``i`` is ``[Q_i | K_i | V_i]``, the
+    columns of head group ``i``: GPT-J's three given kernels, or the thirds
+    of GPT's fused ``[Q | K | V]`` kernel and bias, whether the runner was
+    handed host leaves or shards that ``param_shardings`` placed."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.multichip import TensorParallelPagedModelRunner, param_shardings
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+
+    cfg, init = TINY, gptj_init
+    if arch == "gpt":
+        cfg, init = GPTConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
+                              seq_len=64, dtype="float32"), gpt_init
+    given = init(jax.random.PRNGKey(3), cfg)
+    blocks = jax.device_get(given["blocks"])
+    if arch == "gptj":
+        parts = {"kernel": [blocks[m]["kernel"] for m in "qkv"]}
+        placed = jax.device_put(given, param_shardings(given, tp))
+    else:
+        parts = {slot: np.split(x, 3, axis=-1) for slot, x in blocks["attn_qkv"].items()}
+        placed = given  # the fused leaf has no column placement to be born in
+    w = cfg.d_model // tp
+    for tree in (jax.device_get(given), placed):
+        runner = TensorParallelPagedModelRunner(cfg, tree, 4, "xla", tp=tp)
+        for slot, (q, k, v) in parts.items():
+            leaf = runner.params["blocks"]["attn_qkv"][slot]
+            assert leaf.shape == q.shape[:-1] + (3 * cfg.d_model,)
+            shards = sorted(leaf.addressable_shards, key=lambda sh: sh.index[-1].start)
+            assert len(shards) == tp
+            for i, shard in enumerate(shards):
+                cols = slice(i * w, (i + 1) * w)
+                np.testing.assert_array_equal(
+                    np.asarray(shard.data),
+                    np.concatenate([q[..., cols], k[..., cols], v[..., cols]], axis=-1),
+                )
+
+
 def test_divisibility_validation():
     from ray_tpu.llm.cache import CacheConfig
     from ray_tpu.llm.multichip import (

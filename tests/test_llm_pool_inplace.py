@@ -239,6 +239,7 @@ def _reference(arch, step):
     ops, rows = _operands(step)
     k_pool, v_pool = _noise_pools(1, bs)
     ref = PagedModelRunner(cfg, params, bs, "xla")
+    params = ref.params  # as the helpers take it (q / k / v one leaf)
     pos, phys, off = (jnp.asarray(a, jnp.int32) for a in rows)
     n = pos.shape[0]
     tokens = jnp.asarray(ops[0]).reshape(-1)

@@ -506,7 +506,7 @@ class TestWeightSwapFlush:
         ref = off.generate(prompt, GREEDY)
         eng.generate(prompt, GREEDY)
         assert eng.prefix_cache.stats()["nodes"] > 0
-        eng.update_weights(eng.runner.params)          # same params, new version
+        eng.update_weights(tiny_params)                # same params, new version
         assert eng.prefix_cache.stats()["nodes"] == 0  # stale KV dropped
         assert eng.pool.num_free_blocks == eng.pool.cfg.num_blocks - 1
         assert eng.generate(prompt, GREEDY) == ref     # recomputed, identical
