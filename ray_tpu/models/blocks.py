@@ -22,9 +22,10 @@ never as a flag:
 * ``normal_layers`` and ``gated_mlp_init``: what the seeded initializers
   draw alike (the SAME draws as each family's own copy made);
 * ``pattern_layers``: the layer loop of a body whose layers come in RUNS of
-  one (mixer, closing) kind: one ``_carry_loop`` a run over ALL the pools and
-  the routed layer's ledger (``ops.moe``), the running index of each kind,
-  the step's count at the end.
+  one (mixer, closing) kind, the closing ABSENT (None) where a layer is a
+  mixer alone: one ``_carry_loop`` a run over ALL the pools and the routed
+  layer's ledger (``ops.moe``), the running index of each kind, the step's
+  count at the end.
 """
 
 from __future__ import annotations
@@ -283,17 +284,21 @@ def pattern_layers(runs, stacks, x, arrays, mixers: dict, closings: dict, phase:
     the routed layer's ledger (``ops.moe``), which gets the step's count at
     the end.  ``mixers[mixer](h, layer, *pools, l) -> (h, *pools)`` is the
     ``l``-th layer of that mixer, ``closings[closing](h, layer, counts, m) ->
-    (h, counts)`` the ``m``-th of that closing.  Returns (x, arrays)."""
+    (h, counts)`` the ``m``-th of that closing.  A run whose ``closing`` is
+    None has layers that are a mixer alone: nothing is called for a closing,
+    and nothing of it is counted.  Returns (x, arrays)."""
     n_blocks = arrays[0].shape[1]
     mixed, closed = collections.Counter(), collections.Counter()
     for (mixer, closing, n), stack in zip(runs, stacks):
 
-        def layer_fn(h, layer, *carry, mix=mixers[mixer], close=closings[closing],
+        def layer_fn(h, layer, *carry, mix=mixers[mixer],
+                     close=closing and closings[closing],
                      first=mixed[mixer], index=closed[closing]):
             *pools, counts, base = carry
             at = base // n_blocks  # the layer's place in its run
             h, *pools = mix(h, layer, *pools, first + at)
-            h, counts = close(h, layer, counts, index + at)
+            if close:
+                h, counts = close(h, layer, counts, index + at)
             return (h, *pools, counts)
 
         x, *arrays = _carry_loop(stack, x, tuple(arrays), layer_fn)

@@ -12,6 +12,14 @@ held`` and computes THEIR part of the result: ``sum over chosen AND held e
 of w_e * Expert_e(x)``.  What the absent experts would add is left out (it
 is the other chips' part); nothing here stands in for them.
 
+An expert is one of TWO forms, and its weights, not a flag, choose
+(``expert_mlp``): three matrices ``(gate, up, down)`` are the gated SiLU form
+``(silu(x W_gate) . (x W_up)) W_down`` (``swiglu``: Kimi-K2.5, Granite-4.0-H,
+LFM2), two ``(up, down)`` the UNGATED ``relu(x W_up)**2 W_down`` (``relu2``:
+Nemotron-3-Nano).  ``expert_layer`` takes the matrices there are, and its two
+kernels, their block specs, ``block_f`` and the VMEM they ask for count them;
+below, "swiglu" stands for either.
+
 No capacity and no drop: every (token, held expert) pair the router chose is
 computed, whatever the load's shape (``models.gpt._moe_mlp``, the training
 path's layer, drops what exceeds a fixed capacity and so matches no
@@ -66,7 +74,10 @@ PR 45-61 did a tile of 64, without its sort and its search).
 
 The kernels' weight blocks are cut along ``f`` alone (``block_f``): the
 widest multiple of 128 lanes that divides ``f`` and keeps two buffers of the
-three matrices' blocks inside ``VMEM_BUDGET``.  An expert of 3 x 4096 x 768
+expert's matrices' blocks inside ``VMEM_BUDGET`` (all of ``f`` where that
+fits: Nemotron's 2 x 2688 x 1920 goes whole; its published 1,856 is STORED in
+whole lane rows, since XLA copies an array whose last axis is off them into a
+padded layout before a kernel reads it).  An expert of 3 x 4096 x 768
 bfloat16 (18.9 MB) goes whole, every product one dot and every read
 contiguous; one of 3 x 7168 x 2048 (88 MB, Kimi-K2.5) goes in blocks of
 ``f``, and a row's partial sums of the down product are added to the
@@ -96,7 +107,7 @@ TILE = 64
 #: height (on a v5e blocks of 32, 64 and 128 rows read alike, PR 62: a touched
 #: expert's step is its weights' reads; a taller block only adds padding)
 BLOCK_ROWS = 128
-#: VMEM the kernels' weight blocks may take: two buffers of the three
+#: VMEM the kernels' weight blocks may take: two buffers of an expert's
 #: matrices' blocks (a v5e has 128 MiB; the batch kernel's ``x`` and
 #: accumulator are under 2 MB beside them, the grouped kernel's rows,
 #: accumulator and one block of each 8 x (512 + 128) x d bytes: 37 MB at
@@ -142,12 +153,27 @@ def held_pairs(chosen, weights, offset: int, held: int, live):
     return local.any(axis=1), (local * weights[:, :, None]).sum(axis=1)
 
 
+def _dot(a, k):
+    return jnp.dot(a, k.astype(a.dtype), preferred_element_type=jnp.float32)
+
+
 def swiglu(x, gate, up, down):
     """``(silu(x gate) * (x up)) down``: products on x's dtype, sums, the
     activation and the result in float32."""
-    dot = lambda a, k: jnp.dot(  # noqa: E731
-        a, k.astype(a.dtype), preferred_element_type=jnp.float32)
-    return dot((jax.nn.silu(dot(x, gate)) * dot(x, up)).astype(x.dtype), down)
+    return _dot((jax.nn.silu(_dot(x, gate)) * _dot(x, up)).astype(x.dtype), down)
+
+
+def relu2(x, up, down):
+    """``relu(x up)**2 down``, the UNGATED expert: products on x's dtype,
+    sums, the activation and the result in float32."""
+    return _dot(jnp.square(jax.nn.relu(_dot(x, up))).astype(x.dtype), down)
+
+
+def expert_mlp(x, *weights):
+    """An expert's function, as its WEIGHTS say: three matrices ``(gate, up,
+    down)`` are ``swiglu``, two ``(up, down)`` are ``relu2``.  Every form of
+    ``expert_layer`` calls this and nothing else of an expert."""
+    return swiglu(x, *weights) if len(weights) == 3 else relu2(x, *weights)
 
 
 def row_block(n: int) -> int:
@@ -244,11 +270,13 @@ def touched(mask):
     return jnp.minimum(ids, jnp.where(hit, at, 0).max()), upto[-1]
 
 
-def block_f(d: int, f: int, itemsize: int, budget: int = VMEM_BUDGET) -> int:
-    """Columns of ``f`` a grid step of the batch kernel holds of each matrix:
-    all of ``f`` where two buffers of the three ``d x f`` matrices fit the
-    budget, else the widest multiple of 128 that divides ``f`` and does."""
-    fits = lambda bf: 2 * 3 * d * bf * itemsize <= budget  # noqa: E731
+def block_f(d: int, f: int, itemsize: int, budget: int = VMEM_BUDGET, matrices: int = 3) -> int:
+    """Columns of ``f`` a grid step of the kernels holds of each matrix: all
+    of ``f`` where two buffers of an expert's ``matrices`` ``d x f`` matrices
+    fit the budget, else the widest multiple of 128 that divides ``f`` and
+    does (an ``f`` no 128 divides, 1,856, has no such cut: it goes whole or
+    not at all)."""
+    fits = lambda bf: 2 * matrices * d * bf * itemsize <= budget  # noqa: E731
     if fits(f):
         return f
     cuts = [bf for bf in range(128, f, 128) if f % bf == 0 and fits(bf)]
@@ -257,29 +285,30 @@ def block_f(d: int, f: int, itemsize: int, budget: int = VMEM_BUDGET) -> int:
     return cuts[-1]
 
 
-def _batch_xla(x, wsel, ids, count, gate, up, down, first):
-    """The batch form in plain ``jax.numpy``: one ``swiglu`` of ALL rows a
+def _batch_xla(x, wsel, ids, count, weights, first):
+    """The batch form in plain ``jax.numpy``: one ``expert_mlp`` of ALL rows a
     touched expert, weighed by its column ``wsel[e]`` (E, N)."""
     at = lambda k, e: jax.lax.dynamic_index_in_dim(  # noqa: E731
         k, first + e, 0, keepdims=False)
 
     def one_expert(i, out):
         e = ids[i]
-        y = swiglu(x, at(gate, e), at(up, e), at(down, e))
+        y = expert_mlp(x, *(at(k, e) for k in weights))
         return out + jax.lax.dynamic_index_in_dim(wsel, e, 0, keepdims=False)[:, None] * y
 
     return jax.lax.fori_loop(0, count, one_expert, jnp.zeros(x.shape, jnp.float32))
 
 
-def _batch_kernel(ids_ref, meta_ref, x_ref, w_ref, gate_ref, up_ref, down_ref, o_ref):
+def _batch_kernel(ids_ref, meta_ref, x_ref, w_ref, *refs):
     """One (touched expert, block of ``f``): ``x_ref`` (N, d) and ``o_ref``
-    (N, d) float32 stay in VMEM through the whole grid; ``w_ref`` (1, N, 1) is
-    the expert's weight column, ``gate_ref`` / ``up_ref`` (1, d, bf) and
-    ``down_ref`` (1, bf, d) its blocks.  ``swiglu`` on the block: the
-    activation is elementwise in ``f``, the down product's partial sums add in
-    float32."""
+    (N, d) float32 (the last of ``refs``) stay in VMEM through the whole grid;
+    ``w_ref`` (1, N, 1) is the expert's weight column, the other ``refs`` its
+    matrices' blocks: ``gate`` (where it has one) and ``up`` (1, d, bf),
+    ``down`` (1, bf, d).  ``expert_mlp`` on the block: either activation is
+    elementwise in ``f``, the down product's partial sums add in float32."""
     from jax.experimental import pallas as pl
 
+    *weight_refs, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -288,11 +317,12 @@ def _batch_kernel(ids_ref, meta_ref, x_ref, w_ref, gate_ref, up_ref, down_ref, o
 
     @pl.when(i < meta_ref[0])
     def _():
-        o_ref[...] += w_ref[0] * swiglu(x_ref[...], gate_ref[0], up_ref[0], down_ref[0])
+        o_ref[...] += w_ref[0] * expert_mlp(x_ref[...], *(r[0] for r in weight_refs))
 
 
-def _weight_specs(d: int, bf: int, steps: int):
-    """The block specs of an expert's three matrices over a grid of (listed
+def _weight_specs(d: int, bf: int, steps: int, matrices: int):
+    """The block specs of an expert's ``matrices`` matrices (the last is
+    ``down``, the others read ``x``'s columns) over a grid of (listed
     expert ``i``, block ``j`` of ``f``) whose first two scalar-prefetch
     operands are the compacted list ``ids`` and ``meta = (count, first)``:
     expert ``ids[i]`` of this layer; a step past the list's end names the
@@ -310,20 +340,20 @@ def _weight_specs(d: int, bf: int, steps: int):
         e, b = place(i, j, ids, meta)
         return e, b, 0
 
-    return [pl.BlockSpec((1, d, bf), columns), pl.BlockSpec((1, d, bf), columns),
+    return [*(pl.BlockSpec((1, d, bf), columns) for _ in range(matrices - 1)),
             pl.BlockSpec((1, bf, d), rows_of)]
 
 
-def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool,
+def _batch_pallas(x, wsel, ids, count, weights, first, *, interpret: bool,
                   bf: int | None = None):
     """The batch form as ONE kernel over the compacted list.  Rows are padded
     to whole sublane tiles of ``x``'s dtype (16 rows of bfloat16 are one)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (n, d), experts, f = x.shape, wsel.shape[0], gate.shape[-1]
-    size = gate.dtype.itemsize
-    bf = bf or block_f(d, f, size)
+    (n, d), experts, f, m = x.shape, wsel.shape[0], weights[0].shape[-1], len(weights)
+    size = weights[0].dtype.itemsize
+    bf = bf or block_f(d, f, size, matrices=m)
     pad = -n % (32 // x.dtype.itemsize)
     x = jnp.pad(x, ((0, pad), (0, 0)))
     w = jnp.pad(wsel, ((0, 0), (0, pad)))[:, :, None]
@@ -334,7 +364,7 @@ def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool
         in_specs=[
             pl.BlockSpec((rows, d), lambda i, j, ids, meta: (0, 0)),
             pl.BlockSpec((1, rows, 1), lambda i, j, ids, meta: (ids[i], 0, 0)),
-            *_weight_specs(d, bf, f // bf),
+            *_weight_specs(d, bf, f // bf, m),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i, j, ids, meta: (0, 0)),
     )
@@ -344,12 +374,12 @@ def _batch_pallas(x, wsel, ids, count, gate, up, down, first, *, interpret: bool
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            # the three matrices' blocks, double-buffered, and the rest
-            vmem_limit_bytes=2 * 3 * d * bf * size + (16 << 20),
+            # the matrices' blocks, double-buffered, and the rest
+            vmem_limit_bytes=2 * m * d * bf * size + (16 << 20),
         ),
         interpret=interpret,
         name="moe_batch_experts",
-    )(ids, jnp.stack([count, jnp.asarray(first, jnp.int32)]), x, w, gate, up, down)
+    )(ids, jnp.stack([count, jnp.asarray(first, jnp.int32)]), x, w, *weights)
     return out[:n]
 
 
@@ -382,7 +412,7 @@ def expert_order(mask, wmat, top_k: int | None):
     return starts, counts, token, jnp.where(here, weight[None, :], 0.0).sum(axis=1)
 
 
-def _grouped_xla(x, ids, count, starts, counts, token, weight, gate, up, down, first):
+def _grouped_xla(x, ids, count, starts, counts, token, weight, weights, first):
     """The grouped form in plain ``jax.numpy``: a touched expert's pairs go
     through its weights a block of rows at a time, gathered by ``token`` and
     added back to their rows (a block's places past the expert's pairs are
@@ -394,14 +424,14 @@ def _grouped_xla(x, ids, count, starts, counts, token, weight, gate, up, down, f
 
     def one_expert(i, out):
         e = ids[i]
-        weights = at(gate, e), at(up, e), at(down, e)
+        mine = [at(k, e) for k in weights]
 
         def one_block(b, out):
             row0 = starts[e] + b * rb
             valid = b * rb + jnp.arange(rb, dtype=jnp.int32) < counts[e]
             rows = jax.lax.dynamic_slice_in_dim(token, row0, rb)
             w = jax.lax.dynamic_slice_in_dim(weight, row0, rb)
-            y = swiglu(x[rows], *weights)
+            y = expert_mlp(x[rows], *mine)
             return out.at[jnp.where(valid, rows, n)].add(y * w[:, None], mode="drop")
 
         return jax.lax.fori_loop(0, (counts[e] + (rb - 1)) // rb, one_block, out)
@@ -410,15 +440,14 @@ def _grouped_xla(x, ids, count, starts, counts, token, weight, gate, up, down, f
 
 
 def _grouped_kernel(ids_ref, meta_ref, start_ref, count_ref, token_ref, weight_ref,
-                    x_hbm, gate_ref, up_ref, down_ref, o_hbm, x_all, out, x_blk, y_blk, sem,
-                    *, dtype):
+                    x_hbm, *refs, dtype):
     """One (touched expert, block of ``f``).  ``x_all`` (N, d) float32, every
     row of the batch, comes into VMEM once and ``out`` (N, d) float32, the
     accumulator, leaves it once: neither is double-buffered.  The expert's
     pairs go through the weight blocks ``x_blk``'s rows at a time (a traced
     bound: as many blocks as it has pairs): their rows are copied out of
-    ``x_all`` by ``token``, row by row, ``swiglu`` runs on the block in the products'
-    ``dtype``, and each result row is added to ITS row of ``out`` times the
+    ``x_all`` by ``token``, row by row, ``expert_mlp`` runs on the block in
+    the products' ``dtype``, and each result row is added to ITS row of ``out`` times the
     router's weight -- experts come in order and a block of ``f`` after its
     predecessor, so a row's sum is the batch kernel's.  Rows of a block past
     the pairs hold what the block held before; theirs are results nobody
@@ -426,6 +455,7 @@ def _grouped_kernel(ids_ref, meta_ref, start_ref, count_ref, token_ref, weight_r
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    *weight_refs, o_hbm, x_all, out, x_blk, y_blk, sem = refs
     i, j, rb = pl.program_id(0), pl.program_id(1), x_blk.shape[0]
 
     @pl.when((i == 0) & (j == 0))
@@ -449,7 +479,7 @@ def _grouped_kernel(ids_ref, meta_ref, start_ref, count_ref, token_ref, weight_r
                 return carry
 
             jax.lax.fori_loop(0, rows, take, 0)
-            y_blk[...] = swiglu(x_blk[...].astype(dtype), gate_ref[0], up_ref[0], down_ref[0])
+            y_blk[...] = expert_mlp(x_blk[...].astype(dtype), *(r[0] for r in weight_refs))
 
             def give(r, carry):
                 to = pl.ds(token_ref[row0 + r], 1)
@@ -468,7 +498,7 @@ def _grouped_kernel(ids_ref, meta_ref, start_ref, count_ref, token_ref, weight_r
         copy.wait()
 
 
-def _grouped_pallas(x, ids, count, starts, counts, token, weight, gate, up, down, first, *,
+def _grouped_pallas(x, ids, count, starts, counts, token, weight, weights, first, *,
                     interpret: bool, bf: int | None = None):
     """The grouped form as ONE kernel over the compacted list: the weight
     blocks are the batch kernel's (expert ``i + 1``'s in flight while expert
@@ -477,14 +507,14 @@ def _grouped_pallas(x, ids, count, starts, counts, token, weight, gate, up, down
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (n, d), experts, f = x.shape, ids.shape[0], gate.shape[-1]
-    size = gate.dtype.itemsize
-    bf = bf or block_f(d, f, size)
+    (n, d), experts, f, m = x.shape, ids.shape[0], weights[0].shape[-1], len(weights)
+    size = weights[0].dtype.itemsize
+    bf = bf or block_f(d, f, size, matrices=m)
     rows, rb = n + -n % 8, row_block(n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(experts, f // bf),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY), *_weight_specs(d, bf, f // bf)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), *_weight_specs(d, bf, f // bf, m)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((rows, d), jnp.float32),
@@ -500,22 +530,24 @@ def _grouped_pallas(x, ids, count, starts, counts, token, weight, gate, up, down
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            # the three matrices' blocks, double-buffered, the batch's rows
-            # and their sums, a block of rows and its results, and the rest
-            vmem_limit_bytes=2 * 3 * d * bf * size + 2 * (rows + rb) * d * 4 + (16 << 20),
+            # the matrices' blocks, double-buffered, the batch's rows and
+            # their sums, a block of rows and its results, and the rest
+            vmem_limit_bytes=2 * m * d * bf * size + 2 * (rows + rb) * d * 4 + (16 << 20),
         ),
         interpret=interpret,
         name="moe_grouped_experts",
     )(ids, jnp.stack([count, jnp.asarray(first, jnp.int32)]), starts, counts, token, weight,
-      jnp.pad(x.astype(jnp.float32), ((0, rows - n), (0, 0))), gate, up, down)
+      jnp.pad(x.astype(jnp.float32), ((0, rows - n), (0, 0))), *weights)
     return out[:n]
 
 
-def expert_layer(x, mask, wmat, gate, up, down, *, first=0, top_k: int | None = None,
+def expert_layer(x, mask, wmat, *weights, first=0, top_k: int | None = None,
                  tile: int = TILE, impl: str = "auto"):
     """``sum_e wmat[:, e] * Expert_e(x)`` over exactly the pairs in ``mask``.
-    x: (N, d) in the products' dtype; mask, wmat: (N, E); gate, up: (.., d,
-    f), down: (.., f, d): expert e's weights at ``first + e`` (``first`` may
+    x: (N, d) in the products' dtype; mask, wmat: (N, E); ``weights``: the
+    experts' matrices, ``(gate, up, down)`` of a gated expert or ``(up,
+    down)`` of an ungated one (``expert_mlp``: the weights choose), gate, up:
+    (.., d, f), down: (.., f, d): expert e's weights at ``first + e`` (``first`` may
     be traced: the experts of EVERY layer in one array, so that a layer
     loop hands this one no slice of them -- XLA would copy it, all of a
     layer's experts a layer).  ``top_k``: the most pairs a row of ``mask``
@@ -531,7 +563,7 @@ def expert_layer(x, mask, wmat, gate, up, down, *, first=0, top_k: int | None = 
     plain = impl == "xla" or (impl == "auto" and not _on_tpu())
     ids, count = touched(mask)
     if n <= tile:
-        args = (x, jnp.where(mask, wmat, 0.0).T, ids, count, gate, up, down, first)
+        args = (x, jnp.where(mask, wmat, 0.0).T, ids, count, weights, first)
         return _batch_xla(*args) if plain else _batch_pallas(*args, interpret=not _on_tpu())
-    args = (x, ids, count, *expert_order(mask, wmat, top_k), gate, up, down, first)
+    args = (x, ids, count, *expert_order(mask, wmat, top_k), weights, first)
     return _grouped_xla(*args) if plain else _grouped_pallas(*args, interpret=not _on_tpu())

@@ -83,6 +83,8 @@ _FAMILIES = {
     "granite_h": ("ray_tpu.models.granite_h", "GraniteHConfig", "granite_h_init",
                   "GraniteHConfig"),
     "lfm2_moe": ("ray_tpu.models.lfm2", "Lfm2MoeConfig", "lfm2_moe_init", "Lfm2MoeConfig"),
+    "nemotron_h": ("ray_tpu.models.nemotron_h", "NemotronHConfig", "nemotron_h_init",
+                   "NemotronHConfig"),
 }
 
 

@@ -464,7 +464,7 @@ def test_the_kernel_in_interpret_mode_equals_the_plain_batch_form(d, f, bf, dtyp
     ids, count = moe.touched(mask)
     assert 2 <= int(count) <= 5
     args = (lay["x"], jnp.where(mask, wmat, 0.0).T, ids, count,
-            lay["gate"], lay["up"], lay["down"], 7)
+            (lay["gate"], lay["up"], lay["down"]), 7)
     plain = moe._batch_xla(*args)
     kernel = moe._batch_pallas(*args, interpret=True, bf=bf)
     # the same products on the same dtype; cut blocks add the down product's
@@ -542,7 +542,7 @@ def test_the_grouped_form_equals_the_uncut_masked_sum(case, impl, monkeypatch):
     the row did not choose it.  The kernel runs under the interpreter."""
     lay, mask, wmat, first, top_k = _grouped_case(case)
     if case == "f_in_blocks":                       # three blocks of f a touched expert
-        monkeypatch.setattr(moe, "block_f", lambda d, f, size: 16)
+        monkeypatch.setattr(moe, "block_f", lambda d, f, size, **_: 16)
     layer = jax.jit(lambda x, m, w, g, u, dn, first: moe.expert_layer(
         x, m, w, g, u, dn, first=first, top_k=top_k, impl=impl))
     got = layer(lay["x"], mask, wmat, lay["gate"], lay["up"], lay["down"], first)

@@ -237,13 +237,16 @@ def test_the_latent_chunk_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
 
 
 @pytest.mark.parametrize("rows", [16, 512], ids=["decode", "chunk"])
-@pytest.mark.parametrize("d,f,held,layers,top_k", [
-    (7168, 2048, 12, 6, 8),     # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
-    (4096, 768, 36, 10, 10),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
-    (2048, 1536, 64, 8, 4),     # lfm2-24b-a2b-l10-1chip: 512 experts of 18.9 MB, a layer's WHOLE
-], ids=["kimi", "granite_h", "lfm2_moe"])
+@pytest.mark.parametrize("d,f,held,layers,top_k,m", [
+    (7168, 2048, 12, 6, 8, 3),     # kimi-k2.5-ep32-l7-1chip: experts of 88 MB
+    (4096, 768, 36, 10, 10, 3),    # granite-4.0-h-small-ep2-l10-1chip: 360 experts of 18.9 MB
+    (2048, 1536, 64, 8, 4, 3),     # lfm2-24b-a2b-l10-1chip: 512 experts of 18.9 MB, a layer's WHOLE
+    # nemotron-3-nano-30b-ep8-1chip: 368 UNGATED experts (two matrices), the
+    # published f = 1,856 = 14.5 lane rows STORED as 15 (1,920: 20.6 MB)
+    (2688, 1920, 16, 23, 6, 2),
+], ids=["kimi", "granite_h", "lfm2_moe", "nemotron_h"])
 def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
-        one_chip, monkeypatch, d, f, held, layers, top_k, rows):
+        one_chip, monkeypatch, d, f, held, layers, top_k, m, rows):
     """Both forms index the experts of every layer where they lie: the expert
     layer at the published widths holds no temporary the size of an expert,
     let alone of a layer's held ones (the smallest layer here holds 679 MB).
@@ -262,35 +265,59 @@ def test_the_expert_layer_reads_no_copy_of_its_experts_on_a_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def layer(x, mask, wmat, gate, up, down, first):
-        return moe.expert_layer(x, mask, wmat, gate, up, down, first=first, top_k=top_k)
+    def layer(x, mask, wmat, first, *weights):
+        return moe.expert_layer(x, mask, wmat, *weights, first=first, top_k=top_k)
 
     n = held * layers
     compiled = jax.jit(layer).lower(
         sds((rows, d), jnp.bfloat16), sds((rows, held), jnp.bool_),
-        sds((rows, held), jnp.float32), sds((n, d, f), jnp.bfloat16),
-        sds((n, d, f), jnp.bfloat16), sds((n, f, d), jnp.bfloat16), sds((), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20 < 3 * d * f * 2
+        sds((rows, held), jnp.float32), sds((), jnp.int32),
+        *[sds((n, d, f), jnp.bfloat16)] * (m - 1), sds((n, f, d), jnp.bfloat16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20 < m * d * f * 2
     text = compiled.as_text()
     name = "moe_grouped_experts" if rows > moe.TILE else "moe_batch_experts"
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and name in calls[0]
     # granite's expert goes whole, kimi's in quarters of f; two buffers of the
-    # three blocks are what the budget is stated for
-    bf = moe.block_f(d, f, 2)
-    assert bf == {768: 768, 2048: 512, 1536: 1536}[f] and 2 * 3 * d * bf * 2 <= moe.VMEM_BUDGET
+    # matrices' blocks (three of a gated expert, two of an ungated one) are
+    # what the budget is stated for
+    bf = moe.block_f(d, f, 2, matrices=m)
+    assert bf == {768: 768, 2048: 512, 1536: 1536, 1920: 1920}[f]
+    assert 2 * m * d * bf * 2 <= moe.VMEM_BUDGET
     # ... and all the kernel's VMEM (its scoped limit): the blocks, the rows
     # and the accumulator (a chunk's: 512 x d float32 each) and the rest
     limit = int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
                           r'"size":"(\d+)"', calls[0]).group(1))
-    assert 2 * 3 * d * bf * 2 < limit < 128 * 2**20
+    assert 2 * m * d * bf * 2 < limit < 128 * 2**20
+
+
+def test_an_expert_width_off_the_lane_rows_is_copied_whole_on_a_v5e(one_chip, monkeypatch):
+    """Why Nemotron's experts are STORED at 1,920: Mosaic takes a block whose
+    last axis is 1,856 (= 14.5 x 128), but XLA lays such an array out in whole
+    lane rows for it first: a padded copy of every layer's ``W_up`` (3.8 GB)
+    stands among the temporaries of ONE expert layer."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+    d, f, n = 2688, 1856, 16 * 23
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda x, mask, wmat, up, down: moe.expert_layer(
+        x, mask, wmat, up, down, top_k=6)).lower(
+        sds((16, d), jnp.bfloat16), sds((16, 16), jnp.bool_), sds((16, 16), jnp.float32),
+        sds((n, d, f), jnp.bfloat16), sds((n, f, d), jnp.bfloat16)).compile()
+    assert "moe_batch_experts" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes >= n * d * 1920 * 2
 
 
 @pytest.mark.parametrize("h,p,n,slots,hb", [
     (32, 128, 256, 34, 8),        # falcon-h1-34b-l8-1chip
     (128, 64, 128, 9 * 17, 32),   # granite-4.0-h-small-ep2-l10-1chip: half a vreg's lanes a row
-], ids=["falcon_h1", "granite_h"])
+    (64, 64, 128, 23 * 17, 32),   # nemotron-3-nano-30b-ep8-1chip: 23 layers' slots, 8 groups
+], ids=["falcon_h1", "granite_h", "nemotron_h"])
 def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(
         one_chip, h, p, n, slots, hb):
     """The Mamba-2 update at each cell's sizes: 16 rows, ``h`` heads of ``p x
@@ -319,7 +346,8 @@ def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(
     (32, 8, 225, 14, 128),        # granite-4.0-h-small-ep2-l10-1chip: w = 4 over ONE layer
     # lfm2-24b-a2b-l10-1chip: 8 heads of 64 as 4 rows of 128 lanes, w = 8 over 2 layers
     (32, 8, 2 * 1601, 100, 64),
-], ids=["falcon_h1", "granite_h", "lfm2_moe"])
+    (32, 2, 6 * 1185, 74, 128),   # nemotron-3-nano-30b-ep8-1chip: w = 16 over a row of TWO heads
+], ids=["falcon_h1", "granite_h", "lfm2_moe", "nemotron_h"])
 def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
         one_chip, monkeypatch, heads, kv, blocks, tmax, e):
     """The decode attention of the grouped-query families: the query heads of
