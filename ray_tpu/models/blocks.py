@@ -92,8 +92,11 @@ class Mamba2:
         out = RMSNorm within each of G groups (y . silu(z)) W_out   (gate, THEN norm)
 
     ``in_scale``: a vector over ``W_in``'s columns (a muP vector), or None.
-    The conv tails ride as ``(layers x slots, d_conv - 1, conv_dim)``, the SSD
-    states as ``(layers x slots, H, P, N)``; ``at`` is the layer's slot(s)
+    A slot's conv tail is ONE row, its ``d_conv - 1`` inputs of ``conv_dim``
+    the oldest first: the tails ride as ``(layers x slots, (d_conv - 1) *
+    conv_dim)`` (an axis of 3 taps among a pool's minor two is one the
+    compiler may lay into the lanes, the pool then forty times its size), the
+    SSD states as ``(layers x slots, H, P, N)``; ``at`` is the layer's slot(s)
     there."""
 
     d_ssm: int
@@ -117,7 +120,7 @@ class Mamba2:
         """What a sequence holds of ``n`` such layers, for ``llm.cache.HybridPool``:
         name -> (layers, one slot's shape, dtype)."""
         return {
-            "conv": (n, (self.d_conv - 1, self.conv_dim), self.dtype),
+            "conv": (n, ((self.d_conv - 1) * self.conv_dim,), self.dtype),
             "ssd": (n, (self.heads, self.d_ssm // self.heads, self.d_state), state_dtype),
         }
 
@@ -158,16 +161,14 @@ class Mamba2:
         step = jax.nn.softplus(p[:, conv_end:] + layer["dt_bias"].astype(jnp.float32))
         return p[:, :z_end], p[:, z_end:conv_end].astype(self.dtype), step
 
-    def conv(self, window, layer):
-        """``window``: (..., d_conv + n - 1, conv_dim) inputs, the oldest
-        first -> SiLU of the causal depthwise convolution at the last ``n``,
+    def conv(self, taps, layer):
+        """``taps``: the ``d_conv`` inputs of each of ``n`` outputs, the oldest
+        first, each (n, conv_dim) -> SiLU of the causal depthwise convolution,
         float32, split into x (n, H, P), B and C (n, G, N)."""
-        n = window.shape[-2] - self.d_conv + 1
         x_end, b_end = self.d_ssm, self.d_ssm + self.n_groups * self.d_state
-        w32, kern = window.astype(jnp.float32), layer["conv"]["kernel"].astype(jnp.float32)
-        out = sum(w32[..., i:i + n, :] * kern[i] for i in range(self.d_conv))
+        kern = layer["conv"]["kernel"].astype(jnp.float32)
+        out = sum(t.astype(jnp.float32) * kern[i] for i, t in enumerate(taps))
         out = jax.nn.silu(out + layer["conv"]["bias"].astype(jnp.float32))
-        out = out.reshape(-1, self.conv_dim)
         rows = out.shape[0]
         return (out[:, :x_end].reshape(rows, self.heads, self.d_ssm // self.heads),
                 out[:, x_end:b_end].reshape(rows, self.n_groups, self.d_state),
@@ -189,9 +190,9 @@ class Mamba2:
         that is not ``live`` leaves its state alone.  Returns (the mixer's
         output (S, d) float32, conv, ssd)."""
         z, raw, step = self.project(u, layer)
-        window = jnp.concatenate([conv[at], raw[:, None, :]], axis=1)
-        conv = conv.at[at].set(window[:, 1:])
-        xs, b, c = self.conv(window, layer)
+        window = jnp.concatenate([conv[at], raw], axis=1)       # (S, d_conv * D), oldest first
+        conv = conv.at[at].set(window[:, self.conv_dim:])
+        xs, b, c = self.conv(jnp.split(window, self.d_conv, axis=1), layer)
         with jax.named_scope("ssd_update"):
             ssd, y = ssd_decode(ssd, xs, step, self.a(layer), b, c, layer["D"], at, live,
                                 impl=self.impl)
@@ -205,10 +206,10 @@ class Mamba2:
         float32, conv, ssd)."""
         z, raw, step = self.project(u, layer)
         tail = jnp.where(fresh, 0, jax.lax.dynamic_index_in_dim(conv, at, 0, False))
-        seq = jnp.concatenate([tail, raw], axis=0)              # (taps + C, D)
+        seq = jnp.concatenate([tail.reshape(-1, self.conv_dim), raw], axis=0)  # (taps + C, D)
         conv = jax.lax.dynamic_update_index_in_dim(
-            conv, jax.lax.dynamic_slice_in_dim(seq, n_valid, self.d_conv - 1), at, 0)
-        xs, b, c = self.conv(seq, layer)
+            conv, jax.lax.dynamic_slice_in_dim(seq, n_valid, self.d_conv - 1).reshape(-1), at, 0)
+        xs, b, c = self.conv([seq[i:i + len(raw)] for i in range(self.d_conv)], layer)
         with jax.named_scope("ssd_chunk"):
             s0 = jnp.where(fresh, 0.0, jax.lax.dynamic_index_in_dim(
                 ssd, at, 0, False).astype(jnp.float32))

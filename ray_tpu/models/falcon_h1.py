@@ -196,9 +196,10 @@ class FalconH1Body:
     """The family's traced layer programs for ``HybridModelRunner``.  The
     pools ride as ``HybridPool.arrays`` has them: ``(k, v, conv, ssd)``: K and
     V ``(L, blocks, K, block, e)``, the convolution's tails ``(L, slots + 1,
-    d_conv - 1, conv_dim)`` and the SSD states ``(L, slots + 1, H, P, N)``.  A
-    table row is ``[slot, block table...]``, slot 0 and block 0 the trash a
-    dead decode row and a padded chunk row write."""
+    (d_conv - 1) * conv_dim)``, a slot's ONE row (``blocks.Mamba2``), and the
+    SSD states ``(L, slots + 1, H, P, N)``.  A table row is ``[slot, block
+    table...]``, slot 0 and block 0 the trash a dead decode row and a padded
+    chunk row write."""
 
     def __init__(self, cfg: FalconH1Config):
         self.cfg = cfg

@@ -245,7 +245,8 @@ class GraniteHBody:
     """The family's traced layer programs for ``HybridModelRunner``.
     ``arrays`` is ``(k, v, conv, ssd, counters)``: K and V ``(attention
     layers, blocks, K, block, e)``, the convolution's tails ``(Mamba layers,
-    slots + 1, d_conv - 1, conv_dim)``, the SSD states ``(Mamba layers, slots
+    slots + 1, (d_conv - 1) * conv_dim)``, a slot's ONE row
+    (``blocks.Mamba2``), the SSD states ``(Mamba layers, slots
     + 1, H, P, N)`` and the device's own counts (``ops.moe.counters_shape``).
     A table row is ``[slot, block table...]``, slot 0 and block 0 the trash a
     dead decode row and a padded chunk row write; a dead row has no pair in

@@ -311,7 +311,7 @@ def test_the_ledger_with_three_kv_layers_and_two_state_leaves():
                       body.state_leaves(BLOCK))
     k, v, conv, ssd = pool.arrays
     assert k.shape == v.shape == (3, 41, 2, BLOCK, 8)
-    assert conv.shape == (3, 3, 3, 64 + 2 * 2 * 16) and ssd.shape == (3, 3, 4, 16, 16)
+    assert conv.shape == (3, 3, 3 * (64 + 2 * 2 * 16)) and ssd.shape == (3, 3, 4, 16, 16)
     # a block's bytes are its rows in EVERY layer, K and V
     assert pool.block_bytes == 3 * 2 * (2 * BLOCK * 8 * 4)
     assert pool.device_bytes == k.nbytes + v.nbytes + conv.nbytes + ssd.nbytes
@@ -423,7 +423,7 @@ def test_the_family_is_found_by_name_at_the_published_widths():
     assert body.kv_layout() == {"n_layers": 8, "n_heads": 4, "head_dim": 128,
                                 "dtype": "bfloat16"}
     leaves = body.state_leaves(128)
-    assert leaves == {"conv": (8, (3, 5120), "bfloat16"),
+    assert leaves == {"conv": (8, (3 * 5120,), "bfloat16"),
                       "ssd": (8, (32, 128, 256), "float32")}
     shapes = jax.eval_shape(lambda: falcon_h1_init(jax.random.PRNGKey(0), cfg))
     n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes))

@@ -625,7 +625,7 @@ def test_the_ledger_splits_the_caches_by_layer_kind(periods, kv_layers, state_la
                       body.state_leaves(BLOCK))
     k, v, conv, ssd = pool.arrays
     assert k.shape == v.shape == (kv_layers, 41, 2, BLOCK, 8)
-    assert conv.shape == (state_layers, 3, 3, 64 + 2 * 16)
+    assert conv.shape == (state_layers, 3, 3 * (64 + 2 * 16))
     assert ssd.shape == (state_layers, 3, 4, 16, 16)
     # a block's bytes are its rows in the ATTENTION layers alone, K and V
     assert pool.block_bytes == kv_layers * 2 * (2 * BLOCK * 8 * 4)
@@ -766,7 +766,7 @@ def test_the_family_is_found_by_name_at_the_published_widths():
     body = cfg.serving_body()
     assert body.kv_layout() == {"n_layers": 1, "n_heads": 8, "head_dim": 128,
                                 "dtype": "bfloat16"}
-    assert body.state_leaves(128) == {"conv": (9, (3, 8448), "bfloat16"),
+    assert body.state_leaves(128) == {"conv": (9, (3 * 8448,), "bfloat16"),
                                       "ssd": (9, (128, 64, 128), "float32")}
     assert body.q_scale == pytest.approx(128 ** -0.5)
     shapes = jax.eval_shape(lambda: granite_h_init(jax.random.PRNGKey(0), cfg))

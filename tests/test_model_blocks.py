@@ -115,7 +115,7 @@ def test_the_decode_step_is_the_recurrence_and_a_dead_row_leaves_its_state(layer
     got, conv, ssd = _decodes(mixer, layer, conv, ssd, u, at)
     want, tail, state = _recurrence(mixer, layer, u)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(conv[at], tail, rtol=1e-6)
+    np.testing.assert_allclose(conv[at], tail.reshape(-1), rtol=1e-6)
     np.testing.assert_allclose(ssd[at], state, rtol=2e-4, atol=1e-6)
     # the dead row's slot of state, and every other slot, is as it was
     others = np.delete(np.arange(ssd.shape[0]), at)
@@ -130,7 +130,7 @@ def test_the_chunk_step_is_the_recurrence_over_its_valid_rows(layer, scaled):
     got, conv, ssd = _chunk(mixer, layer, conv, ssd, u, at, fresh=True)
     want, tail, state = _recurrence(mixer, layer, u)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(conv[at], tail, rtol=1e-6)          # the last VALID inputs
+    np.testing.assert_allclose(conv[at], tail.reshape(-1), rtol=1e-6)  # the last VALID inputs
     np.testing.assert_allclose(ssd[at], state, rtol=2e-4, atol=1e-6)
     assert (np.asarray(ssd)[np.delete(np.arange(ssd.shape[0]), at)] == 3.0).all()
 
@@ -151,6 +151,27 @@ def test_a_chunk_followed_by_decodes_equals_decodes_alone(layer, slot):
     np.testing.assert_allclose(np.concatenate(got + [rest]), alone, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(conv[at], conv_a[at], rtol=1e-6)
     np.testing.assert_allclose(ssd[at], ssd_a[at], rtol=2e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("d_conv", [2, 4])
+def test_a_slot_s_row_holds_its_taps_oldest_first_and_a_decode_shifts_it_by_one_input(d_conv):
+    mixer = dataclasses.replace(MIXER, d_conv=d_conv)
+    stack = mixer.init(tuple(jax.random.split(jax.random.PRNGKey(1), 6)), LAYERS, D,
+                       D**-0.5, mixer.d_ssm**-0.5, (1.0, 4.0), (0.05, 0.5))
+    layer = jax.tree_util.tree_map(lambda a: a[0], stack)
+    u, at, taps, cd = _inputs(6, seed=3), 2, d_conv - 1, mixer.conv_dim
+    raw = np.asarray(mixer.project(jnp.asarray(u), layer)[1])       # what the convolution sees
+    conv, ssd = _pools(mixer, fill=3.0)
+    assert conv.shape == (LAYERS * (SLOTS + 1), taps * cd)          # ONE row a slot
+    _, conv, ssd = _chunk(mixer, layer, conv, ssd, u[:5], at, fresh=True)
+    row = np.asarray(conv[at])
+    np.testing.assert_allclose(row, raw[5 - taps:5].reshape(-1), rtol=1e-6)
+    _, after, _ = _decodes(mixer, layer, conv, ssd, u[5:], at)
+    after = np.asarray(after[at])
+    np.testing.assert_array_equal(after[:(taps - 1) * cd], row[cd:])  # by exactly conv_dim
+    np.testing.assert_allclose(after[(taps - 1) * cd:], raw[5], rtol=1e-6)
+    others = np.delete(np.arange(conv.shape[0]), [0, at])           # 0: the dead row's trash
+    assert (np.asarray(conv)[others] == 3.0).all()
 
 
 def test_rmsnorm_and_dot32_give_float32_whatever_comes_in():

@@ -8,6 +8,7 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU's library, and every xdist
 worker imports every test file.  All such tests live in THIS file."""
 
+import math
 import re
 
 import jax
@@ -367,3 +368,67 @@ def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
             sds((16, tmax), jnp.int32), sds((16,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+def _padded_bytes(dims, layout, itemsize):
+    """The bytes of an array of ``dims`` in a compiled program's ``layout``
+    (``minor to major:T(tile)...``): the tile's axes padded to whole tiles."""
+    order, _, tiles = layout.partition(":")
+    phys = [dims[int(i)] for i in order.split(",")][::-1]          # major to minor
+    tile = re.match(r"T\(([\d,]+)\)", tiles)
+    tile = [int(v) for v in tile.group(1).split(",")] if tile else []
+    for at, t in enumerate(tile, len(phys) - len(tile)):
+        phys[at] = -(-phys[at] // t) * t
+    return math.prod(phys) * itemsize
+
+
+def test_a_chunk_s_mamba_runs_carry_the_conv_tails_in_their_own_bytes_on_a_v5e(one_chip):
+    """Runs of Mamba-2 chunk steps at Nemotron-3's mixer widths, each run one
+    ``_carry_loop`` over the tails and the states with a plain op before the
+    next, as ``pattern_layers`` makes a prefill's.  A leaf of ``(3 taps,
+    conv_dim)`` a slot rides such loops with the 3 in the LANES, 128 / 3
+    times the pool, and is re-laid between them; a slot's tail as ONE row
+    leaves no axis of 3 to choose.  No form of the pool in the compiled text
+    passes four times its own bytes (the stored ``(layers, 17, row)`` pads 17
+    to 24)."""
+    from ray_tpu.llm.model_runner import _carry_loop
+    from ray_tpu.models.blocks import Mamba2, rmsnorm
+
+    runs, d, chunk, slots = (2, 1, 2, 1, 1), 2688, 512, 17
+    mixer = Mamba2(d_ssm=4096, heads=64, d_state=128, n_groups=8, d_conv=4, eps=1e-5,
+                   dtype=jnp.dtype("bfloat16"), sub=128, impl="pallas")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    keys = tuple(jax.random.split(jax.random.PRNGKey(0), 6))
+    stacks = [jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda n=n: mixer.init(keys, n, d, d**-0.5, 4096**-0.5, (1.0, 16.0), (0.001, 0.1))))
+        for n in runs]
+    leaves = mixer.state_leaves(sum(runs), "float32")
+    pools = [sds((n, slots, *shape), dt) for n, shape, dt in leaves.values()]
+
+    def step(stacks, x, conv, ssd, at, fresh, n_valid):
+        valid, first = jnp.arange(chunk) < n_valid, 0
+        for n, stack in zip(runs, stacks):
+
+            def layer_fn(h, layer, conv, ssd, base, first=first):
+                y, conv, ssd = mixer.chunk(
+                    h, layer, conv, ssd, first * slots + base + at, fresh, n_valid, valid)
+                return h + y, conv, ssd
+
+            x, conv, ssd = _carry_loop(stack, x, (conv, ssd), layer_fn)
+            x, first = rmsnorm(x, jnp.ones((d,)), 1e-5), first + n
+        return x, conv, ssd
+
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+        stacks, sds((chunk, d), "float32"), *pools,
+        sds((), "int32"), sds((), "bool"), sds((), "int32")).compile()
+    count = sum(runs) * slots * math.prod(leaves["conv"][1])
+    forms = {}  # every form of the pool in the text -> its bytes
+    for m in re.finditer(r"bf16\[([\d,]+)\]\{([^}]*)\}", compiled.as_text()):
+        dims = [int(v) for v in m.group(1).split(",")]
+        if math.prod(dims) == count:
+            forms[m.group(0)] = _padded_bytes(dims, m.group(2), 2)
+    assert forms and max(forms.values()) <= 4 * count * 2, forms
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
