@@ -24,11 +24,25 @@ heads, VD, F)`` float32.
 
 ``retention_decode`` is the decode step's update and read for a batch of
 rows, each on the slot its table names: a Pallas kernel that walks the LIVE
-rows only (scalar-prefetched, compacted), reads each row's state once,
-scales, adds the rank-one update, answers the group's query heads against
-the updated state and writes it back once, aliased onto its input.  A dead
-row's grid steps name the block of the live step before them, so nothing is
-fetched or written for it.  ``impl="xla"`` is the same function as gather,
+rows only (scalar-prefetched, compacted).  The pool stays in HBM, aliased
+onto the kernel's output, and the kernel copies a (row, kv head)'s state
+itself between it and two VMEM buffers: while one state is updated in place
+in its buffer the next is read into the other, and only when that has
+arrived is the updated one written back onto itself.  A state's read and
+another's write are never in flight together: a v5e moves both at the speed
+of the write alone (657 GB/s) when they are, and the read at 756 GB/s when
+it goes alone.  ``k``, ``q`` and ``v`` enter as XLA made them, a row's heads
+together and lane dense; at a row's first head its ``k`` and ``q`` rows are
+set head by head into a scratch (Mosaic loads no row at a sublane it only
+learns when the kernel runs, and a head is then a LEADING index), and ``v``
+is turned to columns in registers.  In the buffer the value rows go in
+static blocks of four or five groups of eight (17 groups: 4 + 4 + 4 + 5; the
+shapes decide, no knob): a block walks the feature vregs once, ``k`` and the
+group's ``q`` vregs of a feature vreg loaded once a BLOCK, each state vreg
+loaded, scaled, given its rank-one update and stored once (``g s + v k``,
+float32, term for term), the partial sums of (group, query head) whole vregs
+on the VPU until one cross-lane sum ends each; ``y`` leaves as ``(G, VD)``
+rows.  A dead row's grid steps move nothing.  ``impl="xla"`` is the same function as gather,
 einsum and scatter (the CPU path).  ``retention_chunk`` is a prefill chunk
 of ONE sequence: the attention form inside the chunk, the state across
 chunks, XLA einsums.
@@ -116,92 +130,181 @@ def _decode_core_xla(state, phi_q, phi_k, v_ext, g, slots, live):
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(rows_ref, slots_ref, n_ref, s_ref, k_ref, q_ref, v_ref, g_ref,
-                   o_ref, y_ref, *, groups: int, vd: int):
-    """One (live row, kv head): eight value rows at a time, the row block's
-    features whole.  ``s_ref``/``o_ref`` (1, 1, VD, F) are the same state in
-    HBM; ``k_ref`` (1, 1, 1, F); ``q_ref`` (1, 1, G, F); ``v_ref``/``g_ref``
-    (1, 1, VD, 1) columns; ``y_ref`` (1, 1, VD, G)."""
+def _row_blocks(n: int, most: int = 5) -> list:
+    """``n`` groups of eight value rows as static (first, count) blocks of at
+    most ``most``, as even as they go: 17 -> 4 + 4 + 4 + 5, 3 -> 3."""
+    blocks = -(-n // most)
+    base, rem = divmod(n, blocks)
+    sizes = [base] * (blocks - rem) + [base + 1] * rem
+    return [(sum(sizes[:i]), size) for i, size in enumerate(sizes)]
+
+
+def _update_and_read(s_ref, kq, v_ref, gate, y_ref, h, *, groups: int, vd: int):
+    """``s_ref`` (VD, F), a state in VMEM, becomes ``gate s + v k^T`` IN
+    PLACE and ``y_ref`` (1, 1, G, VD) its product with the group's query
+    features.  ``kq`` (8, F) holds the head's ``k`` row and, under it, its G
+    ``q`` rows; ``v_ref`` (1, H, VD) the row's heads, of which ``h`` is this
+    one.  A block of four or five groups of eight value rows walks the
+    feature vregs once: ``k`` and the G ``q`` vregs of a feature vreg are
+    loaded (broadcast over sublanes) once a BLOCK, each state vreg is loaded
+    and stored once, and a (group, head)'s partial sums stay one whole (8,
+    128) vreg added on the VPU until ONE cross-lane sum ends them."""
     from jax.experimental import pallas as pl
 
-    r, h = pl.program_id(0), pl.program_id(1)
-    n_live = n_ref[0]
+    f32 = jnp.float32
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    chunks = [(c, min(128, vd - c)) for c in range(0, vd, 128)]  # lane vregs of a (., VD) row
+    # lane 8 i + j of a row's lane vreg is value row 8 i + j, sublane j of group i
+    heads = jax.lax.broadcasted_iota(jnp.int32, (v_ref.shape[1], 1), 0) == h
+    v_rows = [jnp.broadcast_to(jnp.max(  # the head's row picked, never summed: exact
+        jnp.where(heads, v_ref[0, :, c:c + w], -jnp.inf), axis=0, keepdims=True), (8, w))
+        for c, w in chunks]
+    y = [[jnp.zeros((8, 128), f32) for _ in chunks] for _ in range(groups)]
+    for first, count in _row_blocks(vd // 8):
+        rows = [slice((first + i) * 8, (first + i + 1) * 8) for i in range(count)]
+        diagonal = [lane == at.start % 128 + sub for at in rows]
+        # v as COLUMNS: the one lane of the row a sublane wants, picked (never summed: exact)
+        v = [jnp.broadcast_to(jnp.max(
+            jnp.where(diagonal[i][:, :chunks[at.start // 128][1]], v_rows[at.start // 128], -jnp.inf),
+            axis=-1, keepdims=True), (8, 128)) for i, at in enumerate(rows)]
 
-    @pl.when(r < n_live)
-    def _():
-        def eight(i, carry):
-            rows = pl.ds(pl.multiple_of(i * 8, 8), 8)
-            new = g_ref[0, 0, rows, :] * s_ref[0, 0, rows, :].astype(jnp.float32) \
-                + v_ref[0, 0, rows, :] * k_ref[0, 0]
-            o_ref[0, 0, rows, :] = new.astype(o_ref.dtype)
+        def feature_vreg(c, acc, rows=rows, v=v):
+            lanes = pl.ds(pl.multiple_of(c * 128, 128), 128)
+            k = jnp.broadcast_to(kq[0:1, lanes], (8, 128))
+            q = [jnp.broadcast_to(kq[1 + j:2 + j, lanes], (8, 128)) for j in range(groups)]
+            out = []
+            for i, at in enumerate(rows):
+                new = gate * s_ref[at, lanes].astype(f32) + v[i] * k
+                s_ref[at, lanes] = new.astype(s_ref.dtype)
+                out.append(tuple(acc[i][j] + new * q[j] for j in range(groups)))
+            return tuple(out)
+
+        zero = tuple(tuple(jnp.zeros((8, 128), f32) for _ in range(groups)) for _ in rows)
+        acc = jax.lax.fori_loop(0, s_ref.shape[-1] // 128, feature_vreg, zero)
+        for i, at in enumerate(rows):
             for j in range(groups):
-                y_ref[0, 0, rows, j:j + 1] = jnp.sum(
-                    new * q_ref[0, 0, j:j + 1, :], axis=-1, keepdims=True)
-            return carry
-
-        jax.lax.fori_loop(0, vd // 8, eight, 0)
-
-    # no live row at all: every step names ONE block, which goes back as it
-    # came (the output buffer is written out whatever the body did)
-    @pl.when((n_live == 0) & (r == 0) & (h == 0))
-    def _():
-        o_ref[...] = s_ref[...]
+                column = jnp.sum(acc[i][j], axis=-1, keepdims=True)
+                y[j][at.start // 128] += jnp.where(diagonal[i], column, 0.0)
+    for j in range(groups):
+        for (c, w), part in zip(chunks, y[j]):
+            y_ref[0, 0, j:j + 1, c:c + w] = jnp.sum(part, axis=0, keepdims=True)[:, :w]
 
 
-def _decode_core_pallas(state, phi_q, phi_k, v_ext, g, slots, live, interpret):
+def _decode_kernel(rows_ref, slots_ref, n_ref, s_hbm, k_ref, q_ref, v_ref, g_ref,
+                   o_hbm, y_ref, buf, kq, sem, *, groups: int, vd: int, heads: int):
+    """Grid step (r, h) is block ``t = r H + h`` of the walk over the live
+    rows' states.  ``s_hbm``/``o_hbm`` (NS, H, VD, F) are the same pool in
+    HBM; ``buf`` (2, VD, F) holds block ``t`` in ``buf[t % 2]`` when the
+    step starts.  The step asks for block ``t + 1``, updates its own in
+    place while that arrives, and only then writes its own back: a state's
+    read and another's write are never in flight together (a v5e moves
+    both at the speed of the write alone when they are).  ``k_ref`` (1, H,
+    F), ``q_ref`` (1, H G, F), ``v_ref`` (1, H, VD): the row's heads, lane
+    dense as XLA made them; ``kq`` (H, 8, F): the same ``k`` and ``q`` rows
+    head by head, set at the row's first head; ``g_ref`` (S, H) in SMEM;
+    ``y_ref`` (1, 1, G, VD)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    S, H, G, F = phi_q.shape
+    r, h = pl.program_id(0), pl.program_id(1)
+    t = r * heads + h
+    blocks = n_ref[0] * heads
+    b = t % 2
+
+    def fetch(t, b):
+        return pltpu.make_async_copy(
+            s_hbm.at[slots_ref[t // heads], t % heads], buf.at[b], sem.at[b])
+
+    @pl.when(t < blocks)  # a dead row's steps move nothing
+    def _():
+        @pl.when(t == 0)
+        def _():
+            first = fetch(0, 0)
+            first.start()
+            first.wait()
+
+        @pl.when(t + 1 < blocks)
+        def _():
+            fetch(t + 1, 1 - b).start()
+
+        @pl.when(h == 0)  # the row's k and q rows, head by head: kq[h] = [k_h, q_hG .. q_hG+G-1]
+        def _():
+            for head in range(heads):
+                kq[head, 0:1, :] = k_ref[0, head:head + 1, :]
+                kq[head, 1:1 + groups, :] = q_ref[0, head * groups:(head + 1) * groups, :]
+
+        gate = jnp.full((8, 128), g_ref[rows_ref[r], h], jnp.float32)
+        _update_and_read(buf.at[b], kq.at[h], v_ref, gate, y_ref, h, groups=groups, vd=vd)
+
+        @pl.when(t + 1 < blocks)
+        def _():
+            fetch(t + 1, 1 - b).wait()
+
+        back = pltpu.make_async_copy(buf.at[b], o_hbm.at[slots_ref[r], h], sem.at[2])
+        back.start()
+        back.wait()
+
+
+def _decode_core_pallas(state, phi_q, phi_k, v_ext, g, slots, live, interpret):
+    """As ``_decode_core_xla``, but ``phi_q`` is (S, H G, F): the query heads
+    as the model has them, a key-value head's group side by side."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, F = phi_k.shape
+    G = phi_q.shape[1] // H
     vd = state.shape[2]
     # live rows first, in slot order; the rest repeat the last live row, and
-    # their steps name the block that step left: nothing moves for them
+    # their steps name the blocks that step left: nothing moves for them
     n_live = jnp.sum(live.astype(jnp.int32))
     order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
     rows = order[jnp.minimum(jnp.arange(S), jnp.maximum(n_live - 1, 0))]
 
+    def heads_of_row(r, h, rows_ref, slots_ref, n_ref):
+        return (rows_ref[r], 0, 0)
+
     def by_row(r, h, rows_ref, slots_ref, n_ref):
         return (rows_ref[r], jnp.where(r < n_ref[0], h, H - 1), 0, 0)
-
-    def by_slot(r, h, rows_ref, slots_ref, n_ref):
-        return (slots_ref[r], jnp.where(r < n_ref[0], h, H - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, H),
         in_specs=[
-            pl.BlockSpec((1, 1, vd, F), by_slot),
-            pl.BlockSpec((1, 1, 1, F), by_row),
-            pl.BlockSpec((1, 1, G, F), by_row),
-            pl.BlockSpec((1, 1, vd, 1), by_row),
-            pl.BlockSpec((1, 1, vd, 1), by_row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, H, F), heads_of_row),
+            pl.BlockSpec((1, H * G, F), heads_of_row),
+            pl.BlockSpec((1, H, vd), heads_of_row),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, vd, F), by_slot),
-            pl.BlockSpec((1, 1, vd, G), by_row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, 1, G, vd), by_row),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, vd, F), state.dtype),
+            pltpu.VMEM((H, -(-(1 + G) // 8) * 8, F), jnp.float32),
+            pltpu.SemaphoreType.DMA((3,)),
         ],
     )
     block = vd * F * state.dtype.itemsize
     state, y = pl.pallas_call(
-        functools.partial(_decode_kernel, groups=G, vd=vd),
+        functools.partial(_decode_kernel, groups=G, vd=vd, heads=H),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, state.dtype),
-            jax.ShapeDtypeStruct((S, H, vd, G), jnp.float32),
+            jax.ShapeDtypeStruct((S, H, G, vd), jnp.float32),
         ],
         # operand 3 (after the three prefetched scalars) is the state
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            # the state block in and out, each double-buffered, and the rest
-            vmem_limit_bytes=int(4 * block + 12 * F * 4 * 8 + (4 << 20)),
+            # two states; a row's k, q, v double-buffered; k and q again head by head
+            vmem_limit_bytes=int(2 * block + (2 * (H * G + 2 * H) + 8 * H) * F * 4 + (4 << 20)),
         ),
         interpret=interpret,
         name="retention_decode",
-    )(rows, slots[rows].astype(jnp.int32), n_live[None],
-      state, phi_k[:, :, None, :], phi_q, v_ext[..., None],
-      jnp.broadcast_to(g[:, :, None, None], v_ext.shape + (1,)))
-    y = jnp.swapaxes(y, 2, 3)  # (S, H, G, VD)
+    )(rows, slots[rows].astype(jnp.int32), n_live[None], state, phi_k, phi_q, v_ext, g)
     return state, jnp.where(live[:, None, None, None], y, 0.0)
 
 
@@ -221,12 +324,14 @@ def retention_decode(state, q, k, v, log_g, slots, live, *, eps: float,
         raise ValueError(f"unknown retention impl {impl!r}; expected 'auto', 'xla' or 'pallas'")
     S, Hq, d = q.shape
     H = k.shape[1]
-    vd = state.shape[2]
-    phi_q = phi(q).reshape(S, H, Hq // H, -1)
+    xla = impl == "xla" or (impl == "auto" and not _on_tpu())
+    phi_q = phi(q)  # the kernel takes the query heads as they stand: (S, Hq, F)
+    if xla:
+        phi_q = phi_q.reshape(S, H, Hq // H, -1)
     phi_k = phi(k)
     g = jnp.exp(log_g.astype(jnp.float32))
-    args = (state, phi_q, phi_k, _v_ext(v, vd), g, slots.astype(jnp.int32), live)
-    if impl == "xla" or (impl == "auto" and not _on_tpu()):
+    args = (state, phi_q, phi_k, _v_ext(v, state.shape[2]), g, slots.astype(jnp.int32), live)
+    if xla:
         state, y = _decode_core_xla(*args)
     else:
         state, y = _decode_core_pallas(*args, interpret=not _on_tpu())

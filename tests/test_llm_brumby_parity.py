@@ -152,21 +152,40 @@ def test_a_reused_slot_equals_a_fresh_one():
 # -- the op: both forms, both implementations ----------------------------------------
 
 
-def _op_inputs(seed, rows, d=16, hq=4, h=2, pool=6):
+def _op_inputs(seed, rows, d=16, hq=4, h=2, pool=6, past=0):
+    """A pool of states and a batch of rows.  ``past`` = 0: states of normal
+    draws (a normaliser may fall near 0); otherwise each is what ``past``
+    tokens left, so its normaliser is a sum of squares."""
     rng = np.random.default_rng(seed)
     vd, F = pr.state_dims(d)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    state = f(pool, h, vd, F)
+    if past:
+        state = jnp.einsum("nhsv,nhsf->nhvf", pr._v_ext(f(pool, h, past, d), vd),
+                           pr.phi(f(pool, h, past, d)), precision="highest")
+    else:
+        state = f(pool, h, vd, F)
     log_g = jnp.asarray(-rng.uniform(0.001, 0.2, (rows, h)), jnp.float32)
     return state, f(rows, hq, d), f(rows, h, d), f(rows, h, d), log_g
+
+
+#: the parity tests' head and the PUBLISHED one (a state of (136, 8320): 17 groups
+#: of eight value rows, which no block of four divides, by 65 lane vregs, 5 query heads)
+HEADS = {"tiny": dict(d=16, hq=4, h=2, pool=6, slots=[5, 0, 0, 2]),
+         "published": dict(d=128, hq=5, h=1, pool=3, past=8, slots=[2, 0, 0, 1])}
+
+
+def _head_inputs(head, seed=0):
+    shape = dict(HEADS[head])
+    slots = jnp.asarray(shape.pop("slots"), jnp.int32)  # a dead row names a live row's slot
+    return _op_inputs(seed, 4, **shape), slots
 
 
 @pytest.mark.parametrize("live", [
     [True, True, True, True], [False, True, False, True], [True, False, False, False],
     [False, False, False, False]])
-def test_the_pallas_kernel_equals_the_xla_form_and_spares_dead_rows(live):
-    state, q, k, v, log_g = _op_inputs(0, 4)
-    slots = jnp.asarray([5, 0, 0, 2], jnp.int32)  # a dead row names a live row's slot
+@pytest.mark.parametrize("head", list(HEADS))
+def test_the_pallas_kernel_equals_the_xla_form_and_spares_dead_rows(head, live):
+    (state, q, k, v, log_g), slots = _head_inputs(head)
     live = jnp.asarray(live)
     out = {}
     for impl in ("xla", "pallas"):
@@ -179,6 +198,20 @@ def test_the_pallas_kernel_equals_the_xla_form_and_spares_dead_rows(live):
         same = np.array_equal(np.asarray(out["pallas"][0][slot]), np.asarray(state[slot]))
         assert same == (slot not in touched), slot
     assert not np.asarray(out["pallas"][1])[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("head", list(HEADS))
+def test_the_read_out_never_reaches_the_stored_state(head):
+    """The kernel reads ``y`` out of the state it has just updated, in an order
+    of its own: two calls that differ in ``q`` alone read different ``y`` and
+    store the SAME bits."""
+    (state, q, k, v, log_g), slots = _head_inputs(head, seed=1)
+    live = jnp.asarray([True, True, False, True])
+    first, second = (pr.retention_decode(jnp.array(state), each, k, v, log_g, slots, live,
+                                         eps=1e-6, impl="pallas")
+                     for each in (q, 1.0 + q[::-1]))
+    assert not np.array_equal(np.asarray(first[1]), np.asarray(second[1]))
+    assert np.array_equal(np.asarray(first[0]), np.asarray(second[0]))
 
 
 def test_the_recurrent_form_equals_the_attention_form_over_300_steps():

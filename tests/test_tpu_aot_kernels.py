@@ -342,6 +342,32 @@ def test_the_ssd_decode_kernel_compiles_for_a_v5e_and_updates_in_place(
     assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes < pool // 16
 
 
+def test_the_retention_decode_kernel_compiles_for_a_v5e_and_updates_in_place(one_chip):
+    """Brumby's decode at the cell's sizes: 16 rows, 8 key-value heads of 5
+    query heads, a state of (136, 8320) float32 = 4.5 MB copied into VMEM and
+    back by the kernel's own DMAs (two states of scratch).  The pool is
+    aliased and no copy of it stands among the temporaries; ``y`` leaves as
+    (G, VD) rows: no array of (VD, G) columns is made or turned."""
+    from ray_tpu.ops import power_retention as pr
+
+    s, h, g, slots = 16, 8, 5, 17
+    vd, f = pr.state_dims(128)
+    assert pr._row_blocks(vd // 8) == [(0, 4), (4, 4), (8, 4), (12, 5)]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda state, *rest: pr._decode_core_pallas(state, *rest, interpret=False),
+        donate_argnums=(0,),
+    ).lower(sds((slots, h, vd, f)), sds((s, h * g, f)), sds((s, h, f)), sds((s, h, vd)),
+            sds((s, h)), sds((s,), jnp.int32), sds((s,), jnp.bool_)).compile()
+    mem, pool, text = compiled.memory_analysis(), slots * h * vd * f * 4, compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{s},{h},{g},{vd}]" in text and f"f32[{s},{h},{vd},{g}]" not in text
+    assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes < pool // 16
+
+
 @pytest.mark.parametrize("heads,kv,blocks,tmax,e", [
     (20, 4, 8 * 1601, 100, 128),  # falcon-h1-34b-l8-1chip: w = 5 over 8 layers of 1,601 blocks
     (32, 8, 225, 14, 128),        # granite-4.0-h-small-ep2-l10-1chip: w = 4 over ONE layer

@@ -27,7 +27,7 @@ Two readings a configuration of ``BENCHMARK.json`` that has an ``engine``:
   (decode, prefill, and where the engine has them verify and fork) lowered
   again at the operands of its first call (``StepRunner._first_operands``).
 * ``v5e`` (families that bring their own layer programs: ``cache_kind``
-  ``hybrid`` or ``paged``): the decode and the prefill chunk at the PUBLISHED
+  ``hybrid``, ``paged`` or ``state``): the decode and the prefill chunk at the PUBLISHED
   sizes and the configuration's engine, lowered for a described v5e chip with
   the Pallas kernels on (the dispatch rules are told they are on a TPU), from
   shapes alone: nothing is compiled and nothing runs.
@@ -94,11 +94,12 @@ def _v5e(config: dict, H, one_chip) -> dict:
 
     from ray_tpu.llm.cache import KVBlockPool
     from ray_tpu.llm.model_runner import host_batch, pack_knobs
-    from ray_tpu.llm.state_runner import HybridModelRunner
+    from ray_tpu.llm.state_runner import HybridModelRunner, StateModelRunner
 
     sizes = H.sizes(config, False)
     cfg = H.family_piece(config, "model_config")(sizes)
-    if getattr(cfg, "cache_kind", "kv") not in ("hybrid", "paged"):
+    kind = getattr(cfg, "cache_kind", "kv")
+    if kind not in ("hybrid", "paged", "state"):
         return {}
     init, e = H.family_piece(config, "program_init")(), sizes["engine"]
 
@@ -108,18 +109,26 @@ def _v5e(config: dict, H, one_chip) -> dict:
 
     params = jax.tree_util.tree_map(
         sds, jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg)))
-    runner = HybridModelRunner(cfg, params, e["block_size"])
-    body, slots, table = runner.body, e["max_slots"], e["max_blocks_per_seq"]
-    lay = body.kv_layout()
-    shape = (lay["n_layers"], e["num_blocks"], lay["n_heads"], e["block_size"], lay["head_dim"])
-    pool = [jax.ShapeDtypeStruct(shape, jnp.dtype(lay["dtype"]))] * KVBlockPool.n_arrays(**lay)
-    hybrid = cfg.cache_kind == "hybrid"
-    leaves = body.state_leaves(e["block_size"]) if hybrid else {}
-    pools = [sds(p) for p in pool] + [
-        jax.ShapeDtypeStruct((n, slots + 1, *shape), jnp.dtype(dt), sharding=one_chip)
-        for n, shape, dt in leaves.values()]
-    counts = [sds(c) for c in getattr(body, "counters", tuple)()]
-    width = table + (1 if hybrid else 0)
+    slots = e["max_slots"]
+    if kind == "state":  # ONE fixed-size state a layer and slot (cache.StatePool), no trash slot
+        runner = StateModelRunner(cfg, params)
+        body = runner.body
+        pools = [jax.ShapeDtypeStruct((cfg.n_layers, slots) + tuple(body.state_shape),
+                                      jnp.dtype(body.state_dtype), sharding=one_chip)]
+        counts, width = [], 1
+    else:
+        runner = HybridModelRunner(cfg, params, e["block_size"])
+        body, table = runner.body, e["max_blocks_per_seq"]
+        lay = body.kv_layout()
+        shape = (lay["n_layers"], e["num_blocks"], lay["n_heads"], e["block_size"], lay["head_dim"])
+        pool = [jax.ShapeDtypeStruct(shape, jnp.dtype(lay["dtype"]))] * KVBlockPool.n_arrays(**lay)
+        hybrid = kind == "hybrid"
+        leaves = body.state_leaves(e["block_size"]) if hybrid else {}
+        pools = [sds(p) for p in pool] + [
+            jax.ShapeDtypeStruct((n, slots + 1, *shape), jnp.dtype(dt), sharding=one_chip)
+            for n, shape, dt in leaves.values()]
+        counts = [sds(c) for c in getattr(body, "counters", tuple)()]
+        width = table + (1 if hybrid else 0)
     z, i32 = np.zeros(slots), np.int32
     decode = [sds(o) for o in host_batch(
         z.astype(i32), z.astype(i32), np.zeros((slots, width), i32), z, z, np.ones(slots), z, z)]
