@@ -1,7 +1,9 @@
 """What sequences hold on the device: the paged KV-cache block pool, the
-pool of fixed-size states of a model without keys and values (``StatePool``)
-and the two behind one ledger for a model with both (``HybridPool``, at the
-end) — static-shape JAX storage, host-side ledger.
+pool of fixed-size states of a model without keys and values (``StatePool``),
+the two behind one ledger for a model with both (``HybridPool``) and two
+block pools behind one ledger for a model whose layers are of two KINDS, full
+and window attention (``LayerTypedPool``, at the end) — static-shape JAX
+storage, host-side ledger.
 
 vLLM-style paging on the TPU shape discipline: the device side is two
 fixed arrays per model
@@ -312,6 +314,22 @@ class KVBlockPool:
             for b in reversed(tail):
                 self._deref_locked(b)
             return excess
+
+    def release_head(self, seq_id: str, n_blocks: int) -> int:
+        """Return the sequence's FIRST ``n_blocks`` blocks to the free list
+        (``shrink_to``'s mirror); the rest keep their order.  What a window
+        layer's pool does with the blocks behind the window
+        (``LayerTypedPool``, which keeps count of how many went).  Returns
+        how many went."""
+        with self._lock:
+            blocks = self._owned.get(seq_id)
+            if blocks is None:
+                raise KeyError(f"unknown sequence {seq_id!r}")
+            head = blocks[:max(n_blocks, 0)]
+            del blocks[:len(head)]
+            for b in head:
+                self._deref_locked(b)
+            return len(head)
 
     def free(self, seq_id: str) -> int:
         """Drop the sequence's references (idempotent); returns how many
@@ -752,3 +770,266 @@ class HybridPool:
         """(1 + max_blocks_per_seq,) int32: the slot, then the block table;
         ``None`` is the trash slot and all trash blocks."""
         return np.concatenate([self.states.table_row(seq_id), self.kv.table_row(seq_id)])
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerTypedConfig:
+    """Geometry of a ``LayerTypedPool``: ``CacheConfig``'s for the FULL
+    layers' blocks (what the scheduler and the engine read), and beside it
+    the window layers': ``window`` tokens a query sees, ``chunk`` tokens the
+    longest step writes of one sequence, ``slots`` sequences at once."""
+
+    num_blocks: int
+    block_size: int
+    max_blocks_per_seq: int
+    window: int
+    chunk: int
+    slots: int
+
+    def __post_init__(self):
+        if self.window < 1 or self.chunk < 1 or self.slots < 1:
+            raise ValueError("window, chunk and slots must be >= 1")
+
+    @property
+    def max_seq_len(self) -> int:
+        return self.max_blocks_per_seq * self.block_size
+
+    @property
+    def window_blocks_per_seq(self) -> int:
+        """The most window blocks a sequence holds: those that ``window - 1``
+        keys before a step's first query and its ``chunk`` positions touch
+        (``W / BS + chunk / BS + 1`` where the block size divides both), and
+        never more than its table has."""
+        span = self.window - 1 + self.chunk
+        return min(-(-span // self.block_size) + 1, self.max_blocks_per_seq)
+
+    @property
+    def window_num_blocks(self) -> int:
+        """The window sub-pool: every slot's most, and the trash block."""
+        return self.slots * self.window_blocks_per_seq + 1
+
+
+class LayerTypedPool:
+    """Blocks of TWO layer kinds for one sequence behind ONE ledger: the
+    ``full`` attention layers' (a ``KVBlockPool`` that holds every block of
+    a sequence, as any) and the ``window`` layers' (a second ``KVBlockPool``
+    with its own arrays and free list), in which a sequence holds only the
+    blocks a later query can still see.  It answers the calls the scheduler
+    and the engine make of ``KVBlockPool`` the way ``HybridPool`` does, by
+    composition: block counts, ``blocks_of``, ``block_bytes`` and the free /
+    owned partition are the FULL sub-pool's, where the pressure is; the
+    window sub-pool stands beside them (``ledger_counts``, ``audit``,
+    ``stats``).
+
+    **The window layers' blocks.**  A window block is claimed when a step is
+    about to write into it and handed back to the free list when it lies
+    WHOLLY behind the window of the next step's first query
+    (``ops.paged_attention.first_window_block``, the rule the kernels start
+    their walk by).  ``slide(seq, start, n)`` does both for a step that
+    writes positions ``start .. start + n`` with its first query at
+    ``start``; ``grow_to(seq, n_tokens)`` is that for a decode (one query at
+    ``n_tokens - 1``) after the full layers' growth, and the engine calls
+    ``slide`` itself before each prefill chunk (the full layers' blocks of a
+    whole prompt are claimed at admission; a window layer's could not be: 262
+    against 37 at 32k tokens).  A sequence therefore holds at most
+    ``window_blocks_per_seq`` window blocks, the sub-pool has that many for
+    every slot, and a window claim NEVER fails: admission, growth and
+    preemption are decided by the full layers' blocks alone, exactly as in a
+    ``KVBlockPool``.
+
+    **Why releasing at launch is enough.**  ``slide`` runs on the host while
+    the step that needs it is being built, and steps ahead of it may still
+    be in flight on the device, reading a block this call hands back.  The
+    block is written again only by a step launched LATER (this one at the
+    earliest), and every step takes the pool's arrays donated from the step
+    before: the device runs them in launch order, so whoever re-claims the
+    block writes it after every earlier reader is done.  What matters is
+    that no step YET TO BE LAUNCHED reads a released block, and none does:
+    its queries stand at ``start`` or later, the table it is sent has the
+    trash block in the released entries, and the kernels never read an entry
+    before their first visible block.
+
+    A table row is the full layers' table followed by the window layers',
+    both ``max_blocks_per_seq`` wide and indexed by LOGICAL block, a
+    released or never-claimed entry the trash block; ``arrays`` is ``(k_full,
+    v_full, k_window, v_window)``.  Nothing is shared: the radix prefix
+    cache keys blocks of ONE kind (the engine refuses it for this pool).
+    No lock of its own beside ``_first``'s: the calls that change both parts
+    come from under the engine's lock, as ``HybridPool``'s."""
+
+    paged = True
+
+    def __init__(self, cfg: LayerTypedConfig, kv_layout: dict):
+        self.cfg = cfg
+        layout = {k: v for k, v in kv_layout.items() if k not in ("kinds", "window")}
+        kinds = kv_layout["kinds"]
+        self.full = KVBlockPool(
+            CacheConfig(cfg.num_blocks, cfg.block_size, cfg.max_blocks_per_seq),
+            n_layers=kinds["full"], **layout)
+        self.windowed = KVBlockPool(
+            CacheConfig(cfg.window_num_blocks, cfg.block_size, cfg.max_blocks_per_seq),
+            n_layers=kinds["window"], **layout)
+        self._lock = threading.Lock()
+        #: logical index of the first window block each sequence still holds
+        self._first: dict[str, int] = {}
+        self._released = 0
+
+    @staticmethod
+    def n_arrays(**_layout) -> int:
+        return 4
+
+    @property
+    def k(self):
+        return self.full.k
+
+    @property
+    def arrays(self) -> tuple:
+        return self.full.arrays + self.windowed.arrays
+
+    @arrays.setter
+    def arrays(self, new) -> None:
+        self.full.arrays, self.windowed.arrays = new[:2], new[2:]
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return self.full.blocks_for(n_tokens)
+
+    @property
+    def block_bytes(self) -> int:
+        """Device bytes of one FULL-layer block (the unit ``seq_owned`` and
+        ``free`` count); a window block's are ``window_block_bytes``."""
+        return self.full.block_bytes
+
+    @property
+    def window_block_bytes(self) -> int:
+        return self.windowed.block_bytes
+
+    @property
+    def device_bytes(self) -> int:
+        return self.full.device_bytes + self.windowed.device_bytes
+
+    @property
+    def num_free_blocks(self) -> int:
+        return self.full.num_free_blocks
+
+    @property
+    def num_used_blocks(self) -> int:
+        return self.full.num_used_blocks
+
+    num_evictable_blocks = 0
+
+    def ledger_counts(self) -> dict:
+        """The full layers' partition (``KVBlockPool.ledger_counts``), and
+        beside it what the sequences hold NOW of each kind, the window
+        sub-pool's free blocks and what the window layers have handed back
+        behind a window since the start (one lock each)."""
+        full, window = self.full.ledger_counts(), self.windowed.ledger_counts()
+        with self._lock:
+            released = self._released
+        return dict(full, full_blocks_held=full["seq_owned"],
+                    window_blocks_held=window["seq_owned"], window_free=window["free"],
+                    window_blocks_released=released)
+
+    def stats(self) -> dict:
+        """The three counts of ``ledger_counts`` that ``stats()["kv_pool"]``
+        shows."""
+        counts = self.ledger_counts()
+        return {k: counts[k] for k in (
+            "full_blocks_held", "window_blocks_held", "window_blocks_released")}
+
+    def utilization(self) -> float:
+        return self.full.utilization()
+
+    def can_allocate(self, n_tokens: int, shared: int = 0) -> bool:
+        return self.full.can_allocate(n_tokens, shared)
+
+    def allocate(self, seq_id: str, n_tokens: int, shared: Sequence[int] = ()) -> list[int]:
+        """Claim the full layers' blocks for ``n_tokens`` and the window
+        layers' FIRST block (the others follow step by step: ``slide``), or
+        neither."""
+        if shared:
+            raise ValueError("a sequence with window layers shares no blocks")
+        # the ledger entry is the caller's, under ``seq_id``, exactly as
+        # KVBlockPool.allocate's own: this only forwards it
+        blocks = self.full.allocate(seq_id, n_tokens)  # raylint: disable=RL015
+        try:
+            self.windowed.allocate(seq_id, 1)  # raylint: disable=RL015
+        except Exception:
+            self.full.free(seq_id)
+            raise
+        with self._lock:
+            self._first[seq_id] = 0
+        return blocks
+
+    def slide(self, seq_id: str, start: int, n: int) -> None:
+        """Make the window layers ready for a step of ``seq_id`` that writes
+        positions ``start .. start + n``, its first query at ``start``: hand
+        back every block wholly behind that query's window, then claim up to
+        the last position written."""
+        bs, pool = self.cfg.block_size, self.windowed
+        keep_from = max(start - (self.cfg.window - 1), 0) // bs
+        end = min(start + n, self.cfg.max_seq_len)
+        with self._lock:
+            # steps follow one another (a chunk or a decode starts where the
+            # last one ended), so ``keep_from`` lies inside what is held
+            gone = pool.release_head(seq_id, keep_from - self._first[seq_id])
+            self._first[seq_id] = first = self._first[seq_id] + gone
+            self._released += gone
+            if not pool.grow_to(seq_id, end - first * bs):
+                raise MemoryError(
+                    f"window sub-pool exhausted ({pool.num_free_blocks} free): it holds "
+                    f"{self.cfg.window_blocks_per_seq} blocks for each of {self.cfg.slots} "
+                    "slots, which no sequence in a slot can pass")
+
+    def grow_to(self, seq_id: str, n_tokens: int) -> bool:
+        """Room for a decode that writes position ``n_tokens - 1``: the full
+        layers' block where one is due (False, nothing changed, when their
+        pool is dry), then the window layers' slide to that query."""
+        if not self.full.grow_to(seq_id, n_tokens):
+            return False
+        self.slide(seq_id, min(n_tokens, self.cfg.max_seq_len) - 1, 1)
+        return True
+
+    def free(self, seq_id: str) -> int:
+        freed = self.full.free(seq_id)
+        self.windowed.free(seq_id)
+        with self._lock:
+            self._first.pop(seq_id, None)
+        return freed
+
+    def blocks_of(self, seq_id: str) -> list[int]:
+        return self.full.blocks_of(seq_id)
+
+    def _window_blocks(self, seq_id: str) -> list:
+        try:
+            return self.windowed.blocks_of(seq_id)
+        except KeyError:  # freed since the audit read its owners
+            return []
+
+    def window_blocks_of(self, seq_id: str) -> tuple:
+        """(logical index of the first window block held, the blocks)."""
+        with self._lock:
+            return self._first[seq_id], self.windowed.blocks_of(seq_id)
+
+    def audit(self) -> dict:
+        """Both parts' audits and every owner in both: the full sub-pool's
+        keys, ``window`` the window sub-pool's, ``unpaired`` the owners of one
+        and not the other (read again once, as ``HybridPool.audit``), and no
+        sequence over its most window blocks."""
+        for _ in range(2):
+            full, win = self.full.audit(), self.windowed.audit()
+            unpaired = sorted(set(full["owners"]) ^ set(win["owners"]))
+            if not unpaired:
+                break
+        over = [s for s in win["owners"] if s not in unpaired
+                and len(self._window_blocks(s)) > self.cfg.window_blocks_per_seq]
+        return dict(full, ok=full["ok"] and win["ok"] and not unpaired and not over,
+                    window=win, unpaired=unpaired, over_window=over)
+
+    def table_row(self, seq_id: Optional[str]) -> np.ndarray:
+        """(2 * max_blocks_per_seq,) int32: the full layers' table, then
+        the window layers' by logical block; ``None`` is all trash."""
+        row = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
+        if seq_id is not None:
+            first, blocks = self.window_blocks_of(seq_id)
+            row[first:first + len(blocks)] = blocks
+        return np.concatenate([self.full.table_row(seq_id), row])

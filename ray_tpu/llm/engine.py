@@ -16,7 +16,12 @@ ALONE, of whatever it says a token leaves behind (``cache_kind == "paged"``:
 ``models.kimi_k2``, one array of latent rows), gets the same runner over a
 ``cache.KVBlockPool`` of that layout: its blocks are shared, forked and
 evicted as GPT-J's, so the prefix cache runs, and only speculation and
-``tp > 1`` are refused.  ``step()`` is the whole design:
+``tp > 1`` are refused.  A family whose layers are of TWO kinds, full and
+window attention (``cache_kind == "windowed"``: ``models.afmoe``), gets that
+runner over a ``cache.LayerTypedPool``: every block of a sequence in the full
+layers, in the window layers only the blocks a later query still sees (slid
+before each prefill chunk and each decode); the same three are refused.
+``step()`` is the whole design:
 
 1. reap cancellations and blown deadlines;
 2. admit waiting requests into free decode slots (FIFO, memory-gated,
@@ -99,6 +104,8 @@ from ray_tpu.llm.cache import (
     CacheConfig,
     HybridConfig,
     HybridPool,
+    LayerTypedConfig,
+    LayerTypedPool,
     KVBlockPool,
     StateConfig,
     StatePool,
@@ -435,11 +442,13 @@ class LLMEngine:
             max_blocks_per_seq=self.cfg.max_blocks_per_seq,
         )
         cache_kind = getattr(model_cfg, "cache_kind", "kv")
-        if cache_kind in ("hybrid", "paged"):
+        if cache_kind in ("hybrid", "paged", "windowed"):
             # a family that gives its own layer programs and the layout of
             # what its sequences hold: blocks ("paged": a KVBlockPool of the
-            # body's layout, shared and forked as any), or blocks AND a slot
-            # of state behind one ledger ("hybrid": cache.HybridPool)
+            # body's layout, shared and forked as any), blocks AND a slot
+            # of state behind one ledger ("hybrid": cache.HybridPool), or
+            # blocks of two layer kinds, the window layers' handed back
+            # behind the window ("windowed": cache.LayerTypedPool)
             self._refuse_for_hooks_body(state=cache_kind == "hybrid")
             from ray_tpu.llm.state_runner import HybridModelRunner
 
@@ -453,6 +462,14 @@ class LLMEngine:
                 self.pool = HybridPool(
                     cache_cfg, body.kv_layout(), body.state_leaves(self.cfg.block_size)
                 )
+            elif cache_kind == "windowed":
+                layout = body.kv_layout()
+                cache_cfg = LayerTypedConfig(
+                    self.cfg.num_blocks, self.cfg.block_size, self.cfg.max_blocks_per_seq,
+                    window=layout["window"], chunk=self.cfg.prefill_chunk,
+                    slots=self.cfg.max_slots,
+                )
+                self.pool = LayerTypedPool(cache_cfg, layout)
             else:
                 self.pool = KVBlockPool(cache_cfg, **body.kv_layout())
         elif cache_kind == "state":
@@ -631,6 +648,11 @@ class LLMEngine:
         self._state_n = {"overwrites": 0, "decodes": 0, "decode_rows": 0,
                          "decode_tokens": 0, "chunks": 0, "chunk_tokens": 0,
                          "chunk_context_tokens": 0}
+        #: the tokens a pool with window layers lets a query see there (0:
+        #: no such layers), and what its decodes' rows saw of them: the sum
+        #: over live rows of min(context, window), beside ``decode_tokens``
+        self._window = self.pool.cfg.window if isinstance(self.pool, LayerTypedPool) else 0
+        self._decode_window_tokens = 0
         #: the pool of fixed-size states, where the model has one: the pool
         #: itself, or the part of a hybrid pool
         self._states = self.pool if isinstance(self.pool, StatePool) else getattr(
@@ -650,6 +672,15 @@ class LLMEngine:
             n = self.model_cfg.serving_body().kv_layout()["n_layers"]
             holds = ("a recurrent state beside "
                      + ("one layer's" if n == 1 else f"{n} layers'") + " keys and values")
+        if self.cfg.prefix_cache and getattr(self.model_cfg, "cache_kind", "") == "windowed":
+            raise ValueError(
+                f"prefix_cache=True with {what}: the radix prefix cache keys "
+                "blocks of ONE kind, and this model's window layers hand the "
+                "blocks behind their window back (a shared prefix would need its "
+                "window layers' last W tokens alone, kept beside the full "
+                "layers' blocks: not implemented). "
+                "Pass EngineConfig(prefix_cache=False)"
+            )
         if self.cfg.prefix_cache and state:
             raise ValueError(
                 f"prefix_cache=True with {what}: the radix prefix cache shares "
@@ -1171,6 +1202,24 @@ class LLMEngine:
             elif self.runner.arch == "hybrid":
                 # a body over blocks alone
                 s["kv_pool"] = self._kv_pool_stats(led)
+                if self._window:
+                    # blocks of two layer kinds (cache.LayerTypedPool): what
+                    # the sequences hold of each now, what the window layers
+                    # handed back, and the tokens the decodes' rows saw in a
+                    # window layer.  The window sub-pool is what a slot holds
+                    # at most, as a state is: the steps' own account stands
+                    # under ``state_pool`` too, where the readers of a
+                    # decode's and a chunk's occupancy look for it
+                    win = self.pool.windowed
+                    s["kv_pool"].update(
+                        self.pool.stats(), window=self._window,
+                        decode_window_tokens=self._decode_window_tokens)
+                    s["state_pool"] = dict(
+                        self._state_n, slots=self.cfg.max_slots,
+                        live=self.scheduler.num_running, bytes=win.device_bytes,
+                        kinds={"window_kv": win.device_bytes},
+                        decode_window_tokens=self._decode_window_tokens,
+                    )
             # what the body counted on the device: the reader is taken here
             # and called below, outside the lock, because it waits for every
             # step launched so far and the step loop must not wait with it
@@ -1439,6 +1488,10 @@ class LLMEngine:
             n_valid = len(piece)
             tokens = np.zeros(chunk, np.int32)
             tokens[:n_valid] = piece
+            if self._window:
+                # the window layers' blocks follow the chunks (cache.
+                # LayerTypedPool: the full layers' were claimed at admission)
+                self.pool.slide(req.id, req.prefill_pos, n_valid)
             table = self.pool.table_row(req.id)
             # the program samples from every chunk's last logits; only the
             # final chunk's token is kept, so only it pays for its knobs
@@ -1719,6 +1772,9 @@ class LLMEngine:
             self._state_n["decodes"] += 1
             self._state_n["decode_rows"] += len(rows)
             self._state_n["decode_tokens"] += sum(r.seq_len + a for _, r, a in rows)
+            if self._window:
+                self._decode_window_tokens += sum(
+                    min(r.seq_len + a, self._window) for _, r, a in rows)
             flight = _Flight([(i, r) for i, r, _ in rows], nxt, logp, self._step_n)
         if piece is not None:
             self._pipe["joint_steps"] += 1
@@ -2006,6 +2062,11 @@ class LLMEngine:
             # slots of state are allocated beside them, once
             led["state_bytes"] = self._states.device_bytes
             led["state_seq_bytes"] = counts["slots_owned"] * self._states.block_bytes
+        if "window_blocks_held" in counts:
+            # blocks of two layer kinds: the partition above is the full
+            # layers'; the window layers' sub-pool lies beside it
+            led["window_bytes"] = self.pool.windowed.device_bytes
+            led["window_seq_bytes"] = counts["window_blocks_held"] * self.pool.window_block_bytes
         if self.cfg.tp > 1:
             pool_dev = self.pool.per_device_bytes()
             par_dev = self.runner.per_device_param_bytes()

@@ -42,7 +42,11 @@ back in its place.  Two kinds of family take it:
   token leaves behind (``cache_kind == "paged"``, a ``cache.KVBlockPool``;
   ``models.kimi_k2``: one array of latent rows): a table row is the block
   table, blocks are shared, so the runner has ``fork_blocks`` and the engine
-  runs the radix prefix cache over it.
+  runs the radix prefix cache over it;
+* sequences hold blocks of TWO layer kinds, the window layers' handed back
+  behind the window (``cache_kind == "windowed"``, a ``cache.LayerTypedPool``;
+  ``models.afmoe``): four paged arrays, a table row is the full layers' table
+  followed by the window layers', and nothing is shared.
 
 A body may count on the device (``counters()``: shapes; ``models.kimi_k2``
 and ``models.granite_h`` count the router's load): those arrays ride every step after the pool's
@@ -59,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.cache import KVBlockPool
+from ray_tpu.llm.cache import KVBlockPool, LayerTypedPool
 from ray_tpu.llm.model_runner import (
     StepRunner,
     _advance_slots,
@@ -143,9 +147,11 @@ class HybridModelRunner(StepRunner):
         #: what the body counts on the device; none for most
         self._counts = tuple(
             jnp.zeros(c.shape, c.dtype) for c in getattr(self.body, "counters", tuple)())
-        # the paged pool's arrays (K and V, or what the body's layout says),
-        # one a kind of state, and the body's counters
-        self.n_paged = KVBlockPool.n_arrays(**self.body.kv_layout())
+        # the paged pool's arrays (K and V, or what the body's layout says:
+        # one array, or K and V of each of two layer kinds), one a kind of
+        # state, and the body's counters
+        layout = self.body.kv_layout()
+        self.n_paged = (LayerTypedPool if "kinds" in layout else KVBlockPool).n_arrays(**layout)
         self.n_arrays = n = (
             self.n_paged + len(self.body.state_leaves(block_size)) + len(self._counts))
         # the pool's arrays are donated; the counters are not, so that a

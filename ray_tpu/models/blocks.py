@@ -218,37 +218,45 @@ class Mamba2:
         return self.out(y, z, layer), conv, ssd
 
 
-def paged_kv_decode(k_pool, btab, positions, impl: str, pack=lambda x: x):
+def paged_kv_decode(k_pool, btab, positions, impl: str, pack=lambda x: x,
+                    window: int | None = None):
     """The pool part of a decode's grouped-query layers: one position of each
     of many sequences (``btab`` (S, T) their block tables).  Returns
     ``step(q, k, v, k_pool, v_pool, base) -> (att, k_pool, v_pool)``: K and V
     written at the layer's ``base`` (its first block in the pools' flat
     view), each as ``pack`` lays a token's heads into the pool's rows, then
-    the paged attention."""
+    the paged attention.  With a ``window`` a query sees the last ``window``
+    keys alone, the walk starts at the first block it still sees
+    (``ops.gqa_attention``) and the scope is ``window_attention``."""
+    scope = "gqa_attention" if window is None else "window_attention"
     bs = k_pool.shape[3]
     phys = jnp.take_along_axis(btab, (positions // bs)[:, None], axis=1)[:, 0]
     write = _slots_write(phys, positions % bs, bs)
 
     def step(q, k, v, k_pool, v_pool, base):
         k_pool, v_pool = write(k_pool, pack(k), base), write(v_pool, pack(v), base)
-        with jax.named_scope("gqa_attention"):
-            att = gqa_paged_attention(q, k_pool, v_pool, btab + base, positions, impl=impl)
+        with jax.named_scope(scope):
+            att = gqa_paged_attention(
+                q, k_pool, v_pool, btab + base, positions, impl=impl, window=window)
         return att, k_pool, v_pool
 
     return step
 
 
-def paged_kv_chunk(k_pool, btab, positions, start, n_valid, pack=lambda x: x):
+def paged_kv_chunk(k_pool, btab, positions, start, n_valid, pack=lambda x: x,
+                   window: int | None = None):
     """The same for a chunk of ONE sequence at ``positions`` (``start ..``),
     the first ``n_valid`` real: the chunk's K and V written, then its queries
-    (in the pool's dtype) against the sequence's ``start + n_valid`` tokens."""
+    (in the pool's dtype) against the sequence's ``start + n_valid`` tokens
+    (each against its last ``window`` alone, where one is given)."""
     write = _chunk_write(btab, start, n_valid, positions.shape[0], k_pool.shape[3])
 
     def step(q, k, v, k_pool, v_pool, base):
         k_pool, v_pool = write(k_pool, pack(k), base), write(v_pool, pack(v), base)
         with jax.named_scope("chunk_attention"):
             att = gqa_chunk_attention(
-                q.astype(k_pool.dtype), k_pool, v_pool, btab + base, positions, start + n_valid)
+                q.astype(k_pool.dtype), k_pool, v_pool, btab + base, positions, start + n_valid,
+                window)
         return att, k_pool, v_pool
 
     return step

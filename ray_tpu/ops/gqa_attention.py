@@ -20,6 +20,13 @@ key-value heads, query head ``i`` reads key-value head ``i // (H / K)``.
   ``jax.numpy``: the scores of a run go through HBM (PERF.md section 5 has
   what that costs on the chip).
 * ``rotary_half`` — rotary over the whole head in the half-split form.
+
+Both attentions take a ``window``: a query at position ``p`` then sees the
+keys ``> p - window`` alone, the walk STARTS at the first block the step's
+first query still sees (``ops.paged_attention.first_window_block``) and no
+table entry before that block is read, so a window layer's work and bytes
+follow ``min(context, window)`` and its pool may take those blocks back
+(``llm.cache.LayerTypedPool``).
 """
 
 from __future__ import annotations
@@ -27,7 +34,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.paged_attention import NEG_INF, paged_verify_attention
+from ray_tpu.ops.paged_attention import (
+    NEG_INF, first_window_block, paged_verify_attention)
 
 #: blocks a step of the chunk walk gathers: 512 tokens at a block of 128
 _RUN_TOKENS = 512
@@ -45,7 +53,8 @@ def rotary_half(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def gqa_paged_attention(q, k_pool, v_pool, tables, positions, impl: str = "auto"):
+def gqa_paged_attention(q, k_pool, v_pool, tables, positions, impl: str = "auto",
+                        window: int | None = None):
     """q: (rows, H, e) in the pools' dtype; pools: (blocks, K, block, e);
     tables: (rows, tmax) int32; positions: (rows,) int32, the query's position
     (its own k/v already written).  Returns (rows, H, e) in q's dtype.
@@ -72,20 +81,21 @@ def gqa_paged_attention(q, k_pool, v_pool, tables, positions, impl: str = "auto"
         grouped = laid.astype(k_pool.dtype).reshape(
             rows, kv, pack * group, pack * e).transpose(0, 2, 1, 3)
     pos = jnp.broadcast_to(positions[:, None], grouped.shape[:2])
-    att = paged_verify_attention(grouped, k_pool, v_pool, tables, pos, impl=impl)
+    att = paged_verify_attention(grouped, k_pool, v_pool, tables, pos, impl=impl, window=window)
     if pack == 1:
         return att.transpose(0, 2, 1, 3).reshape(rows, h, e)
     att = att.transpose(0, 2, 1, 3).reshape(rows, kv, pack, group, pack, e)
     return jnp.stack([att[:, :, j, :, j] for j in range(pack)], axis=2).reshape(rows, h, e)
 
 
-def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx):
+def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx, window: int | None = None):
     """q: (C, H, e) in the pools' dtype, at ``positions`` (C,) of ONE
     sequence; pools: (blocks, K, block, e), or ``pack`` heads a row (above);
     table: (tmax,) int32; ``n_ctx``:
     how many positions of the sequence are written (the chunk's last valid
-    one, plus one).  Query ``c`` attends every position ``<= positions[c]``.
-    Returns (C, H, e) float32."""
+    one, plus one).  Query ``c`` attends every position ``<= positions[c]``
+    (and ``> positions[c] - window``: the walk then starts at the run that
+    holds query 0's first visible block).  Returns (C, H, e) float32."""
     c, h, e = q.shape
     pack = k_pool.shape[3] // e
     kv, bs = k_pool.shape[1] * pack, k_pool.shape[2]
@@ -109,7 +119,10 @@ def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx):
         k, v = tokens_of(k_pool, ids), tokens_of(v_pool, ids)
         scores = jnp.einsum("kme,kte->kmt", qm, k,
                             preferred_element_type=jnp.float32) * e**-0.5
-        seen = (r * span + jnp.arange(span))[None, None, :] <= reach
+        at = (r * span + jnp.arange(span))[None, None, :]
+        seen = at <= reach
+        if window is not None:
+            seen &= at > reach - window
         scores = jnp.where(seen, scores, NEG_INF)
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -120,8 +133,9 @@ def gqa_chunk_attention(q, k_pool, v_pool, table, positions, n_ctx):
         return m_new, l_new, acc * alpha + pv
 
     rows = qm.shape[1]
+    first_run = 0 if window is None else first_window_block(positions[0], window, bs) // run
     _, l, acc = jax.lax.fori_loop(
-        0, (n_ctx + span - 1) // span, over_runs,
+        first_run, (n_ctx + span - 1) // span, over_runs,
         (jnp.full((kv, rows, 1), NEG_INF, jnp.float32),
          jnp.zeros((kv, rows, 1), jnp.float32),
          jnp.zeros((kv, rows, e), jnp.float32)))

@@ -109,6 +109,19 @@ def auto_impl(block_size: int, head_dim: int) -> str:
     return "xla"
 
 
+def first_window_block(position, window: int, block_size: int):
+    """The first block a query at ``position`` still sees with a window of
+    ``window`` tokens (keys ``> position - window``): every block before it
+    lies WHOLLY behind the window.  ``llm.cache.LayerTypedPool`` releases by
+    the same rule, on the host."""
+    return jnp.maximum(position - (window - 1), 0) // block_size
+
+
+def window_blocks(tokens: int, block_size: int) -> int:
+    """The most blocks ``tokens`` consecutive positions touch."""
+    return -(-tokens // block_size) + 1
+
+
 # ---------------------------------------------------------------------------
 # XLA reference path
 # ---------------------------------------------------------------------------
@@ -176,16 +189,27 @@ def paged_verify_attention_xla(
     v_pool: jax.Array,
     block_tables: jax.Array,
     positions: jax.Array,
+    window: int | None = None,
 ) -> jax.Array:
     """Multi-query verification attention (see module doc).  q: (slots, w,
     heads, d); pools: (num_blocks, heads, block, d); block_tables:
     (slots, tmax) int32; positions: (slots, w) int32 — the absolute cache
     position of each query (its own k/v already written).  Query (s, i)
     attends every cache position ``<= positions[s, i]`` — causal across
-    the window because the window's positions are consecutive.  Returns
-    (slots, w, heads, d) in q.dtype, fp32 softmax accumulation."""
+    the window because the window's positions are consecutive — and, with
+    ``window``, ``> positions[s, i] - window``: only the table's
+    ``window_blocks`` entries from the first query's first visible block on
+    are gathered (``first_window_block``), so an entry behind them is never
+    read.  Returns (slots, w, heads, d) in q.dtype, fp32 softmax
+    accumulation."""
     s, w, h, d = q.shape
     scale = d**-0.5
+    if window is not None:
+        bs, tmax = k_pool.shape[2], block_tables.shape[1]
+        n = min(tmax, window_blocks(window + w - 1, bs))
+        lo = jnp.minimum(first_window_block(positions[:, 0], window, bs), tmax - n)
+        block_tables = jax.vmap(
+            lambda row, at: jax.lax.dynamic_slice_in_dim(row, at, n))(block_tables, lo)
     k = k_pool[block_tables]  # (slots, tmax, heads, block, d)
     v = v_pool[block_tables]
     k = k.transpose(0, 2, 1, 3, 4).reshape(s, h, -1, d)
@@ -193,10 +217,12 @@ def paged_verify_attention_xla(
     logits = jnp.einsum(
         "swhd,shkd->swhk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
-    mask = (
-        jnp.arange(k.shape[2])[None, None, None, :]
-        <= positions[:, :, None, None]
-    )
+    at = jnp.arange(k.shape[2])[None, None, None, :]
+    if window is not None:
+        at = at + (lo * bs)[:, None, None, None]
+    mask = at <= positions[:, :, None, None]
+    if window is not None:
+        mask &= at > positions[:, :, None, None] - window
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("swhk,shkd->swhd", probs, v.astype(jnp.float32))
@@ -243,17 +269,24 @@ def _paged_verify_kernel(
     run: int,
     tmax: int,
     scale: float,
+    window: int | None,
 ):
     s = pl.program_id(0)
     slots = pl.num_programs(0)
     rows, d = q_ref.shape[1], q_ref.shape[2]
     cols = run * heads * block_size
 
+    def first_block(slot):
+        # with a ``window``: the first block the slot's FIRST query still
+        # sees; the walk starts there, and no table entry before it is read
+        return jax.lax.div(jnp.maximum(pos_ref[slot * w] - (window - 1), 0), block_size)
+
     def blocks_held(slot):
         # the window is consecutive, so the LAST query's position bounds
         # the valid cache
         length = pos_ref[slot * w + (w - 1)] + 1
-        return jnp.minimum(jax.lax.div(length + (block_size - 1), block_size), tmax)
+        held = jnp.minimum(jax.lax.div(length + (block_size - 1), block_size), tmax)
+        return held if window is None else held - first_block(slot)
 
     def for_run(slot, r, buf, act):
         """``act`` on the K and V copy of every block run ``r`` of ``slot``
@@ -261,7 +294,10 @@ def _paged_verify_kernel(
         n = jnp.minimum(run, blocks_held(slot) - r * run)
 
         def body(i, carry):
-            blk = tables_ref[slot * tmax + r * run + i]
+            if window is None:
+                blk = tables_ref[slot * tmax + r * run + i]
+            else:
+                blk = tables_ref[slot * tmax + first_block(slot) + r * run + i]
             act(pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[buf, i], sems.at[0, buf]))
             act(pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[buf, i], sems.at[1, buf]))
             return carry
@@ -311,6 +347,9 @@ def _paged_verify_kernel(
             row_pos = jnp.where(
                 jax.lax.div(row, heads) == i, pos_ref[s * w + i], row_pos
             )
+        if window is not None:
+            # positions count from the slot's first block walked
+            row_pos = row_pos - first_block(s) * block_size
         reach = jnp.where(
             col_head == jax.lax.rem(row, heads), row_pos - col_pos, -1
         )                                                       # (rows, cols)
@@ -348,6 +387,8 @@ def _paged_verify_kernel(
                 preferred_element_type=jnp.float32,
             ) * scale                                           # (rows, cols)
             seen = reach >= r * (run * block_size)
+            if window is not None:
+                seen &= reach < r * (run * block_size) + window
             scores = jnp.where(seen, scores, NEG_INF)
             m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -379,7 +420,7 @@ def _paged_verify_kernel(
         o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
+def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions, window=None):
     slots, w, heads, d = q.shape
     _, _, block_size, _ = k_pool.shape
     tmax = block_tables.shape[1]
@@ -406,7 +447,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions):
     out = pl.pallas_call(
         functools.partial(
             _paged_verify_kernel, heads=heads, block_size=block_size, w=w,
-            run=run, tmax=tmax, scale=d**-0.5,
+            run=run, tmax=tmax, scale=d**-0.5, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, rows, d), q.dtype),
@@ -467,6 +508,7 @@ def paged_verify_attention(
     block_tables: jax.Array,
     positions: jax.Array,
     impl: str = "auto",
+    window: int | None = None,
 ) -> jax.Array:
     """Multi-query verification attention over a paged KV cache (see
     module doc): ``w`` consecutive queries per slot for speculative-decode
@@ -474,11 +516,16 @@ def paged_verify_attention(
 
     q: (slots, w, heads, head_dim); k_pool/v_pool: (num_blocks, heads,
     block_size, head_dim); block_tables: (slots, tmax) int32; positions:
-    (slots, w) int32.  ``impl``: auto | xla | pallas.
+    (slots, w) int32.  ``impl``: auto | xla | pallas.  ``window``: a query
+    at position ``p`` sees the keys ``> p - window`` alone, and both paths
+    START at the first block the slot's first query still sees
+    (``first_window_block``): work and bytes follow ``min(length, window)``,
+    and a table entry behind that block (one its pool has taken back) is
+    never read.
     """
     with jax.named_scope("paged_attention"):
         if _resolve_impl(impl, k_pool) == "xla":
             return paged_verify_attention_xla(
-                q, k_pool, v_pool, block_tables, positions
+                q, k_pool, v_pool, block_tables, positions, window
             )
-        return _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions)
+        return _paged_verify_pallas(q, k_pool, v_pool, block_tables, positions, window)

@@ -85,6 +85,7 @@ _FAMILIES = {
     "lfm2_moe": ("ray_tpu.models.lfm2", "Lfm2MoeConfig", "lfm2_moe_init", "Lfm2MoeConfig"),
     "nemotron_h": ("ray_tpu.models.nemotron_h", "NemotronHConfig", "nemotron_h_init",
                    "NemotronHConfig"),
+    "afmoe": ("ray_tpu.models.afmoe", "AfmoeConfig", "afmoe_init", "AfmoeConfig"),
 }
 
 

@@ -396,6 +396,31 @@ def test_grouped_query_heads_through_the_paged_kernel_compile_for_a_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
 
 
+@pytest.mark.parametrize("layers,blocks,window", [
+    (1, 16 * 262 + 1, None),   # trinity-large-ep8-l5-1chip: the ONE full layer's sub-pool
+    (4, 16 * 37 + 1, 4096),    # its four window layers': the walk starts at the row's first block
+], ids=["full", "window"])
+def test_a_windowed_walk_through_the_paged_kernel_compiles_for_a_v5e(
+        one_chip, monkeypatch, layers, blocks, window):
+    """Trinity's decode attention: 48 query heads on 8 key-value heads of 128
+    (w = 6) over a table of 262 blocks; a window layer's kernel reads its
+    lower bound from the positions, as it reads its length."""
+    from ray_tpu.ops import gqa_attention as ga
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)  # Mosaic, not the interpreter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers * blocks, 8, 128, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, p: ga.gqa_paged_attention(q, k, v, t, p, impl="pallas", window=window)
+    ).lower(sds((16, 48, 128), jnp.bfloat16), pool, pool,
+            sds((16, 262), jnp.int32), sds((16,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
 def _padded_bytes(dims, layout, itemsize):
     """The bytes of an array of ``dims`` in a compiled program's ``layout``
     (``minor to major:T(tile)...``): the tile's axes padded to whole tiles."""

@@ -27,7 +27,7 @@ Two readings a configuration of ``BENCHMARK.json`` that has an ``engine``:
   (decode, prefill, and where the engine has them verify and fork) lowered
   again at the operands of its first call (``StepRunner._first_operands``).
 * ``v5e`` (families that bring their own layer programs: ``cache_kind``
-  ``hybrid``, ``paged`` or ``state``): the decode and the prefill chunk at the PUBLISHED
+  ``hybrid``, ``paged``, ``windowed`` or ``state``): the decode and the prefill chunk at the PUBLISHED
   sizes and the configuration's engine, lowered for a described v5e chip with
   the Pallas kernels on (the dispatch rules are told they are on a TPU), from
   shapes alone: nothing is compiled and nothing runs.
@@ -92,14 +92,14 @@ def _v5e(config: dict, H, one_chip) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm.cache import KVBlockPool
+    from ray_tpu.llm.cache import KVBlockPool, LayerTypedConfig
     from ray_tpu.llm.model_runner import host_batch, pack_knobs
     from ray_tpu.llm.state_runner import HybridModelRunner, StateModelRunner
 
     sizes = H.sizes(config, False)
     cfg = H.family_piece(config, "model_config")(sizes)
     kind = getattr(cfg, "cache_kind", "kv")
-    if kind not in ("hybrid", "paged", "state"):
+    if kind not in ("hybrid", "paged", "windowed", "state"):
         return {}
     init, e = H.family_piece(config, "program_init")(), sizes["engine"]
 
@@ -120,8 +120,20 @@ def _v5e(config: dict, H, one_chip) -> dict:
         runner = HybridModelRunner(cfg, params, e["block_size"])
         body, table = runner.body, e["max_blocks_per_seq"]
         lay = body.kv_layout()
-        shape = (lay["n_layers"], e["num_blocks"], lay["n_heads"], e["block_size"], lay["head_dim"])
-        pool = [jax.ShapeDtypeStruct(shape, jnp.dtype(lay["dtype"]))] * KVBlockPool.n_arrays(**lay)
+        if kind == "windowed":  # K and V of each layer kind (cache.LayerTypedPool)
+            geo = LayerTypedConfig(e["num_blocks"], e["block_size"], table, lay["window"],
+                                   e["prefill_chunk"], slots)
+            pool = [jax.ShapeDtypeStruct(
+                (lay["kinds"][kind_], blocks, lay["n_heads"], e["block_size"], lay["head_dim"]),
+                jnp.dtype(lay["dtype"]))
+                for kind_, blocks in (("full", geo.num_blocks), ("window", geo.window_num_blocks))
+                for _ in range(2)]
+            table *= 2  # a row is both kinds' tables
+        else:
+            shape = (lay["n_layers"], e["num_blocks"], lay["n_heads"], e["block_size"],
+                     lay["head_dim"])
+            pool = [jax.ShapeDtypeStruct(shape, jnp.dtype(lay["dtype"]))] * KVBlockPool.n_arrays(
+                **lay)
         hybrid = kind == "hybrid"
         leaves = body.state_leaves(e["block_size"]) if hybrid else {}
         pools = [sds(p) for p in pool] + [
