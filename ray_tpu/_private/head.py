@@ -97,6 +97,10 @@ EVENT_NAMES = (
 #: - _outbox: deque of worker-bound sends, appended under the head lock,
 #:   drained by the single _flush_lock holder; deque append/popleft are
 #:   GIL-atomic, which is exactly why the outbox is a deque.
+#: - _stream_pushes: deque of (sink, entry) made under the head lock and
+#:   sent after it by whichever thread gets there (append/popleft are
+#:   GIL-atomic); an entry says where its items start, so two flushers
+#:   that split a stream's entries between them break no order.
 #: - ClientSession.refs/.actors: written only by the session's OWN conn
 #:   thread while connected (one thread per client conn — _session_track
 #:   docstring); the health loop's expiry sweep runs only after the grace
@@ -104,6 +108,7 @@ EVENT_NAMES = (
 LOCKFREE = (
     "Head._io_conns: atomic",
     "Head._outbox: atomic",
+    "Head._stream_pushes: atomic",
     "ClientSession.refs: atomic",
     "ClientSession.actors: atomic",
 )
@@ -868,6 +873,16 @@ class Head:
         # self.lock), not on self.cv: an item wakes the stream it belongs
         # to, not every blocked consumer of the cluster (``_wake_stream``)
         self.streams: dict[bytes, dict] = {}
+        # the PUSHED path (``_stream_subscribe``): a consumer that wants
+        # values subscribes once and is sent every item from then on.
+        # sink -> the task ids it subscribed to, for the consumer that dies;
+        # a sink is ("conn", connection) or ("fn", the in-process driver's)
+        self._stream_subs: dict[tuple, set] = {}
+        # (sink, entry) made under the lock, sent after it
+        # (``_flush_stream_pushes``): one ``stream_push`` message a sink
+        self._stream_pushes: deque = deque()
+        # streams a task_done ended, pushed once its results are stored
+        self._streams_ended: list = []
         # disposed stream ids (bounded): late stream_items/task_done from a
         # producer that had not yet seen the cancel must NOT resurrect the
         # stream entry (it would leak the items forever — nobody consumes a
@@ -1192,6 +1207,8 @@ class Head:
         self._io_readers.pop(conn, None)
         ent = self._io_conns.pop(conn, None)
         self._io_conns_gen = next(self._io_gen_src)
+        if ("conn", conn) in self._stream_subs:
+            self._drop_stream_consumer(conn)  # the streams it was pushed
         if ent is not None:
             self._on_worker_disconnect(ent[0])
             self.flush_outbox()
@@ -1325,6 +1342,9 @@ class Head:
             if session.conn is conn:  # a reconnect may already own the session
                 session.conn = None
                 session.disconnected_at = time.monotonic()
+        # the streams this connection was pushed: the client fails their
+        # generators at the drop (``RemoteDriverContext._pump_loop``)
+        self._drop_stream_consumer(conn)
 
     def _reap_client_sessions(self) -> None:
         """Health-loop tick: release what clients that never came back held
@@ -1397,7 +1417,7 @@ class Head:
         return node_id
 
     def _dispatch_request(self, conn, worker, seq, method, payload, remote: bool = False):
-        if method in ("subscribe", "unsubscribe"):
+        if method in ("subscribe", "unsubscribe", "stream_subscribe"):
             import functools
 
             handler = functools.partial(getattr(self, "_rpc_" + method), conn)
@@ -1515,10 +1535,9 @@ class Head:
             out = ("resp", seq, False, e if _picklable(e) else rex.RayError(repr(e)))
         self.flush_outbox()
         try:
-            if worker is not None:
-                with worker.send_lock:
-                    ser.conn_send(conn, out)
-            else:
+            # (a worker's send_lock; a driver connection's own, which the
+            # publisher and the stream pushes to it take too)
+            with worker.send_lock if worker is not None else self._conn_lock(conn):
                 ser.conn_send(conn, out)
         except (OSError, ValueError, BrokenPipeError):
             pass
@@ -1874,7 +1893,12 @@ class Head:
         each worker's own FIFO (including non-dispatch messages like exit,
         which flush that worker's pending batch first) is preserved. Each
         spec is header-split per worker at write time (_wire_spec): static
-        per-function fields ship once, steady-state bodies reference them."""
+        per-function fields ship once, steady-state bodies reference them.
+
+        What the pushed streams queued under the lock (an end, a failure:
+        ``_push_stream``) leaves here too, before the worker sends."""
+        if self._stream_pushes:
+            self._flush_stream_pushes()
         while self._outbox:
             if not self._flush_lock.acquire(blocking=False):
                 return  # active drainer will pick ours up (or we re-enter)
@@ -2535,14 +2559,23 @@ class Head:
         the one-item case of ``_on_stream_items``."""
         self._on_stream_items(wh, (payload,))
 
+    def _stream_state(self, task_id: bytes) -> dict:
+        """Lock held. The stream's record, made at whatever comes first:
+        an item, an ask, a subscription, the end."""
+        st = self.streams.get(task_id)
+        if st is None:
+            st = self.streams[task_id] = {"items": {}, "count": None, "next": 0}
+        return st
+
     def _on_stream_items(self, wh: WorkerHandle, payloads) -> None:
         """Items of one producer's streaming tasks, in the order they were
-        yielded: store each one's object and publish its index so blocked
-        ``stream_next`` calls wake (reference: ReportGeneratorItemReturns,
-        task_manager.cc).  ONE message of the batched producer path
-        (``_private.stream_sink``: a step's tokens, an item a stream or
-        several) costs one take of the lock, one wake-up a stream and one
-        ``cv.notify_all()``, however many items it carries."""
+        yielded: store each one's object, wake the ``stream_next`` calls
+        blocked on its stream and push it to the stream's subscriber
+        (reference: ReportGeneratorItemReturns, task_manager.cc).  ONE
+        message of the batched producer path (``_private.stream_sink``: a
+        step's tokens, an item a stream or several) costs one take of the
+        lock, one ``cv.notify_all()`` and ONE ``stream_push`` message a
+        subscribed connection, however many items and streams it carries."""
         located = [(p, self._normalize_locator(p["locator"])) for p in payloads]
         with self.lock:
             now = time.perf_counter()
@@ -2557,32 +2590,33 @@ class Head:
                     if ent is not None:
                         self._maybe_evict(payload["obj_id"], ent)
                     continue
-                st = self.streams.setdefault(
-                    task_id, {"items": {}, "count": None, "next": 0}
-                )
+                st = self._stream_state(task_id)
                 if ent is not None:
                     ent.refcount += 1  # held by the stream until handed out/disposed
                 st["items"][payload["index"]] = payload["obj_id"]
-                # the `head_hold` leg starts (rpc_stream_next ends it); the
+                # the `head_hold` leg starts (the hand-out ends it); the
                 # producer is remembered for the ack of an item handed out
                 # after its task is done and gone from self.tasks
                 st.setdefault("t_in", {})[payload["index"]] = now
                 st["wh"] = wh
                 woken[task_id] = st
-            for st in woken.values():
-                self._wake_stream(st)
+            for task_id, st in woken.items():
+                self._wake_stream(task_id, st)
             if woken:
                 self.cv.notify_all()  # the objects' readiness, as every store
+        self._flush_stream_pushes()
 
-    def _wake_stream(self, st: Optional[dict]) -> None:
-        """Lock held. Wake the consumers blocked in ``stream_next`` on this
-        stream: a new item, its end, its failure or its disposal."""
-        cond = st.get("cond") if st is not None else None
+    def _wake_stream(self, task_id: bytes, st: dict) -> None:
+        """Lock held. A new item of this stream, its end or its failure:
+        wake the consumers blocked in ``stream_next`` on it, and queue for
+        its subscriber what it has not been sent yet."""
+        cond = st.get("cond")
         if cond is not None:
             cond.notify_all()
+        if "sub" in st:
+            self._push_stream(task_id, st)
 
-    def rpc_stream_next(self, task_id, index, timeout=None, delivered=None,
-                        values=False):
+    def rpc_stream_next(self, task_id, index, timeout=None, delivered=None):
         """Blocking: ('item', obj_id) when the index exists; ('end', count)
         past the final item; ('error', completion_obj_id) when the task
         failed (the completion object holds the exception). Acks the
@@ -2592,15 +2626,9 @@ class Head:
         items it has written out since it last asked (the producer's
         ``head_hold`` and ``written`` readings, ``_private.stream_stats``).
 
-        With ``values`` (a consumer that wants the items, not references to
-        them: ``ObjectRefGenerator.values``) the answer is ('items', [...]):
-        EVERY item that has arrived from ``index`` on, in one ask, so a
-        consumer that has fallen behind catches up at one round trip for
-        all of them.  An item stored inline rides the answer as ('v', its
-        bytes) and is released here: no object id leaves, nothing is fetched
-        or freed for it afterwards.  Any other rides as ('r', obj_id), held
-        by the consumer as the plain answer's is.  One ack covers the lot;
-        its ``hold_s`` is then a list, an entry an item."""
+        This is the way of a consumer that wants REFERENCES, one ask an
+        item (``ObjectRefGenerator.__next__``).  One that wants the values
+        subscribes instead and is pushed to (``_stream_subscribe``)."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self.lock:
             while True:
@@ -2608,23 +2636,18 @@ class Head:
                     return ("end", 0)
                 st = self.streams.get(task_id)
                 if st is not None:
-                    if values and index in st["items"]:
-                        out, ack = self._take_stream_items(task_id, st, index)
-                        break
                     if index in st["items"]:
                         out = ("item", st["items"][index])
                         st["next"] = max(st["next"], index + 1)
                         ack = {"task_id": task_id, "consumed": index + 1}
                         t_in = st.get("t_in", {}).pop(index, None)
                         if t_in is not None:
-                            ack["hold_s"] = time.perf_counter() - t_in
+                            ack["hold_s"] = [time.perf_counter() - t_in]
                         break
                     if st["count"] is not None and index >= st["count"]:
-                        comp = st.get("completion")
+                        comp = self._stream_error(st)
                         if comp is not None:
-                            ent = self.objects.get(comp)
-                            if ent is not None and ent.is_error:
-                                return ("error", comp)
+                            return ("error", comp)
                         out = ("end", st["count"])
                         # the last write gaps ride an ack of their own
                         ack = {"task_id": task_id, "consumed": st["count"]} if delivered else None
@@ -2635,9 +2658,7 @@ class Head:
                 if self._shutdown:
                     raise rex.RayError("shutting down")
                 if st is None:  # asked for before the first item arrived
-                    st = self.streams.setdefault(
-                        task_id, {"items": {}, "count": None, "next": 0}
-                    )
+                    st = self._stream_state(task_id)
                 cond = st.get("cond")
                 if cond is None:
                     cond = st["cond"] = threading.Condition(self.lock)
@@ -2647,29 +2668,147 @@ class Head:
         if ack is not None and wh is not None and wh.alive:
             if delivered:
                 ack["delivered"] = delivered
-            wh.send(("stream_ack", ack))
+            wh.send(("stream_ack", [ack]))
         return out
 
-    def _take_stream_items(self, task_id, st: dict, index: int) -> tuple:
-        """Lock held. Hand out every item of the stream from ``index`` on
-        (``rpc_stream_next`` with ``values``): the answer and its ack."""
-        items, t_in = st["items"], st.get("t_in", {})
-        now = time.perf_counter()
-        out, holds = [], []
-        while index in items:
-            oid = items[index]
-            ent = self.objects.get(oid)
-            if ent is not None and ent.small is not None and not ent.is_error:
-                out.append(("v", ent.small))
-                ent.refcount -= 1  # the stream's hold: the value has left
-                self._maybe_evict(oid, ent)
+    def _stream_error(self, st: dict) -> Optional[bytes]:
+        """Lock held, the stream over: its completion object's id if that
+        holds the producer's exception, else None."""
+        comp = st.get("completion")
+        ent = self.objects.get(comp) if comp is not None else None
+        return comp if ent is not None and ent.is_error else None
+
+    # -- the pushed path: a consumer that wants values subscribes once ------
+
+    def _rpc_stream_subscribe(self, conn, task_id, index):
+        self._stream_subscribe(("conn", conn), task_id, index)
+
+    def stream_subscribe_local(self, fn, task_id: bytes, index: int) -> None:
+        """In-process subscription (the driver shares this process): the
+        same delivery as a direct call of ``fn(entries)``."""
+        self._stream_subscribe(("fn", fn), task_id, index)
+
+    def _stream_subscribe(self, sink: tuple, task_id: bytes, index: int) -> None:
+        """``ObjectRefGenerator.values`` starts: from here on every item of
+        the stream from ``index`` on is SENT to ``sink`` as it arrives
+        (``_push_stream``), and the end or the failure after the last.
+        What has arrived already leaves at once (a consumer that comes late
+        catches up in one message), so does the end of a stream that is
+        over or disposed.  Nothing parks in the head for a pushed stream."""
+        with self.lock:
+            if task_id in self._disposed_streams:
+                self._stream_pushes.append((sink, (task_id, index, [], (0, None))))
             else:
-                out.append(("r", oid))
-            t = t_in.pop(index, None)
-            holds.append(0.0 if t is None else now - t)
-            index += 1
-        st["next"] = max(st["next"], index)
-        return ("items", out), {"task_id": task_id, "consumed": index, "hold_s": holds}
+                st = self._stream_state(task_id)
+                st["sub"] = sink
+                st["next"] = max(st["next"], index)
+                st["acked"] = st["next"]  # what the consumer has said it took
+                self._stream_subs.setdefault(sink, set()).add(task_id)
+                self._push_stream(task_id, st)
+        self._flush_stream_pushes()
+
+    def _push_stream(self, task_id: bytes, st: dict) -> None:
+        """Lock held. Hand out to the stream's subscriber every item it has
+        not been sent, and the end once the last is out: an entry
+        ``(task_id, index of the first item, items, end)`` queued for
+        ``_flush_stream_pushes``.  An item stored inline rides as ('v', its
+        bytes) and is released here: no object id leaves, nothing is
+        fetched or freed for it afterwards.  Any other rides as ('r',
+        obj_id), a reference the consumer holds from then on.  ``end`` is
+        None or ``(count, completion)``, ``completion`` the id of the object
+        that holds the producer's exception, or None.  How long each item
+        lay here (``hold_s``) waits in ``holds`` for the ack that says the
+        consumer took it."""
+        start = index = st["next"]
+        items, out = st["items"], []
+        if index in items:
+            t_in, holds = st.get("t_in", {}), st.setdefault("holds", [])
+            now = time.perf_counter()
+            while index in items:
+                oid = items[index]
+                ent = self.objects.get(oid)
+                if ent is not None and ent.small is not None and not ent.is_error:
+                    out.append(("v", ent.small))
+                    ent.refcount -= 1  # the stream's hold: the value has left
+                    self._maybe_evict(oid, ent)
+                else:
+                    out.append(("r", oid))
+                t = t_in.pop(index, None)
+                holds.append(0.0 if t is None else now - t)
+                index += 1
+            st["next"] = index
+        end = None
+        count = st["count"]
+        if count is not None and index >= count and not st.get("end_pushed"):
+            st["end_pushed"] = True
+            end = (count, self._stream_error(st))
+        if out or end is not None:
+            self._stream_pushes.append((st["sub"], (task_id, start, out, end)))
+
+    def _flush_stream_pushes(self) -> None:
+        """After the lock: send what ``_push_stream`` queued, ONE
+        ``stream_push`` message a subscribed connection whatever the number
+        of streams and items (a step's sixteen tokens for sixteen streams
+        of one proxy are one message), a direct call for the driver in this
+        process.  Two threads may flush at once: an entry says where its
+        items start, and the consumer puts entries in order."""
+        pushes = self._stream_pushes
+        if not pushes:
+            return
+        by_sink: dict = {}
+        while True:
+            try:
+                sink, entry = pushes.popleft()
+            except IndexError:
+                break
+            by_sink.setdefault(sink, []).append(entry)
+        for (kind, to), entries in by_sink.items():
+            try:
+                if kind == "fn":
+                    to(entries)
+                else:
+                    with self._conn_lock(to):
+                        ser.conn_send(to, ("stream_push", entries))
+            except (OSError, ValueError, BrokenPipeError):
+                pass  # the consumer is gone: its disconnect disposes its streams
+            except Exception as e:  # noqa: BLE001
+                warn_throttled("stream push", e)
+
+    def rpc_stream_consumed(self, acks) -> None:
+        """A consumer's coalesced ack: ``(task_id, consumed, delivered)``
+        for every pushed stream of its whose iterator TOOK items since it
+        last said so (``BaseContext._flush_stream_acks``).  Each gets its
+        items' ``hold_s`` and goes on to the producing worker, ONE
+        ``stream_ack`` message a worker: its window opens by what the
+        consumer took, never by what was pushed."""
+        by_wh: dict = {}
+        with self.lock:
+            for task_id, consumed, delivered in acks:
+                st = self.streams.get(task_id)
+                wh = st.get("wh") if st is not None else None
+                if wh is None or not wh.alive:
+                    continue  # disposed, or the producer is gone
+                ack = {"task_id": task_id, "consumed": consumed}
+                n = consumed - st.get("acked", consumed)
+                if n > 0:
+                    holds = st.get("holds", [])
+                    ack["hold_s"] = holds[:n]
+                    del holds[:n]
+                    st["acked"] = consumed
+                if delivered:
+                    ack["delivered"] = delivered
+                by_wh.setdefault(wh, []).append(ack)
+        for wh, out in by_wh.items():
+            wh.send(("stream_ack", out))
+
+    def _drop_stream_consumer(self, conn) -> None:
+        """A consuming connection is gone (its worker died, a client
+        disconnected): dispose every stream it subscribed to, as the
+        consumer would have."""
+        with self.lock:
+            subs = self._stream_subs.pop(("conn", conn), ())
+        for task_id in list(subs):
+            self.rpc_stream_dispose(task_id)
 
     def rpc_stream_dispose(self, task_id):
         """Consumer dropped its generator: cancel the producer if it is
@@ -2678,11 +2817,18 @@ class Head:
         with self.lock:
             st = self.streams.pop(task_id, None)
             self._disposed_streams[task_id] = True
-            self._wake_stream(st)
             while len(self._disposed_streams) > 4096:
                 self._disposed_streams.pop(next(iter(self._disposed_streams)))
             running = task_id in self.tasks
             if st is not None:
+                cond = st.get("cond")
+                if cond is not None:
+                    cond.notify_all()  # a stream_next blocked on it meets the end
+                subs = self._stream_subs.get(st.get("sub"))
+                if subs is not None:
+                    subs.discard(task_id)
+                    if not subs:
+                        del self._stream_subs[st["sub"]]
                 for idx, oid in st["items"].items():
                     if idx >= st["next"]:
                         ent = self.objects.get(oid)
@@ -2701,26 +2847,34 @@ class Head:
             return
         if spec["task_id"] in self._disposed_streams:
             return
-        st = self.streams.setdefault(
-            spec["task_id"], {"items": {}, "count": None, "next": 0}
-        )
+        st = self._stream_state(spec["task_id"])
         if st["count"] is None:
             st["count"] = len(st["items"])
             st["completion"] = spec["return_ids"][0]
-        self._wake_stream(st)
+        self._wake_stream(spec["task_id"], st)
 
     def _finish_stream_locked(self, task_id: bytes, payload: dict):
         """task_done of a streaming task: record the final item count and
         where the completion object (error carrier) lives."""
         if task_id in self._disposed_streams:
             return
-        st = self.streams.setdefault(task_id, {"items": {}, "count": None, "next": 0})
+        st = self._stream_state(task_id)
         st["count"] = payload.get("stream_count", len(st["items"]))
         results = payload.get("results") or []
         if results:
             st["completion"] = results[0][0]
-        self._wake_stream(st)
+        # (the completion object is stored later in this take of the lock:
+        # ``_task_done_locked`` wakes the stream once it is)
+        self._streams_ended.append((task_id, st))
         self.cv.notify_all()
+
+    def _wake_ended_streams(self) -> None:
+        """Lock held, after ``_task_done_locked``: the streams it ended
+        (``_finish_stream_locked``), their completion objects stored now."""
+        if self._streams_ended:
+            for task_id, st in self._streams_ended:
+                self._wake_stream(task_id, st)
+            self._streams_ended.clear()
 
     def _on_task_done(self, wh: WorkerHandle, payload: dict):
         # singular fast lane (the sync round trip): same receipt contract
@@ -2738,6 +2892,7 @@ class Head:
                     results[i] = (rid, nloc)
         with self.lock:
             self._task_done_locked(wh, payload)
+            self._wake_ended_streams()
             self.cv.notify_all()
             self._schedule()
 
@@ -2770,6 +2925,7 @@ class Head:
         with self.lock:
             for payload in payloads:
                 self._task_done_locked(wh, payload)
+            self._wake_ended_streams()
             self.cv.notify_all()
             self._schedule()
 

@@ -267,6 +267,55 @@ def _deserialized_ref(id_bytes: bytes, nonce: bytes = None) -> ObjectRef:
 # --------------------------------------------------------------------------
 
 
+#: how long a context's ack flusher waits for the NEXT of the consumers that
+#: one ``stream_push`` woke to take its item, so that their acks leave as one
+#: message (``BaseContext._stream_ack_loop``).  Sixteen threads take a
+#: step's sixteen items one after another, some 50-100 us apart; one that
+#: does not come holds nobody else's ack back for longer than this.
+_ACK_GATHER_S = 0.001
+
+
+class _Inbox:
+    """A pushed stream at its consumer: what the head sent and the
+    generator's iterator has not taken up yet.  The context holds it by the
+    stream's task id for as long as the generator lives, never the
+    generator itself (which would then never be collected).
+
+    An entry is ``(start, items, end, woke, then)``: ``woke`` says that the
+    push found the iterator parked here, ``then`` is ``(inbox, entry)`` of
+    the NEXT consumer that the same push found parked, or None.  Whoever
+    takes an entry passes ``then`` on before anything else
+    (``BaseContext._on_stream_push`` says why).  ``(None, exception or None,
+    None, False, None)`` ends the wait locally."""
+
+    __slots__ = ("q", "waiting", "retired")
+
+    def __init__(self):
+        self.q: "queue.SimpleQueue" = queue.SimpleQueue()
+        # the iterator is parked on ``q``
+        self.waiting = False
+        self.retired = False  # no iterator will read it again
+
+    def put(self, entry) -> None:
+        self.q.put(entry)
+        if self.retired:
+            self.retire()  # (it retired as this came: nobody would pass it on)
+
+    def retire(self) -> None:
+        """No iterator reads this inbox any more (the stream is over here,
+        closed or collected): what it holds is dropped, and what it holds
+        FOR OTHERS is passed on.  Any thread, a finalizer's too: queue
+        operations alone."""
+        self.retired = True
+        while True:
+            try:
+                then = self.q.get_nowait()[4]
+            except queue.Empty:
+                return
+            if then is not None:
+                then[0].put(then[1])
+
+
 class ObjectRefGenerator:
     """Iterator over a streaming task's per-item ObjectRefs
     (``num_returns="streaming"``; reference: ``ObjectRefGenerator`` in
@@ -278,6 +327,9 @@ class ObjectRefGenerator:
     window on the producer. A mid-stream producer exception is raised from
     ``next()`` once the already-produced items are drained. Dropping the
     generator cancels a still-running producer and frees unconsumed items.
+
+    ``values()`` iterates the items themselves and is PUSHED to: one
+    subscription a stream, no ask an item.
     """
 
     def __init__(self, task_id: bytes, completion_ref: "ObjectRef", ctx):
@@ -287,7 +339,14 @@ class ObjectRefGenerator:
         self._i = 0
         self._done = False
         self._disposed = False
-        self._delivered: list = []  # report_delivered's, until the next ask
+        self._delivered: list = []  # report_delivered's, until the next ack
+        # the pushed path's state, from the first ``values()`` on
+        self._inbox: Optional[_Inbox] = None
+        self._ready: deque = deque()  # items in order, not taken yet
+        self._ahead: dict = {}        # start -> items that came before their turn
+        self._filed = 0               # the index the next item filed gets
+        self._ended: Optional[tuple] = None  # (count, completion id or None)
+        self._woke = False            # the entry being read was expected (see _Inbox)
 
     def __iter__(self):
         return self
@@ -299,15 +358,20 @@ class ObjectRefGenerator:
         """A consumer that passes items on (the HTTP proxy) says what its
         own clock read between the items it has written out since it last
         reported: seconds, one gap an item from the stream's second on.
-        They ride the next ask for an item to the head and the ack to the
-        producing worker, which observes them as the ``written`` station
-        (``_private.stream_stats``); a consumer that never reports leaves
-        that station empty."""
+        They ride the ack of the next item taken (or the next ask for one)
+        to the head and on to the producing worker, which observes them as
+        the ``written`` station (``_private.stream_stats``); a consumer that
+        never reports leaves that station empty."""
         self._delivered.extend(gaps)
 
     def _next(self, timeout: Optional[float]) -> "ObjectRef":
         if self._done or self._disposed:
             raise StopIteration
+        if self._inbox is not None:
+            raise RuntimeError(
+                "this stream is read by values(): its items are pushed here "
+                "as values and cannot be had by reference any more"
+            )
         ask = {"task_id": self._task_id, "index": self._i, "timeout": timeout}
         if self._delivered:
             ask["delivered"], self._delivered = self._delivered, []
@@ -321,28 +385,88 @@ class ObjectRefGenerator:
     def values(self, timeout: Optional[float] = None):
         """Iterate the stream's VALUES, not references to them: for a
         consumer that reads every item once and passes it on (a serve
-        handle).  One ask brings every item that has arrived
-        (``Head.rpc_stream_next`` with ``values``): an item small enough to
-        be stored inline comes in the answer itself and is an object no
-        longer; one that is not comes as a reference and is fetched here,
-        within ``timeout``.  An ask waits as ``next()`` does: until the
-        producer yields, ends or fails."""
-        while not (self._done or self._disposed):
-            ask = {"task_id": self._task_id, "index": self._i,
-                   "timeout": None, "values": True}
-            if self._delivered:
-                ask["delivered"], self._delivered = self._delivered, []
-            kind, payload = self._ctx.call("stream_next", **ask)
-            if kind != "items":
-                self._end(kind, payload)
-                return
-            refs = [ObjectRef(p, owned=True) if k == "r" else None for k, p in payload]
-            self._i += len(payload)
-            for (k, p), ref in zip(payload, refs):
-                if ref is not None:
-                    yield self._ctx.get([ref], timeout)[0]
+        handle).  The first call SUBSCRIBES, once (``Head._stream_subscribe``);
+        from then on the head pushes every item as it arrives, what had
+        arrived before at once, and the end or the producer's failure after
+        the last: nothing is asked for and nothing parks in the head.  An
+        item small enough to be stored inline comes in the push itself and
+        is an object no longer; one that is not comes as a reference and is
+        fetched here, within ``timeout``.  The wait for an item is as
+        ``next()``'s: until the producer yields, ends or fails.
+
+        An item is ACKED when this iterator takes it, not when it arrives:
+        the context gathers what all its streams took and says so in one
+        message (``BaseContext._stream_took``), so a consumer that stops
+        taking stalls its producer at the window as ever."""
+        if self._done or self._disposed:
+            return
+        ctx, ready = self._ctx, self._ready
+        inbox = self._inbox
+        if inbox is None:
+            inbox = self._inbox = _Inbox()
+            self._filed = self._i
+            ctx._stream_subscribe(self._task_id, self._i, inbox)
+        while not (self._disposed or self._done):
+            while ready and not self._disposed:
+                item = ready.popleft()
+                self._i += 1
+                delivered, self._delivered = self._delivered, []
+                ctx._stream_took(self._task_id, self._i, delivered, self._woke)
+                self._woke = False
+                if type(item) is ObjectRef:
+                    yield ctx.get([item], timeout)[0]
                 else:
-                    yield self._ctx._materialize(b"", ("inline", p, False))
+                    yield ctx._materialize(b"", ("inline", item, False))
+            ended = self._ended
+            if ended is not None and self._i >= ended[0]:
+                if self._delivered:  # the last write gaps ride an ack of their own
+                    delivered, self._delivered = self._delivered, []
+                    ctx._stream_took(self._task_id, self._i, delivered, False)
+                inbox.retire()
+                self._end("end" if ended[1] is None else "error", ended[1])
+                return
+            self._file(inbox)
+
+    def _file(self, inbox: _Inbox) -> None:
+        """Wait for one entry of the inbox and file it: items whose turn it
+        is become ready, others wait for theirs (two threads of the head
+        may send at once), the end is noted."""
+        q = inbox.q
+        try:
+            entry = q.get_nowait()
+        except queue.Empty:
+            inbox.waiting = True
+            while True:
+                try:
+                    entry = q.get(timeout=1.0)
+                    break
+                except queue.Empty:
+                    # the timeout bounds what no message reaches: shutdown
+                    if self._ctx.closed:
+                        entry = (None, rex.RayError("shutting down"), None, False, None)
+                        break
+            inbox.waiting = False
+        start, items, ended, woke, then = entry
+        if then is not None:
+            then[0].put(then[1])  # the next consumer the same push woke: its turn
+        if start is None:  # ended here: closed, or the connection is gone
+            self._done = True
+            inbox.retire()
+            if items is not None:
+                raise items
+            return
+        self._woke = self._woke or woke
+        if ended is not None:
+            self._ended = ended
+        if not items:
+            return
+        if start != self._filed:
+            self._ahead[start] = items
+            return
+        while items:
+            self._ready.extend(items)
+            self._filed += len(items)
+            items = self._ahead.pop(self._filed, None)
 
     def _end(self, kind: str, payload) -> None:
         """The stream is over: quietly ('end'), or by the producer's
@@ -364,7 +488,19 @@ class ObjectRefGenerator:
             return
         self._disposed = True
         try:
+            inbox = self._inbox
+            if inbox is not None:
+                # no push finds it any more (a dict's pop: no lock); what it
+                # holds untaken is freed with its references
+                self._ctx._stream_inboxes.pop(self._task_id, None)
+                self._ready.clear()
+                self._ahead.clear()
+                inbox.retire()
+                inbox.q.put((None, None, None, False, None))  # an iterator parked on it
             if blocking:
+                if inbox is not None:
+                    # what was taken is acked BEFORE the stream is gone
+                    self._ctx._flush_stream_acks()
                 self._ctx.call("stream_dispose", task_id=self._task_id)
             elif not self._ctx.closed:
                 self._ctx.enqueue_gc(
@@ -424,6 +560,20 @@ class BaseContext:
         # retriable error get() raises. The head may never learn these ids,
         # so resolving them locally is what keeps a ref from hanging.
         self._poisoned: dict[bytes, Exception] = {}
+        # pushed streams (``ObjectRefGenerator.values``): task id -> the
+        # inbox the recv loop files that stream's pushes into
+        self._stream_inboxes: dict[bytes, _Inbox] = {}
+        # what their iterators took since the last ack message: task id ->
+        # [consumed, delivered gaps]; ONE flusher thread a context sends it
+        # (started at the first item taken)
+        self._stream_acks: dict[bytes, list] = {}
+        self._stream_ack_lock = threading.Lock()
+        self._stream_ack_send = threading.Lock()  # one ack message at a time, in order
+        self._stream_ack_expected = 0  # consumers a push woke, yet to take
+        self._stream_ack_taken = 0     # items taken, ever: the flusher's sign of life
+        self._stream_ack_wake = threading.Event()  # something to send
+        self._stream_ack_now = threading.Event()   # nobody left to wait for
+        self._stream_acker: Optional[threading.Thread] = None
         self._thunk_threads: list[threading.Thread] = []
         self._gc_thread = threading.Thread(
             target=self._gc_drain_loop, name="gc-drain", daemon=True
@@ -495,6 +645,8 @@ class BaseContext:
             try:
                 if kind == "call":
                     method, kwargs = payload
+                    if method == "stream_dispose":
+                        self._flush_stream_acks()  # what was taken, first
                     self.call(method, **kwargs)
                 elif kind == "thunk":
                     # thunks may block for seconds (e.g. CompiledDAG teardown
@@ -546,6 +698,115 @@ class BaseContext:
                 self.call("unsubscribe", channel=channel)
             except Exception:
                 pass
+
+    # -- pushed streams ------------------------------------------------------
+    def _stream_subscribe(self, task_id: bytes, index: int, inbox: "_Inbox") -> None:
+        """``ObjectRefGenerator.values`` starts: ONE message a stream."""
+        self._stream_inboxes[task_id] = inbox
+        self.call("stream_subscribe", task_id=task_id, index=index)
+
+    def _on_stream_push(self, entries) -> None:
+        """The recv loop (in the head's own process: the thread that
+        flushes): ONE ``stream_push`` message, an entry ``(task_id, start,
+        items, end)`` a stream (``Head._push_stream``).  Each goes to its
+        generator's inbox; a reference is held from here on, so that it is
+        freed whatever becomes of the generator.
+
+        The consumers that the push finds PARKED on their inboxes (a proxy's
+        thread a stream, each waiting for its row's next token) are woken
+        one after another in the push's order, which is the producer's (an
+        engine's rows): this thread wakes the first, and each passes the
+        turn on as it takes its entry, before it does anything with it.
+        Woken all at once they would run in whatever order the interpreter
+        lock fell to them, another one every step, and a stream's place in
+        that order is what its consumer downstream sees as jitter; they
+        cannot run side by side anyway.  A consumer that is busy is no part
+        of the chain and holds nobody up."""
+        inboxes = self._stream_inboxes
+        parked = []
+        for task_id, start, items, ended in entries:
+            items = [ObjectRef(p, owned=True) if k == "r" else p for k, p in items]
+            inbox = inboxes.get(task_id)
+            if inbox is None:
+                continue  # closed here meanwhile: its references go with `items`
+            if inbox.waiting:
+                inbox.waiting = False
+                parked.append((inbox, start, items, ended))
+            else:
+                inbox.put((start, items, ended, False, None))
+        if parked:
+            with self._stream_ack_lock:
+                self._stream_ack_expected += len(parked)
+            then = None
+            for inbox, start, items, ended in reversed(parked):
+                then = (inbox, (start, items, ended, True, then))
+            then[0].put(then[1])
+
+    def _stream_took(self, task_id: bytes, consumed: int, delivered: list,
+                     woke: bool) -> None:
+        """A pushed stream's iterator took the item before ``consumed``
+        (any thread).  Noted for the context's next ack message, which the
+        flusher sends as soon as every consumer that the same push woke has
+        taken its item too: a step's sixteen acks are one message."""
+        with self._stream_ack_lock:
+            acks = self._stream_acks
+            first = not acks  # else the flusher is awake, or about to be
+            ent = acks.get(task_id)
+            if ent is None:
+                acks[task_id] = [consumed, delivered]
+            else:
+                ent[0] = consumed
+                ent[1].extend(delivered)
+            self._stream_ack_taken += 1
+            if woke and self._stream_ack_expected:
+                self._stream_ack_expected -= 1
+            all_in = not self._stream_ack_expected
+            if self._stream_acker is None:
+                self._stream_acker = threading.Thread(
+                    target=self._stream_ack_loop, name="stream-acker", daemon=True
+                )
+                self._stream_acker.start()
+        if all_in:
+            self._stream_ack_now.set()
+        if first:
+            self._stream_ack_wake.set()
+
+    def _stream_ack_loop(self) -> None:
+        wake, now = self._stream_ack_wake, self._stream_ack_now
+        while not self.closed:
+            wake.wait()
+            wake.clear()
+            while self._stream_ack_expected:
+                # the consumers one push woke take their items one after
+                # another: while they keep coming, their acks ride this message
+                seen = self._stream_ack_taken
+                if now.wait(_ACK_GATHER_S):
+                    break
+                if self._stream_ack_taken == seen:
+                    with self._stream_ack_lock:
+                        self._stream_ack_expected = 0  # whoever is late acks alone
+            now.clear()
+            try:
+                self._flush_stream_acks()
+            except Exception as e:  # noqa: BLE001 - the connection is going
+                warn_throttled("stream ack flush", e)
+
+    def _flush_stream_acks(self) -> None:
+        """Say what this context's pushed streams took since it last did:
+        ONE ``stream_consumed`` message for all of them.  The flusher's
+        work; a stream's disposal does it first, here (``_dispose``, the gc
+        drain), so that the last acks of a stream precede its end."""
+        with self._stream_ack_send:
+            with self._stream_ack_lock:
+                acks, self._stream_acks = self._stream_acks, {}
+            if acks and not self.closed:
+                self.call("stream_consumed", acks=[(t, c, d) for t, (c, d) in acks.items()])
+
+    def _fail_streams(self, error: BaseException) -> None:
+        """The way to the head is gone: every pushed stream's iterator
+        raises ``error`` where it would have waited for ever."""
+        for inbox in list(self._stream_inboxes.values()):
+            inbox.q.put((None, error, None, False, None))
 
     # -- objects ----------------------------------------------------------
     def put(self, value: Any) -> ObjectRef:
@@ -868,6 +1129,8 @@ class BaseContext:
             if t is not threading.current_thread():  # channel unlinks
                 t.join(timeout=5.0)
         self.closed = True
+        self._fail_streams(rex.RayError("shutting down"))
+        self._stream_ack_wake.set()  # the flusher sees `closed` and ends
         with self._readers_lock:
             for reader in self._readers.values():
                 reader.close()
@@ -948,6 +1211,8 @@ class DriverContext(BaseContext):
             return self.head.subscribe_local(payload["channel"], self.on_pub)
         if method == "unsubscribe":
             return self.head.unsubscribe_local(payload["channel"], self.on_pub)
+        if method == "stream_subscribe":
+            return self.head.stream_subscribe_local(self._on_stream_push, **payload)
         if method == "free_ref_async":
             # runs on the gc-drain thread (never from __del__ directly):
             # blocking on the head lock here is safe, and eviction may queue
@@ -1284,6 +1549,10 @@ class WorkerContext(BaseContext):
             except Exception:
                 pass
             return None
+        if method in ("stream_subscribe", "stream_consumed"):
+            # one-way: the answer to a subscription is the pushes
+            self._send(("req", 0, method, payload))
+            return None
         return self._call_blocking(method, payload)
 
     def _free_refs_rpc(self, ids: list) -> None:
@@ -1525,6 +1794,12 @@ class RemoteDriverContext(WorkerContext):
                 # the fresh connection), then redial with the session token
                 self._fail_pending()
                 self._fail_submits()
+                # a pushed stream's items may have died with the socket:
+                # its generator fails as a call in flight does (the head
+                # disposes the stream when it sees the connection go)
+                self._fail_streams(rex.RayError(
+                    "connection to the cluster was lost mid-stream"
+                ))
                 if self.closed or not self._try_reconnect():
                     # giving up for good: re-queued puts will never ship —
                     # poison them so pending gets raise instead of hanging
@@ -1536,6 +1811,8 @@ class RemoteDriverContext(WorkerContext):
                 self.on_response(seq, ok, payload)
             elif msg[0] == "pub":
                 self.on_pub(msg[1], msg[2])
+            elif msg[0] == "stream_push":
+                self._on_stream_push(msg[1])
             elif msg[0] == "submit_ack":
                 self._on_submit_ack(msg[1]["wid"])
 

@@ -18,18 +18,24 @@ grows from one station to the next is where the tail is made.
   ``stream_items`` for every stream's items of a step, and items of one
   stream in one message read a gap of 0 between them.
 * ``acked`` — the worker's recv loop, the item's ``stream_ack`` arrived:
-  the consumer took it from the head, plus the hop back.  A consumer that
-  asks for values (``ObjectRefGenerator.values``) takes every item that
-  has arrived in one ask: one ack, and a gap of 0 between its items.
+  the consumer TOOK it, plus the two hops back.  A consumer that reads
+  values (``ObjectRefGenerator.values``) is pushed to and acks what its
+  iterators took, every stream of its process in ONE message
+  (``BaseContext._flush_stream_acks``), which the head passes on as ONE
+  ``stream_ack`` a producing worker; items of one stream acked together
+  read a gap of 0 between them.  A consumer that reads references asks
+  for each (``stream_next``), and the ask is the ack.
 * ``written`` — the consumer's own report (``ObjectRefGenerator
   .report_delivered``; the HTTP proxy reports the gaps between chunks
-  written and drained), carried by ``stream_next`` and the ack.
+  written and drained), carried by the ack of the next item taken (or the
+  next ``stream_next``).
 
 Two legs are durations inside one process: ``wake`` (a token's wait for
 the thread that sends it: in ``req.stream`` for its handler thread,
 ``LLMEngine.stream_tokens``, or in the outbox until ``stream_sink.Outbox.flush``
-picks it up) and ``head_hold`` (an item's stay in the head before its
-consumer had it, ``hold_s`` on the ack).  All of it lands in the PRODUCING
+picks it up) and ``head_hold`` (an item's stay in the head: from its arrival
+to its push to the stream's subscriber, or to the ask that took it;
+``hold_s`` on the ack).  All of it lands in the PRODUCING
 worker's registry, where ``snapshot()`` reads it for
 ``LLMDeployment.stats()``.
 
@@ -39,6 +45,13 @@ is the rows a message carries, about the live rows of a step) and
 ``deferred`` (items a stream's ack window held back to a later message).
 ``backpressure`` keeps its meaning on both paths: an item that had to wait
 for its stream's window, and how long.
+
+The acks count themselves under ``ack``: ``messages`` (``stream_ack``
+messages this worker received), ``streams`` (the acks they carried, one a
+stream) and ``items`` (the items those said were taken).  ``streams /
+messages`` near the live rows of a step says the consumer's acks come
+gathered; near 1, that each stream's ack travels alone (a consumer that
+asks item by item, or one whose streams do not move together).
 """
 
 from __future__ import annotations
@@ -57,17 +70,22 @@ METRIC_NAMES = (
     "core_stream_batch_items",
     "core_stream_batch_streams",
     "core_stream_batch_deferred",
+    "core_stream_ack_messages",
+    "core_stream_ack_streams",
+    "core_stream_ack_items",
 )
 
 STATIONS = ("sent", "acked", "written")
 LEGS = ("wake", "head_hold")
 BATCH = ("sends", "items", "streams", "deferred")
+ACK = ("messages", "streams", "items")
 
 
 class _Stations:
     """The process's station series, each bound to its tag set once."""
 
-    __slots__ = STATIONS + LEGS + BATCH + ("waits", "wait_s")
+    __slots__ = STATIONS + LEGS + BATCH + ("waits", "wait_s") + tuple(
+        "ack_" + name for name in ACK)
 
     def __init__(self):
         from ray_tpu.util.metrics import Counter, Histogram
@@ -110,6 +128,17 @@ class _Stations:
             "core_stream_batch_deferred",
             "items a stream's ack window held back to a later message",
         )
+        self.ack_messages = Counter(
+            "core_stream_ack_messages",
+            "stream_ack messages this producing worker received",
+        )
+        self.ack_streams = Counter(
+            "core_stream_ack_streams",
+            "acks those messages carried, one a stream, summed over messages",
+        )
+        self.ack_items = Counter(
+            "core_stream_ack_items", "items those acks said the consumer took",
+        )
 
 
 _STATIONS = None
@@ -139,4 +168,5 @@ def snapshot(emit: list) -> dict:
         "wait_s": st.wait_s.value(),
     }
     out["batch"] = {name: int(getattr(st, name).value()) for name in BATCH}
+    out["ack"] = {name: int(getattr(st, "ack_" + name).value()) for name in ACK}
     return out

@@ -344,6 +344,9 @@ def _recv_loop(conn, ctx: WorkerContext, state: WorkerState):
             ctx.on_response(seq, ok, payload)
         elif kind == "pub":
             ctx.on_pub(msg[1], msg[2])
+        elif kind == "stream_push":
+            # a step's items of every stream this process reads by value
+            ctx._on_stream_push(msg[1])
         elif kind == "run_task_batch":
             # head coalesced dispatches (flush_outbox); FIFO order within
             # the batch is the dispatch order
@@ -693,48 +696,54 @@ def _store_results(state: WorkerState, spec: dict, value, is_error=False):
     return results
 
 
-def _on_stream_ack(state: WorkerState, ack: dict) -> None:
-    """Recv loop: the consumer took an item from the head.  Opens the
-    producer's window, and feeds three stations of the streaming path
-    (``_private.stream_stats``): the gap between this stream's acks
-    (``acked``; an ack for several items taken in one ask reads 0 between
-    them), how long the head held the item (``hold_s`` →
+def _on_stream_ack(state: WorkerState, acks: list) -> None:
+    """Recv loop: ONE message of the head's, an ack a stream: the consumer
+    took items (a pushed stream's iterator took them, coalesced over every
+    stream of that consumer: ``BaseContext._flush_stream_acks``; or it
+    asked for one by reference).  Opens each producer's window, and feeds
+    three stations of the streaming path (``_private.stream_stats``): the
+    gap between a stream's acks (``acked``; items acked together read 0
+    between them), how long the head held each item (``hold_s`` →
     ``head_hold``) and the write gaps the consumer reported (``delivered``
-    → ``written``)."""
+    → ``written``).  Counted under ``ack``: messages, streams, items."""
     now = time.perf_counter()
-    tid = ack["task_id"]
-    t_prev = rid = None
-    together = 0  # items this ack covers beyond its first: taken in one ask
-    behind = False  # the batched path holds items this ack's window lets out
+    st = _stream_stats.stations()
+    n_items = 0
+    behind = False  # the batched path holds items an ack's window lets out
+    marks = []  # (rid, last index) of the acks that moved a window
     with state.stream_lock:
-        stream = state.streams.get(tid)
-        # (None: its last ack is in, or the producer failed or was cancelled)
-        if stream is not None and ack["consumed"] > stream.acked:
-            together = ack["consumed"] - stream.acked - 1
+        for ack in acks:
+            stream = state.streams.get(ack["task_id"])
+            # (None: its last ack is in, or the producer failed or was cancelled)
+            if stream is None or ack["consumed"] <= stream.acked:
+                continue
+            together = ack["consumed"] - stream.acked
+            n_items += together
             stream.acked = ack["consumed"]
-            t_prev, stream.t_ack, rid = stream.t_ack, now, stream.rid
+            t_prev, stream.t_ack = stream.t_ack, now
             if stream.cond is not None:
                 stream.cond.notify()
             if stream.count is not None and stream.acked >= stream.count:
-                del state.streams[tid]  # ended, and this was its last ack
-            behind = stream.sink is not None and bool(stream.sink.held)
+                del state.streams[ack["task_id"]]  # ended, and this was its last ack
+            behind = behind or (stream.sink is not None and bool(stream.sink.held))
+            marks.append((stream.rid, ack["consumed"] - 1, t_prev, together))
     if behind:
         state.outbox.flush_soon()  # the sender's: this thread parks on no send
-    st = _stream_stats.stations()
-    if t_prev is not None:
-        st.acked.observe(now - t_prev)
-    for _ in range(together):
-        st.acked.observe(0.0)  # the consumer had them at one moment
-    hold_s = ack.get("hold_s")
-    if hold_s is not None:
-        # an item's stay in the head; a list where one ask took several
-        for h in hold_s if type(hold_s) is list else (hold_s,):
-            st.head_hold.observe(h)
-    for gap in ack.get("delivered") or ():
-        st.written.observe(gap)
-    if rid is not None:
-        with _tracing.annotate("core.stream.ack", rid=rid, i=ack["consumed"] - 1):
+    for rid, last, t_prev, together in marks:
+        if t_prev is not None:
+            st.acked.observe(now - t_prev)
+        for _ in range(together - 1):
+            st.acked.observe(0.0)  # the consumer had them at one moment
+        with _tracing.annotate("core.stream.ack", rid=rid, i=last):
             pass  # an instant on the recv thread's line
+    for ack in acks:
+        for h in ack.get("hold_s") or ():  # an item's stay in the head
+            st.head_hold.observe(h)
+        for gap in ack.get("delivered") or ():
+            st.written.observe(gap)
+    st.ack_messages.inc()
+    st.ack_streams.inc(len(acks))
+    st.ack_items.inc(n_items)
 
 
 def _stream_results(state: WorkerState, spec: dict, gen) -> None:

@@ -377,9 +377,12 @@ class ProxyActor:
         the client disconnects. Sentinels: ("end", None) | ("error", exc).
 
         ``written`` is the coroutine's list of gaps between the chunks it
-        has written and drained (``_respond_stream``): before each ask for
-        an item this thread hands what is there to the generator, which
-        carries it to the producing replica (the ``written`` station)."""
+        has written and drained (``_respond_stream``): after each item this
+        thread hands what is there to the generator, which carries it to
+        the producing replica on the ack of the next item it takes (the
+        ``written`` station).  The items are PUSHED to this process
+        (``ObjectRefGenerator.values``): this thread's wait for the next is
+        on a local queue, not a round trip to the head."""
 
         def post(item):
             loop.call_soon_threadsafe(q.put_nowait, item)

@@ -96,6 +96,7 @@ class Rows:
         snap = stream_stats.snapshot(emit=[])
         out = dict(snap["batch"], **snap["backpressure"])
         out.update(sent=sum(snap["sent"]), wake=sum(snap["wake"]))
+        out.update({"ack_" + k: v for k, v in snap["ack"].items()})
         return out
 
 

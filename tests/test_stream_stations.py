@@ -60,12 +60,17 @@ def _gained(actor, before):
         time.sleep(0.05)
 
 
-def _consume(gen, report=True, sleep_every=0, sleep_s=0.0):
+#: a consumer reads references (an ask an item) or values (pushed, acks gathered)
+READS = pytest.mark.parametrize("values", [False, True], ids=["by_reference", "by_value"])
+
+
+def _consume(gen, report=True, sleep_every=0, sleep_s=0.0, values=False):
     """Drain a stream as a consumer that passes items on would: before each
     ask it reports the gap between the two items it 'wrote' last."""
     out, t_prev = [], None
-    for i, ref in enumerate(gen):
-        out.append(ray_tpu.get(ref, timeout=30))
+    items = gen.values(timeout=30) if values else (ray_tpu.get(r, timeout=30) for r in gen)
+    for i, item in enumerate(items):
+        out.append(item)
         now = time.perf_counter()
         if report and t_prev is not None:
             gen.report_delivered([now - t_prev])
@@ -102,29 +107,36 @@ def producer():
     ray_tpu.shutdown()
 
 
-def test_every_station_counts_each_stream(producer):
+@READS
+def test_every_station_counts_each_stream(producer, values):
     """(a) two streams of 30 and 12 items through a local head, the
     consumer reporting: a gap an item from each stream's second on at
     ``sent``, ``acked`` and ``written``; ``head_hold`` one an item."""
     actor, before = producer
     for n in (30, 12):
         gen = actor.items.options(num_returns="streaming").remote(n)
-        assert _consume(gen) == list(range(n))
+        assert _consume(gen, values=values) == list(range(n))
+        gen.close()  # (its last acks leave before its disposal)
     got = _gained(actor, before)
     assert got["sent"] == got["acked"] == got["written"] == 29 + 11, got
     assert got["head_hold"] == 42, got
     assert got["emit"] == got["wake"] == 0  # no engine in this process
 
 
-def test_a_slow_consumer_shows_downstream_only(producer):
+@READS
+def test_a_slow_consumer_shows_downstream_only(producer, values):
     """(b) a consumer that sleeps 80 ms before every sixth ask (and stays
-    inside the window of 16): the acks come late and the items lie in the
-    head, the producer sends every 8 ms as before."""
+    inside the window of 16): the acks come late, the producer sends every
+    8 ms as before.  Read by reference the items lie in the HEAD meanwhile
+    (``head_hold``); read by value they are pushed on arrival and lie in the
+    consumer's inbox, so the head holds none for long."""
     actor, before = producer
     gen = actor.items.options(num_returns="streaming").remote(30, 0.008)
-    assert len(_consume(gen, sleep_every=6, sleep_s=0.08)) == 30
+    assert len(_consume(gen, sleep_every=6, sleep_s=0.08, values=values)) == 30
+    gen.close()
     got = _gained(actor, before)
-    assert got["slow"]["acked"] >= 3 and got["slow"]["head_hold"] >= 3, got
+    assert got["slow"]["acked"] >= 3, got
+    assert got["slow"]["head_hold"] == 0 if values else got["slow"]["head_hold"] >= 3, got
     assert got["slow"]["sent"] <= 1 and got["waits"] == 0, got  # 1: a loaded CI host
 
 
@@ -140,18 +152,22 @@ def test_a_slow_generator_body_shows_at_sent(producer):
     assert got["slow"]["head_hold"] <= 1 and got["emit"] == 0, got
 
 
-def test_a_plain_consumer_leaves_written_empty(producer):
+@READS
+def test_a_plain_consumer_leaves_written_empty(producer, values):
     """(f) a consumer that reports nothing streams as before."""
     actor, before = producer
     gen = actor.items.options(num_returns="streaming").remote(25)
-    assert _consume(gen, report=False) == list(range(25))
+    assert _consume(gen, report=False, values=values) == list(range(25))
+    gen.close()
     got = _gained(actor, before)
     assert got["written"] == 0 and got["sent"] == got["acked"] == 24, got
 
 
-def test_backpressure_waits_are_counted(monkeypatch):
+@READS
+def test_backpressure_waits_are_counted(monkeypatch, values):
     """(c) a window of 2 and a consumer slower than the producer: the
-    producer waits for acks, and says how often and how long."""
+    producer waits for acks, and says how often and how long (read by value
+    too: an item pushed is not an item taken)."""
     # workers read the flag from their environment
     monkeypatch.setenv("RAY_TPU_STREAMING_BACKPRESSURE_ITEMS", "2")
     # (``init`` reads it into this process's config too: put that back after)
@@ -161,7 +177,7 @@ def test_backpressure_waits_are_counted(monkeypatch):
         actor = Producer.remote()
         before = ray_tpu.get(actor.totals.remote(), timeout=60)
         gen = actor.items.options(num_returns="streaming").remote(12)
-        assert len(_consume(gen, sleep_every=1, sleep_s=0.02)) == 12
+        assert len(_consume(gen, sleep_every=1, sleep_s=0.02, values=values)) == 12
         got = _gained(actor, before)
         assert got["waits"] > 0 and got["wait_s"] > 0.02, got
     finally:
@@ -231,7 +247,7 @@ def test_llm_deployment_stats_carry_the_stations():
             if gained["acked"] >= 2 * per_stream or time.time() > deadline:
                 break
             time.sleep(0.05)
-        assert set(stream) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch"}
+        assert set(stream) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch", "ack"}
         assert stream["bounds_s"] == list(FINE_LATENCY_BOUNDS_S)
         for k in STATION_KEYS:
             assert len(stream[k]) == len(stream["bounds_s"]) + 1, k
@@ -359,7 +375,7 @@ def test_the_stream_section_needs_no_engine_lock():
         dep._engine.start_watchdog().stop()  # the one the deployment started
         dep._loop.join(timeout=10)
     assert not dep._loop.is_alive()
-    assert set(section) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch"}
+    assert set(section) == set(STATION_KEYS) | {"bounds_s", "backpressure", "batch", "ack"}
     assert set(whole[0]["stream"]) == set(section) and "steps" in whole[0]
 
 

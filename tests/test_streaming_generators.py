@@ -247,7 +247,7 @@ def test_an_item_wakes_its_own_stream_only(ray_start_regular):
     assert list(slow) == []
 
 
-# -- values(): the items themselves, every one that has arrived in one ask --
+# -- values(): the items themselves, pushed (tests/test_stream_pushed.py has the path) --
 
 
 def test_values_are_the_items_in_order(ray_start_regular):
@@ -278,46 +278,8 @@ def test_values_error_mid_stream(ray_start_regular):
     assert got == [1, 2]
 
 
-def test_a_late_consumer_catches_up_in_one_ask(ray_start_regular):
-    """A consumer that has fallen behind is handed everything that has
-    arrived in ONE ask (its backlog costs one round trip, not one an item),
-    and one ack opens the producer's window for all of it."""
-    from ray_tpu._private.runtime import get_ctx
-
-    @ray_tpu.remote(num_returns="streaming")
-    def gen(n):
-        for i in range(n):
-            yield i
-
-    ctx = get_ctx()
-    asks = []
-    call = ctx.call
-
-    def counting(method, **payload):
-        if method == "stream_next":
-            asks.append(payload["index"])
-        return call(method, **payload)
-
-    ctx.call = counting
-    try:
-        g = gen.remote(12)
-        head = ctx.head
-        deadline = time.time() + 30
-        while time.time() < deadline:  # the whole stream has arrived (window 16)
-            with head.lock:
-                st = head.streams.get(g._task_id)
-                if st is not None and st["count"] == 12:
-                    break
-            time.sleep(0.01)
-        assert list(g.values(timeout=30)) == list(range(12))
-    finally:
-        del ctx.call
-    # one ask took the twelve, one more met the end
-    assert asks == [0, 12]
-
-
 def test_values_leave_no_object_behind(ray_start_regular):
-    """An inline item rides the answer and is released where it is handed
+    """An inline item rides the push and is released where it is handed
     out; an item too large for that comes as a reference, is fetched, and is
     freed with the reference."""
     import numpy as np
